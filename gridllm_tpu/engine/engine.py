@@ -51,6 +51,7 @@ window buffer (ops/sampling.py) capped at EngineConfig.repeat_window.
 from __future__ import annotations
 
 import dataclasses
+import math
 import random
 import threading
 import time
@@ -98,8 +99,13 @@ from gridllm_tpu.ops.spec import (
     tree_topology,
 )
 from gridllm_tpu.parallel.mesh import MeshConfig, build_mesh
-from gridllm_tpu.parallel.sharding import shard_cache, shard_params
-from gridllm_tpu.utils.config import env_bool, env_int, env_str
+from gridllm_tpu.parallel.sharding import shard_params
+from gridllm_tpu.utils.config import (
+    compile_cache_dir,
+    env_bool,
+    env_int,
+    env_str,
+)
 from gridllm_tpu.utils.logging import get_logger
 
 log = get_logger("engine")
@@ -159,38 +165,29 @@ _MODEL_LOAD_SECONDS = _OBS.histogram(
     buckets=(0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0),
 )
 
-# Persistent XLA compilation cache (ISSUE 20): wiring the jax config at
-# first engine construction (idempotent, process-global) means a
-# swapped-in model replays its warmup compiles from disk instead of
-# re-running XLA — the compile half of fast cold-start. Guarded: an old
-# jax without the knobs degrades to no cache, never a startup failure.
-_compile_cache_lock = threading.Lock()
-_compile_cache_dir: str | None = None
+# Persistent XLA compilation cache: a restarted worker, or a swapped-in
+# model, replays its compiles from disk instead of re-running XLA — the
+# compile half of a fast cold start. The directory is part of the cache
+# key, so it must not move: utils.config.compile_cache_dir names the one
+# in force.
 
 
-def ensure_compile_cache() -> str | None:
-    """Point jax at GRIDLLM_COMPILE_CACHE_DIR (once). Returns the active
-    cache dir, or None when disabled/unsupported."""
-    global _compile_cache_dir
-    with _compile_cache_lock:
-        if _compile_cache_dir is not None:
-            return _compile_cache_dir or None
-        cache_dir = env_str("GRIDLLM_COMPILE_CACHE_DIR")
-        _compile_cache_dir = cache_dir or ""
-        if not cache_dir:
-            return None
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            # tiny-model compiles are fast and small — cache them anyway,
-            # or the CPU tests/bench never exercise the persistent path
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception as e:  # pragma: no cover - jax version drift
-            log.warning("compile cache unavailable", error=str(e))
-            _compile_cache_dir = ""
-            return None
-        log.info("persistent compile cache enabled", dir=cache_dir)
-        return cache_dir
+def ensure_compile_cache() -> None:
+    """Point jax at the compile cache (idempotent, process-global). A
+    directory jax already holds stands — from JAX_COMPILATION_CACHE_DIR
+    in the environment, or set earlier in this process — so two
+    constructions never produce two paths."""
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+        log.info("persistent compile cache enabled",
+                 dir=jax.config.jax_compilation_cache_dir)
+    # cache the sub-second programs too: a worker's start-up is dozens of
+    # them, and tiny-model CPU runs would otherwise never exercise the
+    # persistent path
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
 # speculative decoding (ISSUE 5): draft-token accounting. proposed =
 # drafts sent to a verify step, accepted = drafts the model agreed with,
 # rejected = proposed - accepted (a draft discarded because an EARLIER one
@@ -230,6 +227,22 @@ _SPEC_ACCEPT_RATE = _OBS.histogram(
 _FLIGHTREC = default_flight_recorder()
 _FLIGHT_SAMPLE = 16
 
+# KV pool sizing (EngineConfig.num_pages = None). 1024 pages is every
+# slot of the worker's defaults at full context (8 slots x 128 pages);
+# more would only deepen the prefix cache.
+DEFAULT_NUM_PAGES = 1024
+# Device memory kept out of the pool for what XLA allocates per program
+# beside weights and KV: activations of a 1024-token prefill chunk, the
+# [slots, K+1, vocab] verify logits and the sampler's sorts over them,
+# the draft model's pool.
+WORKSPACE_RESERVE_BYTES = 2 << 30
+
+
+def _device_memory_stats(device) -> dict[str, int]:
+    """The allocator's statistics for one device ({} where the backend
+    keeps none: CPU). A seam: tests size pools against a faked limit."""
+    return device.memory_stats() or {}
+
 
 def _model_module(cfg: ModelConfig):
     if cfg.family == "mixtral":
@@ -263,7 +276,12 @@ class EngineConfig:
     quantize: str | None = None
     max_slots: int = 8
     page_size: int = 64
-    num_pages: int = 1024
+    # KV pool pages. None → the engine sizes the pool at construction:
+    # DEFAULT_NUM_PAGES, or what the device's free memory holds after
+    # the weights and WORKSPACE_RESERVE_BYTES when that is fewer. A
+    # number is taken as given and must fit the same budget. A backend
+    # that reports no memory statistics (CPU) is never sized against.
+    num_pages: int | None = None
     max_pages_per_slot: int = 128
     prefill_buckets: tuple[int, ...] = (64, 256, 1024, 4096)
     mesh: MeshConfig | None = None       # None → no mesh (single device)
@@ -570,8 +588,6 @@ class InferenceEngine:
         self.prewarm_duration_ns = 0
         self._load()
         self._build_fns()
-        if env_bool("GRIDLLM_PREWARM_COMPILES") and not self.embedding_only:
-            self.prewarm()
 
     # ---------------------------------------------------------- state setup
 
@@ -653,6 +669,8 @@ class InferenceEngine:
                 model=self.cfg.name, source=self.load_source,
             )
             return
+        self.config = c = dataclasses.replace(
+            c, num_pages=self._resolve_num_pages())
         self._init_device_state()
         self.load_duration_ns = time.perf_counter_ns() - t0
         self.max_context = min(
@@ -692,22 +710,51 @@ class InferenceEngine:
         return ok
 
     def prewarm(self) -> None:
-        """Compile the serving shapes before the first real request: one
-        inline greedy token compiles the smallest prefill bucket plus the
-        decode step (and, with the persistent compile cache, writes them
-        to disk for every future swap-in of this model). The recompile
-        tripwire is re-disarmed afterwards so warmup accounting still
-        treats the first REAL request as warmup."""
+        """Run every program a first request can need, before serving:
+        bucketed prefill at each bucket a prompt up to one chunk can pad
+        to, the chunked-prefill program, the decode or verify step, and
+        the prefix-cache admission — inline, as greedy requests of the
+        lengths that reach them. A worker that advertised a model first
+        would compile these inside its first requests, minutes on a cold
+        cache, under the scheduler's deadlines and the hang watchdog's
+        requeue; and a kernel that cannot compile fails here, at
+        construction. With the persistent cache a restart reads them
+        from disk. The recompile tripwire stays disarmed: the first REAL
+        completed request still ends warm-up."""
         if self.embedding_only or self.running:
             return
         t0 = time.perf_counter_ns()
-        self.generate(GenerationRequest(
-            id="prewarm",
-            prompt_ids=[1],
-            raw=True,
-            options={"temperature": 0, "seed": 0, "num_predict": 1},
-        ))
-        self._perf_armed = False
+        room = self.max_context - 2          # a prompt plus two tokens
+        lengths: list[int] = []
+        for b in self._buckets:
+            lengths.append(min(b, room))
+            if self._use_chunked and b >= self._chunk_len:
+                break
+        if self._use_chunked and self._chunk_len < room:
+            # two chunks of one program; sent twice when the prefix cache
+            # is on, so the second admission is a hit (window_seed)
+            lengths += [self._chunk_len + 1] * (
+                2 if self._prefix_cache_cap != 0 else 1)
+        self._perf_armed = True              # _finish arms only when False
+        try:
+            for i, n in enumerate(lengths):
+                t1 = time.perf_counter()
+                # one fill token per length: prompts that shared a first
+                # page would hit the prefix cache and skip their bucket
+                res = self.generate(GenerationRequest(
+                    id=f"prewarm-{i}", raw=True,
+                    prompt_ids=[1 + lengths.index(n)] * n,
+                    options={"temperature": 0, "seed": 0, "num_predict": 2},
+                ))
+                if res.done_reason == "error":
+                    raise RuntimeError(
+                        f"{self.cfg.name}: prewarm request of {n} tokens "
+                        f"failed: {res.error}")
+                log.info("prewarm step", model=self.cfg.name, promptTokens=n,
+                         cachedTokens=res.cached_tokens,
+                         ms=int((time.perf_counter() - t1) * 1000))
+        finally:
+            self._perf_armed = False
         self.prewarm_duration_ns = time.perf_counter_ns() - t0
         log.info("engine prewarmed", model=self.cfg.name,
                  ms=self.prewarm_duration_ns // 1_000_000)
@@ -914,11 +961,112 @@ class InferenceEngine:
             return d
         return lane_pad_dim(d)
 
+    def _new_cache(self, num_pages: int) -> PagedKVCache:
+        """An empty cache with a pool of `num_pages` (traceable)."""
+        c, mc = self.config, self.cfg
+        dpool = self._pool_head_dim()
+        if not self._kv_int8:
+            return PagedKVCache.create(
+                mc.num_layers, num_pages, c.page_size, mc.num_kv_heads,
+                dpool, c.max_slots, c.max_pages_per_slot,
+                dtype=jnp.dtype(c.dtype),
+            )
+        # resident int8 pool (ISSUE 11): QuantPages where the fp pool
+        # arrays would sit — int8 values + one f32 scale per (layer,
+        # page, row). Scales init to 1.0 so unwritten rows dequant to
+        # exact zeros. Halves KV HBM; the write dispatchers quantize
+        # per row at the boundary, the ragged kernel / jnp fallbacks
+        # dequantize on read.
+        shape = (mc.num_layers, num_pages, c.page_size, mc.num_kv_heads,
+                 dpool)
+
+        def pages():
+            return QuantPages(jnp.zeros(shape, jnp.int8),
+                              jnp.ones(shape[:3], jnp.float32))
+
+        return PagedKVCache(
+            k=pages(), v=pages(),
+            page_table=jnp.full((c.max_slots, c.max_pages_per_slot), -1,
+                                jnp.int32),
+            lengths=jnp.zeros((c.max_slots,), jnp.int32),
+            page_size=c.page_size,
+        )
+
+    def _page_bytes_per_device(self) -> int:
+        """Bytes ONE pool page (K and V, every layer, int8 scales
+        included) takes on the device that holds most of it."""
+        proto = jax.eval_shape(partial(self._new_cache, 1))
+        pools = jax.tree.leaves((proto.k, proto.v))
+        if self.mesh is None:
+            return sum(math.prod(a.shape) * a.dtype.itemsize for a in pools)
+        from gridllm_tpu.parallel.sharding import cache_shardings
+
+        sh = cache_shardings(proto, self.mesh)
+        return sum(
+            math.prod(s.shard_shape(a.shape)) * a.dtype.itemsize
+            for a, s in zip(pools, jax.tree.leaves((sh.k, sh.v))))
+
+    def _resolve_num_pages(self) -> int:
+        """KV pool size in pages, checked against the device (see
+        EngineConfig.num_pages). Called with the weights loaded, so the
+        allocator's bytes_in_use is the weights plus whatever else this
+        process already holds (other engines of a multi-model worker).
+        Logs pages, tokens and bytes; a pool that cannot fit raises here,
+        with the figures, instead of as an XLA out-of-memory error inside
+        the first request."""
+        c = self.config
+        devices = (list(self.mesh.devices.flat) if self.mesh is not None
+                   else jax.devices()[:1])
+        jax.block_until_ready(self.params)   # init temporaries are freed
+        free = limit = None
+        for d in devices:
+            stats = (_device_memory_stats(d)
+                     if d.process_index == jax.process_index() else {})
+            if stats.get("bytes_limit"):
+                f = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+                if free is None or f < free:
+                    free, limit = f, stats["bytes_limit"]
+        want = DEFAULT_NUM_PAGES if c.num_pages is None else c.num_pages
+        page_bytes = self._page_bytes_per_device()
+        if free is None:
+            pages = want
+        else:
+            fit = max(free - WORKSPACE_RESERVE_BYTES, 0) // page_bytes
+            # an explicit count is a contract; the default shrinks to the
+            # device, down to one slot at full context
+            floor = want if c.num_pages is not None else min(
+                want, c.max_pages_per_slot)
+            if fit < floor:
+                raise ValueError(
+                    f"{self.cfg.name}: KV pool does not fit the device: "
+                    f"{floor} pages of {c.page_size} tokens "
+                    f"({floor * page_bytes / 2**30:.2f} GiB at "
+                    f"{page_bytes} B/page/device) needed, {fit} fit — "
+                    f"device limit {limit / 2**30:.2f} GiB, "
+                    f"{(limit - free) / 2**30:.2f} GiB in use after "
+                    f"loading weights, {WORKSPACE_RESERVE_BYTES / 2**30:.2f}"
+                    " GiB reserved for workspace")
+            pages = min(want, fit)
+        log.info(
+            "kv pool sized", model=self.cfg.name, pages=pages,
+            tokens=pages * c.page_size, pageSize=c.page_size,
+            bytesPerDevice=pages * page_bytes, requestedPages=c.num_pages,
+            deviceFreeBytes=free, deviceLimitBytes=limit,
+            reserveBytes=WORKSPACE_RESERVE_BYTES if free is not None else None,
+            devices=len(devices))
+        return pages
+
     def _init_device_state(self) -> None:
         """(Re)build all device-side mutable generation state: KV pool,
         page allocator, sampler params, context counts, token/active rows."""
         c, mc = self.config, self.cfg
-        dtype = jnp.dtype(c.dtype)
+        # a rebuild (reset_device_state) frees the old pool FIRST: the pool
+        # is sized to fill the device, so two do not fit side by side, and
+        # a step that failed before it ran (a compile error) donated
+        # nothing — the old buffers are still live
+        old, self.cache = getattr(self, "cache", None), None
+        for buf in jax.tree.leaves(old):
+            buf.delete()
         dpool = self._pool_head_dim()
         if dpool != mc.head_dim_:
             # lane padding multiplies KV bytes per page while num_pages is
@@ -933,33 +1081,18 @@ class InferenceEngine:
                 hint=f"to keep KV HBM at the unpadded budget, set "
                      f"num_pages={int(c.num_pages * mc.head_dim_ / dpool)}",
             )
-        if self._kv_int8:
-            # resident int8 pool (ISSUE 11): QuantPages where the fp pool
-            # arrays would sit — int8 values + one f32 scale per (layer,
-            # page, row). Scales init to 1.0 so unwritten rows dequant to
-            # exact zeros. Halves KV HBM; the write dispatchers quantize
-            # per row at the boundary, the ragged kernel / jnp fallbacks
-            # dequantize on read.
-            shape = (mc.num_layers, c.num_pages, c.page_size,
-                     mc.num_kv_heads, dpool)
-            sshape = (mc.num_layers, c.num_pages, c.page_size)
-            cache = PagedKVCache(
-                k=QuantPages(jnp.zeros(shape, jnp.int8),
-                             jnp.ones(sshape, jnp.float32)),
-                v=QuantPages(jnp.zeros(shape, jnp.int8),
-                             jnp.ones(sshape, jnp.float32)),
-                page_table=jnp.full((c.max_slots, c.max_pages_per_slot),
-                                    -1, jnp.int32),
-                lengths=jnp.zeros((c.max_slots,), jnp.int32),
-                page_size=c.page_size,
-            )
+        if self.mesh is not None:
+            # built under jit with the mesh's shardings: no device ever
+            # holds more than its shard (a pool sized to several chips'
+            # memory does not fit the one a plain create would fill first)
+            from gridllm_tpu.parallel.sharding import cache_shardings
+
+            make = partial(self._new_cache, c.num_pages)
+            self.cache = self.perf.wrap("kv_pool_init", jax.jit(
+                make, out_shardings=cache_shardings(
+                    jax.eval_shape(make), self.mesh)), armable=False)()
         else:
-            cache = PagedKVCache.create(
-                mc.num_layers, c.num_pages, c.page_size, mc.num_kv_heads,
-                dpool, c.max_slots, c.max_pages_per_slot,
-                dtype=dtype,
-            )
-        self.cache = shard_cache(cache, self.mesh) if self.mesh else cache
+            self.cache = self._new_cache(c.num_pages)
         self.alloc = PageAllocator(
             c.num_pages, c.page_size, c.max_pages_per_slot,
             cache_pages=self._prefix_cache_cap, model=mc.name,
@@ -2338,8 +2471,10 @@ class InferenceEngine:
             except Exception as e:  # noqa: BLE001 — keep serving others
                 log.error("engine block failed; aborting in-flight requests",
                           model=self.cfg.name, error=str(e))
+                # 1000 chars: a Mosaic or XLA compile error names its
+                # cause a few hundred characters in
                 _FLIGHTREC.record("engine", "step_failure",
-                                  model=self.cfg.name, error=str(e)[:200],
+                                  model=self.cfg.name, error=str(e)[:1000],
                                   streak=fail_streak + 1)
                 self._inflight.clear()
                 self._t_prev_fetch = None

@@ -70,8 +70,23 @@ class ByteTokenizer:
         return ids[:max_len] if max_len is not None else ids
 
     def decode(self, ids: Sequence[int]) -> str:
-        data = bytes(i for i in ids if 0 <= i < 256)
-        return data.decode("utf-8", errors="replace")
+        """Ids below 256 are bytes. An id past EOS — a random-weight run
+        at a real model's vocabulary samples almost nothing else — is one
+        printable character chosen by the id, so every generated token is
+        text a streaming client receives; dropped, such a run would
+        stream no frame until its last."""
+        out: list[str] = []
+        run = bytearray()
+        for i in ids:
+            if 0 <= i < 256:
+                run.append(i)
+            elif i > 257:
+                if run:
+                    out.append(run.decode("utf-8", errors="replace"))
+                    run.clear()
+                out.append(chr(0x21 + i % 94))
+        out.append(run.decode("utf-8", errors="replace"))
+        return "".join(out)
 
 
 class HFTokenizer:

@@ -41,6 +41,7 @@ processes. Pure stdlib otherwise.
 
 from __future__ import annotations
 
+import math
 import os
 import shutil
 import threading
@@ -476,9 +477,15 @@ def memory_snapshot() -> dict[str, Any]:
                 kind = "kvPoolBytes"
             else:
                 kind = "workspaceBytes"
-            for shard in arr.addressable_shards:
-                entry = dev_entry(_device_label(shard.device))
-                nbytes = getattr(shard.data, "nbytes", 0)
+            # per-device bytes from the sharding's metadata. Walking
+            # arr.addressable_shards instead materialises one Array per
+            # shard, which the NEXT walk finds in live_arrays() and counts
+            # again — every figure doubled from the second snapshot on
+            # (seen on the chip, PR 21: /metrics scrapes take one too)
+            nbytes = math.prod(
+                arr.sharding.shard_shape(arr.shape)) * arr.dtype.itemsize
+            for device in arr.sharding.addressable_devices:
+                entry = dev_entry(_device_label(device))
                 entry[kind] += nbytes
                 entry["totalLiveBytes"] += nbytes
         except Exception:  # noqa: BLE001 — deleted mid-walk (donation race)

@@ -152,18 +152,9 @@ def kernel_mesh_axis(mesh, kvh: int, h: int | None = None):
 def _shard_map_kernel(mesh, body, in_specs, out_specs):
     """jax.shard_map for a kernel body: full-manual (all axes), with vma
     checking off — pallas_call can't annotate how outputs vary across
-    mesh axes, and the bodies here have no collectives to get wrong.
-    Resolves whichever spelling this jax ships: the stable ``jax
-    .shard_map`` (``check_vma``) or the older experimental one
-    (``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    mesh axes, and the bodies here have no collectives to get wrong."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def flat_lanes_ok(kvh: int, d: int) -> bool:

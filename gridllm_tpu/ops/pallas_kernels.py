@@ -42,11 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across the versions this
-# repo supports; resolve whichever this jaxlib ships
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 # masking sentinel for the online-softmax paths (same value as
 # ops/attention.py's _NEG_INF): large enough that exp(x - m) underflows
 # to exactly 0 for masked columns, small enough to stay finite in f32 —
@@ -313,7 +308,7 @@ def flash_prefill_streamed(
                 pltpu.VMEM((bq * g, d), jnp.float32),
             ],
             interpret=interpret,
-            compiler_params=_COMPILER_PARAMS(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
         )(jnp.stack([ln, wn]).reshape(1, 2), qb.reshape(t, kvh, g, d),
@@ -808,6 +803,12 @@ def prefix_chunk(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((c, kvh, g, d), q.dtype),
         interpret=interpret,
+        # same working set as the ragged kernel's chunk tiles, whose
+        # budget also covers this kernel's sublane-padded q/out blocks
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ragged_vmem_limit(
+                page_size, kvh, g, d, bq, c, 0, q.dtype.itemsize,
+                k_pages.dtype.itemsize)),
     )(scal, table_row.astype(jnp.int32), q[0].reshape(c, kvh, g, d),
       k_cur, v_cur, k_pages, v_pages)
     return out.reshape(1, c, h, d)
@@ -845,11 +846,11 @@ def _ragged_attn_kernel(
                                  # entry i = node j on node i's root path)
     if has_chunk:
         crow_ref = next(it)      # SMEM [maxp] chunk slot's page row
-        qc_ref = next(it)        # VMEM (BQ, KVH, G, D)
+        qc_ref = next(it)        # VMEM (KVH, BQ*G, D) — rows token-major
         kc_ref = next(it)        # VMEM (C, KVH, D) — resident chunk K
         vc_ref = next(it)
     if has_group:
-        qg_ref = next(it)        # VMEM (1, Td, KVH, G, D)
+        qg_ref = next(it)        # VMEM (1, KVH, Td*G, D) — rows token-major
         kg_ref = next(it)        # VMEM (1, Td, KVH, D)
         vg_ref = next(it)
     k_hbm = next(it)             # ANY [L, P, ps, KVH, D]
@@ -1007,8 +1008,8 @@ def _ragged_attn_kernel(
         start = scal_ref[2]
         total = scal_ref[3]
         r = bq * g
-        q = qc_ref[...].astype(jnp.float32) * scale  # [BQ, KVH, G, D]
-        q_heads = [_lp(q[:, h].reshape(r, d)) for h in range(kvh)]
+        q_heads = [_lp(qc_ref[h].astype(jnp.float32) * scale)
+                   for h in range(kvh)]             # KVH x [R, D]
         # row → chunk-relative token index (rows are token-major: g rows
         # per token)
         q_rel = i * bq + jax.lax.broadcasted_iota(jnp.int32, (r,), 0) // g
@@ -1068,25 +1069,28 @@ def _ragged_attn_kernel(
 
         _, l, acc = jax.lax.fori_loop(kb0, nkb, chunk_body, (m, l, acc))
         out = (acc / jnp.maximum(l, 1e-30))[..., :d]  # [KVH, R, D]
-        oc_ref[...] = (
-            out.reshape(kvh, bq, g, d).transpose(1, 0, 2, 3)
-            .astype(oc_ref.dtype)
-        )
+        oc_ref[...] = out.astype(oc_ref.dtype)
 
     def group_tile():
         s = i - nct if has_chunk else i
         length = lens_ref[s]
         r = td * g
-        q = qg_ref[0].astype(jnp.float32) * scale   # [Td, KVH, G, D]
-        q_heads = [_lp(q[:, h].reshape(r, d)) for h in range(kvh)]
+        q_heads = [_lp(qg_ref[0, h].astype(jnp.float32) * scale)
+                   for h in range(kvh)]             # KVH x [R, D]
         tok = jax.lax.broadcasted_iota(jnp.int32, (r,), 0) // g
         if has_tree:
             # tree verify (ISSUE 18): row token i's LOGICAL position is
             # length + depth[i] (its storage position stays length + i).
             # The topology rides in as two static-length scalar-prefetch
-            # rows; td unrolled scalar reads per tile (td <= 32).
-            depths = jnp.stack([tpos_ref[j] for j in range(td)])
-            row_depth = jnp.broadcast_to(depths[:, None], (td, g)).reshape(r)
+            # rows, spread over the rows/columns by td unrolled selects
+            # (td <= 32) — Mosaic has no [Td, G] -> [Td*G] shape cast.
+            def per_node(ref, idx):
+                out = jnp.zeros(idx.shape, jnp.int32)
+                for j in range(td):
+                    out = jnp.where(idx == j, ref[j], out)
+                return out
+
+            row_depth = per_node(tpos_ref, tok)
             q_abs = length + row_depth
         else:
             q_abs = length + tok
@@ -1121,11 +1125,9 @@ def _ragged_attn_kernel(
             # of the row's node (bit j of the row's ancestor bitmask),
             # windowed on logical (depth) distance — ancestor implies
             # dist >= 0, so no separate causal term
-            bits = jnp.stack([tbits_ref[j] for j in range(td)])
-            row_bits = jnp.broadcast_to(bits[:, None], (td, g)).reshape(r)
+            row_bits = per_node(tbits_ref, tok)
             anc = ((row_bits[None, :, None] >> col) & 1) != 0
-            dist = row_depth[None, :, None] - jnp.broadcast_to(
-                depths[None, None, :], (kvh, r, td))
+            dist = row_depth[None, :, None] - per_node(tpos_ref, col)
             valid = anc & ((window <= 0) | (dist < window))
         else:
             dist = tok[None, :, None] - col
@@ -1144,10 +1146,7 @@ def _ragged_attn_kernel(
             for h in range(kvh)
         ])
         out = (acc / jnp.maximum(l, 1e-30))[..., :d]  # [KVH, R, D]
-        og_ref[0] = (
-            out.reshape(kvh, td, g, d).transpose(1, 0, 2, 3)
-            .astype(og_ref.dtype)
-        )
+        og_ref[0] = out.astype(og_ref.dtype)
 
     if has_chunk and has_group:
         @pl.when(i < nct)
@@ -1161,6 +1160,36 @@ def _ragged_attn_kernel(
         chunk_tile()
     else:
         group_tile()
+
+
+def _ragged_vmem_limit(ps: int, kvh: int, g: int, d: int, bq: int, c: int,
+                       td: int, itemsize: int, pool_itemsize: int) -> int:
+    """Scoped-VMEM limit for one ragged_attention launch, from its
+    buffers: Mosaic's 16 MiB default is below what a 1024-token chunk at
+    llama3.2:3b's widths needs, and a limit that is too low is a compile
+    error in the first request. Pipelined blocks are double-buffered;
+    the f32 working set (per-head q, one page or key block of K and V,
+    logits, probabilities, the accumulator and its rescaled copy) is
+    counted at the larger of the two tile kinds. Doubled for what the
+    compiler keeps live beyond that, floored at the default and capped
+    well under the 128 MiB a v5e core has."""
+    dp = -(-d // 128) * 128
+    r = max(bq, td) * g
+    kcols = max(ps, bq)
+    blocks = 2 * itemsize * (
+        2 * kvh * bq * g * d          # chunk q + out tiles
+        + 2 * c * kvh * d             # resident chunk K + V
+        + 2 * kvh * td * g * d        # group q + out tiles
+        + 2 * td * kvh * d            # group fresh K + V
+    )
+    scratch = 2 * 2 * ps * kvh * d * pool_itemsize + 2 * 2 * ps * 4
+    work = 4 * (
+        kvh * r * dp * 3              # q heads, acc, rescaled acc
+        + kvh * r * kcols * 3         # logits, mask/exp temporaries, prob
+        + 2 * kvh * kcols * dp        # one page/block of K and V in f32
+    )
+    need = 2 * (blocks + scratch + work)
+    return int(min(max(need, 16 << 20), 96 << 20))
 
 
 @functools.partial(jax.jit,
@@ -1255,40 +1284,62 @@ def ragged_attention(
     if has_chunk:
         prefetch += [chunk_row.astype(jnp.int32)]
 
+    # Queries and outputs travel kv-head-major — [..., KVH, tokens*G, D],
+    # a token's G query heads on adjacent rows — so each kv head's rows
+    # are one aligned [R, D] slab for the per-head dots. A token-major
+    # [tokens, KVH, G, D] block pads every G-row group to a whole sublane
+    # tile in VMEM (5x for G = 3: 20 MB of blocks at C = 1024, past the
+    # scoped limit) and needs a 4-D transpose in the kernel.
+    def heads_major(x):      # [..., T, H, D] -> [..., KVH, T*G, D]
+        *lead, t, _, _ = x.shape
+        x = jnp.moveaxis(x.reshape(*lead, t, kvh, g, d), -3, -4)
+        return x.reshape(*lead, kvh, t * g, d)
+
+    def tokens_major(x):     # [..., KVH, T*G, D] -> [..., T, H, D]
+        *lead, _, tg, _ = x.shape
+        x = jnp.moveaxis(x.reshape(*lead, kvh, tg // g, g, d), -4, -3)
+        return x.reshape(*lead, tg // g, h, d)
+
     # block index clamps: chunk operands pin to their last tile during
     # group steps (and vice versa at index 0) — those blocks are simply
     # not re-fetched/written outside their region
     last_ct = max(nct - 1, 0)
 
+    def chunk_q_spec():
+        return pl.BlockSpec(
+            (kvh, bq * g, d),
+            lambda i, *_: (0, jnp.minimum(i, last_ct), 0),
+            memory_space=pltpu.VMEM)
+
+    def group_q_spec():
+        return pl.BlockSpec(
+            (1, kvh, td * g, d),
+            lambda i, *_: (jnp.maximum(i - nct, 0), 0, 0, 0),
+            memory_space=pltpu.VMEM)
+
     in_specs = []
     args = []
     if has_chunk:
         in_specs += [
-            pl.BlockSpec((bq, kvh, g, d),
-                         lambda i, *_: (jnp.minimum(i, last_ct), 0, 0, 0),
-                         memory_space=pltpu.VMEM),
+            chunk_q_spec(),
             pl.BlockSpec((c, kvh, d), lambda i, *_: (0, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((c, kvh, d), lambda i, *_: (0, 0, 0),
                          memory_space=pltpu.VMEM),
         ]
-        args += [q_chunk[0].reshape(c, kvh, g, d), k_chunk, v_chunk]
+        args += [heads_major(q_chunk[0]), k_chunk, v_chunk]
     if has_group:
-        def _gidx(i, *_):
-            return (jnp.maximum(i - nct, 0), 0, 0, 0, 0)
-
         def _gidx4(i, *_):
             return (jnp.maximum(i - nct, 0), 0, 0, 0)
 
         in_specs += [
-            pl.BlockSpec((1, td, kvh, g, d), _gidx,
-                         memory_space=pltpu.VMEM),
+            group_q_spec(),
             pl.BlockSpec((1, td, kvh, d), _gidx4,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, td, kvh, d), _gidx4,
                          memory_space=pltpu.VMEM),
         ]
-        args += [q_group.reshape(s, td, kvh, g, d), k_group, v_group]
+        args += [heads_major(q_group), k_group, v_group]
     in_specs += [pl.BlockSpec(memory_space=pl.ANY),
                  pl.BlockSpec(memory_space=pl.ANY)]
     args += [k_pages, v_pages]
@@ -1302,17 +1353,11 @@ def ragged_attention(
     out_specs = []
     out_shape = []
     if has_chunk:
-        out_specs.append(
-            pl.BlockSpec((bq, kvh, g, d),
-                         lambda i, *_: (jnp.minimum(i, last_ct), 0, 0, 0),
-                         memory_space=pltpu.VMEM))
-        out_shape.append(jax.ShapeDtypeStruct((c, kvh, g, d), dtype))
+        out_specs.append(chunk_q_spec())
+        out_shape.append(jax.ShapeDtypeStruct((kvh, c * g, d), dtype))
     if has_group:
-        out_specs.append(
-            pl.BlockSpec((1, td, kvh, g, d),
-                         lambda i, *_: (jnp.maximum(i - nct, 0), 0, 0, 0, 0),
-                         memory_space=pltpu.VMEM))
-        out_shape.append(jax.ShapeDtypeStruct((s, td, kvh, g, d), dtype))
+        out_specs.append(group_q_spec())
+        out_shape.append(jax.ShapeDtypeStruct((s, kvh, td * g, d), dtype))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
@@ -1334,13 +1379,17 @@ def ragged_attention(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ragged_vmem_limit(
+                page_size, kvh, g, d, bq, c, td, dtype.itemsize,
+                k_pages.dtype.itemsize)),
     )(*prefetch, *args)
     it = iter(outs)
     out_chunk = out_group = None
     if has_chunk:
-        out_chunk = next(it).reshape(1, c, h, d)
+        out_chunk = tokens_major(next(it))[None]
     if has_group:
-        out_group = next(it).reshape(s, td, h, d)
+        out_group = tokens_major(next(it))
     return out_chunk, out_group
 
 

@@ -128,9 +128,8 @@ def ring_attention(
         out = acc / jnp.maximum(l, 1e-30)
         return out.reshape(b, c, kvh_l * g, d).astype(q_loc.dtype)
 
-    # routed through the version-resolving wrapper (jax.shard_map/check_vma
-    # vs experimental shard_map/check_rep — ppermute's value motion defeats
-    # the replication check either way)
+    # vma checking off (the kernel wrapper's setting): ppermute's value
+    # motion defeats the check
     sm = _shard_map_kernel(
         mesh, local,
         in_specs=(

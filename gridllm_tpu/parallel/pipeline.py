@@ -60,20 +60,9 @@ Params = dict
 
 def _pp_shard_map(mesh, in_specs, out_specs):
     """Decorator for the pp token-passing programs: manual over {"pp"}
-    only, tp/ep/sp/dp stay AUTO (GSPMD). Resolves whichever shard_map
-    this jax ships — the stable ``jax.shard_map`` (``axis_names`` +
-    ``check_vma``) or the older experimental one (``auto`` = the
-    non-manual axes, ``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return partial(sm, mesh=mesh, axis_names={"pp"},
-                       in_specs=in_specs, out_specs=out_specs,
-                       check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return partial(shard_map, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False,
-                   auto=frozenset(mesh.axis_names) - {"pp"})
+    only, tp/ep/sp/dp stay AUTO (GSPMD)."""
+    return partial(jax.shard_map, mesh=mesh, axis_names={"pp"},
+                   in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
 
 def pp_size(mesh) -> int:

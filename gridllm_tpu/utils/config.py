@@ -72,6 +72,19 @@ def _registered(name: str) -> EnvVar:
     return var
 
 
+def compile_cache_dir() -> str:
+    """The persistent XLA compile cache's directory. JAX's own
+    JAX_COMPILATION_CACHE_DIR when the environment sets it — jax reads
+    that itself and the program sets no other — else one fixed directory
+    inside the checkout, ignored by git. Fixed because the path is part
+    of the cache key: a directory that moves never hits. Every process
+    of the program (workers, tests, bench.py, chip_smoke.py) resolves it
+    here, so they share one cache."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
+
+
 def env_raw(name: str) -> str | None:
     """The raw environment value, or None when unset. The name must be
     registered — callers with bespoke parsing start here."""
@@ -415,24 +428,13 @@ register_env("GRIDLLM_HEALTH_DEGRADED_PENALTY", "0.5",
              "probation workers (same scale as the proportional load "
              "term; mirrors prefix_affinity_weight).")
 
-# elastic serving (ISSUE 20) — snapshot tier, compile cache, placement
+# elastic serving (ISSUE 20) — snapshot tier, placement
 register_env("GRIDLLM_WEIGHT_SNAPSHOT_BYTES", "0",
              "Host-RAM weight snapshot tier capacity (bytes). Unloading "
              "a model parks its device params as host arrays keyed by "
              "checkpoint identity; a later load restores via host-to-"
              "device transfer instead of re-reading the checkpoint. "
              "LRU-evicted past capacity; 0 disables the tier.")
-register_env("GRIDLLM_COMPILE_CACHE_DIR", "",
-             "Persistent XLA compilation-cache directory (wired to "
-             "jax_compilation_cache_dir at engine construction). A "
-             "swapped-in model reuses compiles from any prior process "
-             "that warmed the same shapes. Empty disables.")
-register_env("GRIDLLM_PREWARM_COMPILES", "0",
-             "When 1, a freshly loaded engine runs a one-token greedy "
-             "prewarm request before serving, compiling the smallest "
-             "prefill bucket and the decode step so the first real "
-             "request skips warmup compiles (with the compile cache "
-             "this is a disk hit, not an XLA compile).")
 register_env("GRIDLLM_PLACEMENT_INTERVAL_MS", "0",
              "Model-placement controller cadence per scheduler shard "
              "(ms between ticks). Each tick compares per-model demand "

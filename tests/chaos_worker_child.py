@@ -10,10 +10,6 @@ import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 
 async def main() -> None:
     broker_port, worker_id = sys.argv[1], sys.argv[2]

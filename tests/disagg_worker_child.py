@@ -16,10 +16,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("GRIDLLM_KVX_CHUNK_BYTES", "2048")
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 
 async def main() -> None:
     broker_port, worker_id, role = sys.argv[1], sys.argv[2], sys.argv[3]

@@ -25,8 +25,6 @@ def main() -> None:
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-
     from gridllm_tpu.parallel.distributed import GroupConfig, initialize_group
 
     group = initialize_group(GroupConfig.from_env())
@@ -36,12 +34,11 @@ def main() -> None:
     # one real cross-host collective over the slice mesh
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from gridllm_tpu.parallel.mesh import MeshConfig, build_mesh
 
     mesh = build_mesh(MeshConfig(tp=8))
-    total = jax.jit(shard_map(
+    total = jax.jit(jax.shard_map(
         lambda x: jax.lax.psum(x, "tp"), mesh=mesh,
         in_specs=P("tp"), out_specs=P(),
     ))(jnp.arange(8.0))

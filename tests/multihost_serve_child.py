@@ -34,10 +34,6 @@ def main() -> None:
         "GRIDLLM_PREFILL_BUCKETS": "32,64",
         "HEARTBEAT_INTERVAL": "500",  # worker config reads HEARTBEAT_INTERVAL
     })
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from gridllm_tpu.worker.main import run
 
     print(f"[{pid}] starting worker", flush=True)

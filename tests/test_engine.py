@@ -394,3 +394,33 @@ def test_num_ctx_caps_request_context():
     assert res.prompt_eval_count < 16
     assert res.prompt_eval_count + res.eval_count <= 16
     assert res.done_reason in ("stop", "length")
+
+
+def test_byte_tokenizer_round_trip_and_streaming_increments():
+    """The byte tokenizer's contract: text round-trips through ids below
+    256, BOS/EOS render as nothing, and an id past EOS (all a
+    random-weight run at a real vocabulary ever samples) renders as
+    exactly one printable character — so a stream of such ids delivers
+    one text increment per token instead of nothing until its end."""
+    from gridllm_tpu.engine.tokenizer import ByteTokenizer, DetokState
+
+    tok = ByteTokenizer(128_256)
+    text = "héllo, wörld — ok"
+    ids = tok.encode(text, add_bos=True)
+    assert ids[0] == tok.bos_id and all(0 <= i < 256 for i in ids[1:])
+    assert tok.decode(ids) == text
+    assert tok.decode(ids + [257]) == text             # EOS is not text
+
+    high = [258, 300, 4242, 128_255]
+    out = tok.decode(high)
+    assert len(out) == len(high) and out.isprintable() and " " not in out
+    assert tok.decode([104, 105] + high[:1] + [33]) == "hi" + out[0] + "!"
+
+    # streaming: a multi-byte char split over two tokens is held back
+    # until whole; every id past EOS is one increment of one character
+    st, seen, got = DetokState(), [], []
+    for i in list("é!".encode()) + high:
+        seen.append(i)
+        got.append(st.delta(tok, seen))
+    assert got == ["", "é", "!"] + list(out)
+    assert "".join(got) == tok.decode(seen)

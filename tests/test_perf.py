@@ -150,6 +150,17 @@ def test_memory_snapshot_sums_and_kv_math(engine):
         cache.k.nbytes + cache.v.nbytes)
     # idle engine: nothing live, no fragmentation
     assert m["liveTokens"] == 0 and m["fragmentation"] == 0.0
+    # a snapshot leaves nothing behind for the next one to count: walking
+    # per-shard Arrays did, and every later figure came out doubled
+    register_memory_probe("test-perf", lambda: {
+        "tiny-llama": engine.memory_arrays()})
+    try:
+        again = memory_snapshot()
+    finally:
+        unregister_memory_probe("test-perf")
+    for label, dev in snap["devices"].items():
+        for kind in ("weightsBytes", "kvPoolBytes"):
+            assert again["devices"][label][kind] == dev[kind]
 
 
 def test_memory_fragmentation_counts_reserved_capacity(engine):
