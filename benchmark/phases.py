@@ -1,0 +1,80 @@
+"""What the ``runner.*`` readers and the two roofline readers share: the
+engine runner's phase series and the verify step's context counter.
+
+``gridllm_engine_phase_seconds{model,phase}`` partitions the runner
+thread's wall time (``obs/perf.py`` ``PhaseClock``): ``idle_wait``, ``ctl``,
+``admit``, ``dispatch_prefill``, ``draft``, ``dispatch_verify``, ``fetch``,
+``ingest``. ``_count{phase="dispatch_verify"}`` is the number of verify /
+decode launches. A program without the series (the parent of the PR that
+added it) gives {} and every reader built on this returns None.
+"""
+
+from __future__ import annotations
+
+import costs
+import readers
+import stack
+
+SERIES = "gridllm_engine_phase_seconds"
+CTX_TOKENS = "gridllm_engine_verify_ctx_tokens_total"
+IDLE, FETCH, LAUNCH = "idle_wait", "fetch", "dispatch_verify"
+
+
+def totals(text: str) -> dict[str, tuple[float, float]]:
+    """{phase: (seconds, stretches)} of one ``/metrics`` text, over models."""
+    out: dict[str, list[float]] = {}
+    for i, suffix in enumerate(("_sum", "_count")):
+        for labels, v in stack.metric_values(text, SERIES + suffix).items():
+            out.setdefault(dict(labels).get("phase"), [0.0, 0.0])[i] += v
+    return {p: (s, n) for p, (s, n) in out.items()}
+
+
+def between(before: str, after: str) -> dict[str, tuple[float, float]]:
+    """{phase: (seconds, stretches)} between two ``/metrics`` texts."""
+    b = totals(before)
+    return {p: (s - b.get(p, (0.0, 0.0))[0], n - b.get(p, (0.0, 0.0))[1])
+            for p, (s, n) in totals(after).items()}
+
+
+def window(run: dict) -> dict[str, tuple[float, float]]:
+    return between(run["worker_before"], run["worker_after"])
+
+
+def per_launch_ms(run: dict, keep) -> float | None:
+    """Σ seconds of the phases `keep(phase)` picks, a launch, in ms."""
+    w = window(run)
+    launches = w.get(LAUNCH, (0.0, 0.0))[1]
+    if launches <= 0:
+        return None
+    return 1e3 * sum(s for p, (s, _) in w.items() if keep(p)) / launches
+
+
+def kv_bytes_per_launch(run: dict) -> float | None:
+    """Mean KV bytes one verify / decode launch has to read, over the
+    capture: the context-token counter's change over the launches' (both
+    from ``trace_counters``, the worker's ``/metrics`` at the capture's two
+    ends) times the bytes of one position over every layer."""
+    ends = run.get("trace_counters")
+    if not ends:
+        return None
+    launches = between(*ends).get(LAUNCH, (0.0, 0.0))[1]
+    tokens = (stack.metric_sum(ends[1], CTX_TOKENS)
+              - stack.metric_sum(ends[0], CTX_TOKENS))
+    if launches <= 0 or tokens <= 0:
+        return None
+    return tokens / launches * costs.kv_bytes_per_token(run["config"])
+
+
+def verify_launches(run: dict) -> tuple[float, int]:
+    """(device seconds, launches) of the verify / decode programs in the
+    traced window."""
+    return readers.programs(run, readers.VERIFY_PROGRAMS)
+
+
+def hbm_bytes_per_s(run: dict) -> float | None:
+    """The chip's published memory bandwidth; None in a CPU rehearsal,
+    which has no roofline (an accelerator missing from ``peaks.json`` is
+    still an error, never a default)."""
+    if run["device"]["platform"] == "cpu":
+        return None
+    return costs.peaks(run["device"]["kind"])["hbm_bytes_per_s"]

@@ -69,9 +69,9 @@ from gridllm_tpu.models import llama
 from gridllm_tpu.models.configs import ModelConfig, get_config
 from gridllm_tpu.obs import SIZE_BUCKETS, default_flight_recorder, default_registry
 from gridllm_tpu.obs.perf import (
-    DEVICE_STEP_SECONDS,
-    DISPATCH_SECONDS,
-    HOST_SCHED_SECONDS,
+    ADMIT_WAIT_SECONDS,
+    VERIFY_CTX_TOKENS_TOTAL,
+    PhaseClock,
     RecompileTripwire,
 )
 from gridllm_tpu.ops.attention import ragged_attention_enabled
@@ -389,6 +389,8 @@ class GenerationRequest:
     snapshot_every: int = 0
     # called from the engine loop: (text_delta, done, result|None)
     on_chunk: Callable[[str, bool, "GenerationResult | None"], None] | None = None
+    # perf_counter_ns at submit(): the engine's stamp, for admit_wait
+    t_submit_ns: int = 0
 
 
 @dataclasses.dataclass
@@ -403,6 +405,9 @@ class GenerationResult:
     # ≤ prompt_eval_count, 0 with caching off
     cached_tokens: int = 0
     prompt_eval_duration_ns: int = 0
+    # submit() → popped for admission: the wait in the engine's pending
+    # queue, which ends where prompt_eval_duration_ns begins
+    admit_wait_ns: int = 0
     eval_count: int = 0
     eval_duration_ns: int = 0
     load_duration_ns: int = 0
@@ -431,7 +436,7 @@ class _Slot:
         "cached_tokens", "spec_proposed", "spec_accepted", "export_only",
         "snapshot",
         "t_start", "t_prefill_ns", "t_first_decode", "t_last_ingest",
-        "t_admit_wall", "pages_held", "device_s",
+        "t_admit_wall", "pages_held", "device_s", "admit_wait_ns",
     )
 
     def __init__(self, req: GenerationRequest, ids: list[int], capacity: int,
@@ -469,6 +474,7 @@ class _Slot:
         self.t_admit_wall = time.time()  # wall clock at admission
         self.pages_held = 0              # KV pages allocated to this slot
         self.device_s = 0.0              # accumulated decode device-second share
+        self.admit_wait_ns = 0           # submit → popped for admission
 
     def holdback(self) -> int:
         """Chars at the tail of `text` that could still become a stop
@@ -547,8 +553,8 @@ class InferenceEngine:
         self._free_slots = list(range(config.max_slots - 1, -1, -1))
         # dispatch pipeline state (runner thread / step()):
         self._gen = 0                     # generation counter of dispatched blocks
-        # (gen, toks, k, dispatch perf_counter ts)
-        self._inflight: deque[tuple[int, Any, int, float]] = deque()
+        # (gen, toks, k)
+        self._inflight: deque[tuple[int, Any, int]] = deque()
         # recompile tripwire (obs/perf.py): every jitted entry point is
         # wrapped; armed after the first naturally completed request, at
         # which point any new compile signature is a flagged steady-state
@@ -563,9 +569,11 @@ class InferenceEngine:
         self._tree_width = 1
         self.spec_stats = {"steps": 0, "proposed": 0, "accepted": 0,
                            "emitted": 0, "draft_ns": 0}
-        # step-time decomposition state (runner thread only)
-        self._t_prev_fetch: float | None = None
-        self._t_ingest_done: float | None = None
+        # the runner thread's wall time, phase by phase (obs/perf.py);
+        # runner_wall_s is the same stretch measured on its own, _run
+        # entry to exit, so a test can hold the phases to it
+        self._clock = PhaseClock(self.cfg.name)
+        self.runner_wall_s = 0.0
         # cross-thread control requests: ("cancel" | "suspend", req_id)
         self._ctl: deque[tuple[str, str]] = deque()
         self._work = threading.Condition()
@@ -1141,8 +1149,6 @@ class InferenceEngine:
         with self._alloc_lock, self.dispatch_lock:
             self._slots.clear()
             self._inflight.clear()
-            self._t_prev_fetch = None  # recovery wall must not read as
-            self._t_ingest_done = None  # device/host pace
             self._free_slots = list(range(self.config.max_slots - 1, -1, -1))
             self._init_device_state()
             if self._drafter is not None and hasattr(self._drafter, "reset"):
@@ -1544,6 +1550,8 @@ class InferenceEngine:
         with self._lock:
             if len(self._pending) >= self.config.max_queue:
                 raise RuntimeError("engine queue full")
+            if not req.t_submit_ns:  # a requeue keeps its first stamp
+                req.t_submit_ns = time.perf_counter_ns()
             self._pending.append(req)
         with self._work:
             self._work.notify_all()
@@ -1573,6 +1581,11 @@ class InferenceEngine:
             if not self._pending or not self._free_slots:
                 return False
             req = self._pending.popleft()
+        # marked only once a request was popped: the phase's count is
+        # the number of admissions tried
+        self._clock.mark("admit", request=req.id)
+        wait_ns = (time.perf_counter_ns() - req.t_submit_ns
+                   if req.t_submit_ns else 0)
         ids = self._tokenize(req)
         images = list(req.images or [])
         # decode resume (ISSUE 9): tokens a previous attempt already
@@ -1656,6 +1669,10 @@ class InferenceEngine:
         stop = opts.get("stop") or []
         stop_seqs = [stop] if isinstance(stop, str) else list(stop)
         st = _Slot(req, ids, want, num_predict, stop_seqs, self.tokenizer.eos_ids)
+        # observed once a slot and its pages are held: a request put back
+        # for want of pages is still waiting
+        st.admit_wait_ns = wait_ns
+        ADMIT_WAIT_SECONDS.observe(wait_ns / 1e9, model=self.cfg.name)
         if resume:
             # continue, don't restart: generated/detok/text pick up where
             # the lost attempt stopped (num_predict, stop scanning, and
@@ -1697,6 +1714,10 @@ class InferenceEngine:
         row_list = self.alloc.table_row(slot)
         st.pages_held = len(row_list)
         t0 = time.perf_counter_ns()
+        # the span that caused the program launch; what follows the
+        # dispatch in this function (counters, gauges) stays in this phase
+        self._clock.mark("dispatch_prefill", request=req.id,
+                         prompt_tokens=len(ids), cached_tokens=cached)
         with self.dispatch_lock:
             # emit AFTER the dispatch succeeds: a record for a program the
             # liaison never actually issued would make followers replay a
@@ -2012,6 +2033,7 @@ class InferenceEngine:
             prompt_eval_count=st.prompt_len,
             cached_tokens=st.cached_tokens,
             prompt_eval_duration_ns=st.t_prefill_ns,
+            admit_wait_ns=st.admit_wait_ns,
             eval_count=len(st.generated),
             eval_duration_ns=(now - st.t_first_decode) if st.t_first_decode else 0,
             load_duration_ns=self.load_duration_ns,
@@ -2071,18 +2093,12 @@ class InferenceEngine:
                                   gen=self._gen, k=k,
                                   slots=len(self._slots),
                                   pending=len(self._pending))
-            t0 = time.perf_counter()
             (out, self.tokens, self.cache, self.counts, self.window,
              self.wlen, self.sampling) = self._decode_block_fn(
                 self.params, self.cache, self.tokens, self.active,
                 self.counts, self.window, self.wlen, self.sampling, k=k,
             )
-            now = time.perf_counter()
-            # dispatch-to-device: trace/lower/enqueue wall time — the call
-            # returns before the device finishes; a spike here is usually
-            # a recompile (pairs with gridllm_recompiles_total)
-            DISPATCH_SECONDS.observe(now - t0, model=self.cfg.name)
-            self._inflight.append((self._gen, out, k, now))
+            self._inflight.append((self._gen, out, k))
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({"op": "block", "k": k})
 
@@ -2094,7 +2110,6 @@ class InferenceEngine:
         decode-token block joins _inflight with its own generation —
         fetched later by the normal block drains, no host sync here."""
         self._gen += 1
-        t0 = time.perf_counter()
         (out, self.cache, self.counts, self.window, self.wlen, self.tokens,
          self.active, self.sampling) = self._mixed_chunk_fn(
             self.params, padded, self.cache, self.counts, self.window,
@@ -2102,21 +2117,20 @@ class InferenceEngine:
             jnp.int32(start), jnp.int32(length), jnp.int32(slot), row,
             jnp.bool_(is_final), embeds=embeds,
         )
-        now = time.perf_counter()
-        DISPATCH_SECONDS.observe(now - t0, model=self.cfg.name)
-        self._inflight.append((self._gen, out, 1, now))
+        self._inflight.append((self._gen, out, 1))
 
     def _fetch_oldest(self) -> None:
         """Fetch + ingest the oldest in-flight decode/mixed block — the
         ONE copy of the block fetch protocol: step()'s sync path,
         _pump_once's pipelined pop, and the admission-block drains all go
-        through here. Observes device pace and per-fused-step duration
-        (fetch+ingest wall over the block's step count)."""
-        gen, out, blk, t_disp = self._inflight.popleft()
+        through here. Observes per-fused-step duration (fetch+ingest
+        wall over the block's step count)."""
+        gen, out, blk = self._inflight.popleft()
         t0 = time.perf_counter()
+        self._clock.mark("fetch")
         # the ONE declared block-fetch sync point (host-sync-discipline)
         raw = np.asarray(jax.device_get(out))  # sync-ok
-        self._observe_device_step(t_disp, blk)
+        self._mark_ingest()
         self._ingest_block(gen, raw)
         _STEP_DURATION.observe(
             (time.perf_counter() - t0) / max(blk, 1), model=self.cfg.name)
@@ -2134,7 +2148,6 @@ class InferenceEngine:
                                   slots=len(self._slots),
                                   drafted=int(dlen.sum()),
                                   pending=len(self._pending))
-            t0 = time.perf_counter()
             (block, n_emit, self.tokens, self.cache, self.counts,
              self.window, self.wlen, self.sampling) = self._verify_fn(
                 self.params, self.cache, self.tokens, self.active,
@@ -2142,9 +2155,7 @@ class InferenceEngine:
                 jnp.asarray(drafts, jnp.int32), jnp.asarray(dlen, jnp.int32),
                 k1=int(drafts.shape[1]) + 1,  # from the record: follower
             )                                 # replay may differ from env K
-            now = time.perf_counter()
-            DISPATCH_SECONDS.observe(now - t0, model=self.cfg.name)
-            self._inflight.append((self._gen, (block, n_emit), 1, now))
+            self._inflight.append((self._gen, (block, n_emit), 1))
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({"op": "verify", "drafts": drafts.tolist(),
                                 "dlen": dlen.tolist()})
@@ -2168,16 +2179,13 @@ class InferenceEngine:
                                   drafted=int(valid[:, 1:].sum()),
                                   pending=len(self._pending))
             fn = self._tree_fn_for(parents)
-            t0 = time.perf_counter()
             (block, n_emit, self.tokens, self.cache, self.counts,
              self.window, self.wlen, self.sampling) = fn(
                 self.params, self.cache, self.tokens, self.active,
                 self.counts, self.window, self.wlen, self.sampling,
                 jnp.asarray(drafts, jnp.int32), jnp.asarray(valid, bool),
             )
-            now = time.perf_counter()
-            DISPATCH_SECONDS.observe(now - t0, model=self.cfg.name)
-            self._inflight.append((self._gen, (block, n_emit), 1, now))
+            self._inflight.append((self._gen, (block, n_emit), 1))
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({
                     "op": "verify_tree", "drafts": drafts.tolist(),
@@ -2237,12 +2245,14 @@ class InferenceEngine:
             # accounting so acceptance rates compare across drafters
             # (siblings are a free second chance, not extra proposals)
             dlen[slot] = depth
+        self._mark_launch()
         self._dispatch_verify_tree(drafts, valid, parents)
-        gen, (block, n_emit), _blk, t_disp = self._inflight.popleft()
+        gen, (block, n_emit), _blk = self._inflight.popleft()
         t0 = time.perf_counter()
+        self._clock.mark("fetch")
         raw = np.asarray(jax.device_get(block))  # sync-ok (see _step_spec)
         n_np = np.asarray(jax.device_get(n_emit))  # sync-ok
-        self._observe_device_step(t_disp, 1)
+        self._mark_ingest()
         self._ingest_spec(gen, raw, n_np, dlen)
         _STEP_DURATION.observe(time.perf_counter() - t0, model=self.cfg.name)
 
@@ -2258,6 +2268,7 @@ class InferenceEngine:
             # be host-visible before drafting (and the verify fetch below
             # assumes the queue head is its own dispatch)
             self._fetch_oldest()
+        self._clock.mark("draft")
         k = self._spec_k
         if getattr(self._drafter, "tree", False):
             self._step_spec_tree(k)
@@ -2275,14 +2286,16 @@ class InferenceEngine:
             if prop:
                 dlen[slot] = len(prop)
                 drafts[slot, :len(prop)] = prop
+        self._mark_launch()
         self._dispatch_verify(drafts, dlen)
-        gen, (block, n_emit), _blk, t_disp = self._inflight.popleft()
+        gen, (block, n_emit), _blk = self._inflight.popleft()
         t0 = time.perf_counter()
+        self._clock.mark("fetch")
         # the spec path's declared fetch: serial by construction (drafts
         # depend on this step's tokens), so the sync is the design
         raw = np.asarray(jax.device_get(block))  # sync-ok
         n_np = np.asarray(jax.device_get(n_emit))  # sync-ok
-        self._observe_device_step(t_disp, 1)
+        self._mark_ingest()
         self._ingest_spec(gen, raw, n_np, dlen)
         _STEP_DURATION.observe(time.perf_counter() - t0, model=self.cfg.name)
 
@@ -2324,6 +2337,7 @@ class InferenceEngine:
                     break  # finished mid-span; later rows are post-stop junk
         if ingested:
             _TOKENS_TOTAL.inc(ingested, model=self.cfg.name, kind="decode")
+        self._clock.annotate(tokens=ingested)
         m = self.cfg.name
         dk = getattr(self._drafter, "kind", "ngram") or "ngram"
         if proposed_t:
@@ -2369,6 +2383,7 @@ class InferenceEngine:
                     break  # finished mid-block; later rows are post-EOS junk
         if ingested:
             _TOKENS_TOTAL.inc(ingested, model=self.cfg.name, kind="decode")
+        self._clock.annotate(tokens=ingested)
 
     def _drain_ctl(self) -> None:
         while self._ctl:
@@ -2384,44 +2399,49 @@ class InferenceEngine:
         semantics (block size 1, no pipelining) — the test/sync driver.
         The serving path is the runner thread (start()/stop()), which uses
         fused blocks and pipelined dispatch. Returns False when idle."""
-        self._drain_ctl()
-        while self._try_admit():
-            pass
-        while self._inflight:
-            # ragged mixed admission steps enqueue [2, S] blocks; sync
-            # semantics = nothing left in flight before this step's own
-            # dispatch
+        try:
+            self._clock.mark("ctl")
+            self._drain_ctl()
+            while self._try_admit():
+                pass
+            while self._inflight:
+                # ragged mixed admission steps enqueue [2, S] blocks; sync
+                # semantics = nothing left in flight before this step's
+                # own dispatch
+                self._fetch_oldest()
+            if not self._slots:
+                return bool(self._pending)
+            if self._spec_k:
+                self._step_spec()
+                return True
+            self._mark_launch()
+            self._dispatch_block(1)
             self._fetch_oldest()
-        if not self._slots:
-            self._t_prev_fetch = None
-            return bool(self._pending)
-        if self._spec_k:
-            self._step_spec()
             return True
-        self._dispatch_block(1)
-        self._fetch_oldest()
-        return True
+        finally:
+            # the time between two calls of a synchronous driver is not
+            # the engine's: close the open phase and flush
+            self._clock.pause()
 
-    def _observe_device_step(self, t_disp: float, k: int) -> None:
-        """Per-step on-device time estimate, pipelined-dispatch aware:
-        with another block already in flight when this fetch completed,
-        the device never idled between blocks, so consecutive fetch
-        completions pace at the device's block time; with the pipeline
-        drained, dispatch→fetch wall is the honest (queue-inclusive)
-        upper bound. Called right after the device_get returns."""
-        now = time.perf_counter()
-        prev = self._t_prev_fetch
-        self._t_prev_fetch = now
-        if prev is not None and self._inflight:
-            dev = (now - prev) / max(k, 1)
-        else:
-            dev = (now - t_disp) / max(k, 1)
-        DEVICE_STEP_SECONDS.observe(dev, model=self.cfg.name)
-        # usage attribution (ISSUE 16): split the block's device time
-        # evenly across the slots that shared the batch (engine thread
-        # owns _slots — no lock needed)
+    def _mark_launch(self) -> None:
+        """Enter ``dispatch_verify`` for one verify / decode-block launch
+        (the runner's call sites, not the dispatch functions: a multi-host
+        follower replays those off the runner). Counts the context the
+        launch's ragged kernel reads: Σ over live slots of context length."""
+        ctx = sum(len(st.ids) for st in self._slots.values())
+        VERIFY_CTX_TOKENS_TOTAL.inc(ctx, model=self.cfg.name)
+        self._clock.mark("dispatch_verify", gen=self._gen + 1,
+                         slots=len(self._slots), ctx_tokens=ctx)
+
+    def _mark_ingest(self) -> None:
+        """Leave ``fetch`` for ``ingest``, right after the device_get
+        returned. The fetch's measured time is the runner blocked on the
+        device: usage attribution (ISSUE 16) splits it evenly across the
+        slots that shared the batch (engine thread owns _slots — no lock
+        needed)."""
+        waited = self._clock.mark("ingest")
         if self._slots:
-            share = dev * max(k, 1) / len(self._slots)
+            share = waited / len(self._slots)
             for st in self._slots.values():
                 st.device_s += share
 
@@ -2436,7 +2456,8 @@ class InferenceEngine:
             return
         self._runner_stop.clear()
         self._runner = threading.Thread(
-            target=self._run, name=f"engine-{self.cfg.name}", daemon=True
+            target=self._runner_main, name=f"engine-{self.cfg.name}",
+            daemon=True
         )
         self._runner.start()
 
@@ -2456,15 +2477,31 @@ class InferenceEngine:
     def running(self) -> bool:
         return self._runner is not None and self._runner.is_alive()
 
+    def _runner_main(self) -> None:
+        """The runner thread: _run, on the phase clock from entry to exit."""
+        t_run = time.perf_counter()
+        self._clock.mark("idle_wait")
+        try:
+            self._run()
+        finally:
+            self._clock.pause()
+            self.runner_wall_s += time.perf_counter() - t_run
+
     def _run(self) -> None:
         fail_streak = 0
         while not self._runner_stop.is_set():
             with self._work:
                 while not (self._pending or self._slots or self._ctl
                            or self._runner_stop.is_set()):
+                    # one observation per stretch of waiting, flushed
+                    # while idle so an idle worker's series keeps pace
+                    self._clock.mark("idle_wait")
+                    self._clock.flush()
                     self._work.wait(timeout=0.5)
             if self._runner_stop.is_set():
                 break
+            # once an iteration, never per mark: what the last one closed
+            self._clock.flush()
             try:
                 self._pump_once()
                 fail_streak = 0
@@ -2477,7 +2514,6 @@ class InferenceEngine:
                                   model=self.cfg.name, error=str(e)[:1000],
                                   streak=fail_streak + 1)
                 self._inflight.clear()
-                self._t_prev_fetch = None
                 self.abort_all(f"engine failure: {e}")
                 try:
                     self.reset_device_state()
@@ -2504,6 +2540,7 @@ class InferenceEngine:
         # runner's step-failure recovery path — abort in-flight requests,
         # rebuild device state, keep serving
         faults.inject("engine.step")
+        self._clock.mark("ctl")
         self._drain_ctl()
         # idle engine admits everything (first tokens as early as possible);
         # a busy engine bounds admission so running streams never stall for
@@ -2515,45 +2552,24 @@ class InferenceEngine:
         admitted = 0
         while admitted < budget and self._try_admit():
             admitted += 1
-        if admitted:
-            # a prefill ran between decode blocks: the next fetch delta
-            # would span it and book prefill wall time as device pace —
-            # fall back to dispatch→fetch for the next block instead
-            self._t_prev_fetch = None
         if not self._slots:
-            self._t_prev_fetch = None
-            self._t_ingest_done = None
             return
         if self._spec_k:
             # speculative serving: one verify block per iteration, fetched
             # immediately (the next step's drafts depend on this step's
             # tokens, so the block pipeline can't apply — acceptance > 1
             # token/step is what pays the un-hidden fetch back)
-            if self._t_ingest_done is not None:
-                HOST_SCHED_SECONDS.observe(
-                    time.perf_counter() - self._t_ingest_done,
-                    model=self.cfg.name)
             self._step_spec()
-            self._t_ingest_done = time.perf_counter()
             return
         k = self.config.decode_block
-        # host-scheduling gap since the previous block's ingest finished
-        # — control drain, admission (incl. prefill dispatch), stream
-        # callbacks — amortized per fused step so it compares 1:1 with
-        # gridllm_engine_device_step_seconds (the host-stall alert and
-        # dashboard plot them against each other)
-        if self._t_ingest_done is not None:
-            HOST_SCHED_SECONDS.observe(
-                (time.perf_counter() - self._t_ingest_done) / max(k, 1),
-                model=self.cfg.name)
         while len(self._inflight) < max(1, self.config.pipeline_depth):
+            self._mark_launch()
             self._dispatch_block(k)
         # fetch+ingest wall time per fused step (observed inside
         # _fetch_oldest); in steady state the fetch of block N overlaps
         # block N+1's compute, so this is the honest per-step pace the
         # pipeline sustains
         self._fetch_oldest()
-        self._t_ingest_done = time.perf_counter()
 
     # ---------------------------------------------------------- public API
 
@@ -3175,6 +3191,10 @@ class InferenceEngine:
                 **self.spec_stats,
             } if self._spec_k else None,
             "jit": self.perf.state(),
+            # the runner's wall time so far, phase by phase (a wedged
+            # runner shows in the dump as one phase that stopped growing)
+            "runnerPhaseSeconds": {p: round(v, 3) for p, v
+                                   in self._clock.seconds.items()},
         }
 
     def memory_arrays(self) -> dict[str, Any]:

@@ -325,9 +325,10 @@ def build_routes(scheduler: JobScheduler,
 
 
 async def start_profile_capture(request: web.Request) -> web.Response:
-    """``POST /admin/profile?seconds=N``: start a background jax.profiler
-    capture; the response carries the artifact path so the caller can
-    fetch/open it after `seconds`. Validation, the busy conflict, and
+    """``POST /admin/profile?seconds=N[&python=1]``: start a background
+    jax.profiler capture (Python tracer off unless ``python=1``); the
+    response carries the artifact path so the caller can fetch/open it
+    after `seconds`. Validation, the busy conflict, and
     the engine-less-process refusal live in obs/perf.py — the worker
     health port serves the same helper without importing gateway code."""
     from gridllm_tpu.obs.perf import handle_profile_request
@@ -336,5 +337,6 @@ async def start_profile_capture(request: web.Request) -> web.Response:
     # start_trace — blocking filesystem/profiler work that must not
     # stall the event loop serving streams and health checks
     status, payload = await asyncio.to_thread(
-        handle_profile_request, request.query.get("seconds"))
+        handle_profile_request, request.query.get("seconds"),
+        request.query.get("python"))
     return web.json_response(payload, status=status)

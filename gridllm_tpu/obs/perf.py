@@ -24,15 +24,18 @@ this module instruments the three dominant TPU-side reasons they don't:
    collector, with headroom/limit gauges where the backend reports
    allocator stats (TPU; CPU reports live bytes only).
 3. **On-demand profiler capture** (:class:`ProfilerCapture`) —
-   ``POST /admin/profile?seconds=N`` starts a ``jax.profiler`` trace into
+   ``POST /admin/profile?seconds=N`` starts a ``jax.profiler`` trace
+   (Python tracer off unless ``&python=1``: safe under load) into
    a bounded artifact directory (``GRIDLLM_PROFILE_DIR``, oldest captures
    pruned past ``GRIDLLM_PROFILE_KEEP``) and returns the path; the hang
    watchdog auto-triggers a short capture on decode-step hangs so the
    trace covers the wedge, not its aftermath.
 
-The step-time decomposition histograms (host scheduling vs dispatch vs
-on-device step) are registered here and driven by the engine's runner
-loop — see engine/engine.py.
+4. **Phase clock** (:class:`PhaseClock`) — the engine runner's wall time
+   partitioned into named phases (``gridllm_engine_phase_seconds``), and
+   the same boundaries as ``jax.profiler.TraceAnnotation`` spans while a
+   capture runs, so a trace shows what the host did in every device gap.
+   Driven by the engine's runner loop — see engine/engine.py.
 
 jax is imported lazily (function-level): importing this module — and
 therefore ``gridllm_tpu.obs`` — must stay cheap for control-plane-only
@@ -41,6 +44,7 @@ processes. Pure stdlib otherwise.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 import shutil
@@ -82,29 +86,41 @@ STEP_PHASE_BUCKETS = (
     0.0002, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 15.0, 60.0,
 )
-HOST_SCHED_SECONDS = _OBS.histogram(
-    "gridllm_engine_host_sched_seconds",
-    "Host-side gap between finishing one decode block's ingest and "
-    "dispatching the next (admission, tokenize, stream callbacks, control "
-    "drain), AMORTIZED PER FUSED STEP so it compares 1:1 with "
-    "gridllm_engine_device_step_seconds, by model. Growth here is a host "
-    "stall, not a device problem.",
+# Every instant of the runner thread is in exactly one of these (no
+# "other"): the clock runs mark to mark. What each covers is in the
+# histogram's help text below and in PERF.md section 3.
+PHASES = ("idle_wait", "ctl", "admit", "dispatch_prefill", "draft",
+          "dispatch_verify", "fetch", "ingest")
+PHASE_SECONDS = _OBS.histogram(
+    "gridllm_engine_phase_seconds",
+    "The engine runner thread's wall time, partitioned: one observation "
+    "per contiguous stretch in a phase (several stretches of one phase "
+    "inside one runner iteration are observed at their mean). idle_wait = "
+    "waiting for work; ctl = cancel/suspend drain and the loop's own "
+    "bookkeeping; admit = a popped request up to its prefill dispatch "
+    "(tokenize, prefix lookup, page allocation; _count = admissions); "
+    "dispatch_prefill = the prefill / mixed-chunk jitted calls returning; "
+    "draft = speculative drafting; dispatch_verify = the verify / decode "
+    "block jitted call returning (_count = launches); fetch = blocked on "
+    "the device for a block's tokens; ingest = stop checks, detokenize, "
+    "stream callbacks. Sum over phases = the runner's wall time; host "
+    "phases growing against fetch is a host stall, not a device problem.",
+    ("model", "phase"), buckets=STEP_PHASE_BUCKETS,
+)
+ADMIT_WAIT_SECONDS = _OBS.histogram(
+    "gridllm_engine_admit_wait_seconds",
+    "submit() to popped for admission: a request's wait in the engine's "
+    "pending queue (behind admit_per_block, a full batch or an exhausted "
+    "pool), by model.",
     ("model",), buckets=STEP_PHASE_BUCKETS,
 )
-DISPATCH_SECONDS = _OBS.histogram(
-    "gridllm_engine_dispatch_seconds",
-    "Wall time for a fused decode block's jitted call to RETURN (trace + "
-    "lower + enqueue; the device keeps computing after). A spike here "
-    "usually means a recompile — pair with gridllm_recompiles_total.",
-    ("model",), buckets=STEP_PHASE_BUCKETS,
-)
-DEVICE_STEP_SECONDS = _OBS.histogram(
-    "gridllm_engine_device_step_seconds",
-    "Estimated on-device time per fused decode step, by model. With the "
-    "dispatch pipeline saturated this is the delta between consecutive "
-    "block fetch completions (device-bound pace); otherwise dispatch-to-"
-    "fetch wall time (upper bound including queue wait).",
-    ("model",), buckets=STEP_PHASE_BUCKETS,
+VERIFY_CTX_TOKENS_TOTAL = _OBS.counter(
+    "gridllm_engine_verify_ctx_tokens_total",
+    "Sum over live slots of context length at each verify / decode block "
+    "dispatch — the KV positions the ragged kernel reads. Over "
+    "gridllm_engine_phase_seconds_count{phase=\"dispatch_verify\"} it is the "
+    "mean live context a launch.",
+    ("model",),
 )
 
 # -- device-memory gauges ----------------------------------------------------
@@ -200,7 +216,7 @@ class JitProbe:
         # identity-memo for the first positional arg: every engine entry
         # point passes the (large, shape-stable) params tree first, and
         # re-flattening its hundreds of leaves per decode-block dispatch
-        # would tax the hot path and inflate DISPATCH_SECONDS. One
+        # would tax the hot path (the dispatch_verify phase). One
         # (obj, sig) tuple so cross-thread reads are never torn; the
         # strong ref makes the `is` check immune to id reuse.
         self._memo: tuple[Any, tuple] | None = None
@@ -555,6 +571,17 @@ _OBS.add_collector("perf.device_memory", _memory_collector)
 # ---------------------------------------------------------------------------
 
 
+def _tree_bytes(path: str) -> int:
+    total = 0
+    for root, _dirs, files in os.walk(path):
+        for name in files:
+            try:
+                total += os.path.getsize(os.path.join(root, name))
+            except OSError:
+                pass
+    return total
+
+
 class CaptureBusy(RuntimeError):
     """A profiler capture is already running (jax allows one trace at a
     time per process)."""
@@ -580,6 +607,10 @@ class ProfilerCapture:
         self._keep = keep
         self._lock = threading.Lock()
         self._active: dict[str, Any] | None = None
+        # True from start_trace to stop_trace: the one plain attribute the
+        # hot paths (PhaseClock.mark, capture_span) read to decide whether
+        # a TraceAnnotation is worth constructing
+        self.tracing = False
         self.captures: list[dict[str, Any]] = []  # bounded history
 
     @property
@@ -620,9 +651,19 @@ class ProfilerCapture:
         for stale in entries[:max(0, len(entries) - self.keep)]:
             shutil.rmtree(os.path.join(base, stale), ignore_errors=True)
 
-    def capture(self, seconds: float, reason: str = "on_demand") -> dict[str, Any]:
-        """Start a capture; returns {path, seconds, reason, startedAt}.
-        Raises :class:`CaptureBusy` when one is already running."""
+    def capture(self, seconds: float, reason: str = "on_demand",
+                python: bool = False) -> dict[str, Any]:
+        """Start a capture; returns {path, seconds, reason, python,
+        startedAt}. Raises :class:`CaptureBusy` when one is already
+        running.
+
+        The Python tracer is OFF unless ``python=True``: with it on, every
+        Python call of every thread is an event (970 k in 5 s on a serving
+        worker, ``stop_trace`` 3.4 s) and requests slowed 5-15x while it
+        ran (PERF.md, PR 23 finding 6). The host tracer stays on, so the
+        trace still holds the runtime's own spans and this module's
+        ``gridllm.*`` annotations beside the device's lines. A wedge is
+        read from its Python stack: the hang watchdog asks for it."""
         seconds = min(max(float(seconds), 0.05), self.MAX_SECONDS)
         safe_reason = "".join(
             c if c.isalnum() or c in "-_" else "-" for c in reason)[:48]
@@ -636,10 +677,14 @@ class ProfilerCapture:
 
             os.makedirs(path, exist_ok=True)
             self._prune()
-            jax.profiler.start_trace(path)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 1 if python else 0
+            options.host_tracer_level = 2
+            jax.profiler.start_trace(path, profiler_options=options)
             info = {"path": path, "seconds": seconds, "reason": reason,
-                    "startedAt": time.time()}
+                    "python": bool(python), "startedAt": time.time()}
             self._active = info
+            self.tracing = True
         default_flight_recorder().record("engine", "profile_capture",
                                          path=path, seconds=seconds,
                                          reason=reason)
@@ -665,6 +710,8 @@ class ProfilerCapture:
             if info is None or info.get("stopping"):
                 return None  # no capture, or another thread owns the flush
             info["stopping"] = True
+            self.tracing = False
+        t0 = time.perf_counter()
         try:
             import jax
 
@@ -675,8 +722,15 @@ class ProfilerCapture:
             self._active = None
             info.pop("stopping", None)
             info["endedAt"] = time.time()
+            # what the capture cost to write out (the flush runs on the
+            # timer thread, beside the serving threads)
+            info["stopTraceS"] = round(time.perf_counter() - t0, 3)
             self.captures.append(dict(info))
             del self.captures[:-16]
+        log.info("profiler capture written", path=info["path"],
+                 reason=info["reason"], python=info["python"],
+                 stopTraceS=info["stopTraceS"], bytes=_tree_bytes(info["path"]),
+                 error=info.get("error"))
         return dict(info)
 
 
@@ -688,9 +742,103 @@ def default_profiler() -> ProfilerCapture:
     return _PROFILER
 
 
-def handle_profile_request(seconds_raw: str | None) -> tuple[int, dict[str, Any]]:
-    """Transport-agnostic body of ``POST /admin/profile?seconds=N``:
-    (http_status, json_payload). Shared by the gateway admin surface and
+def capture_span(name: str, **meta: Any):
+    """A ``jax.profiler.TraceAnnotation`` while the process-global capture
+    runs, else a no-op context: for code off the runner thread that a
+    trace should name (the worker's ``/metrics`` render). One attribute
+    read when nothing is being captured."""
+    if not _PROFILER.tracing:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **meta)
+
+
+class PhaseClock:
+    """One clock for the engine's runner thread: its wall time partitioned
+    into :data:`PHASES`, mark to mark.
+
+    ``mark(phase)`` closes the phase the thread was in and opens `phase`:
+    one ``perf_counter``, one float add into a local dict. ``flush()``
+    (once a runner iteration, never per mark) moves what was closed into
+    ``gridllm_engine_phase_seconds{model,phase}``. ``pause()`` closes the
+    open phase without opening another (the runner stopping, or the end of
+    a synchronous ``step()``): time until the next mark is nobody's.
+
+    While the profiler captures, each phase is also entered as a
+    ``TraceAnnotation("gridllm.<phase>", **meta)``, so the ``.xplane.pb``
+    holds the runner's phases on the same clock as the device's ``XLA
+    Ops`` line; with no capture no annotation is constructed. Owned by one
+    thread: no lock."""
+
+    def __init__(self, model: str, profiler: ProfilerCapture | None = None):
+        self.model = model
+        self._profiler = profiler or _PROFILER
+        self._phase: str | None = None
+        self._t = 0.0
+        self._span: Any = None
+        # closed and not yet flushed: phase -> [seconds, stretches]
+        self._acc: dict[str, list] = {p: [0.0, 0] for p in PHASES}
+        # cumulative, flushed: what tests and batch_state read
+        self.seconds: dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.counts: dict[str, int] = dict.fromkeys(PHASES, 0)
+
+    def _close(self, now: float) -> float:
+        span = self._span
+        if span is not None:
+            self._span = None
+            span.__exit__(None, None, None)
+        if self._phase is None:
+            return 0.0
+        dt = now - self._t
+        cell = self._acc[self._phase]
+        cell[0] += dt
+        cell[1] += 1
+        return dt
+
+    def mark(self, phase: str, **meta: Any) -> float:
+        """Enter `phase`; returns the seconds the closed phase lasted."""
+        now = time.perf_counter()
+        dt = self._close(now)
+        self._phase, self._t = phase, now
+        if self._profiler.tracing:
+            import jax
+
+            self._span = jax.profiler.TraceAnnotation(
+                "gridllm." + phase, **meta)
+            self._span.__enter__()
+        return dt
+
+    def annotate(self, **meta: Any) -> None:
+        """Metadata known only once the phase is under way (the tokens an
+        ingest emitted). Free when nothing is being captured."""
+        if self._span is not None:
+            self._span.set_metadata(**meta)
+
+    def pause(self) -> None:
+        self._close(time.perf_counter())
+        self._phase = None
+        self.flush()
+
+    def flush(self) -> None:
+        for phase, cell in self._acc.items():
+            secs, n = cell
+            if not n:
+                continue
+            cell[0], cell[1] = 0.0, 0
+            self.seconds[phase] += secs
+            self.counts[phase] += n
+            for _ in range(n):
+                PHASE_SECONDS.observe(secs / n, model=self.model, phase=phase)
+
+
+def handle_profile_request(seconds_raw: str | None,
+                           python_raw: str | None = None,
+                           ) -> tuple[int, dict[str, Any]]:
+    """Transport-agnostic body of ``POST /admin/profile?seconds=N[&python=1]``:
+    (http_status, json_payload). ``python=1`` turns the Python tracer on
+    (slows the process severalfold while it runs: see
+    :meth:`ProfilerCapture.capture`). Shared by the gateway admin surface and
     the worker health port so neither re-implements validation, the
     busy conflict, or the no-jax guard (which refuses rather than
     synchronously initializing a backend in a control-plane process).
@@ -711,7 +859,9 @@ def handle_profile_request(seconds_raw: str | None) -> tuple[int, dict[str, Any]
         return 400, {"error": f"seconds must be in "
                               f"(0, {ProfilerCapture.MAX_SECONDS:g}]",
                      "code": "BAD_REQUEST"}
+    python = (python_raw or "").strip().lower() in ("1", "true", "yes", "on")
     try:
-        return 200, default_profiler().capture(seconds, reason="on_demand")
+        return 200, default_profiler().capture(seconds, reason="on_demand",
+                                               python=python)
     except CaptureBusy as e:
         return 409, {"error": str(e), "code": "CAPTURE_BUSY"}

@@ -247,8 +247,10 @@ class HangWatchdog:
             # is the engine-side capture.
             return None
         try:
+            # python=True: a wedge is read from its Python stack
             return default_profiler().capture(seconds,
-                                              reason=f"hang-{phase}")
+                                              reason=f"hang-{phase}",
+                                              python=True)
         except Exception as e:  # noqa: BLE001
             log.warning("hang profiler capture skipped", error=str(e))
             return None

@@ -599,6 +599,16 @@ class JobScheduler(EventEmitter):
         spans = self.tracer.export(request_id)
         if not spans:
             return
+        names = {s.get("name") for s in spans}
+        if "scheduler.dispatch" in names and "worker.execute" not in names:
+            # dispatched, but the worker's half of the trace is still on
+            # the bus: a worker publishes it AFTER its result, so the seal
+            # path always gets here first, and a decomposition of the
+            # gateway's spans alone books the whole prefill and decode as
+            # "dispatch" (seen on the chip, PR 24: mean dispatch = mean
+            # request duration). The ingest of the worker's half calls
+            # again; a request whose worker never publishes is not observed
+            return
         seg = critical_path(spans)
         if seg is None:
             return

@@ -159,8 +159,14 @@ def build_health_app(service: WorkerService) -> web.Application:
         # the process-global registry carries every worker-plane series:
         # engine tokens/steps/KV pool, kernel-dispatch paths, bus, jobs
         from gridllm_tpu.obs import PROMETHEUS_CONTENT_TYPE, default_registry
+        from gridllm_tpu.obs.perf import capture_span
 
-        return web.Response(text=default_registry().render(),
+        # the render walks jax.live_arrays() on this event loop (the
+        # device-memory collector): a capture names it, so a stream frame
+        # that waited behind a scrape reads as such in the trace
+        with capture_span("gridllm.metrics_scrape"):
+            text = default_registry().render()
+        return web.Response(text=text,
                             headers={"Content-Type": PROMETHEUS_CONTENT_TYPE})
 
     async def dump(_):
@@ -198,7 +204,8 @@ def build_health_app(service: WorkerService) -> web.Application:
         # to_thread: capture start does blocking dir-prune/start_trace
         # work — the health port must keep answering liveness probes
         status, payload = await asyncio.to_thread(
-            handle_profile_request, request.query.get("seconds"))
+            handle_profile_request, request.query.get("seconds"),
+            request.query.get("python"))
         return web.json_response(payload, status=status)
 
     async def drain(request):

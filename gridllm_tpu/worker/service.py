@@ -1434,14 +1434,17 @@ class WorkerService(EventEmitter):
                 # engine-stage spans: submit→first-token is the honest
                 # prefill wait (queue + compile + prefill dispatch),
                 # first-token→done the decode stretch; engine-measured ns
-                # ride in meta for exact attribution
+                # ride in meta for exact attribution: admitWaitNs is the
+                # wait in the engine's pending queue (submit → admission),
+                # engineNs admission → first token
                 now = time.time()
                 tf = t_first if t_first is not None else now
                 self.tracer.record(
                     req.id, "engine.prefill", t_submit, tf,
                     promptTokens=res.prompt_eval_count,
                     cachedTokens=res.cached_tokens,
-                    engineNs=res.prompt_eval_duration_ns)
+                    engineNs=res.prompt_eval_duration_ns,
+                    admitWaitNs=res.admit_wait_ns)
                 if res.eval_count:
                     # specAccepted/specProposed: this request's speculative
                     # draft outcomes (ISSUE 5) — 0/0 with speculation off;

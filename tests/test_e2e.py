@@ -209,6 +209,77 @@ async def test_images_travel_to_engine_and_reject_loudly(tiny_engine):
         await _teardown(registry, scheduler, worker, client, bus)
 
 
+async def test_engine_queue_wait_lands_in_the_prefill_spans_meta(tiny_engine):
+    """ISSUE 24: inside the worker a request's wait is no longer one lump.
+    A request that met a stopped runner waited in the engine's pending
+    queue; that wait is in `engine.prefill`'s meta beside engineNs."""
+    bus, registry, scheduler, worker, client = await _stack(tiny_engine)
+    try:
+        tiny_engine.stop()
+
+        async def restart():
+            await asyncio.sleep(0.3)
+            tiny_engine.start()
+
+        wake = asyncio.create_task(restart())
+        resp = await client.post("/ollama/api/generate", json={
+            "model": MODEL, "prompt": "wait for it", "stream": False,
+            "options": {"temperature": 0, "num_predict": 4}})
+        assert resp.status == 200 and (await resp.json())["done"]
+        await wake
+        await bus.flush()
+        body = await (await client.get(
+            f"/admin/trace/{scheduler.tracer.ids()[-1]}")).json()
+        span = next(s for s in body["spans"] if s["name"] == "engine.prefill")
+        waited = span["meta"]["admitWaitNs"]
+        # engineNs starts at admission: the queue wait lies before it, and
+        # both lie inside the span (worker submit to first token)
+        assert waited >= 0.2e9
+        assert (waited + span["meta"]["engineNs"]) / 1e6 <= span["durationMs"] + 5.0
+    finally:
+        tiny_engine.start()
+        await _teardown(registry, scheduler, worker, client, bus)
+
+
+async def test_critical_path_waits_for_the_workers_half_of_the_trace(tiny_engine):
+    """A worker publishes its spans after its result, so the gateway seals
+    a request's root span before the engine spans land. The decomposition
+    must wait for them: from the gateway's spans alone the whole prefill
+    and decode read as `dispatch` (PR 24, seen on the chip)."""
+    bus, registry, scheduler, worker, client = await _stack(tiny_engine)
+    try:
+        cp = scheduler._critical_path
+        publish = worker._publish_trace
+
+        async def late(request_id):     # as over a real bus: result first
+            await asyncio.sleep(0.2)
+            await publish(request_id)
+
+        worker._publish_trace = late
+        resp = await client.post("/ollama/api/generate", json={
+            "model": MODEL, "prompt": "decompose me", "stream": False,
+            "options": {"temperature": 0, "num_predict": 24}})
+        assert resp.status == 200 and (await resp.json())["done"]
+        assert cp.count(segment="dispatch") == 0    # sealed, not yet decomposed
+        for _ in range(100):
+            if cp.count(segment="dispatch"):
+                break
+            await asyncio.sleep(0.02)
+        await bus.flush()
+        assert cp.count(segment="dispatch") == 1
+        body = await (await client.get(
+            f"/admin/trace/{scheduler.tracer.ids()[-1]}")).json()
+        spans = {s["name"]: s for s in body["spans"]}
+        engine_ms = (spans["engine.prefill"]["durationMs"]
+                     + spans["engine.decode"]["durationMs"])
+        in_engine = (cp.sum(segment="prefill") + cp.sum(segment="decode_device")
+                     + cp.sum(segment="decode_host_stall"))
+        assert in_engine * 1e3 == pytest.approx(engine_ms, rel=0.05)
+        assert cp.sum(segment="dispatch") < in_engine
+    finally:
+        await _teardown(registry, scheduler, worker, client, bus)
+
+
 async def test_metrics_and_trace_through_real_engine(tiny_engine):
     """ISSUE 1 acceptance: after a request served by the REAL engine worker,
     /metrics carries engine token counters, KV page-pool gauges, and

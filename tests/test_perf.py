@@ -232,18 +232,22 @@ async def test_admin_memory_endpoint(engine):
 
 
 def test_step_decomposition_histograms_populate():
-    from gridllm_tpu.obs.perf import (
-        DEVICE_STEP_SECONDS,
-        DISPATCH_SECONDS,
-        HOST_SCHED_SECONDS,
-    )
+    """One series decomposes a step: gridllm_engine_phase_seconds. On the
+    pipelined block path a served request leaves launches, fetches and
+    ingests in equal number, time in each, and host phases that can be
+    held against the fetch wait (the host-stall alert's ratio)."""
+    from gridllm_tpu.obs.perf import PHASE_SECONDS
 
-    eng = InferenceEngine(EngineConfig(**TINY, decode_block=2,
-                                       pipeline_depth=2))
+    eng = InferenceEngine(EngineConfig(**TINY, spec_decode=False,
+                                       decode_block=2, pipeline_depth=2))
     model = "tiny-llama"
-    d0 = DISPATCH_SECONDS.count(model=model)
-    v0 = DEVICE_STEP_SECONDS.count(model=model)
-    h0 = HOST_SCHED_SECONDS.count(model=model)
+
+    def read(what):
+        return {p: getattr(PHASE_SECONDS, what)(model=model, phase=p)
+                for p in ("ctl", "admit", "dispatch_prefill",
+                          "dispatch_verify", "fetch", "ingest")}
+
+    n0, s0 = read("count"), read("sum")
     eng.start()
     try:
         eng.generate(GenerationRequest(id="dec", prompt="hello",
@@ -251,10 +255,14 @@ def test_step_decomposition_histograms_populate():
                                                 "num_predict": 12}))
     finally:
         eng.stop()
-    assert DISPATCH_SECONDS.count(model=model) > d0
-    assert DEVICE_STEP_SECONDS.count(model=model) > v0
-    # host-sched gap is recorded between consecutive runner iterations
-    assert HOST_SCHED_SECONDS.count(model=model) > h0
+    n = {p: v - n0[p] for p, v in read("count").items()}
+    s = {p: v - s0[p] for p, v in read("sum").items()}
+    assert n["admit"] == 1 and n["dispatch_prefill"] == 1
+    # 12 tokens at 2 a block, pipelined 2 deep: at least 6 launches, and
+    # every launch but those still in flight at the end was fetched
+    assert n["dispatch_verify"] >= 6
+    assert n["dispatch_verify"] - 2 <= n["fetch"] == n["ingest"]
+    assert all(v > 0 for v in s.values()), s
 
 
 # ---------------------------------------------------------------------------
