@@ -27,6 +27,19 @@ the line carries a ``# sync-ok`` waiver (the declared sync points):
 
 A ``# sync-ok`` on a line the rule would not flag is itself a finding
 (stale waivers rot into blanket permissions).
+
+The mirror image of a stray sync is a stray LAUNCH (ISSUE 25): an eager
+device program per call. ``jnp.int32(x)`` / ``jnp.bool_(x)`` /
+``jnp.asarray(x)`` / ``jnp.array(x)`` on a host value and
+``self.<device attr>.at[i].set(v)`` outside a jitted function each run a
+one-element program (several, for ``.at``) with the chip idle behind the
+Python and PJRT dispatch — forty of them were 28 ms of every admission.
+In the same loop functions, plus ``_finish`` and ``apply_plan_op`` (the
+other writers of per-slot device state), they are findings unless they
+sit inside a nested jitted ``def``: build host arguments as numpy at
+their final dtype and pass them straight to the jitted call, and write
+device rows inside a donated program. No waiver: there is no case for
+one in these functions.
 """
 
 from __future__ import annotations
@@ -39,11 +52,13 @@ from gridllm_tpu.analysis.core import Finding, Repo, dotted_name, rule
 RULE = "host-sync-discipline"
 ENGINE = "gridllm_tpu/engine/engine.py"
 _WAIVER = "# sync-ok"
-_LOOP_FN = re.compile(
-    r"^(step|_run|_pump_once|_step_spec|_fetch_oldest|_drain_ctl|"
-    r"_try_admit|_dispatch_\w+|_ingest\w*)$")
+_LOOP_NAMES = (r"step|_run|_pump_once|_step_spec|_fetch_oldest|_drain_ctl|"
+               r"_try_admit|_dispatch_\w+|_ingest\w*")
+_LOOP_FN = re.compile(rf"^({_LOOP_NAMES})$")
+_EAGER_FN = re.compile(rf"^({_LOOP_NAMES}|_finish|apply_plan_op)$")
 _DEVICE_ATTRS = {"tokens", "cache", "active", "counts", "window", "wlen",
                  "sampling"}
+_EAGER_CTORS = {"int32", "bool_", "asarray", "array"}
 
 
 def _reads_device_state(node: ast.AST) -> bool:
@@ -75,9 +90,42 @@ def _flag_line(node: ast.Call) -> str | None:
     return None
 
 
+def _is_jitted(fn: ast.AST) -> bool:
+    """A nested def whose decorators mention jit (``@jax.jit``,
+    ``@partial(jax.jit, donate_argnums=...)``): its body is traced."""
+    return isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)) and any(
+        dotted_name(sub).rsplit(".", 1)[-1] == "jit"
+        for dec in fn.decorator_list for sub in ast.walk(dec)
+        if isinstance(sub, (ast.Name, ast.Attribute)))
+
+
+def _eager_programs(fn: ast.AST):
+    """(lineno, message) for every eager device program in `fn`'s body,
+    nested jitted defs excluded."""
+    stack = list(ast.iter_child_nodes(fn))
+    while stack:
+        node = stack.pop()
+        if _is_jitted(node):
+            continue
+        stack.extend(ast.iter_child_nodes(node))
+        if isinstance(node, ast.Call):
+            name = dotted_name(node.func)
+            mod, _, leaf = name.rpartition(".")
+            if mod in ("jnp", "jax.numpy") and leaf in _EAGER_CTORS:
+                yield node.lineno, (
+                    f"{name}() — its own device launch per call; pass "
+                    "numpy at its final dtype to the jitted call")
+        elif isinstance(node, ast.Attribute) and node.attr == "at" \
+                and _reads_device_state(node.value):
+            yield node.lineno, (
+                ".at[] on engine device state outside a jitted def — "
+                "several programs per write; do it in a donated program")
+
+
 @rule(RULE, "no .item()/device_get/np.asarray/block_until_ready or "
             "scalar conversion of device state inside the engine "
-            "step/dispatch/ingest loops, except at # sync-ok points")
+            "step/dispatch/ingest loops, except at # sync-ok points; no "
+            "eager jnp constructor or .at[] write of device state there")
 def check(repo: Repo) -> list[Finding]:
     findings: list[Finding] = []
     f = repo.file(ENGINE)
@@ -89,8 +137,14 @@ def check(repo: Repo) -> list[Finding]:
     in_scope_lines: set[int] = set()
 
     for node in ast.walk(f.tree):
-        if not (isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef))
-                and _LOOP_FN.match(node.name)):
+        if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            continue
+        if _EAGER_FN.match(node.name):
+            findings.extend(
+                Finding(RULE, f.rel, lineno,
+                        f"eager device program inside {node.name}(): {msg}")
+                for lineno, msg in _eager_programs(node))
+        if not _LOOP_FN.match(node.name):
             continue
         for sub in ast.walk(node):
             if hasattr(sub, "lineno"):

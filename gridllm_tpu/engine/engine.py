@@ -244,6 +244,16 @@ def _device_memory_stats(device) -> dict[str, int]:
     return device.memory_stats() or {}
 
 
+def _host_i32(values: list[int], size: int) -> np.ndarray:
+    """`values` zero-padded to `size` as a host int32 buffer — what a
+    jitted call takes for a token chunk or a page-table row. Passed
+    straight in, it is transferred by the call's argument path; wrapped
+    in jnp.asarray it would be a device program of its own."""
+    buf = np.zeros((size,), np.int32)
+    buf[:len(values)] = values
+    return buf
+
+
 def _model_module(cfg: ModelConfig):
     if cfg.family == "mixtral":
         from gridllm_tpu.models import mixtral
@@ -1367,6 +1377,20 @@ class InferenceEngine:
                 mc.vocab_size,
             )
 
+        # Admission's sampler row and a finished slot's active flag: ONE
+        # donated program each, fed a small host record. An eager
+        # `.at[slot].set(v)` per field is several one-element programs,
+        # each a Python + PJRT dispatch with the chip idle (PERF.md, PR 25)
+        @partial(jax.jit, donate_argnums=(0,))
+        def sampler_row_fn(sp, slot, f32, i32):
+            return sp.set_row(slot, f32, i32)
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def deactivate_fn(active, slot):
+            return active.at[slot].set(False)
+
+        self._sampler_row_fn = self.perf.wrap("sampler_row", sampler_row_fn)
+        self._deactivate_fn = self.perf.wrap("deactivate", deactivate_fn)
         self._window_seed_fn = self.perf.wrap("window_seed", window_seed_fn)
         # vision models legitimately double the prefill signature space
         # post-warmup: an image request adds the embeds leaf to the same
@@ -1384,9 +1408,12 @@ class InferenceEngine:
             # vision path (llava family): encode_images per image-count
             # (jit caches per shape — image counts are tiny), splice per
             # (bucket, image-count) pair
-            self._encode_fn = self.perf.wrap("encode_images", jax.jit(
-                lambda params, px: self.mod.encode_images(params, mc, px)
-            ), armable=False)
+            def encode_fn(params, px):
+                emb = self.mod.encode_images(params, mc, px)  # [n, N, E]
+                return emb.reshape(-1, emb.shape[-1])
+
+            self._encode_fn = self.perf.wrap(
+                "encode_images", jax.jit(encode_fn), armable=False)
             self._splice_fn = self.perf.wrap("splice_embeds", jax.jit(
                 lambda params, toks, ie, off: self.mod.splice_embeds(
                     params, mc, toks, ie, off
@@ -1819,8 +1846,7 @@ class InferenceEngine:
         from gridllm_tpu.engine.images import preprocess_images
 
         px = preprocess_images(images, self.cfg.vision_cfg.image_size)
-        emb = self._encode_fn(self.params, jnp.asarray(px))  # [n, N, E]
-        return emb.reshape(-1, emb.shape[-1])
+        return self._encode_fn(self.params, px)
 
     def _dispatch_prefill(self, slot: int, ids: list[int],
                           row_list: list[int], upd: dict[str, Any],
@@ -1833,15 +1859,17 @@ class InferenceEngine:
         pages are already installed in `row_list`: those tokens skip the
         model forward (window bookkeeping only) and chunked prefill starts
         at the first uncached token."""
-        self.sampling = SamplingParams(**{
-            f.name: getattr(self.sampling, f.name).at[slot].set(upd[f.name])
-            for f in dataclasses.fields(SamplingParams)
-        })
+        # every argument below is host numpy at its final dtype: the
+        # jitted call's argument path transfers it, where an eager jnp
+        # scalar or array constructor would be a program of its own
+        slot_ = np.int32(slot)
+        self.sampling = self._sampler_row_fn(
+            self.sampling, slot_, *SamplingParams.pack_row(upd))
         img_flat = self._image_embeds(images) if images else None
         img_tok = self.cfg.vision_cfg.image_token if images else -1
         # counts[slot] is cleared INSIDE prefill_fn / prefill_chunk_fn —
         # no host-side clear here (it would be a dead full-row rewrite)
-        row = jnp.asarray(row_list, jnp.int32)
+        row = _host_i32(row_list, len(row_list))
         if cached or (self._use_chunked and len(ids) > self._chunk_len):
             # chunked prefill: repeated invocations of ONE fixed-shape
             # program against the growing cached prefix — no per-length
@@ -1853,20 +1881,19 @@ class InferenceEngine:
                 # state a warm request decodes with is bit-identical to
                 # the cold path's
                 part = ids[s0 : min(s0 + c, cached)]
-                padded = jnp.asarray(part + [0] * (c - len(part)), jnp.int32)
                 (self.window, self.wlen, self.counts) = self._window_seed_fn(
                     self.sampling, self.window, self.wlen, self.counts,
-                    padded, jnp.int32(s0), jnp.int32(len(part)),
-                    jnp.int32(slot),
+                    _host_i32(part, c), np.int32(s0), np.int32(len(part)),
+                    slot_,
                 )
             for s0 in range(cached, len(ids), c):
                 part = ids[s0 : s0 + c]
-                padded = jnp.asarray(part + [0] * (c - len(part)), jnp.int32)
+                padded = _host_i32(part, c)
                 embeds = None
                 if img_flat is not None:
                     off = sum(1 for t in ids[:s0] if t == img_tok)
                     embeds = self._splice_fn(
-                        self.params, padded, img_flat, jnp.int32(off)
+                        self.params, padded, img_flat, np.int32(off)
                     )
                 if self._use_mixed:
                     # ragged mixed step (ISSUE 6): this chunk AND one
@@ -1884,26 +1911,23 @@ class InferenceEngine:
                     self._prefill_chunk_fn(
                         self.params, padded, self.cache, self.counts,
                         self.window, self.wlen, self.tokens, self.active,
-                        self.sampling, jnp.int32(s0), jnp.int32(len(part)),
-                        jnp.int32(slot), row, jnp.bool_(s0 + c >= len(ids)),
+                        self.sampling, np.int32(s0), np.int32(len(part)),
+                        slot_, row, np.bool_(s0 + c >= len(ids)),
                         embeds=embeds,
                     )
                 )
         else:
-            bucket = self._bucket_for(len(ids))
-            padded = jnp.asarray(
-                ids + [0] * (bucket - len(ids)), jnp.int32
-            )
+            padded = _host_i32(ids, self._bucket_for(len(ids)))
             embeds = None
             if img_flat is not None:
                 embeds = self._splice_fn(
-                    self.params, padded, img_flat, jnp.int32(0)
+                    self.params, padded, img_flat, np.int32(0)
                 )
             (self.cache, self.counts, self.window, self.wlen, self.tokens,
              self.active, self.sampling) = self._prefill_fn(
                 self.params, padded, self.cache, self.counts,
                 self.window, self.wlen, self.tokens, self.active,
-                self.sampling, jnp.int32(len(ids)), jnp.int32(slot), row,
+                self.sampling, np.int32(len(ids)), slot_, row,
                 embeds=embeds,
             )
 
@@ -1942,11 +1966,11 @@ class InferenceEngine:
             )
             self._inflight.clear()  # replay never fetches
         elif op == "deact":
-            self.active = self.active.at[int(rec["slot"])].set(False)
+            self.active = self._deactivate_fn(
+                self.active, np.int32(rec["slot"]))
         elif op == "embed":
-            tok = jnp.asarray(np.asarray(rec["tok"], np.int32))
-            lens = jnp.asarray(np.asarray(rec["lens"], np.int32))
-            self._embed_fn(self.params, tok, lens)  # result unused
+            self._embed_fn(self.params, np.asarray(rec["tok"], np.int32),
+                           np.asarray(rec["lens"], np.int32))  # result unused
         elif op == "reset":
             self.reset_device_state()
         else:
@@ -2045,7 +2069,7 @@ class InferenceEngine:
             * max(time.time() - st.t_admit_wall, 0.0),
         )
         with self.dispatch_lock:
-            self.active = self.active.at[slot].set(False)
+            self.active = self._deactivate_fn(self.active, np.int32(slot))
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({"op": "deact", "slot": slot})
         # Release pages into the prefix-cache reuse LRU, registering full
@@ -2114,8 +2138,8 @@ class InferenceEngine:
          self.active, self.sampling) = self._mixed_chunk_fn(
             self.params, padded, self.cache, self.counts, self.window,
             self.wlen, self.tokens, self.active, self.sampling,
-            jnp.int32(start), jnp.int32(length), jnp.int32(slot), row,
-            jnp.bool_(is_final), embeds=embeds,
+            np.int32(start), np.int32(length), np.int32(slot), row,
+            np.bool_(is_final), embeds=embeds,
         )
         self._inflight.append((self._gen, out, 1))
 
@@ -2152,7 +2176,7 @@ class InferenceEngine:
              self.window, self.wlen, self.sampling) = self._verify_fn(
                 self.params, self.cache, self.tokens, self.active,
                 self.counts, self.window, self.wlen, self.sampling,
-                jnp.asarray(drafts, jnp.int32), jnp.asarray(dlen, jnp.int32),
+                drafts, dlen,
                 k1=int(drafts.shape[1]) + 1,  # from the record: follower
             )                                 # replay may differ from env K
             self._inflight.append((self._gen, (block, n_emit), 1))
@@ -2183,7 +2207,7 @@ class InferenceEngine:
              self.window, self.wlen, self.sampling) = fn(
                 self.params, self.cache, self.tokens, self.active,
                 self.counts, self.window, self.wlen, self.sampling,
-                jnp.asarray(drafts, jnp.int32), jnp.asarray(valid, bool),
+                drafts, valid,
             )
             self._inflight.append((self._gen, (block, n_emit), 1))
             if self.plan_sink is not None:  # after-success; see _try_admit

@@ -19,6 +19,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from gridllm_tpu.analysis import numcheck
 
@@ -27,6 +28,11 @@ from gridllm_tpu.analysis import numcheck
 # sampler setting (top_k clamps at TOPK — was 64 in round 3, lifted per
 # VERDICT r03 weak #7; top_p tail beyond 128 tokens ~0).
 TOPK = 128
+
+
+# SamplingParams' fields by dtype: the order of a pack_row record
+_F32_FIELDS = ("temperature", "top_p", "min_p", "repeat_penalty")
+_I32_FIELDS = ("top_k", "repeat_last_n", "seed", "step")
 
 
 @partial(
@@ -64,6 +70,24 @@ class SamplingParams:
             seed=jnp.zeros((s,), jnp.int32),
             step=jnp.zeros((s,), jnp.int32),
         )
+
+    @staticmethod
+    def pack_row(values: dict) -> tuple[np.ndarray, np.ndarray]:
+        """One slot's values (keyed by field name, plain host numbers) as
+        the host record `set_row` takes: the f32 fields and the i32 fields,
+        each in `_F32_FIELDS` / `_I32_FIELDS` order at its final dtype —
+        two small transfers instead of eight scalars."""
+        return (np.array([values[f] for f in _F32_FIELDS], np.float32),
+                np.array([values[f] for f in _I32_FIELDS], np.int32))
+
+    def set_row(self, slot, f32, i32) -> "SamplingParams":
+        """Write one slot's row of every field from a `pack_row` record
+        (traceable: the engine runs it as one donated program per
+        admission). Other rows are untouched."""
+        upd = {f: f32[i] for i, f in enumerate(_F32_FIELDS)}
+        upd.update({f: i32[i] for i, f in enumerate(_I32_FIELDS)})
+        return SamplingParams(**{
+            f: getattr(self, f).at[slot].set(v) for f, v in upd.items()})
 
 
 def _slot_gumbel(seed: jnp.ndarray, step: jnp.ndarray, k: int) -> jnp.ndarray:

@@ -1080,6 +1080,64 @@ def test_host_sync_item_and_block_until_ready(tmp_path):
     assert any("block_until_ready" in m for m in msgs), msgs
 
 
+_FIXTURE_EAGER_PROGRAMS = (
+    "import jax\n"
+    "import jax.numpy as jnp\n"
+    "import numpy as np\n"
+    "from functools import partial\n"
+    "class Engine:\n"
+    "    def _build_fns(self):\n"
+    "        self.x = jnp.int32(0)\n"
+    "    def _dispatch_prefill(self, slot, ids, row_list):\n"
+    "        @partial(jax.jit, donate_argnums=(0,))\n"
+    "        def row_fn(sp, slot):\n"
+    "            one = jnp.asarray(1)\n"
+    "            return self.sampling.step.at[slot].set(one)\n"
+    "        self.sampling = self.sampling.step.at[slot].set(0)\n"
+    "        row = jnp.asarray(row_list, jnp.int32)\n"
+    "        buf = np.zeros((4,), np.int32)\n"
+    "        return self.fn(buf, row, jnp.int32(slot), jnp.bool_(True),\n"
+    "                       np.int32(slot))\n"
+    "    def _finish(self, slot):\n"
+    "        self.active = self.active.at[slot].set(False)\n"
+    "    def apply_plan_op(self, rec):\n"
+    "        tok = jax.numpy.array(rec['tok'])\n"
+    "        self.local = self.scratch.at[0].set(1)\n"
+    "        return tok\n"
+)
+
+
+def test_host_sync_flags_eager_device_programs(tmp_path):
+    """ISSUE 25: a jnp constructor on a host value or an .at[] write of
+    device state in the loop functions is one program (or several) per
+    call; the same code inside a nested jitted def is traced, not run."""
+    root = make_repo(tmp_path, {
+        "gridllm_tpu/engine/engine.py": _FIXTURE_EAGER_PROGRAMS})
+    found = [(f.line, f.message)
+             for f in findings_for(root, "host-sync-discipline")]
+    lines = _FIXTURE_EAGER_PROGRAMS.splitlines()
+
+    def hit(fn: str, what: str, source: str) -> bool:
+        return any(fn in m and what in m and source in lines[ln - 1]
+                   for ln, m in found)
+
+    assert hit("_dispatch_prefill()", ".at[]", "step.at[slot].set(0)")
+    assert hit("_dispatch_prefill()", "jnp.asarray()", "row_list")
+    assert hit("_dispatch_prefill()", "jnp.int32()", "jnp.int32(slot)")
+    assert hit("_dispatch_prefill()", "jnp.bool_()", "jnp.bool_(True)")
+    assert hit("_finish()", ".at[]", "self.active.at[slot]")
+    assert hit("apply_plan_op()", "jax.numpy.array()", "rec['tok']")
+    # the nested jitted def's body, numpy buffers, a local array's .at[]
+    # and functions outside the loops are all silent
+    assert len(found) == 6, found
+
+
+def test_host_sync_real_engine_runs_no_eager_programs():
+    """The engine as committed: admission, finish and plan replay build
+    their arguments on the host and write device rows in jitted programs."""
+    assert findings_for(REPO_ROOT, "host-sync-discipline") == []
+
+
 # -- helpers ----------------------------------------------------------------
 
 def test_expand_braces():

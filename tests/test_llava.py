@@ -290,6 +290,24 @@ def test_plan_replay_reproduces_vision_admission(twin):
     follower.apply_plan_op(rec)
     np.testing.assert_array_equal(prompt_rows(follower), want)
     assert int(np.asarray(follower.cache.lengths)[rec["slot"]]) == n_prompt
+    # the record's format is what it was (ISSUE 25 moved the sampler row
+    # into one program, not the plan): the follower's sampler row is the
+    # record's, its slot is live, and once every record has been replayed
+    # ("deact" included) sampler rows and active flags are the liaison's
+    assert set(rec) == {"op", "slot", "ids", "row", "sp", "cached", "images"}
+    assert np.asarray(follower.active)[rec["slot"]]
+    for f, v in rec["sp"].items():
+        got = np.asarray(getattr(follower.sampling, f))
+        assert got[rec["slot"]] == np.asarray(v, got.dtype) + (f == "step"), f
+    assert {"op": "deact", "slot": rec["slot"]} in records
+    for later in records[records.index(rec) + 1:]:
+        follower.apply_plan_op(later)
+    for f in rec["sp"]:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(follower.sampling, f)),
+            np.asarray(getattr(liaison.sampling, f)), f)
+    np.testing.assert_array_equal(np.asarray(follower.active),
+                                  np.asarray(liaison.active))
 
     # and the image must MATTER: replaying with the pixels dropped gives
     # different K/V (guards against a replay path that skips the splice)
