@@ -1,15 +1,85 @@
 """Operations and bytes computed from a configuration's shapes (the
 published ``config.json`` keys of a ``configs/<name>.json``), and the table
 of peaks. Kept with the benchmark so that no PR that claims a gain can
-change what a roofline share is measured against."""
+change what a roofline share is measured against.
+
+This file is the dense SwiGLU decoder. A configuration of another
+architecture (routed experts, latent attention) names a file of its own,
+``"costs": "family_costs/<name>.py"``, with the same functions:
+``total_params``, ``weight_bytes``, ``step_weight_bytes``,
+``kv_bytes_per_token``, ``flash_prefill_flops`` and ``chip_share``, each
+taking the configuration. ``of(spec)`` is the one lookup the readers use.
+The first five count the whole model; ``chip_share`` says by how much
+each is divided to give what ONE chip holds under the configuration's
+``mesh``, because a trace's times are one chip's."""
 
 from __future__ import annotations
 
+import importlib.util
 import json
+import math
 import os
+import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4, "int8": 1}
+MESH_AXES = ("pp", "dp", "ep", "tp", "sp")      # the program's vocabulary
+
+
+def of(spec: dict):
+    """The module that counts for this configuration: the file its
+    ``costs`` key names under ``benchmark/``, else this one."""
+    rel = spec.get("costs")
+    if not rel:
+        return sys.modules[__name__]
+    path = os.path.normpath(os.path.join(HERE, rel))
+    if not path.startswith(HERE + os.sep):
+        raise ValueError(f"costs file {rel!r} is not under benchmark/")
+    name = "bench_costs_" + "".join(c if c.isalnum() else "_" for c in rel)
+    if name not in sys.modules:
+        mod_spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        mod_spec.loader.exec_module(mod)
+        sys.modules[name] = mod
+    return sys.modules[name]
+
+
+def mesh_axes(spec: dict) -> dict[str, int]:
+    """``"tp:4"`` -> ``{"tp": 4}``: the configuration's ``mesh``, in the
+    form of ``GRIDLLM_MESH_SHAPE`` and parsed as the worker parses that."""
+    axes = {}
+    for part in filter(None, (spec.get("mesh") or "").split(",")):
+        axis, _, size = part.partition(":")
+        if axis not in MESH_AXES or not size.isdigit() or int(size) < 1:
+            raise ValueError(f"mesh {spec.get('mesh')!r}: {part!r} is not "
+                             f"<axis>:<size> with an axis of {MESH_AXES}")
+        axes[axis] = int(size)
+    return axes
+
+
+def mesh_size(spec: dict) -> int:
+    return math.prod(mesh_axes(spec).values())
+
+
+def chip_share(spec: dict) -> dict | None:
+    """What each whole-model count is divided by to give one chip's:
+    ``weights`` (``step_weight_bytes``, ``weight_bytes``), ``kv``
+    (``kv_bytes_per_token``) and ``heads`` (``flash_prefill_flops``, and
+    the query heads in a kernel's result shape). No mesh: 1 each. ``tp:N``
+    splits every projection, the FFN, the vocabulary and the KV heads N
+    ways (the norms, 0.003 % of a layer, are held whole and counted as
+    split). An axis with no rule here gives None, and a roofline reader
+    then reports nothing rather than a guess."""
+    axes = mesh_axes(spec)
+    n = axes.pop("tp", 1)
+    if any(size > 1 for size in axes.values()):
+        return None
+    if spec["num_key_value_heads"] % n or spec["num_attention_heads"] % n:
+        raise ValueError(
+            f"tp:{n} does not divide {spec['num_key_value_heads']} KV heads "
+            f"and {spec['num_attention_heads']} query heads: one chip's "
+            "share of the cache is not a whole head")
+    return {"weights": n, "kv": n, "heads": n}
 
 
 def peaks(device_kind: str) -> dict:

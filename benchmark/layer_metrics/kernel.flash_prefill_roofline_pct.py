@@ -1,8 +1,10 @@
 """The flash prefill kernel's share of its compute roofline in the traced
-window: the floating-point operations its calls need
-(``costs.flash_prefill_flops`` at the bucket T each call ran at, read from
-the call's result shape ``[T, KV heads, group, head dim]`` in the trace)
-over the chip's bf16 peak, over the calls' device time. Bound named:
+window, the first chip's time against the first chip's share: the
+floating-point operations its calls need on one chip
+(``flash_prefill_flops`` of the configuration's costs at the bucket T each
+call ran at, over ``chip_share``'s heads; T is read from the call's result
+shape ``[T, one chip's KV heads, group, head dim]`` in the trace) over the
+chip's bf16 peak, over the calls' device time. Bound named:
 compute (at T = 512 the kernel's bytes over 819 GB/s are a tenth of its
 operations over 197 TFLOP/s)."""
 import re
@@ -15,11 +17,16 @@ NAME, UNIT, LAYER, MOVES = "kernel.flash_prefill_roofline_pct", "%", "kernels", 
 
 def compute(run):
     spec, need, secs = run["config"], 0.0, 0.0
+    count = costs.of(spec)
+    share = count.chip_share(spec)
+    if not share:
+        return None
     for o in readers.ops(run, readers.FLASH_OPS):
         t, kvh, group, d = (int(x) for x in re.search(readers.FLASH_OPS, o["text"]).groups())
-        if (kvh * group, d) != (spec["num_attention_heads"], costs.head_dim(spec)):
+        if (kvh * group * share["heads"], d) != (
+                spec["num_attention_heads"], costs.head_dim(spec)):
             continue
-        need += o["count"] * costs.flash_prefill_flops(spec, t)
+        need += o["count"] * count.flash_prefill_flops(spec, t) / share["heads"]
         secs += o["seconds"]
     if not secs:
         return None

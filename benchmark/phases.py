@@ -49,25 +49,33 @@ def per_launch_ms(run: dict, keep) -> float | None:
     return 1e3 * sum(s for p, (s, _) in w.items() if keep(p)) / launches
 
 
+def chip_share(run: dict) -> dict | None:
+    """One chip's share of the configuration's counts under its mesh
+    (``costs.chip_share``); None where the costs have no rule."""
+    return costs.of(run["config"]).chip_share(run["config"])
+
+
 def kv_bytes_per_launch(run: dict) -> float | None:
-    """Mean KV bytes one verify / decode launch has to read, over the
-    capture: the context-token counter's change over the launches' (both
-    from ``trace_counters``, the worker's ``/metrics`` at the capture's two
-    ends) times the bytes of one position over every layer."""
-    ends = run.get("trace_counters")
-    if not ends:
+    """Mean KV bytes one verify / decode launch has to read ON ONE CHIP,
+    over the capture: the context-token counter's change over the
+    launches' (both from ``trace_counters``, the worker's ``/metrics`` at
+    the capture's two ends) times the bytes of one position over every
+    layer, over the chips its KV heads are split across."""
+    ends, share = run.get("trace_counters"), chip_share(run)
+    if not ends or not share:
         return None
     launches = between(*ends).get(LAUNCH, (0.0, 0.0))[1]
     tokens = (stack.metric_sum(ends[1], CTX_TOKENS)
               - stack.metric_sum(ends[0], CTX_TOKENS))
     if launches <= 0 or tokens <= 0:
         return None
-    return tokens / launches * costs.kv_bytes_per_token(run["config"])
+    per_token = costs.of(run["config"]).kv_bytes_per_token(run["config"])
+    return tokens / launches * (per_token / share["kv"])
 
 
 def verify_launches(run: dict) -> tuple[float, int]:
     """(device seconds, launches) of the verify / decode programs in the
-    traced window."""
+    traced window, on the first chip."""
     return readers.programs(run, readers.VERIFY_PROGRAMS)
 
 

@@ -11,10 +11,15 @@ returns a number or None (nothing to read: the metric is left out).
   ``/metrics`` text when the window opened and after the drain;
 - ``samples``: ``[(monotonic time, worker /metrics text), ...]`` taken
   every half second of a traced window;
-- ``trace``: ``trace_reduce.reduce()`` of the profiler capture, or {};
+- ``trace``: ``trace_reduce.reduce()`` of the profiler capture, or {}:
+  its ``programs`` and ``ops`` are the FIRST chip's, so a reader that sets
+  them against bytes or operations takes the first chip's share of those
+  (``costs.of(config).chip_share(config)``; ``phases.chip_share``): the
+  first chip's time against the first chip's share;
 - ``trace_counters``: worker ``/metrics`` text at the capture's start and end;
 - ``memory``: the worker's ``/admin/memory``; ``pool``: its ``kv pool
-  sized`` log record; ``config``: the configuration file; ``device``.
+  sized`` log record; ``config``: the configuration file (its ``mesh``,
+  ``chips`` and ``costs`` with it); ``device``.
 """
 
 from __future__ import annotations
@@ -71,7 +76,8 @@ def first_device_busy_s(run: dict):
 # module names; a Pallas kernel is a ``custom-call`` named after the
 # Python function that wraps it: ``%ragged_attention.N``, and ``%vmap__.N``
 # for the flash prefill kernel (``jax.vmap(one)`` in ``flash_prefill``),
-# whose result is ``[T, KV heads, group, head dim]``.
+# whose result is ``[T, KV heads, group, head dim]``: one chip's KV heads
+# under a mesh, where the kernel runs inside a ``shard_map``.
 VERIFY_PROGRAMS = r"verify_block|decode_block"
 PREFILL_PROGRAMS = r"prefill|mixed_chunk"
 RAGGED_OPS = r"^%ragged_attention[.\d]* = .*custom-call\("

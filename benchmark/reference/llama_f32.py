@@ -26,6 +26,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 F32 = jnp.float32
 
@@ -109,11 +110,13 @@ def penalized(rows, tokens, first: int, penalty: float, last_n: int):
     ``rows[i]`` are the logits that predict ``tokens[first + i]``."""
     if penalty == 1.0 or last_n <= 0:
         return rows
-    seen = jnp.zeros(rows.shape, bool)
+    # which tokens each row has seen is bookkeeping, kept on the host: one
+    # mask, where a scatter a row on the device was a program a window
+    # length (and, on rows sharded over chips, seconds each to compile)
+    seen = np.zeros(rows.shape, bool)
     for i in range(rows.shape[0]):
         p = first + i
-        window = jnp.asarray(tokens[max(0, p - last_n):p])
-        seen = seen.at[i, window].set(True)
+        seen[i, np.asarray(tokens[max(0, p - last_n):p], np.int64)] = True
     return jnp.where(seen, jnp.where(rows > 0, rows / penalty, rows * penalty), rows)
 
 

@@ -75,3 +75,35 @@ def test_recorded_tpu_trace():
     assert r["idle_pct"] == pytest.approx(100 * (1 - r["busy_s"] / r["window_s"]))
     assert sum(o["seconds"] for o in r["ops"].values()) == pytest.approx(r["busy_s"], rel=1e-3)
     assert len(r["breakdown"]["device_ops"]) <= 10
+
+
+COLLECTIVES = r"^%(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+
+
+def test_recorded_four_chip_trace():
+    """tests/data/tiny4.xplane.pb (``record_tiny4.py`` beside it): four
+    device planes; busy and idle are the mean over the chips; programs and
+    operations are the first chip's, not four times over; the sharded
+    matmul's all-reduce is among the operations."""
+    import re
+
+    path = os.path.join(DATA, "tiny4.xplane.pb")
+    assert os.path.getsize(path) < 1_000_000
+    planes = trace_reduce.read(path)
+    r = trace_reduce.reduce(planes)
+    assert list(r["devices"]) == [f"/device:TPU:{i}" for i in range(4)]
+    per_chip = list(r["devices"].values())
+    assert r["idle_pct"] == pytest.approx(sum(d["idle_pct"] for d in per_chip) / 4)
+    assert r["busy_s"] == pytest.approx(sum(d["busy_s"] for d in per_chip) / 4)
+    assert r["idle_pct_max"] == max(d["idle_pct"] for d in per_chip)
+    assert len({round(d["busy_s"], 9) for d in per_chip}) > 1      # four clocks, not one copied
+    # four launches each, as the first chip saw them (all chips: sixteen)
+    assert r["programs"]["jit_step_a"]["count"] == 4
+    assert r["programs"]["jit_step_b"]["count"] == 4
+    first = planes["devices"]["/device:TPU:0"]
+    assert sum(o["count"] for o in r["ops"].values()) == len(first["ops"])
+    assert sum(o["seconds"] for o in r["ops"].values()) == pytest.approx(
+        per_chip[0]["busy_s"], rel=1e-3)
+    collectives = [k for k, o in r["ops"].items() if re.search(COLLECTIVES, o["text"])]
+    assert collectives and all(r["ops"][k]["program"] == "jit_step_a" for k in collectives)
+    assert all(r["ops"][k]["count"] == 4 for k in collectives)

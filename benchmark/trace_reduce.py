@@ -139,8 +139,12 @@ def self_times(events: list[tuple]) -> list[float]:
 def reduce(planes: dict, top: int = 10) -> dict:
     """Seconds throughout. ``busy_s``/``idle_pct`` are per chip and
     averaged (``idle_pct_max`` is the idlest chip); programs, operations
-    and idle gaps are the first chip's. An operation's ``seconds`` are its
-    own (``self_times``), keyed ``<program>#<id>/<operation>``."""
+    and idle gaps are the first chip's, so whatever a reader sets against
+    them is the first chip's share (``costs.chip_share``): the first chip's
+    time against the first chip's share. Under a mesh every chip runs the
+    same programs in step, and a collective shows on each as an operation
+    of its own. An operation's ``seconds`` are its own (``self_times``),
+    keyed ``<program>#<id>/<operation>``."""
     devs = planes["devices"]
     if not devs:
         return {}
