@@ -5,6 +5,14 @@
 #   REDIS_HOST=<bus-node> GRIDLLM_MODELS=llama3:8b \
 #   GRIDLLM_CHECKPOINT_DIR=/data/checkpoints ./tpu-vm-bootstrap.sh
 #
+# A model that fits no single chip, on one four-chip host (measured:
+# PERF.md, PR 27; the benchmark's cell nemo12b-tp4.chat): v5e-4,
+#   GRIDLLM_MODELS=mistral-nemo:12b GRIDLLM_MESH_SHAPE=tp:4 \
+#   GRIDLLM_MAX_BATCH_SLOTS=16 ./tpu-vm-bootstrap.sh
+# 6.1 GB of weights + 5.4 GB of KV pool a chip (11.5 of 16.9 GB at peak);
+# start to serving 116 s with an empty compile cache, 36 s with a warm one
+# (keep JAX_COMPILATION_CACHE_DIR on a disk that survives restarts).
+#
 # Multi-host slices (e.g. v5e-16 across 2 hosts): run this on every host;
 # jax.distributed coordination is derived from the TPU metadata when
 # GRIDLLM_MULTIHOST=1 — only process 0 speaks to the Redis bus (the slice

@@ -71,8 +71,11 @@ from gridllm_tpu.obs import SIZE_BUCKETS, default_flight_recorder, default_regis
 from gridllm_tpu.obs.perf import (
     ADMIT_WAIT_SECONDS,
     VERIFY_CTX_TOKENS_TOTAL,
+    XLA_COMPILE_SECONDS,
     PhaseClock,
     RecompileTripwire,
+    capture_span,
+    compile_owner,
 )
 from gridllm_tpu.ops.attention import ragged_attention_enabled
 from gridllm_tpu.ops.kvcache import (
@@ -99,7 +102,7 @@ from gridllm_tpu.ops.spec import (
     tree_topology,
 )
 from gridllm_tpu.parallel.mesh import MeshConfig, build_mesh
-from gridllm_tpu.parallel.sharding import shard_params
+from gridllm_tpu.parallel.sharding import param_shardings, shard_params
 from gridllm_tpu.utils.config import (
     compile_cache_dir,
     env_bool,
@@ -521,6 +524,10 @@ class InferenceEngine:
             config.tokenizer, self.cfg.vocab_size
         )
         self.mesh = build_mesh(config.mesh) if config.mesh else None
+        # the mesh as GRIDLLM_MESH_SHAPE writes it ("tp:4"; "" unmeshed):
+        # for log records and the dispatch spans' meta
+        self.mesh_axes = "" if self.mesh is None else ",".join(
+            f"{a}:{n}" for a, n in self.mesh.shape.items() if n > 1)
         # family-specific mesh constraints fail HERE (engine startup), not
         # at the first request's trace (e.g. gemma2 has no sp variant)
         getattr(self.mod, "validate_mesh", lambda *_: None)(self.cfg, self.mesh)
@@ -604,8 +611,9 @@ class InferenceEngine:
         self.plan_sink: Callable[[dict[str, Any]], None] | None = None
         self.dispatch_lock: threading.RLock = threading.RLock()
         self.prewarm_duration_ns = 0
-        self._load()
-        self._build_fns()
+        with compile_owner(self.cfg.name):
+            self._load()
+            self._build_fns()
 
     # ---------------------------------------------------------- state setup
 
@@ -630,6 +638,11 @@ class InferenceEngine:
                 return quantize_params(p)
             return p
 
+        def _init():
+            return _maybe_quant(
+                self.mod.init_params(mc, jax.random.PRNGKey(0), dtype))
+
+        init_times: dict[str, float] = {}
         # Weight snapshot tier (ISSUE 20): a parked host copy of this
         # exact checkpoint identity skips the safetensors re-read (or
         # re-init) — host→device transfer only. An injected restore fault
@@ -655,27 +668,18 @@ class InferenceEngine:
             self.load_source = "snapshot"
         elif c.checkpoint_path:
             from gridllm_tpu.engine.loader import load_checkpoint
-            from gridllm_tpu.parallel.sharding import param_shardings
 
             shardings = None
             if self.mesh is not None:
-                proto = jax.eval_shape(
-                    lambda: _maybe_quant(
-                        self.mod.init_params(mc, jax.random.PRNGKey(0), dtype)
-                    )
-                )
-                shardings = param_shardings(proto, self.mesh)
+                shardings = param_shardings(jax.eval_shape(_init), self.mesh)
             self.params = load_checkpoint(
                 mc, c.checkpoint_path, dtype, shardings, quantize=c.quantize
             )
             self.load_source = "checkpoint"
         else:
-            self.params = _maybe_quant(
-                self.mod.init_params(mc, jax.random.PRNGKey(0), dtype)
-            )
-            if self.mesh is not None:
-                self.params = shard_params(self.params, self.mesh)
+            self.params, init_times = self._init_weights(_init)
             self.load_source = "init"
+        self._log_weights_ready(init_times)
         if self.embedding_only:
             # no generation state: encoder families have no KV cache,
             # sampler, or decode loop — just the pooled-forward embed path
@@ -699,6 +703,48 @@ class InferenceEngine:
             self.load_duration_ns / 1e9,
             model=self.cfg.name, source=self.load_source,
         )
+
+    def _init_weights(self, init: Callable[[], Any]) -> tuple[Any, dict]:
+        """Synthetic weights. Under a mesh the tree is born sharded: one
+        jitted program whose out-shardings are the mesh's layout, so no
+        chip ever holds more than its share (a 12B tree does not pass
+        through device 0; the out-shardings move no bit, and against the
+        eager call XLA's fusion rounds about one element in a million the
+        other way by one bf16 step). Unmeshed it stays the eager per-leaf
+        call (ROADMAP D12). Returns the tree and the init's seconds."""
+        with capture_span("gridllm.init_params", model=self.cfg.name,
+                          mesh=self.mesh_axes):
+            t0 = time.perf_counter()
+            if self.mesh is None:
+                params = jax.block_until_ready(init())
+                return params, {"initRunS": time.perf_counter() - t0}
+            # compiled ahead of its one call, so that the record can tell
+            # the compile's seconds from the run's
+            program = self.perf.wrap("weights_init", jax.jit(
+                init, out_shardings=param_shardings(
+                    jax.eval_shape(init), self.mesh)),
+                armable=False).lower().compile()
+            t1 = time.perf_counter()
+            params = jax.block_until_ready(program())
+            return params, {"initCompileS": t1 - t0,
+                            "initRunS": time.perf_counter() - t1}
+
+    def _log_weights_ready(self, init_times: dict[str, float]) -> None:
+        """One record of where the weights are: the most parameter bytes
+        any one device holds is what a sharded birth is for."""
+        # from the shardings' metadata, as /admin/memory counts: walking
+        # addressable_shards would leave one live Array a shard behind
+        held: dict[Any, int] = {}
+        for leaf in jax.tree_util.tree_leaves(self.params):
+            nbytes = math.prod(
+                leaf.sharding.shard_shape(leaf.shape)) * leaf.dtype.itemsize
+            for device in leaf.sharding.addressable_devices:
+                held[device] = held.get(device, 0) + nbytes
+        log.info("weights ready", model=self.cfg.name,
+                 source=self.load_source, mesh=self.mesh_axes,
+                 devices=len(held),
+                 paramBytesMaxDevice=max(held.values(), default=0),
+                 **{k: round(v, 3) for k, v in init_times.items()})
 
     def snapshot_key(self) -> str:
         """Checkpoint identity for the weight snapshot tier: everything
@@ -753,15 +799,24 @@ class InferenceEngine:
             # is on, so the second admission is a hit (window_seed)
             lengths += [self._chunk_len + 1] * (
                 2 if self._prefix_cache_cap != 0 else 1)
+        # one fill token per length: prompts that shared a first page
+        # would hit the prefix cache and skip their bucket
+        prompts = [(n, 1 + lengths.index(n)) for n in lengths]
+        if self.mesh is not None:
+            # the first request again, as a new prompt: under a mesh its
+            # programs (the sampler row, the first bucket's prefill) were
+            # compiled for the state as created, on one device, and every
+            # step leaves the state laid out over the mesh as XLA chose, so
+            # their second call compiles again with no new Python signature
+            # (gridllm_xla_compile_seconds sees it, the tripwire cannot):
+            # here, not inside a user's request
+            prompts.append((lengths[0], 1 + len(lengths)))
         self._perf_armed = True              # _finish arms only when False
         try:
-            for i, n in enumerate(lengths):
+            for i, (n, fill) in enumerate(prompts):
                 t1 = time.perf_counter()
-                # one fill token per length: prompts that shared a first
-                # page would hit the prefix cache and skip their bucket
                 res = self.generate(GenerationRequest(
-                    id=f"prewarm-{i}", raw=True,
-                    prompt_ids=[1 + lengths.index(n)] * n,
+                    id=f"prewarm-{i}", raw=True, prompt_ids=[fill] * n,
                     options={"temperature": 0, "seed": 0, "num_predict": 2},
                 ))
                 if res.done_reason == "error":
@@ -774,8 +829,14 @@ class InferenceEngine:
         finally:
             self._perf_armed = False
         self.prewarm_duration_ns = time.perf_counter_ns() - t0
+        # what jax has built for this model so far (init and prewarm; a
+        # mesh's layout recompile of the first program among them): a
+        # later rise of the series is a compile under traffic
         log.info("engine prewarmed", model=self.cfg.name,
-                 ms=self.prewarm_duration_ns // 1_000_000)
+                 ms=self.prewarm_duration_ns // 1_000_000,
+                 xlaCompiles=XLA_COMPILE_SECONDS.count(model=self.cfg.name),
+                 xlaCompileS=round(
+                     XLA_COMPILE_SECONDS.sum(model=self.cfg.name), 3))
 
     def _set_buckets(self) -> None:
         # always include max_context so every admissible length maps to a
@@ -915,7 +976,6 @@ class InferenceEngine:
         ckpt = (ckpt or "").strip()
         if ckpt:
             from gridllm_tpu.engine.loader import load_checkpoint
-            from gridllm_tpu.parallel.sharding import param_shardings
 
             shardings = None
             if self.mesh is not None:
@@ -1744,7 +1804,8 @@ class InferenceEngine:
         # the span that caused the program launch; what follows the
         # dispatch in this function (counters, gauges) stays in this phase
         self._clock.mark("dispatch_prefill", request=req.id,
-                         prompt_tokens=len(ids), cached_tokens=cached)
+                         prompt_tokens=len(ids), cached_tokens=cached,
+                         mesh=self.mesh_axes)
         with self.dispatch_lock:
             # emit AFTER the dispatch succeeds: a record for a program the
             # liaison never actually issued would make followers replay a
@@ -2455,7 +2516,8 @@ class InferenceEngine:
         ctx = sum(len(st.ids) for st in self._slots.values())
         VERIFY_CTX_TOKENS_TOTAL.inc(ctx, model=self.cfg.name)
         self._clock.mark("dispatch_verify", gen=self._gen + 1,
-                         slots=len(self._slots), ctx_tokens=ctx)
+                         slots=len(self._slots), ctx_tokens=ctx,
+                         mesh=self.mesh_axes)
 
     def _mark_ingest(self) -> None:
         """Leave ``fetch`` for ``ingest``, right after the device_get
@@ -2506,7 +2568,8 @@ class InferenceEngine:
         t_run = time.perf_counter()
         self._clock.mark("idle_wait")
         try:
-            self._run()
+            with compile_owner(self.cfg.name):
+                self._run()
         finally:
             self._clock.pause()
             self.runner_wall_s += time.perf_counter() - t_run
@@ -2617,9 +2680,10 @@ class InferenceEngine:
         if self.running:
             done_evt.wait()
             return box[0]
-        while not box:
-            if not self.step() and not box:
-                time.sleep(0.001)
+        with compile_owner(self.cfg.name):
+            while not box:
+                if not self.step() and not box:
+                    time.sleep(0.001)
         return box[0]
 
     # batch-size buckets for the embeddings path: bounded compile count

@@ -356,6 +356,14 @@ register(ModelConfig(
     intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
     head_dim=16, rope_theta=10_000.0, max_seq_len=256, sliding_window=8,
 ))
+# mistral-nemo's shape in small: heads x head_dim (128) is not the hidden
+# size (64), 8 query / 4 KV heads so that tp:4 really splits the KV heads,
+# vocabulary divisible by 4, untied, no window
+register(ModelConfig(
+    name="tiny-nemo", vocab_size=256, hidden_size=64,
+    intermediate_size=128, num_layers=2, num_heads=8, num_kv_heads=4,
+    head_dim=16, rope_theta=1_000_000.0, max_seq_len=256,
+))
 register(ModelConfig(
     name="tiny-gemma2", family="gemma2", vocab_size=256, hidden_size=64,
     intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,

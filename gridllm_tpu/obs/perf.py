@@ -123,6 +123,58 @@ VERIFY_CTX_TOKENS_TOTAL = _OBS.counter(
     ("model",),
 )
 
+# -- XLA compilations, as jax itself reports them ----------------------------
+# The recompile tripwire above sees Python-level signatures only; under a
+# mesh the first program compiles a second time once the state's layouts
+# have settled, with no new signature. jax.monitoring's backend-compile
+# event fires for every executable jax builds or loads from its persistent
+# cache (eager one-primitive programs included), so "nothing compiled in
+# this window" is the change of _count over it being 0.
+XLA_COMPILE_SECONDS = _OBS.histogram(
+    "gridllm_xla_compile_seconds",
+    "Executables jax built (XLA compilation, or a load from the persistent "
+    "compile cache) and the seconds each took, from jax.monitoring's "
+    "backend-compile duration event, by the model whose engine the "
+    "compiling thread was working for (\"\" = none: another thread). "
+    "_count not rising over a stretch of serving means nothing compiled "
+    "in it, under a mesh or not.",
+    ("model",),
+    buckets=(0.01, 0.05, 0.25, 1.0, 5.0, 15.0, 60.0, 120.0, 300.0),
+)
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_owner = threading.local()
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def _on_jax_duration(event: str, seconds: float, **_: Any) -> None:
+    if event == _BACKEND_COMPILE_EVENT:
+        XLA_COMPILE_SECONDS.observe(
+            seconds, model=getattr(_compile_owner, "model", ""))
+
+
+@contextlib.contextmanager
+def compile_owner(model: str):
+    """Book what this thread compiles inside the block to `model` in
+    ``gridllm_xla_compile_seconds``; the first use registers the listener
+    with ``jax.monitoring`` (once a process: jax offers no way to take one
+    listener off again)."""
+    global _compile_listener_on
+    with _compile_listener_lock:
+        if not _compile_listener_on:
+            import jax.monitoring
+
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _compile_listener_on = True
+    before = getattr(_compile_owner, "model", "")
+    _compile_owner.model = model
+    try:
+        yield
+    finally:
+        _compile_owner.model = before
+
+
 # -- device-memory gauges ----------------------------------------------------
 
 DEVICE_MEMORY_BYTES = _OBS.gauge(

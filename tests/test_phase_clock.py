@@ -226,12 +226,14 @@ def test_no_trace_annotation_is_constructed_with_no_capture_active(monkeypatch):
     assert names == {"gridllm." + p for p in PHASES}
     assert all(s.open is False for s in made)       # each closed by the next mark
     launch = next(s for s in made if s.name == "gridllm.dispatch_verify")
-    assert set(launch.meta) == {"gen", "slots", "ctx_tokens"}
+    assert set(launch.meta) == {"gen", "slots", "ctx_tokens", "mesh"}
+    assert launch.meta["mesh"] == ""                # unmeshed; "tp:4" under one
     assert launch.meta["slots"] >= 1 and launch.meta["ctx_tokens"] > 0
     admit = next(s for s in made if s.name == "gridllm.admit")
     assert admit.meta["request"] in ("r0", "r1")
     prefill = next(s for s in made if s.name == "gridllm.dispatch_prefill")
     assert prefill.meta["prompt_tokens"] > 0 and "cached_tokens" in prefill.meta
+    assert prefill.meta["mesh"] == ""
     assert any("tokens" in s.meta for s in made if s.name == "gridllm.ingest")
     n = len(made)
     _serve(eng, n=1, idle_s=0.0)
