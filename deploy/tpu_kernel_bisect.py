@@ -232,45 +232,6 @@ def _write_chunk():
                 (mask, mask))
 
 
-def _legacy_decode():
-    lens = jnp.asarray(LENGTHS, jnp.int32)
-    args = (_rand(6, (S, H, D)), _pool(1), _pool(2),
-            jnp.asarray(_page_table()), lens,
-            _rand(7, (S, KVH, D)), _rand(8, (S, KVH, D)))
-
-    def run(q, kp, vp, pt, lens, kc, vc):
-        return PK.paged_decode(q, kp, vp, pt, lens, page_size=PS, k_cur=kc,
-                               v_cur=vc, layer=jnp.int32(LAYER),
-                               interpret=_interpret())
-
-    def ref(q, kp, vp, pt, lens, kc, vc):
-        return A.paged_attention_decode_ref(
-            q, kp[LAYER], vp[LAYER], pt, lens, PS, k_cur=kc, v_cur=vc)
-
-    return Case("paged_decode", run, ref, args)
-
-
-def _legacy_chunk():
-    start, total = 1024, 1024 + 900
-    args = (_rand(3, (1, C, H, D)), _pool(1), _pool(2),
-            jnp.asarray(_chunk_row()),
-            _rand(4, (C, KVH, D)), _rand(5, (C, KVH, D)))
-
-    def run(q, kp, vp, row, kc, vc):
-        return PK.prefix_chunk(q, kp, vp, row, jnp.int32(start),
-                               jnp.int32(total), page_size=PS, k_cur=kc,
-                               v_cur=vc, layer=jnp.int32(LAYER),
-                               interpret=_interpret())
-
-    def ref(q, kp, vp, row, kc, vc):
-        return A._prefix_chunk_ref(
-            q, kp, vp, row, jnp.int32(start), jnp.int32(total), PS,
-            k_cur=kc, v_cur=vc, layer=jnp.int32(LAYER))
-
-    return Case("prefix_chunk", run, ref, args,
-                (np.arange(C)[None, :] < total - start,))
-
-
 def _paths():
     """Cold and cached, a short prompt takes two programs: flash prefill
     over its padded bucket, then — on a prefix-cache hit — the chunk
@@ -313,9 +274,9 @@ def _paths():
                  jnp.asarray(row)), rel_to_max=True)
 
 
-# The engine's default path first (ragged attention in every form a step
-# launches, the write kernels, bucketed flash prefill), then the kernels
-# only GRIDLLM_RAGGED_ATTN=0 or a long bucket reaches.
+# The engine's path first (ragged attention in every form a step
+# launches, the write kernels, bucketed flash prefill), then the kernel
+# only a long bucket reaches.
 CASES: dict[str, Callable[[], Case]] = {
     "ragged_chunk": lambda: _ragged(True, 0),
     "ragged_decode": lambda: _ragged(False, 1),
@@ -329,8 +290,6 @@ CASES: dict[str, Callable[[], Case]] = {
     "flash512": lambda: _flash(PK.flash_prefill, 512),
     "flash1024": lambda: _flash(PK.flash_prefill, 1024),
     "streamed": lambda: _flash(PK.flash_prefill_streamed, 1024),
-    "decode": _legacy_decode,
-    "chunkatt": _legacy_chunk,
     "paths": _paths,
 }
 

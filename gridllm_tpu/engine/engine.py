@@ -77,7 +77,6 @@ from gridllm_tpu.obs.perf import (
     capture_span,
     compile_owner,
 )
-from gridllm_tpu.ops.attention import ragged_attention_enabled
 from gridllm_tpu.ops.kvcache import (
     PagedKVCache,
     PageAllocator,
@@ -540,10 +539,6 @@ class InferenceEngine:
             # single-device engines keep their kernels.
             self.cfg = dataclasses.replace(self.cfg, use_pallas=False)
         self._rng = random.Random(config.seed)
-        # ragged paged attention (ISSUE 6), resolved ONCE at startup: the
-        # pool layout (_pool_head_dim) and the admission path both depend
-        # on it, and flipping mid-serving would mix incompatible layouts
-        self._ragged = ragged_attention_enabled()
         # prefix-cache capacity, resolved ONCE (env reads at startup, not
         # per admission): 0 = off, < 0 = unbounded reuse LRU, > 0 = cap.
         # sp > 1 prefills whole prompts via ring attention — there is no
@@ -1030,8 +1025,8 @@ class InferenceEngine:
         if interpret and not env_bool("GRIDLLM_POOL_PAD"):
             return d
         kvh = local_kv_heads(self.cfg.num_kv_heads, self.mesh)
-        if self._ragged and flat_lanes_ok(kvh, d):
-            # ragged layout (ISSUE 6): page rows are lane-aligned viewed
+        if flat_lanes_ok(kvh, d):
+            # flat-lane layout: page rows are lane-aligned viewed
             # flat ([ps, KVH*D] — PER tp SHARD, where kv heads split), so
             # the ragged kernel and the DMA write kernels run on the
             # UNPADDED pool — the lane-pad KV-byte overhead /admin/memory
@@ -1340,7 +1335,7 @@ class InferenceEngine:
         # between decode blocks. Bookkeeping is the union of
         # prefill_chunk_fn's (chunk slot rows) and decode_block_fn's
         # (active slot rows) — per-slot state rows are disjoint, so each
-        # region's updates are bit-identical to the legacy programs'.
+        # region's updates are bit-identical to the per-phase programs'.
         # Returns a [2, S] block (row 0 = input tokens, row 1 = this
         # step's decode samples) that rides the normal ingest protocol.
         @partial(jax.jit, donate_argnums=(2, 3, 4, 5, 6, 7, 8))
@@ -1482,12 +1477,11 @@ class InferenceEngine:
         # ring attention (sp) runs whole-prompt prefill; the chunked path
         # reads the paged prefix instead and has no sp variant yet
         self._use_chunked = attn is None
-        # ragged mixed steps need the chunked path AND a family mixed_step
-        # (parallel/pipeline.py has no mixed schedule — pp engines keep
-        # the legacy per-chunk dispatch even with ragged attention on)
-        self._use_mixed = (
-            self._ragged and self._use_chunked and hasattr(mod, "mixed_step")
-        )
+        # mixed steps need the chunked path AND a family mixed_step.
+        # parallel/pipeline.py is the one module without a mixed schedule:
+        # pp engines admit chunk by chunk through prefill_chunk_fn, which
+        # exists for them alone
+        self._use_mixed = self._use_chunked and hasattr(mod, "mixed_step")
         if self._use_mixed:
             self._mixed_chunk_fn = self.perf.wrap(
                 "mixed_chunk", mixed_chunk_fn, armable=text_only
@@ -2904,8 +2898,7 @@ class InferenceEngine:
             with self._alloc_lock:
                 self.alloc.unpin_pages(pages)
         dpool = self.cache.k.shape[-1]
-        layout = (("ragged" if dpool == d else "ragged-padded")
-                  if self._ragged else "legacy")
+        layout = "ragged" if dpool == d else "ragged-padded"
         return {
             "tokens": [int(t) for t in token_ids[:tokens]],
             "k": k, "v": v,
@@ -3322,17 +3315,14 @@ class InferenceEngine:
             # lane padding multiplies KV bytes for d<128 models under the
             # kernel path (_pool_head_dim) — this is that overhead's share.
             # Under the ragged flat-lane layout (kvLayout "ragged") the
-            # pool stays UNPADDED, so this reads 0 — the KV-bytes win of
-            # ISSUE 6, visible directly here
+            # pool stays UNPADDED, so this reads 0
             "lanePadOverheadBytes": int(
                 kv_bytes * (1 - mc.head_dim_ / dpool)) if dpool else 0,
-            # "ragged" = unified attention on an unpadded pool (the zero-
-            # overhead case the README documents); "ragged-padded" =
-            # ragged attention but the shape can't go flat-lane (e.g.
-            # KVH=1, d=64), so the pool still pays the pad
+            # "ragged" = an unpadded pool (the zero-overhead case the
+            # README documents); "ragged-padded" = the shape can't go
+            # flat-lane (e.g. KVH=1, d=64), so the pool still pays the pad
             "kvLayout": (
-                ("ragged" if dpool == mc.head_dim_ else "ragged-padded")
-                if self._ragged else "legacy"),
+                "ragged" if dpool == mc.head_dim_ else "ragged-padded"),
             "liveTokens": live_tokens,
             # internal fragmentation of the live allocation: capacity
             # reserved at admission (num_predict headroom + tail pages)

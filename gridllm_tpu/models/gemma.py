@@ -43,10 +43,6 @@ from gridllm_tpu.models.configs import ModelConfig
 from gridllm_tpu.models.llama import _precision, validate_mesh  # noqa: F401
 from gridllm_tpu.ops.attention import (
     attention_prefill,
-    attention_prefix_chunk,
-    paged_attention_decode,
-    paged_attention_verify,
-    ragged_attention_enabled,
     ragged_paged_attention,
 )
 from gridllm_tpu.ops.kvcache import (
@@ -304,20 +300,14 @@ def prefill_chunk(
     total = start + length
 
     def attn_fn(q, k, v, win, li):
-        if ragged_attention_enabled():
-            att, _ = ragged_paged_attention(
-                cache.k, cache.v, cache.page_size,
-                q_chunk=q, chunk_row=table_row, chunk_start=start,
-                chunk_total=total, k_chunk=k[0], v_chunk=v[0], layer=li,
-                use_pallas=cfg.use_pallas,
-                logit_softcap=cfg.attn_logit_softcap, window=win, mesh=mesh,
-            )
-            return att.reshape(1, t, -1)
-        return attention_prefix_chunk(
-            q, cache.k, cache.v, table_row, start, total, cache.page_size,
-            k_cur=k[0], v_cur=v[0], layer=li, use_pallas=cfg.use_pallas,
+        att, _ = ragged_paged_attention(
+            cache.k, cache.v, cache.page_size,
+            q_chunk=q, chunk_row=table_row, chunk_start=start,
+            chunk_total=total, k_chunk=k[0], v_chunk=v[0], layer=li,
+            use_pallas=cfg.use_pallas,
             logit_softcap=cfg.attn_logit_softcap, window=win, mesh=mesh,
-        ).reshape(1, t, -1)
+        )
+        return att.reshape(1, t, -1)
 
     x, k_ys, v_ys = _scan_layers(params, cfg, x, pos, attn_fn)
     k_new, v_new = k_ys[:, 0], v_ys[:, 0]  # [L, C, KVH, D]
@@ -357,21 +347,14 @@ def decode_step(
     )
 
     def attn_fn(q, k, v, win, li):
-        if ragged_attention_enabled():
-            _, att = ragged_paged_attention(
-                cache.k, cache.v, cache.page_size,
-                q_group=q, page_table=cache.page_table,
-                group_lengths=positions, k_group=k, v_group=v, layer=li,
-                use_pallas=cfg.use_pallas,
-                logit_softcap=cfg.attn_logit_softcap, window=win, mesh=mesh,
-            )
-            return att.reshape(s, 1, -1)
-        return paged_attention_decode(
-            q[:, 0], cache.k, cache.v, cache.page_table, positions,
-            cache.page_size, k_cur=k[:, 0], v_cur=v[:, 0], layer=li,
+        _, att = ragged_paged_attention(
+            cache.k, cache.v, cache.page_size,
+            q_group=q, page_table=cache.page_table,
+            group_lengths=positions, k_group=k, v_group=v, layer=li,
             use_pallas=cfg.use_pallas,
             logit_softcap=cfg.attn_logit_softcap, window=win, mesh=mesh,
-        ).reshape(s, 1, -1)
+        )
+        return att.reshape(s, 1, -1)
 
     x, k_ys, v_ys = _scan_layers(
         params, cfg, x, positions[:, None], attn_fn
@@ -404,7 +387,7 @@ def verify_step(
     """Speculative-verify forward (llama.verify_step contract): T candidate
     tokens per slot in one pass, KV written optimistically, lengths left
     for the engine's rollback_to_length commit. Softcap and the per-layer
-    sliding windows thread through paged_attention_verify exactly as they
+    sliding windows thread through the ragged group region exactly as they
     do through the decode path. Tree verify (`tree_pos`/`tree_mask`,
     ISSUE 18): rope at logical positions base + depth, KV still stored at
     base + i — same contract as llama.verify_step."""
@@ -417,21 +400,14 @@ def verify_step(
            if tree_pos is not None else store_pos)
 
     def attn_fn(q, k, v, win, li):
-        if ragged_attention_enabled():
-            _, att = ragged_paged_attention(
-                cache.k, cache.v, cache.page_size,
-                q_group=q, page_table=cache.page_table, group_lengths=base,
-                k_group=k, v_group=v, layer=li, use_pallas=cfg.use_pallas,
-                logit_softcap=cfg.attn_logit_softcap, window=win, mesh=mesh,
-                tree_pos=tree_pos, tree_mask=tree_mask,
-            )
-            return att.reshape(s, t, -1)
-        return paged_attention_verify(
-            q, cache.k, cache.v, cache.page_table, base, cache.page_size,
-            k_cur=k, v_cur=v, layer=li, use_pallas=cfg.use_pallas,
+        _, att = ragged_paged_attention(
+            cache.k, cache.v, cache.page_size,
+            q_group=q, page_table=cache.page_table, group_lengths=base,
+            k_group=k, v_group=v, layer=li, use_pallas=cfg.use_pallas,
             logit_softcap=cfg.attn_logit_softcap, window=win, mesh=mesh,
             tree_pos=tree_pos, tree_mask=tree_mask,
-        ).reshape(s, t, -1)
+        )
+        return att.reshape(s, t, -1)
 
     x, k_new, v_new = _scan_layers(params, cfg, x, pos, attn_fn)
     x = _gnorm(x, params["final_norm"], cfg.rms_eps)

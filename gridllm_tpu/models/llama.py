@@ -26,10 +26,6 @@ import jax.numpy as jnp
 from gridllm_tpu.models.configs import ModelConfig
 from gridllm_tpu.ops.attention import (
     attention_prefill,
-    attention_prefix_chunk,
-    paged_attention_decode,
-    paged_attention_verify,
-    ragged_attention_enabled,
     ragged_paged_attention,
 )
 from gridllm_tpu.ops.kvcache import (
@@ -396,8 +392,8 @@ def prefill_chunk_layers(
     layers against the slot's cached prefix (full stack from
     `prefill_chunk`; per-stage blocks from parallel/pipeline.py).
     x: [1, C, E] in; returns (x out, k_new [N, C, KVH, D], v_new).
-    Attention dispatches to pallas_kernels.prefix_chunk (paged-prefix
-    streaming flash) when kernels are on — `mesh` threads through for the
+    Attention is ragged_paged_attention's chunk region (paged-prefix
+    streaming flash when kernels are on) — `mesh` threads through for the
     meshed shard_map wrapper."""
     t = x.shape[1]
     inv_freq = precompute_rope(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
@@ -413,23 +409,15 @@ def prefill_chunk_layers(
         k = apply_rope(k, pos, inv_freq)
         # pool holds the PREFIX only (writes deferred past the scan); the
         # fresh chunk's K/V are overlaid inside the attention. Full pool as
-        # closure + layer index — see decode_layers. Ragged mode routes
-        # through the unified kernel's chunk region (ISSUE 6).
-        if ragged_attention_enabled():
-            att, _ = ragged_paged_attention(
-                k_pool, v_pool, page_size,
-                q_chunk=q, chunk_row=table_row, chunk_start=start,
-                chunk_total=total, k_chunk=k[0], v_chunk=v[0], layer=li,
-                use_pallas=cfg.use_pallas, window=cfg.sliding_window,
-                mesh=mesh,
-            )
-            att = att.reshape(1, t, -1)
-        else:
-            att = attention_prefix_chunk(
-                q, k_pool, v_pool, table_row, start, total, page_size,
-                k_cur=k[0], v_cur=v[0], layer=li, use_pallas=cfg.use_pallas,
-                window=cfg.sliding_window, mesh=mesh,
-            ).reshape(1, t, -1)
+        # closure + layer index — see decode_layers.
+        att, _ = ragged_paged_attention(
+            k_pool, v_pool, page_size,
+            q_chunk=q, chunk_row=table_row, chunk_start=start,
+            chunk_total=total, k_chunk=k[0], v_chunk=v[0], layer=li,
+            use_pallas=cfg.use_pallas, window=cfg.sliding_window,
+            mesh=mesh,
+        )
+        att = att.reshape(1, t, -1)
         x = x + qdot(att, lp["wo"], precision=_precision(x))
         hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         return x + mlp(lp, hx), (k[0], v[0])
@@ -476,24 +464,16 @@ def decode_layers(
         # to the pool ONCE after the scan (in-place DMA kernel). The FULL
         # pool rides in as a scan closure with `li` selecting the layer —
         # per-layer xs slices would materialize 2×pool-slice copies/iter.
-        # Ragged mode: a decode step is the unified kernel's group region
-        # with query_len = 1 per slot (ISSUE 6).
-        if ragged_attention_enabled():
-            _, attn = ragged_paged_attention(
-                k_pool, v_pool, page_size,
-                q_group=q[:, None], page_table=page_table,
-                group_lengths=positions, k_group=k[:, None],
-                v_group=v[:, None], layer=li, use_pallas=cfg.use_pallas,
-                window=cfg.sliding_window, mesh=mesh,
-            )
-            attn = attn[:, 0].reshape(s, -1)
-        else:
-            attn = paged_attention_decode(
-                q, k_pool, v_pool, page_table, positions,
-                page_size, k_cur=k, v_cur=v, layer=li,
-                use_pallas=cfg.use_pallas, window=cfg.sliding_window,
-                mesh=mesh,
-            ).reshape(s, -1)
+        # A decode step is the ragged group region with query_len = 1 per
+        # slot.
+        _, attn = ragged_paged_attention(
+            k_pool, v_pool, page_size,
+            q_group=q[:, None], page_table=page_table,
+            group_lengths=positions, k_group=k[:, None],
+            v_group=v[:, None], layer=li, use_pallas=cfg.use_pallas,
+            window=cfg.sliding_window, mesh=mesh,
+        )
+        attn = attn[:, 0].reshape(s, -1)
         x = x + qdot(attn, lp["wo"], precision=_precision(x))
         hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         return x + mlp(lp, hx), (k, v)
@@ -588,26 +568,17 @@ def verify_layers(
         k = apply_rope(k, pos, inv_freq)
         # pool holds each slot's prefix only; the candidates' K/V are
         # overlaid in-register and written ONCE after the scan (full pool
-        # as closure + layer index — see decode_layers). Ragged mode: ONE
-        # launch over all slots (group region, query_len = K+1) instead
-        # of paged_attention_verify's per-slot kernel loop (ISSUE 6).
-        if ragged_attention_enabled():
-            _, att = ragged_paged_attention(
-                k_pool, v_pool, page_size,
-                q_group=q, page_table=page_table,
-                group_lengths=base_lengths, k_group=k, v_group=v,
-                layer=li, use_pallas=cfg.use_pallas,
-                window=cfg.sliding_window, mesh=mesh,
-                tree_pos=tree_pos, tree_mask=tree_mask,
-            )
-            att = att.reshape(s, t, -1)
-        else:
-            att = paged_attention_verify(
-                q, k_pool, v_pool, page_table, base_lengths, page_size,
-                k_cur=k, v_cur=v, layer=li, use_pallas=cfg.use_pallas,
-                window=cfg.sliding_window, mesh=mesh,
-                tree_pos=tree_pos, tree_mask=tree_mask,
-            ).reshape(s, t, -1)
+        # as closure + layer index — see decode_layers). ONE launch over
+        # all slots: the ragged group region with query_len = K+1.
+        _, att = ragged_paged_attention(
+            k_pool, v_pool, page_size,
+            q_group=q, page_table=page_table,
+            group_lengths=base_lengths, k_group=k, v_group=v,
+            layer=li, use_pallas=cfg.use_pallas,
+            window=cfg.sliding_window, mesh=mesh,
+            tree_pos=tree_pos, tree_mask=tree_mask,
+        )
+        att = att.reshape(s, t, -1)
         x = x + qdot(att, lp["wo"], precision=_precision(x))
         hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         return x + mlp(lp, hx), (k, v)
@@ -685,13 +656,13 @@ def mixed_layers(
     mlp: MlpFn = _mlp,
     mesh=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Mixed chunked-prefill + decode layer scan (ISSUE 6): the ragged
+    """Mixed chunked-prefill + decode layer scan: the ragged
     token batch [1, C+S, E] — rows [0, C) one admitting slot's prefill
     chunk at absolute positions chunk_start + i, rows [C, C+S) one decode
     token per slot at positions group_lengths[s] — runs the whole layer
     stack with ONE ragged attention launch per layer. Pointwise sublayers
     (norms, projections, MLP) are row-independent, so each region's rows
-    compute exactly what the separate legacy programs would. Returns
+    compute exactly what the separate per-phase programs would. Returns
     (x out, k_new [L, C+S, KVH, D], v_new) — pool writes are the
     caller's, split per region."""
     c = chunk_width
@@ -744,10 +715,10 @@ def mixed_step(
     mesh=None,
     embeds: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, PagedKVCache]:
-    """One fused chunked-prefill + decode step (ISSUE 6): the prefill
+    """One fused chunked-prefill + decode step: the prefill
     chunk for ONE admitting slot PLUS one decode token for every active
     slot, batched into one ragged descriptor — a single attention launch
-    per layer instead of the legacy per-phase (and per-slot) dispatches.
+    per layer.
     Long prefills stop stalling running streams: the batch keeps decoding
     while the chunk prefills alongside it (the DeepServe mixed-step
     shape).

@@ -14,10 +14,7 @@ from gridllm_tpu.ops.layers import (
     RopeScaling,
 )
 from gridllm_tpu.ops.kvcache import PagedKVCache
-from gridllm_tpu.ops.attention import (
-    attention_prefill,
-    paged_attention_decode,
-)
+from gridllm_tpu.ops.attention import attention_prefill
 from gridllm_tpu.ops.sampling import SamplingParams, sample_tokens
 
 __all__ = [
@@ -27,7 +24,6 @@ __all__ = [
     "RopeScaling",
     "PagedKVCache",
     "attention_prefill",
-    "paged_attention_decode",
     "SamplingParams",
     "sample_tokens",
 ]

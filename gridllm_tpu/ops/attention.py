@@ -1,7 +1,9 @@
-"""Attention: causal prefill and paged decode.
+"""Attention: causal whole-prompt prefill and ragged paged attention.
 
-Public entry points (`attention_prefill`, `paged_attention_decode`)
-dispatch between the Pallas TPU kernels (ops/pallas_kernels.py) and the
+Public entry points (`attention_prefill` for an unpaged prompt,
+`ragged_paged_attention` for every phase that reads the page pool:
+chunked prefill, decode, verify, tree verify) dispatch between the
+Pallas TPU kernels (ops/pallas_kernels.py) and the
 pure-jnp reference implementations (`*_ref` here) — the jnp versions are
 correct on CPU and TPU and are the numerical oracle for the kernels
 (tests/test_pallas.py). Softmax is computed in fp32 regardless of input
@@ -25,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from gridllm_tpu.analysis import numcheck
-from gridllm_tpu.utils.config import env_bool
 from gridllm_tpu.ops.kvcache import (
     QuantPages,
     _env_mode,
@@ -37,24 +38,12 @@ from gridllm_tpu.ops.kvcache import (
 )
 
 __all__ = [
-    "attention_prefill", "paged_attention_decode", "attention_prefix_chunk",
-    "paged_attention_verify", "ragged_paged_attention",
-    "ragged_paged_attention_ref", "ragged_attention_enabled",
+    "attention_prefill", "ragged_paged_attention",
+    "ragged_paged_attention_ref",
     "attention_prefill_ref", "paged_attention_decode_ref",
     "_env_mode", "_pallas_mode",  # re-export: policy lives in ops/kvcache.py
 ]
 
-
-def ragged_attention_enabled() -> bool:
-    """Ragged paged attention (ISSUE 6): one unified kernel/launch serving
-    chunked prefill, decode, and spec-verify over a ragged per-slot
-    descriptor layout, replacing the three per-phase dispatchers below.
-    Env `GRIDLLM_RAGGED_ATTN` = "1" (default: on) routes the model
-    decode/verify/chunk paths (and the engine's mixed admission steps)
-    through `ragged_paged_attention`; "0" is the escape hatch restoring
-    the legacy dispatchers exactly. Resolved at trace time — flip it
-    before building an engine, not mid-serving."""
-    return env_bool("GRIDLLM_RAGGED_ATTN")
 
 _NEG_INF = -1e30
 
@@ -185,256 +174,6 @@ def attention_prefill(
     return _shadow(sm(q, k, v, seq_lens, jnp.asarray(window, jnp.int32)))
 
 
-def paged_attention_decode(
-    q: jnp.ndarray,
-    k_pages: jnp.ndarray,
-    v_pages: jnp.ndarray,
-    page_table: jnp.ndarray,
-    lengths: jnp.ndarray,
-    page_size: int,
-    k_cur: jnp.ndarray | None = None,
-    v_cur: jnp.ndarray | None = None,
-    layer: jnp.ndarray | None = None,
-    use_pallas: bool | None = None,
-    logit_softcap: float = 0.0,
-    window: jnp.ndarray | int = 0,
-    mesh=None,
-) -> jnp.ndarray:
-    """Paged decode attention (see paged_attention_decode_ref for the
-    contract). With k_cur/v_cur ([S, KVH, D]), `lengths` counts the
-    cached PREFIX only and the current token's K/V are merged in-register
-    (one extra online-softmax step) — the engine defers all pool writes to
-    one all-layer kernel after the layer scan, so the pool lags one token
-    during decode. Pools may be the FULL [L, P, ps, KVH, D] stack with
-    `layer` selecting the layer to read (pass from inside a layer scan so
-    no per-layer pool slice is materialized). Routes to the page-streaming
-    kernel when enabled. Mosaic requires 128-lane-aligned page slices;
-    d=64 models (qwen2.5 class) keep the kernel path via the engine's
-    lane-padded pool (ops.kvcache.lane_pad_dim) — the dispatch pads
-    q/k_cur/v_cur to the pool's D and slices the output back, exactly.
-    `logit_softcap` (static) and `window` (may be traced,
-    gemma2 alternates per layer) are handled inside the kernel — windowed
-    decode never DMAs pages below the window.
-
-    Under `mesh` (VERDICT r04 #2): full-manual shard_map with heads split
-    over tp — each shard runs the kernel on its kv-head slice of the page
-    pool, no collectives (the wo row-parallel psum that follows stays
-    GSPMD's, outside the wrapper)."""
-    d, dpool = q.shape[-1], k_pages.shape[-1]
-    if dpool != d:
-        q, k_cur, v_cur = _lane_pad_qkv(q, k_cur, v_cur, dpool)
-        out = paged_attention_decode(
-            q, k_pages, v_pages, page_table, lengths, page_size,
-            k_cur=k_cur, v_cur=v_cur, layer=layer, use_pallas=use_pallas,
-            logit_softcap=logit_softcap, window=window, mesh=mesh,
-        )
-        return out[..., :d]
-    use, interpret = _pallas_mode(use_pallas)
-    mode, ax = kernel_mesh_axis(mesh, k_pages.shape[-2], q.shape[1])
-    # int8 pools (ISSUE 11) read through the ragged kernel's dequant
-    # epilogue or the jnp fallback; the legacy decode kernel has no
-    # scale plumbing, so a quantized pool takes the reference path here
-    if use and mode != "ref" and not isinstance(k_pages, QuantPages) \
-            and (interpret or q.shape[-1] % 128 == 0):
-        from gridllm_tpu.ops import pallas_kernels
-
-        record_kernel_path("attention_decode", True)
-        kernel = partial(
-            pallas_kernels.paged_decode, page_size=page_size,
-            interpret=interpret, softcap=float(logit_softcap),
-        )
-
-        def _shadow(out):
-            if not numcheck.active():
-                return out
-
-            def ref():
-                kp, vp = k_pages, v_pages
-                if kp.ndim == 5:
-                    li = jnp.int32(0) if layer is None else layer
-                    kp = jax.lax.dynamic_index_in_dim(kp, li,
-                                                      keepdims=False)
-                    vp = jax.lax.dynamic_index_in_dim(vp, li,
-                                                      keepdims=False)
-                return paged_attention_decode_ref(
-                    q, kp, vp, page_table, lengths, page_size,
-                    k_cur=k_cur, v_cur=v_cur,
-                    logit_softcap=logit_softcap, window=window)
-
-            # without the current-token merge a length-0 slot is garbage
-            # by contract (callers mask on active); with it, even a fresh
-            # slot's single-column softmax is specified output
-            return numcheck.shadow(
-                "attention_decode", out, ref,
-                valid=None if k_cur is not None else lengths > 0)
-
-        if mode == "direct":
-            return _shadow(kernel(q, k_pages, v_pages, page_table, lengths,
-                                  k_cur=k_cur, v_cur=v_cur, layer=layer,
-                                  window=window))
-        from jax.sharding import PartitionSpec as P
-
-        pool = P(*((None,) * (k_pages.ndim - 2)), ax, None)
-        hs = P(None, ax, None)
-        # optional/traced operands (k_cur/v_cur, layer, window) must enter
-        # through in_specs — shard_map bodies cannot close over tracers.
-        # window is always an operand: the kernels read it from SMEM at
-        # runtime either way, so there is nothing to specialize.
-        opt = {"window": (jnp.asarray(window, jnp.int32), P())}
-        if k_cur is not None:
-            opt["k_cur"], opt["v_cur"] = (k_cur, hs), (v_cur, hs)
-        if layer is not None:
-            opt["layer"] = (layer, P())
-        names = sorted(opt)
-
-        def sm_body(q, kp, vp, pt, lens, *dyn):
-            return kernel(q, kp, vp, pt, lens, **dict(zip(names, dyn)))
-
-        args = [q, k_pages, v_pages, page_table, lengths]
-        specs = [hs, pool, pool, P(*((None,) * page_table.ndim)), P(None)]
-        args += [opt[n][0] for n in names]
-        specs += [opt[n][1] for n in names]
-        sm = _shard_map_kernel(mesh, sm_body, in_specs=tuple(specs),
-                               out_specs=hs)
-        return _shadow(sm(*args))
-    record_kernel_path("attention_decode", False)
-    if k_pages.ndim == 5:  # fallback: materialize the layer slice
-        li = jnp.int32(0) if layer is None else layer
-        if isinstance(k_pages, QuantPages):
-            k_pages, v_pages = k_pages.layer(li), v_pages.layer(li)
-        else:
-            k_pages = jax.lax.dynamic_index_in_dim(k_pages, li,
-                                                   keepdims=False)
-            v_pages = jax.lax.dynamic_index_in_dim(v_pages, li,
-                                                   keepdims=False)
-    return paged_attention_decode_ref(
-        q, k_pages, v_pages, page_table, lengths, page_size,
-        k_cur=k_cur, v_cur=v_cur, logit_softcap=logit_softcap,
-        window=window,
-    )
-
-
-def attention_prefix_chunk(
-    q: jnp.ndarray,
-    k_pages: jnp.ndarray,
-    v_pages: jnp.ndarray,
-    table_row: jnp.ndarray,
-    start: jnp.ndarray,
-    total_len: jnp.ndarray,
-    page_size: int,
-    k_cur: jnp.ndarray | None = None,
-    v_cur: jnp.ndarray | None = None,
-    layer: jnp.ndarray | None = None,
-    use_pallas: bool | None = None,
-    logit_softcap: float = 0.0,
-    window: jnp.ndarray | int = 0,
-    mesh=None,
-) -> jnp.ndarray:
-    """Chunked-prefill attention: one chunk of queries against the slot's
-    FULL cached context (prefix + this chunk), read from the page pool.
-
-    q: [1, T, H, D] — chunk queries at absolute positions start + arange(T);
-    k_pages/v_pages: [P, page_size, KVH, D] one layer's pool; table_row:
-    [max_pages] the slot's pages; start: scalar absolute position of q[0];
-    total_len: scalar = start + valid tokens in this chunk. Without
-    k_cur/v_cur the chunk's K/V must already be in the pool; with them
-    ([T, KVH, D], pool writes deferred to after the layer scan) the chunk
-    rows are overlaid onto the gathered context at positions start+i.
-    Returns [1, T, H, D].
-
-    This is what `attention_prefill_ref`'s docstring named as missing in
-    round 1 ("chunked prefill against an existing cached prefix") — the
-    piece that makes prompts longer than the largest bucket run as repeated
-    fixed-shape chunk programs instead of per-length recompiles
-    (VERDICT.md #4). Dispatch: pallas_kernels.prefix_chunk (prefix pages
-    streamed from HBM, chunk K/V resident) when the chunk fits the VMEM
-    budget; jnp fallback (dense prefix gather) otherwise — both mesh-aware
-    (full-manual shard_map over tp, like paged_attention_decode).
-    """
-    dq, dpool = q.shape[-1], k_pages.shape[-1]
-    if dpool != dq:
-        q, k_cur, v_cur = _lane_pad_qkv(q, k_cur, v_cur, dpool)
-        out = attention_prefix_chunk(
-            q, k_pages, v_pages, table_row, start, total_len, page_size,
-            k_cur=k_cur, v_cur=v_cur, layer=layer, use_pallas=use_pallas,
-            logit_softcap=logit_softcap, window=window, mesh=mesh,
-        )
-        return out[..., :dq]
-    _, t, h, d = q.shape
-    kvh = k_pages.shape[-2]
-    use, interpret = _pallas_mode(use_pallas)
-    mode, ax = kernel_mesh_axis(mesh, kvh, h)
-    # kernel path: the chunk flash kernel streams prefix pages from HBM
-    # and keeps the chunk's K/V resident — gated on the chunk's per-layer
-    # K+V fitting the VMEM budget and Mosaic's lane alignment. The budget
-    # is per SHARD: under tp the resident chunk is kvh/tp heads wide.
-    kvh_local = kvh // mesh.shape["tp"] if ax == "tp" else kvh
-    if (
-        use and mode != "ref" and k_cur is not None
-        and not isinstance(k_pages, QuantPages)
-        and (interpret or d % 128 == 0)
-        and t % min(128, t) == 0
-        and 2 * t * kvh_local * d * q.dtype.itemsize <= _FLASH_KV_VMEM_CAP
-    ):
-        from gridllm_tpu.ops import pallas_kernels
-
-        record_kernel_path("attention_prefix_chunk", True)
-        kernel = partial(
-            pallas_kernels.prefix_chunk, page_size=page_size,
-            interpret=interpret, softcap=float(logit_softcap),
-        )
-
-        def _shadow(out):
-            if not numcheck.active():
-                return out
-            return numcheck.shadow(
-                "attention_prefix_chunk", out,
-                lambda: _prefix_chunk_ref(
-                    q, k_pages, v_pages, table_row, start, total_len,
-                    page_size, k_cur=k_cur, v_cur=v_cur, layer=layer,
-                    logit_softcap=logit_softcap, window=window),
-                # rows past the chunk's valid length are bucket padding
-                valid=jnp.arange(q.shape[1])[None, :] < total_len - start,
-            )
-
-        if mode == "direct":
-            return _shadow(kernel(q, k_pages, v_pages, table_row, start,
-                                  total_len, k_cur=k_cur, v_cur=v_cur,
-                                  layer=layer, window=window))
-        from jax.sharding import PartitionSpec as P
-
-        pool = P(*((None,) * (k_pages.ndim - 2)), ax, None)
-        hs = P(None, None, ax, None)
-        cur = P(None, ax, None)
-        opt = {
-            "start": (start, P()),
-            "total_len": (total_len, P()),
-            "window": (jnp.asarray(window, jnp.int32), P()),
-        }
-        if layer is not None:
-            opt["layer"] = (layer, P())
-        names = sorted(opt)
-
-        def sm_body(q, kp, vp, row, kc, vc, *dyn):
-            kw = dict(zip(names, dyn))
-            return kernel(q, kp, vp, row, kw.pop("start"),
-                          kw.pop("total_len"), k_cur=kc, v_cur=vc, **kw)
-
-        args = [q, k_pages, v_pages, table_row, k_cur, v_cur]
-        specs = [hs, pool, pool, P(None), cur, cur]
-        args += [opt[n][0] for n in names]
-        specs += [opt[n][1] for n in names]
-        sm = _shard_map_kernel(mesh, sm_body, in_specs=tuple(specs),
-                               out_specs=hs)
-        return _shadow(sm(*args))
-    record_kernel_path("attention_prefix_chunk", False)
-    return _prefix_chunk_ref(
-        q, k_pages, v_pages, table_row, start, total_len, page_size,
-        k_cur=k_cur, v_cur=v_cur, layer=layer,
-        logit_softcap=logit_softcap, window=window,
-    )
-
-
 def _prefix_chunk_ref(
     q: jnp.ndarray,
     k_pages: jnp.ndarray,
@@ -449,10 +188,19 @@ def _prefix_chunk_ref(
     logit_softcap: float = 0.0,
     window: jnp.ndarray | int = 0,
 ) -> jnp.ndarray:
-    """jnp reference for chunked-prefill attention against a paged prefix
-    (the fallback leg of attention_prefix_chunk, factored out so
-    ragged_paged_attention's chunk region shares it VERBATIM — ragged-on
-    and ragged-off jnp paths must stay bit-identical)."""
+    """jnp reference for chunked-prefill attention: one chunk of queries
+    against the slot's FULL cached context (prefix + this chunk), read
+    from the page pool — ragged_paged_attention_ref's chunk region.
+
+    q: [1, T, H, D] — chunk queries at absolute positions start + arange(T);
+    k_pages/v_pages: [P, page_size, KVH, D] one layer's pool, or the full
+    [L, P, ps, KVH, D] stack with `layer` selecting; table_row:
+    [max_pages] the slot's pages; start: scalar absolute position of q[0];
+    total_len: scalar = start + valid tokens in this chunk. Without
+    k_cur/v_cur the chunk's K/V must already be in the pool; with them
+    ([T, KVH, D], pool writes deferred to after the layer scan) the chunk
+    rows are overlaid onto the gathered context at positions start+i.
+    Returns [1, T, H, D]."""
     _, t, h, d = q.shape
     kvh = k_pages.shape[-2]
     g = h // kvh
@@ -513,78 +261,6 @@ def _prefix_chunk_ref(
     return out.reshape(1, t, h, d).astype(q.dtype)
 
 
-def paged_attention_verify(
-    q: jnp.ndarray,
-    k_pages: jnp.ndarray,
-    v_pages: jnp.ndarray,
-    page_table: jnp.ndarray,
-    lengths: jnp.ndarray,
-    page_size: int,
-    k_cur: jnp.ndarray,
-    v_cur: jnp.ndarray,
-    layer: jnp.ndarray | None = None,
-    use_pallas: bool | None = None,
-    logit_softcap: float = 0.0,
-    window: jnp.ndarray | int = 0,
-    mesh=None,
-    tree_pos: jnp.ndarray | None = None,
-    tree_mask: jnp.ndarray | None = None,
-) -> jnp.ndarray:
-    """Batched multi-token decode attention — the speculative-verify step
-    (ISSUE 5): S slots × T candidate tokens each, attending the slot's
-    paged prefix plus the candidates before them. With
-    `tree_pos`/`tree_mask` the candidates form a token tree (ISSUE 18,
-    see paged_attention_verify_ref) — the per-slot chunk-kernel loop
-    cannot express an ancestor mask, so tree verify always takes the
-    batched reference here (the fused ragged kernel carries the tree
-    leg).
-
-    q: [S, T, H, D] (candidate queries, post-rope); k_cur/v_cur:
-    [S, T, KVH, D] (the candidates' fresh K/V, not yet in the pool);
-    lengths: [S] cached-prefix length per slot — candidate i of slot s
-    sits at absolute position lengths[s] + i. Returns [S, T, H, D].
-
-    Kernel path: per-slot dispatch through attention_prefix_chunk with
-    start = lengths[s] and total_len = lengths[s] + T — chunked prefill
-    against a cached prefix IS verify attention with every chunk row
-    valid, so the paged-prefix streaming kernel (runtime start/total
-    scalars, lane-padded pools, meshed shard_map) is reused wholesale;
-    the slot loop is static and T tiny (spec_k + 1). A fused
-    ragged-verify kernel (one grid over slots, the Ragged Paged Attention
-    shape) can replace the loop later without touching callers.
-
-    jnp path: ONE batched reference (vmap over slots of the dense prefix
-    gather) — tracing S separate chunk fallbacks per layer would bloat
-    CPU compiles S-fold for the same math.
-    """
-    t = q.shape[1]
-    use, interpret = _pallas_mode(use_pallas)
-    mode, _ax = kernel_mesh_axis(mesh, k_cur.shape[2], q.shape[2])
-    if tree_pos is not None:
-        record_kernel_path("attention_verify", False)
-        return paged_attention_verify_ref(
-            q, k_pages, v_pages, page_table, lengths, page_size, k_cur,
-            v_cur, layer=layer, logit_softcap=logit_softcap, window=window,
-            tree_pos=tree_pos, tree_mask=tree_mask,
-        )
-    if use and mode != "ref" and not isinstance(k_pages, QuantPages):
-        outs = [
-            attention_prefix_chunk(
-                q[i][None], k_pages, v_pages, page_table[i], lengths[i],
-                lengths[i] + t, page_size, k_cur=k_cur[i], v_cur=v_cur[i],
-                layer=layer, use_pallas=use_pallas,
-                logit_softcap=logit_softcap, window=window, mesh=mesh,
-            )
-            for i in range(q.shape[0])
-        ]
-        return jnp.concatenate(outs, axis=0)
-    record_kernel_path("attention_verify", False)
-    return paged_attention_verify_ref(
-        q, k_pages, v_pages, page_table, lengths, page_size, k_cur, v_cur,
-        layer=layer, logit_softcap=logit_softcap, window=window,
-    )
-
-
 def paged_attention_verify_ref(
     q: jnp.ndarray,
     k_pages: jnp.ndarray,
@@ -600,11 +276,18 @@ def paged_attention_verify_ref(
     tree_pos: jnp.ndarray | None = None,
     tree_mask: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    """Batched verify-attention reference: vmap over slots of the dense
+    """Batched multi-token decode attention — the speculative-verify step:
+    S slots × T candidate tokens each, attending the slot's paged prefix
+    plus the candidates before them. vmap over slots of the dense
     per-slot gather + candidate overlay + causal mask — the same math as
-    attention_prefix_chunk's fallback with start = lengths[s] and every
-    candidate row valid. Pools may be one layer [P, ps, KVH, D] or the
-    full [L, P, ps, KVH, D] stack with `layer` selecting (pass from
+    _prefix_chunk_ref with start = lengths[s] and every candidate row
+    valid.
+
+    q: [S, T, H, D] (candidate queries, post-rope); k_cur/v_cur:
+    [S, T, KVH, D] (the candidates' fresh K/V, not yet in the pool);
+    lengths: [S] cached-prefix length per slot — candidate i of slot s
+    sits at absolute position lengths[s] + i. Pools may be one layer
+    [P, ps, KVH, D] or the full [L, P, ps, KVH, D] stack with `layer` selecting (pass from
     inside a layer scan). Returns [S, T, H, D].
 
     Tree verify (ISSUE 18): with `tree_pos` ([T] i32 — node depths) and
@@ -619,8 +302,8 @@ def paged_attention_verify_ref(
     raggedness lives in the accept walk, not the mask, because node
     validity is ancestor-closed so a live query never attends a dead
     node. A chain (tree_pos = arange(T), tree_mask = lower-triangular)
-    produces the exact same mask as the legacy branch, but the legacy
-    trace is kept verbatim on a separate branch so chain spec stays
+    produces the exact same mask as the chain branch, but the chain
+    trace is kept on a separate branch so chain spec stays
     bit-identical."""
     s, t, h, d = q.shape
     tree = tree_pos is not None
@@ -722,12 +405,12 @@ def ragged_paged_attention(
     tree_pos: jnp.ndarray | None = None,
     tree_mask: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray | None, jnp.ndarray | None]:
-    """Unified ragged paged attention (ISSUE 6, Ragged Paged Attention
-    design): causal paged attention for a ragged token batch — one prefill
-    CHUNK region plus S fixed-stride per-slot GROUPS — in a single kernel
-    launch, replacing the three per-phase dispatchers
-    (attention_prefix_chunk / paged_attention_decode /
-    paged_attention_verify) and the per-slot Python loop verify used.
+    """Ragged paged attention (the Ragged Paged Attention design): causal
+    paged attention for a ragged token batch — one prefill CHUNK region
+    plus S fixed-stride per-slot GROUPS — in a single kernel launch. The
+    only dispatcher for the phases that read the page pool: chunked
+    prefill passes a chunk region, decode a group region with Td = 1,
+    verify a group region with Td = K+1, a mixed admission step both.
 
     Tree verify (ISSUE 18): `tree_pos` [Td] i32 + `tree_mask` [Td, Td]
     bool turn the GROUP region's Td tokens into a static-topology token
@@ -735,8 +418,8 @@ def ragged_paged_attention(
     The topology is a jit constant shared by every slot; the kernel
     carries it as two scalar-prefetch rows (depths + ancestor BITMASKS,
     one int32 per node — hence Td <= 32 on the kernel path, larger
-    budgets fall back to the jnp reference). The non-tree trace is
-    untouched — tree args absent compiles the exact pre-ISSUE-18 kernel.
+    budgets fall back to the jnp reference). Without the tree arguments
+    the kernel is compiled with no tree operands at all.
 
     Regions (either may be absent; descriptors are per-sequence
     `(query_len, context_len, page_table_row)` in the RPA sense):
@@ -749,7 +432,7 @@ def ragged_paged_attention(
       decode, K+1 for spec-verify) at positions group_lengths[s] + i
       against page_table[s]; fresh K/V k_group/v_group [S, Td, KVH, D]
       merged in-register. Slots with length 0 (inactive) compute garbage
-      cheaply — callers mask on `active`, matching the legacy ops.
+      cheaply — callers mask on `active`.
 
     Pools may be one layer [P, ps, KVH, D] or the full stack with `layer`
     selecting (pass from inside a layer scan). Returns (chunk_out,
@@ -761,16 +444,18 @@ def ragged_paged_attention(
     models keep the kernel path WITHOUT the 2x lane-padded pool when the
     per-shard (KVH*D) % 128 == 0: pages are stored unpadded (tile-aligned
     flat rows for the DMA) and lane-padded in-register at load — the
-    KV-bytes win /admin/memory itemizes. jnp path: the per-region
-    legacy references, shared verbatim, so greedy streams are
-    bit-identical ragged-on vs ragged-off on the fallback path.
+    KV-bytes win /admin/memory itemizes. `logit_softcap` (static) and
+    `window` (may be a traced per-layer scalar, gemma2 alternates) are
+    handled inside the kernel — windowed tiles never DMA pages below the
+    window. Under `mesh` the kernel runs in a full-manual shard_map with
+    kv heads split over tp, no collectives. jnp path:
+    ragged_paged_attention_ref, region by region.
     """
     some_q = q_chunk if q_chunk is not None else q_group
     d, dpool = some_q.shape[-1], k_pages.shape[-1]
     if dpool != d:
-        # lane-padded pool (legacy layout or KVH*D not lane-aligned):
-        # pad q/fresh-K/V at the boundary and slice back, exactly as the
-        # legacy dispatchers do
+        # lane-padded pool (KVH*D not lane-aligned): pad q/fresh-K/V at
+        # the boundary and slice back — exact, see _lane_pad_qkv
         if q_chunk is not None:
             q_chunk, k_chunk, v_chunk = _lane_pad_qkv(
                 q_chunk, k_chunk, v_chunk, dpool)
@@ -808,8 +493,8 @@ def ragged_paged_attention(
     chunk_ok = True
     if q_chunk is not None:
         c = q_chunk.shape[1]
-        # the chunk's fresh K/V stay VMEM-resident — same budget gate as
-        # attention_prefix_chunk (per shard under tp)
+        # the chunk's fresh K/V stay VMEM-resident: budget gate, per
+        # shard under tp
         chunk_ok = (
             c % min(128, c) == 0
             and 2 * c * kvh_local * d * q_chunk.dtype.itemsize
@@ -994,14 +679,13 @@ def ragged_paged_attention_ref(
     tree_pos: jnp.ndarray | None = None,
     tree_mask: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray | None, jnp.ndarray | None]:
-    """jnp reference for the unified ragged launch — the per-region
-    legacy references composed VERBATIM (the fallback leg of
+    """jnp reference for the ragged launch, composed from the per-region
+    references: the chunk region is _prefix_chunk_ref, a Td = 1 group is
+    paged_attention_decode_ref, a wider group is
+    paged_attention_verify_ref (its tree branch when
+    `tree_pos`/`tree_mask` are given). The fallback leg of
     ragged_paged_attention, and the oracle the KERNELS registry and the
-    numerics sanitizer hold the ragged kernel to). Greedy streams stay
-    bit-identical ragged-on vs ragged-off on the jnp path because each
-    region delegates to the exact legacy reference. Tree verify
-    (`tree_pos`/`tree_mask`, ISSUE 18) routes the group region through
-    paged_attention_verify_ref's tree branch."""
+    numerics sanitizer hold the ragged kernel to."""
     out_chunk = out_group = None
     if q_chunk is not None:
         out_chunk = _prefix_chunk_ref(
@@ -1019,8 +703,7 @@ def ragged_paged_attention_ref(
                 tree_pos=tree_pos, tree_mask=tree_mask,
             )
         elif td == 1:
-            # Td == 1 IS legacy decode — delegate to its reference so the
-            # ragged-on jnp path stays bit-identical to ragged-off decode
+            # Td == 1 is decode: its reference takes one layer's pool
             kp, vp = k_pages, v_pages
             if kp.ndim == 5:
                 li = jnp.int32(0) if layer is None else layer
@@ -1118,7 +801,8 @@ def paged_attention_decode_ref(
     tokens per slot *including* the current token (already written to the
     cache). With k_cur/v_cur ([S, KVH, D]), lengths counts the cached
     prefix only and the current token is overlaid at position lengths[s]
-    before attending (pool writes deferred — see paged_attention_decode).
+    before attending (the engine defers all pool writes to one all-layer
+    kernel after the layer scan, so the pool lags one token during decode).
     Returns [S, H, D].
 
     `logit_softcap`/`window` as in attention_prefill_ref (the current

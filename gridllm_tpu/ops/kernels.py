@@ -61,32 +61,15 @@ KERNELS: tuple[KernelSpec, ...] = (
                     "stream from HBM as a grid dimension",
     ),
     KernelSpec(
-        name="paged_decode",
-        reference="attention:paged_attention_decode_ref",
-        dispatch="attention_decode",
-        rtol=3e-2, atol=3e-2,
-        test="tests/test_pallas.py::test_paged_decode_matches_ref",
-        description="one-token decode attention against the HBM page "
-                    "pool, double-buffered page DMA",
-    ),
-    KernelSpec(
-        name="prefix_chunk",
-        reference="attention:_prefix_chunk_ref",
-        dispatch="attention_prefix_chunk",
-        rtol=3e-2, atol=3e-2,
-        test="tests/test_pallas.py::test_prefix_chunk_kernel_matches_jnp",
-        description="chunked-prefill attention: prefix pages streamed "
-                    "from HBM, the chunk's own K/V resident",
-    ),
-    KernelSpec(
         name="ragged_attention",
         reference="attention:ragged_paged_attention_ref",
         dispatch="attention_ragged",
         rtol=3e-2, atol=3e-2,
         test="tests/test_ragged_attention.py::"
              "test_ragged_kernel_mixed_batch_matches_ref",
-        description="unified ragged paged attention: one launch serving "
+        description="ragged paged attention: one launch serving "
                     "chunked prefill, decode, and spec-verify tiles "
+                    "against the HBM page pool, double-buffered page DMA "
                     "(int8 pools via the dequant epilogue)",
     ),
     KernelSpec(
@@ -110,16 +93,13 @@ KERNELS: tuple[KernelSpec, ...] = (
     ),
 )
 
-# Dispatch labels with NO kernel of their own: jnp-only dispatchers whose
-# kernel leg routes through another registered kernel (verify loops over
-# prefix_chunk per slot; write_multi flattens onto paged_write_decode).
+# Dispatch labels with NO kernel of their own: dispatchers whose kernel
+# leg routes through another registered kernel (write_multi flattens onto
+# paged_write_decode).
 # The kernel-parity rule requires the union of KERNELS dispatch labels
 # and this table to equal the set of record_kernel_path(...) literals in
 # ops/ exactly, both ways.
 EXTRA_DISPATCH_LABELS: dict[str, str] = {
-    "attention_verify": "per-slot loop over the prefix_chunk kernel "
-                        "(a fused tree-verify kernel can replace it "
-                        "without touching callers)",
     "write_multi": "multi-token append flattened onto paged_write_decode",
 }
 

@@ -200,7 +200,7 @@ def lane_pad_dim(d: int) -> int:
     class) so Mosaic's alignment constraint is met and decode/writes keep
     the kernel path; the attention/write dispatchers pad q/K/V to the
     pool's width and slice outputs back (exact — see
-    ops.attention.paged_attention_decode). Costs 2x KV memory on d=64
+    ops.attention._lane_pad_qkv). Costs 2x KV memory on d=64
     models, which are the smallest ones served."""
     return -(-d // 128) * 128
 

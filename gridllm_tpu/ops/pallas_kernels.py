@@ -1,9 +1,9 @@
 """Pallas TPU attention kernels (SURVEY.md §7 step 5: "paged KV cache +
 Pallas flash-attention kernel" is where the baseline metric is won).
 
-Two kernels, each with the pure-jnp implementation in ops/attention.py as
-its numerical oracle (tests/test_pallas.py runs both in interpret mode on
-CPU and asserts equality):
+Three attention kernels, each with the pure-jnp implementation in
+ops/attention.py as its numerical oracle (tests/test_pallas.py runs both
+in interpret mode on CPU and asserts equality):
 
 - `flash_prefill`: causal GQA flash attention over one prompt chunk.
   Grid (KVH, q-blocks); K/V for the grid's kv head stay VMEM-resident
@@ -12,9 +12,14 @@ CPU and asserts equality):
   dot_general(preferred_element_type=f32). Causal + length masking via
   broadcasted_iota — no materialized [T, T] mask.
 
-- `paged_decode`: one-token-per-slot decode attention directly against
-  the HBM page pool. Grid (slots,); the slot's page table row and length
-  are scalar-prefetched (PrefetchScalarGridSpec) so the kernel can DMA
+- `flash_prefill_streamed`: the same attention for buckets whose
+  per-head K+V exceed the VMEM budget — K/V stream from HBM as a grid
+  dimension instead of staying resident.
+
+- `ragged_attention`: every paged phase (chunked prefill, decode,
+  spec-verify, tree verify) directly against the HBM page pool, in one
+  launch. Grid over query-token tiles; page-table rows and lengths are
+  scalar-prefetched (PrefetchScalarGridSpec) so the kernel can DMA
   exactly the valid pages HBM→VMEM, double-buffered to overlap the next
   page's fetch with the current page's math. This is the "stream only
   valid pages" design the jnp oracle's gather materializes densely
@@ -319,502 +324,6 @@ def flash_prefill_streamed(
 
 
 # ---------------------------------------------------------------------------
-# paged decode
-# ---------------------------------------------------------------------------
-
-def _paged_decode_kernel(
-    layer_ref,   # SMEM prefetch: [2] [layer to read, sliding window (0=full)]
-    table_ref,   # SMEM prefetch: [S, maxp] page ids
-    len_ref,     # SMEM prefetch: [S] lengths (see paged_decode docstring)
-    q_ref,       # VMEM (1, H, D) — this slot's query
-    k_hbm,       # ANY  [L, P, ps, KVH, D] — the FULL page pool, stays in HBM
-    v_hbm,
-    kc_ref,      # VMEM (1, KVH, D) — this slot's CURRENT token K (merge_cur)
-    vc_ref,
-    o_ref,       # VMEM (1, H, D)
-    k_scr,       # VMEM (2, ps, KVH, D) double buffer
-    v_scr,
-    sems,        # DMA sems (2, 2): [buffer, k/v]
-    *, ps: int, kvh: int, g: int, d: int, merge_cur: bool, softcap: float,
-):
-    s = pl.program_id(0)
-    layer = layer_ref[0]
-    window = layer_ref[1]
-    length = len_ref[s]
-    # the query's absolute position: prefix-only lengths put the current
-    # token AT `length` (merge_cur); otherwise it is already in the pool
-    # at length-1
-    qpos = length if merge_cur else length - 1
-    # clamp to the table width: pipelined decode blocks can push a
-    # finished slot's device-side length past its capacity (host finishes
-    # the slot while in-flight blocks still count it active); the page_no
-    # lookup must never index past the table row
-    n_pages = jnp.minimum(
-        pl.cdiv(jnp.maximum(length, 1), ps), table_ref.shape[1]
-    )
-    scale = jax.lax.rsqrt(jnp.float32(d))
-    q = (q_ref[0].reshape(kvh, g, d).astype(jnp.float32) * scale)
-
-    # indexing the layer INSIDE the DMA (rather than slicing the pool in
-    # the caller's scan body) avoids XLA materializing a per-layer pool
-    # copy per scan iteration — the pool never moves, only pages do
-    def k_dma(slot, page_no):
-        page = jnp.maximum(table_ref[s, page_no], 0)
-        return pltpu.make_async_copy(
-            k_hbm.at[layer, page], k_scr.at[slot], sems.at[slot, 0]
-        )
-
-    def v_dma(slot, page_no):
-        page = jnp.maximum(table_ref[s, page_no], 0)
-        return pltpu.make_async_copy(
-            v_hbm.at[layer, page], v_scr.at[slot], sems.at[slot, 1]
-        )
-
-    # pages the loop will actually visit: in merge_cur mode a length-0
-    # (inactive) slot skips the loop entirely. The initial DMA start MUST
-    # be guarded by the same bound — an async copy that is started but
-    # never waited leaves its semaphore signalled into the NEXT grid
-    # iteration (scratch + semaphores persist across grid steps on TPU),
-    # corrupting every later slot's double-buffer handshake. Interpret
-    # mode completes copies synchronously and never sees this; real
-    # Mosaic dies with an opaque device error (round-4 TPU bench crash).
-    n_eff = jnp.where(length > 0, n_pages, 0) if merge_cur else n_pages
-    # sliding window: pages whose every row is out of the window are never
-    # visited — loop (and DMA) start at the window's first page
-    p0 = jnp.where(
-        window > 0, jnp.maximum(qpos - window + 1, 0) // ps, 0
-    )
-    p0 = jnp.minimum(p0, n_eff)  # degenerate slots: keep bounds sane
-
-    @pl.when(n_eff > p0)
-    def _():
-        k_dma(0, p0).start()
-        v_dma(0, p0).start()
-
-    def body(p, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(p - p0, 2)
-
-        @pl.when(p + 1 < n_eff)
-        def _():
-            nxt = jax.lax.rem(p + 1 - p0, 2)
-            k_dma(nxt, p + 1).start()
-            v_dma(nxt, p + 1).start()
-
-        k_dma(slot, p).wait()
-        v_dma(slot, p).wait()
-        k_page = k_scr[slot]  # [ps, KVH, D]
-        v_page = v_scr[slot]
-
-        # per-kv-head 2D dots, unrolled over the (static, small) KVH —
-        # Mosaic's tpu.matmul requires lhs/rhs batch dims in the same
-        # position, which the [KVH,G,D]x[ps,KVH,D] batched form violates
-        logits = jnp.stack([
-            jax.lax.dot_general(
-                q[h], k_page[:, h, :].astype(jnp.float32),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            for h in range(kvh)
-        ])  # [KVH, G, ps]
-        if softcap:  # gemma2: tanh capping BEFORE masking
-            logits = softcap * jnp.tanh(logits / softcap)
-        pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, (kvh, g, ps), 2)
-        valid = (pos < length) & (
-            (window <= 0) | (qpos - pos < window)
-        )
-        logits = jnp.where(valid, logits, _NEG_INF)
-
-        m_new = jnp.maximum(m, logits.max(axis=2, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        prob = jnp.exp(logits - m_new)
-        l_new = l * alpha + prob.sum(axis=2, keepdims=True)
-        acc_new = acc * alpha + jnp.stack([
-            jax.lax.dot_general(
-                prob[h], v_page[:, h, :].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            for h in range(kvh)
-        ])
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((kvh, g, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((kvh, g, 1), jnp.float32)
-    acc0 = jnp.zeros((kvh, g, d), jnp.float32)
-    if merge_cur:
-        # `length` counts the PREFIX only; the current token's K/V arrive
-        # via kc/vc (not yet written to the pool — the engine writes all
-        # layers at once after the layer scan). length == 0 (fresh slot
-        # with empty pool) skips the page loop entirely (n_eff == 0; the
-        # initial DMA start above is guarded by the same bound).
-        m, l, acc = jax.lax.fori_loop(p0, n_eff, body, (m0, l0, acc0))
-        # online-softmax merge of the single current-token column. The
-        # current token's K is scaled along with q (q already carries
-        # 1/sqrt(d)), matching the in-pool keys.
-        kc = kc_ref[0].astype(jnp.float32)              # [KVH, D]
-        vc = vc_ref[0].astype(jnp.float32)
-        logit_c = (q * kc[:, None, :]).sum(-1, keepdims=True)  # [KVH, G, 1]
-        if softcap:  # same capping as the in-pool columns (oracle parity)
-            logit_c = softcap * jnp.tanh(logit_c / softcap)
-        m_new = jnp.maximum(m, logit_c)
-        alpha = jnp.exp(m - m_new)
-        p_c = jnp.exp(logit_c - m_new)
-        l = l * alpha + p_c
-        acc = acc * alpha + p_c * vc[:, None, :]
-        out = acc / jnp.maximum(l, 1e-30)
-    else:
-        _, l, acc = jax.lax.fori_loop(p0, n_pages, body, (m0, l0, acc0))
-        out = acc / jnp.maximum(l, 1e-30)
-    o_ref[0] = out.reshape(kvh * g, d).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("page_size", "interpret", "softcap"))
-def paged_decode(
-    q: jnp.ndarray,
-    k_pages: jnp.ndarray,
-    v_pages: jnp.ndarray,
-    page_table: jnp.ndarray,
-    lengths: jnp.ndarray,
-    page_size: int,
-    k_cur: jnp.ndarray | None = None,
-    v_cur: jnp.ndarray | None = None,
-    layer: jnp.ndarray | None = None,
-    interpret: bool = False,
-    softcap: float = 0.0,
-    window: jnp.ndarray | int = 0,
-) -> jnp.ndarray:
-    """Same contract as ops.attention.paged_attention_decode incl.
-    softcap/window (gemma2/mistral): q [S, H, D],
-    pools [P, ps, KVH, D] (or [L, P, ps, KVH, D] with `layer` selecting
-    which layer to read — pass the FULL pool from inside a layer scan so
-    no per-layer pool slice is ever materialized), page_table [S, maxp]
-    → [S, H, D]. Reads only valid pages.
-
-    Two modes (matching the oracle):
-    - k_cur/v_cur None: `lengths` includes the already-written current
-      token; attention runs purely over the pool.
-    - k_cur/v_cur [S, KVH, D]: `lengths` counts the PREFIX only; the
-      current token's K/V are merged in-register via one extra
-      online-softmax step (the engine writes all layers' K/V into the pool
-      once per step, after the layer scan — so the pool lags one token).
-
-    Slots with length 0 (inactive) compute garbage rows cheaply — callers
-    mask on `active`, matching the oracle. With a sliding window, pages
-    fully below the window are never DMA'd — windowed decode reads
-    O(window) context regardless of length.
-    """
-    s, h, d = q.shape
-    if k_pages.ndim == 4:
-        k_pages = k_pages[None]
-        v_pages = v_pages[None]
-    if layer is None:
-        layer = jnp.int32(0)
-    kvh = k_pages.shape[3]
-    g = h // kvh
-    merge_cur = k_cur is not None
-    if not merge_cur:
-        k_cur = jnp.zeros((s, kvh, d), k_pages.dtype)
-        v_cur = jnp.zeros((s, kvh, d), v_pages.dtype)
-
-    kernel = functools.partial(
-        _paged_decode_kernel, ps=page_size, kvh=kvh, g=g, d=d,
-        merge_cur=merge_cur, softcap=softcap,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(s,),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda i, *_: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, kvh, d), lambda i, *_: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, kvh, d), lambda i, *_: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda i, *_: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, page_size, kvh, d), k_pages.dtype),
-            pltpu.VMEM((2, page_size, kvh, d), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, h, d), q.dtype),
-        interpret=interpret,
-    )(jnp.stack([jnp.asarray(layer, jnp.int32).reshape(()),
-                 jnp.asarray(window, jnp.int32).reshape(())]),
-      page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pages, v_pages, k_cur, v_cur)
-
-
-# ---------------------------------------------------------------------------
-# chunked-prefill attention against the paged prefix
-# ---------------------------------------------------------------------------
-
-
-def _prefix_chunk_kernel(
-    scal_ref,    # SMEM prefetch [4]: [layer, window (0=full), start, total]
-    table_ref,   # SMEM prefetch [maxp]: this slot's page ids
-    q_ref,       # VMEM (BQ, KVH, G, D) — this q block
-    kc_ref,      # VMEM (C, KVH, D) — the WHOLE chunk's K (resident)
-    vc_ref,
-    k_hbm,       # ANY [L, P, ps, KVH, D] — the full page pool
-    v_hbm,
-    o_ref,       # VMEM (BQ, KVH, G, D)
-    k_scr,       # VMEM (2, ps, KVH, D) double buffer (prefix pages)
-    v_scr,
-    sems,        # DMA sems (2, 2)
-    *, ps: int, bq: int, bk: int, kvh: int, g: int, d: int,
-    softcap: float,
-):
-    """Two-phase online softmax per q block: (1) stream the slot's PREFIX
-    pages HBM→VMEM double-buffered (same DMA discipline as
-    _paged_decode_kernel — every conditional start is guarded by the same
-    bound as its wait); (2) the chunk's own K/V blocks from VMEM with
-    causal masking. Positions: q row r of block qi is absolute
-    start + qi*BQ + r; prefix rows are absolute [0, start); chunk K rows
-    are absolute start + [0, C) with rows ≥ total (= start + valid)
-    masked."""
-    qi = pl.program_id(0)
-    window = scal_ref[1]
-    start = scal_ref[2]
-    total = scal_ref[3]
-    scale = jax.lax.rsqrt(jnp.float32(d))
-    q = q_ref[...].astype(jnp.float32) * scale     # [BQ, KVH, G, D]
-
-    q_rel = qi * bq + jax.lax.broadcasted_iota(
-        jnp.int32, (kvh, bq * g, 1), 1
-    ) // g                                          # chunk-relative q pos
-    q_abs = start + q_rel
-
-    layer = scal_ref[0]
-
-    def k_dma(slot, page_no):
-        page = jnp.maximum(table_ref[page_no], 0)
-        return pltpu.make_async_copy(
-            k_hbm.at[layer, page], k_scr.at[slot], sems.at[slot, 0]
-        )
-
-    def v_dma(slot, page_no):
-        page = jnp.maximum(table_ref[page_no], 0)
-        return pltpu.make_async_copy(
-            v_hbm.at[layer, page], v_scr.at[slot], sems.at[slot, 1]
-        )
-
-    # prefix pages: [0, start) — ceil so a partial last page is visited
-    # (its rows ≥ start are masked); with a sliding window, pages fully
-    # below this q block's lowest window edge are never DMA'd
-    n_pref = jnp.minimum(
-        pl.cdiv(jnp.maximum(start, 0), ps), table_ref.shape[0]
-    )
-    p0 = jnp.where(
-        window > 0, jnp.maximum(start + qi * bq - window + 1, 0) // ps, 0
-    )
-    p0 = jnp.minimum(p0, n_pref)
-
-    @pl.when(n_pref > p0)
-    def _():
-        k_dma(0, p0).start()
-        v_dma(0, p0).start()
-
-    def pref_body(p, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(p - p0, 2)
-
-        @pl.when(p + 1 < n_pref)
-        def _():
-            nxt = jax.lax.rem(p + 1 - p0, 2)
-            k_dma(nxt, p + 1).start()
-            v_dma(nxt, p + 1).start()
-
-        k_dma(slot, p).wait()
-        v_dma(slot, p).wait()
-        k_page = k_scr[slot]                        # [ps, KVH, D]
-        v_page = v_scr[slot]
-
-        logits = jnp.stack([
-            jax.lax.dot_general(
-                q[:, h].reshape(bq * g, d),
-                k_page[:, h, :].astype(jnp.float32),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            for h in range(kvh)
-        ])                                          # [KVH, BQ*G, ps]
-        if softcap:
-            logits = softcap * jnp.tanh(logits / softcap)
-        pos = p * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (kvh, bq * g, ps), 2
-        )
-        valid = (pos < start) & (
-            (window <= 0) | (q_abs - pos < window)
-        )
-        logits = jnp.where(valid, logits, _NEG_INF)
-
-        m_new = jnp.maximum(m, logits.max(axis=2, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        prob = jnp.exp(logits - m_new)
-        l_new = l * alpha + prob.sum(axis=2, keepdims=True)
-        acc_new = acc * alpha + jnp.stack([
-            jax.lax.dot_general(
-                prob[h], v_page[:, h, :].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            for h in range(kvh)
-        ])
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((kvh, bq * g, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((kvh, bq * g, 1), jnp.float32)
-    acc0 = jnp.zeros((kvh, bq * g, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(p0, n_pref, pref_body, (m0, l0, acc0))
-
-    # phase 2: the chunk's own K/V — causal within the chunk
-    nkb = pl.cdiv((qi + 1) * bq, bk)
-    kb0 = jnp.where(
-        window > 0, jnp.maximum(qi * bq - window + 1, 0) // bk, 0
-    )
-    kb0 = jnp.minimum(kb0, nkb)
-
-    def chunk_body(kb, carry):
-        m, l, acc = carry
-        k_blk = kc_ref[pl.ds(kb * bk, bk)]          # [BK, KVH, D]
-        v_blk = vc_ref[pl.ds(kb * bk, bk)]
-        logits = jnp.stack([
-            jax.lax.dot_general(
-                q[:, h].reshape(bq * g, d),
-                k_blk[:, h, :].astype(jnp.float32),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            for h in range(kvh)
-        ])                                          # [KVH, BQ*G, BK]
-        if softcap:
-            logits = softcap * jnp.tanh(logits / softcap)
-        krel = kb * bk + jax.lax.broadcasted_iota(
-            jnp.int32, (kvh, bq * g, bk), 2
-        )
-        dist = q_rel - krel
-        valid = (dist >= 0) & (start + krel < total) & (
-            (window <= 0) | (dist < window)
-        )
-        logits = jnp.where(valid, logits, _NEG_INF)
-
-        m_new = jnp.maximum(m, logits.max(axis=2, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        prob = jnp.exp(logits - m_new)
-        l_new = l * alpha + prob.sum(axis=2, keepdims=True)
-        acc_new = acc * alpha + jnp.stack([
-            jax.lax.dot_general(
-                prob[h], v_blk[:, h, :].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            for h in range(kvh)
-        ])
-        return m_new, l_new, acc_new
-
-    _, l, acc = jax.lax.fori_loop(kb0, nkb, chunk_body, (m, l, acc))
-    out = acc / jnp.maximum(l, 1e-30)               # [KVH, BQ*G, D]
-    o_ref[...] = (
-        out.reshape(kvh, bq, g, d).transpose(1, 0, 2, 3).astype(o_ref.dtype)
-    )
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("page_size", "interpret", "softcap"))
-def prefix_chunk(
-    q: jnp.ndarray,
-    k_pages: jnp.ndarray,
-    v_pages: jnp.ndarray,
-    table_row: jnp.ndarray,
-    start: jnp.ndarray,
-    total_len: jnp.ndarray,
-    page_size: int,
-    k_cur: jnp.ndarray,
-    v_cur: jnp.ndarray,
-    layer: jnp.ndarray | None = None,
-    interpret: bool = False,
-    softcap: float = 0.0,
-    window: jnp.ndarray | int = 0,
-) -> jnp.ndarray:
-    """Kernel form of ops.attention.attention_prefix_chunk (k_cur mode):
-    one chunk of queries [1, C, H, D] against the slot's full cached
-    context — prefix K/V streamed from the page pool page-by-page
-    (double-buffered DMA), the chunk's own K/V ([C, KVH, D], not yet in
-    the pool) VMEM-resident with causal masking. `start` is the absolute
-    position of q[0]; `total_len` = start + valid rows in this chunk.
-    This keeps >prefill_chunk prompts on the kernel path (VERDICT r04 #5)
-    — the jnp fallback gathers the whole prefix densely per layer.
-    """
-    _, c, h, d = q.shape
-    if k_pages.ndim == 4:
-        k_pages = k_pages[None]
-        v_pages = v_pages[None]
-    if layer is None:
-        layer = jnp.int32(0)
-    kvh = k_pages.shape[3]
-    g = h // kvh
-    bq = min(128, c)
-    bk = min(128, c)
-    assert c % bq == 0 and c % bk == 0, (c, bq, bk)
-
-    kernel = functools.partial(
-        _prefix_chunk_kernel, ps=page_size, bq=bq, bk=bk, kvh=kvh,
-        g=g, d=d, softcap=softcap,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(c // bq,),
-        in_specs=[
-            pl.BlockSpec((bq, kvh, g, d), lambda i, *_: (i, 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((c, kvh, d), lambda i, *_: (0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((c, kvh, d), lambda i, *_: (0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((bq, kvh, g, d), lambda i, *_: (i, 0, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, page_size, kvh, d), k_pages.dtype),
-            pltpu.VMEM((2, page_size, kvh, d), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-    )
-    scal = jnp.stack([
-        jnp.asarray(layer, jnp.int32).reshape(()),
-        jnp.asarray(window, jnp.int32).reshape(()),
-        jnp.asarray(start, jnp.int32).reshape(()),
-        jnp.asarray(total_len, jnp.int32).reshape(()),
-    ])
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((c, kvh, g, d), q.dtype),
-        interpret=interpret,
-        # same working set as the ragged kernel's chunk tiles, whose
-        # budget also covers this kernel's sublane-padded q/out blocks
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_ragged_vmem_limit(
-                page_size, kvh, g, d, bq, c, 0, q.dtype.itemsize,
-                k_pages.dtype.itemsize)),
-    )(scal, table_row.astype(jnp.int32), q[0].reshape(c, kvh, g, d),
-      k_cur, v_cur, k_pages, v_pages)
-    return out.reshape(1, c, h, d)
-
-
-# ---------------------------------------------------------------------------
 # unified ragged paged attention (ISSUE 6)
 # ---------------------------------------------------------------------------
 
@@ -828,11 +337,10 @@ def _ragged_attn_kernel(
     """One grid over query-token tiles serving all phases at once
     (the Ragged Paged Attention shape): tiles [0, nct) are the prefill
     chunk's BQ-row blocks (prefix pages streamed HBM→VMEM double-buffered
-    + the chunk's own resident K/V, causally masked — the
-    _prefix_chunk_kernel math); tiles [nct, nct+S) are one slot each —
-    Td query rows (1 = decode, K+1 = spec-verify) against the slot's
-    paged context with the Td fresh K/V columns merged in-register (the
-    _paged_decode_kernel math generalized from 1 to Td tokens). The DMA
+    + the chunk's own resident K/V, causally masked); tiles
+    [nct, nct+S) are one slot each — Td query rows (1 = decode, K+1 =
+    spec-verify) against the slot's paged context with the Td fresh K/V
+    columns merged in-register (one extra online-softmax step). The DMA
     discipline is shared: every conditional start is guarded by the same
     bound as its wait (scratch + semaphores persist across grid steps)."""
     it = iter(refs)
@@ -875,8 +383,8 @@ def _ragged_attn_kernel(
     # flat-lane pools (d % 128 != 0, ISSUE 6): pages are STORED unpadded
     # (the KV-bytes win) and lane-padded here, in-register after the
     # load, so every dot still runs on 128-lane minors — numerically
-    # exact (zero lanes meet zero q lanes), same compute as the legacy
-    # lane-padded-pool kernels, half the HBM bytes/bandwidth
+    # exact (zero lanes meet zero q lanes), same compute as over a
+    # lane-padded pool, half the HBM bytes/bandwidth
     dp = -(-d // 128) * 128
 
     def _lp(x):
@@ -1221,12 +729,12 @@ def ragged_attention(
     """Kernel form of ops.attention.ragged_paged_attention: ONE launch,
     static grid (C/BQ chunk tiles + S group tiles) serving chunked
     prefill, decode (Td=1), and spec-verify (Td=K+1) at once. See the
-    dispatcher's docstring for the region contracts. Unlike the legacy
-    kernels this one accepts d < 128 pools when the PER-SHARD KVH*D is
-    lane-aligned: pages are STORED unpadded (contiguous [ps, KVH*D]-byte
-    rows, so the page DMA stays tile-aligned) and the loaded values are
-    zero-padded to 128 lanes in-register before every dot — same compute
-    as the lane-padded-pool kernels, half the HBM bytes/bandwidth."""
+    dispatcher's docstring for the region contracts. Accepts d < 128
+    pools when the PER-SHARD KVH*D is lane-aligned: pages are STORED
+    unpadded (contiguous [ps, KVH*D]-byte rows, so the page DMA stays
+    tile-aligned) and the loaded values are zero-padded to 128 lanes
+    in-register before every dot — same compute as over a lane-padded
+    pool, half the HBM bytes/bandwidth."""
     has_chunk = q_chunk is not None
     has_group = q_group is not None
     assert has_chunk or has_group

@@ -9,9 +9,9 @@ will re-derive from the token ids. The wire therefore carries:
 
 - a JSON header: format version, request/model identity, pool geometry
   (page size, layer/head/dim counts), dtype, kvLayout (``ragged`` pools
-  are UNPADDED — ISSUE 6 — while ``legacy`` kernel pools may be
-  lane-padded; the wire always carries the UNPADDED model head dim and
-  each side pads/slices to its own pool), the weight-quant mode (info
+  are UNPADDED while ``ragged-padded`` pools — and the ``legacy`` pools
+  of older peers — are lane-padded; the wire always carries the UNPADDED
+  model head dim and each side pads/slices to its own pool), the weight-quant mode (info
   only; KV bytes are the engine dtype either way), the token ids the
   pages cover, and a blake2b digest of the full payload;
 - a raw payload: K bytes then V bytes, each [L, n_pages, ps, KVH, D]

@@ -224,9 +224,6 @@ register_env("GRIDLLM_POOL_PAD", "0",
 register_env("GRIDLLM_PALLAS", "auto",
              "Pallas kernel policy: auto (TPU only), 1 (force on), "
              "0 (force off), interpret (CPU interpreter mode).")
-register_env("GRIDLLM_RAGGED_ATTN", "1",
-             "Unified ragged paged-attention kernel for prefill/decode/"
-             "verify; 0 restores the legacy per-phase dispatchers.")
 register_env("GRIDLLM_MOE_RAGGED", "auto",
              "MoE grouped-matmul via ragged_dot: auto (TPU only), "
              "1 (force on), 0 (dense fallback).")

@@ -21,6 +21,19 @@ from gridllm_tpu.utils.types import (
 )
 
 
+def ragged_decode(q, k_pool, v_pool, table, prefix, ps, k_cur, v_cur, **kw):
+    """Decode through the ragged entry: a group region with Td = 1 —
+    `prefix` cached tokens per slot plus the current token's fresh K/V."""
+    from gridllm_tpu.ops.attention import ragged_paged_attention
+
+    _, out = ragged_paged_attention(
+        k_pool, v_pool, ps, q_group=q[:, None], page_table=table,
+        group_lengths=prefix, k_group=k_cur[:, None], v_group=v_cur[:, None],
+        **kw,
+    )
+    return out[:, 0]
+
+
 def fast_config() -> SchedulerConfig:
     """Sub-second timers so failure-path tests run quickly."""
     return SchedulerConfig(

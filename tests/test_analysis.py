@@ -1172,6 +1172,35 @@ def test_self_run_is_clean():
     assert len(RULES) == 13, sorted(RULES)
 
 
+def test_live_tree_has_five_kernels_and_no_attention_switch():
+    """The KERNELS registry names exactly the public functions of
+    ops/pallas_kernels.py that launch a pl.pallas_call — five, with one
+    paged-attention kernel among them — and no knob selects a second
+    paged-attention path."""
+    import ast
+
+    from gridllm_tpu.ops.kernels import dispatch_labels, kernel_names
+    from gridllm_tpu.utils.config import ENV_VARS
+
+    tree = ast.parse(
+        (REPO_ROOT / "gridllm_tpu/ops/pallas_kernels.py").read_text())
+    launchers = {
+        fn.name for fn in tree.body
+        if isinstance(fn, ast.FunctionDef) and not fn.name.startswith("_")
+        and any(isinstance(n, ast.Attribute) and n.attr == "pallas_call"
+                for n in ast.walk(fn))
+    }
+    assert launchers == set(kernel_names()) == {
+        "flash_prefill", "flash_prefill_streamed", "ragged_attention",
+        "paged_write_decode", "paged_write_chunk",
+    }
+    assert {lb for lb in dispatch_labels() if lb.startswith("attention_")} \
+        == {"attention_prefill", "attention_ragged"}
+    # the switch that used to pick the dispatcher is gone, and no knob
+    # names attention at all
+    assert [n for n in ENV_VARS if "ATTN" in n or "ATTENTION" in n] == []
+
+
 def test_cli_exit_codes_and_json(tmp_path):
     env_table = _full_env_table()
     bad = make_repo(tmp_path / "bad", {"gridllm_tpu/mod.py": (

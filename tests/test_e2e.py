@@ -313,17 +313,10 @@ async def test_metrics_and_trace_through_real_engine(tiny_engine):
         assert free is not None and cached is not None
         assert free + cached == 64
         # kernel-vs-jnp dispatch counters (jnp fallback on the CPU backend)
-        # the decode plane's op is attention_ragged with the unified
-        # ragged kernel on (ISSUE 6, the default); the legacy ops
-        # (attention_verify with speculation, attention_decode without)
-        # appear only with GRIDLLM_RAGGED_ATTN=0 — any of the three
-        # proves the dispatch counters flow
+        # the decode plane's op is attention_ragged, the one
+        # paged-attention label — it proves the dispatch counters flow
         assert (
             'gridllm_kernel_dispatch_total{op="attention_ragged",path="jnp"}'
-            in text
-            or 'gridllm_kernel_dispatch_total{op="attention_verify",path="jnp"}'
-            in text
-            or 'gridllm_kernel_dispatch_total{op="attention_decode",path="jnp"}'
             in text
         )
         # engine step/occupancy histograms populated

@@ -159,8 +159,8 @@ def test_chunked_prefill_matches_single_shot():
     single = InferenceEngine(EngineConfig(**TINY, prefill_chunk=64))
     opts = {"temperature": 0.0, "num_predict": 6}
 
-    # the chunk program: the ragged mixed step (ISSUE 6) when ragged
-    # attention is on, the legacy per-chunk prefill otherwise
+    # the chunk program: the mixed step where the family has one, the
+    # per-chunk prefill otherwise (pipeline)
     chunk_fn = (chunked._mixed_chunk_fn if chunked._use_mixed
                 else chunked._prefill_chunk_fn)
 

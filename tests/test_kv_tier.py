@@ -314,7 +314,6 @@ def test_lane_padded_pool_spill_restore(monkeypatch):
     migration wire."""
     monkeypatch.setenv("GRIDLLM_PALLAS", "interpret")
     monkeypatch.setenv("GRIDLLM_POOL_PAD", "1")
-    monkeypatch.setenv("GRIDLLM_RAGGED_ATTN", "0")
     from gridllm_tpu.ops.kvcache import _env_mode
 
     _env_mode.cache_clear()
@@ -357,7 +356,7 @@ def test_int8_attention_close_to_fp():
     within the per-row quant error's reach of the fp output."""
     import jax.numpy as jnp
 
-    from gridllm_tpu.ops.attention import paged_attention_decode
+    from gridllm_tpu.ops.attention import ragged_paged_attention
 
     L, P, ps, kvh, d, s = 2, 6, 8, 2, 16, 3
     rng = np.random.default_rng(0)
@@ -376,10 +375,11 @@ def test_int8_attention_close_to_fp():
     kc = jnp.asarray(rng.normal(size=(s, kvh, d)), jnp.float32)
     vc = jnp.asarray(rng.normal(size=(s, kvh, d)), jnp.float32)
     li = jnp.int32(1)
-    of = paged_attention_decode(q, kf, vf, pt, lengths, ps, k_cur=kc,
-                                v_cur=vc, layer=li, use_pallas=False)
-    oq = paged_attention_decode(q, kq, vq, pt, lengths, ps, k_cur=kc,
-                                v_cur=vc, layer=li, use_pallas=False)
+    group = dict(q_group=q[:, None], page_table=pt, group_lengths=lengths,
+                 k_group=kc[:, None], v_group=vc[:, None], layer=li,
+                 use_pallas=False)
+    _, of = ragged_paged_attention(kf, vf, ps, **group)
+    _, oq = ragged_paged_attention(kq, vq, ps, **group)
     assert float(jnp.abs(of - oq).max()) < 0.05
 
 

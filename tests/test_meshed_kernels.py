@@ -23,8 +23,8 @@ import pytest
 from gridllm_tpu.ops.attention import (
     attention_prefill,
     attention_prefill_ref,
-    paged_attention_decode,
     paged_attention_decode_ref,
+    ragged_paged_attention,
 )
 from gridllm_tpu.ops.kvcache import (
     kernel_mesh_axis,
@@ -32,6 +32,7 @@ from gridllm_tpu.ops.kvcache import (
     write_prefill_all,
 )
 from gridllm_tpu.parallel.mesh import MeshConfig, build_mesh
+from tests.helpers import ragged_decode
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs the 8-device CPU mesh"
@@ -50,6 +51,11 @@ def _interpret_kernels(monkeypatch):
 
 def _mesh(tp=4, dp=2, sp=1, ep=1):
     return build_mesh(MeshConfig(tp=tp, dp=dp, sp=sp, ep=ep))
+
+
+def _decode(q, kp, vp, pt, lens, kc, vc, **kw):
+    return ragged_decode(q, kp, vp, pt, lens, PS, kc, vc, use_pallas=True,
+                         **kw)
 
 
 L, NP, PS, MPS = 3, 24, 16, 6
@@ -83,10 +89,8 @@ def test_meshed_decode_matches_ref():
     kp, vp, pt, lens, q, kc, vc = _decode_operands()
 
     def f(q, kp, vp, pt, lens, kc, vc):
-        return paged_attention_decode(
-            q, kp, vp, pt, lens, PS, k_cur=kc, v_cur=vc,
-            layer=jnp.int32(1), use_pallas=True, mesh=mesh,
-        )
+        return _decode(q, kp, vp, pt, lens, kc, vc,
+                       layer=jnp.int32(1), mesh=mesh)
 
     out = jax.jit(f)(q, kp, vp, pt, lens, kc, vc)
     ref = paged_attention_decode_ref(
@@ -102,10 +106,8 @@ def test_meshed_decode_indivisible_heads_replicates():
     kp, vp, pt, lens, q, kc, vc = _decode_operands(kvh=2, h=4)
 
     def f(q, kp, vp, pt, lens, kc, vc):
-        return paged_attention_decode(
-            q, kp, vp, pt, lens, PS, k_cur=kc, v_cur=vc,
-            layer=jnp.int32(2), use_pallas=True, mesh=mesh,
-        )
+        return _decode(q, kp, vp, pt, lens, kc, vc,
+                       layer=jnp.int32(2), mesh=mesh)
 
     out = jax.jit(f)(q, kp, vp, pt, lens, kc, vc)
     ref = paged_attention_decode_ref(
@@ -121,11 +123,9 @@ def test_meshed_decode_traced_window_softcap():
     kp, vp, pt, lens, q, kc, vc = _decode_operands()
 
     def f(q, kp, vp, pt, lens, kc, vc, win):
-        return paged_attention_decode(
-            q, kp, vp, pt, lens, PS, k_cur=kc, v_cur=vc,
-            layer=jnp.int32(0), use_pallas=True, mesh=mesh,
-            logit_softcap=50.0, window=win,
-        )
+        return _decode(q, kp, vp, pt, lens, kc, vc,
+                       layer=jnp.int32(0), mesh=mesh,
+                       logit_softcap=50.0, window=win)
 
     win = jnp.int32(32)
     out = jax.jit(f)(q, kp, vp, pt, lens, kc, vc, win)
@@ -259,7 +259,8 @@ def test_meshed_engine_generates_with_kernels():
 
 
 def test_meshed_prefix_chunk_matches_ref():
-    """The chunk-prefill kernel through the full-manual tp shard_map."""
+    """The ragged kernel's chunk region through the full-manual tp
+    shard_map."""
     mesh = _mesh()
     t, ps, maxp = 16, 8, 6
     kp = jax.random.normal(jax.random.PRNGKey(0), (L, NP, PS, KVH, D),
@@ -271,16 +272,17 @@ def test_meshed_prefix_chunk_matches_ref():
     vc = jax.random.normal(jax.random.PRNGKey(3), (t, KVH, D), jnp.float32)
     start, total = jnp.int32(PS), jnp.int32(PS + 12)
 
-    from gridllm_tpu.ops.attention import attention_prefix_chunk
+    from gridllm_tpu.ops.attention import _prefix_chunk_ref
 
     got = jax.jit(
-        lambda q, kp, vp, row, start, total, kc, vc: attention_prefix_chunk(
-            q, kp, vp, row, start, total, PS, k_cur=kc, v_cur=vc,
+        lambda q, kp, vp, row, start, total, kc, vc: ragged_paged_attention(
+            kp, vp, PS, q_chunk=q, chunk_row=row, chunk_start=start,
+            chunk_total=total, k_chunk=kc, v_chunk=vc,
             layer=jnp.int32(1), use_pallas=True, mesh=mesh,
-        )
+        )[0]
     )(q, kp, vp, row, start, total, kc, vc)
-    want = attention_prefix_chunk(
+    want = _prefix_chunk_ref(
         q, kp, vp, row, start, total, PS, k_cur=kc, v_cur=vc,
-        layer=jnp.int32(1), use_pallas=False,
+        layer=jnp.int32(1),
     )
     np.testing.assert_allclose(got[:, :12], want[:, :12], atol=2e-5)

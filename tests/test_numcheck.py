@@ -40,12 +40,12 @@ def test_shadow_within_tolerance_is_clean(armed):
 def test_shadow_past_tolerance_records_violation(armed):
     @jax.jit
     def f(x):
-        return numcheck.shadow("attention_decode", x, lambda: x + 0.5)
+        return numcheck.shadow("attention_ragged", x, lambda: x + 0.5)
 
     jax.block_until_ready(f(jnp.ones((4,))))
     v = numcheck.violations()
     assert len(v) == 1 and v[0]["kind"] == "tolerance"
-    assert v[0]["op"] == "attention_decode"
+    assert v[0]["op"] == "attention_ragged"
     assert v[0]["excess"] > 0 and v[0]["max_err"] == pytest.approx(0.5)
     with pytest.raises(numcheck.NumericsError):
         numcheck.assert_clean()
@@ -121,7 +121,7 @@ def test_sampling_determinism_under_seeding():
         # a different op draws an independent stream from the same seed,
         # and a different seed changes the sequence
         numcheck.configure(sample=0.3, seed=1234)
-        other_op = [numcheck._decide("attention_decode") for _ in range(64)]
+        other_op = [numcheck._decide("attention_prefill") for _ in range(64)]
         numcheck.configure(sample=0.3, seed=4321)
         other_seed = [numcheck._decide("attention_ragged") for _ in range(64)]
         assert first != other_op

@@ -15,12 +15,13 @@ from gridllm_tpu.ops import (
     SamplingParams,
     apply_rope,
     attention_prefill,
-    paged_attention_decode,
     precompute_rope,
     rms_norm,
     sample_tokens,
 )
+from gridllm_tpu.ops.attention import ragged_paged_attention
 from gridllm_tpu.ops.kvcache import PageAllocator, write_decode, write_prefill
+from tests.helpers import ragged_decode as _decode
 
 
 def ref_attention(q, k, v, causal=True):
@@ -136,8 +137,13 @@ class TestAttention:
         table = cache.page_table.at[0].set(row)
         q_last = jnp.asarray(q_all[t - 1 : t])  # [1, H, D] → use as slot 0
         q_batch = jnp.concatenate([q_last, jnp.zeros_like(q_last)], axis=0)
-        out = paged_attention_decode(
-            q_batch, kp, vp, table, jnp.array([t, 0], jnp.int32), ps
+        # token T's K/V ride in fresh (the pool's copy of it is past the
+        # prefix and never read); slot 1 is inactive
+        cur = lambda x: jnp.concatenate(
+            [jnp.asarray(x[t - 1 : t]), jnp.zeros((1, kvh, d), jnp.float32)])
+        out = _decode(
+            q_batch, kp, vp, table, jnp.array([t - 1, 0], jnp.int32), ps,
+            cur(k_all), cur(v_all),
         )
         want = ref_attention(q_all, k_all, v_all)[t - 1]
         np.testing.assert_allclose(np.asarray(out)[0], want, rtol=1e-4, atol=1e-5)
@@ -161,7 +167,8 @@ class TestAttention:
                 jnp.array([i], jnp.int32), jnp.array([True]), ps,
             )
         q = rs.randn(1, 4, d).astype(np.float32)
-        out = paged_attention_decode(jnp.asarray(q), kp, vp, table, jnp.array([6], jnp.int32), ps)
+        out = _decode(jnp.asarray(q), kp, vp, table, jnp.array([5], jnp.int32), ps,
+                      jnp.asarray(ks[5])[None], jnp.asarray(vs[5])[None])
         want = ref_attention(
             q, np.stack(ks), np.stack(vs), causal=False
         )  # single query attends all 6
@@ -271,10 +278,7 @@ def test_decode_dispatch_on_lane_padded_pool_matches_unpadded_ref():
     unpadded pool."""
     import numpy as np
 
-    from gridllm_tpu.ops.attention import (
-        paged_attention_decode,
-        paged_attention_decode_ref,
-    )
+    from gridllm_tpu.ops.attention import paged_attention_decode_ref
 
     S, H, KVH, d, dpool = 3, 8, 4, 64, 128
     P_, ps, MPS = 16, 8, 4
@@ -290,10 +294,7 @@ def test_decode_dispatch_on_lane_padded_pool_matches_unpadded_ref():
     vc = jax.random.normal(jax.random.PRNGKey(4), (S, KVH, d), jnp.float32)
 
     # padded-pool dispatch, jnp path
-    got = paged_attention_decode(
-        q, kp_pad, vp_pad, pt, lens, ps, k_cur=kc, v_cur=vc,
-        use_pallas=False,
-    )
+    got = _decode(q, kp_pad, vp_pad, pt, lens, ps, kc, vc, use_pallas=False)
     want = paged_attention_decode_ref(q, kp, vp, pt, lens, ps, k_cur=kc, v_cur=vc)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -305,9 +306,7 @@ def test_decode_dispatch_on_lane_padded_pool_matches_unpadded_ref():
 
     kvcache._env_mode.cache_clear()
     try:
-        got_k = paged_attention_decode(
-            q, kp_pad, vp_pad, pt, lens, ps, k_cur=kc, v_cur=vc,
-        )
+        got_k = _decode(q, kp_pad, vp_pad, pt, lens, ps, kc, vc)
     finally:
         os.environ.pop("GRIDLLM_PALLAS", None)
         kvcache._env_mode.cache_clear()
@@ -316,8 +315,6 @@ def test_decode_dispatch_on_lane_padded_pool_matches_unpadded_ref():
 
 def test_prefix_chunk_on_lane_padded_pool_matches_unpadded():
     import numpy as np
-
-    from gridllm_tpu.ops.attention import attention_prefix_chunk
 
     T, H, KVH, d, dpool = 8, 8, 4, 64, 128
     P_, ps, MPS = 16, 8, 4
@@ -330,13 +327,11 @@ def test_prefix_chunk_on_lane_padded_pool_matches_unpadded():
     vc = jax.random.normal(jax.random.PRNGKey(4), (T, KVH, d), jnp.float32)
     start, total = jnp.int32(8), jnp.int32(8 + 6)
 
-    got = attention_prefix_chunk(
-        q, jnp.pad(kp, pad), jnp.pad(vp, pad), row, start, total, ps,
-        k_cur=kc, v_cur=vc,
-    )
-    want = attention_prefix_chunk(
-        q, kp, vp, row, start, total, ps, k_cur=kc, v_cur=vc,
-    )
+    chunk = dict(q_chunk=q, chunk_row=row, chunk_start=start,
+                 chunk_total=total, k_chunk=kc, v_chunk=vc)
+    got, _ = ragged_paged_attention(
+        jnp.pad(kp, pad), jnp.pad(vp, pad), ps, **chunk)
+    want, _ = ragged_paged_attention(kp, vp, ps, **chunk)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 

@@ -9,11 +9,51 @@ import pytest
 
 from gridllm_tpu.ops import attention
 from gridllm_tpu.ops.attention import (
+    _prefix_chunk_ref,
     attention_prefill_ref,
     paged_attention_decode_ref,
+    ragged_paged_attention,
 )
 from gridllm_tpu.ops.kvcache import PageAllocator, PagedKVCache, write_prefill
-from gridllm_tpu.ops.pallas_kernels import flash_prefill, paged_decode
+from gridllm_tpu.ops.pallas_kernels import flash_prefill, ragged_attention
+from tests.helpers import ragged_decode
+
+
+def _last_row_fresh(k_pool, v_pool, table, lengths, ps, layer=None):
+    """(prefix, k_cur, v_cur) for a case whose `lengths` count a current
+    token that is already in the pool: that row read back out of the pool,
+    and the prefix lengths - 1 — the same context, so the same reference."""
+    last = jnp.maximum(lengths - 1, 0)
+    page = jnp.take_along_axis(table, (last // ps)[:, None], axis=1)[:, 0]
+    kl, vl = (k_pool, v_pool) if k_pool.ndim == 4 else (
+        k_pool[layer], v_pool[layer])
+    return last, kl[page, last % ps], vl[page, last % ps]
+
+
+def _ragged_decode(q, k_pool, v_pool, table, lengths, ps, k_cur=None,
+                   v_cur=None, layer=None, softcap=0.0, window=0):
+    """Decode through the interpreted ragged kernel: a group region with
+    Td = 1. The kernel always merges the current token's fresh K/V
+    in-register; a case without them passes its last pool row as such."""
+    if k_cur is None:
+        lengths, k_cur, v_cur = _last_row_fresh(
+            k_pool, v_pool, table, lengths, ps, layer)
+    _, out = ragged_attention(
+        k_pool, v_pool, ps, q_group=q[:, None], page_table=table,
+        group_lengths=lengths, k_group=k_cur[:, None], v_group=v_cur[:, None],
+        layer=layer, interpret=True, softcap=softcap, window=window)
+    return out[:, 0]
+
+
+def _ragged_chunk(q, k_pool, v_pool, row, start, total, ps, k_cur, v_cur,
+                  layer=None, softcap=0.0, window=0):
+    """Chunked prefill through the interpreted ragged kernel: a chunk
+    region alone."""
+    out, _ = ragged_attention(
+        k_pool, v_pool, ps, q_chunk=q, chunk_row=row, chunk_start=start,
+        chunk_total=total, k_chunk=k_cur, v_chunk=v_cur, layer=layer,
+        interpret=True, softcap=softcap, window=window)
+    return out
 
 
 @pytest.mark.parametrize("t,h,kvh,d,lens", [
@@ -87,7 +127,7 @@ def _fill_pool(key, lens, page_size=8, kvh=2, d=16, maxp=8, num_pages=32):
     ([8, 17, 1, 30], 4),   # ragged multi-slot
     ([0, 12], 2),          # inactive slot present
 ])
-def test_paged_decode_matches_ref(lens, h):
+def test_ragged_decode_matches_ref(lens, h):
     kvh, d = 2, 16
     k_pool, v_pool, table, ps = _fill_pool(jax.random.PRNGKey(2), lens)
     s = len(lens)
@@ -95,7 +135,7 @@ def test_paged_decode_matches_ref(lens, h):
     lengths = jnp.asarray(lens, jnp.int32)
 
     want = paged_attention_decode_ref(q, k_pool, v_pool, table, lengths, ps)
-    got = paged_decode(q, k_pool, v_pool, table, lengths, ps, interpret=True)
+    got = _ragged_decode(q, k_pool, v_pool, table, lengths, ps)
     for i, ln in enumerate(lens):
         if ln == 0:
             continue  # inactive slots are unspecified in both impls
@@ -253,9 +293,8 @@ def test_paged_write_chunk_matches_scatter_valid_region(start, length):
             )
 
 
-def test_paged_decode_current_token_merge_matches_overlay():
-    """Kernel merge_cur mode == ref overlay mode == old written-pool mode."""
-    from gridllm_tpu.ops.pallas_kernels import paged_decode
+def test_ragged_decode_current_token_merge_matches_overlay():
+    """Kernel in-register merge == ref overlay mode == written-pool mode."""
     from gridllm_tpu.ops.kvcache import write_decode_all
 
     s, maxp, ps, kvh, d, num_pages, h = 3, 4, 8, 2, 16, 16, 4
@@ -281,18 +320,16 @@ def test_paged_decode_current_token_merge_matches_overlay():
     got_ref = paged_attention_decode_ref(
         q, kp, vp, table, prefix, ps, k_cur=kc, v_cur=vc
     )
-    got_kernel = paged_decode(
-        q, kp, vp, table, prefix, ps, k_cur=kc, v_cur=vc, interpret=True
+    got_kernel = _ragged_decode(
+        q, kp, vp, table, prefix, ps, k_cur=kc, v_cur=vc
     )
     np.testing.assert_allclose(np.asarray(got_ref), np.asarray(want), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(got_kernel), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
-def test_paged_decode_layer_indexed_pool():
-    """5D pool + layer index reads the right layer (kernel and ref)."""
-    from gridllm_tpu.ops.pallas_kernels import paged_decode
-    from gridllm_tpu.ops import attention
-
+def test_ragged_decode_layer_indexed_pool():
+    """5D pool + layer index reads the right layer (kernel and the
+    dispatcher's jnp leg)."""
     L, s, maxp, ps, kvh, d, num_pages, h = 3, 2, 2, 8, 2, 16, 8, 4
     q = jax.random.normal(jax.random.PRNGKey(1), (s, h, d), jnp.float32)
     kp = jax.random.normal(jax.random.PRNGKey(2), (L, num_pages, ps, kvh, d), jnp.float32)
@@ -301,19 +338,18 @@ def test_paged_decode_layer_indexed_pool():
     lens = jnp.asarray([12, 6], jnp.int32)
     for li in range(L):
         want = paged_attention_decode_ref(q, kp[li], vp[li], table, lens, ps)
-        got = paged_decode(q, kp, vp, table, lens, ps,
-                           layer=jnp.int32(li), interpret=True)
+        got = _ragged_decode(q, kp, vp, table, lens, ps, layer=jnp.int32(li))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
-        got2 = attention.paged_attention_decode(
-            q, kp, vp, table, lens, ps, layer=jnp.int32(li), use_pallas=False
-        )
+        # the dispatcher's jnp leg
+        last, kc, vc = _last_row_fresh(kp, vp, table, lens, ps, li)
+        got2 = ragged_decode(q, kp, vp, table, last, ps, kc, vc,
+                             layer=jnp.int32(li), use_pallas=False)
         np.testing.assert_allclose(np.asarray(got2), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
 def test_prefix_chunk_overlay_matches_written_pool():
-    """attention_prefix_chunk with k_cur overlay == chunk already written."""
+    """The chunk region with fresh K/V overlaid == chunk already written."""
     from gridllm_tpu.ops.kvcache import write_prefill_all
-    from gridllm_tpu.ops.attention import attention_prefix_chunk
 
     t, ps, kvh, d, num_pages, maxp, h = 16, 8, 2, 16, 16, 8, 4
     start, chunk_len = 8, 10
@@ -330,13 +366,12 @@ def test_prefix_chunk_overlay_matches_written_pool():
         kp[None], vp[None], kc[None], vc[None], row,
         jnp.int32(start), jnp.int32(chunk_len), ps, use_pallas=False,
     )
-    want = attention_prefix_chunk(
+    want = _prefix_chunk_ref(
         q, kp_w[0], vp_w[0], row, jnp.int32(start), total, ps,
-        use_pallas=False,
     )
-    got = attention_prefix_chunk(
-        q, kp, vp, row, jnp.int32(start), total, ps,
-        k_cur=kc, v_cur=vc, use_pallas=False,
+    got, _ = ragged_paged_attention(
+        kp, vp, ps, q_chunk=q, chunk_row=row, chunk_start=jnp.int32(start),
+        chunk_total=total, k_chunk=kc, v_chunk=vc, use_pallas=False,
     )
     np.testing.assert_allclose(
         np.asarray(got[:, :chunk_len]), np.asarray(want[:, :chunk_len]),
@@ -457,7 +492,7 @@ def test_flash_prefill_softcap_window_matches_ref(window, softcap):
     (16, 50.0, True),
     (1, 0.0, True),      # window 1: only the merged current token attends
 ])
-def test_paged_decode_softcap_window_matches_ref(window, softcap, merge):
+def test_ragged_decode_softcap_window_matches_ref(window, softcap, merge):
     lens = [5, 30, 17]
     kvh, d, h = 2, 16, 4
     k_pool, v_pool, table, ps = _fill_pool(jax.random.PRNGKey(11), lens)
@@ -472,9 +507,9 @@ def test_paged_decode_softcap_window_matches_ref(window, softcap, merge):
     want = paged_attention_decode_ref(
         q, k_pool, v_pool, table, lengths, ps, k_cur=kc, v_cur=vc,
         logit_softcap=softcap, window=window)
-    got = paged_decode(q, k_pool, v_pool, table, lengths, ps,
-                       k_cur=kc, v_cur=vc, interpret=True,
-                       softcap=softcap, window=window)
+    got = _ragged_decode(q, k_pool, v_pool, table, lengths, ps,
+                         k_cur=kc, v_cur=vc,
+                         softcap=softcap, window=window)
     for i in range(s):
         np.testing.assert_allclose(
             np.asarray(got[i]), np.asarray(want[i]), rtol=2e-5, atol=2e-5)
@@ -531,7 +566,7 @@ def test_flash_prefill_window_multiblock(window):
             rtol=2e-5, atol=2e-5)
 
 
-def test_paged_decode_window_skips_pages_multipage():
+def test_ragged_decode_window_skips_pages_multipage():
     """Slot long enough (60 tokens, 8/page) that a 16-token window makes
     p0 > 0 — the below-window pages are skipped entirely and the result
     still matches the full-gather oracle."""
@@ -546,14 +581,14 @@ def test_paged_decode_window_skips_pages_multipage():
         want = paged_attention_decode_ref(
             q, k_pool, v_pool, table, lengths, ps, k_cur=kc, v_cur=vc,
             window=window)
-        got = paged_decode(q, k_pool, v_pool, table, lengths, ps,
-                           k_cur=kc, v_cur=vc, interpret=True, window=window)
+        got = _ragged_decode(q, k_pool, v_pool, table, lengths, ps,
+                             k_cur=kc, v_cur=vc, window=window)
         np.testing.assert_allclose(
             np.asarray(got[0]), np.asarray(want[0]), rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------------------
-# prefix_chunk kernel (chunked prefill against the paged prefix)
+# the ragged kernel's chunk region (chunked prefill against the paged prefix)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("start,chunk_valid", [
@@ -562,11 +597,9 @@ def test_paged_decode_window_skips_pages_multipage():
     (32, 1),       # deep prefix, single valid row
 ])
 def test_prefix_chunk_kernel_matches_jnp(start, chunk_valid):
-    """pallas_kernels.prefix_chunk (interpret) == the jnp prefix-chunk
-    path, over a multi-page prefix + in-register chunk overlay."""
-    from gridllm_tpu.ops.attention import attention_prefix_chunk
-    from gridllm_tpu.ops.pallas_kernels import prefix_chunk
-
+    """The ragged kernel's chunk region (interpret) == the jnp
+    prefix-chunk reference, over a multi-page prefix + in-register chunk
+    overlay."""
     t, ps, kvh, d, num_pages, maxp, h = 16, 8, 2, 16, 16, 8, 4
     q = jax.random.normal(jax.random.PRNGKey(5), (1, t, h, d), jnp.float32)
     kc = jax.random.normal(jax.random.PRNGKey(6), (t, kvh, d), jnp.float32)
@@ -577,13 +610,11 @@ def test_prefix_chunk_kernel_matches_jnp(start, chunk_valid):
     vp = kp - 0.5
     total = jnp.int32(start + chunk_valid)
 
-    want = attention_prefix_chunk(
+    want = _prefix_chunk_ref(
         q, kp, vp, row, jnp.int32(start), total, ps, k_cur=kc, v_cur=vc,
-        use_pallas=False,
     )
-    got = prefix_chunk(
+    got = _ragged_chunk(
         q, kp, vp, row, jnp.int32(start), total, ps, k_cur=kc, v_cur=vc,
-        interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(got[:, :chunk_valid]), np.asarray(want[:, :chunk_valid]),
@@ -593,9 +624,6 @@ def test_prefix_chunk_kernel_matches_jnp(start, chunk_valid):
 
 def test_prefix_chunk_kernel_full_pool_layer_select():
     """5D pool + traced layer index, matching the in-scan usage."""
-    from gridllm_tpu.ops.attention import attention_prefix_chunk
-    from gridllm_tpu.ops.pallas_kernels import prefix_chunk
-
     L, t, ps, kvh, d, num_pages, maxp, h = 3, 16, 8, 2, 16, 16, 8, 4
     q = jax.random.normal(jax.random.PRNGKey(1), (1, t, h, d), jnp.float32)
     kc = jax.random.normal(jax.random.PRNGKey(2), (t, kvh, d), jnp.float32)
@@ -606,13 +634,13 @@ def test_prefix_chunk_kernel_full_pool_layer_select():
     vp = kp * 0.7
     start, total = jnp.int32(16), jnp.int32(16 + 16)
 
-    want = attention_prefix_chunk(
+    want = _prefix_chunk_ref(
         q, kp, vp, row, start, total, ps, k_cur=kc, v_cur=vc,
-        layer=jnp.int32(2), use_pallas=False,
+        layer=jnp.int32(2),
     )
-    got = prefix_chunk(
+    got = _ragged_chunk(
         q, kp, vp, row, start, total, ps, k_cur=kc, v_cur=vc,
-        layer=jnp.int32(2), interpret=True,
+        layer=jnp.int32(2),
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -622,9 +650,6 @@ def test_prefix_chunk_kernel_window_softcap():
     """Sliding window (mistral/gemma2) + softcap through the chunk kernel:
     windows that reach back into the paged prefix must match the jnp
     mask."""
-    from gridllm_tpu.ops.attention import attention_prefix_chunk
-    from gridllm_tpu.ops.pallas_kernels import prefix_chunk
-
     t, ps, kvh, d, num_pages, maxp, h = 16, 8, 2, 16, 16, 8, 4
     q = jax.random.normal(jax.random.PRNGKey(11), (1, t, h, d), jnp.float32)
     kc = jax.random.normal(jax.random.PRNGKey(12), (t, kvh, d), jnp.float32)
@@ -636,22 +661,22 @@ def test_prefix_chunk_kernel_window_softcap():
     start, total = jnp.int32(24), jnp.int32(24 + 16)
 
     for win in (6, 20):
-        want = attention_prefix_chunk(
+        want = _prefix_chunk_ref(
             q, kp, vp, row, start, total, ps, k_cur=kc, v_cur=vc,
-            use_pallas=False, logit_softcap=30.0, window=jnp.int32(win),
+            logit_softcap=30.0, window=jnp.int32(win),
         )
-        got = prefix_chunk(
+        got = _ragged_chunk(
             q, kp, vp, row, start, total, ps, k_cur=kc, v_cur=vc,
-            interpret=True, softcap=30.0, window=jnp.int32(win),
+            softcap=30.0, window=jnp.int32(win),
         )
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
 
 def test_prefix_chunk_dispatch_routes_to_kernel(monkeypatch):
-    """attention_prefix_chunk takes the kernel when interpret kernels are
-    on and the chunk fits VMEM; long prompts keep kernel-path prefill
-    (VERDICT r04 #5 'done' condition)."""
+    """The dispatcher's chunk region takes the kernel when interpret
+    kernels are on and the chunk fits VMEM; long prompts keep kernel-path
+    prefill (VERDICT r04 #5 'done' condition)."""
     from unittest import mock
 
     from gridllm_tpu.ops import attention, kvcache, pallas_kernels
@@ -667,11 +692,13 @@ def test_prefix_chunk_dispatch_routes_to_kernel(monkeypatch):
         kp = jax.random.normal(jax.random.PRNGKey(3), (num_pages, ps, kvh, d),
                                jnp.float32)
         with mock.patch.object(
-            pallas_kernels, "prefix_chunk", wraps=pallas_kernels.prefix_chunk
+            pallas_kernels, "ragged_attention",
+            wraps=pallas_kernels.ragged_attention,
         ) as spy:
-            attention.attention_prefix_chunk(
-                q, kp, kp, row, jnp.int32(8), jnp.int32(8 + 16), ps,
-                k_cur=kc, v_cur=vc,
+            attention.ragged_paged_attention(
+                kp, kp, ps, q_chunk=q, chunk_row=row,
+                chunk_start=jnp.int32(8), chunk_total=jnp.int32(8 + 16),
+                k_chunk=kc, v_chunk=vc,
             )
             assert spy.called
     finally:

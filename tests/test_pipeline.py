@@ -126,6 +126,44 @@ def test_engine_serves_over_pp_mesh():
     assert base == pp
 
 
+def test_pp_engine_attention_is_the_ragged_jnp_leg(monkeypatch):
+    """A pp engine's chunked prefill and decode go through the one paged
+    dispatcher, on its jnp leg even with kernels on (the partial-manual pp
+    region pins use_pallas=False), chunk by chunk through prefill_chunk_fn
+    — pipeline is the one module without a mixed_step."""
+    from gridllm_tpu.engine import EngineConfig, InferenceEngine
+    from gridllm_tpu.engine.engine import GenerationRequest
+    from gridllm_tpu.obs import default_registry
+    from gridllm_tpu.ops import kvcache
+
+    monkeypatch.setenv("GRIDLLM_PALLAS", "interpret")
+    kvcache._env_mode.cache_clear()
+    c = default_registry().get("gridllm_kernel_dispatch_total")
+
+    def snap():
+        return {(lb["op"], lb["path"]): v for lb, v in c.items()
+                if lb["op"].startswith("attention_")}
+
+    try:
+        before = snap()
+        eng = InferenceEngine(EngineConfig(
+            model="tiny-llama", max_slots=2, page_size=8, num_pages=32,
+            max_pages_per_slot=8, prefill_buckets=(16, 32), prefill_chunk=16,
+            mesh=MeshConfig(pp=2, dp=2, tp=2),
+        ))
+        assert not eng._use_mixed
+        res = eng.generate(GenerationRequest(
+            id="ppr", prompt="abcdefgh" * 4,  # 33 ids > chunk 16 → chunked
+            options={"temperature": 0, "num_predict": 4, "seed": 1},
+        ))
+        assert len(res.token_ids) == 4
+        after = snap()
+    finally:
+        kvcache._env_mode.cache_clear()
+    grew = {k for k, v in after.items() if v > before.get(k, 0)}
+    assert grew == {("attention_ragged", "jnp")}, grew
+
+
 def test_pp_engine_rejects_decoder_embeddings():
     from gridllm_tpu.engine import EngineConfig, InferenceEngine
 

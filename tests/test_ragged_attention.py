@@ -1,19 +1,18 @@
-"""Ragged paged attention (ISSUE 6): the unified kernel/dispatcher that
-serves chunked prefill, decode, and spec-verify in one launch.
+"""Ragged paged attention: the one kernel/dispatcher that serves chunked
+prefill, decode, and spec-verify in one launch.
 
 Four layers of pinning:
 
-- differential: the ragged jnp reference is BIT-identical to the legacy
-  per-phase references (it delegates to them region-by-region), and the
+- differential: the ragged jnp reference is BIT-identical to the
+  per-region references (it delegates to them region-by-region), and the
   interpret-mode kernel matches the reference across mixed batches,
   page-boundary straddles, empty slots, windows, and softcap;
-- stream parity: greedy engine token streams are identical ragged-on vs
-  ragged-off — concurrent mixed batches, warm prefix-cache replays, and
-  the speculative path included; GRIDLLM_RAGGED_ATTN=0 restores the
-  legacy dispatchers exactly;
-- single launch: the kernel-dispatch counters prove a ragged engine
-  compiles ONLY `attention_ragged` programs — no per-phase
-  decode/chunk/verify dispatches, no per-slot loop;
+- stream parity: greedy engine token streams under the interpreted
+  kernel equal those under the jnp reference — concurrent mixed
+  batches, warm prefix-cache replays, and the speculative path included;
+- single launch: the kernel-dispatch counters prove an engine compiles
+  ONLY `attention_ragged` programs — no other paged-attention label is
+  even legal;
 - recompile hygiene: varying batch mixes (admissions mid-decode, spec
   verify, warm cache) trigger zero steady-state recompiles.
 """
@@ -46,16 +45,23 @@ GREEDY = {"temperature": 0.0, "repeat_penalty": 1.0, "num_predict": 24}
 
 
 @contextmanager
-def ragged(flag: bool):
-    old = os.environ.get("GRIDLLM_RAGGED_ATTN")
-    os.environ["GRIDLLM_RAGGED_ATTN"] = "1" if flag else "0"
+def pallas(mode: str):
+    """GRIDLLM_PALLAS for the engines built and driven inside: "interpret"
+    (the kernel, interpreted) or "0" (the jnp reference). The policy is
+    read at trace time, so hold it over generation too."""
+    from gridllm_tpu.ops.kvcache import _env_mode
+
+    old = os.environ.get("GRIDLLM_PALLAS")
+    os.environ["GRIDLLM_PALLAS"] = mode
+    _env_mode.cache_clear()
     try:
         yield
     finally:
         if old is None:
-            os.environ.pop("GRIDLLM_RAGGED_ATTN", None)
+            os.environ.pop("GRIDLLM_PALLAS", None)
         else:
-            os.environ["GRIDLLM_RAGGED_ATTN"] = old
+            os.environ["GRIDLLM_PALLAS"] = old
+        _env_mode.cache_clear()
 
 
 def _gen_batch(engine, prompts, opts=GREEDY):
@@ -80,7 +86,7 @@ def _gen_batch(engine, prompts, opts=GREEDY):
 
 
 # ---------------------------------------------------------------------------
-# differential: ragged op vs the legacy references / interpret kernel
+# differential: ragged op vs the per-region references / interpret kernel
 # ---------------------------------------------------------------------------
 
 
@@ -90,9 +96,9 @@ def _pools(rng, L=2, P=32, ps=8, kvh=2, d=16):
     return kp, vp
 
 
-def test_ragged_ref_bitwise_equals_legacy_refs():
-    """The fallback path delegates region-by-region to the legacy
-    references — ragged-on and ragged-off jnp paths are the same bits."""
+def test_ragged_ref_bitwise_equals_region_refs():
+    """The fallback path delegates region-by-region to the per-region
+    references — the same bits as calling each directly."""
     rng = np.random.default_rng(0)
     kp, vp = _pools(rng)
     ps, kvh, d, h = 8, 2, 16, 4
@@ -130,9 +136,9 @@ def test_ragged_ref_bitwise_equals_legacy_refs():
     qc = jnp.asarray(rng.normal(size=(1, C, h, d)), jnp.float32)
     kcc = jnp.asarray(rng.normal(size=(C, kvh, d)), jnp.float32)
     vcc = jnp.asarray(rng.normal(size=(C, kvh, d)), jnp.float32)
-    wantc = A.attention_prefix_chunk(
+    wantc = A._prefix_chunk_ref(
         qc, kp, vp, row, start, start + C, ps, k_cur=kcc, v_cur=vcc,
-        layer=li, use_pallas=False)
+        layer=li)
     gotc, _ = A.ragged_paged_attention(
         kp, vp, ps, q_chunk=qc, chunk_row=row, chunk_start=start,
         chunk_total=start + C, k_chunk=kcc, v_chunk=vcc, layer=li,
@@ -248,73 +254,57 @@ def test_ragged_kernel_first_chunk_empty_prefix():
 
 
 # ---------------------------------------------------------------------------
-# greedy stream parity: ragged-on vs ragged-off engines
+# greedy stream parity: the interpreted kernel vs the jnp reference
 # ---------------------------------------------------------------------------
 
 
-def _engine(ragged_on: bool, **kw):
-    with ragged(ragged_on):
-        return InferenceEngine(EngineConfig(**TINY, **kw))
+def _engine(**kw):
+    return InferenceEngine(EngineConfig(**TINY, **kw))
+
+
+def _streams(mode: str, rounds, **kw):
+    """Results of each round of prompts from a fresh engine under
+    GRIDLLM_PALLAS=mode, and the engine."""
+    with pallas(mode):
+        eng = _engine(**kw)
+        return [_gen_batch(eng, prompts) for prompts in rounds], eng
 
 
 def test_greedy_parity_concurrent_mixed_batch():
-    """Long (chunked → ragged mixed-step) and short (bucketed) prompts in
-    one concurrent batch: identical greedy streams ragged-on vs off."""
-    prompts = [LONG_PROMPT, "hello", LONG_PROMPT + " xyz", "q"]
-    off = _engine(False, spec_decode=False, prefix_cache=False)
-    with ragged(False):
-        want = [r.token_ids for r in _gen_batch(off, prompts)]
-    on = _engine(True, spec_decode=False, prefix_cache=False)
-    with ragged(True):
-        got = [r.token_ids for r in _gen_batch(on, prompts)]
-    assert got == want
+    """Long (chunked → mixed-step) and short (bucketed) prompts in one
+    concurrent batch: identical greedy streams kernel vs reference."""
+    # (bf16 random weights: a prompt whose top two logits tie to 1e-4,
+    # as "q" does, flips on kernel-vs-reference rounding — not used here)
+    prompts = [LONG_PROMPT, "hello", LONG_PROMPT + " xyz", "hi"]
+    kw = dict(spec_decode=False, prefix_cache=False)
+    (want,), _ = _streams("0", [prompts], **kw)
+    (got,), _ = _streams("interpret", [prompts], **kw)
+    got = [r.token_ids for r in got]
+    assert got == [r.token_ids for r in want]
     assert all(len(t) == GREEDY["num_predict"] for t in got)
 
 
 def test_greedy_parity_warm_prefix_cache():
-    """Warm (cache-hit) admissions replay through the ragged mixed path
-    bit-identically: cold == warm == legacy."""
-    off = _engine(False, spec_decode=False)
-    with ragged(False):
-        want = [_gen_batch(off, [LONG_PROMPT])[0].token_ids
-                for _ in range(2)]
-    on = _engine(True, spec_decode=False)
-    with ragged(True):
-        got = [_gen_batch(on, [LONG_PROMPT])[0].token_ids
-               for _ in range(2)]
-    assert got == want
+    """Warm (cache-hit) admissions replay through the mixed path
+    bit-identically: cold == warm, kernel == reference."""
+    rounds = [[LONG_PROMPT]] * 2
+    want, _ = _streams("0", rounds, spec_decode=False)
+    got, on = _streams("interpret", rounds, spec_decode=False)
+    got = [r[0].token_ids for r in got]
+    assert got == [r[0].token_ids for r in want]
     assert got[0] == got[1]            # cold == warm
     assert on.alloc.hits > 0           # the warm round really hit
 
 
 def test_greedy_parity_speculative():
-    """Spec-on engines: the ragged verify path (one launch, no per-slot
-    loop) keeps greedy streams identical, with real acceptance."""
+    """Spec-on engines: the verify path (one launch over all slots) keeps
+    greedy streams identical kernel vs reference, with real acceptance."""
     prompts = [LONG_PROMPT, "hello"]
-    off = _engine(False, spec_decode=True, spec_k=4, prefix_cache=False)
-    with ragged(False):
-        want = _gen_batch(off, prompts)
-    on = _engine(True, spec_decode=True, spec_k=4, prefix_cache=False)
-    with ragged(True):
-        got = _gen_batch(on, prompts)
+    kw = dict(spec_decode=True, spec_k=4, prefix_cache=False)
+    (want,), _ = _streams("0", [prompts], **kw)
+    (got,), _ = _streams("interpret", [prompts], **kw)
     assert [r.token_ids for r in got] == [r.token_ids for r in want]
     assert sum(r.spec_accepted for r in got) > 0
-
-
-def test_escape_hatch_restores_legacy_dispatchers():
-    """GRIDLLM_RAGGED_ATTN=0 engines never trace the ragged op."""
-    c = default_registry().get("gridllm_kernel_dispatch_total")
-
-    def count(op):
-        return sum(v for labels, v in c.items() if labels["op"] == op)
-
-    before = count("attention_ragged")
-    legacy_before = count("attention_decode")
-    off = _engine(False, spec_decode=False, prefix_cache=False)
-    with ragged(False):
-        _gen_batch(off, [LONG_PROMPT])
-    assert count("attention_ragged") == before
-    assert count("attention_decode") > legacy_before
 
 
 # ---------------------------------------------------------------------------
@@ -323,29 +313,29 @@ def test_escape_hatch_restores_legacy_dispatchers():
 
 
 def test_single_attention_dispatch_per_step():
-    """A ragged engine serving a mixed workload (chunked admission +
-    decode + spec verify + warm cache) compiles ONLY attention_ragged
-    programs — the legacy per-phase ops (and verify's per-slot chunk
-    loop) are never dispatched. Counters count per compiled program, so
-    zero deltas prove the phases share the unified entry point."""
+    """An engine serving a mixed workload (chunked admission + decode +
+    spec verify + warm cache) compiles attention_ragged programs, and the
+    per-phase labels of old are not legal dispatch labels at all — the
+    kernel-parity rule holds every record_kernel_path literal to the
+    legal set, so nothing can record one."""
+    from gridllm_tpu.ops.kernels import dispatch_labels
+
     c = default_registry().get("gridllm_kernel_dispatch_total")
 
-    def snap():
-        return {op: sum(v for labels, v in c.items()
-                        if labels["op"] == op)
-                for op in ("attention_ragged", "attention_decode",
-                           "attention_prefix_chunk", "attention_verify")}
+    def count(op):
+        return sum(v for labels, v in c.items() if labels["op"] == op)
 
-    before = snap()
-    eng = _engine(True, spec_decode=True, spec_k=4)
-    with ragged(True):
-        _gen_batch(eng, [LONG_PROMPT, "hello"])
-        _gen_batch(eng, [LONG_PROMPT])  # warm-cache replay
-    after = snap()
-    assert after["attention_ragged"] > before["attention_ragged"]
+    before = count("attention_ragged")
+    eng = _engine(spec_decode=True, spec_k=4)
+    _gen_batch(eng, [LONG_PROMPT, "hello"])
+    _gen_batch(eng, [LONG_PROMPT])  # warm-cache replay
+    assert count("attention_ragged") > before
+    legal = dispatch_labels()
+    assert "attention_ragged" in legal
     for op in ("attention_decode", "attention_prefix_chunk",
                "attention_verify"):
-        assert after[op] == before[op], op
+        assert op not in legal, op
+        assert count(op) == 0, op
 
 
 # ---------------------------------------------------------------------------
@@ -357,31 +347,27 @@ def test_zero_steady_recompiles_over_varying_mixes():
     """After the first completed request arms the tripwire, admissions
     mid-decode (mixed steps), different batch fills, spec verify, and
     warm-cache replays must all reuse compiled programs."""
-    eng = _engine(True, spec_decode=True, spec_k=4)
-    with ragged(True):
-        # warm every program this test's mixes need: chunked + bucketed
-        # admission, decode, verify, warm-cache window seeding
-        _gen_batch(eng, [LONG_PROMPT, "hello"])
-        _gen_batch(eng, [LONG_PROMPT])
-        assert eng.perf.armed
-        steady0 = recompile_totals()["steady"]
-        _gen_batch(eng, [LONG_PROMPT, "hi", LONG_PROMPT + " xyz"])
-        _gen_batch(eng, ["hello", LONG_PROMPT])
-        steady = recompile_totals()["steady"]
+    eng = _engine(spec_decode=True, spec_k=4)
+    # warm every program this test's mixes need: chunked + bucketed
+    # admission, decode, verify, warm-cache window seeding
+    _gen_batch(eng, [LONG_PROMPT, "hello"])
+    _gen_batch(eng, [LONG_PROMPT])
+    assert eng.perf.armed
+    steady0 = recompile_totals()["steady"]
+    _gen_batch(eng, [LONG_PROMPT, "hi", LONG_PROMPT + " xyz"])
+    _gen_batch(eng, ["hello", LONG_PROMPT])
+    steady = recompile_totals()["steady"]
     assert steady == steady0, recompile_totals()["byFn"]
 
 
 def test_ragged_pool_unpadded_and_memory_fields():
-    """_pool_head_dim under ragged: the pool stays at the model's head
+    """_pool_head_dim: the pool stays at the model's head
     dim when KVH*D is flat-lane aligned (no 2x lane-pad bytes), and
     /admin/memory's allocator math reports zero lane-pad overhead with
     kvLayout "ragged". (Interpret/CPU engines keep the unpadded pool
     either way; the layout assertion is on the accounting fields.)"""
-    eng = _engine(True, spec_decode=False)
+    eng = _engine(spec_decode=False)
     alloc = eng.memory_arrays()["alloc"]
     assert alloc["kvLayout"] == "ragged"
     assert alloc["lanePadOverheadBytes"] == 0
     assert eng.cache.k.shape[-1] == eng.cfg.head_dim_
-
-    off = _engine(False, spec_decode=False)
-    assert off.memory_arrays()["alloc"]["kvLayout"] == "legacy"
