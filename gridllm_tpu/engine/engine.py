@@ -51,6 +51,7 @@ window buffer (ops/sampling.py) capped at EngineConfig.repeat_window.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import math
 import random
 import threading
@@ -152,6 +153,20 @@ _PREFIX_HIT_RATE = _OBS.gauge(
     "Cumulative prompt-page prefix-cache hit rate (hits / (hits+misses)), "
     "by model.",
     ("model",),
+)
+# chunked prefill: how often each width of the chunk program runs, and how
+# much of what it computes is prompt (real over padded = the fill)
+_CHUNK_LAUNCHES = _OBS.counter(
+    "gridllm_engine_chunk_launches_total",
+    "Chunked-prefill launches (mixed_chunk / prefill_chunk), by model and "
+    "the token width the chunk was padded to.",
+    ("model", "width"),
+)
+_CHUNK_TOKENS = _OBS.counter(
+    "gridllm_engine_chunk_tokens_total",
+    "Tokens through chunked-prefill launches, by model and kind (real = "
+    "prompt tokens, padded = the launches' widths, padding included).",
+    ("model", "kind"),
 )
 # elastic serving (ISSUE 20): cold-start cost, by how the weights arrived
 # — "snapshot" (host-RAM weight tier hit), "checkpoint" (safetensors
@@ -305,6 +320,14 @@ class EngineConfig:
     # padding to the next bucket; rounded down to a multiple of page_size
     # (the in-place page-write kernel requires page-aligned chunk starts)
     prefill_chunk: int = 1024
+    # the one narrower width of the chunk program: a prompt's LAST chunk
+    # (cold, or the fresh tail behind a cached prefix) is padded to it when
+    # it fits, to prefill_chunk otherwise. A cached re-ask is a few dozen
+    # to 192 fresh tokens; a 256-wide launch costs a third of a 1024-wide
+    # one (PERF.md, PR 32). One more model-sized program, so one width and
+    # no ladder; page-aligned like prefill_chunk, and without effect where
+    # prefill_chunk is no wider
+    prefill_chunk_narrow: int = 256
     # decode steps fused per dispatch in the runner loop (step() always
     # uses 1 — exact per-token semantics for tests/sync callers)
     decode_block: int = 8
@@ -790,8 +813,10 @@ class InferenceEngine:
             if self._use_chunked and b >= self._chunk_len:
                 break
         if self._use_chunked and self._chunk_len < room:
-            # two chunks of one program; sent twice when the prefix cache
-            # is on, so the second admission is a hit (window_seed)
+            # a full chunk and a one-token last chunk: both widths of the
+            # chunk program (_chunk_width); sent twice when the prefix
+            # cache is on, so the second admission is a hit (window_seed,
+            # then the narrow width behind a cached prefix)
             lengths += [self._chunk_len + 1] * (
                 2 if self._prefix_cache_cap != 0 else 1)
         # one fill token per length: prompts that shared a first page
@@ -807,6 +832,13 @@ class InferenceEngine:
             # here, not inside a user's request
             prompts.append((lengths[0], 1 + len(lengths)))
         self._perf_armed = True              # _finish arms only when False
+        # what follows is tracing and lowering: a few hundred thousand
+        # objects that stay alive in jax's caches, so the cyclic
+        # collector's passes over them free next to nothing and cost a
+        # warm start a third of a second (PERF.md, PR 32). Paused, with the
+        # one pass that is due made at the end, inside what set-up times
+        gc_was_on = gc.isenabled()
+        gc.disable()
         try:
             for i, (n, fill) in enumerate(prompts):
                 t1 = time.perf_counter()
@@ -823,6 +855,9 @@ class InferenceEngine:
                          ms=int((time.perf_counter() - t1) * 1000))
         finally:
             self._perf_armed = False
+            if gc_was_on:
+                gc.enable()
+                gc.collect()
         self.prewarm_duration_ns = time.perf_counter_ns() - t0
         # what jax has built for this model so far (init and prewarm; a
         # mesh's layout recompile of the first program among them): a
@@ -1492,6 +1527,8 @@ class InferenceEngine:
         self._chunk_len = max(
             ps, (min(self.config.prefill_chunk, self.max_context) // ps) * ps
         )
+        self._chunk_narrow = max(
+            ps, (self.config.prefill_chunk_narrow // ps) * ps)
         self._decode_block_fn = self.perf.wrap("decode_block", decode_block_fn)
 
         # Speculative decoding (ISSUE 5): one verify step = ONE batched
@@ -1903,6 +1940,16 @@ class InferenceEngine:
         px = preprocess_images(images, self.cfg.vision_cfg.image_size)
         return self._encode_fn(self.params, px)
 
+    def _chunk_width(self, n: int) -> int:
+        """The width a prompt's LAST chunk of `n` tokens is launched at:
+        the narrow one where it holds them, else the full chunk. The one
+        place the choice is made, from a host integer of the admit plan,
+        so a follower's replay picks the same program. Where the chunk
+        is no wider than the narrow width there is one width, as before."""
+        if n <= self._chunk_narrow < self._chunk_len:
+            return self._chunk_narrow
+        return self._chunk_len
+
     def _dispatch_prefill(self, slot: int, ids: list[int],
                           row_list: list[int], upd: dict[str, Any],
                           images: list[str] | None = None,
@@ -1943,13 +1990,22 @@ class InferenceEngine:
                 )
             for s0 in range(cached, len(ids), c):
                 part = ids[s0 : s0 + c]
-                padded = _host_i32(part, c)
+                final = s0 + c >= len(ids)
+                # a chunk before the last is full. An image prompt keeps
+                # the one width: a second splice program would compile
+                # inside a user's request
+                width = (self._chunk_width(len(part))
+                         if final and img_flat is None else c)
+                padded = _host_i32(part, width)
                 embeds = None
                 if img_flat is not None:
                     off = sum(1 for t in ids[:s0] if t == img_tok)
                     embeds = self._splice_fn(
                         self.params, padded, img_flat, np.int32(off)
                     )
+                _CHUNK_LAUNCHES.inc(model=self.cfg.name, width=str(width))
+                _CHUNK_TOKENS.inc(len(part), model=self.cfg.name, kind="real")
+                _CHUNK_TOKENS.inc(width, model=self.cfg.name, kind="padded")
                 if self._use_mixed:
                     # ragged mixed step (ISSUE 6): this chunk AND one
                     # decode token for every active slot share a single
@@ -1957,8 +2013,7 @@ class InferenceEngine:
                     # prompt prefills; the decode rows ride _inflight and
                     # are ingested like any other block
                     self._dispatch_mixed_chunk(
-                        padded, s0, len(part), slot, row,
-                        s0 + c >= len(ids), embeds,
+                        padded, s0, len(part), slot, row, final, embeds,
                     )
                     continue
                 (self.cache, self.counts, self.window, self.wlen,
@@ -1967,12 +2022,12 @@ class InferenceEngine:
                         self.params, padded, self.cache, self.counts,
                         self.window, self.wlen, self.tokens, self.active,
                         self.sampling, np.int32(s0), np.int32(len(part)),
-                        slot_, row, np.bool_(s0 + c >= len(ids)),
-                        embeds=embeds,
+                        slot_, row, np.bool_(final), embeds=embeds,
                     )
                 )
         else:
-            padded = _host_i32(ids, self._bucket_for(len(ids)))
+            width = self._bucket_for(len(ids))
+            padded = _host_i32(ids, width)
             embeds = None
             if img_flat is not None:
                 embeds = self._splice_fn(
@@ -1985,6 +2040,9 @@ class InferenceEngine:
                 self.sampling, np.int32(len(ids)), slot_, row,
                 embeds=embeds,
             )
+        # the last launch's width and the tokens that went through the
+        # model, on the gridllm.dispatch_prefill span (free off a capture)
+        self._clock.annotate(width=width, tokens=len(ids) - cached)
 
     def apply_plan_op(self, rec: dict[str, Any]) -> None:
         """Follower-side replay of one liaison plan record (multi-host

@@ -1,7 +1,8 @@
 """mistral-nemo's shape under ``tp:4`` (PR 27): the preset the benchmark's
 four-chip cell rehearses with, the engine's weights born sharded, the served
-path against the benchmark's plain float32 reference, and the counter of
-XLA compilations that jax itself feeds."""
+path against the benchmark's plain float32 reference, the counter of
+XLA compilations that jax itself feeds, and (ISSUE 32) the narrow width of a
+prompt's last chunk over the same four devices."""
 import importlib.util
 import json
 import logging
@@ -275,3 +276,41 @@ def test_engine_books_its_compiles_and_logs_the_init(four_devices, caplog):
     for i, prompt in enumerate(("hello there", "hello again", "and a third")):
         eng.generate(GenerationRequest(id=str(i), prompt=prompt, options=opts))
         assert XLA_COMPILE_SECONDS.count(model="tiny-nemo") == warm, prompt
+
+
+# -- (e) a prompt's last chunk at the narrow width, under the mesh -----------
+
+def test_narrow_last_chunk_under_tp4_costs_one_program_and_no_token(four_devices):
+    """Chunks of 32 with the narrow width 16 against chunks of 32 alone,
+    both under tp:4: prewarm builds exactly one executable more (the narrow
+    chunk program, once: by the time it first runs the state lies where the
+    mesh leaves it), nothing compiles after it for any admission kind, and
+    the greedy tokens are the one-width engine's."""
+    kw = dict(model="tiny-nemo", mesh=MeshConfig(tp=4), max_slots=2,
+              num_pages=64, page_size=8, max_pages_per_slot=13,
+              prefill_buckets=(16, 32), prefill_chunk=32, prefix_cache=True)
+    opts = {"temperature": 0, "num_predict": 4}
+    built, tokens = [], []
+    # the first engine also pays what the process builds once
+    for narrow in (32, 16, 32):
+        eng = InferenceEngine(EngineConfig(**kw, prefill_chunk_narrow=narrow))
+        assert eng.mesh_axes == "tp:4"
+        n0 = XLA_COMPILE_SECONDS.count(model="tiny-nemo")
+        eng.prewarm()
+        warm = XLA_COMPILE_SECONDS.count(model="tiny-nemo")
+        built.append(warm - n0)
+        out = []
+        # one bucket; a cached tail; chunk + narrow tail, cold and re-asked;
+        # a tail the narrow width does not hold; a chunk boundary
+        for n in (9, 20, 40, 40, 70, 70, 90, 64, 97):
+            res = eng.generate(GenerationRequest(
+                id=f"n{n}", raw=True, options=opts,
+                prompt_ids=[3 + (n + 5 * i) % 200 for i in range(n)]))
+            assert res.done_reason in ("stop", "length"), res.error
+            out.append((n, res.cached_tokens, res.token_ids))
+        assert XLA_COMPILE_SECONDS.count(model="tiny-nemo") == warm, narrow
+        assert eng.perf.state()["mixed_chunk"]["signatures"] == 1 + (narrow < 32)
+        tokens.append(out)
+    assert built[1] == built[2] + 1, built
+    assert tokens[1] == tokens[2] == tokens[0]
+    assert [c for _, c, _ in tokens[1]] == [0, 0, 0, 32, 0, 64, 0, 0, 0]
