@@ -17,7 +17,12 @@ Shapes are llama3.2:3b's under the worker's defaults: 24 query / 8 KV
 heads of 128, 128-token pages, a 128-page table row per slot, 8 slots,
 1024-token prefill chunks, speculation depth 4 (a 5-token verify block).
 
-The last case, ``paths``, is the one whole-model comparison: a prompt
+The ``g7_*`` cases are SmallThinker-21BA3B's attention: 28 query / 4 KV
+heads of 128 (a query group of 7), a traced per-layer window of 4096, 16
+slots, contexts of 3 k and 7 k tokens, in the ragged kernel's decode,
+verify, chunk and mixed forms and in flash prefill.
+
+The case ``paths`` is the one whole-model comparison: a prompt
 shorter than a prefill chunk is answered by bucketed flash prefill when
 cold and by the chunk program behind its cached pages on a prefix-cache
 hit — two kernels, so two roundings. Both programs' last-token logits are
@@ -52,6 +57,11 @@ LAYER = 1
 # one slot of every kind the engine produces: mid-page, inactive, a single
 # cached token, exactly one full page, one past it, and long contexts
 LENGTHS = (600, 0, 1, 128, 129, 1023, 2000, 300)
+# the g7 cases' shapes: contexts on both sides of the window of 4096
+G7 = {"H": 28, "KVH": 4, "NP": 384, "S": 16, "WINDOW": 4096,
+      "LENGTHS": (7000, 0, 3000, 4095, 4097, 129, 6200, 300,
+                  1, 128, 3071, 5000, 0, 600, 2000, 4096)}
+WINDOW = 0
 
 
 def _interpret() -> bool:
@@ -107,7 +117,8 @@ def _chunk_row() -> np.ndarray:
     """The admitting slot's table row: 16 pages, enough for a 1024-token
     prefix plus one 1024-token chunk."""
     row = np.full((MAXP,), -1, np.int32)
-    row[:16] = np.arange(NP - 1, NP - 17, -1)
+    n = 16 if not WINDOW else 56        # g7: a 6144-token prefix + a chunk
+    row[:n] = np.arange(NP - 1, NP - 1 - n, -1)
     return row
 
 
@@ -120,7 +131,19 @@ def _sibling_tree():
     return tree_depths(parents), tree_ancestor_mask(parents)
 
 
-def _ragged(chunk: bool, td: int, quant: bool = False, tree: bool = False):
+def _g7(build: Callable[[], Case]) -> Case:
+    """`build` under SmallThinker's shapes (the helpers read the module's
+    globals when a case is built, never when it runs)."""
+    saved = {k: globals()[k] for k in G7}
+    globals().update(G7)
+    try:
+        return build()
+    finally:
+        globals().update(saved)
+
+
+def _ragged(chunk: bool, td: int, quant: bool = False, tree: bool = False,
+            chunk_start: int = 1024):
     kp, vp = _pool(1), _pool(2)
     if quant:
         kq, ks = KV.quantize_kv_rows(kp)
@@ -128,8 +151,11 @@ def _ragged(chunk: bool, td: int, quant: bool = False, tree: bool = False):
         kp, vp = KV.QuantPages(kq, ks), KV.QuantPages(vq, vs)
     kw: dict[str, Any] = {}
     valid: list = []
+    if WINDOW:
+        # a traced scalar, as the layer scan hands it to the kernel
+        kw["window"] = jnp.int32(WINDOW)
     if chunk:
-        start, total = 1024, 1024 + 900
+        start, total = chunk_start, chunk_start + 900
         kw.update(
             q_chunk=_rand(3, (1, C, H, D)),
             chunk_row=jnp.asarray(_chunk_row()),
@@ -170,15 +196,22 @@ def _ragged(chunk: bool, td: int, quant: bool = False, tree: bool = False):
                 (kp, vp, *(kw[n] for n in names)), tuple(valid))
 
 
-def _flash(fn, t: int):
+def _flash(fn, t: int, window: int = 0):
     lens = jnp.asarray([t - 100], jnp.int32)
     args = (_rand(1, (1, t, H, D)), _rand(2, (1, t, KVH, D)),
             _rand(3, (1, t, KVH, D)), lens)
+    if window:
+        args += (jnp.int32(window),)      # traced, as the layer scan's
 
-    def run(q, k, v, lens):
-        return fn(q, k, v, lens, interpret=_interpret())
+    def run(q, k, v, lens, *win):
+        return fn(q, k, v, lens, interpret=_interpret(),
+                  **({"window": win[0]} if win else {}))
 
-    return Case(fn.__name__, run, A.attention_prefill_ref, args,
+    def ref(q, k, v, lens, *win):
+        return A.attention_prefill_ref(
+            q, k, v, lens, **({"window": win[0]} if win else {}))
+
+    return Case(fn.__name__, run, ref, args,
                 (np.arange(t)[None, :] < t - 100,))
 
 
@@ -291,6 +324,15 @@ CASES: dict[str, Callable[[], Case]] = {
     "flash1024": lambda: _flash(PK.flash_prefill, 1024),
     "streamed": lambda: _flash(PK.flash_prefill_streamed, 1024),
     "paths": _paths,
+    "g7_decode": lambda: _g7(lambda: _ragged(False, 1)),
+    "g7_verify": lambda: _g7(lambda: _ragged(False, TD_VERIFY)),
+    "g7_chunk3k": lambda: _g7(lambda: _ragged(True, 0, chunk_start=2048)),
+    "g7_chunk7k": lambda: _g7(lambda: _ragged(True, 0, chunk_start=6144)),
+    "g7_mixed7k": lambda: _g7(lambda: _ragged(True, 1, chunk_start=6144)),
+    "g7_flash1024": lambda: _g7(
+        lambda: _flash(PK.flash_prefill, 1024, window=4096)),
+    "g7_flash1024_w256": lambda: _g7(
+        lambda: _flash(PK.flash_prefill, 1024, window=256)),
 }
 
 

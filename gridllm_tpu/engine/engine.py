@@ -71,7 +71,10 @@ from gridllm_tpu.models.configs import ModelConfig, get_config
 from gridllm_tpu.obs import SIZE_BUCKETS, default_flight_recorder, default_registry
 from gridllm_tpu.obs.perf import (
     ADMIT_WAIT_SECONDS,
+    MOE_EXPERT_ROWS_TOTAL,
+    MOE_EXPERTS_TOUCHED_TOTAL,
     VERIFY_CTX_TOKENS_TOTAL,
+    VERIFY_WINDOW_TOKENS_TOTAL,
     XLA_COMPILE_SECONDS,
     PhaseClock,
     RecompileTripwire,
@@ -261,6 +264,18 @@ def _device_memory_stats(device) -> dict[str, int]:
     return device.memory_stats() or {}
 
 
+# A routed family's one chunk width (where EngineConfig.prefill_chunk is
+# wider): every prompt is admitted through the mixed step in launches this
+# wide, and the bucketed prefill is not used. Its launch reads every expert
+# it holds whatever rows it carries, so a chunk that takes about a verify
+# launch's period (35 ms at 512 rows beside 34 on the v5e; PERF.md, PR 33)
+# admits a prompt while the running streams decode in step, where a
+# prefill of its own stalls them for a launch and a half. One width: a
+# narrower last chunk would put a step in the time to the first token at
+# the prompt length it starts from
+ROUTED_CHUNK = 512
+
+
 def _host_i32(values: list[int], size: int) -> np.ndarray:
     """`values` zero-padded to `size` as a host int32 buffer — what a
     jitted call takes for a token chunk or a page-table row. Passed
@@ -272,7 +287,9 @@ def _host_i32(values: list[int], size: int) -> np.ndarray:
 
 
 def _model_module(cfg: ModelConfig):
-    if cfg.family == "mixtral":
+    if cfg.family in ("mixtral", "smallthinker"):
+        # one routed-experts module: the two differ in data (activation,
+        # the router's tap, the layer pattern), not in code
         from gridllm_tpu.models import mixtral
 
         return mixtral
@@ -541,6 +558,11 @@ class InferenceEngine:
 
             self.cfg = config_from_hf_dir(config.model, config.checkpoint_path)
         self.mod = _model_module(self.cfg)
+        # each layer's sliding window (none: it reads the whole context),
+        # and how many layers have one
+        self._layer_windows = np.asarray(
+            [w or np.inf for w in self.cfg.layer_windows])
+        self._windowed = int(np.isfinite(self._layer_windows).sum())
         self.embedding_only = self.cfg.family == "bert_embed"
         self.tokenizer: Tokenizer = get_tokenizer(
             config.tokenizer, self.cfg.vocab_size
@@ -1298,6 +1320,14 @@ class InferenceEngine:
         def _gather_sp(sp: SamplingParams, slot) -> SamplingParams:
             return jax.tree.map(lambda a: a[slot][None], sp)
 
+        # a routed family's decode / verify steps also return what they
+        # routed ([live rows, experts touched], summed over layers): two
+        # integers that travel to the host in the fetch a launch makes
+        # anyway, and feed gridllm_moe_*
+        self._step_stats = bool(
+            getattr(mod, "STEP_STATS", False) and mc.num_experts)
+        stats_kw = {"with_stats": True} if self._step_stats else {}
+
         # Prefill folds EVERYTHING into device state — the sampled first
         # token lands in `tokens[slot]` and the host never synchronizes on
         # it (it arrives with the next decode block's row 0). sp.step for
@@ -1421,7 +1451,8 @@ class InferenceEngine:
             return out, cache, counts, window, wlen, tokens, active, sp
 
         # One decode block: k fused (model step + sample + bookkeeping)
-        # iterations under lax.scan. Returns [k+1, S] tokens — row 0 is the
+        # iterations under lax.scan. Returns ([k+1, S] tokens, a routed
+        # family's statistics or None) first — row 0 of the tokens is the
         # block's INPUT tokens (a newly admitted slot's prefill sample),
         # rows 1..k the block's samples.
         @partial(jax.jit, static_argnames=("k",),
@@ -1432,8 +1463,9 @@ class InferenceEngine:
 
             def body(carry, _):
                 tokens, cache, counts, window, wlen, sp = carry
-                logits, cache = mod.decode_step(
-                    params, mc, tokens, cache, active, mesh=self.mesh
+                logits, cache, *stats = mod.decode_step(
+                    params, mc, tokens, cache, active, mesh=self.mesh,
+                    **stats_kw,
                 )
                 sampled = sample_tokens(logits, sp, counts)
                 tokens = jnp.where(active, sampled, tokens)
@@ -1444,13 +1476,17 @@ class InferenceEngine:
                 sp = dataclasses.replace(
                     sp, step=sp.step + active.astype(jnp.int32)
                 )
-                return (tokens, cache, counts, window, wlen, sp), tokens
+                return (tokens, cache, counts, window, wlen, sp), (tokens, stats)
 
-            (tokens, cache, counts, window, wlen, sp), toks = jax.lax.scan(
-                body, (tokens, cache, counts, window, wlen, sp), None, length=k
-            )
+            (tokens, cache, counts, window, wlen, sp), (toks, stats) = (
+                jax.lax.scan(
+                    body, (tokens, cache, counts, window, wlen, sp), None,
+                    length=k))
             out = jnp.concatenate([first[None], toks])  # [k+1, S]
-            return out, tokens, cache, counts, window, wlen, sp
+            # a routed family's statistics, summed over the k steps: a
+            # second output that the block's own fetch brings along
+            stats = stats[0].sum(axis=0) if stats else None
+            return (out, stats), tokens, cache, counts, window, wlen, sp
 
         # Prefix-cache warm admission (ISSUE 3): the cached region's tokens
         # skip the model forward but must still flow through the
@@ -1522,13 +1558,17 @@ class InferenceEngine:
                 "mixed_chunk", mixed_chunk_fn, armable=text_only
             )
         ps = self.config.page_size
+        # a routed family with a mixed step admits every prompt through
+        # it, at one width (ROUTED_CHUNK)
+        self._admit_mixed = bool(self._use_mixed and mc.num_experts)
+        chunk = (min(self.config.prefill_chunk, ROUTED_CHUNK)
+                 if self._admit_mixed else self.config.prefill_chunk)
         # page-aligned chunking: the in-place page-write kernel requires
         # chunk starts at page boundaries
-        self._chunk_len = max(
-            ps, (min(self.config.prefill_chunk, self.max_context) // ps) * ps
-        )
-        self._chunk_narrow = max(
-            ps, (self.config.prefill_chunk_narrow // ps) * ps)
+        self._chunk_len = max(ps, (min(chunk, self.max_context) // ps) * ps)
+        self._chunk_narrow = (
+            self._chunk_len if self._admit_mixed else
+            max(ps, (self.config.prefill_chunk_narrow // ps) * ps))
         self._decode_block_fn = self.perf.wrap("decode_block", decode_block_fn)
 
         # Speculative decoding (ISSUE 5): one verify step = ONE batched
@@ -1569,8 +1609,9 @@ class InferenceEngine:
                 # admitted slot's prefill sample stays device-side, same
                 # no-sync admission contract as the block path)
                 cand = jnp.concatenate([tokens[:, None], drafts], axis=1)
-                logits, cache = mod.verify_step(
-                    params, mc, cand, cache, active, mesh=self.mesh
+                logits, cache, *stats = mod.verify_step(
+                    params, mc, cand, cache, active, mesh=self.mesh,
+                    **stats_kw,
                 )
                 out, n_emit, last, counts, window, wlen, sp = spec_accept(
                     logits, cand, dlen, sp, counts, window, wlen, active,
@@ -1586,6 +1627,8 @@ class InferenceEngine:
                 # (a just-admitted slot's prefill sample), rows 1..K+1 the
                 # emitted tokens, valid up to n_emit per slot
                 block = jnp.concatenate([cand[:, :1].T, out])
+                if stats:  # behind the per-slot counts: [S + 2]
+                    n_emit = jnp.concatenate([n_emit, *stats])
                 return block, n_emit, tokens, cache, counts, window, wlen, sp
 
             self._verify_fn = self.perf.wrap("verify_block", verify_block_fn)
@@ -1620,9 +1663,9 @@ class InferenceEngine:
                     # ancestor-masked attention inside verify_step).
                     cand = jnp.concatenate([tokens[:, None], drafts],
                                            axis=1)
-                    logits, cache = mod.verify_step(
+                    logits, cache, *stats = mod.verify_step(
                         params, mc, cand, cache, active, mesh=self.mesh,
-                        tree_pos=depths, tree_mask=anc,
+                        tree_pos=depths, tree_mask=anc, **stats_kw,
                     )
                     (out, path, n_emit, last, counts, window, wlen,
                      sp) = spec_accept_tree(
@@ -1642,6 +1685,8 @@ class InferenceEngine:
                     # block protocol: [N+1, S], same contract as the
                     # chain path (row 0 = block-input tokens)
                     block = jnp.concatenate([cand[:, :1].T, out])
+                    if stats:
+                        n_emit = jnp.concatenate([n_emit, *stats])
                     return (block, n_emit, tokens, cache, counts, window,
                             wlen, sp)
 
@@ -1972,7 +2017,8 @@ class InferenceEngine:
         # counts[slot] is cleared INSIDE prefill_fn / prefill_chunk_fn —
         # no host-side clear here (it would be a dead full-row rewrite)
         row = _host_i32(row_list, len(row_list))
-        if cached or (self._use_chunked and len(ids) > self._chunk_len):
+        if (cached or (self._use_chunked and len(ids) > self._chunk_len)
+                or self._admit_mixed):
             # chunked prefill: repeated invocations of ONE fixed-shape
             # program against the growing cached prefix — no per-length
             # traces, no padding to a distant bucket (VERDICT.md #4)
@@ -2254,7 +2300,7 @@ class InferenceEngine:
             np.int32(start), np.int32(length), np.int32(slot), row,
             np.bool_(is_final), embeds=embeds,
         )
-        self._inflight.append((self._gen, out, 1))
+        self._inflight.append((self._gen, (out, None), 1))
 
     def _fetch_oldest(self) -> None:
         """Fetch + ingest the oldest in-flight decode/mixed block — the
@@ -2266,8 +2312,11 @@ class InferenceEngine:
         t0 = time.perf_counter()
         self._clock.mark("fetch")
         # the ONE declared block-fetch sync point (host-sync-discipline)
-        raw = np.asarray(jax.device_get(out))  # sync-ok
+        # (tokens, a routed family's decode-block statistics or None)
+        raw, stats = jax.device_get(out)  # sync-ok
         self._mark_ingest()
+        if stats is not None:
+            self._count_step_stats(stats)
         self._ingest_block(gen, raw)
         _STEP_DURATION.observe(
             (time.perf_counter() - t0) / max(blk, 1), model=self.cfg.name)
@@ -2390,6 +2439,7 @@ class InferenceEngine:
         raw = np.asarray(jax.device_get(block))  # sync-ok (see _step_spec)
         n_np = np.asarray(jax.device_get(n_emit))  # sync-ok
         self._mark_ingest()
+        self._count_step_stats(n_np[self.config.max_slots:])
         self._ingest_spec(gen, raw, n_np, dlen)
         _STEP_DURATION.observe(time.perf_counter() - t0, model=self.cfg.name)
 
@@ -2433,6 +2483,7 @@ class InferenceEngine:
         raw = np.asarray(jax.device_get(block))  # sync-ok
         n_np = np.asarray(jax.device_get(n_emit))  # sync-ok
         self._mark_ingest()
+        self._count_step_stats(n_np[self.config.max_slots:])
         self._ingest_spec(gen, raw, n_np, dlen)
         _STEP_DURATION.observe(time.perf_counter() - t0, model=self.cfg.name)
 
@@ -2564,12 +2615,26 @@ class InferenceEngine:
         """Enter ``dispatch_verify`` for one verify / decode-block launch
         (the runner's call sites, not the dispatch functions: a multi-host
         follower replays those off the runner). Counts the context the
-        launch's ragged kernel reads: Σ over live slots of context length."""
-        ctx = sum(len(st.ids) for st in self._slots.values())
+        launch's ragged kernel reads: Σ over live slots of context length,
+        and the same with each layer's window applied."""
+        lens = [len(st.ids) for st in self._slots.values()]
+        ctx = sum(lens)
         VERIFY_CTX_TOKENS_TOTAL.inc(ctx, model=self.cfg.name)
+        VERIFY_WINDOW_TOKENS_TOTAL.inc(
+            float(np.minimum.outer(lens, self._layer_windows).mean(axis=1)
+                  .sum()) if self._windowed and lens else ctx,
+            model=self.cfg.name)
         self._clock.mark("dispatch_verify", gen=self._gen + 1,
                          slots=len(self._slots), ctx_tokens=ctx,
-                         mesh=self.mesh_axes)
+                         mesh=self.mesh_axes, experts=self.cfg.num_experts,
+                         window_layers=self._windowed)
+
+    def _count_step_stats(self, stats: np.ndarray) -> None:
+        """A launch's [live rows routed, experts touched] (summed over
+        layers), from the launch's own fetch; empty for a dense family."""
+        if len(stats) >= 2:
+            MOE_EXPERT_ROWS_TOTAL.inc(int(stats[0]), model=self.cfg.name)
+            MOE_EXPERTS_TOUCHED_TOTAL.inc(int(stats[1]), model=self.cfg.name)
 
     def _mark_ingest(self) -> None:
         """Leave ``fetch`` for ``ingest``, right after the device_get

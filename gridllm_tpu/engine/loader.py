@@ -55,10 +55,10 @@ def _name_map(cfg: ModelConfig) -> dict[str, tuple[str, bool]]:
     """The family's HF layout contract — owned by the model module
     (llama.HF_MAP / mixtral.HF_MAP) so loader and state-dict converter
     cannot drift. {} is the layer index; an extra {} the expert index."""
-    if cfg.family == "mixtral":
+    if cfg.family in ("mixtral", "smallthinker"):
         from gridllm_tpu.models import mixtral
 
-        return mixtral.HF_MAP
+        return mixtral.hf_map(cfg)
     if cfg.family == "gemma2":
         from gridllm_tpu.models import gemma
 

@@ -46,7 +46,9 @@ class VisionConfig:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str = "llama"            # llama | mixtral | bert_embed
+    # llama | qwen2 | qwen3 | gemma2 | mixtral | smallthinker | llava |
+    # bert_embed (engine._model_module picks the module)
+    family: str = "llama"
     vocab_size: int = 128_256
     hidden_size: int = 4096
     intermediate_size: int = 14_336
@@ -59,12 +61,22 @@ class ModelConfig:
     rms_eps: float = 1e-5
     tie_embeddings: bool = False
     max_seq_len: int = 8192
-    # MoE (mixtral family)
+    # MoE (mixtral, smallthinker): an expert's width is intermediate_size
     num_experts: int = 0
     experts_per_token: int = 2
+    expert_act: str = "silu"         # "silu" (SwiGLU) | "relu" (ReGLU)
+    # smallthinker: the router reads the PRE-attention normed state, not
+    # the post-attention one the experts compute on
+    router_pre_attn: bool = False
     # attention variants
     attn_logit_softcap: float = 0.0
     sliding_window: int = 0          # 0 → full attention
+    # layers that differ in kind (gemma2's window, smallthinker's window
+    # and RoPE), one entry a layer; () = every layer alike. window_layout[l] == 0: layer l attends globally
+    # whatever sliding_window says; rope_layout[l] == 0: no positional
+    # encoding in layer l (NoPE)
+    window_layout: tuple[int, ...] = ()
+    rope_layout: tuple[int, ...] = ()
     attn_bias: bool = False          # qwen2: bias on q/k/v projections
     qk_norm: bool = False            # qwen3: per-head RMSNorm on q/k pre-rope
     # gemma2: logits scale by qpas**-0.5 (None → head_dim), lm-head
@@ -81,9 +93,26 @@ class ModelConfig:
     # sets False on its config copy when serving under a device mesh
     use_pallas: bool | None = None
 
+    def __post_init__(self):
+        # a depth cut keeps the first layers: a layout longer than the
+        # depth is cut to it, so that two configs of one depth compare equal
+        for name in ("window_layout", "rope_layout"):
+            layout = tuple(getattr(self, name))
+            if layout and len(layout) < self.num_layers:
+                raise ValueError(
+                    f"{self.name}: {name} has {len(layout)} entries for "
+                    f"{self.num_layers} layers")
+            object.__setattr__(self, name, layout[:self.num_layers])
+
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def layer_windows(self) -> tuple[int, ...]:
+        """Each layer's window (0 = global)."""
+        on = self.window_layout or (1,) * self.num_layers
+        return tuple(self.sliding_window if f else 0 for f in on)
 
     def hf_config(self) -> Any:
         """Equivalent transformers config (for golden tests, local only)."""
@@ -100,6 +129,10 @@ class ModelConfig:
             max_position_embeddings=self.max_seq_len,
             attention_bias=False,
         )
+        if self.family == "smallthinker":
+            raise NotImplementedError(
+                "smallthinker has no transformers twin here: its reference "
+                "is benchmark/reference/smallthinker_f32.py")
         if self.family == "mixtral":
             from transformers import MixtralConfig
 
@@ -281,13 +314,16 @@ register(ModelConfig(
     head_dim=128, rope_theta=1_000_000.0, max_seq_len=131_072, rms_eps=1e-5,
 ))
 
-# gemma2 (public HF configs; Ollama's gemma2 tags)
+# gemma2 (public HF configs; Ollama's gemma2 tags): even layers slide
+# (HF layer_idx % 2 == 0), odd ones attend globally
+_GEMMA2_LAYOUT = (1, 0)
 register(ModelConfig(
     name="gemma2:2b", family="gemma2", vocab_size=256_000, hidden_size=2304,
     intermediate_size=9216, num_layers=26, num_heads=8, num_kv_heads=4,
     head_dim=256, rope_theta=10_000.0, rms_eps=1e-6, tie_embeddings=True,
     max_seq_len=8192, sliding_window=4096, attn_logit_softcap=50.0,
     final_logit_softcap=30.0, query_pre_attn_scalar=256,
+    window_layout=_GEMMA2_LAYOUT * 13,
 ))
 register(ModelConfig(
     name="gemma2:9b", family="gemma2", vocab_size=256_000, hidden_size=3584,
@@ -295,6 +331,7 @@ register(ModelConfig(
     head_dim=256, rope_theta=10_000.0, rms_eps=1e-6, tie_embeddings=True,
     max_seq_len=8192, sliding_window=4096, attn_logit_softcap=50.0,
     final_logit_softcap=30.0, query_pre_attn_scalar=256,
+    window_layout=_GEMMA2_LAYOUT * 21,
 ))
 register(ModelConfig(
     name="gemma2:27b", family="gemma2", vocab_size=256_000, hidden_size=4608,
@@ -302,6 +339,7 @@ register(ModelConfig(
     head_dim=128, rope_theta=10_000.0, rms_eps=1e-6, tie_embeddings=True,
     max_seq_len=8192, sliding_window=4096, attn_logit_softcap=50.0,
     final_logit_softcap=30.0, query_pre_attn_scalar=144,
+    window_layout=_GEMMA2_LAYOUT * 23,
 ))
 
 register(ModelConfig(
@@ -309,6 +347,20 @@ register(ModelConfig(
     hidden_size=4096, intermediate_size=14_336, num_layers=32,
     num_heads=32, num_kv_heads=8, rope_theta=1_000_000.0,
     num_experts=8, experts_per_token=2, max_seq_len=32_768, rms_eps=1e-5,
+))
+
+# SmallThinker-21BA3B-Instruct (PowerInfer, config.json): 64 ReGLU experts
+# of 768, top-6, no shared expert, the router before attention; layers 0,
+# 4, 8, ... attend globally with no positional encoding, the rest slide
+# over 4096 keys with RoPE
+_ST_LAYOUT = (0, 1, 1, 1)
+register(ModelConfig(
+    name="smallthinker:21b", family="smallthinker", vocab_size=151_936,
+    hidden_size=2560, intermediate_size=768, num_layers=52, num_heads=28,
+    num_kv_heads=4, head_dim=128, rope_theta=1_500_000.0, rms_eps=1e-6,
+    max_seq_len=16_384, num_experts=64, experts_per_token=6,
+    expert_act="relu", router_pre_attn=True, sliding_window=4096,
+    window_layout=_ST_LAYOUT * 13, rope_layout=_ST_LAYOUT * 13,
 ))
 
 register(ModelConfig(
@@ -333,6 +385,16 @@ register(ModelConfig(
     intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
     head_dim=16, rope_theta=10_000.0, max_seq_len=256,
     num_experts=4, experts_per_token=2,
+))
+# smallthinker's shape in small: one period of (global NoPE, 3 x window
+# RoPE), a window shorter than the test contexts, 7 query heads a KV head
+register(ModelConfig(
+    name="tiny-smallthinker", family="smallthinker", vocab_size=256,
+    hidden_size=64, intermediate_size=32, num_layers=4, num_heads=14,
+    num_kv_heads=2, head_dim=16, rope_theta=10_000.0, rms_eps=1e-6,
+    max_seq_len=256, num_experts=8, experts_per_token=3,
+    expert_act="relu", router_pre_attn=True, sliding_window=8,
+    window_layout=_ST_LAYOUT, rope_layout=_ST_LAYOUT,
 ))
 register(ModelConfig(
     name="tiny-qwen2", family="qwen2", vocab_size=256, hidden_size=64,
@@ -370,6 +432,7 @@ register(ModelConfig(
     head_dim=16, rope_theta=10_000.0, rms_eps=1e-6, tie_embeddings=True,
     max_seq_len=256, sliding_window=8, attn_logit_softcap=50.0,
     final_logit_softcap=30.0, query_pre_attn_scalar=24,
+    window_layout=_GEMMA2_LAYOUT,
 ))
 register(ModelConfig(
     name="tiny-llava", family="llava", vocab_size=256, hidden_size=64,
@@ -404,6 +467,7 @@ _HF_FAMILY = {
     "qwen3": "qwen3",
     "gemma2": "gemma2",
     "mixtral": "mixtral",
+    "smallthinker": "smallthinker",
     "bert": "bert_embed",
 }
 
@@ -472,6 +536,32 @@ def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
             rms_eps=hf.get("layer_norm_eps", 1e-12),
             max_seq_len=hf.get("max_position_embeddings", 512),
         )
+    if family == "smallthinker":
+        if not (hf.get("moe_primary_router_apply_softmax")
+                and hf.get("norm_topk_prob", True)):
+            raise ValueError(
+                f"{path}: smallthinker with a sigmoid or unnormalised "
+                "router is not served (softmax over the chosen only)")
+        return ModelConfig(
+            name=name, family=family,
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf["moe_ffn_hidden_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf["num_key_value_heads"],
+            head_dim=hf.get("head_dim"),
+            rope_theta=float(hf["rope_theta"]),
+            rms_eps=hf.get("rms_norm_eps", 1e-6),
+            tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            max_seq_len=hf.get("max_position_embeddings", 16_384),
+            num_experts=hf["moe_num_primary_experts"],
+            experts_per_token=hf["moe_num_active_primary_experts"],
+            expert_act="relu", router_pre_attn=True,
+            sliding_window=hf["sliding_window_size"],
+            window_layout=tuple(hf["sliding_window_layout"]),
+            rope_layout=tuple(hf["rope_layout"]),
+        )
     scaling = None
     rs = hf.get("rope_scaling") or None
     if rs and rs.get("rope_type", rs.get("type")) == "llama3":
@@ -510,4 +600,6 @@ def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
         attn_logit_softcap=hf.get("attn_logit_softcapping") or 0.0,
         final_logit_softcap=hf.get("final_logit_softcapping") or 0.0,
         query_pre_attn_scalar=hf.get("query_pre_attn_scalar"),
+        window_layout=(_GEMMA2_LAYOUT * -(-hf["num_hidden_layers"] // 2)
+                       if family == "gemma2" else ()),
     )

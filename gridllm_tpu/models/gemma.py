@@ -106,11 +106,7 @@ def _qkv(cfg: ModelConfig, lp: Params, x: jnp.ndarray):
 
 def _layer_windows(cfg: ModelConfig) -> jnp.ndarray:
     """Per-layer sliding window (0 = global): EVEN layers slide."""
-    return jnp.asarray(
-        [cfg.sliding_window if i % 2 == 0 else 0
-         for i in range(cfg.num_layers)],
-        jnp.int32,
-    )
+    return jnp.asarray(cfg.layer_windows, jnp.int32)
 
 
 def _unembed(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:

@@ -43,6 +43,8 @@ Params = dict[str, Any]
 # llama uses the dense SwiGLU `_mlp`; models/mixtral.py routes its sparse
 # MoE body through the same decoder skeleton (attention/norm/paged-cache
 # structure is identical across both families).
+# A routed family's hook (cfg.num_experts) is called through `_ffn` with
+# the router's input as well and returns (output, per-layer statistics).
 MlpFn = Callable[["Params", jnp.ndarray], jnp.ndarray]
 
 # Prefill attention body: (q, k, v, seq_lens) -> attended values. Default
@@ -54,13 +56,54 @@ AttnFn = Callable[
 
 
 def _default_attn(cfg: ModelConfig, mesh=None) -> AttnFn:
-    def attn(q, k, v, seq_lens):
+    # `window`: a patterned family's per-layer scalar; a uniform family's
+    # layers never pass it (nor could they to ring attention's AttnFn)
+    def attn(q, k, v, seq_lens, window=cfg.sliding_window):
         return attention_prefill(
             q, k, v, seq_lens, use_pallas=cfg.use_pallas,
-            window=cfg.sliding_window, mesh=mesh,
+            window=window, mesh=mesh,
         )
 
     return attn
+
+
+def _layer_kinds(cfg: ModelConfig, n: int):
+    """Scan operands of a family whose layers differ in kind
+    (cfg.window_layout / cfg.rope_layout): each layer's window and its
+    RoPE switch, [n] int32 each. None where every layer is alike: the
+    uniform families trace the static window and the unconditional RoPE
+    they always have."""
+    if not (cfg.window_layout or cfg.rope_layout):
+        return None
+    if n != cfg.num_layers:
+        raise NotImplementedError(
+            f"{cfg.name}: a block of {n} of {cfg.num_layers} patterned "
+            "layers (pp stages) does not know its place in the pattern")
+    rope = cfg.rope_layout or (1,) * n
+    return (jnp.asarray(cfg.layer_windows, jnp.int32),
+            jnp.asarray(rope, jnp.int32))
+
+
+def _kind(cfg: ModelConfig, kind, pos: jnp.ndarray):
+    """(window, RoPE positions) of one layer. A patterned family's window
+    is the layer's traced scalar and its positions are multiplied by the
+    layer's switch: at 0 every angle is 0, cos 1 and sin 0, so RoPE is
+    the identity exactly (NoPE)."""
+    if kind is None:
+        return cfg.sliding_window, pos
+    win, rope = kind
+    return win, pos * rope
+
+
+def _ffn(cfg: ModelConfig, mlp: MlpFn, lp: Params, hx: jnp.ndarray,
+         pre: jnp.ndarray):
+    """The feed-forward hook on the post-attention normed state `hx` →
+    (output, per-layer statistics or None). A routed family's hook is also
+    handed the router's input: `pre`, the PRE-attention normed state,
+    where cfg.router_pre_attn says so."""
+    if not cfg.num_experts:
+        return mlp(lp, hx), None
+    return mlp(lp, hx, pre if cfg.router_pre_attn else hx)
 
 
 def _precision(x: jnp.ndarray):
@@ -195,17 +238,21 @@ def hidden_states(
     if seq_lens is None:
         seq_lens = jnp.full((b,), t, jnp.int32)
 
-    def layer(x, lp):
-        hx = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, hx)
-        q = apply_rope(q, pos, inv_freq)
-        k = apply_rope(k, pos, inv_freq)
-        att = attn(q, k, v, seq_lens).reshape(b, t, -1)
+    def layer(x, xs):
+        lp, kind = xs
+        win, lpos = _kind(cfg, kind, pos)
+        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        q, k, v = _qkv(cfg, lp, pre)
+        q = apply_rope(q, lpos, inv_freq)
+        k = apply_rope(k, lpos, inv_freq)
+        att = (attn(q, k, v, seq_lens) if kind is None
+               else attn(q, k, v, seq_lens, window=win)).reshape(b, t, -1)
         x = x + qdot(att, lp["wo"], precision=_precision(x))
         hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        return x + mlp(lp, hx), None
+        return x + _ffn(cfg, mlp, lp, hx, pre)[0], None
 
-    x, _ = jax.lax.scan(layer, x, params["layers"])
+    x, _ = jax.lax.scan(
+        layer, x, (params["layers"], _layer_kinds(cfg, cfg.num_layers)))
     return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 
@@ -260,20 +307,25 @@ def prefill_layers(
     inv_freq = precompute_rope(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
     pos = jnp.arange(t, dtype=jnp.int32)[None]
 
-    def layer(x, lp):
-        hx = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, hx)
-        q = apply_rope(q, pos, inv_freq)
-        k = apply_rope(k, pos, inv_freq)
-        att = attn(q, k, v, seq_lens).reshape(1, t, -1)
+    n = jax.tree.leaves(layers)[0].shape[0]
+
+    def layer(x, xs):
+        lp, kind = xs
+        win, lpos = _kind(cfg, kind, pos)
+        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        q, k, v = _qkv(cfg, lp, pre)
+        q = apply_rope(q, lpos, inv_freq)
+        k = apply_rope(k, lpos, inv_freq)
+        att = (attn(q, k, v, seq_lens) if kind is None
+               else attn(q, k, v, seq_lens, window=win)).reshape(1, t, -1)
         x = seq_c(x + qdot(att, lp["wo"], precision=_precision(x)))
         hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         # K/V ride out as scan ys; the pool is written ONCE after the scan
         # (per-layer writes inside the scan defeat XLA's in-place aliasing
         # and cost full-pool copies — round-4 profiling)
-        return seq_c(x + mlp(lp, hx)), (k[0], v[0])
+        return seq_c(x + _ffn(cfg, mlp, lp, hx, pre)[0]), (k[0], v[0])
 
-    x, (k_new, v_new) = jax.lax.scan(layer, x, layers)
+    x, (k_new, v_new) = jax.lax.scan(layer, x, (layers, _layer_kinds(cfg, n)))
     return x, k_new, v_new
 
 
@@ -402,11 +454,12 @@ def prefill_chunk_layers(
     n = jax.tree.leaves(layers)[0].shape[0]
 
     def layer(x, xs):
-        lp, li = xs
-        hx = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, hx)
-        q = apply_rope(q, pos, inv_freq)
-        k = apply_rope(k, pos, inv_freq)
+        lp, li, kind = xs
+        win, lpos = _kind(cfg, kind, pos)
+        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        q, k, v = _qkv(cfg, lp, pre)
+        q = apply_rope(q, lpos, inv_freq)
+        k = apply_rope(k, lpos, inv_freq)
         # pool holds the PREFIX only (writes deferred past the scan); the
         # fresh chunk's K/V are overlaid inside the attention. Full pool as
         # closure + layer index — see decode_layers.
@@ -414,16 +467,17 @@ def prefill_chunk_layers(
             k_pool, v_pool, page_size,
             q_chunk=q, chunk_row=table_row, chunk_start=start,
             chunk_total=total, k_chunk=k[0], v_chunk=v[0], layer=li,
-            use_pallas=cfg.use_pallas, window=cfg.sliding_window,
+            use_pallas=cfg.use_pallas, window=win,
             mesh=mesh,
         )
         att = att.reshape(1, t, -1)
         x = x + qdot(att, lp["wo"], precision=_precision(x))
         hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        return x + mlp(lp, hx), (k[0], v[0])
+        return x + _ffn(cfg, mlp, lp, hx, pre)[0], (k[0], v[0])
 
     x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (layers, jnp.arange(n, dtype=jnp.int32))
+        layer, x, (layers, jnp.arange(n, dtype=jnp.int32),
+                   _layer_kinds(cfg, n))
     )
     return x, k_new, v_new
 
@@ -446,19 +500,22 @@ def decode_layers(
     matching [N, P, ps, KVH, D] pool block. decode_step runs this over the
     full [L] stack; parallel/pipeline.py runs it per pp stage with the
     stage's local block. x: [S, E] residual stream in; returns
-    (x out, k_new [N, S, KVH, D], v_new) — pool writes are the caller's
-    (deferred one-shot write after the scan).
+    (x out, k_new [N, S, KVH, D], v_new, stats) — pool writes are the
+    caller's (deferred one-shot write after the scan); `stats` are a
+    routed family's per-layer statistics [N, ...] (`_ffn`), None for a
+    dense one.
     """
     s = x.shape[0]
     inv_freq = precompute_rope(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
     n = jax.tree.leaves(layers)[0].shape[0]
 
     def layer(x, xs):
-        lp, li = xs
-        hx = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, hx)  # q: [S, H, D] (T-less), k/v: [S, KVH, D]
-        q = apply_rope(q[:, None], positions[:, None], inv_freq)[:, 0]
-        k = apply_rope(k[:, None], positions[:, None], inv_freq)[:, 0]
+        lp, li, kind = xs
+        win, lpos = _kind(cfg, kind, positions[:, None])
+        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        q, k, v = _qkv(cfg, lp, pre)  # q: [S, H, D] (T-less), k/v: [S, KVH, D]
+        q = apply_rope(q[:, None], lpos, inv_freq)[:, 0]
+        k = apply_rope(k[:, None], lpos, inv_freq)[:, 0]
         # pool holds the prefix only (lengths = positions); the current
         # token's K/V are merged in-register by the attention and written
         # to the pool ONCE after the scan (in-place DMA kernel). The FULL
@@ -471,17 +528,19 @@ def decode_layers(
             q_group=q[:, None], page_table=page_table,
             group_lengths=positions, k_group=k[:, None],
             v_group=v[:, None], layer=li, use_pallas=cfg.use_pallas,
-            window=cfg.sliding_window, mesh=mesh,
+            window=win, mesh=mesh,
         )
         attn = attn[:, 0].reshape(s, -1)
         x = x + qdot(attn, lp["wo"], precision=_precision(x))
         hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        return x + mlp(lp, hx), (k, v)
+        y, stats = _ffn(cfg, mlp, lp, hx, pre)
+        return x + y, (k, v, stats)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (layers, jnp.arange(n, dtype=jnp.int32))
+    x, (k_new, v_new, stats) = jax.lax.scan(
+        layer, x, (layers, jnp.arange(n, dtype=jnp.int32),
+                   _layer_kinds(cfg, n))
     )
-    return x, k_new, v_new
+    return x, k_new, v_new, stats
 
 
 def decode_step(
@@ -492,10 +551,12 @@ def decode_step(
     active: jnp.ndarray,
     mlp: MlpFn = _mlp,
     mesh=None,  # meshed-kernel dispatch (ops) + MoE EP routing
+    with_stats: bool = False,
 ) -> tuple[jnp.ndarray, PagedKVCache]:
     """One decode step for ALL slots. tokens: [S] (last sampled token per
     slot), active: [S] bool. Returns (logits [S, V] fp32, updated cache
-    with lengths advanced for active slots).
+    with lengths advanced for active slots) and, `with_stats`, a routed
+    family's statistics summed over the layers.
     """
     _check_supported(cfg)
     x = params["embed"][tokens]  # [S, E]
@@ -508,7 +569,7 @@ def decode_step(
         cache.lengths + active.astype(jnp.int32), cache.max_context
     )
 
-    x, k_new, v_new = decode_layers(
+    x, k_new, v_new, stats = decode_layers(
         params["layers"], cfg, x, cache.k, cache.v, cache.page_table,
         positions, cache.page_size, mlp, mesh=mesh,
     )
@@ -523,6 +584,8 @@ def decode_step(
         k=k_pool, v=v_pool, page_table=cache.page_table,
         lengths=new_lengths, page_size=cache.page_size,
     )
+    if with_stats:
+        return logits, cache, stats.sum(axis=0)
     return logits, cache
 
 
@@ -544,8 +607,8 @@ def verify_layers(
     once against each slot's paged prefix (ISSUE 5). x: [S, T, E];
     base_lengths: [S] cached-prefix length per slot (candidate i sits at
     absolute position base_lengths[s] + i). Returns (x out, k_new
-    [L, S, T, KVH, D], v_new) — pool writes are the caller's, same
-    deferred-write discipline as decode_layers.
+    [L, S, T, KVH, D], v_new, stats) — pool writes are the caller's, same
+    deferred-write discipline (and `stats`) as decode_layers.
 
     Tree verify (ISSUE 18): with `tree_pos` ([T] node depths) and
     `tree_mask` ([T, T] ancestor-or-self, both static host constants) the
@@ -561,11 +624,12 @@ def verify_layers(
     n = jax.tree.leaves(layers)[0].shape[0]
 
     def layer(x, xs):
-        lp, li = xs
-        hx = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, hx)  # q: [S, T, H, D], k/v: [S, T, KVH, D]
-        q = apply_rope(q, pos, inv_freq)
-        k = apply_rope(k, pos, inv_freq)
+        lp, li, kind = xs
+        win, lpos = _kind(cfg, kind, pos)
+        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        q, k, v = _qkv(cfg, lp, pre)  # q: [S, T, H, D], k/v: [S, T, KVH, D]
+        q = apply_rope(q, lpos, inv_freq)
+        k = apply_rope(k, lpos, inv_freq)
         # pool holds each slot's prefix only; the candidates' K/V are
         # overlaid in-register and written ONCE after the scan (full pool
         # as closure + layer index — see decode_layers). ONE launch over
@@ -575,18 +639,20 @@ def verify_layers(
             q_group=q, page_table=page_table,
             group_lengths=base_lengths, k_group=k, v_group=v,
             layer=li, use_pallas=cfg.use_pallas,
-            window=cfg.sliding_window, mesh=mesh,
+            window=win, mesh=mesh,
             tree_pos=tree_pos, tree_mask=tree_mask,
         )
         att = att.reshape(s, t, -1)
         x = x + qdot(att, lp["wo"], precision=_precision(x))
         hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        return x + mlp(lp, hx), (k, v)
+        y, stats = _ffn(cfg, mlp, lp, hx, pre)
+        return x + y, (k, v, stats)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (layers, jnp.arange(n, dtype=jnp.int32))
+    x, (k_new, v_new, stats) = jax.lax.scan(
+        layer, x, (layers, jnp.arange(n, dtype=jnp.int32),
+                   _layer_kinds(cfg, n))
     )
-    return x, k_new, v_new
+    return x, k_new, v_new, stats
 
 
 def verify_step(
@@ -599,6 +665,7 @@ def verify_step(
     mesh=None,
     tree_pos: jnp.ndarray | None = None,
     tree_mask: jnp.ndarray | None = None,
+    with_stats: bool = False,
 ) -> tuple[jnp.ndarray, PagedKVCache]:
     """One speculative-verify forward for ALL slots (ISSUE 5). tokens:
     [S, T] candidate blocks (col 0 = each slot's committed last token,
@@ -614,14 +681,14 @@ def verify_step(
     STORAGE position lengths[s] + i (the engine compacts the accepted
     path with ops.kvcache.commit_tree_path before rolling lengths
     forward), logits row i is the distribution after consuming node i's
-    root path."""
+    root path. `with_stats`: as decode_step."""
     _check_supported(cfg)
     s, t = tokens.shape
     x = params["embed"][tokens]  # [S, T, E]
     base = cache.lengths
     positions = base[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
 
-    x, k_new, v_new = verify_layers(
+    x, k_new, v_new, stats = verify_layers(
         params["layers"], cfg, x, cache.k, cache.v, cache.page_table,
         base, cache.page_size, mlp, mesh=mesh,
         tree_pos=tree_pos, tree_mask=tree_mask,
@@ -637,6 +704,8 @@ def verify_step(
         k=k_pool, v=v_pool, page_table=cache.page_table,
         lengths=base, page_size=cache.page_size,
     )
+    if with_stats:
+        return logits, cache, stats.sum(axis=0)
     return logits, cache
 
 
@@ -675,11 +744,12 @@ def mixed_layers(
     n = jax.tree.leaves(layers)[0].shape[0]
 
     def layer(x, xs):
-        lp, li = xs
-        hx = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, hx)
-        q = apply_rope(q, pos, inv_freq)
-        k = apply_rope(k, pos, inv_freq)
+        lp, li, kind = xs
+        win, lpos = _kind(cfg, kind, pos)
+        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        q, k, v = _qkv(cfg, lp, pre)
+        q = apply_rope(q, lpos, inv_freq)
+        k = apply_rope(k, lpos, inv_freq)
         oc, og = ragged_paged_attention(
             k_pool, v_pool, page_size,
             q_chunk=q[:, :c], chunk_row=chunk_row, chunk_start=chunk_start,
@@ -687,15 +757,16 @@ def mixed_layers(
             q_group=q[0, c:][:, None], page_table=page_table,
             group_lengths=group_lengths, k_group=k[0, c:][:, None],
             v_group=v[0, c:][:, None], layer=li, use_pallas=cfg.use_pallas,
-            window=cfg.sliding_window, mesh=mesh,
+            window=win, mesh=mesh,
         )
         att = jnp.concatenate([oc[0], og[:, 0]]).reshape(1, t, -1)
         x = x + qdot(att, lp["wo"], precision=_precision(x))
         hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        return x + mlp(lp, hx), (k[0], v[0])
+        return x + _ffn(cfg, mlp, lp, hx, pre)[0], (k[0], v[0])
 
     x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (layers, jnp.arange(n, dtype=jnp.int32))
+        layer, x, (layers, jnp.arange(n, dtype=jnp.int32),
+                   _layer_kinds(cfg, n))
     )
     return x, k_new, v_new
 

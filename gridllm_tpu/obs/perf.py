@@ -123,6 +123,32 @@ VERIFY_CTX_TOKENS_TOTAL = _OBS.counter(
     ("model",),
 )
 
+VERIFY_WINDOW_TOKENS_TOTAL = _OBS.counter(
+    "gridllm_engine_verify_window_tokens_total",
+    "As gridllm_engine_verify_ctx_tokens_total, with every layer's sliding "
+    "window applied: sum over live slots of the mean over layers of "
+    "min(context, that layer's window), the KV positions a launch's ragged "
+    "kernel has to read. Equal to the context counter for a family with no "
+    "window; their ratio is the share of the context that window layers "
+    "leave to be read.",
+    ("model",),
+)
+MOE_EXPERT_ROWS_TOTAL = _OBS.counter(
+    "gridllm_moe_expert_rows_total",
+    "Routed families: live token rows routed by the verify / decode block "
+    "launches, summed over layers (rows of inactive slots are not live). "
+    "Read from the launch's own fetch; nothing for a dense family.",
+    ("model",),
+)
+MOE_EXPERTS_TOUCHED_TOTAL = _OBS.counter(
+    "gridllm_moe_experts_touched_total",
+    "Routed families: experts with at least one live row, summed over "
+    "layers and launches. Over num_experts x layers x "
+    "gridllm_engine_phase_seconds_count{phase=\"dispatch_verify\"} it is "
+    "the share of the held experts a launch has to read.",
+    ("model",),
+)
+
 # -- XLA compilations, as jax itself reports them ----------------------------
 # The recompile tripwire above sees Python-level signatures only; under a
 # mesh the first program compiles a second time once the state's layouts

@@ -171,7 +171,7 @@ def decode_step(
 
         def stage(args):
             x, kp, vp = args
-            x, k_new, v_new = llama.decode_layers(
+            x, k_new, v_new, _ = llama.decode_layers(
                 params["layers"], cfg, x, kp, vp, page_table, positions,
                 cache.page_size, mlp,
             )
@@ -217,7 +217,7 @@ def decode_step(
             return llama.decode_layers(
                 params["layers"], cfg, x_in, k_pool, v_pool, pt, pos,
                 cache.page_size, mlp,
-            )
+            )[:3]
 
         for t in range(2 * pp - 1):  # static unroll: pipeline schedule
             m = t - p                # this tick's microbatch for this stage

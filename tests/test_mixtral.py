@@ -77,7 +77,7 @@ def test_moe_mlp_matches_per_token_brute_force(params_fp32):
     gating bugs (dropped renormalization, wrong combine) without torch."""
     x = jax.random.normal(jax.random.PRNGKey(2), (5, CFG.hidden_size), jnp.float32)
     lp = jax.tree.map(lambda a: a[0], params_fp32["layers"])
-    got = np.asarray(mixtral._moe_mlp(CFG, None, lp, x))
+    got = np.asarray(mixtral._moe_mlp(CFG, None, None, lp, x)[0])
 
     def silu(a):
         return a / (1.0 + np.exp(-a))
@@ -166,8 +166,9 @@ def test_ragged_dispatch_matches_dense():
     lp = jax.tree.map(lambda a: a[0], params["layers"])  # layer 0 slice
     for t in (16, 33, 128):
         x = jax.random.normal(jax.random.PRNGKey(t), (1, t, cfg.hidden_size))
-        dense = _moe_mlp_dense(cfg, lp, x)
-        ragged = _moe_mlp_ragged(cfg, lp, x)
+        route = mixtral._route(cfg, lp, x)
+        dense = _moe_mlp_dense(cfg, lp, x, *route)
+        ragged = _moe_mlp_ragged(cfg, lp, x, *route)
         np.testing.assert_allclose(
             np.asarray(ragged), np.asarray(dense), rtol=2e-5, atol=2e-5,
         )
@@ -208,10 +209,10 @@ def test_meshed_ep_ragged_matches_dense(monkeypatch):
     x = jax.random.normal(jax.random.PRNGKey(6), (1, 32, cfg.hidden_size),
                           jnp.float32)
 
-    dense = mixtral._moe_mlp_dense(cfg, lp, x)
+    dense = mixtral._moe_mlp_dense(cfg, lp, x, *mixtral._route(cfg, lp, x))
     monkeypatch.setenv("GRIDLLM_MOE_RAGGED", "1")
     with mesh:
-        ragged = mixtral._moe_mlp(cfg, mesh, lp, x)
+        ragged = mixtral._moe_mlp(cfg, mesh, None, lp, x)[0]
     np.testing.assert_allclose(
         np.asarray(ragged), np.asarray(dense), rtol=2e-4, atol=2e-4,
     )
@@ -236,13 +237,13 @@ def test_meshed_moe_selects_ragged_for_prefill(monkeypatch):
         mixtral, "_moe_mlp_ragged_ep", wraps=mixtral._moe_mlp_ragged_ep
     ) as spy:
         with mesh:
-            mixtral._moe_mlp(cfg, mesh, lp, x)
+            mixtral._moe_mlp(cfg, mesh, None, lp, x)
         assert spy.called
     # decode-sized batch stays dense under the mesh
     xs = jnp.zeros((4, cfg.hidden_size), jnp.float32)
     with mock.patch.object(mixtral, "_moe_mlp_ragged_ep") as spy2:
         with mesh:
-            mixtral._moe_mlp(cfg, mesh, lp, xs)
+            mixtral._moe_mlp(cfg, mesh, None, lp, xs)
         assert not spy2.called
 
 
@@ -259,7 +260,8 @@ def test_delegation_threads_mesh_to_llama():
     mesh = build_mesh(MeshConfig(tp=2, dp=4))
     seen = {}
 
-    def spy_decode(params, c, tokens, cache, active, mlp=None, mesh=None):
+    def spy_decode(params, c, tokens, cache, active, mlp=None, mesh=None,
+                   **kw):
         seen["decode"] = mesh
         raise RuntimeError("stop")
 
@@ -275,8 +277,9 @@ def test_delegation_threads_mesh_to_llama():
             pass
     with mock.patch.object(mixtral.llama, "prefill_chunk", spy_chunk):
         try:
-            mixtral.prefill_chunk(None, cfg, None, None, None, None, None,
-                                  None, mesh=mesh)
+            # a real chunk: the wrapper marks its live rows for the router
+            mixtral.prefill_chunk(None, cfg, jnp.zeros((8,), jnp.int32), None,
+                                  jnp.int32(8), None, None, None, mesh=mesh)
         except RuntimeError:
             pass
     assert seen["decode"] is mesh

@@ -226,7 +226,8 @@ def test_no_trace_annotation_is_constructed_with_no_capture_active(monkeypatch):
     assert names == {"gridllm." + p for p in PHASES}
     assert all(s.open is False for s in made)       # each closed by the next mark
     launch = next(s for s in made if s.name == "gridllm.dispatch_verify")
-    assert set(launch.meta) == {"gen", "slots", "ctx_tokens", "mesh"}
+    assert set(launch.meta) == {"gen", "slots", "ctx_tokens", "mesh", "experts",
+                                "window_layers"}
     assert launch.meta["mesh"] == ""                # unmeshed; "tp:4" under one
     assert launch.meta["slots"] >= 1 and launch.meta["ctx_tokens"] > 0
     admit = next(s for s in made if s.name == "gridllm.admit")
