@@ -22,6 +22,14 @@ heads of 128 (a query group of 7), a traced per-layer window of 4096, 16
 slots, contexts of 3 k and 7 k tokens, in the ragged kernel's decode,
 verify, chunk and mixed forms and in flash prefill.
 
+The ``mla_*`` cases are DeepSeek-V2-Lite's latent attention (PR 36): ONE
+cache head whose row is key and value at once, no V pool, 16 query heads
+on it, 16 slots at contexts of 2 k and 4 k, in the ragged kernel's decode,
+verify (Td = 5), chunk (512 rows behind prefixes of 0, 2 k and 4 k) and
+mixed forms. The pool is stored at 640 lanes and the queries are 576 wide (the
+dispatcher pads them: what the engine runs); a pool stored at 576 is what
+Mosaic refuses (tests/test_deepseek_v2.py compiles both for the chip).
+
 The case ``paths`` is the one whole-model comparison: a prompt
 shorter than a prefill chunk is answered by bucketed flash prefill when
 cold and by the chunk program behind its cached pages on a prefix-cache
@@ -62,6 +70,11 @@ G7 = {"H": 28, "KVH": 4, "NP": 384, "S": 16, "WINDOW": 4096,
       "LENGTHS": (7000, 0, 3000, 4095, 4097, 129, 6200, 300,
                   1, 128, 3071, 5000, 0, 600, 2000, 4096)}
 WINDOW = 0
+# the mla cases' shapes: one cache head, a row of 512 + 64, 16 query heads
+MLA = {"H": 16, "KVH": 1, "NP": 640, "S": 16, "C": 512,
+       "LENGTHS": (4000, 0, 2048, 4095, 4097, 129, 2200, 300,
+                   1, 128, 3071, 2000, 0, 600, 2049, 4096)}
+MLA_ROW, MLA_DV = 576, 512
 
 
 def _interpret() -> bool:
@@ -140,6 +153,56 @@ def _g7(build: Callable[[], Case]) -> Case:
         return build()
     finally:
         globals().update(saved)
+
+
+def _mla(chunk: bool, td: int, chunk_start: int = 0, stored: int = 640):
+    """A latent read under DeepSeek-V2-Lite's shapes: queries as wide as
+    the row (576), a pool stored at 640 lanes, no V anywhere."""
+    saved = {k: globals()[k] for k in MLA}
+    globals().update(MLA)
+    try:
+        kp = _rand(1, (L, NP, PS, 1, stored))
+        if stored != MLA_ROW:     # the padding lanes hold zeros, as written
+            kp = kp.at[..., MLA_ROW:].set(0)
+        kw: dict[str, Any] = {}
+        valid: list = []
+        if chunk:
+            start, total = chunk_start, chunk_start + C - 60
+            row = np.full((MAXP,), -1, np.int32)
+            row[:40] = np.arange(NP - 1, NP - 41, -1)
+            kw.update(
+                q_chunk=_rand(3, (1, C, H, MLA_ROW)),
+                chunk_row=jnp.asarray(row),
+                chunk_start=jnp.int32(start), chunk_total=jnp.int32(total),
+                k_chunk=_rand(4, (C, 1, MLA_ROW)))
+            valid.append(np.arange(C)[None, :] < total - start)
+        if td:
+            lens = np.asarray(LENGTHS, np.int32)
+            kw.update(
+                q_group=_rand(6, (S, td, H, MLA_ROW)),
+                page_table=jnp.asarray(_page_table()),
+                group_lengths=jnp.asarray(lens),
+                k_group=_rand(7, (S, td, 1, MLA_ROW)))
+            valid.append(lens > 0)
+    finally:
+        globals().update(saved)
+    names = sorted(kw)
+
+    def pick(outs):
+        return tuple(o for o in outs if o is not None)
+
+    def run(kp, *dyn):
+        return pick(A.ragged_paged_attention(
+            kp, None, PS, layer=jnp.int32(LAYER), use_pallas=True,
+            latent_dv=MLA_DV, **dict(zip(names, dyn))))
+
+    def ref(kp, *dyn):
+        return pick(A.ragged_paged_attention_ref(
+            kp[..., :MLA_ROW], None, PS, layer=jnp.int32(LAYER),
+            latent_dv=MLA_DV, **dict(zip(names, dyn))))
+
+    return Case("ragged_attention", run, ref,
+                (kp, *(kw[n] for n in names)), tuple(valid))
 
 
 def _ragged(chunk: bool, td: int, quant: bool = False, tree: bool = False,
@@ -329,6 +392,12 @@ CASES: dict[str, Callable[[], Case]] = {
     "g7_chunk3k": lambda: _g7(lambda: _ragged(True, 0, chunk_start=2048)),
     "g7_chunk7k": lambda: _g7(lambda: _ragged(True, 0, chunk_start=6144)),
     "g7_mixed7k": lambda: _g7(lambda: _ragged(True, 1, chunk_start=6144)),
+    "mla_decode": lambda: _mla(False, 1),
+    "mla_verify": lambda: _mla(False, TD_VERIFY),
+    "mla_chunk0": lambda: _mla(True, 0, chunk_start=0),
+    "mla_chunk2k": lambda: _mla(True, 0, chunk_start=2048),
+    "mla_chunk4k": lambda: _mla(True, 0, chunk_start=4096),
+    "mla_mixed4k": lambda: _mla(True, 1, chunk_start=4096),
     "g7_flash1024": lambda: _g7(
         lambda: _flash(PK.flash_prefill, 1024, window=4096)),
     "g7_flash1024_w256": lambda: _g7(

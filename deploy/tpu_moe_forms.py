@@ -13,7 +13,10 @@ call, the experts the rows touch, those experts' bytes and the bytes/s
 that makes. ``--ops`` also captures one profiler
 trace a form and prints its largest device operations by name, which is
 how a reader's pattern for the grouped products is found. What it read
-on the v5e is in models/mixtral.py's docstring and PERF.md (PR 33).
+on the v5e is in models/mixtral.py's docstring and PERF.md (PR 33; PR 36
+for ``--model deepseek-v2-lite:16b``: 64 experts of 2048 x 1408, whose
+router is not renormalised; the shared experts are outside both forms
+and are not timed here).
 """
 
 from __future__ import annotations
@@ -60,7 +63,7 @@ def main() -> None:
     ap.add_argument("--ops", action="store_true")
     args = ap.parse_args()
     cfg = get_config(args.model)
-    e, f, x = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
+    e, f, x = cfg.hidden_size, cfg.expert_width, cfg.num_experts
     dev = jax.devices()[0]
     print(f"device: {dev.platform} {dev.device_kind}; {args.model}: "
           f"{x} experts of {e}x{f}, top-{cfg.experts_per_token}, "

@@ -171,6 +171,20 @@ _CHUNK_TOKENS = _OBS.counter(
     "prompt tokens, padded = the launches' widths, padding included).",
     ("model", "kind"),
 )
+_KV_ROW_BYTES = _OBS.gauge(
+    "gridllm_kv_row_bytes",
+    "Bytes of one token's row in one layer of the page pool as stored "
+    "(lane padding included), by model and kind: latent (one row shared "
+    "by every head) or kv (K and V per KV head). Set at pool creation.",
+    ("model", "kind"),
+)
+_KV_ROW_BYTES_EQUIV = _OBS.gauge(
+    "gridllm_kv_row_bytes_per_head_equiv",
+    "Bytes the same row would take stored as K and V per head at the "
+    "model's own head sizes, unpadded; equals gridllm_kv_row_bytes for a "
+    "family that stores K and V per head unpadded.",
+    ("model",),
+)
 # elastic serving (ISSUE 20): cold-start cost, by how the weights arrived
 # — "snapshot" (host-RAM weight tier hit), "checkpoint" (safetensors
 # re-read), "init" (fresh random init). The ModelColdStartSlow alert keys
@@ -293,6 +307,10 @@ def _model_module(cfg: ModelConfig):
         from gridllm_tpu.models import mixtral
 
         return mixtral
+    if cfg.family == "deepseek_v2":
+        from gridllm_tpu.models import deepseek
+
+        return deepseek
     if cfg.family == "bert_embed":
         from gridllm_tpu.models import bert_embed
 
@@ -563,6 +581,12 @@ class InferenceEngine:
         self._layer_windows = np.asarray(
             [w or np.inf for w in self.cfg.layer_windows])
         self._windowed = int(np.isfinite(self._layer_windows).sum())
+        # span meta of every launch: what a cache row is and the form its
+        # attention reads it in (a latent family: absorbed in every region)
+        self._attn_meta = (
+            {"cache_row": "latent", "attn_form": "absorbed"}
+            if self.cfg.kv_lora_rank
+            else {"cache_row": "kv", "attn_form": "per_head"})
         self.embedding_only = self.cfg.family == "bert_embed"
         self.tokenizer: Tokenizer = get_tokenizer(
             config.tokenizer, self.cfg.vocab_size
@@ -924,6 +948,12 @@ class InferenceEngine:
             on = env_bool("GRIDLLM_KV_INT8")
         if not on or self.embedding_only:
             return False
+        if self.cfg.kv_lora_rank:
+            raise ValueError(
+                f"{self.cfg.name}: an int8 KV pool is not served for a "
+                "latent cache (one row is key and value at once; no "
+                "quantised read of it has been written or measured): "
+                "unset GRIDLLM_KV_INT8 / EngineConfig.kv_int8")
         if self.mesh is not None:
             log.info("int8 KV pool disabled: meshed pools keep the fp "
                      "layout", model=self.cfg.name)
@@ -940,6 +970,11 @@ class InferenceEngine:
             cap = env_int("GRIDLLM_KV_HOST_BYTES")
         if cap <= 0 or self.embedding_only:
             return None
+        if self.cfg.kv_lora_rank:
+            raise ValueError(
+                f"{self.cfg.name}: the host KV tier is not served for a "
+                "latent cache (its spill format is K and V pages): unset "
+                "GRIDLLM_KV_HOST_BYTES / EngineConfig.kv_host_bytes")
         if self._prefix_cache_cap == 0 or self.mesh is not None:
             log.info("host KV tier disabled: needs the prefix cache and "
                      "an unsharded pool", model=self.cfg.name,
@@ -1075,13 +1110,17 @@ class InferenceEngine:
             local_kv_heads,
         )
 
-        d = self.cfg.head_dim_
+        # a latent family's row (cfg.cache_dim: 576 at DeepSeek-V2-Lite)
+        # has one cache head and no flat-lane view: Mosaic tiles the HBM
+        # page [ps, 576] at 640 lanes and refuses to slice 576 of them, so
+        # the row is stored lane-padded (PERF.md, PR 36)
+        d = self.cfg.cache_dim
         use, interpret = _pallas_mode(self.cfg.use_pallas)
         if not use:
             return d
         if interpret and not env_bool("GRIDLLM_POOL_PAD"):
             return d
-        kvh = local_kv_heads(self.cfg.num_kv_heads, self.mesh)
+        kvh = local_kv_heads(self.cfg.cache_heads, self.mesh)
         if flat_lanes_ok(kvh, d):
             # flat-lane layout: page rows are lane-aligned viewed
             # flat ([ps, KVH*D] — PER tp SHARD, where kv heads split), so
@@ -1097,9 +1136,9 @@ class InferenceEngine:
         dpool = self._pool_head_dim()
         if not self._kv_int8:
             return PagedKVCache.create(
-                mc.num_layers, num_pages, c.page_size, mc.num_kv_heads,
+                mc.num_layers, num_pages, c.page_size, mc.cache_heads,
                 dpool, c.max_slots, c.max_pages_per_slot,
-                dtype=jnp.dtype(c.dtype),
+                dtype=jnp.dtype(c.dtype), latent=bool(mc.kv_lora_rank),
             )
         # resident int8 pool (ISSUE 11): QuantPages where the fp pool
         # arrays would sit — int8 values + one f32 scale per (layer,
@@ -1198,19 +1237,32 @@ class InferenceEngine:
         for buf in jax.tree.leaves(old):
             buf.delete()
         dpool = self._pool_head_dim()
-        if dpool != mc.head_dim_:
+        if dpool != mc.cache_dim:
             # lane padding multiplies KV bytes per page while num_pages is
             # config-fixed — say so at startup instead of silently serving
             # with a pool that costs dpool/d× the HBM the config budgeted
             # (ADVICE r05: d=64 models pay 2×)
             log.warning(
                 "page pool lane-padded; KV bytes per page scaled",
-                model=mc.name, head_dim=mc.head_dim_, pool_head_dim=dpool,
-                kv_bytes_factor=round(dpool / mc.head_dim_, 2),
+                model=mc.name, head_dim=mc.cache_dim, pool_head_dim=dpool,
+                kv_bytes_factor=round(dpool / mc.cache_dim, 2),
                 num_pages=c.num_pages,
                 hint=f"to keep KV HBM at the unpadded budget, set "
-                     f"num_pages={int(c.num_pages * mc.head_dim_ / dpool)}",
+                     f"num_pages={int(c.num_pages * mc.cache_dim / dpool)}",
             )
+        itemsize = 1 if self._kv_int8 else jnp.dtype(c.dtype).itemsize
+        kind = "latent" if mc.kv_lora_rank else "kv"
+        # the row as stored: every cache head of K (and V) at the pool's width
+        row_bytes = mc.kv_row_values // mc.cache_dim * dpool * itemsize
+        _KV_ROW_BYTES.set(row_bytes, model=mc.name, kind=kind)
+        # K and V per head at the model's own head sizes: a latent family
+        # would store a key of head_dim and a value of v_head_dim a head
+        _KV_ROW_BYTES_EQUIV.set(
+            (mc.num_heads * (mc.head_dim_ + mc.v_head_dim) if mc.kv_lora_rank
+             else mc.kv_row_values) * itemsize, model=mc.name)
+        log.info("kv pool rows", model=mc.name, cacheRow=kind,
+                 kvRowBytes=row_bytes, rowValues=mc.kv_row_values,
+                 poolRowDim=dpool)
         if self.mesh is not None:
             # built under jit with the mesh's shardings: no device ever
             # holds more than its shard (a pool sized to several chips'
@@ -1881,7 +1933,7 @@ class InferenceEngine:
         # dispatch in this function (counters, gauges) stays in this phase
         self._clock.mark("dispatch_prefill", request=req.id,
                          prompt_tokens=len(ids), cached_tokens=cached,
-                         mesh=self.mesh_axes)
+                         mesh=self.mesh_axes, **self._attn_meta)
         with self.dispatch_lock:
             # emit AFTER the dispatch succeeds: a record for a program the
             # liaison never actually issued would make followers replay a
@@ -1930,7 +1982,7 @@ class InferenceEngine:
         _KV_PAGES_CACHED.set(cached, model=self.cfg.name)
         # per-tier residency (ISSUE 11): hbm = reuse-LRU pages at pool
         # bytes/page, host = encoded bytes actually held by the tier
-        kv_bytes = self.cache.k.nbytes + self.cache.v.nbytes
+        kv_bytes = self.cache.pool_nbytes
         bpp = kv_bytes / max(self.config.num_pages, 1)
         tier = self.host_tier
         set_tier_gauges(
@@ -2627,7 +2679,8 @@ class InferenceEngine:
         self._clock.mark("dispatch_verify", gen=self._gen + 1,
                          slots=len(self._slots), ctx_tokens=ctx,
                          mesh=self.mesh_axes, experts=self.cfg.num_experts,
-                         window_layers=self._windowed)
+                         window_layers=self._windowed,
+                         **self._attn_meta)
 
     def _count_step_stats(self, stats: np.ndarray) -> None:
         """A launch's [live rows routed, experts touched] (summed over
@@ -2971,7 +3024,11 @@ class InferenceEngine:
         return (not self.embedding_only
                 and self._prefix_cache_cap != 0
                 and self.mesh is None
-                and self.plan_sink is None)
+                and self.plan_sink is None
+                # the wire's header and its pages are K and V per head: a
+                # latent pool is neither exported nor imported (the
+                # request is served where it arrived)
+                and not self.cfg.kv_lora_rank)
 
     def export_prefix_pages(self, token_ids: list[int]) -> dict[str, Any] | None:
         """Gather the longest cached full-page prefix of `token_ids` as
@@ -3417,9 +3474,10 @@ class InferenceEngine:
             out["kv"] = [cache.k.data, cache.k.scale, cache.v.data,
                          cache.v.scale, cache.page_table, cache.lengths]
         else:
-            out["kv"] = [cache.k, cache.v, cache.page_table, cache.lengths]
+            out["kv"] = [a for a in (cache.k, cache.v, cache.page_table,
+                                     cache.lengths) if a is not None]
         c, mc = self.config, self.cfg
-        kv_bytes = cache.k.nbytes + cache.v.nbytes
+        kv_bytes = cache.pool_nbytes
         bpp = kv_bytes / max(c.num_pages, 1)
         used = c.num_pages - self.alloc.free_pages - self.alloc.cached_pages
         live_tokens = sum(len(st.ids) for st in list(self._slots.values()))
@@ -3440,12 +3498,16 @@ class InferenceEngine:
             # Under the ragged flat-lane layout (kvLayout "ragged") the
             # pool stays UNPADDED, so this reads 0
             "lanePadOverheadBytes": int(
-                kv_bytes * (1 - mc.head_dim_ / dpool)) if dpool else 0,
+                kv_bytes * (1 - mc.cache_dim / dpool)) if dpool else 0,
             # "ragged" = an unpadded pool (the zero-overhead case the
             # README documents); "ragged-padded" = the shape can't go
             # flat-lane (e.g. KVH=1, d=64), so the pool still pays the pad
             "kvLayout": (
-                "ragged" if dpool == mc.head_dim_ else "ragged-padded"),
+                "ragged" if dpool == mc.cache_dim else "ragged-padded"),
+            # what a token's row of one layer is: K and V per KV head, or
+            # one latent row shared by every head (no V array)
+            "cacheRow": "latent" if mc.kv_lora_rank else "kv",
+            "rowBytes": int(bpp / max(c.page_size * mc.num_layers, 1)),
             "liveTokens": live_tokens,
             # internal fragmentation of the live allocation: capacity
             # reserved at admission (num_predict headroom + tail pages)

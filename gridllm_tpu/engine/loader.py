@@ -114,6 +114,12 @@ def load_checkpoint(
         from gridllm_tpu.models import llava
 
         return llava.from_getter(cfg, get, dtype, place)
+    if cfg.family == "deepseek_v2":
+        # two stacked trees (the leading dense layers, the routed ones)
+        # and re-paired RoPE columns: the family assembles its own
+        from gridllm_tpu.models import deepseek
+
+        return deepseek.from_getter(cfg, get, dtype, place)
     return hf_layout.to_pytree(cfg, get, _name_map(cfg), dtype, place)
 
 
@@ -124,6 +130,10 @@ def save_checkpoint(params: Any, cfg: ModelConfig, path: str) -> None:
 
     from gridllm_tpu.models import hf_layout
 
+    if cfg.family == "deepseek_v2":
+        raise NotImplementedError(
+            "deepseek_v2 checkpoints are read (models/deepseek.from_getter), "
+            "not written: the inverse of its RoPE re-pairing is not here")
     os.makedirs(path, exist_ok=True)
     if cfg.family == "bert_embed":
         from gridllm_tpu.models import bert_embed

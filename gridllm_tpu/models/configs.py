@@ -46,8 +46,9 @@ class VisionConfig:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    # llama | qwen2 | qwen3 | gemma2 | mixtral | smallthinker | llava |
-    # bert_embed (engine._model_module picks the module)
+    # llama | qwen2 | qwen3 | gemma2 | mixtral | smallthinker |
+    # deepseek_v2 | llava | bert_embed (engine._model_module picks the
+    # module)
     family: str = "llama"
     vocab_size: int = 128_256
     hidden_size: int = 4096
@@ -62,9 +63,29 @@ class ModelConfig:
     tie_embeddings: bool = False
     max_seq_len: int = 8192
     # MoE (mixtral, smallthinker): an expert's width is intermediate_size
+    # unless moe_intermediate_size says otherwise (deepseek_v2, whose
+    # intermediate_size is the leading dense layers' width)
     num_experts: int = 0
     experts_per_token: int = 2
     expert_act: str = "silu"         # "silu" (SwiGLU) | "relu" (ReGLU)
+    moe_intermediate_size: int = 0
+    # deepseek_v2: experts every token takes, unweighted (one SwiGLU of
+    # num_shared_experts x the expert width); the top-k weights as the
+    # softmax gave them (not renormalised) times routed_scaling_factor;
+    # the first first_k_dense layers are dense SwiGLUs of intermediate_size
+    num_shared_experts: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    first_k_dense: int = 0
+    # latent attention (MLA, deepseek_v2): the cache row of a token in a
+    # layer is ONE latent of kv_lora_rank and one RoPE key of
+    # qk_rope_head_dim, shared by every head (`cache_heads`, `cache_dim`);
+    # a query/key head is qk_nope_head_dim + qk_rope_head_dim (= head_dim)
+    # and a value head v_head_dim. 0 = K and V per KV head
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # smallthinker: the router reads the PRE-attention normed state, not
     # the post-attention one the experts compute on
     router_pre_attn: bool = False
@@ -109,6 +130,30 @@ class ModelConfig:
         return self.head_dim or self.hidden_size // self.num_heads
 
     @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def cache_heads(self) -> int:
+        """Heads of a cache row as the paged pool stores it: one for a
+        latent cache, whatever num_kv_heads says of the published model."""
+        return 1 if self.kv_lora_rank else self.num_kv_heads
+
+    @property
+    def cache_dim(self) -> int:
+        """Values a cache head of one token holds (before lane padding):
+        the latent and its RoPE key, or a K (= V) head."""
+        if self.kv_lora_rank:
+            return self.kv_lora_rank + self.qk_rope_head_dim
+        return self.head_dim_
+
+    @property
+    def kv_row_values(self) -> int:
+        """Values one token's row of one layer holds in the pool."""
+        return self.cache_heads * self.cache_dim * (1 if self.kv_lora_rank
+                                                    else 2)
+
+    @property
     def layer_windows(self) -> tuple[int, ...]:
         """Each layer's window (0 = global)."""
         on = self.window_layout or (1,) * self.num_layers
@@ -129,10 +174,10 @@ class ModelConfig:
             max_position_embeddings=self.max_seq_len,
             attention_bias=False,
         )
-        if self.family == "smallthinker":
+        if self.family in ("smallthinker", "deepseek_v2"):
             raise NotImplementedError(
-                "smallthinker has no transformers twin here: its reference "
-                "is benchmark/reference/smallthinker_f32.py")
+                f"{self.family} has no transformers twin here: its "
+                "reference is under benchmark/reference/")
         if self.family == "mixtral":
             from transformers import MixtralConfig
 
@@ -363,6 +408,26 @@ register(ModelConfig(
     window_layout=_ST_LAYOUT * 13, rope_layout=_ST_LAYOUT * 13,
 ))
 
+# DeepSeek-V2-Lite (deepseek-ai, config.json): latent attention (no
+# q_lora), one leading dense layer of 10,944, then 64 experts of 1,408
+# top-6 with 2 shared, softmax scores not renormalised, YaRN factor 40.
+# num_kv_heads is the published 16; the pool holds one latent row a token
+# (cache_heads / cache_dim)
+_DSV2_YARN = RopeScaling(
+    rope_type="yarn", factor=40.0, beta_fast=32.0, beta_slow=1.0,
+    mscale=0.707, mscale_all_dim=0.707,
+    original_max_position_embeddings=4096,
+)
+register(ModelConfig(
+    name="deepseek-v2-lite:16b", family="deepseek_v2", vocab_size=102_400,
+    hidden_size=2048, intermediate_size=10_944, num_layers=27, num_heads=16,
+    num_kv_heads=16, head_dim=192, rope_theta=10_000.0,
+    rope_scaling=_DSV2_YARN, rms_eps=1e-6, max_seq_len=163_840,
+    num_experts=64, experts_per_token=6, moe_intermediate_size=1408,
+    num_shared_experts=2, norm_topk_prob=False, routed_scaling_factor=1.0,
+    first_k_dense=1, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128,
+))
 register(ModelConfig(
     name="all-minilm", family="bert_embed", vocab_size=30_522,
     hidden_size=384, intermediate_size=1536, num_layers=6, num_heads=12,
@@ -395,6 +460,23 @@ register(ModelConfig(
     max_seq_len=256, num_experts=8, experts_per_token=3,
     expert_act="relu", router_pre_attn=True, sliding_window=8,
     window_layout=_ST_LAYOUT, rope_layout=_ST_LAYOUT,
+))
+# deepseek-v2-lite's shape in small: one dense layer then three expert
+# layers, a latent of 32 with a RoPE key of 16, one shared expert, top-3
+# not renormalised, YaRN on with an original context shorter than the
+# test contexts
+register(ModelConfig(
+    name="tiny-deepseek-v2", family="deepseek_v2", vocab_size=256,
+    hidden_size=64, intermediate_size=96, num_layers=4, num_heads=4,
+    num_kv_heads=4, head_dim=32, rope_theta=10_000.0,
+    rope_scaling=RopeScaling(
+        rope_type="yarn", factor=4.0, beta_fast=32.0, beta_slow=1.0,
+        mscale=0.707, mscale_all_dim=0.707,
+        original_max_position_embeddings=64),
+    rms_eps=1e-6, max_seq_len=256, num_experts=8, experts_per_token=3,
+    moe_intermediate_size=32, num_shared_experts=1, norm_topk_prob=False,
+    first_k_dense=1, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=16, v_head_dim=16,
 ))
 register(ModelConfig(
     name="tiny-qwen2", family="qwen2", vocab_size=256, hidden_size=64,
@@ -468,6 +550,7 @@ _HF_FAMILY = {
     "gemma2": "gemma2",
     "mixtral": "mixtral",
     "smallthinker": "smallthinker",
+    "deepseek_v2": "deepseek_v2",
     "bert": "bert_embed",
 }
 
@@ -484,6 +567,67 @@ def config_from_hf_dir(name: str, path: str) -> ModelConfig:
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
     return _config_from_hf_dict(name, hf, path)
+
+
+def _deepseek_v2_from_hf(name: str, hf: dict, path: str) -> ModelConfig:
+    """DeepseekV2ForCausalLM's published keys. What this program does not
+    serve is refused here, not run wrong: a low-rank query (q_lora_rank),
+    group-limited or sigmoid routing, expert layers at a stride."""
+    unserved = {
+        "q_lora_rank": hf.get("q_lora_rank") is not None,
+        "scoring_func": hf.get("scoring_func", "softmax") != "softmax",
+        "topk_method": hf.get("topk_method", "greedy") != "greedy",
+        "n_group": (hf.get("n_group") or 1) != 1,
+        "moe_layer_freq": hf.get("moe_layer_freq", 1) != 1,
+        "attention_bias": bool(hf.get("attention_bias")),
+        "hidden_act": hf.get("hidden_act", "silu") != "silu",
+    }
+    if any(unserved.values()):
+        raise ValueError(
+            f"{path}: deepseek_v2 with "
+            f"{[k for k, v in unserved.items() if v]} as published is not "
+            "served (no q_lora, softmax scores, greedy top-k, one group, "
+            "experts in every layer past the dense ones, no bias, silu)")
+    scaling = None
+    rs = hf.get("rope_scaling") or None
+    if rs:
+        if rs.get("rope_type", rs.get("type")) != "yarn":
+            raise ValueError(f"{path}: deepseek_v2 rope_scaling {rs!r}")
+        scaling = RopeScaling(
+            rope_type="yarn", factor=float(rs["factor"]),
+            beta_fast=float(rs.get("beta_fast", 32)),
+            beta_slow=float(rs.get("beta_slow", 1)),
+            mscale=float(rs.get("mscale", 1)),
+            mscale_all_dim=float(rs.get("mscale_all_dim", 0)),
+            original_max_position_embeddings=rs[
+                "original_max_position_embeddings"],
+        )
+    return ModelConfig(
+        name=name, family="deepseek_v2",
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
+        head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+        rope_theta=float(hf.get("rope_theta", 10_000.0)),
+        rope_scaling=scaling,
+        rms_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_seq_len=hf.get("max_position_embeddings", 163_840),
+        num_experts=hf["n_routed_experts"],
+        experts_per_token=hf["num_experts_per_tok"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_shared_experts=hf.get("n_shared_experts") or 0,
+        norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        first_k_dense=hf.get("first_k_dense_replace", 0),
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+    )
 
 
 def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
@@ -562,6 +706,8 @@ def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
             window_layout=tuple(hf["sliding_window_layout"]),
             rope_layout=tuple(hf["rope_layout"]),
         )
+    if family == "deepseek_v2":
+        return _deepseek_v2_from_hf(name, hf, path)
     scaling = None
     rs = hf.get("rope_scaling") or None
     if rs and rs.get("rope_type", rs.get("type")) == "llama3":

@@ -1,13 +1,18 @@
 """Routed-experts decoders: Mixtral (BASELINE.md config #4: mixtral:8x7b EP)
-and SmallThinker (smallthinker:21b, PR 33).
+and SmallThinker (smallthinker:21b, PR 33); `_moe_mlp` is also
+DeepSeek-V2's expert layer (models/deepseek.py, PR 36).
 
 Shares llama's decoder skeleton (attention, norms, paged KV cache) and
 swaps the FFN for a top-k routed mixture of experts. One module serves
-both families because they differ in data, not in code: the experts'
+the families because they differ in data, not in code: the experts'
 activation (`cfg.expert_act`: SwiGLU or ReGLU), the state the router reads
 (`cfg.router_pre_attn`: llama._ffn hands the hook the pre-attention normed
 state), the per-layer window and RoPE pattern (threaded by the skeleton),
-the expert width (`cfg.intermediate_size`) and the HF tensor names. The
+the expert width (`cfg.expert_width`: `intermediate_size` unless
+`moe_intermediate_size` says otherwise), whether the top-k weights are
+renormalised (`cfg.norm_topk_prob`, `cfg.routed_scaling_factor`), shared
+experts every token takes (`cfg.num_shared_experts`: `_shared_mlp`, added
+once whatever form the routed ones take) and the HF tensor names. The
 reference has no MoE (or any model) code — SURVEY.md §2.5 marks expert
 parallelism "No … north star names Mixtral 8×7B EP as a target config".
 
@@ -34,6 +39,11 @@ GFLOP) and XLA's `ragged-dot` at a tenth of it. At mixtral's 8 of 4096 x
 16.3 against 39.1 at 1040. The price is X/top_k times the arithmetic: a
 chunk of 1024 is compute-bound there, and a grouped product that reached
 the weights' roofline would be four times faster (PERF.md section 7).
+At deepseek-v2-lite's 64 experts of 2048 x 1408 top-6 (PERF.md, PR 36,
+call 2; host clock around one call): 80 rows 2.12 ms all-experts against
+4.61 ms sorted (the experts' 1,107 MB at 522 GB/s), 528 rows (a chunk of
+512 beside 16 decode rows) 3.81 against 9.36 ms: the rule stands for this
+shape too.
 `GRIDLLM_MOE_RAGGED=on` still forces the sorted dispatch. Under a mesh the
 inherited rule stands (the `ep` dispatch from `_RAGGED_MIN_TOKENS` rows
 up), not measured: its claims, "top_k-proportional FLOPs per shard" and
@@ -42,7 +52,8 @@ tokens, stand unread.
 
 Routing numerics follow HF `MixtralSparseMoeBlock`: softmax over ALL
 expert logits in fp32 → top-k → renormalize the selected weights (the
-same numbers as SmallThinker's top-k → softmax over the chosen).
+same numbers as SmallThinker's top-k → softmax over the chosen);
+deepseek_v2 keeps the softmax's own weights (`norm_topk_prob` false).
 """
 
 from __future__ import annotations
@@ -68,7 +79,8 @@ _RAGGED_MIN_TOKENS = 16
 
 def _route(cfg: ModelConfig, lp: Params, r: jnp.ndarray):
     """Router math (HF MixtralSparseMoeBlock order): softmax over ALL
-    expert logits in fp32 → top-k → renormalize. Returns (top_w, top_i).
+    expert logits in fp32 → top-k → renormalize (unless the config says
+    the softmax's own weights stand). Returns (top_w, top_i).
     SmallThinker's order (top-k of the logits, softmax over the chosen)
     gives the same numbers: exp(s_j) / Σ_chosen exp(s), either way."""
     with jax.named_scope("moe_router"):
@@ -77,7 +89,11 @@ def _route(cfg: ModelConfig, lp: Params, r: jnp.ndarray):
             axis=-1,
         )  # [..., X] fp32 — router math stays fp32 (tiny; routing flips are costly)
         top_w, top_i = jax.lax.top_k(probs, cfg.experts_per_token)
-        top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+        if cfg.norm_topk_prob:
+            top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+        elif cfg.routed_scaling_factor != 1.0:
+            # deepseek_v2: the softmax's own weights, times a constant
+            top_w = top_w * cfg.routed_scaling_factor
     return top_w, top_i
 
 
@@ -220,6 +236,16 @@ def _moe_mlp_ragged_ep(
     return out.reshape(*lead, e)
 
 
+def _shared_mlp(lp: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """The shared experts (deepseek_v2): one SwiGLU of num_shared_experts
+    x the expert width that every token takes, unweighted."""
+    with jax.named_scope("moe_shared"):
+        p = llama._precision(x)
+        g = jnp.dot(x, lp["ws_gate"], precision=p)
+        u = jnp.dot(x, lp["ws_up"], precision=p)
+        return jnp.dot(jax.nn.silu(g) * u, lp["ws_down"], precision=p)
+
+
 def _use_ragged(n_tokens: int, meshed: bool) -> bool:
     """Whether a call of `n_tokens` rows takes the sorted dispatch:
     GRIDLLM_MOE_RAGGED on / off says so; `auto` takes it under a mesh on a
@@ -239,7 +265,10 @@ def _moe_mlp(
     """Sparse-MoE FFN: x [..., E] → ([..., E], `_route_stats`).
 
     lp carries router [E, X] and stacked experts we_gate/we_up [X, E, F],
-    we_down [X, F, E] (the per-layer slice of the [L, X, ...] leaves). `r`
+    we_down [X, F, E] (the per-layer slice of the [L, X, ...] leaves), and
+    where the family has shared experts ws_gate/ws_up [E, Fs], ws_down
+    [Fs, E], whose output is added once whatever form the routed ones
+    take. `r`
     is what the router reads (x itself unless the family taps another
     state, llama._ffn); `live` ([...] bool or None) marks the token rows
     that belong to a request.
@@ -253,6 +282,15 @@ def _moe_mlp(
     - single device → the all-experts form (the faster on the chip at
       every shape read), unless GRIDLLM_MOE_RAGGED=on.
     """
+    y, stats = _routed_mlp(cfg, mesh, live, lp, x, r)
+    if cfg.num_shared_experts:
+        y = y + _shared_mlp(lp, x)
+    return y, stats
+
+
+def _routed_mlp(cfg: ModelConfig, mesh, live, lp: Params, x: jnp.ndarray,
+                r: jnp.ndarray | None):
+    """The routed experts of `_moe_mlp`, in the form `_use_ragged` picks."""
     top_w, top_i = _route(cfg, lp, x if r is None else r)
     stats = _route_stats(cfg, top_i, live)
     n_tokens = 1
@@ -264,7 +302,7 @@ def _moe_mlp(
         tp = mesh.shape.get("tp", 1)
         divisible = (
             cfg.num_experts % ep == 0
-            and cfg.intermediate_size % tp == 0
+            and cfg.expert_width % tp == 0
         )
         if ragged and divisible:
             return _moe_mlp_ragged_ep(cfg, lp, x, top_w, top_i, mesh), stats
@@ -285,7 +323,7 @@ def _normal_leaf(key, *, shape, scale, dtype):
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
     """Random-init params: llama attention skeleton + MoE expert leaves."""
-    e, f = cfg.hidden_size, cfg.intermediate_size
+    e, f = cfg.hidden_size, cfg.expert_width
     X, L = cfg.num_experts, cfg.num_layers
     base_key, k_r, k_g, k_u, k_d = jax.random.split(key, 5)
     params = llama.init_params(cfg, base_key, dtype, dense_ffn=False)

@@ -68,7 +68,8 @@ def _lane_pad_qkv(q, k_cur, v_cur, dpool):
     if k_cur is not None:
         cpad = [(0, 0)] * (k_cur.ndim - 1) + [(0, dpool - d)]
         k_cur = jnp.pad(k_cur, cpad)
-        v_cur = jnp.pad(v_cur, cpad)
+        if v_cur is not None:      # a latent pool has no fresh V
+            v_cur = jnp.pad(v_cur, cpad)
     return q, k_cur, v_cur
 
 
@@ -404,6 +405,7 @@ def ragged_paged_attention(
     mesh=None,
     tree_pos: jnp.ndarray | None = None,
     tree_mask: jnp.ndarray | None = None,
+    latent_dv: int = 0,
 ) -> tuple[jnp.ndarray | None, jnp.ndarray | None]:
     """Ragged paged attention (the Ragged Paged Attention design): causal
     paged attention for a ragged token batch — one prefill CHUNK region
@@ -438,6 +440,14 @@ def ragged_paged_attention(
     selecting (pass from inside a layer scan). Returns (chunk_out,
     group_out), each shaped like its q (None when the region is absent).
 
+    Latent pools (MLA's absorbed form, `latent_dv` > 0): `k_pages` holds
+    one cache head whose row [latent, RoPE key] is the key, and the
+    row's first `latent_dv` values are the value; `v_pages`, `v_chunk`
+    and `v_group` are None, the queries are as wide as the row (the
+    caller folds the key up-projection and its softmax scale into them:
+    the scale applied here is rsqrt(row width)) and the outputs are
+    `latent_dv` wide. The kernel reads each page once.
+
     Kernel path: ONE pallas_call with a static grid over query-token tiles
     (C/BQ chunk tiles + S group tiles, pallas_kernels.ragged_attention) —
     a mixed prefill+decode+verify engine step is a single launch. d=64
@@ -453,6 +463,7 @@ def ragged_paged_attention(
     """
     some_q = q_chunk if q_chunk is not None else q_group
     d, dpool = some_q.shape[-1], k_pages.shape[-1]
+    assert (latent_dv > 0) == (v_pages is None), "a latent pool has no V"
     if dpool != d:
         # lane-padded pool (KVH*D not lane-aligned): pad q/fresh-K/V at
         # the boundary and slice back — exact, see _lane_pad_qkv
@@ -470,11 +481,12 @@ def ragged_paged_attention(
             group_lengths=group_lengths, k_group=k_group, v_group=v_group,
             layer=layer, use_pallas=use_pallas, logit_softcap=logit_softcap,
             window=window, mesh=mesh, tree_pos=tree_pos,
-            tree_mask=tree_mask,
+            tree_mask=tree_mask, latent_dv=latent_dv,
         )
+        dout = latent_dv or d
         return (
-            oc[..., :d] if oc is not None else None,
-            og[..., :d] if og is not None else None,
+            oc[..., :dout] if oc is not None else None,
+            og[..., :dout] if og is not None else None,
         )
 
     h = some_q.shape[-2]
@@ -501,6 +513,10 @@ def ragged_paged_attention(
             <= _FLASH_KV_VMEM_CAP
         )
     quant = isinstance(k_pages, QuantPages)
+    if latent_dv and (quant or mode == "wrap"):
+        # a latent pool is one cache head: nothing to split over tp, and
+        # no int8 form (the engine refuses both at construction)
+        raise NotImplementedError("latent pool under a mesh or as int8")
     if quant and mode == "wrap":
         # int8 pools are single-device by engine policy (no shard_map
         # plumbing for the scale operands) — a meshed call is a wiring
@@ -556,7 +572,8 @@ def ragged_paged_attention(
                     page_table=page_table, group_lengths=group_lengths,
                     k_group=k_group, v_group=v_group, layer=layer,
                     logit_softcap=logit_softcap, window=window,
-                    tree_pos=tree_pos, tree_mask=tree_mask),
+                    tree_pos=tree_pos, tree_mask=tree_mask,
+                    latent_dv=latent_dv),
                 valid=(vc, vg),
             )
 
@@ -584,10 +601,12 @@ def ragged_paged_attention(
                 k_scale=ksc, v_scale=vsc, **tree_kw,
             ))
         kp = k_pages if k_pages.ndim == 5 else k_pages[None]
-        vp = v_pages if v_pages.ndim == 5 else v_pages[None]
+        vp = (None if latent_dv
+              else v_pages if v_pages.ndim == 5 else v_pages[None])
         kernel = partial(
             pallas_kernels.ragged_attention, page_size=page_size,
             interpret=interpret, softcap=float(logit_softcap),
+            latent_dv=latent_dv,
         )
         if mode == "direct":
             return _shadow(kernel(
@@ -654,7 +673,7 @@ def ragged_paged_attention(
         q_group=q_group, page_table=page_table,
         group_lengths=group_lengths, k_group=k_group, v_group=v_group,
         layer=layer, logit_softcap=logit_softcap, window=window,
-        tree_pos=tree_pos, tree_mask=tree_mask,
+        tree_pos=tree_pos, tree_mask=tree_mask, latent_dv=latent_dv,
     )
 
 
@@ -678,6 +697,7 @@ def ragged_paged_attention_ref(
     window: jnp.ndarray | int = 0,
     tree_pos: jnp.ndarray | None = None,
     tree_mask: jnp.ndarray | None = None,
+    latent_dv: int = 0,
 ) -> tuple[jnp.ndarray | None, jnp.ndarray | None]:
     """jnp reference for the ragged launch, composed from the per-region
     references: the chunk region is _prefix_chunk_ref, a Td = 1 group is
@@ -685,7 +705,20 @@ def ragged_paged_attention_ref(
     paged_attention_verify_ref (its tree branch when
     `tree_pos`/`tree_mask` are given). The fallback leg of
     ragged_paged_attention, and the oracle the KERNELS registry and the
-    numerics sanitizer hold the ragged kernel to."""
+    numerics sanitizer hold the ragged kernel to. A latent pool
+    (`latent_dv`) is read as its own V: softmax(.) @ row, of which the
+    first `latent_dv` columns are softmax(.) @ latent."""
+    if latent_dv:
+        oc, og = ragged_paged_attention_ref(
+            k_pages, k_pages, page_size, q_chunk=q_chunk,
+            chunk_row=chunk_row, chunk_start=chunk_start,
+            chunk_total=chunk_total, k_chunk=k_chunk, v_chunk=k_chunk,
+            q_group=q_group, page_table=page_table,
+            group_lengths=group_lengths, k_group=k_group, v_group=k_group,
+            layer=layer, logit_softcap=logit_softcap, window=window,
+            tree_pos=tree_pos, tree_mask=tree_mask)
+        return (None if oc is None else oc[..., :latent_dv],
+                None if og is None else og[..., :latent_dv])
     out_chunk = out_group = None
     if q_chunk is not None:
         out_chunk = _prefix_chunk_ref(

@@ -7,6 +7,8 @@ rather than slots × max_seq_len, and shapes stay static under jit.
 
 Layout (per model):
   k/v: [num_layers, num_pages, page_size, num_kv_heads, head_dim]
+  (a LATENT pool, MLA: k alone, one cache head whose row is the latent and
+  its RoPE key, and v is None — same pages, tables, allocator and keys)
   page_table: [max_slots, max_pages_per_slot] int32 page ids (-1 = unmapped)
   lengths: [max_slots] int32 tokens stored per slot
 
@@ -211,7 +213,31 @@ def _pad_new_lanes(k_pages, k_new, v_new):
     if dpool == d:
         return k_new, v_new
     pad = [(0, 0)] * (k_new.ndim - 1) + [(0, dpool - d)]
-    return jnp.pad(k_new, pad), jnp.pad(v_new, pad)
+    return jnp.pad(k_new, pad), (None if v_new is None
+                                 else jnp.pad(v_new, pad))
+
+
+def _write_latent_rows(k_pages, rows, page_idx, offset):
+    """Row writes of a LATENT pool (decode and verify): one XLA scatter
+    of whole rows into the pool viewed flat, [L * P * ps, D].at[rows]. Not
+    a kernel's fallback: it has no kernel. A latent page is [ps, D] with no
+    head axis under the row, so one bfloat16 row is half a sublane tile,
+    which Mosaic cannot slice for a DMA (compiled for v5e, PR 36); and the
+    flat view is what keeps XLA in place: the same scatter written
+    `pool.at[:, page, row]` copies the whole pool twice a launch (2.7 GB of
+    temporaries at 10 layers x 1,024 pages). Counted apart
+    (`path="xla"`), so the `jnp` tripwire stays a kernel's alone.
+
+    rows [L, n, 1, D]; page_idx [n] with the out-of-bounds sentinel
+    `num_pages` = drop; offset [n]."""
+    n_layers, num_pages, ps, _, d = k_pages.shape
+    _KERNEL_DISPATCH.inc(op="write_latent_rows", path="xla")
+    at = (jnp.arange(n_layers, dtype=jnp.int32)[:, None] * num_pages
+          + page_idx[None]) * ps + offset[None]
+    at = jnp.where(page_idx[None] < num_pages, at, n_layers * num_pages * ps)
+    flat = k_pages.reshape(-1, d).at[at.reshape(-1)].set(
+        rows.reshape(-1, d), mode="drop")
+    return flat.reshape(k_pages.shape)
 
 
 def _wrap_write_kernel(mesh, ax, kernel, scalar_specs):
@@ -237,7 +263,7 @@ def _wrap_write_kernel(mesh, ax, kernel, scalar_specs):
 @dataclasses.dataclass
 class PagedKVCache:
     k: jnp.ndarray           # [L, P, page_size, KVH, D]
-    v: jnp.ndarray           # [L, P, page_size, KVH, D]
+    v: jnp.ndarray | None    # [L, P, page_size, KVH, D]; None: latent pool
     page_table: jnp.ndarray  # [S, max_pages] int32
     lengths: jnp.ndarray     # [S] int32
     page_size: int = 128
@@ -252,11 +278,15 @@ class PagedKVCache:
         max_slots: int,
         max_pages_per_slot: int,
         dtype=jnp.bfloat16,
+        latent: bool = False,
     ) -> "PagedKVCache":
+        """`latent`: one array of rows that are key and value at once
+        (MLA: num_kv_heads 1, head_dim the latent and its RoPE key); no V
+        array is made."""
         shape = (num_layers, num_pages, page_size, num_kv_heads, head_dim)
         return PagedKVCache(
             k=jnp.zeros(shape, dtype),
-            v=jnp.zeros(shape, dtype),
+            v=None if latent else jnp.zeros(shape, dtype),
             page_table=jnp.full((max_slots, max_pages_per_slot), -1, jnp.int32),
             lengths=jnp.zeros((max_slots,), jnp.int32),
             page_size=page_size,
@@ -265,6 +295,11 @@ class PagedKVCache:
     @property
     def num_layers(self) -> int:
         return self.k.shape[0]
+
+    @property
+    def pool_nbytes(self) -> int:
+        """Bytes of the page pool: K and V, or the latent rows alone."""
+        return self.k.nbytes + (0 if self.v is None else self.v.nbytes)
 
     @property
     def max_slots(self) -> int:
@@ -469,7 +504,8 @@ def write_decode_all(
     """
     # numerics sanitizer: a NaN/Inf KV row poisons every later read of
     # its page — trip at the write boundary, not in a garbled stream
-    numcheck.check_finite("kv.write", k_new, v_new)
+    numcheck.check_finite(
+        "kv.write", *(x for x in (k_new, v_new) if x is not None))
     if isinstance(k_pages, QuantPages):
         k_new, v_new = _pad_new_lanes(k_pages, k_new, v_new)
         s = jnp.arange(page_table.shape[0], dtype=jnp.int32)
@@ -498,6 +534,8 @@ def write_decode_all(
         page_table.shape[1], k_pages.shape[1],
     )
     offset = positions % page_size
+    if v_pages is None:
+        return _write_latent_rows(k_pages, k_new, page_idx, offset), None
     use, interpret = _pallas_mode(use_pallas)
     # same Mosaic constraint as the attention kernels: page slices need a
     # 128-lane-aligned minor dim on real TPU — met either by a (padded)
@@ -557,7 +595,8 @@ def write_multi_all(
     int8 pools (QuantPages): the flattened rows quantize per row and the
     scales scatter alongside, exactly like write_decode_all.
     """
-    numcheck.check_finite("kv.write", k_new, v_new)
+    numcheck.check_finite(
+        "kv.write", *(x for x in (k_new, v_new) if x is not None))
     if isinstance(k_pages, QuantPages):
         k_new, v_new = _pad_new_lanes(k_pages, k_new, v_new)
         n_layers, s, t = k_new.shape[:3]
@@ -594,6 +633,8 @@ def write_multi_all(
     )
     offset = pos % page_size
     k_flat = k_new.reshape(n_layers, s * t, *k_new.shape[3:])
+    if v_pages is None:
+        return _write_latent_rows(k_pages, k_flat, page_idx, offset), None
     v_flat = v_new.reshape(n_layers, s * t, *v_new.shape[3:])
     use, interpret = _pallas_mode(use_pallas)
     mode, ax = kernel_mesh_axis(mesh, k_new.shape[3])
@@ -703,6 +744,8 @@ def commit_tree_path(cache: PagedKVCache,
     dst_off = dst_pos % ps
 
     def move(pages):
+        if pages is None:          # a latent pool has no V
+            return None
         if isinstance(pages, QuantPages):
             return QuantPages(
                 pages.data.at[:, dst_page, dst_off].set(
@@ -741,7 +784,8 @@ def write_prefill_all(
     int8 pools (QuantPages): per-row quantize + scale scatter, like
     write_decode_all (scatter path — see the rationale there).
     """
-    numcheck.check_finite("kv.write", k_new, v_new)
+    numcheck.check_finite(
+        "kv.write", *(x for x in (k_new, v_new) if x is not None))
     if isinstance(k_pages, QuantPages):
         k_new, v_new = _pad_new_lanes(k_pages, k_new, v_new)
         t = jnp.arange(k_new.shape[1], dtype=jnp.int32)
@@ -792,7 +836,8 @@ def write_prefill_all(
     )
     offset = pos % page_size
     k_pages = k_pages.at[:, page_idx, offset].set(k_new, mode="drop")
-    v_pages = v_pages.at[:, page_idx, offset].set(v_new, mode="drop")
+    if v_pages is not None:        # a latent pool has no V
+        v_pages = v_pages.at[:, page_idx, offset].set(v_new, mode="drop")
     return k_pages, v_pages
 
 

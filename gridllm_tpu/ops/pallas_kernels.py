@@ -332,7 +332,7 @@ def _ragged_attn_kernel(
     *refs,
     ps: int, bq: int, bk: int, c: int, kvh: int, g: int, d: int,
     td: int, nct: int, softcap: float, has_chunk: bool, has_group: bool,
-    quant: bool = False, has_tree: bool = False,
+    quant: bool = False, has_tree: bool = False, dv: int = 0,
 ):
     """One grid over query-token tiles serving all phases at once
     (the Ragged Paged Attention shape): tiles [0, nct) are the prefill
@@ -342,7 +342,16 @@ def _ragged_attn_kernel(
     spec-verify) against the slot's paged context with the Td fresh K/V
     columns merged in-register (one extra online-softmax step). The DMA
     discipline is shared: every conditional start is guarded by the same
-    bound as its wait (scratch + semaphores persist across grid steps)."""
+    bound as its wait (scratch + semaphores persist across grid steps).
+
+    `dv` > 0 is a LATENT pool (MLA, absorbed form): one cache head whose
+    row is both key (all d values) and value (its first dv): no V pool,
+    no V scratch, no fresh-V operands, a page is DMA'd once and read for
+    scores and values; the output is dv wide. Its pool, scratch and fresh
+    rows come WITHOUT the head axis ([L, P, ps, D], (C, D), (1, Td, D)):
+    a head axis of one as the second-minor dimension is padded to the
+    sublane tile (twice the bytes in bfloat16) and cannot be sliced."""
+    latent = dv > 0
     it = iter(refs)
     scal_ref = next(it)      # SMEM [4]: layer, window, chunk_start, total
     if has_group:
@@ -356,20 +365,20 @@ def _ragged_attn_kernel(
         crow_ref = next(it)      # SMEM [maxp] chunk slot's page row
         qc_ref = next(it)        # VMEM (KVH, BQ*G, D) — rows token-major
         kc_ref = next(it)        # VMEM (C, KVH, D) — resident chunk K
-        vc_ref = next(it)
+        vc_ref = None if latent else next(it)
     if has_group:
         qg_ref = next(it)        # VMEM (1, KVH, Td*G, D) — rows token-major
         kg_ref = next(it)        # VMEM (1, Td, KVH, D)
-        vg_ref = next(it)
+        vg_ref = None if latent else next(it)
     k_hbm = next(it)             # ANY [L, P, ps, KVH, D]
-    v_hbm = next(it)
+    v_hbm = None if latent else next(it)
     if quant:
         ks_hbm = next(it)        # ANY [L, P, ps] f32 per-row scales
         vs_hbm = next(it)
     oc_ref = next(it) if has_chunk else None
     og_ref = next(it) if has_group else None
     k_scr = next(it)             # VMEM (2, ps, KVH, D) double buffer
-    v_scr = next(it)
+    v_scr = None if latent else next(it)
     sems = next(it)              # DMA sems (2, 2)
     if quant:
         ks_scr = next(it)        # VMEM (2, ps) f32 scale double buffer
@@ -384,14 +393,26 @@ def _ragged_attn_kernel(
     # (the KV-bytes win) and lane-padded here, in-register after the
     # load, so every dot still runs on 128-lane minors — numerically
     # exact (zero lanes meet zero q lanes), same compute as over a
-    # lane-padded pool, half the HBM bytes/bandwidth
-    dp = -(-d // 128) * 128
+    # lane-padded pool, half the HBM bytes/bandwidth (`_lp`)
+    do = dv if latent else d     # output (value) width
+    dop = -(-do // 128) * 128    # ... and the accumulator's, lane-padded
 
     def _lp(x):
-        """Zero-pad a loaded value's last dim from d to the lane tile."""
-        if dp == d:
+        """Zero-pad a loaded value's last dim to the lane tile."""
+        w = x.shape[-1]
+        wp = -(-w // 128) * 128
+        if wp == w:
             return x
-        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, dp - d)])
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, wp - w)])
+
+    def _val(x):
+        """The value part of a loaded key block: a latent row's first dv
+        values; otherwise V is its own operand and this is not called."""
+        return x[..., :dv]
+
+    def _head(x, h):
+        """Cache head h of a block of rows [N, KVH, D] ([N, D] latent)."""
+        return x if latent else x[:, h, :]
 
     def attend_pages(page_of, ctx_limit, n_table, q_f32, q_abs, q_lo,
                      r, carry):
@@ -435,7 +456,8 @@ def _ragged_attn_kernel(
         @pl.when(n_pages > p0)
         def _():
             k_dma(0, p0).start()
-            v_dma(0, p0).start()
+            if not latent:
+                v_dma(0, p0).start()
             if quant:
                 for dma in scale_dmas(0, p0):
                     dma.start()
@@ -448,15 +470,19 @@ def _ragged_attn_kernel(
             def _():
                 nxt = jax.lax.rem(p + 1 - p0, 2)
                 k_dma(nxt, p + 1).start()
-                v_dma(nxt, p + 1).start()
+                if not latent:
+                    v_dma(nxt, p + 1).start()
                 if quant:
                     for dma in scale_dmas(nxt, p + 1):
                         dma.start()
 
             k_dma(slot, p).wait()
-            v_dma(slot, p).wait()
             k_page = k_scr[slot]                    # [ps, KVH, D]
-            v_page = v_scr[slot]
+            if latent:
+                v_page = None
+            else:
+                v_dma(slot, p).wait()
+                v_page = v_scr[slot]
             if quant:
                 # dequant epilogue: the flat-row page load multiplies by
                 # its [ps, 1] scale column right after the DMA — the dots
@@ -467,12 +493,14 @@ def _ragged_attn_kernel(
                 vscale = vs_scr[slot].reshape(ps, 1)
 
             def k_head(h):
-                x = k_page[:, h, :].astype(jnp.float32)
+                x = _head(k_page, h).astype(jnp.float32)
                 if quant:
                     x = x * kscale
                 return _lp(x)
 
             def v_head(h):
+                if latent:
+                    return _lp(_val(k_page).astype(jnp.float32))
                 x = v_page[:, h, :].astype(jnp.float32)
                 if quant:
                     x = x * vscale
@@ -525,7 +553,7 @@ def _ragged_attn_kernel(
 
         m0 = jnp.full((kvh, r, 1), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((kvh, r, 1), jnp.float32)
-        acc0 = jnp.zeros((kvh, r, dp), jnp.float32)
+        acc0 = jnp.zeros((kvh, r, dop), jnp.float32)
         m, l, acc = attend_pages(
             lambda p: crow_ref[p], start, crow_ref.shape[0], q_heads,
             q_abs, start + i * bq, r, (m0, l0, acc0),
@@ -541,10 +569,11 @@ def _ragged_attn_kernel(
         def chunk_body(kb, carry):
             m, l, acc = carry
             k_blk = kc_ref[pl.ds(kb * bk, bk)]      # [BK, KVH, D]
-            v_blk = vc_ref[pl.ds(kb * bk, bk)]
+            v_blk = (_val(k_blk) if latent
+                     else vc_ref[pl.ds(kb * bk, bk)])
             logits = jnp.stack([
                 jax.lax.dot_general(
-                    q_heads[h], _lp(k_blk[:, h, :].astype(jnp.float32)),
+                    q_heads[h], _lp(_head(k_blk, h).astype(jnp.float32)),
                     (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
@@ -567,7 +596,7 @@ def _ragged_attn_kernel(
             l_new = l * alpha + prob.sum(axis=2, keepdims=True)
             acc_new = acc * alpha + jnp.stack([
                 jax.lax.dot_general(
-                    prob[h], _lp(v_blk[:, h, :].astype(jnp.float32)),
+                    prob[h], _lp(_head(v_blk, h).astype(jnp.float32)),
                     (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
@@ -576,7 +605,7 @@ def _ragged_attn_kernel(
             return m_new, l_new, acc_new
 
         _, l, acc = jax.lax.fori_loop(kb0, nkb, chunk_body, (m, l, acc))
-        out = (acc / jnp.maximum(l, 1e-30))[..., :d]  # [KVH, R, D]
+        out = (acc / jnp.maximum(l, 1e-30))[..., :do]  # [KVH, R, D]
         oc_ref[...] = out.astype(oc_ref.dtype)
 
     def group_tile():
@@ -605,7 +634,7 @@ def _ragged_attn_kernel(
 
         m0 = jnp.full((kvh, r, 1), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((kvh, r, 1), jnp.float32)
-        acc0 = jnp.zeros((kvh, r, dp), jnp.float32)
+        acc0 = jnp.zeros((kvh, r, dop), jnp.float32)
         m, l, acc = attend_pages(
             lambda p: gtable_ref[s, p], length, gtable_ref.shape[1],
             q_heads, q_abs, length, r, (m0, l0, acc0),
@@ -616,10 +645,10 @@ def _ragged_attn_kernel(
         # row token i attends columns j <= i (verify causality; Td = 1
         # degenerates to the decode kernel's single current-token merge)
         kg = kg_ref[0].astype(jnp.float32)          # [Td, KVH, D]
-        vg = vg_ref[0].astype(jnp.float32)
+        vg = _val(kg) if latent else vg_ref[0].astype(jnp.float32)
         logits = jnp.stack([
             jax.lax.dot_general(
-                q_heads[h], _lp(kg[:, h, :]),
+                q_heads[h], _lp(_head(kg, h)),
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
@@ -647,13 +676,13 @@ def _ragged_attn_kernel(
         l = l * alpha + prob.sum(axis=2, keepdims=True)
         acc = acc * alpha + jnp.stack([
             jax.lax.dot_general(
-                prob[h], _lp(vg[:, h, :]),
+                prob[h], _lp(_head(vg, h)),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             for h in range(kvh)
         ])
-        out = (acc / jnp.maximum(l, 1e-30))[..., :d]  # [KVH, R, D]
+        out = (acc / jnp.maximum(l, 1e-30))[..., :do]  # [KVH, R, D]
         og_ref[0] = out.astype(og_ref.dtype)
 
     if has_chunk and has_group:
@@ -701,7 +730,8 @@ def _ragged_vmem_limit(ps: int, kvh: int, g: int, d: int, bq: int, c: int,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("page_size", "interpret", "softcap"))
+                   static_argnames=("page_size", "interpret", "softcap",
+                                    "latent_dv"))
 def ragged_attention(
     k_pages: jnp.ndarray,
     v_pages: jnp.ndarray,
@@ -725,6 +755,7 @@ def ragged_attention(
     v_scale: jnp.ndarray | None = None,
     tree_pos: jnp.ndarray | None = None,
     tree_bits: jnp.ndarray | None = None,
+    latent_dv: int = 0,
 ) -> tuple[jnp.ndarray | None, jnp.ndarray | None]:
     """Kernel form of ops.attention.ragged_paged_attention: ONE launch,
     static grid (C/BQ chunk tiles + S group tiles) serving chunked
@@ -734,7 +765,13 @@ def ragged_attention(
     unpadded (contiguous [ps, KVH*D]-byte rows, so the page DMA stays
     tile-aligned) and the loaded values are zero-padded to 128 lanes
     in-register before every dot — same compute as over a lane-padded
-    pool, half the HBM bytes/bandwidth."""
+    pool, half the HBM bytes/bandwidth.
+
+    `latent_dv` > 0: a latent pool (`v_pages`, `v_chunk`, `v_group` all
+    None): the one cache head's row is the key and its first `latent_dv`
+    values are the value; outputs are `latent_dv` wide."""
+    latent = latent_dv > 0
+    assert latent == (v_pages is None)
     has_chunk = q_chunk is not None
     has_group = q_group is not None
     assert has_chunk or has_group
@@ -743,13 +780,23 @@ def ragged_attention(
     quant = k_scale is not None
     if k_pages.ndim == 4:
         k_pages = k_pages[None]
-        v_pages = v_pages[None]
+        v_pages = None if latent else v_pages[None]
         if quant:
             k_scale = k_scale[None]
             v_scale = v_scale[None]
     if layer is None:
         layer = jnp.int32(0)
     kvh, d = k_pages.shape[-2], k_pages.shape[-1]
+    # rows of a latent pool travel without their head axis of one
+    # (_ragged_attn_kernel): free reshapes of the pool and the fresh rows
+    rows = ((d,) if latent else (kvh, d))
+    if latent:
+        assert kvh == 1, k_pages.shape
+        k_pages = k_pages.reshape(*k_pages.shape[:3], d)
+        if has_chunk:
+            k_chunk = k_chunk.reshape(-1, d)
+        if has_group:
+            k_group = k_group.reshape(*k_group.shape[:2], d)
     h = (q_chunk if has_chunk else q_group).shape[-2]
     g = h // kvh
     dtype = (q_chunk if has_chunk else q_group).dtype
@@ -770,7 +817,7 @@ def ragged_attention(
         _ragged_attn_kernel, ps=page_size, bq=bq, bk=bk, c=c, kvh=kvh,
         g=g, d=d, td=td, nct=nct, softcap=softcap,
         has_chunk=has_chunk, has_group=has_group, quant=quant,
-        has_tree=has_tree,
+        has_tree=has_tree, dv=latent_dv,
     )
 
     scal = jnp.stack([
@@ -803,54 +850,49 @@ def ragged_attention(
         x = jnp.moveaxis(x.reshape(*lead, t, kvh, g, d), -3, -4)
         return x.reshape(*lead, kvh, t * g, d)
 
-    def tokens_major(x):     # [..., KVH, T*G, D] -> [..., T, H, D]
+    do = latent_dv or d      # output (value) width
+
+    def tokens_major(x):     # [..., KVH, T*G, Do] -> [..., T, H, Do]
         *lead, _, tg, _ = x.shape
-        x = jnp.moveaxis(x.reshape(*lead, kvh, tg // g, g, d), -4, -3)
-        return x.reshape(*lead, tg // g, h, d)
+        x = jnp.moveaxis(x.reshape(*lead, kvh, tg // g, g, do), -4, -3)
+        return x.reshape(*lead, tg // g, h, do)
 
     # block index clamps: chunk operands pin to their last tile during
     # group steps (and vice versa at index 0) — those blocks are simply
     # not re-fetched/written outside their region
     last_ct = max(nct - 1, 0)
 
-    def chunk_q_spec():
+    def chunk_q_spec(w=d):
         return pl.BlockSpec(
-            (kvh, bq * g, d),
+            (kvh, bq * g, w),
             lambda i, *_: (0, jnp.minimum(i, last_ct), 0),
             memory_space=pltpu.VMEM)
 
-    def group_q_spec():
+    def group_q_spec(w=d):
         return pl.BlockSpec(
-            (1, kvh, td * g, d),
+            (1, kvh, td * g, w),
             lambda i, *_: (jnp.maximum(i - nct, 0), 0, 0, 0),
             memory_space=pltpu.VMEM)
 
     in_specs = []
     args = []
+    n_kv = 1 if latent else 2    # fresh-row and pool operands a region
     if has_chunk:
-        in_specs += [
-            chunk_q_spec(),
-            pl.BlockSpec((c, kvh, d), lambda i, *_: (0, 0, 0),
+        in_specs += [chunk_q_spec()] + [
+            pl.BlockSpec((c, *rows), lambda i, *_: (0,) * (1 + len(rows)),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((c, kvh, d), lambda i, *_: (0, 0, 0),
-                         memory_space=pltpu.VMEM),
-        ]
-        args += [heads_major(q_chunk[0]), k_chunk, v_chunk]
+        ] * n_kv
+        args += [heads_major(q_chunk[0]), k_chunk, v_chunk][:1 + n_kv]
     if has_group:
-        def _gidx4(i, *_):
-            return (jnp.maximum(i - nct, 0), 0, 0, 0)
+        def _gidx(i, *_):
+            return (jnp.maximum(i - nct, 0), 0) + (0,) * len(rows)
 
-        in_specs += [
-            group_q_spec(),
-            pl.BlockSpec((1, td, kvh, d), _gidx4,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, td, kvh, d), _gidx4,
-                         memory_space=pltpu.VMEM),
-        ]
-        args += [heads_major(q_group), k_group, v_group]
-    in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                 pl.BlockSpec(memory_space=pl.ANY)]
-    args += [k_pages, v_pages]
+        in_specs += [group_q_spec()] + [
+            pl.BlockSpec((1, td, *rows), _gidx, memory_space=pltpu.VMEM),
+        ] * n_kv
+        args += [heads_major(q_group), k_group, v_group][:1 + n_kv]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * n_kv
+    args += [k_pages, v_pages][:n_kv]
     if quant:
         # int8 pool (ISSUE 11): per-row scales stay in HBM and are DMA'd
         # page-by-page next to the value pages (dequant epilogue)
@@ -861,11 +903,11 @@ def ragged_attention(
     out_specs = []
     out_shape = []
     if has_chunk:
-        out_specs.append(chunk_q_spec())
-        out_shape.append(jax.ShapeDtypeStruct((kvh, c * g, d), dtype))
+        out_specs.append(chunk_q_spec(do))
+        out_shape.append(jax.ShapeDtypeStruct((kvh, c * g, do), dtype))
     if has_group:
-        out_specs.append(group_q_spec())
-        out_shape.append(jax.ShapeDtypeStruct((s, kvh, td * g, d), dtype))
+        out_specs.append(group_q_spec(do))
+        out_shape.append(jax.ShapeDtypeStruct((s, kvh, td * g, do), dtype))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
@@ -873,8 +915,8 @@ def ragged_attention(
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, kvh, d), k_pages.dtype),
-            pltpu.VMEM((2, page_size, kvh, d), v_pages.dtype),
+            pltpu.VMEM((2, page_size, *rows), k_pages.dtype),
+        ] * n_kv + [
             pltpu.SemaphoreType.DMA((2, 2)),
         ] + ([
             pltpu.VMEM((2, page_size), jnp.float32),
@@ -1022,51 +1064,44 @@ def paged_write_decode(
 
 def _write_chunk_all_kernel(
     dst_pages_ref,  # SMEM prefetch: [T//ps] destination page per chunk page
-    k_new_ref,      # VMEM (1, ps, KVH, D) — this (layer, chunk page)'s rows
-    v_new_ref,
-    k_in,           # ANY [L, P, ps, KVH, D] — aliased with k_out
-    v_in,
-    k_out,
-    v_out,
-    sems,           # DMA sems [2]
-    *, num_pages: int,
+    *refs,          # n x new rows VMEM (1, ps, KVH, D), n x pool in (ANY,
+                    # aliased with) n x pool out, DMA sems [n]
+    num_pages: int, n: int,
 ):
-    del k_in, v_in
+    new_refs, outs, sems = refs[:n], refs[2 * n:3 * n], refs[3 * n]
     layer = pl.program_id(0)
     c = pl.program_id(1)
     page = dst_pages_ref[c]
 
     @pl.when(page < num_pages)
     def _():
-        ck = pltpu.make_async_copy(
-            k_new_ref.at[0], k_out.at[layer, page], sems.at[0]
-        )
-        cv = pltpu.make_async_copy(
-            v_new_ref.at[0], v_out.at[layer, page], sems.at[1]
-        )
-        ck.start()
-        cv.start()
-        ck.wait()
-        cv.wait()
+        copies = [pltpu.make_async_copy(
+            new.at[0], out.at[layer, page], sems.at[j])
+            for j, (new, out) in enumerate(zip(new_refs, outs))]
+        for cp in copies:
+            cp.start()
+        for cp in copies:
+            cp.wait()
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def paged_write_chunk(
     k_pages: jnp.ndarray,
-    v_pages: jnp.ndarray,
+    v_pages: jnp.ndarray | None,
     k_new: jnp.ndarray,
-    v_new: jnp.ndarray,
+    v_new: jnp.ndarray | None,
     table_row: jnp.ndarray,
     start: jnp.ndarray,
     length: jnp.ndarray,
     page_size: int,
     interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray | None]:
     """Write a prefill chunk's K/V (all layers) into one slot's pages,
     in place.
 
     k_pages/v_pages: [L, P, ps, KVH, D]; k_new/v_new: [L, T, KVH, D] with
-    T % page_size == 0. `start` (the absolute position of row 0) must be
+    T % page_size == 0 (a latent pool: `v_pages` and `v_new` None).
+    `start` (the absolute position of row 0) must be
     page-aligned — a traced value the engine guarantees: fresh prefills
     start at 0 and chunked prefill chunks at multiples of prefill_chunk,
     which EngineConfig rounds to a multiple of the page size.
@@ -1082,6 +1117,16 @@ def paged_write_chunk(
     assert t % page_size == 0, (t, page_size)
     n_chunk_pages = t // page_size
     num_pages = k_pages.shape[1]
+    pools = [k_pages] + ([] if v_pages is None else [v_pages])
+    news = [k_new] + ([] if v_pages is None else [v_new])
+    n = len(pools)
+    rows = (kvh, d)
+    if v_pages is None:
+        # a latent pool's pages travel as [ps, D]: its head axis of one
+        # would be padded to the sublane tile (_ragged_attn_kernel)
+        rows = (d,)
+        pools = [k_pages.reshape(*k_pages.shape[:3], d)]
+        news = [k_new.reshape(L, t, d)]
 
     first_page = start // page_size
     c = jnp.arange(n_chunk_pages, dtype=jnp.int32)
@@ -1090,36 +1135,27 @@ def paged_write_chunk(
     covered = c * page_size < length  # page holds at least one valid row
     dst = jnp.where(covered & (mapped >= 0), mapped, num_pages).astype(jnp.int32)
 
-    kernel = functools.partial(_write_chunk_all_kernel, num_pages=num_pages)
+    kernel = functools.partial(_write_chunk_all_kernel, num_pages=num_pages,
+                               n=n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(L, n_chunk_pages),
         in_specs=[
-            pl.BlockSpec((1, page_size, kvh, d),
-                         lambda l, c, *_: (l, c, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, page_size, kvh, d),
-                         lambda l, c, *_: (l, c, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        scratch_shapes=[pltpu.SemaphoreType.DMA((2,))],
+            pl.BlockSpec((1, page_size, *rows),
+                         lambda l, c, *_: (l, c) + (0,) * len(rows),
+                         memory_space=pltpu.VMEM)
+        ] * n + [pl.BlockSpec(memory_space=pl.ANY)] * n,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n,
+        scratch_shapes=[pltpu.SemaphoreType.DMA((n,))],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-        ],
-        # 0: dst pages (prefetch), 1: k_new, 2: v_new, 3: k_pages, 4: v_pages
-        input_output_aliases={3: 0, 4: 1},
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # 0: dst pages (prefetch), then the n new rows, then the n pools
+        input_output_aliases={1 + n + j: j for j in range(n)},
         interpret=interpret,
-    )(dst, k_new, v_new, k_pages, v_pages)
-
-
+    )(dst, *news, *pools)
+    if v_pages is None:
+        return out[0].reshape(k_pages.shape), None
+    return out[0], out[1]

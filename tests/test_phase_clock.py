@@ -227,8 +227,10 @@ def test_no_trace_annotation_is_constructed_with_no_capture_active(monkeypatch):
     assert all(s.open is False for s in made)       # each closed by the next mark
     launch = next(s for s in made if s.name == "gridllm.dispatch_verify")
     assert set(launch.meta) == {"gen", "slots", "ctx_tokens", "mesh", "experts",
-                                "window_layers"}
+                                "window_layers", "cache_row", "attn_form"}
     assert launch.meta["mesh"] == ""                # unmeshed; "tp:4" under one
+    # K and V per head; "latent" / "absorbed" for a latent-attention family
+    assert (launch.meta["cache_row"], launch.meta["attn_form"]) == ("kv", "per_head")
     assert launch.meta["slots"] >= 1 and launch.meta["ctx_tokens"] > 0
     admit = next(s for s in made if s.name == "gridllm.admit")
     assert admit.meta["request"] in ("r0", "r1")
