@@ -237,6 +237,13 @@ _SPEC_PROPOSED = _OBS.counter(
     "drafter kind.",
     ("model", "drafter"),
 )
+_SPEC_LOOKUPS = _OBS.counter(
+    "gridllm_spec_draft_lookups_total",
+    "N-gram drafter lookups, one per live slot per verify step, by model "
+    "and outcome (hit: the history's suffix recurred and tokens were "
+    "proposed; miss: nothing to propose).",
+    ("model", "outcome"),
+)
 _SPEC_ACCEPTED = _OBS.counter(
     "gridllm_spec_accepted_tokens_total",
     "Draft tokens accepted by speculative verify steps, by model and "
@@ -2301,7 +2308,8 @@ class InferenceEngine:
         del self._slots[slot]
         self._free_slots.append(slot)
         if self._drafter is not None and hasattr(self._drafter, "reset_slot"):
-            # draft-model drafters keep a per-slot KV prefix view; the
+            # drafters keep a per-slot view of the history (the draft
+            # model's KV prefix, the n-gram drafter's int32 copy); the
             # next request reusing this slot starts from scratch
             self._drafter.reset_slot(slot)
         _FLIGHTREC.record("engine", "finish", model=self.cfg.name,
@@ -2514,10 +2522,14 @@ class InferenceEngine:
             return
         drafts = np.zeros((self.config.max_slots, k), np.int32)
         dlen = np.zeros((self.config.max_slots,), np.int32)
+        looked = hits = history = 0
         for slot, st in list(self._slots.items()):
             if st.joined_gen > self._gen:
                 continue  # first token still device-side — nothing to extend
-            prop = self._drafter.draft(st.ids, k)
+            prop = self._drafter.draft(st.ids, k, slot)
+            looked += 1
+            hits += bool(prop)
+            history += len(st.ids)
             if prop and st.num_predict >= 0:
                 # don't draft past num_predict: the host would discard the
                 # overshoot anyway, and counting it would skew acceptance
@@ -2525,6 +2537,12 @@ class InferenceEngine:
             if prop:
                 dlen[slot] = len(prop)
                 drafts[slot, :len(prop)] = prop
+        if hits:
+            _SPEC_LOOKUPS.inc(hits, model=self.cfg.name, outcome="hit")
+        if looked - hits:
+            _SPEC_LOOKUPS.inc(looked - hits, model=self.cfg.name,
+                              outcome="miss")
+        self._clock.annotate(slots=looked, hits=hits, history_tokens=history)
         self._mark_launch()
         self._dispatch_verify(drafts, dlen)
         gen, (block, n_emit), _blk = self._inflight.popleft()

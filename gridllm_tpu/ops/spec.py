@@ -31,8 +31,8 @@ node-validity mask (data, not shape). n-gram remains the default and the
 fallback whenever no draft model is configured (GRIDLLM_SPEC_DRAFT_MODEL
 empty) or the configured one is incompatible with the target.
 
-The interface is deliberately tiny: the engine calls `draft(ids, k)` per
-slot (chain drafters) or `draft_batch(ids_by_slot, k, width)` (tree
+The interface is deliberately tiny: the engine calls `draft(ids, k, slot)`
+per slot (chain drafters) or `draft_batch(ids_by_slot, k, width)` (tree
 drafters, batched over all slots in one device dispatch).
 """
 
@@ -49,11 +49,14 @@ from gridllm_tpu.utils.config import env_int, env_str
 class Drafter(Protocol):
     """One method: propose up to k likely next tokens for a slot."""
 
-    def draft(self, ids: Sequence[int], k: int) -> list[int]:
+    def draft(self, ids: Sequence[int], k: int,
+              slot: int | None = None) -> list[int]:
         """ids: the slot's full context so far (prompt + generated, oldest
         first; the LAST element is the most recent emitted token). Returns
         0..k proposed continuation tokens — an empty list means "no
-        proposal", which the engine runs as a normal decode step."""
+        proposal", which the engine runs as a normal decode step. The
+        engine names the `slot`, so a drafter may keep what it derived
+        from that slot's history; the proposal is the same without it."""
         ...
 
 
@@ -67,9 +70,25 @@ class NgramDrafter:
     stronger predictor, and the first hit wins (most recent occurrence, the
     llama.cpp/vLLM prompt-lookup convention).
 
-    `lookback` bounds how far back the scan walks (0 = the whole history);
-    worst case is O(max_n × min(len, lookback)) per call, a few µs at chat
-    context lengths — noise next to a model forward.
+    `lookback` bounds how far back the search reaches (0 = the whole
+    history). The history is held as the bytes of an int32 array and the
+    search is `bytearray.rfind` of the suffix's bytes, kept to matches that
+    start on a token: compiled code that never lets go of the interpreter
+    lock. Whole-array numpy compares found the same tokens as fast here,
+    but a ufunc over more than 500 elements releases the lock, and on the
+    chip's host the worker's other threads then ran inside the draft: 0.41
+    ms a step where this form takes 0.12 (PERF.md, PR 37). A miss costs
+    2-19 µs a slot between 256 and 8,192 tokens and a hit 3 µs
+    (deploy/host_draft_cost.py). The walk it replaced compared one list
+    slice a position in the interpreter: a miss cost 1.4 ms a slot at
+    4,096 tokens, which was 7.6 ms a verify step over five 3 k-token
+    documents (PERF_LEDGER, PR 36, `dsv2lite.shared_doc`).
+
+    Called with a `slot`, the drafter keeps that slot's bytes and appends
+    only the tail of `ids` it has not seen, so a step costs the search and
+    not a conversion of the whole list. The engine's lists only grow at
+    the tail between calls; a list that is another object, is shorter, or
+    differs at the last held position is converted afresh.
     """
 
     kind = "ngram"
@@ -80,23 +99,74 @@ class NgramDrafter:
         self.max_n = max_n
         self.min_n = min_n
         self.lookback = max(lookback, 0)
+        # slot -> (the list last handed in, its tokens as int32 bytes)
+        self._held: dict[int, tuple[Sequence[int], bytearray]] = {}
 
-    def draft(self, ids: Sequence[int], k: int) -> list[int]:
-        ids = list(ids)
+    def reset_slot(self, slot: int) -> None:
+        """Drop a slot's history (request finished, slot about to be reused)."""
+        self._held.pop(slot, None)
+
+    def reset(self) -> None:
+        """Drop every slot's history (the engine discarded its slots)."""
+        self._held.clear()
+
+    def _history(self, slot: int, ids: Sequence[int]) -> bytearray:
+        held = self._held.get(slot)
+        if held is not None and held[0] is ids:
+            buf = held[1]
+            n = len(buf) // _TOKEN
+            if n <= len(ids) and buf[-_TOKEN:] == _int32_bytes(ids[n - 1:n]):
+                if n < len(ids):
+                    buf += _int32_bytes(ids[n:])
+                return buf
+        buf = bytearray(_int32_bytes(ids))
+        self._held[slot] = (ids, buf)
+        return buf
+
+    def draft(self, ids: Sequence[int], k: int,
+              slot: int | None = None) -> list[int]:
         n_ids = len(ids)
         if k <= 0 or n_ids < self.min_n + 1:
             return []
-        lo = 0 if not self.lookback else max(n_ids - self.lookback, 0)
-        for n in range(min(self.max_n, n_ids - 1), self.min_n - 1, -1):
-            suffix = ids[n_ids - n:]
-            # most recent occurrence strictly before the suffix itself
-            for i in range(n_ids - n - 1, lo - 1, -1):
-                if ids[i : i + n] == suffix:
-                    cont = ids[i + n : i + n + k]
-                    if cont:
-                        return cont
-                    break  # suffix only recurs at the very end — shorter n
-        return []
+        buf = (_int32_bytes(ids) if slot is None
+               else self._history(slot, ids))
+        size = _TOKEN * n_ids
+        lo = _TOKEN * max(n_ids - self.lookback, 0) if self.lookback else 0
+        # a match ends before the last token: it starts strictly before
+        # the suffix itself, and a token follows it (never an empty draft)
+        end = size - _TOKEN
+        at, n = _rfind_token(buf, buf[end:size], lo, end), 1
+        if at < 0:
+            return []  # the last token never came before, so no suffix did
+        for longer in range(min(self.max_n, n_ids - 1),
+                            max(self.min_n, 2) - 1, -1):
+            found = _rfind_token(buf, buf[size - _TOKEN * longer:size], lo, end)
+            if found >= 0:
+                at, n = found, longer
+                break
+        if n < self.min_n:
+            return []
+        at += _TOKEN * n
+        return np.frombuffer(buf, np.int32, min(k, (size - at) // _TOKEN),
+                             at).tolist()
+
+
+_TOKEN = 4  # bytes of one int32 token
+
+
+def _int32_bytes(ids: Sequence[int]) -> bytes:
+    return np.asarray(ids, np.int32).tobytes()
+
+
+def _rfind_token(buf: bytes | bytearray, pattern: bytes | bytearray,
+                 start: int, end: int) -> int:
+    """Byte offset of the last occurrence of `pattern` in buf[start:end]
+    that starts on a token, or -1."""
+    while True:
+        at = buf.rfind(pattern, start, end)
+        if at < 0 or at % _TOKEN == 0:
+            return at
+        end = at + len(pattern) - 1  # straddles tokens: look before it
 
 
 def make_drafter(kind: str | None = None) -> Drafter:

@@ -2,14 +2,17 @@
 thread's wall time on the speculative and the block path, every phase is
 observed by a run that admits, drafts and finishes, the engine's queue
 wait is measured per request, the verify step's context tokens are
-counted, a capture runs with the Python tracer off unless asked, and no
-TraceAnnotation is constructed while nothing is being captured."""
+counted, a capture runs with the Python tracer off unless asked, no
+TraceAnnotation is constructed while nothing is being captured, and the
+drafter's lookups are counted by outcome and ride on the draft span
+(ISSUE 37)."""
 
 import time
 
 import pytest
 
 from gridllm_tpu.engine import EngineConfig, GenerationRequest, InferenceEngine
+from gridllm_tpu.engine.engine import _SPEC_LOOKUPS
 from gridllm_tpu.obs.perf import (
     ADMIT_WAIT_SECONDS,
     PHASE_SECONDS,
@@ -192,9 +195,10 @@ class _Tracing:
     tracing = False
 
 
-def test_no_trace_annotation_is_constructed_with_no_capture_active(monkeypatch):
-    """The phase clock's spans exist only while a capture runs; then every
-    phase is a gridllm.<phase> annotation, with the launch's metadata."""
+@pytest.fixture
+def made(monkeypatch):
+    """jax.profiler.TraceAnnotation replaced by a recorder: every span
+    constructed, in order, with its metadata."""
     import jax
 
     made: list = []
@@ -214,6 +218,12 @@ def test_no_trace_annotation_is_constructed_with_no_capture_active(monkeypatch):
             self.meta.update(meta)
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Span)
+    return made
+
+
+def test_no_trace_annotation_is_constructed_with_no_capture_active(made):
+    """The phase clock's spans exist only while a capture runs; then every
+    phase is a gridllm.<phase> annotation, with the launch's metadata."""
     eng = InferenceEngine(EngineConfig(**TINY, spec_decode=True))
     flag = _Tracing()
     eng._clock = PhaseClock(MODEL, profiler=flag)
@@ -238,6 +248,43 @@ def test_no_trace_annotation_is_constructed_with_no_capture_active(monkeypatch):
     assert prefill.meta["prompt_tokens"] > 0 and "cached_tokens" in prefill.meta
     assert prefill.meta["mesh"] == ""
     assert any("tokens" in s.meta for s in made if s.name == "gridllm.ingest")
+    draft = next(s for s in made if s.name == "gridllm.draft")
+    assert set(draft.meta) == {"slots", "hits", "history_tokens"}
     n = len(made)
     _serve(eng, n=1, idle_s=0.0)
     assert len(made) == n
+
+
+def test_draft_lookups_are_counted_by_outcome_and_ride_on_the_span(made):
+    """One verify step over two slots, one whose history ends in a suffix
+    it held before and one whose tokens are all distinct: the lookup
+    counter moves by one hit and one miss, and the draft span says so."""
+    eng = InferenceEngine(EngineConfig(**TINY, spec_decode=True))
+    for i in range(2):
+        eng.submit(GenerationRequest(id=f"d{i}", prompt=f"hello there {i}",
+                                     options=OPTS))
+    while not (len(eng._slots) == 2
+               and all(st.joined_gen <= eng._gen for st in eng._slots.values())):
+        assert eng.step()
+    hit, miss = eng._slots.values()
+    # the lookup reads the host's history alone: its content is free
+    hit.ids[:] = [5 + i % 2 for i in range(len(hit.ids))]
+    miss.ids[:] = range(100, 100 + len(miss.ids))
+    history = len(hit.ids) + len(miss.ids)
+
+    def lookups():
+        return {o: _SPEC_LOOKUPS.value(model=MODEL, outcome=o)
+                for o in ("hit", "miss")}
+
+    before = lookups()
+    flag = _Tracing()
+    eng._clock = PhaseClock(MODEL, profiler=flag)
+    flag.tracing = True
+    assert eng.step()
+    flag.tracing = False
+    after = lookups()
+    assert {o: after[o] - before[o] for o in after} == {"hit": 1, "miss": 1}
+    draft, = (s for s in made if s.name == "gridllm.draft")
+    assert draft.meta == {"slots": 2, "hits": 1, "history_tokens": history}
+    while eng.step():
+        pass

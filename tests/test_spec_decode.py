@@ -88,6 +88,134 @@ def test_drafter_factory_env(monkeypatch):
         make_drafter("nope")
 
 
+def _walk_reference(ids, k, max_n, min_n, lookback):
+    """The backward scan NgramDrafter.draft ran until PR 37, verbatim (self.x
+    read as arguments): the oracle the compiled lookup is held to."""
+    ids = list(ids)
+    n_ids = len(ids)
+    if k <= 0 or n_ids < min_n + 1:
+        return []
+    lo = 0 if not lookback else max(n_ids - lookback, 0)
+    for n in range(min(max_n, n_ids - 1), min_n - 1, -1):
+        suffix = ids[n_ids - n:]
+        # most recent occurrence strictly before the suffix itself
+        for i in range(n_ids - n - 1, lo - 1, -1):
+            if ids[i : i + n] == suffix:
+                cont = ids[i + n : i + n + k]
+                if cont:
+                    return cont
+                break  # suffix only recurs at the very end — shorter n
+    return []
+
+
+@pytest.mark.parametrize("lookback", [0, 10, 1000])
+@pytest.mark.parametrize("max_n, min_n", [(4, 1), (2, 2), (7, 1)])
+@pytest.mark.parametrize("alphabet", [2, 16, 256, 100_000])
+def test_lookup_proposes_what_the_walk_proposed(alphabet, max_n, min_n,
+                                                lookback):
+    """Token for token, stateless and through a slot's life as the engine
+    drives it. The four alphabets make every n from max_n down to 1 both
+    hit and miss; a tail copied from earlier history makes long n hit in
+    the large ones."""
+    rng = np.random.RandomState(alphabet + 31 * max_n + 7 * lookback)
+    d = NgramDrafter(max_n=max_n, min_n=min_n, lookback=lookback)
+    seen: set[bool] = set()
+
+    def fresh(n):
+        return [int(t) for t in rng.randint(0, alphabet, n)]
+
+    def same(ids, k, slot=None):
+        want = _walk_reference(ids, k, max_n, min_n, lookback)
+        assert d.draft(ids, k, slot) == want, (len(ids), k, slot)
+        seen.add(bool(want))
+
+    def grow(ids):
+        """One to six more tokens: new ones, or (an accepted draft) the
+        ones that followed an earlier position."""
+        n = int(rng.randint(1, 7))
+        at = int(rng.randint(0, len(ids)))
+        ids.extend(ids[at:at + n] if rng.rand() < 0.5 else fresh(n))
+
+    for n_ids in (1, 2, 3, 4, 5, 8, 9, 17, 64, 300, 1500, 5000):
+        ids = fresh(n_ids)
+        for k in range(9):
+            same(ids, k)
+        ids += ids[n_ids // 3:n_ids // 3 + 5]       # a tail that recurs
+        same(ids, 4)
+
+    # 256 then 0 hold the four bytes of 1 across their boundary: not an
+    # occurrence of 1, and not in the way of the one before it
+    same([256, 0, 5, 1], 4)
+    same([1, 7, 256, 0, 5, 1], 4)
+
+    # the slot's life: admitted with a prompt, 60 verify steps
+    ids = fresh(int(rng.randint(1, 2500)))
+    for step in range(60):
+        same(ids, step % 9, slot=3)
+        grow(ids)
+    # the same list popped at its tail (EOS), then popped and given
+    # another token in that place between two calls
+    same(ids, 4, slot=3)
+    ids.pop()
+    same(ids, 4, slot=3)
+    ids.append(ids.pop() ^ 1)
+    same(ids, 4, slot=3)
+    # finished; a shorter prompt reuses the slot
+    d.reset_slot(3)
+    ids = fresh(40) * 2
+    for _ in range(5):
+        same(ids, 8, slot=3)
+        grow(ids)
+    # a history that is not an extension of the one held, with no reset:
+    # shorter, as long (same last token), longer, and empty
+    held = len(ids)
+    for other in (fresh(7), fresh(held - 1) + ids[-1:], fresh(3 * held), []):
+        same(other, 5, slot=3)
+        if other:
+            grow(other)
+        same(other, 5, slot=3)
+    # another slot's history is its own
+    a, b = fresh(50) * 2, fresh(600)
+    for _ in range(4):
+        same(a, 3, slot=0)
+        same(b, 3, slot=1)
+        grow(a), grow(b)
+    d.reset()
+    same(a, 3, slot=1)
+    if alphabet <= 256:
+        assert seen == {True, False}
+
+
+def test_every_draft_the_engine_takes_is_the_walks():
+    """In the serving loop: ten requests over four slots (slots reused,
+    batches shared, streams ending at their length at different steps),
+    every proposal the engine takes is the walk's on that slot's history."""
+    eng = InferenceEngine(EngineConfig(**TINY, spec_decode=True, spec_k=4))
+    d = eng._drafter
+    lookup, calls, wrong = d.draft, [], []
+
+    def checked(ids, k, slot=None):
+        got = lookup(ids, k, slot)
+        calls.append(slot)
+        if got != _walk_reference(ids, k, d.max_n, d.min_n, d.lookback):
+            wrong.append((slot, len(ids), got))
+        return got
+
+    d.draft = checked
+    done = []
+    for i in range(10):
+        eng.submit(GenerationRequest(
+            id=f"w{i}", prompt=("ab " * (2 + i % 4) + f"{i}") * (1 + i % 3),
+            options={"temperature": 0.0, "repeat_penalty": 1.0,
+                     "num_predict": 4 + 3 * (i % 5)},
+            on_chunk=lambda t, fin, res: fin and done.append(res)))
+    while eng.step():
+        pass
+    assert len(done) == 10 and not wrong
+    assert len(calls) > 40 and set(calls) <= set(range(4))
+    assert not d._held  # every finished slot's history was dropped
+
+
 # ---------------------------------------------------------------------------
 # greedy parity: spec-on streams are byte-identical to spec-off
 # ---------------------------------------------------------------------------
