@@ -3474,6 +3474,11 @@ class InferenceEngine:
             # runner shows in the dump as one phase that stopped growing)
             "runnerPhaseSeconds": {p: round(v, 3) for p, v
                                    in self._clock.seconds.items()},
+            # the runner's own CPU time in the same phases: wall less CPU
+            # is what a phase spent blocked (a lock, the runtime); empty
+            # where the host has no thread clock finer than a millisecond
+            "runnerPhaseCpuSeconds": {p: round(v, 3) for p, v
+                                      in self._clock.cpu_seconds.items()},
         }
 
     def memory_arrays(self) -> dict[str, Any]:

@@ -32,9 +32,12 @@ this module instruments the three dominant TPU-side reasons they don't:
    trace covers the wedge, not its aftermath.
 
 4. **Phase clock** (:class:`PhaseClock`) — the engine runner's wall time
-   partitioned into named phases (``gridllm_engine_phase_seconds``), and
-   the same boundaries as ``jax.profiler.TraceAnnotation`` spans while a
-   capture runs, so a trace shows what the host did in every device gap.
+   partitioned into named phases (``gridllm_engine_phase_seconds``), the
+   thread's own CPU time beside it
+   (``gridllm_engine_phase_cpu_seconds_total``: wall less CPU is what a
+   phase spent blocked), and the same boundaries as
+   ``jax.profiler.TraceAnnotation`` spans while a capture runs, so a
+   trace shows what the host did in every device gap.
    Driven by the engine's runner loop — see engine/engine.py.
 
 jax is imported lazily (function-level): importing this module — and
@@ -45,6 +48,7 @@ processes. Pure stdlib otherwise.
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 import os
 import shutil
@@ -104,8 +108,25 @@ PHASE_SECONDS = _OBS.histogram(
     "block jitted call returning (_count = launches); fetch = blocked on "
     "the device for a block's tokens; ingest = stop checks, detokenize, "
     "stream callbacks. Sum over phases = the runner's wall time; host "
-    "phases growing against fetch is a host stall, not a device problem.",
+    "phases growing against fetch is a host stall, not a device problem. "
+    "A phase's wall sum less gridllm_engine_phase_cpu_seconds_total is its "
+    "blocked time: the runner off the CPU, waiting.",
     ("model", "phase"), buckets=STEP_PHASE_BUCKETS,
+)
+PHASE_CPU_SECONDS_TOTAL = _OBS.counter(
+    "gridllm_engine_phase_cpu_seconds_total",
+    "The engine runner thread's own CPU time (CLOCK_THREAD_CPUTIME_ID), by "
+    "the phases of gridllm_engine_phase_seconds and read at the same marks. "
+    "That series' _sum less this one is the phase's blocked time: the "
+    "runner off the CPU, waiting for the interpreter lock (another thread "
+    "of the worker holding it), a threading lock (dispatch_lock, the "
+    "allocator's), a runtime call that waits on another thread, or the "
+    "scheduler. In fetch and idle_wait blocked is nearly all of the wall "
+    "time by design; in ctl, admit, dispatch_prefill, draft, "
+    "dispatch_verify and ingest it is time the chip waits for work that "
+    "nobody is doing for it. Not served where the host's kernel keeps a "
+    "thread's CPU time in ticks of a millisecond or more (gVisor: 10 ms).",
+    ("model", "phase"),
 )
 ADMIT_WAIT_SECONDS = _OBS.histogram(
     "gridllm_engine_admit_wait_seconds",
@@ -832,16 +853,46 @@ def capture_span(name: str, **meta: Any):
     return jax.profiler.TraceAnnotation(name, **meta)
 
 
+@functools.cache
+def thread_cpu_clock() -> Callable[[], float] | None:
+    """``time.thread_time`` where the kernel keeps a thread's CPU time
+    finer than a millisecond, else None. Linux proper steps it by under a
+    microsecond and a read costs 0.3 us. A sandboxed kernel (gVisor, which
+    the benchmark's machines run under) charges a thread whole 10 ms
+    ticks, so a stretch of a phase reads 0 or a tick, and a read costs
+    6 us: such a clock says nothing of a sub-ms stretch and is not worth
+    its read (PERF.md section 6, PR 38). Probed once a process, by at most
+    2 ms of spinning: a property of the kernel, not a setting."""
+    clock = time.thread_time
+    t0, end = clock(), time.perf_counter() + 2e-3
+    while time.perf_counter() < end:
+        if 0.0 < clock() - t0 < 1e-3:
+            return clock
+    return None
+
+
 class PhaseClock:
     """One clock for the engine's runner thread: its wall time partitioned
     into :data:`PHASES`, mark to mark.
 
     ``mark(phase)`` closes the phase the thread was in and opens `phase`:
-    one ``perf_counter``, one float add into a local dict. ``flush()``
-    (once a runner iteration, never per mark) moves what was closed into
-    ``gridllm_engine_phase_seconds{model,phase}``. ``pause()`` closes the
-    open phase without opening another (the runner stopping, or the end of
-    a synchronous ``step()``): time until the next mark is nobody's.
+    one ``perf_counter``, one ``thread_time`` (the calling thread's CPU
+    time) where :func:`thread_cpu_clock` finds one worth reading, two
+    float adds into a local dict. ``flush()`` (once a runner iteration,
+    never per mark) moves what was closed into
+    ``gridllm_engine_phase_seconds{model,phase}`` and
+    ``gridllm_engine_phase_cpu_seconds_total{model,phase}``. ``pause()``
+    closes the open phase without opening another (the runner stopping, or
+    the end of a synchronous ``step()``): time until the next mark is
+    nobody's. Every ``step()`` and the runner end in ``pause()``, so a
+    stretch opens and closes on one thread.
+
+    A phase's wall seconds less its CPU seconds is its **blocked** time:
+    the thread off the CPU, waiting for the interpreter lock, a
+    ``threading`` lock, the runtime or the scheduler. On a host whose
+    kernel keeps no fine thread clock nothing is read, ``cpu_seconds``
+    stays empty and the CPU series is not served: no reading, not a
+    reading of zero.
 
     While the profiler captures, each phase is also entered as a
     ``TraceAnnotation("gridllm.<phase>", **meta)``, so the ``.xplane.pb``
@@ -852,16 +903,19 @@ class PhaseClock:
     def __init__(self, model: str, profiler: ProfilerCapture | None = None):
         self.model = model
         self._profiler = profiler or _PROFILER
+        self._cpu_clock = thread_cpu_clock()
         self._phase: str | None = None
-        self._t = 0.0
+        self._t = self._cpu = 0.0
         self._span: Any = None
-        # closed and not yet flushed: phase -> [seconds, stretches]
-        self._acc: dict[str, list] = {p: [0.0, 0] for p in PHASES}
+        # closed and not yet flushed: phase -> [seconds, stretches, cpu s]
+        self._acc: dict[str, list] = {p: [0.0, 0, 0.0] for p in PHASES}
         # cumulative, flushed: what tests and batch_state read
         self.seconds: dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.cpu_seconds: dict[str, float] = (
+            dict.fromkeys(PHASES, 0.0) if self._cpu_clock else {})
         self.counts: dict[str, int] = dict.fromkeys(PHASES, 0)
 
-    def _close(self, now: float) -> float:
+    def _close(self, now: float, cpu: float) -> float:
         span = self._span
         if span is not None:
             self._span = None
@@ -872,13 +926,15 @@ class PhaseClock:
         cell = self._acc[self._phase]
         cell[0] += dt
         cell[1] += 1
+        cell[2] += cpu - self._cpu
         return dt
 
     def mark(self, phase: str, **meta: Any) -> float:
         """Enter `phase`; returns the seconds the closed phase lasted."""
-        now = time.perf_counter()
-        dt = self._close(now)
-        self._phase, self._t = phase, now
+        now, clock = time.perf_counter(), self._cpu_clock
+        cpu = clock() if clock else 0.0
+        dt = self._close(now, cpu)
+        self._phase, self._t, self._cpu = phase, now, cpu
         if self._profiler.tracing:
             import jax
 
@@ -894,20 +950,24 @@ class PhaseClock:
             self._span.set_metadata(**meta)
 
     def pause(self) -> None:
-        self._close(time.perf_counter())
+        clock = self._cpu_clock
+        self._close(time.perf_counter(), clock() if clock else 0.0)
         self._phase = None
         self.flush()
 
     def flush(self) -> None:
         for phase, cell in self._acc.items():
-            secs, n = cell
+            secs, n, cpu = cell
             if not n:
                 continue
-            cell[0], cell[1] = 0.0, 0
+            cell[0], cell[1], cell[2] = 0.0, 0, 0.0
             self.seconds[phase] += secs
             self.counts[phase] += n
             for _ in range(n):
                 PHASE_SECONDS.observe(secs / n, model=self.model, phase=phase)
+            if self._cpu_clock:
+                self.cpu_seconds[phase] += cpu
+                PHASE_CPU_SECONDS_TOTAL.inc(cpu, model=self.model, phase=phase)
 
 
 def handle_profile_request(seconds_raw: str | None,

@@ -5,8 +5,13 @@ wait is measured per request, the verify step's context tokens are
 counted, a capture runs with the Python tracer off unless asked, no
 TraceAnnotation is constructed while nothing is being captured, and the
 drafter's lookups are counted by outcome and ride on the draft span
-(ISSUE 37)."""
+(ISSUE 37). The second clock (ISSUE 38): a phase's CPU seconds beside its
+wall seconds, so that a wait (a sleep, the interpreter lock held by
+another thread) reads as blocked time and computing does not; and on a
+host whose kernel counts a thread's CPU in ticks the second clock is not
+read at all and its series not served."""
 
+import threading
 import time
 
 import pytest
@@ -15,12 +20,14 @@ from gridllm_tpu.engine import EngineConfig, GenerationRequest, InferenceEngine
 from gridllm_tpu.engine.engine import _SPEC_LOOKUPS
 from gridllm_tpu.obs.perf import (
     ADMIT_WAIT_SECONDS,
+    PHASE_CPU_SECONDS_TOTAL,
     PHASE_SECONDS,
     PHASES,
     VERIFY_CTX_TOKENS_TOTAL,
     PhaseClock,
     ProfilerCapture,
     handle_profile_request,
+    thread_cpu_clock,
 )
 
 MODEL = "tiny-llama"
@@ -35,6 +42,11 @@ def _phase_counts() -> dict[str, int]:
 
 def _phase_sums() -> dict[str, float]:
     return {p: PHASE_SECONDS.sum(model=MODEL, phase=p) for p in PHASES}
+
+
+def _phase_cpu() -> dict[str, float]:
+    return {p: PHASE_CPU_SECONDS_TOTAL.value(model=MODEL, phase=p)
+            for p in PHASES}
 
 
 def _serve(eng: InferenceEngine, n: int = 3, idle_s: float = 0.3) -> None:
@@ -77,16 +89,32 @@ def test_phases_partition_the_runners_wall_time(spec):
 
 def test_every_phase_is_observed_by_a_run_that_admits_drafts_and_finishes():
     eng = InferenceEngine(EngineConfig(**TINY, spec_decode=True))
-    before = _phase_counts()
+    before, cpu_before = _phase_counts(), _phase_cpu()
     launches0 = eng._gen
     _serve(eng, n=2)
-    after = _phase_counts()
+    after, cpu_after = _phase_counts(), _phase_cpu()
     for p in PHASES:
         assert after[p] > before[p], p
     # admit's count is admissions; dispatch_verify's is launches
     assert after["admit"] - before["admit"] == 2
     assert after["dispatch_verify"] - before["dispatch_verify"] == eng._gen - launches0
-    assert set(eng.batch_state()["runnerPhaseSeconds"]) == set(PHASES)
+    state = eng.batch_state()
+    assert set(state["runnerPhaseSeconds"]) == set(PHASES)
+    clock = eng._clock
+    assert state["runnerPhaseCpuSeconds"].keys() == clock.cpu_seconds.keys()
+    if thread_cpu_clock() is None:
+        assert not clock.cpu_seconds and cpu_after == cpu_before
+        return
+    # the second clock: in every phase the runner's CPU time is within
+    # its wall time, the counter holds what the clock holds, and the two
+    # phases that wait by design are nearly all blocked
+    for p in PHASES:
+        # (two clocks are read one after the other: 1 ms of room)
+        assert 0.0 <= clock.cpu_seconds[p] <= clock.seconds[p] + 1e-3, p
+        assert cpu_after[p] - cpu_before[p] == pytest.approx(
+            clock.cpu_seconds[p], abs=1e-9), p
+    assert clock.cpu_seconds["idle_wait"] < 0.5 * clock.seconds["idle_wait"]
+    assert sum(clock.cpu_seconds.values()) > 0.0
 
 
 def test_admit_wait_is_at_least_an_injected_delay():
@@ -288,3 +316,138 @@ def test_draft_lookups_are_counted_by_outcome_and_ride_on_the_span(made):
     assert draft.meta == {"slots": 2, "hits": 1, "history_tokens": history}
     while eng.step():
         pass
+
+
+# ---------------------------------------------------------------------------
+# the second clock (ISSUE 38): wall beside the thread's CPU time
+# ---------------------------------------------------------------------------
+
+
+def _spin(seconds: float) -> int:
+    """Pure Python for `seconds` of wall time: the interpreter lock is
+    held but for the switch interval's hand-overs."""
+    n, end = 0, time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        n += 1
+    return n
+
+
+def _clocked(work, phase: str = "ingest") -> tuple[float, float]:
+    """(wall, CPU) seconds a fresh clock reads for `work()` in `phase`."""
+    clock = PhaseClock("second-clock")
+    clock.mark(phase)
+    work()
+    clock.pause()
+    return clock.seconds[phase], clock.cpu_seconds[phase]
+
+
+def _best(trials: int, work, good) -> tuple[float, float]:
+    """The first of `trials` readings that `good` accepts, else the last:
+    the suite shares its cores with five other workers, so one reading
+    may be preempted; a clock that could not tell waiting from computing
+    would fail every one."""
+    for _ in range(trials):
+        wall, cpu = _clocked(work)
+        if good(wall, cpu):
+            break
+    return wall, cpu
+
+
+# on a host of tick clocks the second clock is off by design: nothing to hold
+needs_thread_clock = pytest.mark.skipif(
+    thread_cpu_clock() is None, reason="no thread CPU clock under 1 ms here")
+
+
+@needs_thread_clock
+def test_a_sleep_reads_as_wall_time_and_a_spin_as_cpu_time():
+    wall, cpu = _clocked(lambda: time.sleep(0.05))
+    assert wall >= 0.05 and cpu < 0.010
+
+    def spin_cpu():
+        # 50 ms of this thread's CPU, however long a shared core takes
+        end = time.thread_time() + 0.05
+        while time.thread_time() < end:
+            pass
+
+    wall, cpu = _clocked(spin_cpu)
+    assert wall >= 0.05 and 0.035 <= cpu <= wall + 1e-3
+
+
+@needs_thread_clock
+def test_the_interpreter_lock_held_by_another_thread_reads_as_blocked():
+    """A phase of pure Python beside a second thread of pure Python waits
+    for the interpreter lock about half the time; alone it waits for
+    nothing. This is what PR 37 could not see: a phase that computed for
+    part of its wall time and waited for the lock the rest."""
+    def blocked_share(wall, cpu):
+        return (wall - cpu) / wall
+
+    wall, cpu = _best(8, lambda: _spin(0.1),
+                      lambda w, c: blocked_share(w, c) < 0.05)
+    assert blocked_share(wall, cpu) < 0.05, (wall, cpu)
+
+    stop = threading.Event()
+
+    def other():
+        while not stop.is_set():
+            sum(range(1000))
+
+    t = threading.Thread(target=other, daemon=True)
+    t.start()
+    try:
+        wall, cpu = _best(3, lambda: _spin(0.2),
+                          lambda w, c: blocked_share(w, c) >= 0.20)
+    finally:
+        stop.set()
+        t.join(10)
+    assert not t.is_alive()
+    assert blocked_share(wall, cpu) >= 0.20, (wall, cpu)
+
+
+def _ticks(step: float):
+    """A thread clock that moves in whole steps of `step` seconds of the
+    real one, as a sandboxed kernel's does."""
+    return lambda: time.perf_counter() // step * step
+
+
+@pytest.mark.parametrize("step, found", [(0.0, True), (1e-4, True),
+                                         (2e-3, False), (1e-2, False)])
+def test_a_thread_clock_is_read_only_where_it_steps_by_under_a_millisecond(
+        monkeypatch, step, found):
+    """The probe behind every PhaseClock: the kernel's own clock (step 0:
+    whatever this host has, which these tests need to be fine) and clocks
+    of 0.1 ms steps pass, ticks of 2 and 10 ms (gVisor) do not."""
+    if step:
+        monkeypatch.setattr(time, "thread_time", _ticks(step))
+    thread_cpu_clock.cache_clear()
+    try:
+        assert (thread_cpu_clock() is not None) is found
+    finally:
+        thread_cpu_clock.cache_clear()
+
+
+def test_without_a_fine_thread_clock_nothing_is_read_and_nothing_served(
+        monkeypatch):
+    """On a host of 10 ms ticks a mark reads the wall clock alone (a tick
+    clock's read costs 6 us there and says nothing of a sub-ms stretch),
+    ``cpu_seconds`` stays empty and the CPU counter gets no sample, so
+    its readers say nothing rather than "all blocked"."""
+    reads = []
+    monkeypatch.setattr(time, "thread_time",
+                        lambda: reads.append(1) or _ticks(1e-2)())
+    thread_cpu_clock.cache_clear()
+    try:
+        clock = PhaseClock("no-thread-clock")
+        probed = len(reads)
+        assert probed > 0
+        for phase in PHASES:
+            clock.mark(phase)
+        clock.pause()
+    finally:
+        thread_cpu_clock.cache_clear()
+    assert len(reads) == probed
+    assert clock.cpu_seconds == {} and all(clock.counts.values())
+    assert all(PHASE_SECONDS.count(model="no-thread-clock", phase=p) == 1
+               for p in PHASES)
+    assert not [labels for labels, _ in PHASE_CPU_SECONDS_TOTAL.items()
+                if labels["model"] == "no-thread-clock"]
