@@ -6,7 +6,8 @@ compute path was `client/src/services/OllamaService.ts` HTTP calls). Design
 
 - One static device state: paged KV pool shared by `max_slots` concurrent
   requests, per-slot sampler params, per-slot context token counts. All
-  compiled functions are shape-static; prompts pad to the smallest bucket.
+  compiled functions are shape-static; a prompt's chunks pad to one of
+  the chunk program's widths (without a mixed step: to the smallest bucket).
 - Continuous batching: requests join/leave the batch between decode steps
   (the reference capped workers at 1 job, server/src/config/index.ts:31 —
   here concurrency is a device-state property, not a scheduler constant).
@@ -20,9 +21,12 @@ compute path was `client/src/services/OllamaService.ts` HTTP calls). Design
   decode_block × pipeline_depth wasted steps per finishing stream — pure
   compute waste, never a correctness hazard: page-table sentinels drop
   out-of-capacity writes and fetched post-finish tokens are discarded.
-- Admission never synchronizes: the prefill samples the first token on
-  device and folds it into the step state; the host first sees it in the
-  NEXT block's row 0 (blocks return [K+1, S] — input tokens + K sampled),
+- Admission never synchronizes: the prefill (the chunk region of a mixed
+  step where the family has one, so running streams decode in the same
+  launch) samples the first token on device and folds it into the step
+  state; the host first sees it in the mixed launch's own block, beside
+  the decode rows, or after a prefill launch of its own in the NEXT
+  block's row 0 (blocks return [K+1, S] — input tokens + K sampled),
   matched by a per-slot dispatch-generation tag.
 - Speculative decoding (ISSUE 5, default on via GRIDLLM_SPEC_DECODE):
   the host drafts up to GRIDLLM_SPEC_K candidate tokens per slot
@@ -285,16 +289,26 @@ def _device_memory_stats(device) -> dict[str, int]:
     return device.memory_stats() or {}
 
 
+# Every engine with a mixed step admits every prompt through it: the chunk
+# and one decode row for every running stream share a launch, where a
+# prefill of its own stalls the streams for a launch and a half (PERF.md,
+# PRs 33 and 39). What differs by family is the widths of that launch.
+#
 # A routed family's one chunk width (where EngineConfig.prefill_chunk is
-# wider): every prompt is admitted through the mixed step in launches this
-# wide, and the bucketed prefill is not used. Its launch reads every expert
-# it holds whatever rows it carries, so a chunk that takes about a verify
-# launch's period (35 ms at 512 rows beside 34 on the v5e; PERF.md, PR 33)
-# admits a prompt while the running streams decode in step, where a
-# prefill of its own stalls them for a launch and a half. One width: a
-# narrower last chunk would put a step in the time to the first token at
-# the prompt length it starts from
+# wider). Its launch reads every expert it holds whatever rows it carries,
+# so a chunk that takes about a verify launch's period (35 ms at 512 rows
+# beside 34 on the v5e; PERF.md, PR 33) costs the streams least. One
+# width: a narrower last chunk would put a step in the time to the first
+# token at the prompt length it starts from
 ROUTED_CHUNK = 512
+# A dense family's width for the FIRST chunk of an uncached prompt where
+# it holds the whole prompt (else the full chunk): what the smallest
+# prefill bucket was, inside the mixed program. Not narrower: the median
+# chat prompt is 257 tokens, and a width boundary inside the bulk of a
+# mix's prompt lengths puts two modes under the median time to the first
+# token (PERF.md, PR 33 finding 2). Behind a prefix the last chunk keeps
+# EngineConfig.prefill_chunk_narrow
+FIRST_CHUNK = 512
 
 
 def _host_i32(values: list[int], size: int) -> np.ndarray:
@@ -362,13 +376,15 @@ class EngineConfig:
     # padding to the next bucket; rounded down to a multiple of page_size
     # (the in-place page-write kernel requires page-aligned chunk starts)
     prefill_chunk: int = 1024
-    # the one narrower width of the chunk program: a prompt's LAST chunk
-    # (cold, or the fresh tail behind a cached prefix) is padded to it when
-    # it fits, to prefill_chunk otherwise. A cached re-ask is a few dozen
-    # to 192 fresh tokens; a 256-wide launch costs a third of a 1024-wide
-    # one (PERF.md, PR 32). One more model-sized program, so one width and
-    # no ladder; page-aligned like prefill_chunk, and without effect where
-    # prefill_chunk is no wider
+    # the narrow width of a dense family's chunk program: a prompt's LAST
+    # chunk BEHIND A PREFIX (a long prompt's tail, or the fresh tail of a
+    # prefix-cache hit) is padded to it when it fits, to prefill_chunk
+    # otherwise. A cached re-ask is a few dozen to 192 fresh tokens; a
+    # 256-wide launch costs a third of a 1024-wide one (PERF.md, PR 32).
+    # A prompt's first chunk has its own width (FIRST_CHUNK); a routed
+    # family has one width for all (ROUTED_CHUNK). Each width is a
+    # model-sized program, so no ladder; page-aligned like prefill_chunk,
+    # and without effect where prefill_chunk is no wider
     prefill_chunk_narrow: int = 256
     # decode steps fused per dispatch in the runner loop (step() always
     # uses 1 — exact per-token semantics for tests/sync callers)
@@ -510,8 +526,8 @@ class _Slot:
     __slots__ = (
         "req", "ids", "prompt_len", "generated", "detok", "text", "emitted_len",
         "num_predict", "stop_seqs", "eos_ids", "capacity", "joined_gen",
-        "cached_tokens", "spec_proposed", "spec_accepted", "export_only",
-        "snapshot",
+        "first_row", "cached_tokens", "spec_proposed", "spec_accepted",
+        "export_only", "snapshot",
         "t_start", "t_prefill_ns", "t_first_decode", "t_last_ingest",
         "t_admit_wall", "pages_held", "device_s", "admit_wait_ns",
     )
@@ -538,11 +554,15 @@ class _Slot:
         # thread as ONE immutable tuple per surviving token, so a reader
         # on another thread always sees a matched pair.
         self.snapshot: tuple[list[int], str] | None = None
-        # dispatch generation of the FIRST decode block that will see this
-        # slot: its row 0 (block-input tokens) carries the prefill-sampled
-        # token; blocks with a lower generation predate the slot (or belong
-        # to the slot's previous occupant) and are skipped for it
+        # dispatch generation of the FIRST block that carries a token of
+        # this slot, and the row of it that holds the prefill-sampled one:
+        # row 0 (block-input tokens) of the block dispatched after a prefill
+        # launch of its own, row 1 (the decode rows' row) of the block of
+        # the mixed launch that ran the prompt's last chunk; blocks with a
+        # lower generation predate the slot (or belong to the slot's
+        # previous occupant) and are skipped for it
         self.joined_gen = 0
+        self.first_row = 0
         self.t_start = time.perf_counter_ns()
         self.t_prefill_ns = 0
         self.t_first_decode = 0
@@ -846,38 +866,47 @@ class InferenceEngine:
 
     def prewarm(self) -> None:
         """Run every program a first request can need, before serving:
-        bucketed prefill at each bucket a prompt up to one chunk can pad
-        to, the chunked-prefill program, the decode or verify step, and
-        the prefix-cache admission — inline, as greedy requests of the
-        lengths that reach them. A worker that advertised a model first
-        would compile these inside its first requests, minutes on a cold
-        cache, under the scheduler's deadlines and the hang watchdog's
-        requeue; and a kernel that cannot compile fails here, at
-        construction. With the persistent cache a restart reads them
-        from disk. The recompile tripwire stays disarmed: the first REAL
+        the chunk program at each width admission can launch (without a
+        mixed step: bucketed prefill at each bucket a prompt up to one
+        chunk can pad to, and the chunked-prefill program), the decode or
+        verify step, and the prefix-cache admission — inline, as greedy
+        requests of the lengths that reach them. A worker that advertised
+        a model first would compile these inside its first requests,
+        minutes on a cold cache, under the scheduler's deadlines and the
+        hang watchdog's requeue; and a kernel that cannot compile fails
+        here, at construction. With the persistent cache a restart reads
+        them from disk. The recompile tripwire stays disarmed: the first REAL
         completed request still ends warm-up."""
         if self.embedding_only or self.running:
             return
         t0 = time.perf_counter_ns()
         room = self.max_context - 2          # a prompt plus two tokens
         lengths: list[int] = []
-        for b in self._buckets:
-            lengths.append(min(b, room))
-            if self._use_chunked and b >= self._chunk_len:
-                break
+        if self._use_mixed:
+            # a first chunk's own width; the full one too where no chunk
+            # and one below reaches it
+            lengths.append(min(self._chunk_first, room))
+            if self._chunk_first < room <= self._chunk_len:
+                lengths.append(room)
+        else:
+            for b in self._buckets:
+                lengths.append(min(b, room))
+                if self._use_chunked and b >= self._chunk_len:
+                    break
         if self._use_chunked and self._chunk_len < room:
-            # a full chunk and a one-token last chunk: both widths of the
-            # chunk program (_chunk_width); sent twice when the prefix
-            # cache is on, so the second admission is a hit (window_seed,
-            # then the narrow width behind a cached prefix)
+            # a full chunk and a one-token last chunk behind it: the full
+            # and the narrow width of the chunk program (_chunk_width);
+            # sent twice when the prefix cache is on, so the second
+            # admission is a hit (window_seed, then the narrow width
+            # behind a cached prefix)
             lengths += [self._chunk_len + 1] * (
                 2 if self._prefix_cache_cap != 0 else 1)
         # one fill token per length: prompts that shared a first page
-        # would hit the prefix cache and skip their bucket
+        # would hit the prefix cache and skip their width
         prompts = [(n, 1 + lengths.index(n)) for n in lengths]
         if self.mesh is not None:
             # the first request again, as a new prompt: under a mesh its
-            # programs (the sampler row, the first bucket's prefill) were
+            # programs (the sampler row, the first width's chunk) were
             # compiled for the state as created, on one device, and every
             # step leaves the state laid out over the mesh as XLA chose, so
             # their second call compiles again with no new Python signature
@@ -920,6 +949,28 @@ class InferenceEngine:
                  xlaCompiles=XLA_COMPILE_SECONDS.count(model=self.cfg.name),
                  xlaCompileS=round(
                      XLA_COMPILE_SECONDS.sum(model=self.cfg.name), 3))
+
+    def _set_chunk_widths(self) -> None:
+        """The widths a chunk launch can take (_chunk_width picks among
+        them): the full chunk, a first chunk that holds its whole prompt,
+        the last chunk behind a prefix. Page-aligned: the in-place
+        page-write kernel requires chunk starts at page boundaries. A
+        routed launch's cost is flat in its rows (every expert is read
+        whatever it carries), so a routed family has one width; a dense
+        launch's is not."""
+        ps = self.config.page_size
+
+        def aligned(n: int) -> int:
+            return max(ps, (n // ps) * ps)
+
+        full = min(self.config.prefill_chunk, self.max_context)
+        if self.cfg.num_experts and self._use_mixed:
+            self._chunk_len = aligned(min(full, ROUTED_CHUNK))
+            self._chunk_first = self._chunk_narrow = self._chunk_len
+        else:
+            self._chunk_len = aligned(full)
+            self._chunk_first = min(aligned(FIRST_CHUNK), self._chunk_len)
+            self._chunk_narrow = aligned(self.config.prefill_chunk_narrow)
 
     def _set_buckets(self) -> None:
         # always include max_context so every admissible length maps to a
@@ -1616,18 +1667,7 @@ class InferenceEngine:
             self._mixed_chunk_fn = self.perf.wrap(
                 "mixed_chunk", mixed_chunk_fn, armable=text_only
             )
-        ps = self.config.page_size
-        # a routed family with a mixed step admits every prompt through
-        # it, at one width (ROUTED_CHUNK)
-        self._admit_mixed = bool(self._use_mixed and mc.num_experts)
-        chunk = (min(self.config.prefill_chunk, ROUTED_CHUNK)
-                 if self._admit_mixed else self.config.prefill_chunk)
-        # page-aligned chunking: the in-place page-write kernel requires
-        # chunk starts at page boundaries
-        self._chunk_len = max(ps, (min(chunk, self.max_context) // ps) * ps)
-        self._chunk_narrow = (
-            self._chunk_len if self._admit_mixed else
-            max(ps, (self.config.prefill_chunk_narrow // ps) * ps))
+        self._set_chunk_widths()
         self._decode_block_fn = self.perf.wrap("decode_block", decode_block_fn)
 
         # Speculative decoding (ISSUE 5): one verify step = ONE batched
@@ -1779,9 +1819,14 @@ class InferenceEngine:
             self._work.notify_all()
 
     def _tokenize(self, req: GenerationRequest) -> list[int]:
+        """The prompt's ids, never none: an admission is at least one
+        launch of one token, so an empty raw prompt is its BOS alone
+        (llama.cpp's rule)."""
         if req.prompt_ids is not None:
-            return list(req.prompt_ids)
-        return self.tokenizer.encode(req.prompt or "", add_bos=not req.raw)
+            ids = list(req.prompt_ids)
+        else:
+            ids = self.tokenizer.encode(req.prompt or "", add_bos=not req.raw)
+        return ids or [self.tokenizer.bos_id or 0]
 
     def _bucket_for(self, n: int) -> int:
         for b in self._buckets:
@@ -1969,7 +2014,12 @@ class InferenceEngine:
         # sampled token first becomes host-visible in the next block fetch;
         # t_prefill_ns is finalized there (admission → first-token)
         st.t_prefill_ns = time.perf_counter_ns() - t0
-        st.joined_gen = self._gen + 1  # first block dispatched after this
+        if self._use_mixed:
+            # the last chunk's own launch: no launch more before the stream
+            # has its first token
+            st.joined_gen, st.first_row = self._gen, 1
+        else:
+            st.joined_gen = self._gen + 1  # first block dispatched after this
         self._slots[slot] = st
         _TOKENS_TOTAL.inc(len(ids) - cached, model=self.cfg.name,
                           kind="prefill")
@@ -2044,15 +2094,16 @@ class InferenceEngine:
         px = preprocess_images(images, self.cfg.vision_cfg.image_size)
         return self._encode_fn(self.params, px)
 
-    def _chunk_width(self, n: int) -> int:
-        """The width a prompt's LAST chunk of `n` tokens is launched at:
-        the narrow one where it holds them, else the full chunk. The one
-        place the choice is made, from a host integer of the admit plan,
-        so a follower's replay picks the same program. Where the chunk
-        is no wider than the narrow width there is one width, as before."""
-        if n <= self._chunk_narrow < self._chunk_len:
-            return self._chunk_narrow
-        return self._chunk_len
+    def _chunk_width(self, left: int, start: int) -> int:
+        """The width of the next chunk launch, `left` prompt tokens still
+        to go through the model behind `start` that stand in the slot's
+        pages: the narrower width that holds all that is left (a first
+        chunk's, or the one behind a prefix), else the full chunk. The one
+        place the choice is made, from host integers of the admit plan and
+        the widths _set_chunk_widths read off the model, so a follower's
+        replay picks the same program."""
+        fit = self._chunk_narrow if start else self._chunk_first
+        return fit if left <= fit < self._chunk_len else self._chunk_len
 
     def _dispatch_prefill(self, slot: int, ids: list[int],
                           row_list: list[int], upd: dict[str, Any],
@@ -2076,11 +2127,12 @@ class InferenceEngine:
         # counts[slot] is cleared INSIDE prefill_fn / prefill_chunk_fn —
         # no host-side clear here (it would be a dead full-row rewrite)
         row = _host_i32(row_list, len(row_list))
-        if (cached or (self._use_chunked and len(ids) > self._chunk_len)
-                or self._admit_mixed):
+        if (self._use_mixed or cached
+                or (self._use_chunked and len(ids) > self._chunk_len)):
             # chunked prefill: repeated invocations of ONE fixed-shape
             # program against the growing cached prefix — no per-length
-            # traces, no padding to a distant bucket (VERDICT.md #4)
+            # traces, no padding to a distant bucket (VERDICT.md #4). An
+            # engine with a mixed step admits every prompt this way
             c = self._chunk_len
             for s0 in range(0, cached, c):
                 # cached region: repeat-penalty window/counts bookkeeping
@@ -2096,11 +2148,10 @@ class InferenceEngine:
             for s0 in range(cached, len(ids), c):
                 part = ids[s0 : s0 + c]
                 final = s0 + c >= len(ids)
-                # a chunk before the last is full. An image prompt keeps
-                # the one width: a second splice program would compile
-                # inside a user's request
-                width = (self._chunk_width(len(part))
-                         if final and img_flat is None else c)
+                # an image prompt keeps the one width: a second splice
+                # program would compile inside a user's request
+                width = (self._chunk_width(len(ids) - s0, s0)
+                         if img_flat is None else c)
                 padded = _host_i32(part, width)
                 embeds = None
                 if img_flat is not None:
@@ -2574,8 +2625,9 @@ class InferenceEngine:
         for slot, st in list(self._slots.items()):
             if st.joined_gen > gen:
                 continue
-            first_row = 0 if st.joined_gen == gen else 1
-            if first_row == 0:
+            first_row = 1
+            if st.joined_gen == gen:
+                first_row = st.first_row
                 st.t_prefill_ns = now - st.t_start
             if not st.t_first_decode:
                 st.t_first_decode = now
@@ -2617,8 +2669,10 @@ class InferenceEngine:
     def _ingest_block(self, gen: int, tok_np: np.ndarray) -> None:
         """Feed one fetched [k+1, S] token block through per-token
         bookkeeping. Row 0 = block-input tokens: consumed only by slots
-        whose joined_gen == gen (their prefill sample); newer slots (slot
-        reused after this block was dispatched) are skipped entirely."""
+        whose joined_gen == gen after a prefill launch of their own (their
+        prefill sample; a slot a mixed launch admitted reads it from that
+        launch's row 1); newer slots (slot reused after this block was
+        dispatched) are skipped entirely."""
         k = tok_np.shape[0] - 1
         now = time.perf_counter_ns()
         wall = time.time()
@@ -2626,10 +2680,11 @@ class InferenceEngine:
         for slot, st in list(self._slots.items()):
             if st.joined_gen > gen:
                 continue
-            first_row = 0 if st.joined_gen == gen else 1
-            if first_row == 0:
+            first_row = 1
+            if st.joined_gen == gen:
                 # first host-visible token: admission → now is the honest
                 # prompt-eval (prefill) latency for this request
+                first_row = st.first_row
                 st.t_prefill_ns = now - st.t_start
             if not st.t_first_decode:
                 st.t_first_decode = now

@@ -72,7 +72,10 @@ def test_admission_launches_only_named_programs(route):
     eng = InferenceEngine(EngineConfig(**TINY, prefix_cache=True))
     long_ids = [3 + (i % 50) for i in range(40)]
     if route == "bucketed":
-        ids, expect = [5] * 10, ["sampler_row", "prefill"]
+        # a prompt one bucket would hold: one launch, of the mixed step
+        # where the engine has one
+        ids, expect = [5] * 10, [
+            "sampler_row", "mixed_chunk" if eng._use_mixed else "prefill"]
     elif route == "chunked_cold":
         ids = long_ids
         chunk = "mixed_chunk" if eng._use_mixed else "prefill_chunk"

@@ -256,8 +256,12 @@ def test_runner_streams_between_admissions():
         opts = {"temperature": 0.0, "num_predict": 24}
         eng.submit(GenerationRequest(id="a", prompt="aaaa", options=opts,
                                      on_chunk=mk("a", 3)))
-        # let "a" start streaming, then add two more mid-flight
-        _time.sleep(0.3)
+        # let "a" start streaming (its programs compile first: seconds on a
+        # cold cache), then add two more mid-flight
+        deadline = _time.time() + 60
+        while not events and _time.time() < deadline:
+            _time.sleep(0.002)
+        assert events, "stream 'a' never started"
         eng.submit(GenerationRequest(id="b", prompt="bbbb", options=opts,
                                      on_chunk=mk("b", 3)))
         eng.submit(GenerationRequest(id="c", prompt="cccc", options=opts,

@@ -517,9 +517,14 @@ async def test_preemption_round_trip():
     from gridllm_tpu.utils.types import InferenceRequest, Priority
     from gridllm_tpu.worker.service import WorkerService
 
+    # 512 positions a request: the batch job decodes its 400 tokens for
+    # about a second, warm (at 256 it was cut at 230 and done in half a
+    # second, and only the chunk program's compile inside its admission
+    # kept it running until the preempt trigger; since ISSUE 39 the warm-up
+    # request builds that program)
     eng = InferenceEngine(EngineConfig(
         model="tiny-llama", max_slots=1, page_size=16, num_pages=48,
-        max_pages_per_slot=16, prefill_buckets=(32, 64), prefill_chunk=16,
+        max_pages_per_slot=32, prefill_buckets=(32, 64), prefill_chunk=16,
         kv_host_bytes=1 << 22, kv_spill_int8=False, seed=3))
     bus = InMemoryBus()
     await bus.connect()
@@ -550,7 +555,7 @@ async def test_preemption_round_trip():
         batch = req("count: one two three four", Priority.low, 400)
         t_batch = asyncio.ensure_future(
             scheduler.submit_and_wait(batch, timeout_ms=180_000))
-        await asyncio.sleep(0.4)
+        await asyncio.sleep(0.15)
         r_inter = await asyncio.wait_for(
             scheduler.submit_and_wait(
                 req("hello there", Priority.high, 8), timeout_ms=120_000),

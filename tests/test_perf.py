@@ -30,6 +30,10 @@ TINY = dict(
     num_pages=64,
     max_pages_per_slot=8,
     prefill_buckets=(16, 32),
+    # chunks of 32, a last chunk behind a prefix at 16: two widths of the
+    # chunk program, which admits every prompt
+    prefill_chunk=32,
+    prefill_chunk_narrow=16,
 )
 
 OPTS = {"temperature": 0.0, "num_predict": 6}
@@ -68,16 +72,16 @@ def test_steady_state_varying_batch_fill_zero_recompiles(engine):
 
 
 def test_unseen_shape_bucket_counts_exactly_one(engine):
-    """A prompt landing in a bucket never prefilled before compiles ONE
-    new program: counted under {fn="prefill", reason="new_shape"} with a
-    flight-recorder event carrying the offending shapes."""
-    before = RECOMPILES_TOTAL.value(fn="prefill", reason="new_shape")
+    """A prompt landing on a chunk width never launched before compiles
+    ONE new program: counted under {fn="mixed_chunk", reason="new_shape"}
+    with a flight-recorder event carrying the offending shapes."""
+    before = RECOMPILES_TOTAL.value(fn="mixed_chunk", reason="new_shape")
     steady_before = recompile_totals()["steady"]
-    long_prompt = "x" * 24  # > bucket 16, pads to bucket 32
+    long_prompt = "x" * 40  # a chunk, and a last chunk at the narrow width
     engine.generate(GenerationRequest(id="bkt", prompt=long_prompt,
                                       options=OPTS))
     assert RECOMPILES_TOTAL.value(
-        fn="prefill", reason="new_shape") == before + 1
+        fn="mixed_chunk", reason="new_shape") == before + 1
     # exactly one steady recompile total — decode/sampler shapes are
     # bucket-independent and must NOT have recompiled
     assert recompile_totals()["steady"] == steady_before + 1
@@ -86,14 +90,14 @@ def test_unseen_shape_bucket_counts_exactly_one(engine):
               if e["event"] == "recompile"]
     assert events, "steady-state recompile must leave a flight event"
     last = events[-1]
-    assert last["fn"] == "prefill" and last["reason"] == "new_shape"
-    assert "32" in last["shapes"]  # the offending padded bucket
+    assert last["fn"] == "mixed_chunk" and last["reason"] == "new_shape"
+    assert "16" in last["shapes"]  # the offending padded width
 
-    # repeat of the SAME bucket: no further count
-    engine.generate(GenerationRequest(id="bkt2", prompt="y" * 24,
+    # repeat of the SAME width: no further count
+    engine.generate(GenerationRequest(id="bkt2", prompt="y" * 40,
                                       options=OPTS))
     assert RECOMPILES_TOTAL.value(
-        fn="prefill", reason="new_shape") == before + 1
+        fn="mixed_chunk", reason="new_shape") == before + 1
 
 
 def test_static_arg_change_classified_new_static(engine):

@@ -338,7 +338,8 @@ def _served(model, chunk, prompts):
     texts = [eng.generate(GenerationRequest(
         id=f"r{i}", prompt=p, options={"temperature": 0.0, "num_predict": 10})
     ).text for i, p in enumerate(prompts)]
-    return eng._admit_mixed, eng._chunk_width(1), calls, texts
+    # the width of a one-token chunk, first and behind a prefix
+    return eng._chunk_width(1, 0), eng._chunk_width(1, chunk), calls, texts
 
 
 def test_a_routed_family_admits_every_prompt_through_the_mixed_step():
@@ -347,22 +348,23 @@ def test_a_routed_family_admits_every_prompt_through_the_mixed_step():
     width, the chunk's (running streams decode in the admission's launch,
     where a bucketed prefill would stall them for a launch of its own),
     and the bucketed prefill is never called; the tokens are the same at
-    another width. A dense family admits as before: a prompt that fits a
-    bucket through the bucketed prefill, a longer one in chunks of 32 with
-    its last chunk at 16."""
+    another width. A dense family admits the same way since ISSUE 39, with
+    the widths of its own: a longer prompt in chunks of 32 with its last
+    chunk at 16."""
     from gridllm_tpu.engine import engine
 
     assert engine.ROUTED_CHUNK == 512             # the width the chip read
     prompts = ["short one", "a prompt of more than sixteen tokens"]   # 10, 37
-    on, width, calls, texts = _served("tiny-smallthinker", 32, prompts)
-    assert (on, width) == (True, 32)              # one width: no narrow end
+    first, end, calls, texts = _served("tiny-smallthinker", 32, prompts)
+    assert (first, end) == (32, 32)               # one width: no narrow end
     assert calls == {"prefill": 0, "mixed": 1 + 2}
-    _, narrow, calls_narrow, texts_narrow = _served("tiny-smallthinker", 16, prompts)
-    assert narrow == 16 and calls_narrow == {"prefill": 0, "mixed": 1 + 3}
+    first, end, calls_narrow, texts_narrow = _served("tiny-smallthinker", 16, prompts)
+    assert (first, end) == (16, 16)
+    assert calls_narrow == {"prefill": 0, "mixed": 1 + 3}
     assert texts == texts_narrow
-    dense, end, calls_dense, _ = _served("tiny-llama", 32, prompts)
-    assert (dense, end) == (False, 16)
-    assert calls_dense == {"prefill": 1, "mixed": 2}
+    first, end, calls_dense, _ = _served("tiny-llama", 32, prompts)
+    assert (first, end) == (32, 16)
+    assert calls_dense == {"prefill": 0, "mixed": 1 + 2}
 
 
 def test_a_dense_family_counts_its_whole_context_as_window_tokens():
