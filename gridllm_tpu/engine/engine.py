@@ -182,6 +182,23 @@ _KV_ROW_BYTES = _OBS.gauge(
     "by every head) or kv (K and V per KV head). Set at pool creation.",
     ("model", "kind"),
 )
+_STATE_BYTES = _OBS.gauge(
+    "gridllm_state_bytes",
+    "Device bytes of a hybrid family's recurrent state, by model and kind: "
+    "slot (every slot's state, convolution rows and pending rows) or "
+    "snapshot (the prefix cache's snapshot pool). Set at pool creation.",
+    ("model", "kind"),
+)
+_STATE_SNAP_USED = _OBS.gauge(
+    "gridllm_state_snapshot_pool_used",
+    "Entries of the recurrent-state snapshot pool that hold a snapshot.",
+    ("model",),
+)
+_STATE_SNAP_CAPACITY = _OBS.gauge(
+    "gridllm_state_snapshot_pool_capacity",
+    "Entries the recurrent-state snapshot pool has.",
+    ("model",),
+)
 _KV_ROW_BYTES_EQUIV = _OBS.gauge(
     "gridllm_kv_row_bytes_per_head_equiv",
     "Bytes the same row would take stored as K and V per head at the "
@@ -311,6 +328,16 @@ ROUTED_CHUNK = 512
 FIRST_CHUNK = 512
 
 
+# the form a launch reads each kind of cache in (ModelConfig.cache_kinds)
+_ATTN_FORMS = {"kv": "per_head", "latent": "absorbed", "state": "delta"}
+# a hybrid family's snapshot pool where the engine sizes it: this share of
+# what the device has left after the weights, the reserve and the slots'
+# state (the rest is pages), and this many entries a slot where there is
+# no device to size against (CPU)
+SNAPSHOT_SHARE = 0.3
+SNAPSHOTS_PER_SLOT = 4
+
+
 def _host_i32(values: list[int], size: int) -> np.ndarray:
     """`values` zero-padded to `size` as a host int32 buffer — what a
     jitted call takes for a token chunk or a page-table row. Passed
@@ -332,6 +359,10 @@ def _model_module(cfg: ModelConfig):
         from gridllm_tpu.models import deepseek
 
         return deepseek
+    if cfg.family == "olmo_hybrid":
+        from gridllm_tpu.models import olmo_hybrid
+
+        return olmo_hybrid
     if cfg.family == "bert_embed":
         from gridllm_tpu.models import bert_embed
 
@@ -608,12 +639,15 @@ class InferenceEngine:
         self._layer_windows = np.asarray(
             [w or np.inf for w in self.cfg.layer_windows])
         self._windowed = int(np.isfinite(self._layer_windows).sum())
-        # span meta of every launch: what a cache row is and the form its
-        # attention reads it in (a latent family: absorbed in every region)
-        self._attn_meta = (
-            {"cache_row": "latent", "attn_form": "absorbed"}
-            if self.cfg.kv_lora_rank
-            else {"cache_row": "kv", "attn_form": "per_head"})
+        # span meta of every launch, from the family's cache kinds: what a
+        # slot holds of its past and the form each kind is read in (latent
+        # rows: absorbed in every region; a state: the delta rule)
+        kinds = self.cfg.cache_kinds
+        self._attn_meta = {
+            "cache_row": "+".join(kinds),
+            "attn_form": "+".join(_ATTN_FORMS[k] for k in kinds)}
+        # a second kind of cache beside the pages: a recurrent state a slot
+        self._hybrid = "state" in kinds
         self.embedding_only = self.cfg.family == "bert_embed"
         self.tokenizer: Tokenizer = get_tokenizer(
             config.tokenizer, self.cfg.vocab_size
@@ -1012,6 +1046,12 @@ class InferenceEngine:
                 "latent cache (one row is key and value at once; no "
                 "quantised read of it has been written or measured): "
                 "unset GRIDLLM_KV_INT8 / EngineConfig.kv_int8")
+        if self._hybrid:
+            raise ValueError(
+                f"{self.cfg.name}: an int8 KV pool is not served beside a "
+                "recurrent state (the pages are a quarter of the layers; "
+                "no quantised read has been measured against the float32 "
+                "state): unset GRIDLLM_KV_INT8 / EngineConfig.kv_int8")
         if self.mesh is not None:
             log.info("int8 KV pool disabled: meshed pools keep the fp "
                      "layout", model=self.cfg.name)
@@ -1032,6 +1072,12 @@ class InferenceEngine:
             raise ValueError(
                 f"{self.cfg.name}: the host KV tier is not served for a "
                 "latent cache (its spill format is K and V pages): unset "
+                "GRIDLLM_KV_HOST_BYTES / EngineConfig.kv_host_bytes")
+        if self._hybrid:
+            raise ValueError(
+                f"{self.cfg.name}: the host KV tier (and park_to_host) is "
+                "not served beside a recurrent state (pages that come back "
+                "without their snapshot admit nothing): unset "
                 "GRIDLLM_KV_HOST_BYTES / EngineConfig.kv_host_bytes")
         if self._prefix_cache_cap == 0 or self.mesh is not None:
             log.info("host KV tier disabled: needs the prefix cache and "
@@ -1093,6 +1139,11 @@ class InferenceEngine:
         size matching the engine's."""
         name = self._resolve_draft_model()
         if not name:
+            return None
+        if self._hybrid:
+            log.warning("draft-model (tree) speculation is not served for a "
+                        "recurrent state; falling back to n-gram",
+                        model=self.cfg.name, draftModel=name)
             return None
         try:
             dcfg = get_config(name)
@@ -1193,11 +1244,14 @@ class InferenceEngine:
         c, mc = self.config, self.cfg
         dpool = self._pool_head_dim()
         if not self._kv_int8:
-            return PagedKVCache.create(
-                mc.num_layers, num_pages, c.page_size, mc.cache_heads,
+            cache = PagedKVCache.create(
+                mc.cache_layers, num_pages, c.page_size, mc.cache_heads,
                 dpool, c.max_slots, c.max_pages_per_slot,
                 dtype=jnp.dtype(c.dtype), latent=bool(mc.kv_lora_rank),
             )
+            if self._hybrid:
+                cache.rec = self._new_state(self._snapshots)
+            return cache
         # resident int8 pool (ISSUE 11): QuantPages where the fp pool
         # arrays would sit — int8 values + one f32 scale per (layer,
         # page, row). Scales init to 1.0 so unwritten rows dequant to
@@ -1218,6 +1272,13 @@ class InferenceEngine:
             lengths=jnp.zeros((c.max_slots,), jnp.int32),
             page_size=c.page_size,
         )
+
+    def _new_state(self, snapshots: int):
+        """A hybrid family's recurrent state for every slot, a verify
+        launch's rows wide, with a pool of `snapshots` (traceable)."""
+        return self.mod.new_state(
+            self.cfg, self.config.max_slots, self._resolve_spec_k() + 1,
+            snapshots, jnp.dtype(self.config.dtype))
 
     def _page_bytes_per_device(self) -> int:
         """Bytes ONE pool page (K and V, every layer, int8 scales
@@ -1254,11 +1315,24 @@ class InferenceEngine:
                 if free is None or f < free:
                     free, limit = f, stats["bytes_limit"]
         want = DEFAULT_NUM_PAGES if c.num_pages is None else c.num_pages
+        self._snapshots = state_bytes = 0
         page_bytes = self._page_bytes_per_device()
+        if self._hybrid:
+            # the slots' state comes off the top; of what is then left the
+            # snapshot pool takes its share, the pages the rest
+            proto = jax.eval_shape(partial(self._new_state, 1))
+            self._snapshots = (
+                SNAPSHOTS_PER_SLOT * c.max_slots if free is None
+                else max(int(SNAPSHOT_SHARE * max(
+                    free - WORKSPACE_RESERVE_BYTES - proto.slot_nbytes, 0))
+                    // proto.snap_nbytes, SNAPSHOTS_PER_SLOT))
+            state_bytes = (proto.slot_nbytes
+                           + self._snapshots * proto.snap_nbytes)
         if free is None:
             pages = want
         else:
-            fit = max(free - WORKSPACE_RESERVE_BYTES, 0) // page_bytes
+            fit = max(free - WORKSPACE_RESERVE_BYTES - state_bytes,
+                      0) // page_bytes
             # an explicit count is a contract; the default shrinks to the
             # device, down to one slot at full context
             floor = want if c.num_pages is not None else min(
@@ -1280,6 +1354,7 @@ class InferenceEngine:
             bytesPerDevice=pages * page_bytes, requestedPages=c.num_pages,
             deviceFreeBytes=free, deviceLimitBytes=limit,
             reserveBytes=WORKSPACE_RESERVE_BYTES if free is not None else None,
+            stateBytes=state_bytes, stateSnapshots=self._snapshots,
             devices=len(devices))
         return pages
 
@@ -1309,7 +1384,7 @@ class InferenceEngine:
                      f"num_pages={int(c.num_pages * mc.cache_dim / dpool)}",
             )
         itemsize = 1 if self._kv_int8 else jnp.dtype(c.dtype).itemsize
-        kind = "latent" if mc.kv_lora_rank else "kv"
+        kind = mc.cache_kinds[0]
         # the row as stored: every cache head of K (and V) at the pool's width
         row_bytes = mc.kv_row_values // mc.cache_dim * dpool * itemsize
         _KV_ROW_BYTES.set(row_bytes, model=mc.name, kind=kind)
@@ -1320,7 +1395,8 @@ class InferenceEngine:
              else mc.kv_row_values) * itemsize, model=mc.name)
         log.info("kv pool rows", model=mc.name, cacheRow=kind,
                  kvRowBytes=row_bytes, rowValues=mc.kv_row_values,
-                 poolRowDim=dpool)
+                 poolRowDim=dpool, cacheLayers=mc.cache_layers,
+                 modelLayers=mc.num_layers)
         if self.mesh is not None:
             # built under jit with the mesh's shardings: no device ever
             # holds more than its shard (a pool sized to several chips'
@@ -1336,7 +1412,18 @@ class InferenceEngine:
         self.alloc = PageAllocator(
             c.num_pages, c.page_size, c.max_pages_per_slot,
             cache_pages=self._prefix_cache_cap, model=mc.name,
+            snapshots=self._snapshots if self._prefix_cache_cap else 0,
         )
+        if self._hybrid:
+            rec = self.cache.rec
+            _STATE_BYTES.set(rec.slot_nbytes, model=mc.name, kind="slot")
+            _STATE_BYTES.set(rec.snap_nbytes, model=mc.name, kind="snapshot")
+            _STATE_SNAP_CAPACITY.set(self.alloc.snapshots, model=mc.name)
+            log.info("recurrent state", model=mc.name,
+                     linearLayers=mc.linear_layers,
+                     slotStateBytes=rec.slot_nbytes // c.max_slots,
+                     snapshotBytes=rec.snap_nbytes // max(self._snapshots, 1),
+                     snapshots=self._snapshots, stepRows=rec.step_rows)
         if self.host_tier is not None:
             # tiered KV cache (ISSUE 11): eviction spills to host RAM,
             # match_prefix misses consult it — both fire under
@@ -1516,12 +1603,15 @@ class InferenceEngine:
         @partial(jax.jit, donate_argnums=(2, 3, 4, 5, 6, 7, 8))
         def mixed_chunk_fn(params, chunk, cache, counts, window, wlen,
                            tokens, active, sp, start, length, slot,
-                           table_row, is_final, embeds=None):
+                           table_row, is_final, embeds=None, state_io=None):
             tokens_in = tokens
             active_in = active
+            # a hybrid family's launch is also told at which page
+            # boundaries it passes to save its state, and where
+            state_kw = {} if state_io is None else {"state_io": state_io}
             chunk_logits, dec_logits, cache = mod.mixed_step(
                 params, mc, chunk, start, length, slot, table_row, tokens,
-                cache, active, mesh=self.mesh, embeds=embeds,
+                cache, active, mesh=self.mesh, embeds=embeds, **state_kw,
             )
             # chunk-slot bookkeeping (exactly prefill_chunk_fn's)
             rl = sp.repeat_last_n[slot]
@@ -1625,6 +1715,16 @@ class InferenceEngine:
         def deactivate_fn(active, slot):
             return active.at[slot].set(False)
 
+        if self._hybrid:
+            # a prefix-cache admission's state: one snapshot copied into
+            # the slot ahead of its first chunk launch
+            @partial(jax.jit, donate_argnums=(0,))
+            def state_restore_fn(cache, slot, entry):
+                return dataclasses.replace(
+                    cache, rec=cache.rec.restore(slot, entry))
+
+            self._state_restore_fn = self.perf.wrap(
+                "state_restore", state_restore_fn)
         self._sampler_row_fn = self.perf.wrap("sampler_row", sampler_row_fn)
         self._deactivate_fn = self.perf.wrap("deactivate", deactivate_fn)
         self._window_seed_fn = self.perf.wrap("window_seed", window_seed_fn)
@@ -1722,6 +1822,10 @@ class InferenceEngine:
                     cache,
                     jnp.minimum(cache.lengths + n_emit, cache.max_context),
                 )
+                if self._hybrid:
+                    # the state's commit: of the launch's pending rows the
+                    # accepted ones count (rollback's counterpart)
+                    cache = mod.commit_verify(cache, n_emit, active)
                 # block protocol: [K+2, S] — row 0 = block-input tokens
                 # (a just-admitted slot's prefill sample), rows 1..K+1 the
                 # emitted tokens, valid up to n_emit per slot
@@ -1931,6 +2035,8 @@ class InferenceEngine:
                 with self._lock:
                     self._pending.appendleft(req)
                 return False
+            state_plan = (self._plan_state(slot, ids, cached)
+                          if self._hybrid else None)
         self._free_slots.pop()
 
         stop = opts.get("stop") or []
@@ -1983,9 +2089,13 @@ class InferenceEngine:
         t0 = time.perf_counter_ns()
         # the span that caused the program launch; what follows the
         # dispatch in this function (counters, gauges) stays in this phase
+        state_meta = {} if state_plan is None else {
+            "state_restored_at": cached if state_plan["restore"] >= 0 else 0,
+            "replayed_tokens": state_plan["replayed"]}
         self._clock.mark("dispatch_prefill", request=req.id,
                          prompt_tokens=len(ids), cached_tokens=cached,
-                         mesh=self.mesh_axes, **self._attn_meta)
+                         mesh=self.mesh_axes, **self._attn_meta,
+                         **state_meta)
         with self.dispatch_lock:
             # emit AFTER the dispatch succeeds: a record for a program the
             # liaison never actually issued would make followers replay a
@@ -1994,7 +2104,7 @@ class InferenceEngine:
             # is already unpaired and the slice-failure machinery tears the
             # group down — there is no cheap reconciliation for that.)
             self._dispatch_prefill(slot, ids, row_list, upd, images=images,
-                                   cached=cached)
+                                   cached=cached, state_plan=state_plan)
             if self.plan_sink is not None:
                 # SNAPSHOT the ids: the list is also _Slot.ids, which
                 # _ingest APPENDS generated tokens to — a by-reference
@@ -2032,6 +2142,39 @@ class InferenceEngine:
         self._update_kv_gauges()
         return True
 
+    def _plan_state(self, slot: int, ids: list[int],
+                    cached: int) -> dict[str, Any]:
+        """A hybrid admission's plan for the state (under _alloc_lock,
+        after alloc): the snapshot to restore into the slot (-1: none; the
+        pages' match was already cut to it, `cached`), and the page
+        boundaries the prompt's launches pass at which the state is saved,
+        each with its snapshot entry: where this asker's own page match
+        ended if no snapshot stood there (a later asker of the same prefix
+        finds one), and the prompt's last two (a prompt that shares this
+        one up to a question at its end matches at one of them). A launch
+        hands back `SAVES` states at most, so a boundary past that many in
+        one launch (in the order above) is not planned, and nothing is
+        registered that no launch writes. Plain host integers: a record
+        of the admit plan."""
+        found, _kept, entry = self.alloc.state_match(slot)
+        ps = self.config.page_size
+        last = len(ids) // ps * ps
+        want: list[int] = []
+        per_launch: dict[int, int] = {}
+        for b in dict.fromkeys((found, last, last - ps)):
+            # _dispatch_prefill's launches: (cached + i c, cached + (i + 1) c]
+            launch = (b - cached - 1) // self._chunk_len
+            if b > cached and per_launch.get(launch, 0) < self.mod.SAVES:
+                per_launch[launch] = per_launch.get(launch, 0) + 1
+                want.append(b)
+        saves: list[tuple[int, int]] = []
+        if want and self.alloc.snapshots:
+            keys = self.alloc.chain_keys(ids, n_pages=max(want) // ps)
+            entries = self.alloc.snapshot_entries(
+                [keys[b // ps - 1] for b in want])
+            saves = [(b, e) for b, e in zip(want, entries) if e >= 0]
+        return {"restore": entry, "replayed": found - cached, "saves": saves}
+
     def _update_kv_gauges(self) -> None:
         free = self.alloc.free_pages
         cached = self.alloc.cached_pages
@@ -2055,6 +2198,9 @@ class InferenceEngine:
         total = self.alloc.hits + self.alloc.misses
         if total:
             _PREFIX_HIT_RATE.set(self.alloc.hits / total, model=self.cfg.name)
+        if self._hybrid:
+            _STATE_SNAP_USED.set(self.alloc.snapshots_used,
+                                 model=self.cfg.name)
 
     def _expand_image_tokens(self, ids: list[int], n_images: int) -> list[int]:
         """Expand image placeholders to num_patches copies each (the splice
@@ -2108,7 +2254,8 @@ class InferenceEngine:
     def _dispatch_prefill(self, slot: int, ids: list[int],
                           row_list: list[int], upd: dict[str, Any],
                           images: list[str] | None = None,
-                          cached: int = 0) -> None:
+                          cached: int = 0,
+                          state_plan: dict[str, Any] | None = None) -> None:
         """The device half of admission — everything a multi-host follower
         must replay identically: sampler row update + prefill dispatch.
         All inputs are plain host values (the admit plan record). `cached`
@@ -2127,6 +2274,10 @@ class InferenceEngine:
         # counts[slot] is cleared INSIDE prefill_fn / prefill_chunk_fn —
         # no host-side clear here (it would be a dead full-row rewrite)
         row = _host_i32(row_list, len(row_list))
+        saves = state_plan["saves"] if state_plan else []
+        if state_plan and state_plan["restore"] >= 0:
+            self.cache = self._state_restore_fn(
+                self.cache, slot_, np.int32(state_plan["restore"]))
         if (self._use_mixed or cached
                 or (self._use_chunked and len(ids) > self._chunk_len)):
             # chunked prefill: repeated invocations of ONE fixed-shape
@@ -2170,6 +2321,7 @@ class InferenceEngine:
                     # are ingested like any other block
                     self._dispatch_mixed_chunk(
                         padded, s0, len(part), slot, row, final, embeds,
+                        self._state_io(saves, s0, len(part)),
                     )
                     continue
                 (self.cache, self.counts, self.window, self.wlen,
@@ -2396,9 +2548,24 @@ class InferenceEngine:
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({"op": "block", "k": k})
 
+    def _state_io(self, saves: list[tuple[int, int]], start: int,
+                  length: int):
+        """What a hybrid family's chunk launch over [start, start +
+        length) is told of the admission's saves: the positions it passes
+        and their snapshot entries, padded with -1; None for a family
+        without a state."""
+        if not self._hybrid:
+            return None
+        mine = [s for s in saves if start < s[0] <= start + length]
+        pos = np.full((self.mod.SAVES,), -1, np.int32)
+        idx = np.full((self.mod.SAVES,), -1, np.int32)
+        for i, (b, e) in enumerate(mine):     # SAVES at most: _plan_state
+            pos[i], idx[i] = b, e
+        return pos, idx
+
     def _dispatch_mixed_chunk(self, padded, start: int, length: int,
                               slot: int, row, is_final: bool,
-                              embeds) -> None:
+                              embeds, state_io=None) -> None:
         """Dispatch one ragged mixed step (chunk + decode, ISSUE 6). Runs
         under dispatch_lock (called from _dispatch_prefill). The [2, S]
         decode-token block joins _inflight with its own generation —
@@ -2409,7 +2576,7 @@ class InferenceEngine:
             self.params, padded, self.cache, self.counts, self.window,
             self.wlen, self.tokens, self.active, self.sampling,
             np.int32(start), np.int32(length), np.int32(slot), row,
-            np.bool_(is_final), embeds=embeds,
+            np.bool_(is_final), embeds=embeds, state_io=state_io,
         )
         self._inflight.append((self._gen, (out, None), 1))
 
@@ -3100,8 +3267,9 @@ class InferenceEngine:
                 and self.plan_sink is None
                 # the wire's header and its pages are K and V per head: a
                 # latent pool is neither exported nor imported (the
-                # request is served where it arrived)
-                and not self.cfg.kv_lora_rank)
+                # request is served where it arrived); nor are pages whose
+                # snapshot of the recurrent state would stay behind
+                and self.cfg.cache_kinds == ("kv",))
 
     def export_prefix_pages(self, token_ids: list[int]) -> dict[str, Any] | None:
         """Gather the longest cached full-page prefix of `token_ids` as
@@ -3554,6 +3722,9 @@ class InferenceEngine:
         else:
             out["kv"] = [a for a in (cache.k, cache.v, cache.page_table,
                                      cache.lengths) if a is not None]
+        # a hybrid family's second cache: the slots' state and the
+        # snapshot pool are KV-class device memory too
+        out["kv"] += jax.tree.leaves(cache.rec)
         c, mc = self.config, self.cfg
         kv_bytes = cache.pool_nbytes
         bpp = kv_bytes / max(c.num_pages, 1)
@@ -3584,8 +3755,13 @@ class InferenceEngine:
                 "ragged" if dpool == mc.cache_dim else "ragged-padded"),
             # what a token's row of one layer is: K and V per KV head, or
             # one latent row shared by every head (no V array)
-            "cacheRow": "latent" if mc.kv_lora_rank else "kv",
-            "rowBytes": int(bpp / max(c.page_size * mc.num_layers, 1)),
+            "cacheRow": "+".join(mc.cache_kinds),
+            "rowBytes": int(bpp / max(c.page_size * mc.cache_layers, 1)),
+            "stateBytes": (None if cache.rec is None else {
+                "slots": cache.rec.slot_nbytes,
+                "snapshots": cache.rec.snap_nbytes,
+                "snapshotsUsed": self.alloc.snapshots_used,
+                "snapshotsCapacity": self.alloc.snapshots}),
             "liveTokens": live_tokens,
             # internal fragmentation of the live allocation: capacity
             # reserved at admission (num_predict headroom + tail pages)

@@ -120,6 +120,11 @@ def load_checkpoint(
         from gridllm_tpu.models import deepseek
 
         return deepseek.from_getter(cfg, get, dtype, place)
+    if cfg.family == "olmo_hybrid":
+        # two stacked trees by the layers' kinds, [periods, ...]
+        from gridllm_tpu.models import olmo_hybrid
+
+        return olmo_hybrid.from_getter(cfg, get, dtype, place)
     return hf_layout.to_pytree(cfg, get, _name_map(cfg), dtype, place)
 
 
@@ -134,6 +139,10 @@ def save_checkpoint(params: Any, cfg: ModelConfig, path: str) -> None:
         raise NotImplementedError(
             "deepseek_v2 checkpoints are read (models/deepseek.from_getter), "
             "not written: the inverse of its RoPE re-pairing is not here")
+    if cfg.family == "olmo_hybrid":
+        raise NotImplementedError(
+            "olmo_hybrid checkpoints are read (models/olmo_hybrid."
+            "from_getter), not written")
     os.makedirs(path, exist_ok=True)
     if cfg.family == "bert_embed":
         from gridllm_tpu.models import bert_embed

@@ -47,8 +47,8 @@ class VisionConfig:
 class ModelConfig:
     name: str
     # llama | qwen2 | qwen3 | gemma2 | mixtral | smallthinker |
-    # deepseek_v2 | llava | bert_embed (engine._model_module picks the
-    # module)
+    # deepseek_v2 | olmo_hybrid | llava | bert_embed
+    # (engine._model_module picks the module)
     family: str = "llama"
     vocab_size: int = 128_256
     hidden_size: int = 4096
@@ -89,6 +89,21 @@ class ModelConfig:
     # smallthinker: the router reads the PRE-attention normed state, not
     # the post-attention one the experts compute on
     router_pre_attn: bool = False
+    # layers of different MIXERS (olmo_hybrid), one entry a layer:
+    # "linear_attention" (a gated delta rule: no pages, a recurrent state
+    # a slot) or "full_attention" (pages); () = every layer attends. The
+    # pattern is whole periods that end in a full layer (`layer_period`).
+    # A linear layer has linear_num_heads heads with keys of
+    # linear_key_head_dim and values of linear_value_head_dim behind a
+    # depthwise causal convolution of linear_conv_kernel taps;
+    # linear_allow_neg_eigval doubles beta to (0, 2). rope_theta 0 = no
+    # rotary embedding anywhere
+    layer_types: tuple[str, ...] = ()
+    linear_num_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 0
+    linear_allow_neg_eigval: bool = False
     # attention variants
     attn_logit_softcap: float = 0.0
     sliding_window: int = 0          # 0 → full attention
@@ -117,7 +132,7 @@ class ModelConfig:
     def __post_init__(self):
         # a depth cut keeps the first layers: a layout longer than the
         # depth is cut to it, so that two configs of one depth compare equal
-        for name in ("window_layout", "rope_layout"):
+        for name in ("window_layout", "rope_layout", "layer_types"):
             layout = tuple(getattr(self, name))
             if layout and len(layout) < self.num_layers:
                 raise ValueError(
@@ -136,8 +151,20 @@ class ModelConfig:
     @property
     def cache_heads(self) -> int:
         """Heads of a cache row as the paged pool stores it: one for a
-        latent cache, whatever num_kv_heads says of the published model."""
-        return 1 if self.kv_lora_rank else self.num_kv_heads
+        latent cache, whatever num_kv_heads says of the published model.
+        The olmo_hybrid family stores more than eight heads that eight
+        does not divide (30) as the next multiple (32, zero heads behind
+        the real ones: the kernels slice a page's head axis in sublane
+        tiles of eight, and the array is tiled to that in memory whatever
+        its shape says); it alone, because it alone pads its q, k and v
+        to match (`models/olmo_hybrid.py` `_pool_heads`): another
+        family's writes are of num_kv_heads and need a pool of as many."""
+        if self.kv_lora_rank:
+            return 1
+        kvh = self.num_kv_heads
+        if self.family == "olmo_hybrid" and kvh > 8:
+            return -(-kvh // 8) * 8
+        return kvh
 
     @property
     def cache_dim(self) -> int:
@@ -152,6 +179,46 @@ class ModelConfig:
         """Values one token's row of one layer holds in the pool."""
         return self.cache_heads * self.cache_dim * (1 if self.kv_lora_rank
                                                     else 2)
+
+    @property
+    def cache_kinds(self) -> tuple[str, ...]:
+        """What a slot holds of its past: "kv" (K and V rows in pages),
+        "latent" (one latent row a token in pages), "state" (a recurrent
+        state a slot beside the pages)."""
+        rows = "latent" if self.kv_lora_rank else "kv"
+        return (rows, "state") if self.linear_layers else (rows,)
+
+    @property
+    def linear_layers(self) -> int:
+        """Layers whose mixer keeps a recurrent state, not pages."""
+        return sum(t == "linear_attention" for t in self.layer_types)
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers that own pages: the page pool's leading axis."""
+        return self.num_layers - self.linear_layers
+
+    @property
+    def layer_period(self) -> int:
+        """Length of the mixers' repeating pattern: linear layers then one
+        full layer (1 = every layer attends). Anything else is refused."""
+        if not self.layer_types:
+            return 1
+        p = self.layer_types.index("full_attention") + 1 if (
+            "full_attention" in self.layer_types) else 0
+        one = ("linear_attention",) * (p - 1) + ("full_attention",)
+        if not p or self.num_layers % p or (
+                self.layer_types != one * (self.num_layers // p)):
+            raise ValueError(
+                f"{self.name}: layer_types is not whole periods of linear "
+                f"layers ending in a full one: {self.layer_types}")
+        return p
+
+    @property
+    def conv_channels(self) -> int:
+        """Channels the linear layers' convolution runs over: q, k, v."""
+        return self.linear_num_heads * (
+            2 * self.linear_key_head_dim + self.linear_value_head_dim)
 
     @property
     def layer_windows(self) -> tuple[int, ...]:
@@ -174,7 +241,7 @@ class ModelConfig:
             max_position_embeddings=self.max_seq_len,
             attention_bias=False,
         )
-        if self.family in ("smallthinker", "deepseek_v2"):
+        if self.family in ("smallthinker", "deepseek_v2", "olmo_hybrid"):
             raise NotImplementedError(
                 f"{self.family} has no transformers twin here: its "
                 "reference is under benchmark/reference/")
@@ -428,6 +495,19 @@ register(ModelConfig(
     first_k_dense=1, kv_lora_rank=512, qk_nope_head_dim=128,
     qk_rope_head_dim=64, v_head_dim=128,
 ))
+# Olmo-Hybrid-7B (allenai, config.json): gated delta-rule layers 3:1 with
+# full attention (30 heads of 128, a query group of one, QK-norm over the
+# whole width, no rotary embedding: rope_theta null), 30 linear heads with
+# keys of 96 and values of 192 behind a convolution of 4
+_OLMO_HYBRID_PERIOD = ("linear_attention",) * 3 + ("full_attention",)
+register(ModelConfig(
+    name="olmo-hybrid:7b", family="olmo_hybrid", vocab_size=100_352,
+    hidden_size=3840, intermediate_size=11_008, num_layers=32, num_heads=30,
+    num_kv_heads=30, head_dim=128, rope_theta=0.0, rms_eps=1e-6,
+    max_seq_len=65_536, layer_types=_OLMO_HYBRID_PERIOD * 8,
+    linear_num_heads=30, linear_key_head_dim=96, linear_value_head_dim=192,
+    linear_conv_kernel=4, linear_allow_neg_eigval=True,
+))
 register(ModelConfig(
     name="all-minilm", family="bert_embed", vocab_size=30_522,
     hidden_size=384, intermediate_size=1536, num_layers=6, num_heads=12,
@@ -477,6 +557,17 @@ register(ModelConfig(
     moe_intermediate_size=32, num_shared_experts=1, norm_topk_prob=False,
     first_k_dense=1, kv_lora_rank=32, qk_nope_head_dim=16,
     qk_rope_head_dim=16, v_head_dim=16,
+))
+# olmo-hybrid's shape in small: two periods of three delta-rule layers and
+# a full one, a value head twice a key head, four heads so that their
+# values fill one lane tile (4 x 32 = 128)
+register(ModelConfig(
+    name="tiny-olmo-hybrid", family="olmo_hybrid", vocab_size=256,
+    hidden_size=64, intermediate_size=128, num_layers=8, num_heads=4,
+    num_kv_heads=4, head_dim=16, rope_theta=0.0, rms_eps=1e-6,
+    max_seq_len=256, layer_types=_OLMO_HYBRID_PERIOD * 2,
+    linear_num_heads=4, linear_key_head_dim=16, linear_value_head_dim=32,
+    linear_conv_kernel=4, linear_allow_neg_eigval=True,
 ))
 register(ModelConfig(
     name="tiny-qwen2", family="qwen2", vocab_size=256, hidden_size=64,
@@ -551,6 +642,7 @@ _HF_FAMILY = {
     "mixtral": "mixtral",
     "smallthinker": "smallthinker",
     "deepseek_v2": "deepseek_v2",
+    "olmo_hybrid": "olmo_hybrid",
     "bert": "bert_embed",
 }
 
@@ -627,6 +719,51 @@ def _deepseek_v2_from_hf(name: str, hf: dict, path: str) -> ModelConfig:
         qk_nope_head_dim=hf["qk_nope_head_dim"],
         qk_rope_head_dim=hf["qk_rope_head_dim"],
         v_head_dim=hf["v_head_dim"],
+    )
+
+
+def _olmo_hybrid_from_hf(name: str, hf: dict, path: str) -> ModelConfig:
+    """OlmoHybrid's published keys. Refused, not run wrong: value heads
+    that are not the key heads (a grouped delta rule), a bias, another
+    activation, a rotary embedding (rope_theta is null as published: the
+    recurrent layers carry position)."""
+    rope = (hf.get("rope_parameters") or {}).get("rope_theta")
+    unserved = {
+        "linear_num_value_heads": hf["linear_num_value_heads"]
+        != hf["linear_num_key_heads"],
+        "num_key_value_heads": hf.get(
+            "num_key_value_heads", hf["num_attention_heads"])
+        != hf["num_attention_heads"],
+        "attention_bias": bool(hf.get("attention_bias")),
+        "hidden_act": hf.get("hidden_act", "silu") != "silu",
+        "rope_theta": rope is not None or hf.get("rope_theta") is not None,
+    }
+    if any(unserved.values()):
+        raise ValueError(
+            f"{path}: olmo_hybrid with "
+            f"{[k for k, v in unserved.items() if v]} as published is not "
+            "served (as many value heads as key heads, a query group of "
+            "one, no bias, silu, no rotary embedding)")
+    return ModelConfig(
+        name=name, family="olmo_hybrid",
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_attention_heads"],
+        head_dim=hf.get("head_dim")
+        or hf["hidden_size"] // hf["num_attention_heads"],
+        rope_theta=0.0,
+        rms_eps=hf.get("rms_norm_eps", 1e-6),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_seq_len=hf.get("max_position_embeddings", 65_536),
+        layer_types=tuple(hf["layer_types"]),
+        linear_num_heads=hf["linear_num_key_heads"],
+        linear_key_head_dim=hf["linear_key_head_dim"],
+        linear_value_head_dim=hf["linear_value_head_dim"],
+        linear_conv_kernel=hf["linear_conv_kernel_dim"],
+        linear_allow_neg_eigval=bool(hf.get("linear_allow_neg_eigval")),
     )
 
 
@@ -708,6 +845,8 @@ def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
         )
     if family == "deepseek_v2":
         return _deepseek_v2_from_hf(name, hf, path)
+    if family == "olmo_hybrid":
+        return _olmo_hybrid_from_hf(name, hf, path)
     scaling = None
     rs = hf.get("rope_scaling") or None
     if rs and rs.get("rope_type", rs.get("type")) == "llama3":

@@ -53,6 +53,13 @@ _NEG_INF = -1e30
 # time). Buckets past this route to flash_prefill_streamed, which DMAs
 # K/V from HBM block-by-block instead of pinning them.
 _FLASH_KV_VMEM_CAP = 8 * 1024 * 1024
+# The ragged kernel keeps a chunk's fresh K and V resident too, double-
+# buffered under a limit it computes from its own buffers
+# (pallas_kernels._ragged_vmem_limit, at most 96 MiB of a v5e core's 128):
+# 1,024 rows of 32 KV heads of 128 (16 MiB; a model with as many KV heads
+# as query heads) compile for the chip (tests/test_olmo_hybrid.py). A
+# chunk past this takes the jnp path, and the tripwire says so.
+_RAGGED_CHUNK_KV_CAP = 16 * 1024 * 1024
 
 
 def _lane_pad_qkv(q, k_cur, v_cur, dpool):
@@ -510,7 +517,7 @@ def ragged_paged_attention(
         chunk_ok = (
             c % min(128, c) == 0
             and 2 * c * kvh_local * d * q_chunk.dtype.itemsize
-            <= _FLASH_KV_VMEM_CAP
+            <= _RAGGED_CHUNK_KV_CAP
         )
     quant = isinstance(k_pages, QuantPages)
     if latent_dv and (quant or mode == "wrap"):

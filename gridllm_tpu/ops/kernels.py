@@ -91,6 +91,27 @@ KERNELS: tuple[KernelSpec, ...] = (
         description="in-place whole-page KV pool write for one slot's "
                     "prefill chunk, all layers",
     ),
+    KernelSpec(
+        name="gdn_chunk",
+        reference="linear_attn:gdn_recurrent",
+        dispatch="gdn_chunk",
+        rtol=2e-3, atol=2e-3,
+        test="tests/test_olmo_hybrid.py::test_gdn_chunk_matches_recurrent",
+        description="the gated delta rule over one slot's prompt rows in "
+                    "blocks of 64 from a carried state (VMEM-resident a "
+                    "head pack), the state at chosen blocks' ends handed "
+                    "back for the prefix cache's snapshots",
+    ),
+    KernelSpec(
+        name="gdn_step",
+        reference="linear_attn:gdn_recurrent",
+        dispatch="gdn_step",
+        rtol=2e-3, atol=2e-3,
+        test="tests/test_olmo_hybrid.py::test_gdn_step_matches_recurrent",
+        description="the gated delta rule over a launch's 1 to K+1 rows of "
+                    "every slot: the last launch's accepted rows committed "
+                    "into the state in place, the new rows run on top",
+    ),
 )
 
 # Dispatch labels with NO kernel of their own: dispatchers whose kernel

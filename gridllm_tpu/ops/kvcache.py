@@ -11,6 +11,10 @@ Layout (per model):
   its RoPE key, and v is None — same pages, tables, allocator and keys)
   page_table: [max_slots, max_pages_per_slot] int32 page ids (-1 = unmapped)
   lengths: [max_slots] int32 tokens stored per slot
+  rec: a second kind of cache beside the pages (RecurrentState: a hybrid
+  family's linear-attention layers keep one state a slot, not a row a
+  token; the pool's leading axis is then the layers that DO own pages,
+  fewer than the model's)
 
 Page *allocation* is host-side Python (engine/scheduling concern, cheap,
 O(pages)); device ops only read/scatter through the tables. Page 0 is a real,
@@ -25,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
+import math
 from collections import OrderedDict
 from functools import partial
 from typing import Any
@@ -83,6 +88,33 @@ _PREFIX_COW = default_registry().counter(
     "Cached tail pages privately rebuilt because the request writes into "
     "them (copy-on-write of the partial tail page), by model.",
     ("model",),
+)
+
+
+# A hybrid family's recurrent state beside the pages (PR 42). A prefix found
+# in the page cache can be admitted only from a boundary whose STATE is
+# held too (a snapshot): `hit` = restored at the end of the page match,
+# `short` = at an earlier boundary, `miss` = pages matched and no snapshot
+# (cold). replay tokens = prompt tokens run through the model again
+# although their pages were found.
+_STATE_PREFIX = default_registry().counter(
+    "gridllm_state_prefix_total",
+    "Admissions whose prompt matched cached pages, by where the recurrent "
+    "state could be restored: hit (at the match), short (an earlier "
+    "boundary), miss (no snapshot: cold).",
+    ("model", "outcome"),
+)
+_STATE_REPLAY = default_registry().counter(
+    "gridllm_state_replay_tokens_total",
+    "Prompt tokens run through the model again although their pages were "
+    "cached, for want of a state snapshot at the match, by model.",
+    ("model",),
+)
+_STATE_SNAPSHOTS = default_registry().counter(
+    "gridllm_state_snapshots_total",
+    "Recurrent-state snapshots saved by a chunk launch, restored into a "
+    "slot at admission, evicted from the snapshot pool.",
+    ("model", "event"),
 )
 
 
@@ -255,9 +287,91 @@ def _wrap_write_kernel(mesh, ax, kernel, scalar_specs):
     )
 
 
+def _nbytes(a) -> int:
+    """An array's bytes, or those of its shape alone (jax.eval_shape)."""
+    return math.prod(a.shape) * a.dtype.itemsize
+
+
 @partial(
     jax.tree_util.register_dataclass,
-    data_fields=["k", "v", "page_table", "lengths"],
+    data_fields=["state", "conv", "pend_x", "pend_k", "pend_v", "pend_b",
+                 "pend_g", "pend_n", "snap_state", "snap_conv"],
+    meta_fields=[],
+)
+@dataclasses.dataclass
+class RecurrentState:
+    """What a slot holds of its past in the linear-attention layers (a
+    gated delta rule, ops/linear_attn.py): the state and the
+    convolution's last rows. Both LAG: the last step launch's rows are
+    `pend_*`, of which `pend_n` a slot count (a decode step's one row, or
+    as many of a verify launch's K+1 as speculation accepted), and the
+    next launch commits them before its own. So speculation's commit
+    moves no state: it sets `pend_n`. A chunk launch (a prompt) writes its
+    slot's state outright and leaves it nothing pending.
+
+    `snap_*` is the prefix cache's pool of snapshots: the state at a page
+    boundary of some prompt, found by that page's chain key
+    (PageAllocator) and copied into a slot that is admitted from the pages
+    up to there."""
+
+    state: jnp.ndarray       # [Ll, S, dk, H*dv] float32 (heads packed)
+    # rows of C channels side by side on the minor axis (a [.., 3, C]
+    # array is stored at 16 rows, five times the bytes)
+    conv: jnp.ndarray        # [Ll, S, (K-1)*C]: rows before the next one
+    pend_x: jnp.ndarray      # [Ll, S, T*C] the rows before the convolution
+    pend_k: jnp.ndarray      # [Ll, S, T, H, dk] float32, normalised
+    pend_v: jnp.ndarray      # [Ll, S, T, H, dv] float32
+    pend_b: jnp.ndarray      # [Ll, S, T, H] float32 beta
+    pend_g: jnp.ndarray      # [Ll, S, T, H] float32 log decay
+    pend_n: jnp.ndarray      # [S] int32 pending rows that count
+    snap_state: jnp.ndarray  # [Ll, N, dk, H*dv]
+    snap_conv: jnp.ndarray   # [Ll, N, (K-1)*C]
+
+    @staticmethod
+    def create(layers: int, slots: int, heads: int, dk: int, dv: int,
+               conv_kernel: int, step_rows: int, snapshots: int,
+               dtype=jnp.bfloat16) -> "RecurrentState":
+        c = heads * (2 * dk + dv)
+        f32 = jnp.float32
+        return RecurrentState(
+            state=jnp.zeros((layers, slots, dk, heads * dv), f32),
+            conv=jnp.zeros((layers, slots, (conv_kernel - 1) * c), dtype),
+            pend_x=jnp.zeros((layers, slots, step_rows * c), dtype),
+            pend_k=jnp.zeros((layers, slots, step_rows, heads, dk), f32),
+            pend_v=jnp.zeros((layers, slots, step_rows, heads, dv), f32),
+            pend_b=jnp.zeros((layers, slots, step_rows, heads), f32),
+            pend_g=jnp.zeros((layers, slots, step_rows, heads), f32),
+            pend_n=jnp.zeros((slots,), jnp.int32),
+            snap_state=jnp.zeros((layers, snapshots, dk, heads * dv), f32),
+            snap_conv=jnp.zeros((layers, snapshots, (conv_kernel - 1) * c),
+                                dtype),
+        )
+
+    @property
+    def step_rows(self) -> int:
+        return self.pend_k.shape[2]
+
+    @property
+    def slot_nbytes(self) -> int:
+        """Bytes every slot's state takes: all but the snapshot pool."""
+        return sum(map(_nbytes, jax.tree.leaves(self))) - self.snap_nbytes
+
+    @property
+    def snap_nbytes(self) -> int:
+        return _nbytes(self.snap_state) + _nbytes(self.snap_conv)
+
+    def restore(self, slot, snap) -> "RecurrentState":
+        """Snapshot `snap` copied into `slot`, nothing pending."""
+        return dataclasses.replace(
+            self,
+            state=self.state.at[:, slot].set(self.snap_state[:, snap]),
+            conv=self.conv.at[:, slot].set(self.snap_conv[:, snap]),
+            pend_n=self.pend_n.at[slot].set(0))
+
+
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["k", "v", "page_table", "lengths", "rec"],
     meta_fields=["page_size"],
 )
 @dataclasses.dataclass
@@ -267,6 +381,7 @@ class PagedKVCache:
     page_table: jnp.ndarray  # [S, max_pages] int32
     lengths: jnp.ndarray     # [S] int32
     page_size: int = 128
+    rec: RecurrentState | None = None   # a hybrid family's second cache
 
     @staticmethod
     def create(
@@ -294,6 +409,8 @@ class PagedKVCache:
 
     @property
     def num_layers(self) -> int:
+        """Layers that own pages: fewer than the model's where `rec`
+        holds the others' state."""
         return self.k.shape[0]
 
     @property
@@ -680,10 +797,7 @@ def rollback_to_length(cache: PagedKVCache,
       only from the final HOST-visible context (engine._finish), which
       never includes rolled-back tokens.
     """
-    return PagedKVCache(
-        k=cache.k, v=cache.v, page_table=cache.page_table,
-        lengths=new_lengths, page_size=cache.page_size,
-    )
+    return dataclasses.replace(cache, lengths=new_lengths)
 
 
 def commit_tree_path(cache: PagedKVCache,
@@ -756,10 +870,7 @@ def commit_tree_path(cache: PagedKVCache,
         return pages.at[:, dst_page, dst_off].set(
             pages[:, src_page, src_off], mode="drop")
 
-    return PagedKVCache(
-        k=move(cache.k), v=move(cache.v), page_table=cache.page_table,
-        lengths=cache.lengths, page_size=cache.page_size,
-    )
+    return dataclasses.replace(cache, k=move(cache.k), v=move(cache.v))
 
 
 def write_prefill_all(
@@ -903,7 +1014,17 @@ class PageAllocator:
 
     def __init__(self, num_pages: int, page_size: int,
                  max_pages_per_slot: int, cache_pages: int = 0,
-                 model: str = ""):
+                 model: str = "", snapshots: int = 0):
+        # a hybrid family's snapshot pool (RecurrentState.snap_*): entry
+        # index by the chain key of the page that ENDS at the snapshot's
+        # boundary, least recently saved or restored first. > 0 also caps
+        # match_prefix at the deepest matched boundary that has one
+        self.snapshots = snapshots
+        self._snap_by_key: OrderedDict[bytes, int] = OrderedDict()
+        self._snap_free: list[int] = list(range(snapshots - 1, -1, -1))
+        # slot -> (page-match tokens, restore tokens, snapshot entry or -1),
+        # staged by match_prefix, read by the engine, counted by alloc()
+        self._staged_state: dict[int, tuple[int, int, int]] = {}
         self.page_size = page_size
         self.max_pages_per_slot = max_pages_per_slot
         self.cache_pages = cache_pages
@@ -1000,6 +1121,50 @@ class PageAllocator:
         key = self._key_of.pop(page, None)
         if key is not None and self._page_by_key.get(key) == page:
             del self._page_by_key[key]
+            # one eviction order: a snapshot goes with the page it ends on
+            self._drop_snapshot(key)
+
+    def _drop_snapshot(self, key: bytes) -> None:
+        entry = self._snap_by_key.pop(key, None)
+        if entry is not None:
+            self._snap_free.append(entry)
+            _STATE_SNAPSHOTS.inc(model=self.model, event="evicted")
+
+    @property
+    def snapshots_used(self) -> int:
+        return len(self._snap_by_key)
+
+    def state_match(self, slot: int) -> tuple[int, int, int]:
+        """(page-match tokens, restore tokens, snapshot entry or -1) of
+        the slot's last match_prefix; zeros for a family with no state."""
+        return self._staged_state.get(slot, (0, 0, -1))
+
+    def snapshot_entries(self, keys: list[bytes]) -> list[int]:
+        """A snapshot entry for each boundary's chain key a chunk launch is
+        about to save the state at: -1 where the key already has one (it
+        counts as used now), else a free entry or the least recently used
+        one's. Registered here, at dispatch: the device runs launches in
+        order, so a later admission that restores it reads what this one
+        wrote, and pages of an unfinished request match nothing yet."""
+        out: list[int] = []
+        for key in keys:
+            if key in self._snap_by_key:
+                self._snap_by_key.move_to_end(key)
+                out.append(-1)
+                continue
+            if not self._snap_free:
+                fresh = set(keys)
+                old = next((k for k in self._snap_by_key if k not in fresh),
+                           None)
+                if old is None:
+                    out.append(-1)
+                    continue
+                self._drop_snapshot(old)
+            entry = self._snap_free.pop()
+            self._snap_by_key[key] = entry
+            _STATE_SNAPSHOTS.inc(model=self.model, event="saved")
+            out.append(entry)
+        return out
 
     def match_prefix(self, slot: int, token_ids: list[int]) -> int:
         """Pin the longest cached prefix of `token_ids` to a FRESH slot.
@@ -1021,8 +1186,10 @@ class PageAllocator:
         key = b""
         matched = 0
         cow = False
+        keys: list[bytes] = []
         for i in range(max_full):
             key = _page_chain_key(key, token_ids[i * ps:(i + 1) * ps])
+            keys.append(key)
             page = self._page_by_key.get(key)
             if page is None and self.restore_source is not None:
                 # tiered KV cache (ISSUE 11): the chain misses in HBM but
@@ -1053,6 +1220,22 @@ class PageAllocator:
                 tail_key = _page_chain_key(
                     key, token_ids[max_full * ps:(max_full + 1) * ps])
                 cow = tail_key in self._page_by_key
+        if self.snapshots:
+            # the state must be restored where the pages end: keep the
+            # pages up to the deepest matched boundary that has a
+            # snapshot, give the rest back (they are computed again, into
+            # pages of the slot's own)
+            keep = next((i for i in range(matched, 0, -1)
+                         if keys[i - 1] in self._snap_by_key), 0)
+            entry = -1
+            if keep:
+                self._snap_by_key.move_to_end(keys[keep - 1])
+                entry = self._snap_by_key[keys[keep - 1]]
+            for page in owned[keep:]:
+                self._release_page(page)
+            del owned[keep:]
+            self._staged_state[slot] = (matched * ps, keep * ps, entry)
+            matched, cow = keep, False
         # stage the accounting; the successful alloc() commits it (an
         # admission that bounces off an exhausted pool retries this whole
         # sequence and must not re-count the same prompt)
@@ -1065,6 +1248,14 @@ class PageAllocator:
         if staged is None:
             return
         matched, prompt_pages, cow = staged
+        found, kept, entry = self._staged_state.get(slot, (0, 0, -1))
+        if found:
+            _STATE_PREFIX.inc(model=self.model, outcome=(
+                "hit" if kept == found else "short" if kept else "miss"))
+            if found - kept:
+                _STATE_REPLAY.inc(found - kept, model=self.model)
+            if entry >= 0:
+                _STATE_SNAPSHOTS.inc(model=self.model, event="restored")
         self.hits += matched
         self.misses += prompt_pages - matched
         if matched:
@@ -1110,6 +1301,7 @@ class PageAllocator:
         registered page parks in the reuse LRU, an unregistered one returns
         to the free list."""
         self._staged_stats.pop(slot, None)  # uncommitted match: retry path
+        self._staged_state.pop(slot, None)
         owned = self._owned.pop(slot, [])
         if token_ids is not None and self.cache_pages != 0:
             n_full = min(len(token_ids) // self.page_size, len(owned))

@@ -1159,3 +1159,205 @@ def paged_write_chunk(
     if v_pages is None:
         return out[0].reshape(k_pages.shape), None
     return out[0], out[1]
+
+
+# ---------------------------------------------------------------------------
+# gated delta rule (ops/linear_attn.py holds the mathematics and the oracle)
+# ---------------------------------------------------------------------------
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _gdn_block(s, wv, wk, aqk, qg, kd, gc, pack: int, dv: int):
+    """One block of rows on the state of `pack` heads side by side.
+    s [dk, pack*dv] float32; wv [C, pack*dv] and gc [1, pack*dv] packed as
+    the state is; wk, qg, kd [pack, C, dk] and aqk [pack, C, C] a head.
+    Returns (o [C, pack*dv], the state after): U = wv - wk S,
+    O = qg S + aqk U, S' = gc S + kd^T U, each head's product taken at the
+    packed width and kept on its own lanes (no slice at a lane offset
+    that is not a tile's)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, pack * dv), 1)
+
+    def per_head(f):
+        out = None
+        for p in range(pack):
+            own = (lane >= p * dv) & (lane < (p + 1) * dv)
+            term = jnp.where(own, f(p), 0.0)
+            out = term if out is None else out + term
+        return out
+
+    def dot(a, b):
+        return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                       precision=_HI)
+
+    u = wv - per_head(lambda p: dot(wk[p], s))
+    o = per_head(lambda p: dot(qg[p], s) + dot(aqk[p], u))
+    s = s * gc + per_head(lambda p: jax.lax.dot_general(
+        kd[p], u, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_HI))
+    return o, s
+
+
+def _gdn_chunk_kernel(keep_ref, s0_ref, wv_ref, wk_ref, aqk_ref, qg_ref,
+                      kd_ref, gc_ref, o_ref, s_ref, kept_ref, acc, *,
+                      pack: int, dv: int, n_keep: int):
+    i = pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _():
+        acc[...] = s0_ref[...]
+        kept_ref[...] = jnp.zeros_like(kept_ref)
+
+    o, s = _gdn_block(acc[...], wv_ref[...], wk_ref[...], aqk_ref[...],
+                      qg_ref[...], kd_ref[...], gc_ref[...], pack, dv)
+    o_ref[...] = o
+    acc[...] = s
+    for j in range(n_keep):
+        @pl.when(keep_ref[j] == i)
+        def _(j=j):
+            kept_ref[j] = s
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _():
+        s_ref[...] = s
+
+
+def gdn_chunk(state, wy, keep, *, heads: int, interpret: bool = False):
+    """The blocks of ONE slot's rows chained through its state.
+    state [dk, H*dv] float32 (packed); wy: ops.linear_attn._lanes(_wy(..))
+    with leading [nb]; keep [n] int32 block indices whose end state is
+    handed back (-1: zeros). Returns (o [nb, C, H*dv], the state after,
+    kept [n, dk, H*dv]). Grid (head packs, blocks): the state of a pack
+    stays in VMEM across its blocks."""
+    from gridllm_tpu.ops.linear_attn import head_pack
+
+    dk, hd = state.shape
+    dv = hd // heads
+    pack = head_pack(dv, heads)
+    nb, c = wy["wv"].shape[:2]
+    n_keep = keep.shape[0]
+    lanes = pack * dv
+
+    def per_head(width):
+        return pl.BlockSpec((None, pack, c, width),
+                            lambda h, i, *_: (i, h, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(heads // pack, nb),
+        in_specs=[
+            pl.BlockSpec((dk, lanes), lambda h, i, *_: (0, h)),
+            pl.BlockSpec((None, c, lanes), lambda h, i, *_: (i, 0, h)),
+            per_head(dk), per_head(c), per_head(dk), per_head(dk),
+            pl.BlockSpec((None, 1, lanes), lambda h, i, *_: (i, 0, h)),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, c, lanes), lambda h, i, *_: (i, 0, h)),
+            pl.BlockSpec((dk, lanes), lambda h, i, *_: (0, h)),
+            pl.BlockSpec((n_keep, dk, lanes), lambda h, i, *_: (0, 0, h)),
+        ],
+        scratch_shapes=[pltpu.VMEM((dk, lanes), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_gdn_chunk_kernel, pack=pack, dv=dv, n_keep=n_keep),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((nb, c, hd), jnp.float32),
+            jax.ShapeDtypeStruct((dk, hd), jnp.float32),
+            jax.ShapeDtypeStruct((n_keep, dk, hd), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="gdn_chunk",
+    )(keep.astype(jnp.int32), state, wy["wv"], wy["wk"], wy["aqk"],
+      wy["qg"], wy["kd"], wy["gc"])
+
+
+def _gdn_step_kernel(layer_ref, order_ref, live_ref, s_in, wv_ref, wk_ref,
+                     aqk_ref, qg_ref, kd_ref, gc_ref, s_out, o_ref, *,
+                     pack: int, dv: int):
+    del layer_ref, order_ref         # read by the index maps
+    n_live = live_ref[0]
+
+    @pl.when(pl.program_id(1) < n_live)
+    def _():
+        # block 0: the last launch's rows that were kept, committed and
+        # written back; block 1: this launch's rows, run on top, not written
+        _, s = _gdn_block(s_in[...], wv_ref[0], wk_ref[0], aqk_ref[0],
+                          qg_ref[0], kd_ref[0], gc_ref[0], pack, dv)
+        s_out[...] = s
+        o, _ = _gdn_block(s, wv_ref[1], wk_ref[1], aqk_ref[1], qg_ref[1],
+                          kd_ref[1], gc_ref[1], pack, dv)
+        o_ref[...] = o
+
+    @pl.when(n_live == 0)
+    def _():
+        # nothing is live: every step visits the first slot's block, which
+        # goes back as it came
+        s_out[...] = s_in[...]
+
+
+def gdn_step(states, layer, order, n_live, wy, *, heads: int,
+             interpret: bool = False):
+    """Every LIVE slot's pending rows committed and its new rows run.
+    states [Ll, S, dk, H*dv] float32 (every linear layer; `layer` picks,
+    updated IN PLACE: input_output_aliases); order [S] int32 the slots,
+    live ones first, n_live of them; wy: _lanes(_wy(..)) with leading
+    [S, 2] (pending block, new block), rows padded to whole sublane tiles.
+    Returns (states, o [S, C, H*dv] of the new block; junk for a slot
+    that is not live). Grid (head packs, slots in `order`): one read and
+    one write of each live slot's state; a step past the live ones names
+    the last live slot's blocks again, which moves nothing."""
+    from gridllm_tpu.ops.linear_attn import head_pack
+
+    _, slots, dk, hd = states.shape
+    dv = hd // heads
+    pack = head_pack(dv, heads)
+    c = wy["wv"].shape[2]
+    lanes = pack * dv
+
+    def slot(g, order, live):
+        return order[jnp.minimum(g, jnp.maximum(live[0] - 1, 0))]
+
+    def per_head(width):
+        return pl.BlockSpec(
+            (None, 2, pack, c, width),
+            lambda h, g, li, order, live: (slot(g, order, live), 0, h, 0, 0))
+
+    def packed(rows):
+        return pl.BlockSpec(
+            (None, 2, rows, lanes),
+            lambda h, g, li, order, live: (slot(g, order, live), 0, 0, h))
+
+    state_spec = pl.BlockSpec(
+        (None, None, dk, lanes),
+        lambda h, g, li, order, live: (li[0], slot(g, order, live), 0, h))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(heads // pack, slots),
+        in_specs=[state_spec, packed(c), per_head(dk), per_head(c),
+                  per_head(dk), per_head(dk), packed(1)],
+        out_specs=[
+            state_spec,
+            pl.BlockSpec(
+                (None, c, lanes),
+                lambda h, g, li, order, live: (slot(g, order, live), 0, h)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_gdn_step_kernel, pack=pack, dv=dv),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(states.shape, jnp.float32),
+            jax.ShapeDtypeStruct((slots, c, hd), jnp.float32),
+        ],
+        # 0: layer, 1: order, 2: n_live, 3: states, 4..: the blocks' arrays
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="gdn_step",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), order.astype(jnp.int32),
+      jnp.asarray(n_live, jnp.int32).reshape(1), states, wy["wv"], wy["wk"],
+      wy["aqk"], wy["qg"], wy["kd"], wy["gc"])

@@ -1172,11 +1172,11 @@ def test_self_run_is_clean():
     assert len(RULES) == 13, sorted(RULES)
 
 
-def test_live_tree_has_five_kernels_and_no_attention_switch():
+def test_live_tree_has_seven_kernels_and_no_attention_switch():
     """The KERNELS registry names exactly the public functions of
-    ops/pallas_kernels.py that launch a pl.pallas_call — five, with one
-    paged-attention kernel among them — and no knob selects a second
-    paged-attention path."""
+    ops/pallas_kernels.py that launch a pl.pallas_call — seven since
+    PR 42 (the delta rule's two), with one paged-attention kernel among
+    them — and no knob selects a second paged-attention path."""
     import ast
 
     from gridllm_tpu.ops.kernels import dispatch_labels, kernel_names
@@ -1192,7 +1192,7 @@ def test_live_tree_has_five_kernels_and_no_attention_switch():
     }
     assert launchers == set(kernel_names()) == {
         "flash_prefill", "flash_prefill_streamed", "ragged_attention",
-        "paged_write_decode", "paged_write_chunk",
+        "paged_write_decode", "paged_write_chunk", "gdn_chunk", "gdn_step",
     }
     assert {lb for lb in dispatch_labels() if lb.startswith("attention_")} \
         == {"attention_prefill", "attention_ragged"}
