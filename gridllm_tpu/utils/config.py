@@ -206,7 +206,10 @@ register_env("GRIDLLM_MAX_BATCH_SLOTS", "8",
 register_env("GRIDLLM_KV_PAGE_SIZE", "128",
              "Tokens per KV-cache page.")
 register_env("GRIDLLM_STREAM_FLUSH_MS", "20",
-             "Token-frame batching window for streamed responses (ms).")
+             "Least time between two frames of one streamed response "
+             "(ms), and the most a token is held: the worker keeps up to "
+             "this much of a stream back, so that a stall reads that much "
+             "shorter.")
 register_env("GRIDLLM_PREFILL_BUCKETS", "512,1024,2048,4096,8192",
              "Comma-separated prefill padding buckets (tokens); prompts "
              "compile per bucket, not per length.")
@@ -679,7 +682,7 @@ class EngineConfig(BaseModel):
     max_batch_slots: int = 8           # continuous-batching slot count
     prefill_buckets: str = "512,1024,2048,4096,8192"
     kv_page_size: int = 128
-    stream_flush_ms: int = 20          # token-frame batching window
+    stream_flush_ms: int = 20          # least ms between a stream's frames; most a token is held
     # mesh axes (parallel/mesh.py): e.g. "tp:8", "pp:2,tp:4", "dp:2,tp:4";
     # "" → single device
     mesh_shape: str = ""

@@ -108,6 +108,73 @@ _WASTED_TOKENS = default_registry().counter(
     "mid-decode, by reason.",
     ("reason",),
 )
+# When a stream's frames leave (FramePacer), and what the pacing costs a
+# token: the time from the runner's on_chunk to the hand-over to the bus.
+_STREAM_FRAMES = default_registry().counter(
+    "gridllm_worker_stream_frames_total",
+    "Stream frames handed to the bus, by why they left when they did: "
+    "immediate (as they arrived: a stream's first frame, or a burst "
+    "behind the stream's pace), deadline (held until the pacer's time), "
+    "final (a stream's tail, sent with its result).",
+    ("reason",),
+)
+_STREAM_HOLD = default_registry().histogram(
+    "gridllm_worker_stream_hold_seconds",
+    "Time a stream frame's oldest token waited in the worker: from the "
+    "engine's on_chunk (runner thread) to the frame's hand-over to the "
+    "bus. One observation a frame.",
+    buckets=(0.00025, 0.0005, 0.001, 0.002, 0.003, 0.005, 0.0075, 0.01,
+             0.015, 0.02, 0.025, 0.03, 0.04, 0.05),
+)
+
+
+def _frame_left(reason: str, hold_s: float) -> None:
+    _STREAM_FRAMES.inc(reason=reason)
+    _STREAM_HOLD.observe(hold_s)
+
+
+class FramePacer:
+    """When a stream's next frame leaves: a jitter buffer one window deep.
+
+    Two frames are never handed over less than `window_s` apart and no
+    delta is held longer than `window_s`. Between those limits frames
+    leave at the stream's own pace: the smoothed time between its
+    launches' bursts (an eighth of each new gap, as TCP smooths its round
+    trip) and an eighth of the window more, so that a frame is held a
+    little longer than the one before until the hold is one window. A
+    burst that comes later than that pace (a long launch, a stall behind
+    another request's chunk) leaves on arrival, and the gap the client
+    sees is the stall less what had been held. Why hold at all: sent as
+    they come, frames show every stall whole, and where stalls are one
+    gap in twenty the client's 95th-percentile gap is a stall (PERF.md,
+    PR 47)."""
+
+    def __init__(self, window_s: float):
+        self.window_s = window_s
+        self.t_frame = float("-inf")   # the last hand-over to the bus
+        self.t_burst = float("-inf")   # stamp of the last burst's first delta
+        self.pace_s: float | None = None
+
+    def arrived(self, t: float) -> None:
+        """A delta the engine stamped `t` (on_chunk, the runner's thread)."""
+        gap = t - self.t_burst
+        if gap < self.window_s / 8:
+            return  # the same launch's next accepted draft
+        if self.t_burst != float("-inf"):
+            self.pace_s = gap if self.pace_s is None else (
+                self.pace_s + (gap - self.pace_s) / 8)
+        self.t_burst = t
+
+    def due(self, t_oldest: float) -> float:
+        """When the frame whose oldest delta was stamped `t_oldest` leaves."""
+        step = self.window_s
+        if self.pace_s is not None:
+            step = max(step, self.pace_s + self.window_s / 8)
+        return min(t_oldest + self.window_s,
+                   max(t_oldest, self.t_frame + step))
+
+    def mark(self, now: float) -> None:
+        self.t_frame = now
 
 
 class _DrainSuspend(Exception):
@@ -1376,7 +1443,10 @@ class WorkerService(EventEmitter):
         q: asyncio.Queue = asyncio.Queue()
 
         def on_chunk(delta: str, done: bool, res: GenerationResult | None):
-            loop.call_soon_threadsafe(q.put_nowait, (delta, done, res))
+            # stamped on the runner thread: the frame's hold time counts
+            # the wake-up of this loop too
+            loop.call_soon_threadsafe(
+                q.put_nowait, (delta, done, res, loop.time()))
 
         gen = self._make_gen(engine, req, prompt, raw, on_chunk)
         # format / tools / think outputs are post-processed from the FULL
@@ -1410,16 +1480,39 @@ class WorkerService(EventEmitter):
         # response text: a resumed attempt starts where its emission
         # does, so the gateway can trim overlap exactly (ISSUE 9)
         abs_off = gen.resume_sent if gen.resume_ids else 0
-        last_flush = time.monotonic()
+        pacer = FramePacer(self.stream_flush_s)
+        t_oldest: float | None = None  # on_chunk stamp of buf's first delta
+        held = False                   # buf has waited for its time to leave
+
+        async def flush(reason: str) -> None:
+            nonlocal buf, abs_off, t_oldest, held
+            now = loop.time()
+            # stamped BEFORE the publish is awaited: the window is between
+            # hand-overs, whatever the bus's round trip
+            pacer.mark(now)
+            _frame_left(reason, now - t_oldest)
+            text, off = buf, abs_off
+            abs_off += len(buf)
+            buf, t_oldest, held = "", None, False
+            await self._flush_stream(req, text, eval_count, off)
+
         while True:
-            timeout = self.stream_flush_s if (streaming and buf) else None
-            try:
-                delta, done, res = await asyncio.wait_for(q.get(), timeout)
-            except asyncio.TimeoutError:
-                await self._flush_stream(req, buf, eval_count, abs_off)
-                abs_off += len(buf)
-                buf, last_flush = "", time.monotonic()
-                continue
+            if streaming and buf and q.empty():
+                # everything the engine has queued is in buf (a launch's
+                # accepted drafts are one burst, one frame): it leaves when
+                # the pacer says, and what arrives until then leaves with it
+                wait = pacer.due(t_oldest) - loop.time()
+                if wait <= 0:
+                    await flush("deadline" if held else "immediate")
+                    continue
+                held = True
+                try:
+                    item = await asyncio.wait_for(q.get(), wait)
+                except asyncio.TimeoutError:
+                    continue
+            else:
+                item = await q.get()
+            delta, done, res, t_put = item
             if delta and t_first is None:
                 # only a frame actually carrying a token counts — a bare
                 # done frame (cancel/error/immediate EOS) must not leave a
@@ -1428,6 +1521,10 @@ class WorkerService(EventEmitter):
                 self.tracer.event(req.id, "worker.first_token",
                                   sinceSubmitMs=round(
                                       (t_first - t_submit) * 1000, 3))
+            if delta:
+                pacer.arrived(t_put)
+                if t_oldest is None:
+                    t_oldest = t_put
             buf += delta
             if done:
                 assert res is not None
@@ -1476,6 +1573,8 @@ class WorkerService(EventEmitter):
                     if not res.retryable:
                         raise NonRetryableJobError(msg)
                     raise RuntimeError(msg)
+                if streaming and buf:
+                    _frame_left("final", loop.time() - t_oldest)
                 return await self._finalize_generation(
                     req, res, buf, is_chat, streaming,
                     fmt=fmt, tools=req.tools, think=think,
@@ -1489,12 +1588,6 @@ class WorkerService(EventEmitter):
                     snap_len = len(snap["tokens"])
                     await self._publish_snapshot(
                         req, snap["tokens"], gen.options.get("seed"))
-            if streaming and buf and (
-                time.monotonic() - last_flush >= self.stream_flush_s
-            ):
-                await self._flush_stream(req, buf, eval_count, abs_off)
-                abs_off += len(buf)
-                buf, last_flush = "", time.monotonic()
 
     async def _flush_stream(self, req, text: str, eval_count: int,
                             offset: int | None = None) -> None:
