@@ -99,6 +99,7 @@ from gridllm_tpu.ops.sampling import (
     sample_tokens,
     spec_accept,
     spec_accept_tree,
+    topk_stages,
     window_push,
     window_set_slot,
 )
@@ -206,6 +207,14 @@ _WINDOW_ROWS = _OBS.gauge(
     "they do hold: a window, a launch's rows and a page a slot) or table "
     "(what the same slots' pages of one table would hold).",
     ("model", "held"),
+)
+_SAMPLER_TOPK_STAGES = _OBS.gauge(
+    "gridllm_sampler_topk_stages",
+    "How many top_k the sampler's top-128 candidates take at this model's "
+    "vocabulary: 1 (one pass over the vocabulary) or 3 (the maxima of "
+    "128-wide blocks, the winning blocks' maxima by blocks of 16, the "
+    "2,048 values left; exact). Set where the programs are built.",
+    ("model",),
 )
 _KV_ROW_BYTES_EQUIV = _OBS.gauge(
     "gridllm_kv_row_bytes_per_head_equiv",
@@ -1543,6 +1552,9 @@ class InferenceEngine:
         ), armable=False)
         if self.embedding_only:
             return
+        # the form every sampler call site below lowers to, by the width
+        # of the logits' last axis alone (ops/sampling.py)
+        _SAMPLER_TOPK_STAGES.set(topk_stages(mc.vocab_size), model=mc.name)
 
         # sp > 1 → sequence-parallel prefill: ring attention splits the
         # prompt's T axis over the sp mesh axis (ops/ring_attention.py)
