@@ -89,8 +89,10 @@ def commit_check(args) -> None:
     row = jnp.asarray(alloc.table_row(0), jnp.int32)
     toks = np.random.default_rng(0).integers(0, cfg.vocab_size, n0 + 2 * k1)
     active = jnp.arange(slots) == 0
-    chunk = jax.jit(lambda p, c, t, s0, n: oh.prefill_chunk(
-        p, cfg, t, s0, n, c, jnp.int32(0), row))
+    # admitted as the engine admits: the mixed step with no active slot
+    idle = jnp.zeros((slots,), jnp.int32)
+    chunk = jax.jit(lambda p, c, t, s0, n: oh.mixed_step(
+        p, cfg, t, s0, n, jnp.int32(0), row, idle, c, idle > 0)[::2])
     verify = jax.jit(lambda p, c, t: oh.verify_step(p, cfg, t, c, active))
     decode = jax.jit(lambda p, c, t: oh.decode_step(p, cfg, t, c, active))
     for s0 in range(0, n0, width):

@@ -12,21 +12,17 @@
 #      CPU run);
 #   2. every case, or the ones named; a case that fails is reported and
 #      the rest still run, but a case that times out stops the script,
-#      because a kernel that hung may have left the chip unusable;
-#   3. with --then-bench, and only if every case passed, bench.py — whose
-#      exit code becomes this script's.
+#      because a kernel that hung may have left the chip unusable.
 #
-# Usage: deploy/tpu_kernel_bisect.sh [--then-bench] [logdir] [case ...]
+# Usage: deploy/tpu_kernel_bisect.sh [logdir] [case ...]
 # Through the chip tool (logs come back under chiprun_out/):
 #   chiprun -- bash deploy/tpu_kernel_bisect.sh
 # Exit codes: 0 every case passed; 2 no TPU; 3 a case failed or timed out
-# (see $logdir/bisect_<case>.log); otherwise bench.py's own.
+# (see $logdir/bisect_<case>.log).
 set -u
 cd "$(dirname "$0")/.."
 export PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}"
 
-THEN_BENCH=0
-[[ "${1:-}" == "--then-bench" ]] && { THEN_BENCH=1; shift; }
 LOGDIR="${1:-chiprun_out/bisect}"
 [[ $# -gt 0 ]] && shift
 mkdir -p "$LOGDIR"
@@ -79,12 +75,3 @@ if [[ -n "$FAILED" ]]; then
   exit 3
 fi
 say "all cases passed"
-
-if [[ $THEN_BENCH -eq 1 ]]; then
-  say "running bench ..."
-  timeout 2400 "$PY" bench.py > "$LOGDIR/bench.json" 2> "$LOGDIR/bench.err"
-  rc=$?
-  say "bench rc=$rc -> $LOGDIR/bench.json"
-  tail -1 "$LOGDIR/bench.json"
-  exit $rc
-fi

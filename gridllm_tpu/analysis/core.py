@@ -92,9 +92,9 @@ class SourceFile:
 
 
 class Repo:
-    """The analyzed tree: every .py file under the package, tests, deploy
-    scripts, and the top-level entry points, plus raw-text access to
-    non-python artifacts (dashboards, alerts, README)."""
+    """The analyzed tree: every .py file under the package, tests and
+    deploy scripts, plus raw-text access to non-python artifacts
+    (dashboards, alerts, README)."""
 
     def __init__(self, root: Path):
         self.root = Path(root).resolve()
@@ -105,10 +105,6 @@ class Repo:
                 for p in sorted(base.rglob("*.py")):
                     if not _SKIP_DIRS.intersection(p.parts):
                         self.files.append(SourceFile(self.root, p))
-        for name in ("bench.py",):
-            p = self.root / name
-            if p.is_file():
-                self.files.append(SourceFile(self.root, p))
         self._by_rel = {f.rel: f for f in self.files}
         # parse + parent-annotate every file ONCE, here in the loader —
         # the trees (and cached node lists) are shared by all rules;

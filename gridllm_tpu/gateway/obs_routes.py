@@ -3,7 +3,7 @@
 - ``GET /metrics``: Prometheus text exposition. Renders the scheduler's
   per-instance registry (gateway/scheduler/worker-liveness/SLO series) plus
   the process-global default registry (bus, and — in single-process
-  deployments like bench.py — engine/kernel series).
+  deployments — engine/kernel series).
 - ``GET /admin/trace/{request_id}``: the stitched gateway+worker span
   timeline recorded by obs/tracer.py.
 - ``GET /admin/slo``: per-class SLO attainment, burn rates, and goodput

@@ -7,15 +7,19 @@ A module of its own, not the llama skeleton widened: nothing of
 one RoPE key a token shared by every head, a value head narrower than a
 key head), the cache row is not K and V per head, and the first
 `cfg.first_k_dense` layers have no router, so the one stacked tree under
-llama's five scans does not hold the model. What is shared is called, not
+llama's scan does not hold the model. What is shared is called, not
 copied: the routed feed-forward is `mixtral._moe_mlp` (the shared experts
 and the un-renormalised top-k are data of the config there), the dense one
 `llama._mlp`, the head `llama._unembed`, and every read of the pool is
 `ops.attention.ragged_paged_attention`.
 
 One layer body (`_layer`) and one stack runner (`_stack`: the dense
-layers unrolled ahead of ONE scan over the routed layers) serve all five
-phases; a phase is an `attend` closure, where llama has five layer scans.
+layers unrolled ahead of ONE scan over the routed layers) serve every
+phase; a phase is an `attend` closure. The entry points are the ones an
+engine launches, and `validate_mesh` refuses every mesh: `hidden_states`
+(/api/embed), `decode_step`, `verify_step` and `mixed_step`, which admits
+every prompt chunk by chunk. There is no `prefill` / `prefill_chunk`: only
+an `sp` or `pp` engine calls those.
 
 Params: `dense` and `layers` are two stacked trees ([first_k_dense, ...]
 and [num_layers - first_k_dense, ...]); pool layer l is dense layer l or
@@ -31,7 +35,7 @@ The cache row of a token in a layer is `[c (kv_lora_rank), k_pe
   straight on rows: scores over all 576 values of a row, values its first
   512, all heads on the one cache head, each page read once
   (`ragged_paged_attention(latent_dv=...)`);
-- EXPANDED (`forward`, whole-prompt `prefill`): K and V rebuilt per head
+- EXPANDED (`forward`, `hidden_states`): K and V rebuilt per head
   from the latents, plain attention at 192 / 128 a head.
 
 The chunk region is absorbed because one v5e chip read it 2-4 times
@@ -206,14 +210,11 @@ def _stack(params: Params, cfg: ModelConfig, x, pos, attend: Attend,
 # ---------------------------------------------------------------------------
 
 
-def _hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-            seq_lens: jnp.ndarray | None = None, mesh=None,
-            embeds: jnp.ndarray | None = None):
-    """(final-norm hidden states [B, T, E], cache rows [L, B, T, R + dr]),
-    cache-free: the expanded form."""
+def hidden_states(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
+                  seq_lens: jnp.ndarray | None = None, mesh=None) -> jnp.ndarray:
+    """Final-norm hidden states [B, T, E], cache-free: the expanded form."""
     b, t = tokens.shape
-    x = params["embed"][tokens] if embeds is None else embeds.astype(
-        params["embed"].dtype)
+    x = params["embed"][tokens]
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     if seq_lens is None:
         seq_lens = jnp.full((b,), t, jnp.int32)
@@ -222,13 +223,8 @@ def _hidden(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     def attend(lp, li, q_nope, q_pe, row):
         return _expanded(cfg, lp, q_nope, q_pe, pos, row, pos, valid)
 
-    x, rows, _ = _stack(params, cfg, x, pos, attend, mesh, valid)
-    return rms_norm(x, params["final_norm"], cfg.rms_eps), rows
-
-
-def hidden_states(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-                  seq_lens: jnp.ndarray | None = None, mesh=None) -> jnp.ndarray:
-    return _hidden(params, cfg, tokens, seq_lens, mesh)[0]
+    x, _, _ = _stack(params, cfg, x, pos, attend, mesh, valid)
+    return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -249,22 +245,6 @@ def _latent_read(cfg: ModelConfig, cache: PagedKVCache, li, **regions):
     return ragged_paged_attention(
         cache.k, None, cache.page_size, layer=li, use_pallas=cfg.use_pallas,
         latent_dv=cfg.kv_lora_rank, **regions)
-
-
-def prefill(params: Params, cfg: ModelConfig, tokens, length, cache, slot,
-            table_row, attn=None, mesh=None, embeds=None):
-    """Prefill ONE slot from an empty prefix (llama.prefill's contract):
-    the whole padded bucket in the expanded form."""
-    del attn
-    x, rows = _hidden(params, cfg, tokens[None], length[None], mesh,
-                      None if embeds is None else embeds[None])
-    logits = llama._unembed(cfg, params, x[0, jnp.maximum(length - 1, 0)])
-    k_pool, _ = write_prefill_all(
-        cache.k, None, rows[:, 0, :, None], None, table_row, jnp.int32(0),
-        length, cache.page_size, use_pallas=cfg.use_pallas)
-    return logits, _with_k(
-        cache, k_pool, page_table=cache.page_table.at[slot].set(table_row),
-        lengths=cache.lengths.at[slot].set(length))
 
 
 def _chunk_attend(cfg: ModelConfig, cache: PagedKVCache, table_row, start,
@@ -290,29 +270,6 @@ def _chunk_attend(cfg: ModelConfig, cache: PagedKVCache, table_row, start,
         return _absorbed(cfg, lp, q_nope, q_pe, read)
 
     return attend
-
-
-def prefill_chunk(params: Params, cfg: ModelConfig, tokens, start, length,
-                  cache, slot, table_row, mesh=None, embeds=None):
-    """Prefill ONE CHUNK of one slot against its cached prefix
-    (llama.prefill_chunk's contract)."""
-    c = tokens.shape[0]
-    x = params["embed"][tokens] if embeds is None else embeds
-    x = x.astype(params["embed"].dtype)[None]
-    pos = (start + jnp.arange(c, dtype=jnp.int32))[None]
-    total = start + length
-    live = (jnp.arange(c) < length)[None]
-    x, rows, _ = _stack(
-        params, cfg, x, pos,
-        _chunk_attend(cfg, cache, table_row, start, total, c), mesh, live)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = llama._unembed(cfg, params, x[0, jnp.maximum(length - 1, 0)])
-    k_pool, _ = write_prefill_all(
-        cache.k, None, rows[:, 0, :, None], None, table_row, start, length,
-        cache.page_size, use_pallas=cfg.use_pallas)
-    return logits, _with_k(
-        cache, k_pool, page_table=cache.page_table.at[slot].set(table_row),
-        lengths=cache.lengths.at[slot].set(total))
 
 
 def _group_attend(cfg: ModelConfig, cache: PagedKVCache, base, tree_pos=None,

@@ -3,8 +3,8 @@
 rule each; a sigmoid gate a head), a leading dense layer, then routed
 experts behind a sigmoid router with a shared expert.
 
-A module of its own, not the llama skeleton widened: the skeleton's five
-scans stack layers of ONE shape and thread window and RoPE as traced
+A module of its own, not the llama skeleton widened: the skeleton's
+scan stacks layers of ONE shape and threads window and RoPE as traced
 scalars, and `wq`, `wo` and the gate of a 48-head and of a 64-head layer
 cannot share a stack. The layers are stacked BY KIND: `dense` the leading
 dense layers (global), then whole periods of window layers and a global
@@ -31,7 +31,11 @@ models/olmo_hybrid.py does for a state), and an admission from cached
 pages starts from a snapshot the engine restored (`restore_snapshot`).
 
 One layer body (`_layer`) and one stack runner (`_stack`) serve every
-phase; a phase is an `attend` closure.
+phase; a phase is an `attend` closure. The entry points are the ones an
+engine launches, and `validate_mesh` refuses every mesh: `hidden_states`
+(/api/embed), `decode_step`, `verify_step` and `mixed_step`, which admits
+every prompt chunk by chunk. There is no `prefill` / `prefill_chunk`: only
+an `sp` or `pp` engine calls those.
 """
 
 from __future__ import annotations
@@ -281,118 +285,71 @@ def _pools(cfg: ModelConfig, cache: PagedKVCache, window: bool):
     return cache.k, cache.v, cache.page_table, 0
 
 
-def _chunk_launch(params: Params, cfg: ModelConfig, chunk_tokens, start,
-                  length, slot, table_row, cache: PagedKVCache, group=None,
-                  state_io=None, embeds=None, mesh=None):
-    """A chunk region, and with `group` = (tokens [S], active [S]) one
-    decode row a slot beside it: rows [0, c) the chunk, rows [c, c + S)
-    the slots'. Writes both pools, saves the ring's pages at the
-    boundaries `state_io` names. Returns (final-norm x [c (+ S), E], the
-    cache without its lengths and table row set)."""
+def mixed_step(params: Params, cfg: ModelConfig, chunk_tokens, chunk_start,
+               chunk_len, slot, table_row, tokens, cache, active, mesh=None,
+               embeds=None, state_io=None):
+    """One fused chunked-prefill + decode step (llama.mixed_step's
+    contract): rows [0, C) the admitting slot's chunk against its cached
+    prefix and its ring, rows [C, C + S) one decode token a slot, one
+    ragged launch a layer. Writes both pools. `state_io` = (positions
+    [SAVES], snapshot entries [SAVES]): page boundaries this chunk passes
+    at which the ring's last pages are saved."""
     c = chunk_tokens.shape[0]
     ps, win = cache.page_size, cache.win
     assert c <= (win.ring_pages - 1) * ps - cfg.sliding_window, (
         f"a chunk of {c} rows in a ring of {win.ring_pages} pages")
     dt = params["embed"].dtype
     xc = params["embed"][chunk_tokens] if embeds is None else embeds
-    x = xc.astype(dt)
-    total = start + length
-    pos = start + jnp.arange(c, dtype=jnp.int32)
-    live = jnp.arange(c) < length
+    x = jnp.concatenate([xc.astype(dt), params["embed"][tokens]])
+    total = chunk_start + chunk_len
     positions = cache.lengths
-    if group is not None:
-        tokens, active = group
-        x = jnp.concatenate([x, params["embed"][tokens]])
-        pos = jnp.concatenate([pos, positions])
-        live = jnp.concatenate([live, active])
+    pos = jnp.concatenate(
+        [chunk_start + jnp.arange(c, dtype=jnp.int32), positions])
+    live = jnp.concatenate([jnp.arange(c) < chunk_len, active])
     ring_row = win.row(slot, table_row.shape[0])
 
     def attend(window, li, q, k, v):
         k_pool, v_pool, table, w = _pools(cfg, cache, window)
-        regions = dict(
-            q_chunk=q[:, :c], chunk_row=ring_row if window else table_row,
-            chunk_start=start, chunk_total=total, k_chunk=k[0, :c],
-            v_chunk=v[0, :c])
-        if group is not None:
-            regions.update(
-                q_group=q[0, c:, None], page_table=table,
-                group_lengths=positions, k_group=k[0, c:, None],
-                v_group=v[0, c:, None])
         oc, og = ragged_paged_attention(
             k_pool, v_pool, ps, layer=li, use_pallas=cfg.use_pallas,
-            window=w, mesh=mesh, **regions)
-        return jnp.concatenate(
-            [oc[0]] + ([] if og is None else [og[:, 0]]))[None]
+            window=w, mesh=mesh,
+            q_chunk=q[:, :c], chunk_row=ring_row if window else table_row,
+            chunk_start=chunk_start, chunk_total=total, k_chunk=k[0, :c],
+            v_chunk=v[0, :c],
+            q_group=q[0, c:, None], page_table=table,
+            group_lengths=positions, k_group=k[0, c:, None],
+            v_group=v[0, c:, None])
+        return jnp.concatenate([oc[0], og[:, 0]])[None]
 
     x, (kg, vg), (kw, vw), _ = _stack(
         params, cfg, x[None], pos[None], attend, mesh, live[None])
     kg, vg, kw, vw = (z[:, 0] for z in (kg, vg, kw, vw))
+    # region writes target disjoint pages (the admitting slot is not yet
+    # active), so the order is immaterial
     k_pool, v_pool = write_prefill_all(
-        cache.k, cache.v, kg[:, :c], vg[:, :c], table_row, start, length, ps,
-        use_pallas=cfg.use_pallas, mesh=mesh)
+        cache.k, cache.v, kg[:, :c], vg[:, :c], table_row, chunk_start,
+        chunk_len, ps, use_pallas=cfg.use_pallas, mesh=mesh)
     wk, wv = write_prefill_all(
-        win.k, win.v, kw[:, :c], vw[:, :c], ring_row, start, length, ps,
-        use_pallas=cfg.use_pallas, mesh=mesh)
-    if group is not None:
-        # region writes target disjoint pages (the admitting slot is not
-        # yet active), so the order is immaterial
-        k_pool, v_pool = write_decode_all(
-            k_pool, v_pool, kg[:, c:], vg[:, c:], cache.page_table, positions,
-            active, ps, use_pallas=cfg.use_pallas, mesh=mesh)
-        wk, wv = write_decode_all(
-            wk, wv, kw[:, c:], vw[:, c:], win.table(table_row.shape[0]),
-            positions, active, ps, use_pallas=cfg.use_pallas, mesh=mesh)
+        win.k, win.v, kw[:, :c], vw[:, :c], ring_row, chunk_start, chunk_len,
+        ps, use_pallas=cfg.use_pallas, mesh=mesh)
+    k_pool, v_pool = write_decode_all(
+        k_pool, v_pool, kg[:, c:], vg[:, c:], cache.page_table, positions,
+        active, ps, use_pallas=cfg.use_pallas, mesh=mesh)
+    wk, wv = write_decode_all(
+        wk, wv, kw[:, c:], vw[:, c:], win.table(table_row.shape[0]),
+        positions, active, ps, use_pallas=cfg.use_pallas, mesh=mesh)
     win = dataclasses.replace(win, k=wk, v=wv)
     if state_io is not None:
         win = win.save(slot, *state_io, ps)
-    return rms_norm(x[0], params["final_norm"], cfg.rms_eps), (
-        dataclasses.replace(cache, k=k_pool, v=v_pool, win=win))
-
-
-def prefill_chunk(params: Params, cfg: ModelConfig, tokens, start, length,
-                  cache, slot, table_row, mesh=None, embeds=None,
-                  state_io=None):
-    """Prefill ONE CHUNK of one slot against its cached prefix and its
-    ring (llama.prefill_chunk's contract). `state_io` = (positions
-    [SAVES], snapshot entries [SAVES]): page boundaries this chunk passes
-    at which the ring's last pages are saved."""
-    x, cache = _chunk_launch(
-        params, cfg, tokens, start, length, slot, table_row, cache,
-        state_io=state_io, embeds=embeds, mesh=mesh)
-    logits = llama._unembed(cfg, params, x[jnp.maximum(length - 1, 0)])
-    return logits, dataclasses.replace(
-        cache, page_table=cache.page_table.at[slot].set(table_row),
-        lengths=cache.lengths.at[slot].set(start + length))
-
-
-def prefill(params: Params, cfg: ModelConfig, tokens, length, cache, slot,
-            table_row, attn=None, mesh=None, embeds=None):
-    """Prefill ONE slot from an empty prefix (llama.prefill's contract):
-    the chunk launch at position 0, for a bucket no wider than one launch
-    of the ring."""
-    del attn
-    return prefill_chunk(params, cfg, tokens, jnp.int32(0), length, cache,
-                         slot, table_row, mesh=mesh, embeds=embeds)
-
-
-def mixed_step(params: Params, cfg: ModelConfig, chunk_tokens, chunk_start,
-               chunk_len, slot, table_row, tokens, cache, active, mesh=None,
-               embeds=None, state_io=None):
-    """One fused chunked-prefill + decode step (llama.mixed_step's
-    contract): rows [0, C) the admitting slot's chunk, rows [C, C + S) one
-    decode token a slot, one ragged launch a layer."""
-    c = chunk_tokens.shape[0]
-    x, new = _chunk_launch(
-        params, cfg, chunk_tokens, chunk_start, chunk_len, slot, table_row,
-        cache, group=(tokens, active), state_io=state_io, embeds=embeds,
-        mesh=mesh)
+    x = rms_norm(x[0], params["final_norm"], cfg.rms_eps)
     chunk_logits = llama._unembed(cfg, params, x[jnp.maximum(chunk_len - 1, 0)])
     dec_logits = llama._unembed(cfg, params, x[c:])
     new_lengths = jnp.minimum(
         cache.lengths + active.astype(jnp.int32), cache.max_context
-    ).at[slot].set(chunk_start + chunk_len)
+    ).at[slot].set(total)
     return chunk_logits, dec_logits, dataclasses.replace(
-        new, page_table=cache.page_table.at[slot].set(table_row),
+        cache, k=k_pool, v=v_pool, win=win,
+        page_table=cache.page_table.at[slot].set(table_row),
         lengths=new_lengths)
 
 

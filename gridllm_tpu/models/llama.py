@@ -5,9 +5,12 @@ TPU-first design choices (SURVEY.md §7 step 4):
   axis and the layer loop is `lax.scan` — one traced layer body, O(1)
   compile time in depth, and XLA donates the KV pool buffers through the
   scan so cache updates are in-place in HBM.
-- Three entry points, all static-shape: `forward` (full logits, golden
-  tests / graft entry), `prefill` (one slot, bucketed T, writes the paged
-  cache), `decode_step` (all slots, one token each).
+- ONE layer body (`_layer`) and ONE scan (`stack`); a phase is an `attend`
+  closure over them, all static-shape. An engine launches `hidden_states`
+  (/api/embed), `decode_step`, `verify_step` and `mixed_step`, which admits
+  every prompt; `prefill` and, behind a prefix-cache hit, `prefill_chunk`
+  only under `sp` (ring attention) and, through parallel/pipeline.py, under
+  `pp`. `forward` is the golden tests' oracle and the graft entry.
 - No data-dependent Python control flow anywhere; active/inactive slots are
   masked, not branched.
 
@@ -47,24 +50,12 @@ Params = dict[str, Any]
 # the router's input as well and returns (output, per-layer statistics).
 MlpFn = Callable[["Params", jnp.ndarray], jnp.ndarray]
 
-# Prefill attention body: (q, k, v, seq_lens) -> attended values. Default
-# is the ops.attention dispatch (jnp ref / Pallas flash); the engine
-# passes ops.ring_attention for sp-sharded long-context prefill.
+# Whole-prompt attention in place of the ops.attention dispatch: (q, k, v,
+# seq_lens) -> attended values. The engine passes ops.ring_attention for
+# sp-sharded long-context prefill.
 AttnFn = Callable[
     [jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray
 ]
-
-
-def _default_attn(cfg: ModelConfig, mesh=None) -> AttnFn:
-    # `window`: a patterned family's per-layer scalar; a uniform family's
-    # layers never pass it (nor could they to ring attention's AttnFn)
-    def attn(q, k, v, seq_lens, window=cfg.sliding_window):
-        return attention_prefill(
-            q, k, v, seq_lens, use_pallas=cfg.use_pallas,
-            window=window, mesh=mesh,
-        )
-
-    return attn
 
 
 def _layer_kinds(cfg: ModelConfig, n: int):
@@ -213,6 +204,142 @@ def _unembed(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
     )
 
 
+def _seq_constraint(mesh) -> Callable[[jnp.ndarray], jnp.ndarray]:
+    """T-axis activation constraint for sp-sharded prefill: without pinning
+    the [1, T, E] residual stream to P(None, "sp", None), whether the
+    projections and MLP activations outside ring_attention's shard_map are
+    O(T/sp) per device depends on GSPMD propagation luck (round-1 VERDICT
+    #9; asserted structurally by tests/test_parallel.py)."""
+    if mesh is None or mesh.shape.get("sp", 1) <= 1:
+        return lambda x: x
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    s = NamedSharding(mesh, PartitionSpec(None, "sp", None))
+    return lambda x: jax.lax.with_sharding_constraint(x, s)
+
+
+# One layer's attention, which is all a phase is: (layer index, the layer's
+# window, roped q [..., T, H, D], roped k and v [..., T, KVH, D]) -> attended
+# values, in any shape that flattens to [..., T, H*D].
+Attend = Callable[..., jnp.ndarray]
+
+
+def _layer(cfg: ModelConfig, lp: Params, li, kind, x, pos, inv_freq,
+           attend: Attend, mlp: MlpFn, seq_c):
+    """One decoder layer on x [..., T, E] at positions pos [..., T] → (x,
+    the layer's roped k and v [..., T, KVH, D], the feed-forward's
+    statistics)."""
+    win, lpos = _kind(cfg, kind, pos)
+    pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+    q, k, v = _qkv(cfg, lp, pre)
+    q = apply_rope(q, lpos, inv_freq)
+    k = apply_rope(k, lpos, inv_freq)
+    att = attend(li, win, q, k, v).reshape(*x.shape[:-1], -1)
+    x = seq_c(x + qdot(att, lp["wo"], precision=_precision(x)))
+    hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+    y, stats = _ffn(cfg, mlp, lp, hx, pre)
+    return seq_c(x + y), k, v, stats
+
+
+def stack(layers: Params, cfg: ModelConfig, x: jnp.ndarray, pos: jnp.ndarray,
+          attend: Attend, mlp: MlpFn = _mlp, seq_c=lambda x: x,
+          kv: bool = True):
+    """The ONE layer scan every entry point shares, over an arbitrary
+    stacked block of layers: the full [L] stack, or a pp stage's block
+    with its matching pool block inside `attend` (parallel/pipeline.py).
+
+    x: [..., T, E]; pos: [..., T] absolute positions. Returns (x out, k_new
+    [N, ..., T, KVH, D], v_new, stats): K/V ride out as scan ys and the
+    pool is written ONCE after the scan by the caller (per-layer writes
+    inside the scan defeat XLA's in-place aliasing and cost full-pool
+    copies — round-4 profiling); `stats` are a routed family's per-layer
+    statistics [N, ...] (`_ffn`), None for a dense one. `kv=False` stacks
+    nothing (hidden_states has no cache to fill)."""
+    _check_supported(cfg)
+    inv_freq = precompute_rope(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
+    n = jax.tree.leaves(layers)[0].shape[0]
+
+    def body(x, xs):
+        lp, li, kind = xs
+        x, *ys = _layer(cfg, lp, li, kind, x, pos, inv_freq, attend, mlp,
+                        seq_c)
+        return x, tuple(ys) if kv else None
+
+    x, ys = jax.lax.scan(
+        body, x, (layers, jnp.arange(n, dtype=jnp.int32),
+                  _layer_kinds(cfg, n)))
+    return (x, *ys) if kv else (x, None, None, None)
+
+
+def whole_attend(cfg: ModelConfig, seq_lens, attn: AttnFn | None = None,
+                 mesh=None) -> Attend:
+    """Whole-prompt attention, no cache read (hidden_states, prefill): the
+    ops.attention dispatch (jnp ref / Pallas flash), or `attn`."""
+    patterned = bool(cfg.window_layout or cfg.rope_layout)
+
+    def attend(li, win, q, k, v):
+        if attn is None:
+            return attention_prefill(
+                q, k, v, seq_lens, use_pallas=cfg.use_pallas, window=win,
+                mesh=mesh)
+        # a uniform family's layers never pass the window (nor could they
+        # to ring attention's AttnFn)
+        if patterned:
+            return attn(q, k, v, seq_lens, window=win)
+        return attn(q, k, v, seq_lens)
+
+    return attend
+
+
+def _pool_read(cfg: ModelConfig, k_pool, v_pool, page_size: int, mesh):
+    """ragged_paged_attention against the page pool, which holds the
+    PREFIX only: the launch's own K/V are overlaid in-register by the
+    attention. The FULL pool rides in as a scan closure with `li` selecting
+    the layer — per-layer xs slices would materialize 2×pool-slice
+    copies/iter."""
+
+    def read(li, win, **regions):
+        return ragged_paged_attention(
+            k_pool, v_pool, page_size, layer=li, use_pallas=cfg.use_pallas,
+            window=win, mesh=mesh, **regions)
+
+    return read
+
+
+def chunk_attend(cfg: ModelConfig, k_pool, v_pool, page_size: int, row,
+                 start, total, mesh=None) -> Attend:
+    """The ragged chunk region: x [1, C, E] is ONE slot's prefill chunk at
+    positions start.. behind its cached prefix on pages `row` (paged-prefix
+    streaming flash when kernels are on)."""
+    read = _pool_read(cfg, k_pool, v_pool, page_size, mesh)
+
+    def attend(li, win, q, k, v):
+        return read(li, win, q_chunk=q, chunk_row=row, chunk_start=start,
+                    chunk_total=total, k_chunk=k[0], v_chunk=v[0])[0]
+
+    return attend
+
+
+def group_attend(cfg: ModelConfig, k_pool, v_pool, page_size: int,
+                 page_table, lengths, mesh=None, tree_pos=None,
+                 tree_mask=None) -> Attend:
+    """The ragged group region, ONE launch over all slots: x [S, Td, E] is
+    Td rows a slot behind each slot's `lengths` cached rows — a speculative
+    verify at Td = K+1, where `tree_pos` / `tree_mask` make the rows a
+    token tree (verify_step); a decode step is Td = 1 and keeps its x
+    [S, E], the unit axis is the launch's alone."""
+    read = _pool_read(cfg, k_pool, v_pool, page_size, mesh)
+
+    def attend(li, win, q, k, v):
+        if q.ndim == 3:
+            q, k, v = q[:, None], k[:, None], v[:, None]
+        return read(li, win, q_group=q, page_table=page_table,
+                    group_lengths=lengths, k_group=k, v_group=v,
+                    tree_pos=tree_pos, tree_mask=tree_mask)[1]
+
+    return attend
+
+
 def hidden_states(
     params: Params,
     cfg: ModelConfig,
@@ -226,33 +353,15 @@ def hidden_states(
     """Final-norm hidden states [B, T, E] (embeddings path; no unembed).
     seq_lens masks padding keys out of attention (None → all valid).
     `embeds` ([B, T, E]) overrides the embedding lookup (vision splice)."""
-    _check_supported(cfg)
-    if attn is None:
-        attn = _default_attn(cfg, mesh)
     b, t = tokens.shape
-    inv_freq = precompute_rope(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
     x = params["embed"][tokens] if embeds is None else embeds.astype(
         params["embed"].dtype
     )
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
     if seq_lens is None:
         seq_lens = jnp.full((b,), t, jnp.int32)
-
-    def layer(x, xs):
-        lp, kind = xs
-        win, lpos = _kind(cfg, kind, pos)
-        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, pre)
-        q = apply_rope(q, lpos, inv_freq)
-        k = apply_rope(k, lpos, inv_freq)
-        att = (attn(q, k, v, seq_lens) if kind is None
-               else attn(q, k, v, seq_lens, window=win)).reshape(b, t, -1)
-        x = x + qdot(att, lp["wo"], precision=_precision(x))
-        hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        return x + _ffn(cfg, mlp, lp, hx, pre)[0], None
-
-    x, _ = jax.lax.scan(
-        layer, x, (params["layers"], _layer_kinds(cfg, cfg.num_layers)))
+    x, _, _, _ = stack(params["layers"], cfg, x, pos,
+                       whole_attend(cfg, seq_lens, attn, mesh), mlp, kv=False)
     return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 
@@ -270,63 +379,29 @@ def forward(
     )
 
 
-def _seq_constraint(mesh) -> Callable[[jnp.ndarray], jnp.ndarray]:
-    """T-axis activation constraint for sp-sharded prefill.
-
-    Round-1 VERDICT #9: without pinning the [1, T, E] residual stream to
-    P(None, "sp", None), whether q/k/v projections and MLP activations
-    outside ring_attention's shard_map are actually O(T/sp) per device
-    depends on GSPMD propagation luck. This turns the memory claim into an
-    annotated property (asserted structurally by tests/test_parallel.py).
-    """
-    if mesh is None or mesh.shape.get("sp", 1) <= 1:
-        return lambda x: x
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    s = NamedSharding(mesh, PartitionSpec(None, "sp", None))
-    return lambda x: jax.lax.with_sharding_constraint(x, s)
-
-
-def prefill_layers(
-    layers: Params,
-    cfg: ModelConfig,
-    x: jnp.ndarray,
-    seq_lens: jnp.ndarray,
-    mlp: MlpFn = _mlp,
-    attn: AttnFn | None = None,
-    seq_c: Callable[[jnp.ndarray], jnp.ndarray] = lambda x: x,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Self-contained prefill layer scan over an arbitrary stacked block of
-    layers (full [L] stack from `prefill`; per-stage blocks from
-    parallel/pipeline.py). x: [1, T, E] in; returns (x out,
-    k_new [N, T, KVH, D], v_new) — pool writes are the caller's.
-    """
-    if attn is None:
-        attn = _default_attn(cfg)
-    t = x.shape[1]
-    inv_freq = precompute_rope(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
-    pos = jnp.arange(t, dtype=jnp.int32)[None]
-
-    n = jax.tree.leaves(layers)[0].shape[0]
-
-    def layer(x, xs):
-        lp, kind = xs
-        win, lpos = _kind(cfg, kind, pos)
-        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, pre)
-        q = apply_rope(q, lpos, inv_freq)
-        k = apply_rope(k, lpos, inv_freq)
-        att = (attn(q, k, v, seq_lens) if kind is None
-               else attn(q, k, v, seq_lens, window=win)).reshape(1, t, -1)
-        x = seq_c(x + qdot(att, lp["wo"], precision=_precision(x)))
-        hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        # K/V ride out as scan ys; the pool is written ONCE after the scan
-        # (per-layer writes inside the scan defeat XLA's in-place aliasing
-        # and cost full-pool copies — round-4 profiling)
-        return seq_c(x + _ffn(cfg, mlp, lp, hx, pre)[0]), (k[0], v[0])
-
-    x, (k_new, v_new) = jax.lax.scan(layer, x, (layers, _layer_kinds(cfg, n)))
-    return x, k_new, v_new
+def _admit(params, cfg, tokens, start, length, cache, slot, table_row,
+           attend: Attend, mlp, mesh, embeds, seq_c=lambda x: x):
+    """ONE slot's rows `tokens` [T] (padded; `length` valid) at positions
+    start.. through `attend` → (the last *valid* token's logits [V] fp32,
+    the cache with the rows written on `table_row`'s pages and
+    lengths[slot] = start + length)."""
+    x = params["embed"][tokens] if embeds is None else embeds
+    x = seq_c(x.astype(params["embed"].dtype)[None])  # [1, T, E]
+    pos = (start + jnp.arange(tokens.shape[0], dtype=jnp.int32))[None]
+    x, k_new, v_new, _ = stack(
+        params["layers"], cfg, x, pos, attend, mlp, seq_c)
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    logits = _unembed(cfg, params, x[0, jnp.maximum(length - 1, 0)])
+    k_pool, v_pool = write_prefill_all(
+        cache.k, cache.v, k_new[:, 0], v_new[:, 0], table_row, start, length,
+        cache.page_size, use_pallas=cfg.use_pallas, mesh=mesh,
+    )
+    return logits, PagedKVCache(
+        k=k_pool, v=v_pool,
+        page_table=cache.page_table.at[slot].set(table_row),
+        lengths=cache.lengths.at[slot].set(start + length),
+        page_size=cache.page_size,
+    )
 
 
 def prefill(
@@ -351,33 +426,11 @@ def prefill(
     path (models/llava.py splice_embeds) feeds image-spliced embeddings;
     tokens are still used for lengths/window bookkeeping by the caller.
     """
-    _check_supported(cfg)
-    if attn is None:
-        attn = _default_attn(cfg, mesh)
-    seq_c = _seq_constraint(mesh)
-    t = tokens.shape[0]
-    x = params["embed"][tokens] if embeds is None else embeds
-    x = seq_c(x.astype(params["embed"].dtype)[None])  # [1, T, E]
-    x, k_new, v_new = prefill_layers(
-        params["layers"], cfg, x, length[None], mlp, attn, seq_c
+    return _admit(
+        params, cfg, tokens, jnp.int32(0), length, cache, slot, table_row,
+        whole_attend(cfg, length[None], attn, mesh), mlp, mesh, embeds,
+        _seq_constraint(mesh),
     )
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    # last *valid* token's logits
-    last = x[0, jnp.maximum(length - 1, 0)]
-    logits = _unembed(cfg, params, last)
-
-    k_pool, v_pool = write_prefill_all(
-        cache.k, cache.v, k_new, v_new, table_row,
-        jnp.int32(0), length, cache.page_size, use_pallas=cfg.use_pallas,
-        mesh=mesh,
-    )
-    cache = PagedKVCache(
-        k=k_pool, v=v_pool,
-        page_table=cache.page_table.at[slot].set(table_row),
-        lengths=cache.lengths.at[slot].set(length),
-        page_size=cache.page_size,
-    )
-    return logits, cache
 
 
 def prefill_chunk(
@@ -398,149 +451,16 @@ def prefill_chunk(
     tokens: [C] (padded chunk bucket), start: scalar absolute position of
     tokens[0] (0 for the first chunk), length: scalar valid tokens in THIS
     chunk. Attention reads prefix K/V from the page pool (the chunk's K/V
-    are written first), so a long prompt runs as ceil(T/C) invocations of
-    ONE compiled program instead of a per-length trace (VERDICT.md #4).
+    are overlaid inside it), so a long prompt runs as ceil(T/C) invocations
+    of ONE compiled program instead of a per-length trace (VERDICT.md #4).
     Returns (last-valid-token logits [V] fp32, cache with lengths[slot] =
     start + length).
     """
-    _check_supported(cfg)
-    x = params["embed"][tokens] if embeds is None else embeds
-    x = x.astype(params["embed"].dtype)[None]  # [1, C, E]
-    x, k_new, v_new = prefill_chunk_layers(
-        params["layers"], cfg, x, cache.k, cache.v, table_row, start,
-        length, cache.page_size, mlp, mesh=mesh,
+    return _admit(
+        params, cfg, tokens, start, length, cache, slot, table_row,
+        chunk_attend(cfg, cache.k, cache.v, cache.page_size, table_row,
+                     start, start + length, mesh), mlp, mesh, embeds,
     )
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    last = x[0, jnp.maximum(length - 1, 0)]
-    logits = _unembed(cfg, params, last)
-
-    k_pool, v_pool = write_prefill_all(
-        cache.k, cache.v, k_new, v_new, table_row, start, length,
-        cache.page_size, use_pallas=cfg.use_pallas, mesh=mesh,
-    )
-    cache = PagedKVCache(
-        k=k_pool, v=v_pool,
-        page_table=cache.page_table.at[slot].set(table_row),
-        lengths=cache.lengths.at[slot].set(start + length),
-        page_size=cache.page_size,
-    )
-    return logits, cache
-
-
-def prefill_chunk_layers(
-    layers: Params,
-    cfg: ModelConfig,
-    x: jnp.ndarray,
-    k_pool: jnp.ndarray,
-    v_pool: jnp.ndarray,
-    table_row: jnp.ndarray,
-    start: jnp.ndarray,
-    length: jnp.ndarray,
-    page_size: int,
-    mlp: MlpFn = _mlp,
-    mesh=None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Chunked-prefill layer scan over an arbitrary stacked block of
-    layers against the slot's cached prefix (full stack from
-    `prefill_chunk`; per-stage blocks from parallel/pipeline.py).
-    x: [1, C, E] in; returns (x out, k_new [N, C, KVH, D], v_new).
-    Attention is ragged_paged_attention's chunk region (paged-prefix
-    streaming flash when kernels are on) — `mesh` threads through for the
-    meshed shard_map wrapper."""
-    t = x.shape[1]
-    inv_freq = precompute_rope(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
-    pos = (start + jnp.arange(t, dtype=jnp.int32))[None]
-    total = start + length
-    n = jax.tree.leaves(layers)[0].shape[0]
-
-    def layer(x, xs):
-        lp, li, kind = xs
-        win, lpos = _kind(cfg, kind, pos)
-        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, pre)
-        q = apply_rope(q, lpos, inv_freq)
-        k = apply_rope(k, lpos, inv_freq)
-        # pool holds the PREFIX only (writes deferred past the scan); the
-        # fresh chunk's K/V are overlaid inside the attention. Full pool as
-        # closure + layer index — see decode_layers.
-        att, _ = ragged_paged_attention(
-            k_pool, v_pool, page_size,
-            q_chunk=q, chunk_row=table_row, chunk_start=start,
-            chunk_total=total, k_chunk=k[0], v_chunk=v[0], layer=li,
-            use_pallas=cfg.use_pallas, window=win,
-            mesh=mesh,
-        )
-        att = att.reshape(1, t, -1)
-        x = x + qdot(att, lp["wo"], precision=_precision(x))
-        hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        return x + _ffn(cfg, mlp, lp, hx, pre)[0], (k[0], v[0])
-
-    x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (layers, jnp.arange(n, dtype=jnp.int32),
-                   _layer_kinds(cfg, n))
-    )
-    return x, k_new, v_new
-
-
-def decode_layers(
-    layers: Params,
-    cfg: ModelConfig,
-    x: jnp.ndarray,
-    k_pool: jnp.ndarray,
-    v_pool: jnp.ndarray,
-    page_table: jnp.ndarray,
-    positions: jnp.ndarray,
-    page_size: int,
-    mlp: MlpFn = _mlp,
-    mesh=None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """The decode layer scan over an arbitrary stacked block of layers.
-
-    `layers` leaves are stacked [N, ...]; `k_pool`/`v_pool` is the
-    matching [N, P, ps, KVH, D] pool block. decode_step runs this over the
-    full [L] stack; parallel/pipeline.py runs it per pp stage with the
-    stage's local block. x: [S, E] residual stream in; returns
-    (x out, k_new [N, S, KVH, D], v_new, stats) — pool writes are the
-    caller's (deferred one-shot write after the scan); `stats` are a
-    routed family's per-layer statistics [N, ...] (`_ffn`), None for a
-    dense one.
-    """
-    s = x.shape[0]
-    inv_freq = precompute_rope(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
-    n = jax.tree.leaves(layers)[0].shape[0]
-
-    def layer(x, xs):
-        lp, li, kind = xs
-        win, lpos = _kind(cfg, kind, positions[:, None])
-        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, pre)  # q: [S, H, D] (T-less), k/v: [S, KVH, D]
-        q = apply_rope(q[:, None], lpos, inv_freq)[:, 0]
-        k = apply_rope(k[:, None], lpos, inv_freq)[:, 0]
-        # pool holds the prefix only (lengths = positions); the current
-        # token's K/V are merged in-register by the attention and written
-        # to the pool ONCE after the scan (in-place DMA kernel). The FULL
-        # pool rides in as a scan closure with `li` selecting the layer —
-        # per-layer xs slices would materialize 2×pool-slice copies/iter.
-        # A decode step is the ragged group region with query_len = 1 per
-        # slot.
-        _, attn = ragged_paged_attention(
-            k_pool, v_pool, page_size,
-            q_group=q[:, None], page_table=page_table,
-            group_lengths=positions, k_group=k[:, None],
-            v_group=v[:, None], layer=li, use_pallas=cfg.use_pallas,
-            window=win, mesh=mesh,
-        )
-        attn = attn[:, 0].reshape(s, -1)
-        x = x + qdot(attn, lp["wo"], precision=_precision(x))
-        hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        y, stats = _ffn(cfg, mlp, lp, hx, pre)
-        return x + y, (k, v, stats)
-
-    x, (k_new, v_new, stats) = jax.lax.scan(
-        layer, x, (layers, jnp.arange(n, dtype=jnp.int32),
-                   _layer_kinds(cfg, n))
-    )
-    return x, k_new, v_new, stats
 
 
 def decode_step(
@@ -558,7 +478,6 @@ def decode_step(
     with lengths advanced for active slots) and, `with_stats`, a routed
     family's statistics summed over the layers.
     """
-    _check_supported(cfg)
     x = params["embed"][tokens]  # [S, E]
     positions = cache.lengths  # new token's position per slot
     # clamp at pool-wide capacity: finished slots stay device-active for up
@@ -569,9 +488,10 @@ def decode_step(
         cache.lengths + active.astype(jnp.int32), cache.max_context
     )
 
-    x, k_new, v_new, stats = decode_layers(
-        params["layers"], cfg, x, cache.k, cache.v, cache.page_table,
-        positions, cache.page_size, mlp, mesh=mesh,
+    x, k_new, v_new, stats = stack(
+        params["layers"], cfg, x, positions,
+        group_attend(cfg, cache.k, cache.v, cache.page_size,
+                     cache.page_table, positions, mesh), mlp,
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = _unembed(cfg, params, x)
@@ -587,72 +507,6 @@ def decode_step(
     if with_stats:
         return logits, cache, stats.sum(axis=0)
     return logits, cache
-
-
-def verify_layers(
-    layers: Params,
-    cfg: ModelConfig,
-    x: jnp.ndarray,
-    k_pool: jnp.ndarray,
-    v_pool: jnp.ndarray,
-    page_table: jnp.ndarray,
-    base_lengths: jnp.ndarray,
-    page_size: int,
-    mlp: MlpFn = _mlp,
-    mesh=None,
-    tree_pos: jnp.ndarray | None = None,
-    tree_mask: jnp.ndarray | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Speculative-verify layer scan: T candidate tokens for ALL slots at
-    once against each slot's paged prefix (ISSUE 5). x: [S, T, E];
-    base_lengths: [S] cached-prefix length per slot (candidate i sits at
-    absolute position base_lengths[s] + i). Returns (x out, k_new
-    [L, S, T, KVH, D], v_new, stats) — pool writes are the caller's, same
-    deferred-write discipline (and `stats`) as decode_layers.
-
-    Tree verify (ISSUE 18): with `tree_pos` ([T] node depths) and
-    `tree_mask` ([T, T] ancestor-or-self, both static host constants) the
-    T candidates form a token tree — node i takes rope at LOGICAL
-    position base_lengths[s] + tree_pos[i] and its query attends the
-    prefix plus exactly its tree ancestors (see
-    ops.attention.paged_attention_verify_ref)."""
-    s, t = x.shape[:2]
-    inv_freq = precompute_rope(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
-    rel = (jnp.asarray(tree_pos, jnp.int32) if tree_pos is not None
-           else jnp.arange(t, dtype=jnp.int32))
-    pos = base_lengths[:, None] + rel[None]
-    n = jax.tree.leaves(layers)[0].shape[0]
-
-    def layer(x, xs):
-        lp, li, kind = xs
-        win, lpos = _kind(cfg, kind, pos)
-        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, pre)  # q: [S, T, H, D], k/v: [S, T, KVH, D]
-        q = apply_rope(q, lpos, inv_freq)
-        k = apply_rope(k, lpos, inv_freq)
-        # pool holds each slot's prefix only; the candidates' K/V are
-        # overlaid in-register and written ONCE after the scan (full pool
-        # as closure + layer index — see decode_layers). ONE launch over
-        # all slots: the ragged group region with query_len = K+1.
-        _, att = ragged_paged_attention(
-            k_pool, v_pool, page_size,
-            q_group=q, page_table=page_table,
-            group_lengths=base_lengths, k_group=k, v_group=v,
-            layer=li, use_pallas=cfg.use_pallas,
-            window=win, mesh=mesh,
-            tree_pos=tree_pos, tree_mask=tree_mask,
-        )
-        att = att.reshape(s, t, -1)
-        x = x + qdot(att, lp["wo"], precision=_precision(x))
-        hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        y, stats = _ffn(cfg, mlp, lp, hx, pre)
-        return x + y, (k, v, stats)
-
-    x, (k_new, v_new, stats) = jax.lax.scan(
-        layer, x, (layers, jnp.arange(n, dtype=jnp.int32),
-                   _layer_kinds(cfg, n))
-    )
-    return x, k_new, v_new, stats
 
 
 def verify_step(
@@ -676,22 +530,26 @@ def verify_step(
     commits the accepted length afterwards via
     ops.kvcache.rollback_to_length, which drops rejected rows).
 
-    Tree verify (ISSUE 18): `tree_pos`/`tree_mask` (static topology, see
-    verify_layers) make cols 1..T-1 a token TREE — node i still lands at
-    STORAGE position lengths[s] + i (the engine compacts the accepted
-    path with ops.kvcache.commit_tree_path before rolling lengths
-    forward), logits row i is the distribution after consuming node i's
-    root path. `with_stats`: as decode_step."""
-    _check_supported(cfg)
+    Tree verify (ISSUE 18): with `tree_pos` ([T] node depths) and
+    `tree_mask` ([T, T] ancestor-or-self, both static host constants) cols
+    1..T-1 are a token TREE — node i takes rope at LOGICAL position
+    lengths[s] + tree_pos[i] and its query attends the prefix plus exactly
+    its tree ancestors (ops.attention.paged_attention_verify_ref), but
+    still lands at STORAGE position lengths[s] + i (the engine compacts
+    the accepted path with ops.kvcache.commit_tree_path before rolling
+    lengths forward); logits row i is the distribution after consuming
+    node i's root path. `with_stats`: as decode_step."""
     s, t = tokens.shape
     x = params["embed"][tokens]  # [S, T, E]
     base = cache.lengths
     positions = base[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+    rel = (jnp.asarray(tree_pos, jnp.int32) if tree_pos is not None
+           else jnp.arange(t, dtype=jnp.int32))
 
-    x, k_new, v_new, stats = verify_layers(
-        params["layers"], cfg, x, cache.k, cache.v, cache.page_table,
-        base, cache.page_size, mlp, mesh=mesh,
-        tree_pos=tree_pos, tree_mask=tree_mask,
+    x, k_new, v_new, stats = stack(
+        params["layers"], cfg, x, base[:, None] + rel[None],
+        group_attend(cfg, cache.k, cache.v, cache.page_size,
+                     cache.page_table, base, mesh, tree_pos, tree_mask), mlp,
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = _unembed(cfg, params, x)  # [S, T, V]
@@ -707,68 +565,6 @@ def verify_step(
     if with_stats:
         return logits, cache, stats.sum(axis=0)
     return logits, cache
-
-
-def mixed_layers(
-    layers: Params,
-    cfg: ModelConfig,
-    x: jnp.ndarray,
-    chunk_width: int,
-    k_pool: jnp.ndarray,
-    v_pool: jnp.ndarray,
-    page_table: jnp.ndarray,
-    chunk_row: jnp.ndarray,
-    chunk_start: jnp.ndarray,
-    chunk_total: jnp.ndarray,
-    group_lengths: jnp.ndarray,
-    page_size: int,
-    mlp: MlpFn = _mlp,
-    mesh=None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Mixed chunked-prefill + decode layer scan: the ragged
-    token batch [1, C+S, E] — rows [0, C) one admitting slot's prefill
-    chunk at absolute positions chunk_start + i, rows [C, C+S) one decode
-    token per slot at positions group_lengths[s] — runs the whole layer
-    stack with ONE ragged attention launch per layer. Pointwise sublayers
-    (norms, projections, MLP) are row-independent, so each region's rows
-    compute exactly what the separate per-phase programs would. Returns
-    (x out, k_new [L, C+S, KVH, D], v_new) — pool writes are the
-    caller's, split per region."""
-    c = chunk_width
-    t = x.shape[1]
-    s = t - c
-    inv_freq = precompute_rope(cfg.head_dim_, cfg.rope_theta, cfg.rope_scaling)
-    pos = jnp.concatenate([
-        chunk_start + jnp.arange(c, dtype=jnp.int32), group_lengths
-    ])[None]
-    n = jax.tree.leaves(layers)[0].shape[0]
-
-    def layer(x, xs):
-        lp, li, kind = xs
-        win, lpos = _kind(cfg, kind, pos)
-        pre = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(cfg, lp, pre)
-        q = apply_rope(q, lpos, inv_freq)
-        k = apply_rope(k, lpos, inv_freq)
-        oc, og = ragged_paged_attention(
-            k_pool, v_pool, page_size,
-            q_chunk=q[:, :c], chunk_row=chunk_row, chunk_start=chunk_start,
-            chunk_total=chunk_total, k_chunk=k[0, :c], v_chunk=v[0, :c],
-            q_group=q[0, c:][:, None], page_table=page_table,
-            group_lengths=group_lengths, k_group=k[0, c:][:, None],
-            v_group=v[0, c:][:, None], layer=li, use_pallas=cfg.use_pallas,
-            window=win, mesh=mesh,
-        )
-        att = jnp.concatenate([oc[0], og[:, 0]]).reshape(1, t, -1)
-        x = x + qdot(att, lp["wo"], precision=_precision(x))
-        hx = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        return x + _ffn(cfg, mlp, lp, hx, pre)[0], (k[0], v[0])
-
-    x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (layers, jnp.arange(n, dtype=jnp.int32),
-                   _layer_kinds(cfg, n))
-    )
-    return x, k_new, v_new
 
 
 def mixed_step(
@@ -799,8 +595,13 @@ def mixed_step(
     slot's last token, active: [S]. Returns (chunk last-valid-token
     logits [V], decode logits [S, V], updated cache with the chunk
     written at [chunk_start, chunk_start+chunk_len) and active slots
-    advanced by one)."""
-    _check_supported(cfg)
+    advanced by one).
+
+    The ragged token batch is x [1, C+S, E]: rows [0, C) the chunk at
+    positions chunk_start + i, rows [C, C+S) one decode token per slot at
+    positions lengths[s]. Pointwise sublayers (norms, projections, MLP)
+    are row-independent, so each region's rows compute exactly what the
+    separate per-phase programs would."""
     c = chunk_tokens.shape[0]
     xc = params["embed"][chunk_tokens] if embeds is None else embeds
     xg = params["embed"][tokens]
@@ -809,12 +610,24 @@ def mixed_step(
     ])[None]                                        # [1, C+S, E]
     positions = cache.lengths
     total = chunk_start + chunk_len
+    pos = jnp.concatenate([
+        chunk_start + jnp.arange(c, dtype=jnp.int32), positions
+    ])[None]
+    read = _pool_read(cfg, cache.k, cache.v, cache.page_size, mesh)
 
-    x, k_new, v_new = mixed_layers(
-        params["layers"], cfg, x, c, cache.k, cache.v, cache.page_table,
-        table_row, chunk_start, total, positions, cache.page_size, mlp,
-        mesh=mesh,
-    )
+    def attend(li, win, q, k, v):
+        # both regions in ONE ragged launch
+        oc, og = read(
+            li, win,
+            q_chunk=q[:, :c], chunk_row=table_row, chunk_start=chunk_start,
+            chunk_total=total, k_chunk=k[0, :c], v_chunk=v[0, :c],
+            q_group=q[0, c:][:, None], page_table=cache.page_table,
+            group_lengths=positions, k_group=k[0, c:][:, None],
+            v_group=v[0, c:][:, None])
+        return jnp.concatenate([oc[0], og[:, 0]])
+
+    x, k_new, v_new, _ = stack(params["layers"], cfg, x, pos, attend, mlp)
+    k_new, v_new = k_new[:, 0], v_new[:, 0]  # [L, C+S, KVH, D]
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     chunk_logits = _unembed(
         cfg, params, x[0, jnp.maximum(chunk_len - 1, 0)]

@@ -21,12 +21,18 @@ out of it at every step: PERF.md, PR 42). ONE `lax.scan` over periods
 serves every phase, a phase being a pair of closures (`lin`, `att`) over
 `_stack`.
 
+The entry points are the ones an engine launches, and `validate_mesh`
+refuses every mesh: `hidden_states` (/api/embed), `decode_step`,
+`verify_step` and `mixed_step`, which admits every prompt chunk by chunk.
+There is no `prefill` / `prefill_chunk`: only an `sp` or `pp` engine calls
+those.
+
 Phases and the state (see RecurrentState): a CHUNK launch (a prompt's
-rows of one slot: `prefill`, `prefill_chunk`, the chunk region of
-`mixed_step`) starts from zeros at position 0, else from the slot's own
-state (carried from the last chunk launch, or a snapshot the engine
-restored), writes the state outright and hands back the state at up to
-two page boundaries it passes (`state_io`: the prefix cache's snapshots).
+rows of one slot: the chunk region of `mixed_step`) starts from zeros at
+position 0, else from the slot's own state (carried from the last chunk
+launch, or a snapshot the engine restored), writes the state outright and
+hands back the state at up to two page boundaries it passes (`state_io`:
+the prefix cache's snapshots).
 A STEP launch (`decode_step`, `verify_step`, the decode rows of
 `mixed_step`) commits the slot's pending rows first and leaves its own
 pending: `commit_verify` says how many of a verify launch's count.
@@ -346,13 +352,15 @@ def _save_snapshots(rec: RecurrentState, saved, save_idx) -> RecurrentState:
             tails.astype(rec.snap_conv.dtype), mode="drop"))
 
 
-def _chunk_launch(params: Params, cfg: ModelConfig, chunk_tokens, start,
-                  length, slot, table_row, cache: PagedKVCache, group=None,
-                  state_io=None, embeds=None):
-    """A chunk region, and with `group` = (tokens [S], active [S]) one
-    decode row a slot beside it: rows [0, c) the chunk, rows [c, c + S)
-    the slots'. Returns (final-norm x [c (+ S), E], the full layers' new
-    K and V [periods, c (+ S), H, D], rec)."""
+def mixed_step(params: Params, cfg: ModelConfig, chunk_tokens, chunk_start,
+               chunk_len, slot, table_row, tokens, cache, active, mesh=None,
+               embeds=None, state_io=None):
+    """One fused chunked-prefill + decode step (llama.mixed_step's
+    contract): rows [0, C) the admitting slot's chunk against its cached
+    prefix and its carried state, rows [C, C + S) one decode token a slot.
+    `state_io` = (positions [SAVES], snapshot entries [SAVES]): page
+    boundaries this chunk passes at which the state is saved."""
+    del mesh
     c = chunk_tokens.shape[0]
     block = gdn_block(cache.page_size)
     assert c % block == 0, f"a chunk of {c} rows is not whole blocks of {block}"
@@ -360,90 +368,36 @@ def _chunk_launch(params: Params, cfg: ModelConfig, chunk_tokens, start,
     save_pos, save_idx = state_io if state_io is not None else (none, none)
     dt = params["embed"].dtype
     xc = params["embed"][chunk_tokens] if embeds is None else embeds
-    x = xc.astype(dt)
-    total = start + length
-    if group is not None:
-        tokens, active = group
-        x = jnp.concatenate([x, params["embed"][tokens]])
-        positions = cache.lengths
-    x = x[None]
+    x = jnp.concatenate([xc.astype(dt), params["embed"][tokens]])[None]
+    total = chunk_start + chunk_len
+    positions = cache.lengths
 
     def lin(lp, li, x, rec):
         pre, gate, a, bb = _project(lp, x[0])
         o, rec, saved = _chunk_region(
-            cfg, lp, li, rec, (pre[:c], a[:c], bb[:c]), slot, start, length,
-            save_pos, block)
-        if group is not None:
-            og, rec = _step_region(
-                cfg, lp, li, rec,
-                (pre[c:, None], a[c:, None], bb[c:, None]), active)
-            o = jnp.concatenate([o, og[:, 0]])
+            cfg, lp, li, rec, (pre[:c], a[:c], bb[:c]), slot, chunk_start,
+            chunk_len, save_pos, block)
+        og, rec = _step_region(
+            cfg, lp, li, rec, (pre[c:, None], a[c:, None], bb[c:, None]),
+            active)
+        o = jnp.concatenate([o, og[:, 0]])
         return _gated_out(cfg, lp, o, gate, x.dtype)[None], rec, saved
 
     def att(lp, pi, x):
         q, k, v = _pool_heads(cfg, *_full_qkv(cfg, lp, x[0]))
-        regions = dict(
-            q_chunk=q[None, :c], chunk_row=table_row, chunk_start=start,
-            chunk_total=total, k_chunk=k[:c], v_chunk=v[:c])
-        if group is not None:
-            regions.update(
-                q_group=q[c:, None], page_table=cache.page_table,
-                group_lengths=positions, k_group=k[c:, None],
-                v_group=v[c:, None])
         oc, og = ragged_paged_attention(
             cache.k, cache.v, cache.page_size, layer=pi,
-            use_pallas=cfg.use_pallas, **regions)
-        o = oc[0] if og is None else jnp.concatenate([oc[0], og[:, 0]])
+            use_pallas=cfg.use_pallas,
+            q_chunk=q[None, :c], chunk_row=table_row, chunk_start=chunk_start,
+            chunk_total=total, k_chunk=k[:c], v_chunk=v[:c],
+            q_group=q[c:, None], page_table=cache.page_table,
+            group_lengths=positions, k_group=k[c:, None], v_group=v[c:, None])
+        o = jnp.concatenate([oc[0], og[:, 0]])
         return _attn_out(cfg, lp, o, x)[None], (k, v)
 
     x, rec, saved, (k_new, v_new) = _stack(params, cfg, x, cache.rec, lin, att)
     rec = _save_snapshots(rec, saved, save_idx)
-    return rms_norm(x[0], params["final_norm"], cfg.rms_eps), k_new, v_new, rec
-
-
-def prefill_chunk(params: Params, cfg: ModelConfig, tokens, start, length,
-                  cache, slot, table_row, mesh=None, embeds=None,
-                  state_io=None):
-    """Prefill ONE CHUNK of one slot against its cached prefix and its
-    carried state (llama.prefill_chunk's contract). `state_io` =
-    (positions [SAVES], snapshot entries [SAVES]): page boundaries this
-    chunk passes at which the state is saved."""
-    del mesh
-    x, k_new, v_new, rec = _chunk_launch(
-        params, cfg, tokens, start, length, slot, table_row, cache,
-        state_io=state_io, embeds=embeds)
-    logits = llama._unembed(cfg, params, x[jnp.maximum(length - 1, 0)])
-    k_pool, v_pool = write_prefill_all(
-        cache.k, cache.v, k_new, v_new, table_row, start, length,
-        cache.page_size, use_pallas=cfg.use_pallas)
-    rec = dataclasses.replace(rec, pend_n=rec.pend_n.at[slot].set(0))
-    return logits, dataclasses.replace(
-        cache, k=k_pool, v=v_pool, rec=rec,
-        page_table=cache.page_table.at[slot].set(table_row),
-        lengths=cache.lengths.at[slot].set(start + length))
-
-
-def prefill(params: Params, cfg: ModelConfig, tokens, length, cache, slot,
-            table_row, attn=None, mesh=None, embeds=None):
-    """Prefill ONE slot from an empty prefix (llama.prefill's contract):
-    the chunk launch at position 0."""
-    del attn
-    return prefill_chunk(params, cfg, tokens, jnp.int32(0), length, cache,
-                         slot, table_row, mesh=mesh, embeds=embeds)
-
-
-def mixed_step(params: Params, cfg: ModelConfig, chunk_tokens, chunk_start,
-               chunk_len, slot, table_row, tokens, cache, active, mesh=None,
-               embeds=None, state_io=None):
-    """One fused chunked-prefill + decode step (llama.mixed_step's
-    contract): rows [0, C) the admitting slot's chunk, rows [C, C + S) one
-    decode token a slot."""
-    del mesh
-    c = chunk_tokens.shape[0]
-    positions = cache.lengths
-    x, k_new, v_new, rec = _chunk_launch(
-        params, cfg, chunk_tokens, chunk_start, chunk_len, slot, table_row,
-        cache, group=(tokens, active), state_io=state_io, embeds=embeds)
+    x = rms_norm(x[0], params["final_norm"], cfg.rms_eps)
     chunk_logits = llama._unembed(cfg, params, x[jnp.maximum(chunk_len - 1, 0)])
     dec_logits = llama._unembed(cfg, params, x[c:])
     k_pool, v_pool = write_prefill_all(
@@ -456,7 +410,7 @@ def mixed_step(params: Params, cfg: ModelConfig, chunk_tokens, chunk_start,
         rec, pend_n=active.astype(jnp.int32).at[slot].set(0))
     new_lengths = jnp.minimum(
         cache.lengths + active.astype(jnp.int32), cache.max_context
-    ).at[slot].set(chunk_start + chunk_len)
+    ).at[slot].set(total)
     return chunk_logits, dec_logits, dataclasses.replace(
         cache, k=k_pool, v=v_pool, rec=rec,
         page_table=cache.page_table.at[slot].set(table_row),

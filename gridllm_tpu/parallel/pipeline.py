@@ -171,9 +171,10 @@ def decode_step(
 
         def stage(args):
             x, kp, vp = args
-            x, k_new, v_new, _ = llama.decode_layers(
-                params["layers"], cfg, x, kp, vp, page_table, positions,
-                cache.page_size, mlp,
+            x, k_new, v_new, _ = llama.stack(
+                params["layers"], cfg, x, positions,
+                llama.group_attend(cfg, kp, vp, cache.page_size, page_table,
+                                   positions), mlp,
             )
             # Pallas stays off here regardless of cfg.use_pallas: the auto
             # axes inside this partial-manual region (tp/ep) still go
@@ -214,9 +215,10 @@ def decode_step(
             off = m * m_sz
             pt = jax.lax.dynamic_slice_in_dim(page_table, off, m_sz)
             pos = jax.lax.dynamic_slice_in_dim(positions, off, m_sz)
-            return llama.decode_layers(
-                params["layers"], cfg, x_in, k_pool, v_pool, pt, pos,
-                cache.page_size, mlp,
+            return llama.stack(
+                params["layers"], cfg, x_in, pos,
+                llama.group_attend(cfg, k_pool, v_pool, cache.page_size, pt,
+                                   pos), mlp,
             )[:3]
 
         for t in range(2 * pp - 1):  # static unroll: pipeline schedule
@@ -310,11 +312,14 @@ def prefill(
 
         def stage(args):
             x, kp, vp = args
-            x, k_new, v_new = llama.prefill_layers(
-                params["layers"], cfg, x, length[None], mlp,
+            x, k_new, v_new, _ = llama.stack(
+                params["layers"], cfg, x,
+                jnp.arange(x.shape[1], dtype=jnp.int32)[None],
+                llama.whole_attend(cfg, length[None]), mlp,
             )
             kp, vp = write_prefill_all(
-                kp, vp, k_new, v_new, table_row, jnp.int32(0), length,
+                kp, vp, k_new[:, 0], v_new[:, 0], table_row, jnp.int32(0),
+                length,
                 cache.page_size, use_pallas=False,  # see decode_step note
             )
             return x, kp, vp
@@ -369,12 +374,14 @@ def prefill_chunk(
 
         def stage(args):
             x, kp, vp = args
-            x, k_new, v_new = llama.prefill_chunk_layers(
-                params["layers"], cfg, x, kp, vp, table_row, start, length,
-                cache.page_size, mlp,
+            x, k_new, v_new, _ = llama.stack(
+                params["layers"], cfg, x,
+                (start + jnp.arange(x.shape[1], dtype=jnp.int32))[None],
+                llama.chunk_attend(cfg, kp, vp, cache.page_size, table_row,
+                                   start, start + length), mlp,
             )
             kp, vp = write_prefill_all(
-                kp, vp, k_new, v_new, table_row, start, length,
+                kp, vp, k_new[:, 0], v_new[:, 0], table_row, start, length,
                 cache.page_size, use_pallas=False,  # see decode_step note
             )
             return x, kp, vp

@@ -78,7 +78,7 @@ def compile_cache_dir() -> str:
     that itself and the program sets no other — else one fixed directory
     inside the checkout, ignored by git. Fixed because the path is part
     of the cache key: a directory that moves never hits. Every process
-    of the program (workers, tests, bench.py, chip_smoke.py) resolves it
+    of the program (workers, tests, chip_smoke.py) resolves it
     here, so they share one cache."""
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
         os.path.dirname(os.path.dirname(os.path.dirname(

@@ -37,7 +37,7 @@ def resolve_checkpoint(root: str | None, model: str) -> tuple[str | None, str | 
     """(checkpoint_path, tokenizer_path) for `model` under a checkpoint
     root: weights at {root}/{name-with-:-replaced-by-_}, tokenizer either
     in a tokenizer/ subdir or alongside the weights. Single source of
-    truth — bench.py resolves real-checkpoint runs through this too."""
+    truth."""
     if not root:
         return None, None
     cand = os.path.join(root, model.replace(":", "_"))

@@ -288,11 +288,9 @@ def json_instruction(fmt: Any) -> str:
     """The soft constraint appended for format requests.
 
     NOTE: this instruction + extract_json below are the ENTIRE
-    ``format:"json"`` enforcement today. engine/jsonmask.py holds an
-    experimental grammar PDA for true per-step constrained decoding, but
-    it is NOT wired — the sampler has no vocabulary-mask hook — so output
-    that parses is best-effort, not guaranteed (see jsonmask's module
-    docstring before assuming otherwise)."""
+    ``format:"json"`` enforcement today: the sampler has no
+    vocabulary-mask hook, so output that parses is best-effort, not
+    guaranteed (ROADMAP M9)."""
     if isinstance(fmt, dict):
         return (
             "\nRespond ONLY with JSON matching this JSON schema, with no "

@@ -204,15 +204,3 @@ def test_compile_cache_follows_jax_env(tmp_path):
 
 def test_compile_cache_fixed_path_in_checkout():
     assert _cache_dirs(None) == [os.path.join(REPO, ".jax_cache")] * 2
-
-
-def test_bench_without_tiny_refuses_on_cpu():
-    """bench.py measures a TPU or nothing: no CPU fallback, no model
-    substitution — one JSON line with the error, and a nonzero exit."""
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO}
-    out = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                         env=env, capture_output=True, text=True, timeout=300)
-    assert out.returncode == 2
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert "no TPU" in line["error"] and line["platform"] == "cpu"
-    assert line["value"] == 0.0

@@ -76,15 +76,17 @@ def _rows(n_tokens=128):
     return [jnp.asarray(alloc.table_row(s), jnp.int32) for s in (0, 1)]
 
 
-def _chunks(params, toks, cache, slot, row, width=32):
-    """A prompt through `prefill_chunk`, `width` rows a launch."""
-    for s0 in range(0, len(toks), width):
+def _chunks(params, toks, cache, slot, row, width=32, start=0):
+    """A prompt (its rows from `start` on) admitted as the engine admits
+    it, through `mixed_step` with no active slot, `width` rows a launch."""
+    idle = jnp.zeros(cache.lengths.shape, jnp.int32)
+    for s0 in range(start, len(toks), width):
         part = toks[s0:s0 + width]
         chunk = jnp.zeros((width,), jnp.int32).at[:len(part)].set(
             jnp.asarray(part))
-        logits, cache = deepseek.prefill_chunk(
-            params, CFG, chunk, jnp.int32(s0), jnp.int32(len(part)), cache,
-            jnp.int32(slot), row)
+        logits, _, cache = deepseek.mixed_step(
+            params, CFG, chunk, jnp.int32(s0), jnp.int32(len(part)),
+            jnp.int32(slot), row, idle, cache, idle > 0)
     return logits, cache
 
 
@@ -176,9 +178,7 @@ def test_prefill_then_decode_through_the_latent_pool(params):
     toks = _tokens(48, seed=1)
     n = 40                                   # past two pages of 16
     row = _rows()[0]
-    padded = jnp.zeros((64,), jnp.int32).at[:n].set(jnp.asarray(toks[:n]))
-    logits, cache = deepseek.prefill(
-        params, CFG, padded, jnp.int32(n), _cache(), jnp.int32(0), row)
+    logits, cache = _chunks(params, toks[:n], _cache(), 0, row, width=64)
     want = _ref(params, toks)
     assert np.abs(np.asarray(logits) - want[n - 1]).max() < TOL
     # the pool holds ONE row of latent + rope key a token a layer, and no V
@@ -229,10 +229,7 @@ def test_a_prefix_cache_admission_reads_anothers_latent_pages(params):
     alloc.alloc(1, len(b) + 8)
     row_b = jnp.asarray(alloc.table_row(1), jnp.int32)
     assert row_b[:2].tolist() == row_a[:2].tolist()
-    chunk = jnp.zeros((32,), jnp.int32).at[:len(tail_b)].set(jnp.asarray(tail_b))
-    logits, cache = deepseek.prefill_chunk(
-        params, CFG, chunk, jnp.int32(32), jnp.int32(len(tail_b)), cache,
-        jnp.int32(1), row_b)
+    logits, cache = _chunks(params, b, cache, 1, row_b, start=32)
     assert np.abs(np.asarray(logits) - _ref(params, b)[-1]).max() < TOL
 
 
@@ -489,10 +486,12 @@ def test_mosaic_refuses_a_row_stored_at_576_lanes(one_chip):
 # a routed family that is not this one traces its verify program as it did
 # before the shared expert, the un-renormalised weights and the latent
 # pool were threaded through mixtral._moe_mlp and the ops: the hash was
-# taken on the parent commit (965a97a). tests/test_smallthinker.py holds a
-# dense family's the same way.
+# taken on the parent commit (965a97a, bd1c63d7...) and again in PR 49,
+# whose one layer body traces the same equations with the RoPE frequencies
+# computed after the positions and not before.
+# tests/test_smallthinker.py holds a dense family's the same way.
 ROUTED_VERIFY_JAXPR = (
-    "bd1c63d797e945939e1b8362e9e9418f6788c49eab68436cc326ffb18dc0bae4")
+    "ef5657d56692359fb7d823c51591838c85192b4299fc76a217c3ab1cca2bbb0f")
 
 
 def routed_verify_jaxpr() -> str:

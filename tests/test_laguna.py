@@ -83,16 +83,18 @@ def _rows(n_tokens=256):
 
 def _chunks(params, toks, cache, slot, row, width=CHUNK, start=0,
             state_io=None):
-    """A prompt through `prefill_chunk`, `width` rows a launch; every
-    launch's last logits."""
+    """A prompt admitted as the engine admits it, through `mixed_step`
+    with no active slot, `width` rows a launch; every launch's last
+    logits."""
     out = []
+    idle = jnp.zeros(cache.lengths.shape, jnp.int32)
     for s0 in range(start, len(toks), width):
         part = toks[s0:s0 + width]
         chunk = jnp.zeros((width,), jnp.int32).at[:len(part)].set(
             jnp.asarray(part))
-        logits, cache = lg.prefill_chunk(
-            params, CFG, chunk, jnp.int32(s0), jnp.int32(len(part)), cache,
-            jnp.int32(slot), row, state_io=state_io)
+        logits, _, cache = lg.mixed_step(
+            params, CFG, chunk, jnp.int32(s0), jnp.int32(len(part)),
+            jnp.int32(slot), row, idle, cache, idle > 0, state_io=state_io)
         out.append(np.asarray(logits))
     return out, cache
 
@@ -212,11 +214,9 @@ def test_prefill_then_decode_through_both_caches(params, ref_logits):
     """A prompt of one launch, then token by token past the window and
     twice round the ring (64 rows): every step's logits."""
     rows = _rows()
-    logits, cache = lg.prefill(
-        params, CFG, jnp.zeros((CHUNK,), jnp.int32).at[:20].set(
-            jnp.asarray(TOKENS[:20])), jnp.int32(20), _cache(), jnp.int32(0),
-        rows[0])
-    assert np.abs(np.asarray(logits) - ref_logits[19]).max() < TOL
+    (logits,), cache = _chunks(
+        params, list(TOKENS[:20]), _cache(), 0, rows[0])
+    assert np.abs(logits - ref_logits[19]).max() < TOL
     active = jnp.asarray([True, False])
     step = jax.jit(lambda tok, cache: lg.decode_step(
         params, CFG, tok, cache, active))

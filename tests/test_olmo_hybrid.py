@@ -84,14 +84,16 @@ def _rows(n_tokens=128):
 
 
 def _chunks(params, toks, cache, slot, row, width, start=0, state_io=None):
-    """A prompt through `prefill_chunk`, `width` rows a launch."""
+    """A prompt admitted as the engine admits it, through `mixed_step`
+    with no active slot, `width` rows a launch."""
+    idle = jnp.zeros(cache.lengths.shape, jnp.int32)
     for s0 in range(start, len(toks), width):
         part = toks[s0:s0 + width]
         chunk = jnp.zeros((width,), jnp.int32).at[:len(part)].set(
             jnp.asarray(part))
-        logits, cache = oh.prefill_chunk(
-            params, CFG, chunk, jnp.int32(s0), jnp.int32(len(part)), cache,
-            jnp.int32(slot), row, state_io=state_io)
+        logits, _, cache = oh.mixed_step(
+            params, CFG, chunk, jnp.int32(s0), jnp.int32(len(part)),
+            jnp.int32(slot), row, idle, cache, idle > 0, state_io=state_io)
     return logits, cache
 
 

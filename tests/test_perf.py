@@ -2,11 +2,8 @@
 semantics (steady-state decode is recompile-free; an unseen shape bucket
 counts exactly once with the right labels and a flight-recorder event),
 device-memory accounting math on the CPU backend, the /admin/memory and
-/admin/profile endpoints, profiler-capture lifecycle, bench record
-comparison, and the jsonmask experimental/import-clean satellite."""
+/admin/profile endpoints and the profiler-capture lifecycle."""
 
-import importlib
-import json
 import os
 import time
 
@@ -379,98 +376,3 @@ def test_watchdog_hang_capture(tmp_path, monkeypatch):
     _wait_capture_done(default_profiler())
     wd_off = HangWatchdog(_Sched(), WatchdogConfig(profile_on_hang_s=0))
     assert wd_off._profile_hang("decode-step") is None
-
-
-# ---------------------------------------------------------------------------
-# bench record comparison (--emit / --compare)
-# ---------------------------------------------------------------------------
-
-
-def _rec(**metrics):
-    return {"schema": "gridllm-bench/v1", "scenario": "generate",
-            "model": "tiny-llama", "platform": "cpu", "metrics": metrics}
-
-
-def test_compare_records_flags_both_directions():
-    import bench
-
-    old = _rec(tok_s=100.0, p50_ttft_ms=50.0, recompiles_steady=0)
-    ok, _ = bench.compare_records(old, _rec(tok_s=95.0, p50_ttft_ms=54.0,
-                                            recompiles_steady=0))
-    assert ok == []
-    regs, _ = bench.compare_records(old, _rec(tok_s=80.0, p50_ttft_ms=50.0,
-                                              recompiles_steady=0))
-    assert any("tok_s" in r for r in regs)
-    regs, _ = bench.compare_records(old, _rec(tok_s=100.0, p50_ttft_ms=60.0,
-                                              recompiles_steady=0))
-    assert any("p50_ttft_ms" in r for r in regs)
-    # recompiles have zero tolerance — 0 -> 1 is a regression outright
-    regs, _ = bench.compare_records(old, _rec(tok_s=100.0, p50_ttft_ms=50.0,
-                                              recompiles_steady=1))
-    assert any("recompiles_steady" in r for r in regs)
-
-
-def test_compare_records_skips_mismatched_runs():
-    import bench
-
-    old = _rec(tok_s=100.0)
-    new = _rec(tok_s=10.0)
-    new["platform"] = "tpu"
-    regs, notes = bench.compare_records(old, new)
-    assert regs == [] and any("mismatch" in n for n in notes)
-
-
-def test_build_record_schema():
-    import bench
-
-    class _Args:
-        model = "tiny-llama"
-        requests, tokens, slots, prompt_len = 2, 8, 4, 20
-
-    payload = {"value": 42.0, "platform": "cpu", "tok_s": 42.0,
-               "p50_ttft_ms": 10.0, "degraded": False}
-    r = {"perf": {"recompiles_steady": 0, "recompiles_warmup": 3,
-                  "recompiles_by_fn": {}, "peak_hbm_bytes": 1024}}
-    rec = bench.build_record("generate", _Args(), payload, r)
-    assert rec["schema"] == bench.BENCH_SCHEMA
-    assert rec["metrics"]["recompiles_steady"] == 0
-    assert rec["metrics"]["peak_hbm_bytes"] == 1024
-    assert rec["metrics"]["tok_s"] == 42.0
-    json.dumps(rec)  # must be serializable as written
-
-
-# ---------------------------------------------------------------------------
-# jsonmask satellite: explicitly experimental, stays import-clean
-# ---------------------------------------------------------------------------
-
-
-def test_jsonmask_is_marked_experimental_and_import_clean():
-    """engine/jsonmask.py is unwired groundwork (no sampler mask hook
-    exists): its docstring must say so, and importing it must stay
-    side-effect-free — no metrics registered, no jit, no engine imports —
-    so it can never silently become load-bearing at collection time."""
-    from gridllm_tpu.obs import default_registry
-
-    reg = default_registry()
-    with reg._lock:
-        metrics_before = set(reg._metrics)
-    mod = importlib.import_module("gridllm_tpu.engine.jsonmask")
-    mod = importlib.reload(mod)
-    assert "EXPERIMENTAL" in mod.__doc__ and "NOT INTEGRATED" in mod.__doc__
-    with reg._lock:
-        assert set(reg._metrics) == metrics_before
-    # nothing in the package imports it: the guarantee must not be
-    # assumed delivered anywhere in the serving path
-    import subprocess
-    import sys
-
-    probe = subprocess.run(
-        [sys.executable, "-c",
-         "import sys; import gridllm_tpu.worker.service, "
-         "gridllm_tpu.engine.engine, gridllm_tpu.ops.sampling; "
-         "sys.exit(1 if 'gridllm_tpu.engine.jsonmask' in sys.modules "
-         "else 0)"],
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        capture_output=True, text=True, timeout=240,
-    )
-    assert probe.returncode == 0, probe.stderr[-500:]

@@ -215,13 +215,15 @@ def test_the_router_reads_the_pre_attention_state(params):
 
 
 # sha256 of the jaxpr text of llama.verify_step for tiny-mistral, [2, 5]
-# candidates, as the commit before SmallThinker (affe6c5) traced it. A
-# dense family's programs carry no per-layer kind, no router tap and no
-# statistics: this PR's threading of them must not have touched what the
-# accepted cells run. A later PR that changes the dense verify program on
-# purpose computes the hash anew.
+# candidates. A dense family's programs carry no per-layer kind, no router
+# tap and no statistics: SmallThinker's threading of them must not touch
+# what the accepted cells run. A later PR that changes the dense verify
+# program on purpose computes the hash anew: PR 49 (one layer body, one
+# scan) did, for the same equations as the commit before SmallThinker
+# (affe6c5, 3dd20173...) traced, the RoPE frequencies computed after the
+# positions and not before.
 DENSE_VERIFY_JAXPR = (
-    "3dd20173e773db95440bb383ece8b4d5e2e1a6f9efb05ecb1b04879eb1676178")
+    "52ffbf9397e154bc2d4a55f51337386b008fd6ad9cbc0b88d05bf8ef6774de38")
 
 
 def dense_verify_jaxpr() -> str:
