@@ -13,6 +13,16 @@ states at its custom call would read several times that.
 
     python deploy/tpu_gdn_forms.py --commit [--periods 1] [--dtype bfloat16]
 
+    python deploy/tpu_gdn_forms.py --model kimi-linear:48b --layers 6 \
+                                   --rows 512 --oracle 4096
+
+A model whose decay is a value a key channel (``linear_channel_decay``:
+Kimi Delta Attention) runs the same forms under their own names
+(`kda_chunk`, `kda_step`), with log decays drawn as the family's seeded
+leaves give them (down to -48 a row). ``--oracle N`` first holds both
+forms of the chunk over N rows, and of the step, to the token-by-token
+recurrence on the device.
+
 ``--commit`` is speculation's commit through the MODEL at full width (the
 benchmark's cell never accepts a draft: random weights on random bytes do
 not repeat, so its verify launches keep one row and `correct` never sees
@@ -57,12 +67,56 @@ def timed(fn, args, reps: int) -> tuple[float, float]:
     return 1e3 * statistics.median(ts), 1e3 * min(ts)
 
 
-def rows(rng, lead, h, dk, dv):
+def rows(rng, lead, h, dk, dv, channel=False):
     def n(*shape):
         return jnp.asarray(rng.normal(size=(*lead, *shape)), jnp.float32)
 
     q, k = la.l2norm(n(h, dk)) * dk ** -0.5, la.l2norm(n(h, dk))
-    return q, k, n(h, dv), 2 * jax.nn.sigmoid(n(h)), -0.1 * jnp.exp(n(h))
+    if not channel:
+        return q, k, n(h, dv), 2 * jax.nn.sigmoid(n(h)), -0.1 * jnp.exp(n(h))
+    # the family's seeded leaves: A in (0.001, 16) a head, a step through
+    # softplus of a pre-activation that spreads about +-3 a channel
+    a = jnp.asarray(rng.uniform(1e-3, 16.0, size=(h, 1)), jnp.float32)
+    return q, k, n(h, dv), jax.nn.sigmoid(n(h)), -a * jax.nn.softplus(
+        n(h, dk) - 3.0)
+
+
+def oracle_check(args, cfg, chunk_op, step_op) -> None:
+    """Both forms of each op against the token-by-token recurrence."""
+    h, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    rng = np.random.default_rng(1)
+    r = rows(rng, (args.oracle,), h, dk, dv, cfg.linear_channel_decay)
+    print(f"oracle: {args.oracle} rows, log decay down to "
+          f"{float(r[4].min()):.1f} a row, below -5 in "
+          f"{100 * float((r[4] < -5).mean()):.1f} % of its values")
+    s0 = jnp.asarray(rng.normal(size=(h, dk, dv)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want_o, want_s = jax.jit(la.gdn_recurrent)(s0, *r)
+    keep = jnp.asarray([1, -1], jnp.int32)
+    for kernel in (True, False):
+        o, s1, _ = jax.jit(lambda s, *r, kernel=kernel: chunk_op(
+            s, *r, keep, args.block, use_pallas=kernel))(la.pack(s0), *r)
+        print(f"  chunk {'kernel' if kernel else 'jnp   '}: o apart "
+              f"{float(jnp.abs(o - want_o).max()):.2e} (largest "
+              f"{float(jnp.abs(want_o).max()):.2f}), state apart "
+              f"{float(jnp.abs(la.unpack(s1, h) - want_s).max()):.2e} "
+              f"(largest {float(jnp.abs(want_s).max()):.2f}), finite "
+              f"{bool(jnp.isfinite(o).all())}")
+    s, t = args.slots, args.step_rows
+    new = rows(rng, (s, t), h, dk, dv, cfg.linear_channel_decay)
+    pend = rows(rng, (s, t), h, dk, dv, cfg.linear_channel_decay)
+    states = jnp.asarray(rng.normal(size=(2, s, dk, h * dv)), jnp.float32)
+    n = jnp.asarray(rng.integers(0, t + 1, size=(s,)), jnp.int32)
+    live = jnp.arange(s) % 4 != 3
+    got = {}
+    for kernel in (True, False):
+        got[kernel] = jax.jit(lambda st, kernel=kernel: step_op(
+            st, 1, pend[1:], n, *new, live, use_pallas=kernel))(states)
+    print(f"  step kernel against jnp (the recurrence itself): states apart "
+          f"{float(jnp.abs(got[True][0] - got[False][0]).max()):.2e}, o apart "
+          f"{float(jnp.abs(got[True][1] - got[False][1]).max()):.2e} (largest "
+          f"{float(jnp.abs(got[False][1]).max()):.2f})")
 
 
 def commit_check(args) -> None:
@@ -160,12 +214,18 @@ def main() -> None:
     ap.add_argument("--page", type=int, default=128)
     ap.add_argument("--chunk", type=int, default=256)
     ap.add_argument("--prompt", type=int, default=300)
+    ap.add_argument("--oracle", type=int, default=0)
     args = ap.parse_args()
     if args.commit:
         return commit_check(args)
     cfg = get_config(args.model)
     h, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
                  cfg.linear_value_head_dim)
+    channel = cfg.linear_channel_decay
+    chunk_op, step_op = ((la.kda_chunk, la.kda_step) if channel
+                         else (la.gdn_chunk, la.gdn_step))
+    if args.oracle:
+        oracle_check(args, cfg, chunk_op, step_op)
     rng = np.random.default_rng(0)
     n_l, s, t = args.layers, args.slots, args.step_rows
     print(f"{cfg.name}: {h} heads, keys {dk}, values {dv}, {n_l} layers, "
@@ -175,13 +235,13 @@ def main() -> None:
     keep = jnp.asarray([1, -1], jnp.int32)
     forms = []
     for width in (int(w) for w in args.rows.split(",")):
-        r = rows(rng, (width,), h, dk, dv)
+        r = rows(rng, (width,), h, dk, dv, channel)
         for kernel in (True, False):
             def chunk(states, *r, kernel=kernel):
                 def one(states, li):
                     # rows that differ by layer: nothing hoists out
                     q, k, v, b, g = r
-                    o, s1, kept = la.gdn_chunk(
+                    o, s1, kept = chunk_op(
                         states[li, 0], q, k, v * (1.0 + 0.01 * li), b, g,
                         keep, args.block, use_pallas=kernel)
                     return states.at[li, 0].set(s1), (o.sum(), kept.sum())
@@ -189,8 +249,8 @@ def main() -> None:
             forms.append((f"chunk {width:5d} rows {'kernel' if kernel else 'jnp   '}",
                           jax.jit(chunk, donate_argnums=(0,)), r,
                           0.0, n_l * width * h * 7.0 * dk * dv))
-    new = rows(rng, (s, t), h, dk, dv)
-    pend = rows(rng, (s, t), h, dk, dv)[1:]
+    new = rows(rng, (s, t), h, dk, dv, channel)
+    pend = rows(rng, (s, t), h, dk, dv, channel)[1:]
     for live in sorted({s, max(s // 2, 1)}, reverse=True):
         n = jnp.where(jnp.arange(s) < live, 3, 0)
         alive = jnp.arange(s) < live
@@ -199,7 +259,7 @@ def main() -> None:
                 def one(states, li):
                     q, k, v, b, g = new
                     scale = 1.0 + 0.01 * li
-                    states, o = la.gdn_step(
+                    states, o = step_op(
                         states, li, (pend[0], pend[1] * scale, *pend[2:]), n,
                         q, k, v * scale, b, g, alive, use_pallas=kernel)
                     return states, o.sum()

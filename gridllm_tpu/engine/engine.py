@@ -78,6 +78,7 @@ from gridllm_tpu.obs.perf import (
     MOE_EXPERT_ROWS_TOTAL,
     MOE_EXPERTS_TOUCHED_TOTAL,
     MOE_FORM_ROWS_TOTAL,
+    MOE_PICKS_TOTAL,
     VERIFY_CTX_TOKENS_TOTAL,
     VERIFY_WINDOW_TOKENS_TOTAL,
     XLA_COMPILE_SECONDS,
@@ -385,6 +386,10 @@ def _model_module(cfg: ModelConfig):
         from gridllm_tpu.models import laguna
 
         return laguna
+    if cfg.family == "kimi_linear":
+        from gridllm_tpu.models import kimi_linear
+
+        return kimi_linear
     if cfg.family == "bert_embed":
         from gridllm_tpu.models import bert_embed
 
@@ -1443,7 +1448,8 @@ class InferenceEngine:
         # K and V per head at the model's own head sizes: a latent family
         # would store a key of head_dim and a value of v_head_dim a head
         _KV_ROW_BYTES_EQUIV.set(
-            (mc.num_heads * (mc.head_dim_ + mc.v_head_dim) if mc.kv_lora_rank
+            (mc.num_heads * (mc.qk_nope_head_dim + mc.qk_rope_head_dim
+                             + mc.v_head_dim) if mc.kv_lora_rank
              else mc.kv_row_values) * itemsize, model=mc.name)
         log.info("kv pool rows", model=mc.name, cacheRow=kind,
                  kvRowBytes=row_bytes, rowValues=mc.kv_row_values,
@@ -3016,14 +3022,26 @@ class InferenceEngine:
                 self.cfg, rows, self.mesh)
         MOE_FORM_ROWS_TOTAL.inc(rows, model=self.cfg.name, form=form,
                                 launch=launch)
-        return {"expert_form": form}
+        if self.cfg.experts_held is None:
+            return {"expert_form": form}
+        # a share: the experts held here of the router's width
+        return {"expert_form": form,
+                "experts_held": str(self.cfg.experts_held),
+                "experts_of": str(self.cfg.num_experts)}
 
     def _count_step_stats(self, stats: np.ndarray) -> None:
         """A launch's [live rows routed, experts touched] (summed over
-        layers), from the launch's own fetch; empty for a dense family."""
+        layers), from the launch's own fetch; empty for a dense family.
+        A share of the experts (`cfg.experts_held`) adds the live rows'
+        picks [on held experts, on absent ones]."""
         if len(stats) >= 2:
             MOE_EXPERT_ROWS_TOTAL.inc(int(stats[0]), model=self.cfg.name)
             MOE_EXPERTS_TOUCHED_TOTAL.inc(int(stats[1]), model=self.cfg.name)
+        if len(stats) >= 4:
+            MOE_PICKS_TOTAL.inc(int(stats[2]), model=self.cfg.name,
+                                where="held")
+            MOE_PICKS_TOTAL.inc(int(stats[3]), model=self.cfg.name,
+                                where="absent")
 
     def _mark_ingest(self) -> None:
         """Leave ``fetch`` for ``ingest``, right after the device_get

@@ -86,6 +86,12 @@ def load_checkpoint(
     from gridllm_tpu.models import hf_layout
     from gridllm_tpu.ops.quant import NO_QUANT_SUBTREES, quantize_np_leaf
 
+    if cfg.family == "kimi_linear":
+        raise NotImplementedError(
+            f"{cfg.name}: kimi_linear checkpoints are not read (its "
+            "equations are written from the published keys and the report, "
+            "the tensor names of no modeling file are here): it is served "
+            "on seeded weights")
     idx = _open_safetensors(path)
 
     def place(pathkeys: tuple[str, ...], arr: np.ndarray):

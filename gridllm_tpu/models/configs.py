@@ -47,7 +47,7 @@ class VisionConfig:
 class ModelConfig:
     name: str
     # llama | qwen2 | qwen3 | gemma2 | mixtral | smallthinker |
-    # deepseek_v2 | olmo_hybrid | laguna | llava | bert_embed
+    # deepseek_v2 | olmo_hybrid | laguna | kimi_linear | llava | bert_embed
     # (engine._model_module picks the module)
     family: str = "llama"
     vocab_size: int = 128_256
@@ -80,6 +80,16 @@ class ModelConfig:
     # what the router scores the experts with: "softmax" over all of them,
     # or "sigmoid" of each (laguna)
     router_score: str = "softmax"
+    # a selection bias beside the scores (kimi_linear; the sigmoid gate's
+    # e_score_correction_bias): added to CHOOSE the top-k, never to weigh
+    router_bias: bool = False
+    # the experts this chip holds of each routed layer: `experts_held` of
+    # them from index `experts_first` (None = all of num_experts). The
+    # router keeps num_experts outputs and its top-k; a pick of an expert
+    # that is not held contributes nothing here (it lives on another chip
+    # of the expert-parallel group, whose exchange is not run)
+    experts_held: int | None = None
+    experts_first: int | None = None
     # latent attention (MLA, deepseek_v2): the cache row of a token in a
     # layer is ONE latent of kv_lora_rank and one RoPE key of
     # qk_rope_head_dim, shared by every head (`cache_heads`, `cache_dim`);
@@ -95,18 +105,22 @@ class ModelConfig:
     # layers of different MIXERS (olmo_hybrid), one entry a layer:
     # "linear_attention" (a gated delta rule: no pages, a recurrent state
     # a slot) or "full_attention" (pages); () = every layer attends. The
-    # pattern is whole periods that end in a full layer (`layer_period`).
+    # pattern is whole periods that end in a full layer, and perhaps a
+    # shorter last one (`layer_period`, `layer_tail`).
     # A linear layer has linear_num_heads heads with keys of
     # linear_key_head_dim and values of linear_value_head_dim behind a
     # depthwise causal convolution of linear_conv_kernel taps;
-    # linear_allow_neg_eigval doubles beta to (0, 2). rope_theta 0 = no
-    # rotary embedding anywhere
+    # linear_allow_neg_eigval doubles beta to (0, 2);
+    # linear_channel_decay: the log decay is a value a head a KEY CHANNEL
+    # (Kimi Delta Attention), not one a head. rope_theta 0 = no rotary
+    # embedding anywhere
     layer_types: tuple[str, ...] = ()
     linear_num_heads: int = 0
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel: int = 0
     linear_allow_neg_eigval: bool = False
+    linear_channel_decay: bool = False
     # attention variants
     attn_logit_softcap: float = 0.0
     sliding_window: int = 0          # 0 → full attention
@@ -155,6 +169,12 @@ class ModelConfig:
                     f"{self.name}: {name} has {len(layout)} entries for "
                     f"{self.num_layers} layers")
             object.__setattr__(self, name, layout[:self.num_layers])
+        first, held = self.held_experts
+        if self.experts_held is not None and not (
+                0 <= first and 0 < held and first + held <= self.num_experts):
+            raise ValueError(
+                f"{self.name}: experts [{self.experts_first}, +"
+                f"{self.experts_held}) are not among {self.num_experts}")
 
     @property
     def head_dim_(self) -> int:
@@ -244,18 +264,36 @@ class ModelConfig:
     @property
     def layer_period(self) -> int:
         """Length of the mixers' repeating pattern: linear layers then one
-        full layer (1 = every layer attends). Anything else is refused."""
+        full layer (1 = every layer attends), in whole periods and perhaps
+        a shorter last one of the same form (`layer_tail` layers: fewer
+        linear layers, then a full one). Anything else is refused."""
         if not self.layer_types:
             return 1
         p = self.layer_types.index("full_attention") + 1 if (
             "full_attention" in self.layer_types) else 0
-        one = ("linear_attention",) * (p - 1) + ("full_attention",)
-        if not p or self.num_layers % p or (
-                self.layer_types != one * (self.num_layers // p)):
+        lin, full = ("linear_attention",), ("full_attention",)
+        n, tail = divmod(self.num_layers, p) if p else (0, 0)
+        want = (lin * (p - 1) + full) * n + (
+            lin * (tail - 1) + full if tail else ())
+        if not p or self.layer_types != want:
             raise ValueError(
                 f"{self.name}: layer_types is not whole periods of linear "
-                f"layers ending in a full one: {self.layer_types}")
+                "layers ending in a full one (the last perhaps shorter): "
+                f"{self.layer_types}")
         return p
+
+    @property
+    def layer_tail(self) -> int:
+        """Layers of the shorter last period (0: whole periods only)."""
+        return self.num_layers % self.layer_period
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the experts this chip holds of a routed
+        layer: all of them unless `experts_held` says otherwise."""
+        if self.experts_held is None:
+            return 0, self.num_experts
+        return self.experts_first or 0, self.experts_held
 
     @property
     def conv_channels(self) -> int:
@@ -285,7 +323,7 @@ class ModelConfig:
             attention_bias=False,
         )
         if self.family in ("smallthinker", "deepseek_v2", "olmo_hybrid",
-                           "laguna"):
+                           "laguna", "kimi_linear"):
             raise NotImplementedError(
                 f"{self.family} has no transformers twin here: its "
                 "reference is under benchmark/reference/")
@@ -574,6 +612,36 @@ register(ModelConfig(
     sliding_window=512, window_layout=_LAGUNA_LAYOUT * 10,
     heads_layout=(48, 64, 64, 64) * 10,
 ))
+# Kimi-Linear-48B-A3B-Instruct (moonshotai, config.json; arXiv:2510.26692):
+# Kimi Delta Attention (a delta rule whose decay is a value a head a key
+# channel, 32 heads of 128 behind a convolution of 4) 3:1 with latent
+# attention without positions (MLA, mla_use_nope: 32 heads of 128 + 64, a
+# latent of 512, no q_lora); published full_attn_layers 4, 8, .., 24, 27
+# (1-based): six periods of four and a last one of three. Layer 1 is a KDA
+# layer with a dense SwiGLU of 9,216; then 256 experts of 1,024 top-8
+# behind a sigmoid router with a selection bias (normalised, x 2.446) and
+# one shared expert. head_dim is the published 72 (hidden / heads); the
+# latent mixer's heads are qk_nope_head_dim + qk_rope_head_dim
+_KIMI_LAYERS = tuple(
+    "full_attention" if i in (4, 8, 12, 16, 20, 24, 27)
+    else "linear_attention" for i in range(1, 28))
+_KIMI_LINEAR = register(ModelConfig(
+    name="kimi-linear:48b", family="kimi_linear", vocab_size=163_840,
+    hidden_size=2304, intermediate_size=9216, num_layers=27, num_heads=32,
+    num_kv_heads=32, head_dim=72, rope_theta=10_000.0, rms_eps=1e-5,
+    max_seq_len=1_048_576, num_experts=256, experts_per_token=8,
+    moe_intermediate_size=1024, num_shared_experts=1, norm_topk_prob=True,
+    routed_scaling_factor=2.446, router_score="sigmoid", router_bias=True,
+    first_k_dense=1, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, layer_types=_KIMI_LAYERS,
+    linear_num_heads=32, linear_key_head_dim=128, linear_value_head_dim=128,
+    linear_conv_kernel=4, linear_channel_decay=True,
+))
+# one chip of the four that share each layer (expert-parallel over 4): the
+# router's 256 outputs and top-8 as published, experts 0-63 held here
+register(dataclasses.replace(
+    _KIMI_LINEAR, name="kimi-linear:48b-ep4", experts_held=64,
+    experts_first=0))
 register(ModelConfig(
     name="all-minilm", family="bert_embed", vocab_size=30_522,
     hidden_size=384, intermediate_size=1536, num_layers=6, num_heads=12,
@@ -654,6 +722,26 @@ register(ModelConfig(
     attn_gate=True, sliding_window=8, window_layout=_LAGUNA_LAYOUT * 2,
     heads_layout=(6, 8, 8, 8) * 2,
 ))
+# kimi-linear's shape in small: a period of four and a short last one of
+# three (KDA KDA KDA MLA | KDA KDA MLA), the first layer dense, a value
+# head twice a key head (4 x 32 = one lane tile), 16 experts top-4 with a
+# selection bias and one shared; this chip holds experts 4-7 (the second
+# of four shares), so that everything served goes through the share
+register(ModelConfig(
+    name="tiny-kimi-linear", family="kimi_linear", vocab_size=256,
+    hidden_size=64, intermediate_size=128, num_layers=7, num_heads=4,
+    num_kv_heads=4, head_dim=16, rope_theta=10_000.0, rms_eps=1e-5,
+    max_seq_len=256, num_experts=16, experts_per_token=4,
+    moe_intermediate_size=32, num_shared_experts=1, norm_topk_prob=True,
+    routed_scaling_factor=2.446, router_score="sigmoid", router_bias=True,
+    first_k_dense=1, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=16, v_head_dim=16,
+    layer_types=("linear_attention",) * 3 + ("full_attention",)
+    + ("linear_attention",) * 2 + ("full_attention",),
+    linear_num_heads=4, linear_key_head_dim=16, linear_value_head_dim=32,
+    linear_conv_kernel=4, linear_channel_decay=True,
+    experts_held=4, experts_first=4,
+))
 register(ModelConfig(
     name="tiny-qwen2", family="qwen2", vocab_size=256, hidden_size=64,
     intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -729,6 +817,7 @@ _HF_FAMILY = {
     "deepseek_v2": "deepseek_v2",
     "olmo_hybrid": "olmo_hybrid",
     "laguna": "laguna",
+    "kimi_linear": "kimi_linear",
     "bert": "bert_embed",
 }
 
@@ -935,6 +1024,84 @@ def _laguna_from_hf(name: str, hf: dict, path: str) -> ModelConfig:
     )
 
 
+def _kimi_linear_from_hf(name: str, hf: dict, path: str) -> ModelConfig:
+    """Kimi-Linear's published keys. The mixers' pattern comes from the two
+    1-based lists of `linear_attn_config`. `num_experts` is what THIS chip
+    holds of each routed layer: where the file also gives `router_experts`
+    (the published count, the router's width) and they differ, the layer is
+    a share from `experts_first`. Refused, not run wrong: a low-rank query,
+    a scaled or any rotary embedding on the latent layers, group-limited
+    routing, expert layers at a stride, multi-token prediction heads,
+    another score than the sigmoid, another activation than SiLU."""
+    la = hf["linear_attn_config"]
+    kda, full = set(la["kda_layers"]), set(la["full_attn_layers"])
+    depth = hf["num_hidden_layers"]
+    unserved = {
+        "q_lora_rank": hf.get("q_lora_rank") is not None,
+        "rope_scaling": hf.get("rope_scaling") is not None,
+        "mla_use_nope": not hf.get("mla_use_nope"),
+        "num_expert_group": (hf.get("num_expert_group") or 1) > 1
+        or (hf.get("topk_group") or 1) > 1,
+        "moe_layer_freq": hf.get("moe_layer_freq", 1) != 1,
+        "num_nextn_predict_layers": (
+            hf.get("num_nextn_predict_layers") or 0) > 0,
+        "moe_router_activation_func": hf.get(
+            "moe_router_activation_func", "sigmoid") != "sigmoid",
+        "hidden_act": hf.get("hidden_act", "silu") != "silu",
+        "linear_attn_config": bool(kda & full) or not (
+            set(range(1, depth + 1)) <= kda | full),
+    }
+    if any(unserved.values()):
+        raise ValueError(
+            f"{path}: kimi_linear with "
+            f"{[k for k, v in unserved.items() if v]} as published is not "
+            "served (no q_lora, no rotary embedding or scaling of one, one "
+            "expert group, experts in every layer past the dense ones, no "
+            "multi-token prediction, sigmoid scores, silu, every layer in "
+            "exactly one of kda_layers and full_attn_layers)")
+    routed = hf.get("router_experts", hf["num_experts"])
+    share = hf["num_experts"] != routed
+    cfg = ModelConfig(
+        name=name, family="kimi_linear",
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["intermediate_size"],
+        num_layers=depth,
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
+        head_dim=hf.get("head_dim"),
+        rope_theta=float(hf.get("rope_theta", 10_000.0)),
+        rms_eps=hf.get("rms_norm_eps", 1e-5),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_seq_len=hf.get("max_position_embeddings")
+        or hf.get("model_max_length", 1_048_576),
+        num_experts=routed,
+        experts_per_token=hf["num_experts_per_token"],
+        moe_intermediate_size=hf["moe_intermediate_size"],
+        num_shared_experts=hf.get("num_shared_experts") or 0,
+        norm_topk_prob=bool(hf.get("moe_renormalize", True)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        router_score="sigmoid", router_bias=True,
+        first_k_dense=hf.get("first_k_dense_replace", 0),
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        layer_types=tuple(
+            "linear_attention" if i in kda else "full_attention"
+            for i in range(1, max(kda | full) + 1)),
+        linear_num_heads=la["num_heads"],
+        linear_key_head_dim=la["head_dim"],
+        linear_value_head_dim=la["head_dim"],
+        linear_conv_kernel=la["short_conv_kernel_size"],
+        linear_channel_decay=True,
+        experts_held=hf["num_experts"] if share else None,
+        experts_first=(hf.get("experts_first") or 0) if share else None,
+    )
+    cfg.layer_period        # refuses a pattern that is not periods
+    return cfg
+
+
 def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
     mt = hf.get("model_type", "llama")
     if mt == "llava":
@@ -1017,6 +1184,8 @@ def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
         return _olmo_hybrid_from_hf(name, hf, path)
     if family == "laguna":
         return _laguna_from_hf(name, hf, path)
+    if family == "kimi_linear":
+        return _kimi_linear_from_hf(name, hf, path)
     scaling = None
     rs = hf.get("rope_scaling") or None
     if rs and rs.get("rope_type", rs.get("type")) == "llama3":

@@ -89,7 +89,8 @@ def validate_mesh(cfg: ModelConfig, mesh) -> None:
 
 def softmax_scale(cfg: ModelConfig) -> float:
     """(nope + rope)^-0.5 times YaRN's m^2."""
-    return cfg.head_dim_ ** -0.5 * yarn_factors(cfg.rope_scaling)[0]
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * (
+        yarn_factors(cfg.rope_scaling)[0])
 
 
 def _split_kvb(cfg: ModelConfig, lp: Params):
@@ -103,13 +104,17 @@ def _project(cfg: ModelConfig, lp: Params, h: jnp.ndarray, pos: jnp.ndarray,
              inv_freq: jnp.ndarray):
     """Normed state h [B, T, E] at positions pos [B, T] → q_nope
     [B, T, H, dn], q_pe [B, T, H, dr] (rotated), row [B, T, R + dr]: the
-    cache row, the normed latent and its rotated key."""
+    cache row, the normed latent and its rotated key. `inv_freq` None: no
+    positional encoding (kimi_linear's latent layers), nothing rotates."""
     p = llama._precision(h)
     b, t, _ = h.shape
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     q = jnp.dot(h, lp["wq"], precision=p).reshape(b, t, cfg.num_heads, dn + dr)
     kva = jnp.dot(h, lp["w_kva"], precision=p)
     c = rms_norm(kva[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg.rms_eps)
+    if inv_freq is None:
+        return q[..., :dn], q[..., dn:], jnp.concatenate(
+            [c, kva[..., cfg.kv_lora_rank:]], axis=-1)
     q_pe = apply_rope(q[..., dn:], pos, inv_freq)
     k_pe = apply_rope(kva[..., None, cfg.kv_lora_rank:], pos, inv_freq)[..., 0, :]
     mult = yarn_factors(cfg.rope_scaling)[1]

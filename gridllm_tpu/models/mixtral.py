@@ -63,6 +63,17 @@ up), not measured: its claims, "top_k-proportional FLOPs per shard" and
 that an all-to-all token exchange would buy nothing over replicated
 tokens, stand unread.
 
+A chip may hold A SHARE of a layer's experts (`cfg.experts_held` from
+`cfg.experts_first`: one chip of the expert-parallel group that shares
+each layer, kimi-linear:48b-ep4, PR 51): the router keeps its width, its
+top-k and its normalisation over all the chosen, the expert leaves hold
+the held experts only, both forms compute those (`_held`: the all-experts
+form's one-hot over the held, the sorted form's groups over the held,
+`_sorted_share`, which is an `ep` shard's body too), and a pick of an
+expert that lives elsewhere adds nothing: the exchange that would bring
+its part is not run, and nothing stands in for it. The rule of the shape
+reads the held experts over the picks expected here, which is X / k again.
+
 Routing numerics follow HF `MixtralSparseMoeBlock`: softmax over ALL
 expert logits in fp32 → top-k → renormalize the selected weights (the
 same numbers as SmallThinker's top-k → softmax over the chosen);
@@ -103,7 +114,10 @@ def _route(cfg: ModelConfig, lp: Params, r: jnp.ndarray):
     softmax over ALL expert logits, HF MixtralSparseMoeBlock's order, or
     each logit's sigmoid) -> top-k -> the chosen weights divided by their
     sum (`cfg.norm_topk_prob`; else the scores' own) -> times
-    `cfg.routed_scaling_factor`. Returns (top_w, top_i).
+    `cfg.routed_scaling_factor`. Returns (top_w, top_i). With
+    `cfg.router_bias` the top-k is of scores + `lp["router_bias"]` and the
+    weights are still the scores' (the normalisation over all the chosen,
+    held here or not).
     SmallThinker's order (top-k of the logits, softmax over the chosen)
     gives the softmax's numbers: exp(s_j) / sum_chosen exp(s), either way."""
     with jax.named_scope("moe_router"):
@@ -112,7 +126,14 @@ def _route(cfg: ModelConfig, lp: Params, r: jnp.ndarray):
         # [..., X] fp32 - router math stays fp32 (tiny; routing flips are costly)
         scores = (jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid"
                   else jax.nn.softmax(logits, axis=-1))
-        top_w, top_i = jax.lax.top_k(scores, cfg.experts_per_token)
+        if cfg.router_bias:
+            # the bias CHOOSES (scores + bias ranked), the scores weigh
+            _, top_i = jax.lax.top_k(
+                scores + lp["router_bias"].astype(jnp.float32),
+                cfg.experts_per_token)
+            top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+        else:
+            top_w, top_i = jax.lax.top_k(scores, cfg.experts_per_token)
         if cfg.norm_topk_prob:
             top_w = top_w / top_w.sum(axis=-1, keepdims=True)
         if cfg.routed_scaling_factor != 1.0:
@@ -125,15 +146,35 @@ def _act(cfg: ModelConfig):
     return {"silu": jax.nn.silu, "relu": jax.nn.relu}[cfg.expert_act]
 
 
+def _held(cfg: ModelConfig, top_i: jnp.ndarray):
+    """Router picks as this chip's share sees them: (the pick's index
+    among the held experts, `held` itself for an absent one; whether it
+    is held)."""
+    first, held = cfg.held_experts
+    local = top_i - first
+    here = (local >= 0) & (local < held)
+    return jnp.where(here, local, held), here
+
+
 def _route_stats(cfg: ModelConfig, top_i: jnp.ndarray, live) -> jnp.ndarray:
     """[live token rows routed, experts with at least one live row] of one
-    layer, int32[2]: what the engine's gridllm_moe_* counters sum."""
+    layer, int32[2]: what the engine's gridllm_moe_* counters sum. A share
+    (`cfg.experts_held`) counts the HELD experts touched and adds the live
+    rows' picks [on held experts, on absent ones]: int32[4]."""
     flat = top_i.reshape(-1, cfg.experts_per_token)
     if live is None:
         live = jnp.ones(flat.shape[:1], bool)
-    hit = jnp.zeros((cfg.num_experts,), jnp.int32).at[flat].max(
-        jnp.broadcast_to(live.reshape(-1, 1).astype(jnp.int32), flat.shape))
-    return jnp.stack([live.sum().astype(jnp.int32), hit.sum()])
+    if cfg.experts_held is None:
+        hit = jnp.zeros((cfg.num_experts,), jnp.int32).at[flat].max(
+            jnp.broadcast_to(live.reshape(-1, 1).astype(jnp.int32), flat.shape))
+        return jnp.stack([live.sum().astype(jnp.int32), hit.sum()])
+    on = jnp.broadcast_to(live.reshape(-1, 1).astype(jnp.int32), flat.shape)
+    local, here = _held(cfg, flat)
+    hit = jnp.zeros((cfg.experts_held,), jnp.int32).at[local].max(
+        on, mode="drop")
+    picks = (on * here).sum()
+    return jnp.stack([live.sum().astype(jnp.int32), hit.sum(), picks,
+                      on.sum() - picks])
 
 
 def _moe_mlp_dense(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
@@ -143,9 +184,15 @@ def _moe_mlp_dense(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     no dynamic shapes, EP-shardable (each "ep" shard computes its X/ep
     experts for all tokens; the combine is the all-reduce XLA inserts).
     X/top_k times the ragged form's row FLOPs and a [T, X, F]
-    intermediate: see the module docstring for what the chip read."""
+    intermediate: see the module docstring for what the chip read. Of a
+    share (`cfg.experts_held`) the expert leaves hold the held experts
+    only and a pick of an absent one has no gate: it adds nothing."""
     p = llama._precision(x)
-    one_hot = jax.nn.one_hot(top_i, cfg.num_experts, dtype=jnp.float32)
+    if cfg.experts_held is None:
+        one_hot = jax.nn.one_hot(top_i, cfg.num_experts, dtype=jnp.float32)
+    else:       # a share: an absent pick's row of the one-hot is zeros
+        one_hot = jax.nn.one_hot(_held(cfg, top_i)[0], cfg.experts_held,
+                                 dtype=jnp.float32)
     gates = jnp.einsum("...k,...kx->...x", top_w, one_hot).astype(x.dtype)
 
     with jax.named_scope("moe_experts"):
@@ -169,6 +216,11 @@ def _moe_mlp_ragged(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     e = x.shape[-1]
     xf = x.reshape(-1, e)                       # [T, E]
     t = xf.shape[0]
+    if cfg.experts_held is not None:
+        out = _sorted_share(
+            _act(cfg), xf, top_w.reshape(t, k), top_i.reshape(t, k),
+            cfg.held_experts[0], lp["we_gate"], lp["we_up"], lp["we_down"])
+        return out.reshape(*lead, e)
 
     flat_expert = top_i.reshape(-1)             # [T*k]
     token_idx = jnp.repeat(jnp.arange(t), k)    # [T*k]
@@ -188,6 +240,37 @@ def _moe_mlp_ragged(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     return out.reshape(*lead, e)
 
 
+def _sorted_share(act, xf, top_w, top_i, lo, wg, wu, wd):
+    """The sorted dispatch over the experts [lo, lo + wg.shape[0]) that
+    are HERE (an `ep` shard's, or a share's: `cfg.experts_held`): xf
+    [T, E], top_w / top_i [T, k] over ALL experts. A pick of an expert
+    that is not here sorts to the tail, is in no group (no product reads
+    it) and adds zero. Returns this share's part of the output [T, E]."""
+    t, e = xf.shape
+    k = top_i.shape[-1]
+    xl = wg.shape[0]                       # local experts
+    flat = top_i.reshape(-1)               # [T*k] global expert ids
+    tok = jnp.repeat(jnp.arange(t), k)
+    el = flat - lo
+    valid = (el >= 0) & (el < xl)
+    order = jnp.argsort(jnp.where(valid, el, xl))  # invalid → tail
+    rows = tok[order]
+    xs = xf[rows]
+    gs = jnp.bincount(
+        jnp.where(valid, el, xl), length=xl + 1
+    )[:xl].astype(jnp.int32)
+
+    g = jax.lax.ragged_dot(xs, wg, gs)
+    u = jax.lax.ragged_dot(xs, wu, gs)
+    y = (act(g) * u).astype(xf.dtype)
+    d = jax.lax.ragged_dot(y, wd, gs)
+
+    vs = valid[order]
+    w = jnp.where(vs, top_w.reshape(-1)[order], 0.0).astype(xf.dtype)
+    d = jnp.where(vs[:, None], d, 0)       # rows past all groups
+    return jnp.zeros((t, e), xf.dtype).at[rows].add(d * w[:, None])
+
+
 def _moe_mlp_ragged_ep(
     cfg: ModelConfig, lp: Params, x: jnp.ndarray, top_w, top_i, mesh
 ) -> jnp.ndarray:
@@ -198,9 +281,7 @@ def _moe_mlp_ragged_ep(
     shard_map over ("ep", "tp"): each shard holds X/ep experts (their
     gate/up/down slabs further split F-wise over tp), runs the SAME sorted
     ragged_dot dispatch as the single-device path but over its LOCAL
-    expert range (assignments outside the range sort to the tail, get
-    group_sizes 0, and are zero-weighted — NaN-proofed before the
-    combine), then one psum over (ep, tp) merges expert contributions and
+    expert range (`_sorted_share`), then one psum over (ep, tp) merges expert contributions and
     the tp partial sums in a single collective. Tokens are replicated into
     the shard (activations are bytes; expert weights are the GBs), so the
     only cross-device traffic is the output psum — an all-to-all token
@@ -225,28 +306,8 @@ def _moe_mlp_ragged_ep(
     act = _act(cfg)
 
     def shard_fn(xf, top_w, top_i, wg, wu, wd):
-        xl = wg.shape[0]                       # local experts
-        lo = jax.lax.axis_index("ep") * xl
-        flat = top_i.reshape(-1)               # [T*k] global expert ids
-        tok = jnp.repeat(jnp.arange(t), k)
-        el = flat - lo
-        valid = (el >= 0) & (el < xl)
-        order = jnp.argsort(jnp.where(valid, el, xl))  # invalid → tail
-        rows = tok[order]
-        xs = xf[rows]
-        gs = jnp.bincount(
-            jnp.where(valid, el, xl), length=xl + 1
-        )[:xl].astype(jnp.int32)
-
-        g = jax.lax.ragged_dot(xs, wg, gs)
-        u = jax.lax.ragged_dot(xs, wu, gs)
-        y = (act(g) * u).astype(xf.dtype)
-        d = jax.lax.ragged_dot(y, wd, gs)
-
-        vs = valid[order]
-        w = jnp.where(vs, top_w.reshape(-1)[order], 0.0).astype(xf.dtype)
-        d = jnp.where(vs[:, None], d, 0)       # rows past all groups
-        out = jnp.zeros((t, e), xf.dtype).at[rows].add(d * w[:, None])
+        lo = jax.lax.axis_index("ep") * wg.shape[0]
+        out = _sorted_share(act, xf, top_w, top_i, lo, wg, wu, wd)
         return jax.lax.psum(out, ("ep", "tp"))
 
     out = shard_map(
@@ -282,8 +343,12 @@ def _use_ragged(cfg: ModelConfig, n_tokens: int, meshed: bool,
         return False
     if meshed:
         return n_tokens >= _RAGGED_MIN_TOKENS
-    k, x = cfg.experts_per_token, cfg.num_experts
-    return x >= _SORTED_MIN_WASTE * k and n_tokens >= _SORTED_MIN_ROWS
+    # experts the all-experts form multiplies over the picks a token
+    # makes among them: of a share, the held experts over the picks
+    # expected here (k held / X), which is X / k again
+    held = cfg.held_experts[1]
+    picks = cfg.experts_per_token * held / cfg.num_experts
+    return held >= _SORTED_MIN_WASTE * picks and n_tokens >= _SORTED_MIN_ROWS
 
 
 def expert_form(cfg: ModelConfig, n_tokens: int, mesh=None) -> str:

@@ -86,7 +86,8 @@ def new_state(cfg: ModelConfig, slots: int, step_rows: int, snapshots: int,
     return RecurrentState.create(
         cfg.linear_layers, slots, cfg.linear_num_heads,
         cfg.linear_key_head_dim, cfg.linear_value_head_dim,
-        cfg.linear_conv_kernel, step_rows, snapshots, dtype)
+        cfg.linear_conv_kernel, step_rows, snapshots, dtype,
+        channel_decay=cfg.linear_channel_decay)
 
 
 # ---------------------------------------------------------------------------
@@ -109,7 +110,9 @@ def _project(lp: Params, x: jnp.ndarray):
 def _qkvbg(cfg: ModelConfig, lp: Params, conv: jnp.ndarray, a, bb):
     """The convolution's output [..., C] (float32, SiLU applied) and the
     pre-activations -> q^, k^ [..., H, dk], v [..., H, dv], beta and the
-    log decay [..., H], all float32."""
+    log decay [..., H], all float32. Under `cfg.linear_channel_decay` (Kimi
+    Delta Attention) `a` and dt_bias are [..., H*dk] and the log decay a
+    value a key channel, [..., H, dk]."""
     h, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
                  cfg.linear_value_head_dim)
     q, k, v = jnp.split(conv, [h * dk, 2 * h * dk], axis=-1)
@@ -118,16 +121,30 @@ def _qkvbg(cfg: ModelConfig, lp: Params, conv: jnp.ndarray, a, bb):
     v = v.reshape(*v.shape[:-1], h, dv)
     b = jax.nn.sigmoid(bb.astype(jnp.float32)) * (
         2.0 if cfg.linear_allow_neg_eigval else 1.0)
+    if cfg.linear_channel_decay:
+        step = jax.nn.softplus(
+            a.astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
+        g = -jnp.exp(lp["A_log"].astype(jnp.float32))[:, None] * (
+            step.reshape(*step.shape[:-1], h, dk))
+        return q, k, v, b, g
     g = -jnp.exp(lp["A_log"].astype(jnp.float32)) * jax.nn.softplus(
         a.astype(jnp.float32) + lp["dt_bias"].astype(jnp.float32))
     return q, k, v, b, g
 
 
-def _gated_out(cfg: ModelConfig, lp: Params, o: jnp.ndarray, gate, dtype):
-    """o [..., H, dv] float32 -> W_o [RMSNorm_dv(o) * SiLU(gate)]."""
+def _delta_ops(cfg: ModelConfig):
+    """(chunk, step) of ops/linear_attn by the decay's shape."""
+    if cfg.linear_channel_decay:
+        return la.kda_chunk, la.kda_step
+    return la.gdn_chunk, la.gdn_step
+
+
+def _gated_out(cfg: ModelConfig, lp: Params, o: jnp.ndarray, gate, dtype,
+               act=jax.nn.silu):
+    """o [..., H, dv] float32 -> W_o [RMSNorm_dv(o) * act(gate)]."""
     with jax.named_scope("gdn_gate"):
         gate = gate.reshape(o.shape).astype(jnp.float32)
-        y = rms_norm(o, lp["o_norm"], cfg.rms_eps) * jax.nn.silu(gate)
+        y = rms_norm(o, lp["o_norm"], cfg.rms_eps) * act(gate)
         y = y.reshape(*y.shape[:-2], -1).astype(dtype)
     return jnp.dot(y, lp["wo"], precision=llama._precision(y))
 
@@ -175,6 +192,7 @@ def _stack(params: Params, cfg: ModelConfig, x, carry, lin, att):
     [periods, period - 1, ...], the full layers' ys [periods, ...])."""
     per = cfg.layer_period - 1
     n = cfg.num_layers // cfg.layer_period
+    assert not cfg.layer_tail, "olmo_hybrid is whole periods only"
 
     def body(c, xs):
         x, carry = c
@@ -201,6 +219,31 @@ def _stack(params: Params, cfg: ModelConfig, x, carry, lin, att):
 # ---------------------------------------------------------------------------
 
 
+def _free_delta(cfg: ModelConfig, lp: Params, pre, a, bb, live):
+    """The delta rule cache-free: `_project`'s rows of whole sequences
+    [B, T, ...], live [B, T], each sequence from a zero state in the
+    chunked form (jnp). Returns o [B, T, H, dv]."""
+    t = pre.shape[1]
+    block = 64
+    pad = -t % block
+    xfull = jnp.pad(pre, [(0, 0), (cfg.linear_conv_kernel - 1, 0), (0, 0)])
+    q, k, v, bt, g = _qkvbg(cfg, lp, la.causal_conv(xfull, lp["conv_w"]),
+                            a, bb)
+    bt = jnp.where(live[..., None], bt, 0.0)
+    g = jnp.where(live.reshape(live.shape + (1,) * (g.ndim - 2)), g, 0.0)
+    q, k, v = (jnp.where(live[..., None, None], z, 0.0) for z in (q, k, v))
+
+    def one(q, k, v, bt, g):
+        rows = [jnp.pad(z, [(0, pad)] + [(0, 0)] * (z.ndim - 1))
+                for z in (q, k, v, bt, g)]
+        s0 = jnp.zeros((q.shape[1], q.shape[2], v.shape[2]), jnp.float32)
+        o, _, _ = la._chain(s0, la._wy(*rows, block),
+                            jnp.zeros((0,), jnp.int32))
+        return jnp.moveaxis(o, 1, 2).reshape(t + pad, *v.shape[1:])[:t]
+
+    return jax.vmap(one)(q, k, v, bt, g)
+
+
 def hidden_states(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                   seq_lens: jnp.ndarray | None = None, mesh=None) -> jnp.ndarray:
     """Final-norm hidden states [B, T, E], cache-free: each sequence from
@@ -210,27 +253,10 @@ def hidden_states(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     if seq_lens is None:
         seq_lens = jnp.full((b,), t, jnp.int32)
     live = jnp.arange(t)[None] < seq_lens[:, None]
-    k_taps = cfg.linear_conv_kernel
-    block = 64
-    pad = -t % block
 
     def lin(lp, li, x, carry):
         pre, gate, a, bb = _project(lp, x)
-        xfull = jnp.pad(pre, [(0, 0), (k_taps - 1, 0), (0, 0)])
-        q, k, v, bt, g = _qkvbg(cfg, lp, la.causal_conv(xfull, lp["conv_w"]),
-                                a, bb)
-        bt, g = (jnp.where(live[..., None], z, 0.0) for z in (bt, g))
-        q, k, v = (jnp.where(live[..., None, None], z, 0.0) for z in (q, k, v))
-
-        def one(q, k, v, bt, g):
-            rows = [jnp.pad(z, [(0, pad)] + [(0, 0)] * (z.ndim - 1))
-                    for z in (q, k, v, bt, g)]
-            s0 = jnp.zeros((q.shape[1], q.shape[2], v.shape[2]), jnp.float32)
-            o, _, _ = la._chain(s0, la._wy(*rows, block),
-                                jnp.zeros((0,), jnp.int32))
-            return jnp.moveaxis(o, 1, 2).reshape(t + pad, *v.shape[1:])[:t]
-
-        o = jax.vmap(one)(q, k, v, bt, g)
+        o = _free_delta(cfg, lp, pre, a, bb, live)
         return _gated_out(cfg, lp, o, gate, x.dtype), carry, None
 
     def att(lp, pi, x):
@@ -281,13 +307,14 @@ def _chunk_region(cfg: ModelConfig, lp: Params, li, rec: RecurrentState,
     # layer's kernel left a padded row unwritten: PERF.md, PR 42)
     live = (jnp.arange(c) < length)[:, None]
     q, k, v = (jnp.where(live[..., None], z, 0.0) for z in (q, k, v))
-    b, g = jnp.where(live, b, 0.0), jnp.where(live, g, 0.0)
+    b = jnp.where(live, b, 0.0)
+    g = jnp.where(live.reshape(live.shape + (1,) * (g.ndim - 2)), g, 0.0)
     s0 = jnp.where(fresh, 0.0, rec.state[li, slot])
     passed = (save_pos > start) & (save_pos <= start + length)
     rel = jnp.where(passed, save_pos - start, 0)
     keep = jnp.where(passed, rel // block - 1, -1)
-    o, s1, kept = la.gdn_chunk(s0, q, k, v, b, g, keep, block,
-                               use_pallas=cfg.use_pallas)
+    o, s1, kept = _delta_ops(cfg)[0](s0, q, k, v, b, g, keep, block,
+                                     use_pallas=cfg.use_pallas)
 
     def tail_at(r):           # the rows before position start + r
         return jax.lax.dynamic_slice_in_dim(xfull, r, taps)
@@ -322,8 +349,8 @@ def _step_region(cfg: ModelConfig, lp: Params, li, rec: RecurrentState,
     q, k, v, b, g = _qkvbg(cfg, lp, la.causal_conv(xfull, lp["conv_w"]), a, bb)
     pend = tuple(z[li] for z in (rec.pend_k, rec.pend_v, rec.pend_b,
                                  rec.pend_g))
-    state, o = la.gdn_step(rec.state, li, pend, n, q, k, v, b, g, active,
-                           use_pallas=cfg.use_pallas)
+    state, o = _delta_ops(cfg)[1](rec.state, li, pend, n, q, k, v, b, g,
+                                  active, use_pallas=cfg.use_pallas)
     on = active[:, None, None]
     rows_ = slice(0, t)
     rec = dataclasses.replace(

@@ -175,8 +175,19 @@ MOE_EXPERTS_TOUCHED_TOTAL = _OBS.counter(
     "Routed families: experts with at least one live row, summed over "
     "layers and launches. Over num_experts x layers x "
     "gridllm_engine_phase_seconds_count{phase=\"dispatch_verify\"} it is "
-    "the share of the held experts a launch has to read.",
+    "the share of the held experts a launch has to read. Where a chip "
+    "holds a share of each layer's experts (ModelConfig.experts_held) only "
+    "HELD experts are counted, and the divisor is the held count.",
     ("model",),
+)
+MOE_PICKS_TOTAL = _OBS.counter(
+    "gridllm_moe_picks_total",
+    "A share of the experts (ModelConfig.experts_held): router picks of "
+    "live rows in the verify / decode block launches, summed over layers, "
+    "by where the picked expert lives: held (computed here) or absent (on "
+    "another chip of the expert-parallel group: it adds nothing here). "
+    "Nothing for a family that holds every expert.",
+    ("model", "where"),
 )
 
 # -- XLA compilations, as jax itself reports them ----------------------------

@@ -122,6 +122,10 @@ KERNELS: tuple[KernelSpec, ...] = (
 # ops/ exactly, both ways.
 EXTRA_DISPATCH_LABELS: dict[str, str] = {
     "write_multi": "multi-token append flattened onto paged_write_decode",
+    "kda_chunk": "the delta rule with a decay a key channel (Kimi Delta "
+                 "Attention) through gdn_chunk's kernel, general over the "
+                 "decay's shape; the custom call is named kda_chunk",
+    "kda_step": "the same through gdn_step's kernel, named kda_step",
 }
 
 

@@ -322,7 +322,8 @@ class RecurrentState:
     pend_k: jnp.ndarray      # [Ll, S, T, H, dk] float32, normalised
     pend_v: jnp.ndarray      # [Ll, S, T, H, dv] float32
     pend_b: jnp.ndarray      # [Ll, S, T, H] float32 beta
-    pend_g: jnp.ndarray      # [Ll, S, T, H] float32 log decay
+    pend_g: jnp.ndarray      # [Ll, S, T, H] float32 log decay ([.., H, dk]
+    #                          where it is a value a key channel)
     pend_n: jnp.ndarray      # [S] int32 pending rows that count
     snap_state: jnp.ndarray  # [Ll, N, dk, H*dv]
     snap_conv: jnp.ndarray   # [Ll, N, (K-1)*C]
@@ -330,9 +331,11 @@ class RecurrentState:
     @staticmethod
     def create(layers: int, slots: int, heads: int, dk: int, dv: int,
                conv_kernel: int, step_rows: int, snapshots: int,
-               dtype=jnp.bfloat16) -> "RecurrentState":
+               dtype=jnp.bfloat16,
+               channel_decay: bool = False) -> "RecurrentState":
         c = heads * (2 * dk + dv)
         f32 = jnp.float32
+        decay = (dk,) if channel_decay else ()
         return RecurrentState(
             state=jnp.zeros((layers, slots, dk, heads * dv), f32),
             conv=jnp.zeros((layers, slots, (conv_kernel - 1) * c), dtype),
@@ -340,7 +343,7 @@ class RecurrentState:
             pend_k=jnp.zeros((layers, slots, step_rows, heads, dk), f32),
             pend_v=jnp.zeros((layers, slots, step_rows, heads, dv), f32),
             pend_b=jnp.zeros((layers, slots, step_rows, heads), f32),
-            pend_g=jnp.zeros((layers, slots, step_rows, heads), f32),
+            pend_g=jnp.zeros((layers, slots, step_rows, heads, *decay), f32),
             pend_n=jnp.zeros((slots,), jnp.int32),
             snap_state=jnp.zeros((layers, snapshots, dk, heads * dv), f32),
             snap_conv=jnp.zeros((layers, snapshots, (conv_kernel - 1) * c),

@@ -33,6 +33,25 @@ tiles (30 x 192 = 45 x 128; a [.., 96, 192] array is stored at 256 lanes,
 a third more bytes) and a kernel block of `head_pack` heads is lane-dense.
 
 `gdn_recurrent` is the plain token-by-token form: the oracle of both.
+
+KIMI DELTA ATTENTION (`kda_recurrent`, `kda_chunk`, `kda_step`) is the same
+rule with a log decay a head A KEY CHANNEL, g [.., H, dk]: S <- Diag(alpha)
+S in the first line above. The two forms and the two kernels are the same
+code, general over the decay's shape (the state's decay over a block is
+one factor a head, or one a head and key row: the kernels apply the first
+on the lanes and the second as Diag(gc) S on the MXU), and a trace finds
+them under their own names. What changes is a block's pair terms,
+`k_i . (exp(G_i - G_j) * k_j)` with G the running sum of g, a vector: no
+longer one Gram matrix times a scalar, and the split `(k_i exp G_i) .
+(k_j exp -G_j)` overflows float32 (one row's log decay can be below -5,
+a block has 64). `_pairs` takes them in sub-blocks of `SUB_BLOCK` rows:
+between two sub-blocks the decay is split at the boundary row r before
+the later one, `exp(G_i - G_r) exp(G_r - G_j)` with i >= r >= j, two
+matrix operands; inside a sub-block the 16 x 16 x dk exponents are formed
+outright under the causal mask. THE BOUND: every exponent this module
+forms for a channel decay is <= 0 (G falls along the rows), so no factor
+exceeds 1 whatever the decay; a product that underflows is one the exact
+arithmetic puts below 1e-38 of its operands.
 """
 
 from __future__ import annotations
@@ -46,6 +65,7 @@ from gridllm_tpu.ops.kvcache import _pallas_mode, record_kernel_path
 
 HI = jax.lax.Precision.HIGHEST
 STEP_ROWS = 8          # a step's rows padded to one float32 sublane tile
+SUB_BLOCK = 16         # rows whose pair terms a channel decay forms outright
 
 
 def head_pack(dv: int, heads: int) -> int:
@@ -86,10 +106,12 @@ def pack(state: jnp.ndarray) -> jnp.ndarray:
 
 def gdn_recurrent(state, q, k, v, b, g):
     """Token by token. state [H, dk, dv]; q, k [T, H, dk]; v [T, H, dv];
-    b, g [T, H]. Returns (o [T, H, dv], state after the T rows)."""
+    b [T, H]; g [T, H], or [T, H, dk] for a decay a key channel. Returns
+    (o [T, H, dv], state after the T rows)."""
     def one(s, row):
         qt, kt, vt, bt, gt = row
-        s = s * jnp.exp(gt)[:, None, None]
+        s = s * (jnp.exp(gt)[:, None, None] if gt.ndim == 1
+                 else jnp.exp(gt)[:, :, None])
         u = bt[:, None] * (vt - jnp.einsum("hkd,hk->hd", s, kt, precision=HI))
         s = s + kt[:, :, None] * u[:, None, :]
         return s, jnp.einsum("hkd,hk->hd", s, qt, precision=HI)
@@ -97,6 +119,8 @@ def gdn_recurrent(state, q, k, v, b, g):
     state, o = jax.lax.scan(one, state.astype(jnp.float32), (q, k, v, b, g))
     return o, state
 
+
+kda_recurrent = gdn_recurrent      # g [T, H, dk]: the oracle of kda_*
 
 SOLVE_BLOCK = 16       # rows of a diagonal block inverted row by row
 
@@ -144,9 +168,71 @@ def _solve_unit_lower(m, rhs):
     return jnp.concatenate(out, axis=-2)
 
 
+def _pairs(q, k, cum):
+    """A block's pair terms under a decay a key channel. q, k, cum
+    [.., C, dk] (cum the running sum of the log decay, falling along the
+    rows). Returns (kk, qk) [.., C, C] with `sum_c x_ic k_jc exp(cum_ic -
+    cum_jc)` at j <= i and zeros above the diagonal; no exponent formed is
+    positive (module docstring)."""
+    c, dk = q.shape[-2:]
+    sub = math.gcd(SUB_BLOCK, c)
+    n = c // sub
+    lead = q.shape[:-2]
+
+    def subs(x):                         # [.., C, dk] -> [.., n, sub, dk]
+        return x.reshape(*lead, n, sub, dk)
+
+    # the boundary before each sub-block: the running sum at its last
+    # earlier row (zeros before the first)
+    ref = jnp.concatenate(
+        [jnp.zeros((*lead, 1, dk), jnp.float32),
+         subs(cum)[..., :-1, -1, :]], axis=-2)             # [.., n, dk]
+    down = jnp.exp(subs(cum) - ref[..., None, :])          # rows from theirs
+    before = (jnp.arange(c)[None] < (jnp.arange(n) * sub)[:, None])[..., None]
+    up = jnp.where(before, k[..., None, :, :] * jnp.exp(jnp.where(
+        before, ref[..., None, :] - cum[..., None, :, :], 0.0)), 0.0)
+    low = jnp.tril(jnp.ones((sub, sub), bool))[..., None]  # [sub, sub, 1]
+    own = jnp.where(low, jnp.exp(jnp.where(
+        low, subs(cum)[..., :, None, :] - subs(cum)[..., None, :, :], 0.0)),
+        0.0)                                               # [.., n, i, j, dk]
+    eye = jnp.eye(n, dtype=jnp.float32)
+
+    def pairs(x):
+        off = jnp.einsum("...nik,...njk->...nij", subs(x) * down, up,
+                         precision=HI)                     # [.., n, sub, C]
+        diag = jnp.einsum("...nik,...njk,...nijk->...nij", subs(x), subs(k),
+                          own, precision=HI)
+        diag = jnp.einsum("...nij,nm->...nimj", diag, eye, precision=HI)
+        return off.reshape(*lead, c, c) + diag.reshape(*lead, c, c)
+
+    return pairs(k), pairs(q)
+
+
+def _wy_channel(q, k, v, b, g):
+    """`_wy` for a log decay a key channel: q, k, g [nb, H, C, dk], v
+    [nb, H, C, dv], b [nb, H, C], in blocks already. The same arrays, but
+    gc [nb, H, dk]: the state's rows decay each at its own rate."""
+    block = q.shape[-2]
+    cum = jnp.cumsum(g, axis=-2)                           # [nb, H, C, dk]
+    kk, aqk = _pairs(q, k, cum)
+    strict = jnp.tril(jnp.ones((block, block), jnp.float32), -1)
+    lmat = b[..., :, None] * kk * strict + jnp.eye(block)
+    grow = jnp.exp(cum)
+    rhs = jnp.concatenate([b[..., None] * v, b[..., None] * grow * k], axis=-1)
+    w = _solve_unit_lower(lmat, rhs)
+    dv = v.shape[-1]
+    last = cum[..., -1:, :]
+    return {
+        "wv": w[..., :dv], "wk": w[..., dv:], "aqk": aqk,
+        "qg": q * grow, "kd": k * jnp.exp(last - cum),
+        "gc": jnp.exp(last[..., 0, :]),
+    }
+
+
 def _wy(q, k, v, b, g, block: int):
     """What a block's rows give without the state. q, k [T, H, dk],
-    v [T, H, dv], b, g [T, H] with T a multiple of `block`. Returns, each
+    v [T, H, dv], b, g [T, H] (g [T, H, dk] for a decay a key channel:
+    `_wy_channel`) with T a multiple of `block`. Returns, each
     with leading [nb, H]: wv [C, dv] and wk [C, dk] (the corrections are
     U = wv - wk S), aqk [C, C] (the rows' own part of the output:
     O = qg S + aqk U), qg [C, dk], kd [C, dk] and gc [1] (the state after:
@@ -158,6 +244,8 @@ def _wy(q, k, v, b, g, block: int):
         return jnp.moveaxis(x.reshape(nb, block, *x.shape[1:]), 2, 1)
 
     q, k, v, b, g = (blocks(x.astype(jnp.float32)) for x in (q, k, v, b, g))
+    if g.ndim == 4:
+        return _wy_channel(q, k, v, b, g)
     cum = jnp.cumsum(g, axis=-1)                           # [nb, H, C]
     rel = cum[..., :, None] - cum[..., None, :]            # log gamma_i/gamma_j
     low = jnp.tril(jnp.ones((block, block), bool))
@@ -192,6 +280,7 @@ def _chain(state, wy, keep):
         u = blk["wv"] - jnp.einsum("hck,hkd->hcd", blk["wk"], s, precision=HI)
         o = (jnp.einsum("hck,hkd->hcd", blk["qg"], s, precision=HI)
              + jnp.einsum("hij,hjd->hid", blk["aqk"], u, precision=HI))
+        # gc [H, 1] a head, or [H, dk] a key row of the state
         s = s * blk["gc"][..., None] + jnp.einsum(
             "hck,hcd->hkd", blk["kd"], u, precision=HI)
         kept = jnp.where((keep == i)[:, None, None, None], s[None], kept)
@@ -209,16 +298,31 @@ def gdn_chunk(state, q, k, v, b, g, keep, block: int,
     q, k [T, H, dk]; v [T, H, dv]; b, g [T, H], rows that hold no token
     with b = g = 0; keep [n] block indices (see `_chain`). Returns
     (o [T, H, dv], state after, kept [n, dk, H*dv])."""
+    use, interpret = _pallas_mode(use_pallas)
+    record_kernel_path("gdn_chunk", use)
+    return _chunk("gdn_chunk", use, interpret, state, q, k, v, b, g, keep,
+                  block)
+
+
+def kda_chunk(state, q, k, v, b, g, keep, block: int,
+              use_pallas: bool | None = None):
+    """`gdn_chunk` with a log decay a key channel: g [T, H, dk]."""
+    use, interpret = _pallas_mode(use_pallas)
+    record_kernel_path("kda_chunk", use)
+    return _chunk("kda_chunk", use, interpret, state, q, k, v, b, g, keep,
+                  block)
+
+
+def _chunk(op: str, use: bool, interpret: bool, state, q, k, v, b, g, keep,
+           block: int):
     t, h, _ = q.shape
     dv = v.shape[-1]
     wy = _wy(q, k, v, b, g, block)
-    use, interpret = _pallas_mode(use_pallas)
-    record_kernel_path("gdn_chunk", use)
     if use:
         from gridllm_tpu.ops.pallas_kernels import gdn_chunk as kernel
 
         o, state, kept = kernel(state, _lanes(wy, dv), keep,
-                                heads=h, interpret=interpret)
+                                heads=h, interpret=interpret, name=op)
         o = o.reshape(t, h, dv)                  # [nb, C, H*dv] rows
         return o, state, kept
     o, s, kept = _chain(unpack(state, h), wy, keep)
@@ -230,9 +334,14 @@ def _lanes(wy, dv: int):
     """`_wy`'s arrays as the kernels read them: what multiplies the state
     from the left stays a head ([.., H, C, dk] / [.., H, C, C]); what is
     added to a product with it lies packed on the lanes as the state does
-    (wv [.., C, H*dv], gc [.., 1, H*dv])."""
+    (wv [.., C, H*dv], gc [.., 1, H*dv]). A decay a key channel (gc
+    [.., H, dk]) stays a head, as a row [.., H, 1, dk]: the kernels apply
+    it from the left, Diag(gc) S."""
     wv = jnp.moveaxis(wy["wv"], -3, -2)                  # [.., C, H, dv]
-    gc = jnp.repeat(jnp.moveaxis(wy["gc"], -2, -1), dv, axis=-1)
+    if wy["gc"].shape[-1] == 1:
+        gc = jnp.repeat(jnp.moveaxis(wy["gc"], -2, -1), dv, axis=-1)
+    else:
+        gc = wy["gc"][..., None, :]
     return {**wy, "wv": wv.reshape(*wv.shape[:-2], -1), "gc": gc}
 
 
@@ -243,6 +352,25 @@ def _pad_rows(x, rows: int):
 
 def gdn_step(states, layer, pend, n, q, k, v, b, g, live,
              use_pallas: bool | None = None):
+    """A launch's rows of every slot (`_step` has the contract)."""
+    use, interpret = _pallas_mode(use_pallas)
+    record_kernel_path("gdn_step", use)
+    return _step("gdn_step", use, interpret, states, layer, pend, n, q, k,
+                 v, b, g, live)
+
+
+def kda_step(states, layer, pend, n, q, k, v, b, g, live,
+             use_pallas: bool | None = None):
+    """`gdn_step` with a log decay a key channel: g, and the pending g,
+    [S, T, H, dk]."""
+    use, interpret = _pallas_mode(use_pallas)
+    record_kernel_path("kda_step", use)
+    return _step("kda_step", use, interpret, states, layer, pend, n, q, k,
+                 v, b, g, live)
+
+
+def _step(op: str, use: bool, interpret: bool, states, layer, pend, n, q, k,
+          v, b, g, live):
     """A launch's rows of every slot. states [Ll, S, dk, H*dv] (every
     linear layer, packed), `layer` the one stepped; pend = (k, v, b, g) of
     the last launch's rows [S, Tp, ...] of which the first n [S] were
@@ -266,8 +394,6 @@ def gdn_step(states, layer, pend, n, q, k, v, b, g, live,
         jnp.where(live.reshape((s,) + (1,) * (z.ndim - 1)), z, 0.0)
         for z in (q, k, v, b, g))
     alive = live[:, None, None, None]
-    use, interpret = _pallas_mode(use_pallas)
-    record_kernel_path("gdn_step", use)
     if not use:
         st = unpack(jax.lax.dynamic_index_in_dim(states, layer, keepdims=False),
                     h)
@@ -286,5 +412,5 @@ def gdn_step(states, layer, pend, n, q, k, v, b, g, live,
     # live slots first: the kernel's grid walks them and moves no other's
     order = jnp.argsort(~live, stable=True)
     states, o = kernel(states, layer, order, live.sum(), _lanes(wy, dv),
-                       heads=h, interpret=interpret)
+                       heads=h, interpret=interpret, name=op)
     return states, jnp.where(alive, o[:, :t].reshape(s, t, h, dv), 0.0)

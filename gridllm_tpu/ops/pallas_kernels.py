@@ -1175,7 +1175,10 @@ def _gdn_block(s, wv, wk, aqk, qg, kd, gc, pack: int, dv: int):
     Returns (o [C, pack*dv], the state after): U = wv - wk S,
     O = qg S + aqk U, S' = gc S + kd^T U, each head's product taken at the
     packed width and kept on its own lanes (no slice at a lane offset
-    that is not a tile's)."""
+    that is not a tile's). A decay a key channel (Kimi Delta Attention)
+    hands gc a head as a row [pack, 1, dk]: each ROW of a head's state
+    decays at its own rate, S' = Diag(gc) S + kd^T U, a product like the
+    others (the diagonal built from the row: no transpose)."""
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, pack * dv), 1)
 
     def per_head(f):
@@ -1190,9 +1193,18 @@ def _gdn_block(s, wv, wk, aqk, qg, kd, gc, pack: int, dv: int):
         return jnp.dot(a, b, preferred_element_type=jnp.float32,
                        precision=_HI)
 
+    def decayed():
+        if gc.ndim == 2:
+            return s * gc
+        dk = s.shape[0]
+        on_diag = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+                   == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+        return per_head(lambda p: dot(
+            jnp.where(on_diag, jnp.broadcast_to(gc[p], (dk, dk)), 0.0), s))
+
     u = wv - per_head(lambda p: dot(wk[p], s))
     o = per_head(lambda p: dot(qg[p], s) + dot(aqk[p], u))
-    s = s * gc + per_head(lambda p: jax.lax.dot_general(
+    s = decayed() + per_head(lambda p: jax.lax.dot_general(
         kd[p], u, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32, precision=_HI))
     return o, s
@@ -1222,13 +1234,15 @@ def _gdn_chunk_kernel(keep_ref, s0_ref, wv_ref, wk_ref, aqk_ref, qg_ref,
         s_ref[...] = s
 
 
-def gdn_chunk(state, wy, keep, *, heads: int, interpret: bool = False):
+def gdn_chunk(state, wy, keep, *, heads: int, interpret: bool = False,
+              name: str = "gdn_chunk"):
     """The blocks of ONE slot's rows chained through its state.
     state [dk, H*dv] float32 (packed); wy: ops.linear_attn._lanes(_wy(..))
     with leading [nb]; keep [n] int32 block indices whose end state is
     handed back (-1: zeros). Returns (o [nb, C, H*dv], the state after,
     kept [n, dk, H*dv]). Grid (head packs, blocks): the state of a pack
-    stays in VMEM across its blocks."""
+    stays in VMEM across its blocks. `name` is the custom call's (a decay
+    a key channel, gc [nb, H, 1, dk], runs as "kda_chunk")."""
     from gridllm_tpu.ops.linear_attn import head_pack
 
     dk, hd = state.shape
@@ -1249,7 +1263,9 @@ def gdn_chunk(state, wy, keep, *, heads: int, interpret: bool = False):
             pl.BlockSpec((dk, lanes), lambda h, i, *_: (0, h)),
             pl.BlockSpec((None, c, lanes), lambda h, i, *_: (i, 0, h)),
             per_head(dk), per_head(c), per_head(dk), per_head(dk),
-            pl.BlockSpec((None, 1, lanes), lambda h, i, *_: (i, 0, h)),
+            pl.BlockSpec((None, 1, lanes), lambda h, i, *_: (i, 0, h))
+            if wy["gc"].ndim == 3 else pl.BlockSpec(
+                (None, pack, 1, dk), lambda h, i, *_: (i, h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, c, lanes), lambda h, i, *_: (i, 0, h)),
@@ -1269,7 +1285,7 @@ def gdn_chunk(state, wy, keep, *, heads: int, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="gdn_chunk",
+        name=name,
     )(keep.astype(jnp.int32), state, wy["wv"], wy["wk"], wy["aqk"],
       wy["qg"], wy["kd"], wy["gc"])
 
@@ -1299,7 +1315,7 @@ def _gdn_step_kernel(layer_ref, order_ref, live_ref, s_in, wv_ref, wk_ref,
 
 
 def gdn_step(states, layer, order, n_live, wy, *, heads: int,
-             interpret: bool = False):
+             interpret: bool = False, name: str = "gdn_step"):
     """Every LIVE slot's pending rows committed and its new rows run.
     states [Ll, S, dk, H*dv] float32 (every linear layer; `layer` picks,
     updated IN PLACE: input_output_aliases); order [S] int32 the slots,
@@ -1337,7 +1353,12 @@ def gdn_step(states, layer, order, n_live, wy, *, heads: int,
         num_scalar_prefetch=3,
         grid=(heads // pack, slots),
         in_specs=[state_spec, packed(c), per_head(dk), per_head(c),
-                  per_head(dk), per_head(dk), packed(1)],
+                  per_head(dk), per_head(dk),
+                  # the decay: packed on the lanes, or a key row a head
+                  packed(1) if wy["gc"].ndim == 4 else pl.BlockSpec(
+                      (None, 2, pack, 1, dk),
+                      lambda h, g, li, order, live: (
+                          slot(g, order, live), 0, h, 0, 0))],
         out_specs=[
             state_spec,
             pl.BlockSpec(
@@ -1357,7 +1378,7 @@ def gdn_step(states, layer, order, n_live, wy, *, heads: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="gdn_step",
+        name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1), order.astype(jnp.int32),
       jnp.asarray(n_live, jnp.int32).reshape(1), states, wy["wv"], wy["wk"],
       wy["aqk"], wy["qg"], wy["kd"], wy["gc"])
