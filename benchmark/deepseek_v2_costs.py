@@ -68,7 +68,8 @@ def weight_bytes(spec: dict) -> int:
 
 
 def expert_bytes(spec: dict) -> int:
-    """Every held routed expert of every routed layer."""
+    """EVERY held routed expert of every routed layer (``held_experts`` x
+    ``one_expert_bytes``)."""
     return (layer_counts(spec)[1] * spec["n_routed_experts"]
             * expert_params(spec) * DTYPE_BYTES[spec.get("dtype", "bfloat16")])
 
@@ -83,14 +84,32 @@ def expert_layer_bytes(spec: dict) -> int:
             * DTYPE_BYTES[spec.get("dtype", "bfloat16")])
 
 
-def step_weight_bytes(spec: dict) -> int:
-    """Weight bytes one decode or verify step reads AT MOST: every layer
-    with every held expert and the output head; of the embedding only the
-    rows looked up. An upper bound: a launch whose rows leave experts
-    untouched need not read those."""
+def one_expert_bytes(spec: dict) -> int:
+    """ONE routed expert of one layer (8.65 M parameters): what a launch
+    has to read for each expert its live rows touch."""
+    return expert_params(spec) * DTYPE_BYTES[spec.get("dtype", "bfloat16")]
+
+
+def held_experts(spec: dict) -> int:
+    """Routed experts a launch passes, summed over the routed layers: what
+    ``gridllm_moe_experts_touched_total`` reads a launch at the most."""
+    return layer_counts(spec)[1] * spec["n_routed_experts"]
+
+
+def step_weight_bytes(spec: dict, touched: float | None = None) -> float:
+    """Weight bytes one decode or verify step has to read: attention, the
+    norms, the dense layer, every router and shared expert and the output
+    head whole, of the embedding only the rows looked up, and of the
+    routed experts the `touched` ones (experts with at least one live row,
+    summed over the layers: the engine's counter a launch). With no
+    `touched`: every held expert, AT MOST what a launch reads, which the
+    all-experts form reads whatever the rows."""
     b = DTYPE_BYTES[spec.get("dtype", "bfloat16")]
     head = spec["vocab_size"] * spec["hidden_size"]
-    return (total_params(spec) - embedding_params(spec) + head) * b
+    whole = (total_params(spec) - embedding_params(spec) + head) * b
+    if touched is None:
+        return whole
+    return whole - (held_experts(spec) - touched) * one_expert_bytes(spec)
 
 
 def kv_row_values(spec: dict) -> int:

@@ -26,7 +26,8 @@ size among an operation's shapes: the projections).
 
 The held experts' products are found by their stacked weights ``[held, E,
 F]`` / ``[held, F, E]``, the all-experts intermediate ``[rows.., held,
-F]``, or XLA's ``ragged-dot`` (the sorted form of a mixed launch).
+F]``, XLA's ``ragged-dot`` (the sorted form of a mixed launch), or a
+kernel named ``grouped_experts`` (``readers.GROUPED_OPS``).
 """
 
 from __future__ import annotations
@@ -44,7 +45,6 @@ STEP_PROGRAMS = gdn.STEP_PROGRAMS
 CHUNK_PROGRAMS = gdn.CHUNK_PROGRAMS
 PREFIX = "gridllm_state_prefix_total"
 PICKS = "gridllm_moe_picks_total"
-TOUCHED = "gridllm_moe_experts_touched_total"
 
 chunk_rows_per_launch = gdn.chunk_rows_per_launch
 live_slots_per_launch = gdn.live_slots_per_launch
@@ -134,7 +134,7 @@ def held_pattern(spec: dict) -> str | None:
     except (KeyError, TypeError, ValueError):
         return None
     return (rf"ragged-dot|[\[,]{x},{e},{f}\]|[\[,]{x},{f},{e}\]"
-            rf"|\[(\d+,)+{x},{f}\]")
+            rf"|\[(\d+,)+{x},{f}\]|" + readers.GROUPED_OPS)
 
 
 def held_ops(run: dict, programs: str = STEP_PROGRAMS) -> list[dict]:

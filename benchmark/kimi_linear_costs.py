@@ -116,22 +116,34 @@ def expert_bytes(spec: dict) -> int:
     return expert_params(spec) * DTYPE_BYTES[spec.get("dtype", "bfloat16")]
 
 
+def held_experts(spec: dict) -> int:
+    """Held routed experts a launch passes, summed over the expert layers:
+    what ``gridllm_moe_experts_touched_total`` reads a launch at the most."""
+    return spec["num_experts"] * layer_counts(spec)[1]
+
+
 def held_expert_bytes(spec: dict, touched: float | None = None) -> float:
     """Bytes of routed experts one launch reads, every expert layer:
     `touched` experts (summed over layers: the engine's counter a launch),
     else every held one."""
     if touched is None:
-        touched = spec["num_experts"] * layer_counts(spec)[1]
+        touched = held_experts(spec)
     return float(touched) * expert_bytes(spec)
 
 
-def step_weight_bytes(spec: dict) -> int:
-    """Weight bytes one decode or verify step reads AT MOST: every layer
-    with every held expert and the output head; of the embedding only the
-    rows looked up."""
+def step_weight_bytes(spec: dict, touched: float | None = None) -> float:
+    """Weight bytes one decode or verify step has to read: the mixers, the
+    norms, the dense layer, every router and shared expert and the output
+    head whole, of the embedding only the rows looked up, and of the held
+    experts the `touched` ones (``held_expert_bytes``). With no `touched`:
+    every held expert, AT MOST what a launch reads, which the all-experts
+    form reads whatever the rows."""
     b = DTYPE_BYTES[spec.get("dtype", "bfloat16")]
     head = spec["vocab_size"] * spec["hidden_size"]
-    return (total_params(spec) - embedding_params(spec) + head) * b
+    whole = (total_params(spec) - embedding_params(spec) + head) * b
+    if touched is None:
+        return whole
+    return whole - (held_experts(spec) - touched) * expert_bytes(spec)
 
 
 def kv_bytes_per_token(spec: dict, kv_dtype_bytes: int = 2) -> int:

@@ -103,13 +103,27 @@ def expert_flops(spec: dict, rows: float) -> float:
     return 2.0 * rows * spec["num_experts_per_tok"] * expert_params(spec)
 
 
-def step_weight_bytes(spec: dict) -> int:
-    """Weight bytes one decode or verify step reads AT MOST: every layer
-    with every held expert and the output head; of the embedding only the
-    rows looked up."""
+def held_experts(spec: dict) -> int:
+    """Routed experts a launch passes, summed over the expert layers: what
+    ``gridllm_moe_experts_touched_total`` reads a launch at the most."""
+    return layer_counts(spec)[1] * spec["num_experts"]
+
+
+def step_weight_bytes(spec: dict, touched: float | None = None) -> float:
+    """Weight bytes one decode or verify step has to read: attention, the
+    norms, the dense layer, every router and shared expert and the output
+    head whole, of the embedding only the rows looked up, and of the
+    routed experts the `touched` ones (experts with at least one live row,
+    summed over the layers: the engine's counter a launch) at
+    ``expert_bytes`` each. With no `touched`: every held expert, AT MOST
+    what a launch reads, which the all-experts form reads whatever the
+    rows."""
     b = DTYPE_BYTES[spec.get("dtype", "bfloat16")]
     head = spec["vocab_size"] * spec["hidden_size"]
-    return (total_params(spec) - embedding_params(spec) + head) * b
+    whole = (total_params(spec) - embedding_params(spec) + head) * b
+    if touched is None:
+        return whole
+    return whole - (held_experts(spec) - touched) * expert_bytes(spec)
 
 
 def row_bytes(spec: dict, kv_dtype_bytes: int = 2) -> int:
@@ -124,7 +138,7 @@ def kv_bytes_per_token(spec: dict, kv_dtype_bytes: int = 2,
     which is what a position costs in pages and what a launch reads of it
     whatever the context's length; a window layer keeps and reads a window
     of rows a slot, not the context. The readers that cannot be told of the
-    window (``step.verify_mem_roofline_pct``,
+    window (``step.verify_mem_mfu_pct``,
     ``kernel.ragged_decode_roofline_pct``) take this and so leave out the
     window layers' reads (3 x 512 rows x 4,096 B = 6.3 MB a live slot, a
     fifth of a launch's KV bytes at a context of 2,700): they read low

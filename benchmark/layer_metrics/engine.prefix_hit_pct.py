@@ -3,7 +3,7 @@ in the window (``gridllm_prefix_cache_hits_total`` and ``_misses_total``;
 a page is ``pageSize`` prompt tokens)."""
 import readers
 
-NAME, UNIT, LAYER, MOVES = "engine.prefix_hit_pct", "%", "engine admission", "ttft_p50_ms"
+NAME, UNIT, LAYER, MOVES = "engine.prefix_hit_pct", "%", "engine admission", "ttft_p85_ms"
 
 
 def compute(run):

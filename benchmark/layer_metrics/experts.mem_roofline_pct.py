@@ -1,13 +1,19 @@
 """A deepseek_v2 expert layer's share of its memory roofline inside the
-verify (or decode) program, in the traced window: the bytes of the held
-router, routed and shared experts of every routed layer
-(``expert_layer_bytes`` of the configuration's costs: an upper bound on
-what a launch has to read, which the all-experts form reads whatever the
-rows; ``experts.touched_pct`` says how much of it the rows needed) over
-the chip's memory bandwidth, over the device time a launch of the
-operations ``experts.time_pct`` counts (``mla.expert_ops`` inside
-``readers.VERIFY_PROGRAMS``). Bound named: memory (6 of 64 experts a row:
-each expert's 17 MB is read for a handful of rows)."""
+verify (or decode) program, over the capture: the bytes of the routed
+experts a launch's live rows TOUCHED (``phases.touched_per_launch``:
+``gridllm_moe_experts_touched_total`` over the launches, both between the
+capture's two ends, times ``one_expert_bytes`` of the configuration's
+costs: what the model needs read of them, whichever form reads it; the
+router's and the shared experts' bytes, 3 % of a layer's, are left out,
+so it reads low, never high) over the chip's memory bandwidth, over the
+device time a launch of the operations ``experts.time_pct`` counts
+(``mla.expert_ops`` inside ``readers.VERIFY_PROGRAMS``). The all-experts
+form reads every held expert whatever the rows, so it reads here at its
+share of the bandwidth times ``experts.touched_pct``. A capture without
+the counter is charged the held router, routed and shared experts of every
+routed layer (``expert_layer_bytes``), AT MOST what a launch reads. Bound
+named: memory (6 of 64 experts a row: each expert's 17 MB is read for a
+handful of rows)."""
 import costs
 import mla
 import phases
@@ -27,5 +33,7 @@ def compute(run):
     if not n or not secs or peak is None or not share or not hasattr(
             count, "expert_layer_bytes"):
         return None
-    need = count.expert_layer_bytes(run["config"]) / share["weights"]
-    return 100.0 * (need / peak) / (secs / n)
+    touched = phases.touched_per_launch(run)
+    need = (count.expert_layer_bytes(run["config"]) if touched is None
+            else touched * count.one_expert_bytes(run["config"]))
+    return 100.0 * (need / share["weights"] / peak) / (secs / n)

@@ -7,7 +7,7 @@ convolution rows beside them)."""
 import kda
 import readers
 
-NAME, UNIT, LAYER, MOVES = "hybrid.restore_hit_pct", "%", "KV pool", "ttft_p50_ms"
+NAME, UNIT, LAYER, MOVES = "hybrid.restore_hit_pct", "%", "KV pool", "itl_p95_ms"
 CELLS = ["kimilinear.agent_turns"]
 
 

@@ -13,7 +13,7 @@ import kda
 import readers
 
 NAME, UNIT, LAYER, MOVES = ("kda.chunk_roofline_pct", "%", "recurrent state",
-                            "ttft_p50_ms")
+                            "itl_p95_ms")
 CELLS = ["kimilinear.agent_turns"]
 
 

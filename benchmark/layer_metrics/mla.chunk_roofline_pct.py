@@ -14,7 +14,7 @@ import mla
 import readers
 
 NAME, UNIT, LAYER, MOVES = ("mla.chunk_roofline_pct", "%",
-                            "latent attention", "ttft_p50_ms")
+                            "latent attention", "itl_p95_ms")
 CELLS = ["dsv2lite.shared_doc"]
 
 

@@ -1,12 +1,16 @@
 """The grouped expert products' share of their memory roofline inside the
-verify (or decode) program, in the traced window: the bytes of the held
-experts a launch has to read (``expert_bytes`` of the configuration's
-costs: every expert of every layer, an upper bound that a launch of 80
-routed rows over 64 experts all but reaches; ``moe.experts_touched_pct``
-says how nearly) over the chip's memory bandwidth, over the products'
-device time a launch (``moe.expert_ops`` inside ``readers.VERIFY_PROGRAMS``).
-Bound named: memory (6 of 64 experts a row: each expert's 5.9 MB is read
-for a handful of rows)."""
+verify (or decode) program, over the capture: the bytes of the experts a
+launch's live rows TOUCHED (``phases.touched_per_launch``:
+``gridllm_moe_experts_touched_total`` over the launches, both between the
+capture's two ends, times ``one_expert_bytes`` of the configuration's
+costs: what the model needs read, whichever form reads it) over the
+chip's memory bandwidth, over the products' device time a launch
+(``moe.expert_ops`` inside ``readers.VERIFY_PROGRAMS``). The all-experts
+form reads every held expert whatever the rows, so it reads here at its
+share of the bandwidth times ``moe.experts_touched_pct``. A capture
+without the counter is charged every expert of every layer
+(``expert_bytes``), AT MOST what a launch reads. Bound named: memory (6 of
+64 experts a row: each expert's 5.9 MB is read for a handful of rows)."""
 import costs
 import moe
 import phases
@@ -26,5 +30,7 @@ def compute(run):
     if not n or not secs or peak is None or not share or not hasattr(
             count, "expert_bytes"):
         return None
-    need = count.expert_bytes(run["config"]) / share["weights"]
-    return 100.0 * (need / peak) / (secs / n)
+    touched = phases.touched_per_launch(run)
+    need = (count.expert_bytes(run["config"]) if touched is None
+            else touched * count.one_expert_bytes(run["config"]))
+    return 100.0 * (need / share["weights"] / peak) / (secs / n)

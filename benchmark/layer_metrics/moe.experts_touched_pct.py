@@ -1,9 +1,10 @@
 """Of the experts the chip holds, the share a verify / decode launch's
 live rows touch, over the window: ``gridllm_moe_experts_touched_total``
 (experts with at least one live row, summed over layers and launches) over
-experts x layers x launches (``_count{phase="dispatch_verify"}``). Lower
-means a launch could read fewer expert bytes than
-``moe.expert_mem_roofline_pct`` charges it."""
+experts x layers x launches (``_count{phase="dispatch_verify"}``). It is
+the share of the held experts' bytes that ``moe.expert_mem_roofline_pct``
+and ``step.verify_mem_mfu_pct`` charge a launch; lower means the
+all-experts form, which reads every expert, stands further above it."""
 import moe
 import phases
 

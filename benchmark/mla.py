@@ -69,7 +69,8 @@ def latent_ops(run: dict, programs: str = STEP_PROGRAMS) -> list[dict]:
 
 
 def expert_pattern(spec: dict) -> str | None:
-    """Router, routed and shared products of a deepseek_v2 expert layer."""
+    """Router, routed and shared products of a deepseek_v2 expert layer,
+    a kernel named ``grouped_experts`` (``readers.GROUPED_OPS``) among them."""
     try:
         x, e, f = (int(spec["n_routed_experts"]), int(spec["hidden_size"]),
                    int(spec["moe_intermediate_size"]))
@@ -78,7 +79,8 @@ def expert_pattern(spec: dict) -> str | None:
         return None
     shared = rf"|[\[,]{e},{fs}\]|[\[,]{fs},{e}\]|\[(\d+,)+{fs}\]" if fs else ""
     return (rf"ragged-dot|[\[,]{x},{e},{f}\]|[\[,]{x},{f},{e}\]"
-            rf"|\[(\d+,)+{x},{f}\]|f32\[(\d+,)+{x}\]|[\[,]{e},{x}\]" + shared)
+            rf"|\[(\d+,)+{x},{f}\]|f32\[(\d+,)+{x}\]|[\[,]{e},{x}\]" + shared
+            + "|" + readers.GROUPED_OPS)
 
 
 def expert_ops(run: dict, programs: str = STEP_PROGRAMS) -> list[dict]:

@@ -14,17 +14,18 @@ patterns here go by what that line does show, read off the chip's trace
 (PERF.md, PR 33): an expert product has the stacked expert weights
 ``[.., X, E, F]`` / ``[.., X, F, E]`` or the all-experts intermediate
 ``[rows.., X, F]`` among its shapes, or is XLA's ``ragged-dot`` custom
-call; the router is what produces or sorts float32 ``[rows.., X]``.
+call or a kernel named ``grouped_experts`` (``readers.GROUPED_OPS``); the
+router is what produces or sorts float32 ``[rows.., X]``.
 """
 
 from __future__ import annotations
 
 import re
 
+import phases
 import readers
 import stack
 
-TOUCHED = "gridllm_moe_experts_touched_total"
 STEP_PROGRAMS = readers.VERIFY_PROGRAMS + "|" + readers.PREFILL_PROGRAMS
 
 
@@ -42,7 +43,7 @@ def expert_pattern(spec: dict) -> str | None:
         return None
     x, e, f = s
     return (rf"ragged-dot|[\[,]{x},{e},{f}\]|[\[,]{x},{f},{e}\]"
-            rf"|\[(\d+,)+{x},{f}\]")
+            rf"|\[(\d+,)+{x},{f}\]|" + readers.GROUPED_OPS)
 
 
 def router_pattern(spec: dict) -> str | None:
@@ -72,4 +73,5 @@ def router_ops(run: dict, programs: str = STEP_PROGRAMS) -> list[dict]:
 def touched(before: str, after: str) -> float:
     """Experts touched (summed over layers and launches) between two
     ``/metrics`` texts."""
-    return stack.metric_sum(after, TOUCHED) - stack.metric_sum(before, TOUCHED)
+    return (stack.metric_sum(after, phases.TOUCHED)
+            - stack.metric_sum(before, phases.TOUCHED))

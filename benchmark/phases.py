@@ -1,5 +1,6 @@
-"""What the ``runner.*`` readers and the two roofline readers share: the
-engine runner's phase series and the verify step's context counter.
+"""What the ``runner.*`` readers and the roofline readers share: the
+engine runner's phase series, the verify step's context counter and the
+routed families' touched-experts counter, a launch of the capture.
 
 ``gridllm_engine_phase_seconds{model,phase}`` partitions the runner
 thread's wall time (``obs/perf.py`` ``PhaseClock``): ``idle_wait``, ``ctl``,
@@ -17,6 +18,7 @@ import stack
 
 SERIES = "gridllm_engine_phase_seconds"
 CTX_TOKENS = "gridllm_engine_verify_ctx_tokens_total"
+TOUCHED = "gridllm_moe_experts_touched_total"
 IDLE, FETCH, LAUNCH = "idle_wait", "fetch", "dispatch_verify"
 
 
@@ -55,22 +57,39 @@ def chip_share(run: dict) -> dict | None:
     return costs.of(run["config"]).chip_share(run["config"])
 
 
-def kv_bytes_per_launch(run: dict) -> float | None:
-    """Mean KV bytes one verify / decode launch has to read ON ONE CHIP,
-    over the capture: the context-token counter's change over the
-    launches' (both from ``trace_counters``, the worker's ``/metrics`` at
-    the capture's two ends) times the bytes of one position over every
-    layer, over the chips its KV heads are split across."""
-    ends, share = run.get("trace_counters"), chip_share(run)
-    if not ends or not share:
+def capture_per_launch(run: dict, name: str) -> float | None:
+    """A counter's change over the capture a verify / decode launch: both
+    from ``trace_counters``, the worker's ``/metrics`` at the capture's two
+    ends. None without them, without a launch or where the counter did not
+    move (a program or a family that has no such counter)."""
+    ends = run.get("trace_counters")
+    if not ends:
         return None
     launches = between(*ends).get(LAUNCH, (0.0, 0.0))[1]
-    tokens = (stack.metric_sum(ends[1], CTX_TOKENS)
-              - stack.metric_sum(ends[0], CTX_TOKENS))
-    if launches <= 0 or tokens <= 0:
+    moved = stack.metric_sum(ends[1], name) - stack.metric_sum(ends[0], name)
+    if launches <= 0 or moved <= 0:
+        return None
+    return moved / launches
+
+
+def touched_per_launch(run: dict) -> float | None:
+    """Routed experts with at least one live row, summed over the layers,
+    a verify / decode launch of the capture: what a launch has to read of
+    the experts it holds, in experts (a costs file's ``one_expert_bytes``
+    or ``expert_bytes`` each). None for a dense family."""
+    return capture_per_launch(run, TOUCHED)
+
+
+def kv_bytes_per_launch(run: dict) -> float | None:
+    """Mean KV bytes one verify / decode launch has to read ON ONE CHIP,
+    over the capture: the context-token counter's change a launch
+    (``capture_per_launch``) times the bytes of one position over every
+    layer, over the chips its KV heads are split across."""
+    tokens, share = capture_per_launch(run, CTX_TOKENS), chip_share(run)
+    if tokens is None or not share:
         return None
     per_token = costs.of(run["config"]).kv_bytes_per_token(run["config"])
-    return tokens / launches * (per_token / share["kv"])
+    return tokens * (per_token / share["kv"])
 
 
 def verify_launches(run: dict) -> tuple[float, int]:

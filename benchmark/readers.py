@@ -6,7 +6,7 @@ returns a number or None (nothing to read: the metric is left out).
 ``run`` is a dict the harness fills:
 
 - ``requests``, ``outcomes``: the window's schedule and what came back;
-- ``seconds``, ``t0``: the window;
+- ``seconds``, ``t0``, ``drain_s``: the window and the drain behind it;
 - ``worker_before``/``worker_after``, ``gateway_before``/``gateway_after``:
   ``/metrics`` text when the window opened and after the drain;
 - ``samples``: ``[(monotonic time, worker /metrics text), ...]`` taken
@@ -77,8 +77,15 @@ def first_device_busy_s(run: dict):
 # Python function that wraps it: ``%ragged_attention.N``, and ``%vmap__.N``
 # for the flash prefill kernel (``jax.vmap(one)`` in ``flash_prefill``),
 # whose result is ``[T, KV heads, group, head dim]``: one chip's KV heads
-# under a mesh, where the kernel runs inside a ``shard_map``.
+# under a mesh, where the kernel runs inside a ``shard_map``. A grouped
+# expert product that reads the touched experts alone (ROADMAP S9) is to
+# be a Pallas kernel whose wrapper is named ``grouped_experts``: its
+# weight operands may stand past the 240 characters ``trace_reduce.py``
+# keeps of a line, so the four families' expert patterns (``moe.py``,
+# ``mla.py``, ``routed.py``, ``kda.py``) know it by name; no program emits
+# the name yet.
 VERIFY_PROGRAMS = r"verify_block|decode_block"
 PREFILL_PROGRAMS = r"prefill|mixed_chunk"
 RAGGED_OPS = r"^%ragged_attention[.\d]* = .*custom-call\("
+GROUPED_OPS = r"^%grouped_experts[.\d]* = .*custom-call\("
 FLASH_OPS = r"^%vmap__[.\d]* = \w+\[(\d+),(\d+),(\d+),(\d+)\][^ ]* custom-call\("

@@ -29,7 +29,6 @@ import re
 import readers
 import stack
 
-TOUCHED = "gridllm_moe_experts_touched_total"
 PREFIX = "gridllm_state_prefix_total"
 STEP_PROGRAMS = readers.VERIFY_PROGRAMS + "|" + readers.PREFILL_PROGRAMS
 MIXED_PROGRAMS = r"mixed_chunk"
@@ -59,14 +58,16 @@ def _sorted_rows(spec: dict) -> list[int]:
 
 
 def product_pattern(spec: dict) -> str | None:
-    """The routed experts' three products, in either form."""
+    """The routed experts' three products, in either form or in a kernel
+    named ``grouped_experts`` (``readers.GROUPED_OPS``)."""
     s = shapes(spec)
     if s is None:
         return None
     x, e, f = s["num_experts"], s["hidden_size"], s["moe_intermediate_size"]
     rows = "|".join(str(n) for n in _sorted_rows(spec))
     return (rf"ragged-dot|[\[,]{x},{e},{f}\]|[\[,]{x},{f},{e}\]"
-            rf"|\[(\d+,)+{x},{f}\]|\[({rows}),({e}|{f})\]")
+            rf"|\[(\d+,)+{x},{f}\]|\[({rows}),({e}|{f})\]|"
+            + readers.GROUPED_OPS)
 
 
 def layer_pattern(spec: dict) -> str | None:

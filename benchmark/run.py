@@ -9,8 +9,10 @@ wait until the worker has registered with its model -> warm-up of this
 cell's shapes -> read counters -> a window of ``--seconds`` of open-loop
 traffic over HTTP -> drain -> read counters -> stop the processes ->
 reference check (and, with ``--trace 1``, the trace's reduction) in
-children -> ONE JSON line, the last of standard output. Everything else
-goes on earlier lines or under ``benchmark/out/``.
+children -> ONE JSON line, the last of standard output (its last key,
+``compared``, has each number `correct` compares beside its limit; the
+same numbers are the last lines of standard error). Everything else goes
+on earlier lines or under ``benchmark/out/``.
 
 A cell is found by name: ``BENCHMARK.json`` gives its configuration and
 traffic mix, ``workloads/<cell>.json`` its rate, ``configs/<config>.json``
@@ -41,7 +43,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
-import costs  # noqa: E402
 import launch_worker  # noqa: E402
 import loadgen  # noqa: E402
 import stack as st  # noqa: E402
@@ -360,7 +361,7 @@ def window(run: Run, requests, seconds: float, traced: bool, keep) -> dict:
     outcomes = run.play(requests, t0, t0 + seconds + DRAIN_S, keep, background)
     worker_after, gateway_after = run.scrape()
     return {"requests": requests, "outcomes": outcomes, "seconds": seconds,
-            "t0": t0, "worker_before": worker_before,
+            "drain_s": DRAIN_S, "t0": t0, "worker_before": worker_before,
             "worker_after": worker_after, "gateway_before": gateway_before,
             "gateway_after": gateway_after, "samples": samples,
             "trace_counters": got.get("counters"), "capture": got.get("capture")}
@@ -477,6 +478,12 @@ def finish(run: Run, w: dict, device: dict, memory: dict, bad: list,
         "no_engine_failure": not bad, "no_hang_requeue": not live_hangs,
         "streams_well_formed": not malformed,
     }
+    # each number `correct` compares, beside its limit
+    w["compared"] = {"jnp_kernel_paths": [jnp_built, 0],
+                     "recompiles_in_window": [recompiled, 0],
+                     "engine_failures": [len(bad), 0],
+                     "hang_requeues": [len(live_hangs), 0],
+                     "malformed_streams": [len(malformed), 0]}
     for text in malformed[:3]:
         say("malformed: " + text)
     for o in [o for o in outs if stats.failed(o)][:3]:
@@ -546,6 +553,12 @@ def after_stop(run: Run, w: dict, result: dict, checks: dict,
             run.cell.config["reference"].get("timeout_s", 300)) or {}
         say("reference: " + json.dumps(ref)[:1500])
         checks["reference_agrees"] = bool(ref.get("agrees"))
+        for rec in ref.get("records", []):
+            w["compared"][f"shortfall_r{rec['index']}"] = [
+                rec["worst_shortfall"], rec["allowed_there"]]
+        if "mean_shortfall" in ref:
+            w["compared"]["mean_shortfall"] = [ref["mean_shortfall"],
+                                               ref["mean_allowed"]]
     else:
         checks["reference_agrees"] = False
     result["correct"] = all(checks.values())
@@ -569,39 +582,19 @@ def after_stop(run: Run, w: dict, result: dict, checks: dict,
             result["breakdown"] = tr["breakdown"]
         if tr:
             say("programs: " + json.dumps(tr["programs"])[:1500])
-            say_verify_roofline(w)
         say("end to end in the traced run: " + json.dumps(e2e))
     else:
         for name in run.cell.metric_names("end_to_end"):
             result["metrics"][name] = {"value": e2e[name], "unit": units[name]}
     if run.rehearse:
         result["rehearsal"] = True
+    # last in the line and last on standard error: number, then limit
+    result["compared"] = w["compared"]
+    for name, (value, limit) in w["compared"].items():
+        print(f"compared {name}: {value} limit {limit}", file=sys.stderr)
+    print(f"correct: {result['correct']} checks: {json.dumps(checks)}",
+          file=sys.stderr, flush=True)
     return result
-
-
-def say_verify_roofline(w: dict) -> None:
-    """A second reading of ``step.verify_mem_roofline_pct`` from the
-    sampled pages-in-use gauge: the first chip's share of (weight bytes a
-    step reads + mean live KV bytes) over the chip's bandwidth, over the
-    program's mean device time a launch on the first chip."""
-    p = next((v for k, v in w["trace"]["programs"].items()
-              if "verify_block" in k), None)
-    used = [st.metric_sum(t, "gridllm_engine_kv_pages_used")
-            for _, t in w["samples"]]
-    spec = w["config"]
-    count = costs.of(spec)
-    share = count.chip_share(spec)
-    if not p or not used or not share or w["device"]["platform"] != "tpu":
-        return
-    page = (w["pool"] or {}).get("pageSize", 128)
-    kv = (sum(used) / len(used) * page * count.kv_bytes_per_token(spec)
-          / share["kv"])
-    weights = count.step_weight_bytes(spec) / share["weights"]
-    need = (weights + kv) / costs.peaks(w["device"]["kind"])["hbm_bytes_per_s"]
-    say(f"verify step memory-roofline share: "
-        f"{100.0 * need / (p['seconds'] / p['count']):.1f}% "
-        f"(one chip's weights {weights / 1e9:.2f} GB + mean live "
-        f"KV {kv / 1e9:.2f} GB a step, {1e3 * p['seconds'] / p['count']:.2f} ms a launch)")
 
 
 def _trace_file(path: str) -> bool:

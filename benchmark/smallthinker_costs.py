@@ -43,21 +43,38 @@ def weight_bytes(spec: dict) -> int:
 
 
 def expert_bytes(spec: dict) -> int:
-    """Every held expert of every layer: what a launch reads of them when
-    its rows touch them all."""
+    """EVERY held expert of every layer: what a launch reads of them when
+    its rows touch them all (``held_experts`` x ``one_expert_bytes``)."""
     return (spec["num_hidden_layers"] * spec["moe_num_primary_experts"]
             * expert_params(spec) * DTYPE_BYTES[spec.get("dtype", "bfloat16")])
 
 
-def step_weight_bytes(spec: dict) -> int:
-    """Weight bytes one decode or verify step reads AT MOST: every layer
-    with every held expert and the output head; of the embedding only the
-    rows looked up. An upper bound: a launch whose rows leave experts
-    untouched need not read those (80 rows x top-6 over 64 experts touch
-    nearly all; moe.experts_touched_pct says how many)."""
+def one_expert_bytes(spec: dict) -> int:
+    """ONE routed expert of one layer (5.9 M parameters): what a launch
+    has to read for each expert its live rows touch."""
+    return expert_params(spec) * DTYPE_BYTES[spec.get("dtype", "bfloat16")]
+
+
+def held_experts(spec: dict) -> int:
+    """Routed experts a launch passes, summed over the layers: what
+    ``gridllm_moe_experts_touched_total`` reads a launch at the most."""
+    return spec["num_hidden_layers"] * spec["moe_num_primary_experts"]
+
+
+def step_weight_bytes(spec: dict, touched: float | None = None) -> float:
+    """Weight bytes one decode or verify step has to read: attention, the
+    router and the norms of every layer and the output head whole, of the
+    embedding only the rows looked up, and of the routed experts the
+    `touched` ones (experts with at least one live row, summed over the
+    layers: the engine's counter a launch). With no `touched`: every held
+    expert, AT MOST what a launch reads, which the all-experts form reads
+    whatever the rows."""
     b = DTYPE_BYTES[spec.get("dtype", "bfloat16")]
     head = spec["vocab_size"] * spec["hidden_size"]
-    return (spec["num_hidden_layers"] * layer_params(spec) + head) * b
+    whole = (spec["num_hidden_layers"] * layer_params(spec) + head) * b
+    if touched is None:
+        return whole
+    return whole - (held_experts(spec) - touched) * one_expert_bytes(spec)
 
 
 def kv_bytes_per_token(spec: dict, kv_dtype_bytes: int = 2) -> int:

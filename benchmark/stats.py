@@ -33,6 +33,12 @@ def ttfts_ms(outcomes: list, worst_ms: float) -> list[float]:
     return out
 
 
+def client_ttfts_ms(run: dict) -> list[float]:
+    """`ttfts_ms` of a window as the readers get it (``readers.py``)."""
+    return ttfts_ms(run["outcomes"],
+                    (run["seconds"] + run["drain_s"]) * 1e3)
+
+
 def gaps_ms(outcomes: list, worst_ms: float) -> list[float]:
     """Gaps between successive content frames, pooled over all streams;
     a failed stream adds one gap of `worst_ms`."""

@@ -58,7 +58,10 @@ def test_every_cell_reports_it(cell):
                      "better": "higher", "source": "program_counter",
                      "layer": "engine admission", "moves": "ttft_p50_ms"}
     assert "engine.chunk_fill_pct" in c.metric_names("per_layer")
-    assert "ttft_p50_ms" in c.metric_names("end_to_end")
+    # judged end to end where the median first token is (PERF.md section 2:
+    # not in mistral7b.shared_doc), read in every cell
+    assert ("ttft_p50_ms" in c.metric_names("end_to_end")) == (
+        cell != "mistral7b.shared_doc")
     mod = c.reader("engine.chunk_fill_pct")
     assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == (
         entry["name"], entry["unit"], entry["layer"], entry["moves"])

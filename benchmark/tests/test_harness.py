@@ -87,8 +87,13 @@ def test_manifest_agrees_with_the_files():
                 x for x in m["per_layer"] if x["name"] == name)
             assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == (
                 name, entry["unit"], entry["layer"], entry["moves"])
-            # the metric it should move is reported in this cell
-            assert entry["moves"] in cell.metric_names("end_to_end") and NAME.match(name)
+            # the metric it should move is reported in this cell; an entry
+            # with no `workloads` key is owed where that metric is reported
+            # and is read in the other cells too (ttft_p50_ms is judged in
+            # five cells of eight: PERF.md section 2)
+            assert NAME.match(name) and entry["moves"] in e2e
+            assert (entry["moves"] in cell.metric_names("end_to_end")
+                    or "workloads" not in entry)
         reported = cell.metric_names("end_to_end")
         assert "setup_s" in reported and len(reported) >= 2
         assert set(reported) <= set(harness.stats.end_to_end([harness.loadgen.Outcome(0, 0.0)], 0.0, 1.0, 1.0)) | {"setup_s"}

@@ -20,12 +20,12 @@ from conftest import BENCH
 CELL = "kimilinear.agent_turns"
 READERS = {
     "kda.time_pct": ("recurrent state", "itl_p95_ms"),
-    "kda.chunk_roofline_pct": ("recurrent state", "ttft_p50_ms"),
+    "kda.chunk_roofline_pct": ("recurrent state", "itl_p95_ms"),
     "kda.step_roofline_pct": ("recurrent state", "itl_p95_ms"),
     "held.time_pct": ("routed experts", "itl_p95_ms"),
     "held.mem_roofline_pct": ("routed experts", "itl_p95_ms"),
     "held.picks_pct": ("routed experts", "out_tok_s"),
-    "hybrid.restore_hit_pct": ("KV pool", "ttft_p50_ms"),
+    "hybrid.restore_hit_pct": ("KV pool", "itl_p95_ms"),
 }
 
 
@@ -39,8 +39,10 @@ def test_the_cell_and_its_files_are_found_by_name():
     for other in ("olmohybrid7b.agent_turns", "dsv2lite.shared_doc",
                   "laguna-xs2.agent_turns"):
         assert not set(READERS) & set(harness.Cell(other).metric_names("per_layer"))
+    # the median first token is read per layer here (PERF.md section 2)
     assert set(cell.metric_names("end_to_end")) == {
-        "ttft_p50_ms", "itl_p95_ms", "out_tok_s", "setup_s"}
+        "itl_p95_ms", "out_tok_s", "setup_s"}
+    assert {"gen.ttft_p50_ms", "gen.ttft_mean_ms"} <= set(names)
     entries = {m["name"]: m for m in cell.manifest["per_layer"]}
     for name, (layer, moves) in READERS.items():
         mod = cell.reader(name)
@@ -48,7 +50,9 @@ def test_the_cell_and_its_files_are_found_by_name():
             name, "%", layer, moves, [CELL])
         assert entries[name]["workloads"] == [CELL]
     # every new entry stands behind every entry that was there
-    assert [m["name"] for m in cell.manifest["per_layer"]][-7:] == list(READERS)
+    listed = [m["name"] for m in cell.manifest["per_layer"]]
+    at = listed.index("kda.time_pct")
+    assert listed[at:at + 7] == list(READERS)
     assert cell.manifest["workloads"][-1]["name"] == CELL
     assert cell.manifest["configs"][-1]["name"] == cell.config_name
 

@@ -7,6 +7,7 @@ import time
 
 import pytest
 
+import costs
 import phases
 import run as harness
 import trace_reduce
@@ -106,8 +107,8 @@ def test_roofline_readers_on_a_hand_made_run():
     run = a_run()
     kv = 4000 * 20 * 2 * 8 * 128 * 2           # tokens x layers x K,V x heads x dim x bf16
     assert phases.kv_bytes_per_launch(run) == pytest.approx(kv)
-    weights = harness.costs.step_weight_bytes(SPEC)
-    assert read("step.verify_mem_roofline_pct", run) == pytest.approx(
+    weights = costs.step_weight_bytes(SPEC)
+    assert read("step.verify_mem_mfu_pct", run) == pytest.approx(
         100.0 * ((weights + kv) / 819e9) / 0.017)
     # the chunk program's ragged calls are not the decode kernel's
     assert read("kernel.ragged_decode_roofline_pct", run) == pytest.approx(
@@ -115,18 +116,18 @@ def test_roofline_readers_on_a_hand_made_run():
     assert 0 < read("kernel.ragged_decode_roofline_pct", run) < 100
     # a CPU rehearsal has no roofline: nothing, not a KeyError from peaks.json
     run["device"] = {"platform": "cpu", "kind": "cpu", "count": 1}
-    assert read("step.verify_mem_roofline_pct", run) is None
+    assert read("step.verify_mem_mfu_pct", run) is None
     assert read("kernel.ragged_decode_roofline_pct", run) is None
 
 
 def before_this_pr(run: dict) -> dict:
-    """The three rooflines as their readers computed them before a chip's
-    share existed (PR 23-25's formulas, kept here as the record)."""
+    """The two shares as their readers computed them before a chip's share
+    existed (PR 23-25's formulas, kept here as the record)."""
     import re
 
     import readers
 
-    spec, costs = run["config"], harness.costs
+    spec = run["config"]
     ends = run["trace_counters"]
     launches = phases.between(*ends)[phases.LAUNCH][1]
     tokens = (harness.st.metric_sum(ends[1], phases.CTX_TOKENS)
@@ -135,46 +136,24 @@ def before_this_pr(run: dict) -> dict:
     secs, n = phases.verify_launches(run)
     ragged = sum(o["seconds"] for o in readers.ops(run, readers.RAGGED_OPS)
                  if re.search(readers.VERIFY_PROGRAMS, o["program"]))
-    need = fsecs = 0.0
-    for o in readers.ops(run, readers.FLASH_OPS):
-        t, kvh, group, d = (int(x) for x in re.search(readers.FLASH_OPS, o["text"]).groups())
-        if (kvh * group, d) == (spec["num_attention_heads"], costs.head_dim(spec)):
-            need += o["count"] * costs.flash_prefill_flops(spec, t)
-            fsecs += o["seconds"]
-    return {"step.verify_mem_roofline_pct":
+    return {"step.verify_mem_mfu_pct":
             100.0 * ((costs.step_weight_bytes(spec) + kv) / 819e9) / (secs / n),
-            "kernel.ragged_decode_roofline_pct": 100.0 * (kv / 819e9) / (ragged / n),
-            "kernel.flash_prefill_roofline_pct": 100.0 * (need / 197e12) / fsecs}
-
-
-def flash_op(shape: str) -> dict:
-    return {"seconds": 0.117, "count": 340, "program": "jit_prefill_fn", "text":
-            f"%vmap__.9 = bf16[{shape}]{{3,2,1,0:T(4,128)(2,1)S(1)}} custom-call(s32[1,2]{{1,0}} %y)"}
+            "kernel.ragged_decode_roofline_pct": 100.0 * (kv / 819e9) / (ragged / n)}
 
 
 def test_rooflines_take_one_chips_share():
     """One chip: bit-identical to what the readers gave before. ``tp:4``:
-    a quarter of the bytes and operations against the same (first chip's)
-    times, and the flash reader knows a chip's 8 KV heads / 4 = 2."""
+    a quarter of the bytes against the same (first chip's) times."""
     one = a_run()
-    one["trace"]["ops"]["jit_prefill_fn#4251/vmap__.9"] = flash_op("1024,8,4,128")
-    one["trace"]["ops"]["jit_prefill_fn#4251/vmap__.7"] = flash_op("512,8,4,128")
     was = before_this_pr(one)
     for name, value in was.items():
         assert read(name, one) == value, name          # ==, not approx
     four = a_run()
     four["config"] = dict(SPEC, mesh="tp:4", chips=4)
     four["device"]["count"] = 4
-    four["trace"]["ops"]["jit_prefill_fn#4251/vmap__.9"] = flash_op("1024,2,4,128")
-    four["trace"]["ops"]["jit_prefill_fn#4251/vmap__.7"] = flash_op("512,2,4,128")
-    # another model's kernel in the same trace is not this one's
-    four["trace"]["ops"]["jit_prefill_fn#4251/vmap__.5"] = flash_op("512,8,4,128")
     for name, value in was.items():
         assert read(name, four) == pytest.approx(value / 4, rel=1e-12), name
     assert phases.kv_bytes_per_launch(four) == phases.kv_bytes_per_launch(one) / 4
-    # the whole model's shape on one chip of four is not found, nor the reverse
-    four["trace"]["ops"] = {"p/vmap__.9": flash_op("1024,8,4,128")}
-    assert read("kernel.flash_prefill_roofline_pct", four) is None
     # an axis the costs have no rule for: nothing, never a guess
     four["config"] = dict(SPEC, mesh="ep:4", chips=4)
     for name in was:
@@ -185,7 +164,7 @@ def test_rooflines_take_one_chips_share():
 NEW = ["runner.period_ms", "runner.host_ms_per_step", "runner.fetch_wait_pct",
        "runner.ingest_ms_per_step", "runner.draft_ms_per_step",
        "runner.admit_ms_per_request", "engine.admit_wait_mean_ms",
-       "step.verify_mem_roofline_pct", "kernel.ragged_decode_roofline_pct"]
+       "step.verify_mem_mfu_pct", "kernel.ragged_decode_roofline_pct"]
 
 
 @pytest.mark.parametrize("name", NEW)

@@ -204,7 +204,15 @@ def recorded():
 def test_the_readers_on_what_the_chip_recorded(recorded):
     cell = harness.Cell(CELL)
     got = {name: cell.reader(name).compute(recorded) for name in READERS}
-    want = recorded["read_on_the_chip"]
+    want = dict(recorded["read_on_the_chip"])
+    # PR 33 charged a launch every expert held (91.9); since PR 52 the
+    # share charges the experts the capture's launches touched: 43,412
+    # over 147 launches = 295.3 of 768 a launch, so it reads 35.3
+    import phases
+
+    touched = phases.touched_per_launch(recorded)
+    assert touched == pytest.approx(43_412 / 147)
+    want["moe.expert_mem_roofline_pct"] *= touched / (12 * 64)
     for name in READERS:
         assert got[name] == pytest.approx(want[name], rel=1e-9), name
         assert 0 < got[name] <= 100

@@ -63,3 +63,29 @@ def test_well_formed():
     assert "eval_count=2" in stats.malformed(short, 3)
     short.done_reason = "stop"                                      # an EOS
     assert stats.malformed(short, 3) is None
+
+
+@pytest.mark.parametrize("cell", ["mistral7b.shared_doc", "dsv2lite.shared_doc",
+                                  "kimilinear.agent_turns"])
+def test_first_token_readers_of_the_cells_that_do_not_judge_the_median(cell):
+    """`gen.ttft_p50_ms` is `ttft_p50_ms`'s own arithmetic on the traced
+    run's outcomes, and `gen.ttft_mean_ms` the mean of the same samples: a
+    failed request enters both at window + drain; no outcomes, no number."""
+    import run as harness
+
+    c = harness.Cell(cell)
+    assert "ttft_p50_ms" not in c.metric_names("end_to_end")
+    outs = [outcome(0, 10.0, [(10.06, 1)]), outcome(1, 11.0, [(11.08, 1)]),
+            outcome(2, 12.0, [(12.25, 1)]), outcome(3, 13.0, [], error="HTTP 503")]
+    w = {"outcomes": outs, "seconds": 40.0, "drain_s": 10.0}
+    e2e = stats.end_to_end(outs, 10.0, 40.0, 10.0)
+    p50, mean = c.reader("gen.ttft_p50_ms"), c.reader("gen.ttft_mean_ms")
+    assert p50.compute(w) == e2e["ttft_p50_ms"] == pytest.approx(80.0)
+    assert mean.compute(w) == pytest.approx((60.0 + 80.0 + 250.0 + 50_000.0) / 4)
+    assert p50.compute({**w, "outcomes": []}) is None
+    assert mean.compute({**w, "outcomes": []}) is None
+    for mod in (p50, mean):
+        entry, = [m for m in c.manifest["per_layer"] if m["name"] == mod.NAME]
+        assert (mod.UNIT, mod.LAYER, mod.MOVES, mod.CELLS) == (
+            entry["unit"], entry["layer"], entry["moves"], entry["workloads"])
+        assert mod.MOVES in c.metric_names("end_to_end") and cell in mod.CELLS
