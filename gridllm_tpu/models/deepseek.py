@@ -199,6 +199,8 @@ def _stack(params: Params, cfg: ModelConfig, x, pos, attend: Attend,
 
     def body(x, xs):
         lp, li = xs
+        # the whole stack and the index, for the grouped experts' kernel
+        lp = {**lp, "layer_stack": (params["layers"], li - kd)}
         x, row, stats = _layer(cfg, lp, x, pos, inv_freq, attend, li, moe)
         return x, (row, stats)
 

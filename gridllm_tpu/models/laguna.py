@@ -211,11 +211,15 @@ def _stack(params: Params, cfg: ModelConfig, x, pos, attend: Attend,
     def body(x, xs):
         win_p, glob_p, pi = xs
         kvs, stats = [], jnp.zeros((2,), jnp.int32)
+        # beside each period's slice the whole stack and the period's
+        # index, for the grouped experts' kernel
         for j, lp in enumerate(win_p):
+            lp = {**lp, "layer_stack": (params["win"][j], pi)}
             x, kv, st = _layer(cfg, lp, x, pos, rot[True], attend, True,
                                pi * (per - 1) + j, moe)
             kvs.append(kv)
             stats = stats + st
+        glob_p = {**glob_p, "layer_stack": (params["glob"], pi)}
         x, gkv, st = _layer(cfg, glob_p, x, pos, rot[False], attend, False,
                             kd + pi, moe)
         return x, (stacked(kvs), gkv, stats + st)

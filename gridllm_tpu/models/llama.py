@@ -261,6 +261,10 @@ def stack(layers: Params, cfg: ModelConfig, x: jnp.ndarray, pos: jnp.ndarray,
 
     def body(x, xs):
         lp, li, kind = xs
+        if cfg.num_experts:
+            # a routed family's grouped kernel takes the stack and an index:
+            # handed this scan's slice of the experts it would be handed a copy
+            lp = {**lp, "layer_stack": (layers, li)}
         x, *ys = _layer(cfg, lp, li, kind, x, pos, inv_freq, attend, mlp,
                         seq_c)
         return x, tuple(ys) if kv else None

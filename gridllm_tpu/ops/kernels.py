@@ -112,6 +112,18 @@ KERNELS: tuple[KernelSpec, ...] = (
                     "every slot: the last launch's accepted rows committed "
                     "into the state in place, the new rows run on top",
     ),
+    KernelSpec(
+        name="grouped_experts",
+        reference="experts:grouped_experts_ref",
+        dispatch="grouped_experts",
+        rtol=3e-2, atol=3e-2,
+        test="tests/test_grouped_experts.py::"
+             "test_grouped_experts_matches_its_reference_and_the_all_experts_form",
+        description="the routed experts' products over the experts a live "
+                    "row touched and no others: an expert's gate, up and "
+                    "down slabs by double-buffered DMA from the stacked "
+                    "leaves, all rows against each, float32 sums",
+    ),
 )
 
 # Dispatch labels with NO kernel of their own: dispatchers whose kernel

@@ -1382,3 +1382,160 @@ def gdn_step(states, layer, order, n_live, wy, *, heads: int,
     )(jnp.asarray(layer, jnp.int32).reshape(1), order.astype(jnp.int32),
       jnp.asarray(n_live, jnp.int32).reshape(1), states, wy["wv"], wy["wk"],
       wy["aqk"], wy["qg"], wy["kd"], wy["gc"])
+
+
+# ---------------------------------------------------------------------------
+# routed experts: the touched experts' products (models/mixtral.py's third
+# form, `grouped`)
+# ---------------------------------------------------------------------------
+#
+# Under the chip's ridge (240 rows) the routed products are bound by the
+# bytes of the weights they read, and the all-experts einsum reads every
+# expert held whatever the rows picked. This kernel reads an expert's three
+# slabs once if a live row picked it and not at all otherwise: the rows are
+# neither sorted nor gathered (all T rows times one expert's slabs is less
+# arithmetic than the slabs' bytes take to arrive), a row that did not pick
+# the expert has a gate of zero.
+
+# one step's three slabs, double-buffered, may take this much VMEM: whole
+# experts at every accepted width but mixtral's (117 MB a slab: F-tiles)
+_EXPERT_TILE_BYTES = 40 << 20
+
+
+def _expert_tile(e: int, f: int, itemsize: int) -> int:
+    """Columns of F a step takes: F whole, or its largest divisor that is
+    a multiple of 128 lanes whose three double-buffered slabs fit
+    `_EXPERT_TILE_BYTES` (F whole where there is none)."""
+    if 6 * e * f * itemsize <= _EXPERT_TILE_BYTES:
+        return f
+    return max((d for d in range(128, f, 128) if f % d == 0
+                and 6 * e * d * itemsize <= _EXPERT_TILE_BYTES), default=f)
+
+
+def _grouped_experts_kernel(layer_ref, touched_ref, x_ref, gates_ref, wg_hbm,
+                            wu_hbm, wd_hbm, o_ref, ids, gbuf, ubuf, dbuf, sem,
+                            acc, *, nf: int, tf: int, act: str, precision):
+    li = layer_ref[0]
+
+    def pick(e, n):          # the touched experts' ids, compacted
+        ids[n] = e
+        return n + (touched_ref[e] > 0).astype(jnp.int32)
+
+    steps = nf * jax.lax.fori_loop(0, touched_ref.shape[0], pick,
+                                   jnp.int32(0))
+
+    def copies(step, slot):
+        e = ids[step // nf]
+        cols = (slice(None) if nf == 1
+                else pl.ds(pl.multiple_of((step % nf) * tf, 128), tf))
+        return (
+            pltpu.make_async_copy(wg_hbm.at[li, e, :, cols], gbuf.at[slot],
+                                  sem.at[0, slot]),
+            pltpu.make_async_copy(wu_hbm.at[li, e, :, cols], ubuf.at[slot],
+                                  sem.at[1, slot]),
+            pltpu.make_async_copy(wd_hbm.at[li, e, cols, :], dbuf.at[slot],
+                                  sem.at[2, slot]))
+
+    acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(steps > 0)
+    def _():
+        for c in copies(0, 0):
+            c.start()
+
+    experts = jax.lax.broadcasted_iota(jnp.int32, gates_ref.shape, 1)
+    silu = act == "silu"
+
+    def one(step, carry):
+        slot = step % 2
+
+        @pl.when(step + 1 < steps)
+        def _():
+            for c in copies(step + 1, 1 - slot):
+                c.start()
+
+        for c in copies(step, slot):
+            c.wait()
+        x = x_ref[...]
+        g = jnp.dot(x, gbuf[slot], precision=precision,
+                    preferred_element_type=jnp.float32)
+        u = jnp.dot(x, ubuf[slot], precision=precision,
+                    preferred_element_type=jnp.float32)
+        # the rows' gates of this expert: its column of [T, X]
+        gate = jnp.sum(
+            jnp.where(experts == ids[step // nf], gates_ref[...], 0.0),
+            axis=1, keepdims=True)
+        g = g * jax.nn.sigmoid(g) if silu else jnp.maximum(g, 0.0)
+        y = (g * u * gate).astype(dbuf.dtype)
+        acc[...] += jnp.dot(y, dbuf[slot], precision=precision,
+                            preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, steps, one, 0)
+    o_ref[...] = acc[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("act", "interpret", "tile_f"))
+def grouped_experts(x, gates, touched, wg, wu, wd, layer=None, *, act: str,
+                    interpret: bool = False, tile_f: int | None = None):
+    """sum over the TOUCHED experts j of (act(x wg[j]) * (x wu[j]) *
+    gates[:, j]) wd[j]: x [T, E]; gates [T, X] float32, zero where a row
+    did not pick the expert or is not live; touched [X] int32, nonzero
+    where a live row picked it; wg, wu [L, X, E, F] and wd [L, X, F, E] as
+    they are stored, every layer's (`layer` picks; the stack stays in HBM,
+    a slice of it handed over would be copied), or one layer's [X, ..].
+    Operands as they come (bfloat16), float32 sums over F and over
+    experts, one cast at the end. One expert's slabs (or F-tiles of them,
+    `_expert_tile`) arrive by double-buffered DMA while the last ones
+    multiply; an expert that no live row picked is not read, and with none
+    touched nothing is and the output is zeros. The custom call is named
+    after this function (benchmark/readers.py GROUPED_OPS)."""
+    if wg.ndim == 3:
+        wg, wu, wd = wg[None], wu[None], wd[None]
+    if layer is None:
+        layer = jnp.int32(0)
+    t, e = x.shape
+    nx, f = wg.shape[1], wg.shape[3]
+    tf = tile_f or _expert_tile(e, f, wg.dtype.itemsize)
+    assert f % tf == 0 and (tf == f or tf % 128 == 0), (f, tf)
+    rows = -(-t // 16) * 16          # whole sublane tiles of bfloat16
+    if rows != t:
+        x = jnp.pad(x, ((0, rows - t), (0, 0)))
+        gates = jnp.pad(gates, ((0, rows - t), (0, 0)))
+    kernel = functools.partial(
+        _grouped_experts_kernel, nf=f // tf, tf=tf, act=act,
+        precision=(jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+                   else None))
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape),
+                            memory_space=pltpu.VMEM)
+
+    slabs = 2 * 3 * e * tf * wg.dtype.itemsize
+    work = 4 * (3 * rows * e + 4 * rows * tf + rows * nx) + 4 * rows * e * (
+        x.dtype.itemsize)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[whole((rows, e)), whole((rows, nx))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 3,
+            out_specs=whole((rows, e)),
+            scratch_shapes=[
+                pltpu.SMEM((nx,), jnp.int32),
+                pltpu.VMEM((2, e, tf), wg.dtype),
+                pltpu.VMEM((2, e, tf), wu.dtype),
+                pltpu.VMEM((2, tf, e), wd.dtype),
+                pltpu.SemaphoreType.DMA((3, 2)),
+                pltpu.VMEM((rows, e), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((rows, e), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=int(min(max(slabs + 2 * work + (4 << 20),
+                                         16 << 20), 100 << 20))),
+        interpret=interpret,
+        name="grouped_experts",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), touched.astype(jnp.int32),
+      x, gates.astype(jnp.float32), wg, wu, wd)
+    return out[:t]

@@ -95,6 +95,19 @@ def pytest_sessionfinish(session, exitstatus):
 
 
 @pytest.fixture
+def interpreted_kernels(monkeypatch):
+    """GRIDLLM_PALLAS=interpret for one test. ops/kvcache.py resolves the
+    policy once a process (`_env_mode`), so what it remembered is dropped
+    before the test and after it."""
+    from gridllm_tpu.ops.kvcache import _env_mode
+
+    monkeypatch.setenv("GRIDLLM_PALLAS", "interpret")
+    _env_mode.cache_clear()
+    yield
+    _env_mode.cache_clear()
+
+
+@pytest.fixture
 def event_loop_policy():
     return asyncio.DefaultEventLoopPolicy()
 

@@ -1175,8 +1175,9 @@ def test_self_run_is_clean():
 def test_live_tree_has_seven_kernels_and_no_attention_switch():
     """The KERNELS registry names exactly the public functions of
     ops/pallas_kernels.py that launch a pl.pallas_call — seven since
-    PR 42 (the delta rule's two), with one paged-attention kernel among
-    them — and no knob selects a second paged-attention path."""
+    PR 42 (the delta rule's two), eight since PR 53 (the routed experts'
+    grouped product), with one paged-attention kernel among them — and no
+    knob selects a second paged-attention path."""
     import ast
 
     from gridllm_tpu.ops.kernels import dispatch_labels, kernel_names
@@ -1193,6 +1194,7 @@ def test_live_tree_has_seven_kernels_and_no_attention_switch():
     assert launchers == set(kernel_names()) == {
         "flash_prefill", "flash_prefill_streamed", "ragged_attention",
         "paged_write_decode", "paged_write_chunk", "gdn_chunk", "gdn_step",
+        "grouped_experts",
     }
     assert {lb for lb in dispatch_labels() if lb.startswith("attention_")} \
         == {"attention_prefill", "attention_ragged"}

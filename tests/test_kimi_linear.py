@@ -10,6 +10,7 @@ prefix admitted from latent pages AND a snapshot), and the share (four
 shares and the shared expert add up to the whole layer)."""
 
 import dataclasses
+from functools import partial
 import importlib.util
 import os
 
@@ -334,14 +335,16 @@ def test_the_selection_bias_changes_a_choice_and_never_a_weight():
     assert bool((i_f == i_p).all()) and float(jnp.abs(w_f - w_p).max()) < 1e-6
 
 
-@pytest.mark.parametrize("form", ["all_experts", "sorted"])
-def test_four_shares_and_the_shared_expert_add_up_to_the_whole_layer(form):
+@pytest.mark.parametrize("form", ["all_experts", "sorted", "grouped"])
+def test_four_shares_and_the_shared_expert_add_up_to_the_whole_layer(
+        form, interpreted_kernels):
     """The routed parts that the four shares give, plus the shared expert
     counted once, equal the uncut reference's expert layer; and each
     share's own output is the reference's of that share."""
     lp, x1 = _moe_layer(WHOLE)
-    routed = mixtral._moe_mlp_dense if form == "all_experts" else (
-        mixtral._moe_mlp_ragged)
+    routed = {"all_experts": mixtral._moe_mlp_dense,
+              "sorted": mixtral._moe_mlp_ragged,
+              "grouped": partial(mixtral._moe_mlp_grouped, live=None)}[form]
     m = REF.rms_norm(x1, lp["mlp_norm"], CFG.rms_eps)
     top_w, top_i = mixtral._route(WHOLE, lp, m)
     kw = dict(eps=CFG.rms_eps, top_k=4, scaling=CFG.routed_scaling_factor,
@@ -364,7 +367,8 @@ def test_four_shares_and_the_shared_expert_add_up_to_the_whole_layer(form):
 
 @pytest.mark.parametrize("preset", [
     "tiny-smallthinker", "tiny-deepseek-v2", "tiny-laguna"])
-def test_holding_every_expert_is_the_layer_of_before(preset):
+def test_holding_every_expert_is_the_layer_of_before(preset,
+                                                     interpreted_kernels):
     """`experts_held = None` is every routed family's path of before (the
     two pinned jaxprs hold that it traces as it did); a share that holds
     them all says the same in both forms."""
@@ -380,12 +384,15 @@ def test_holding_every_expert_is_the_layer_of_before(preset):
     top_w, top_i = mixtral._route(cfg, lp, x)
     all_held = dataclasses.replace(cfg, experts_held=X, experts_first=0)
     want = mixtral._moe_mlp_dense(cfg, lp, x, top_w, top_i)
-    for form in (mixtral._moe_mlp_dense, mixtral._moe_mlp_ragged):
+    for form in (mixtral._moe_mlp_dense, mixtral._moe_mlp_ragged,
+                 partial(mixtral._moe_mlp_grouped, live=None)):
         got = form(all_held, lp, x, top_w, top_i)
         assert float(jnp.abs(got - want).max()) < 1e-5
     for rows in (16, 80, 528):
         assert mixtral._use_ragged(cfg, rows, False, "tpu") == (
             mixtral._use_ragged(all_held, rows, False, "tpu"))
+        assert mixtral.expert_form(cfg, rows, backend="tpu") == (
+            mixtral.expert_form(all_held, rows, backend="tpu"))
 
 
 def test_the_rule_of_the_shape_reads_the_share():
@@ -393,6 +400,10 @@ def test_the_rule_of_the_shape_reads_the_share():
     assert mixtral._use_ragged(ep4, 528, False, "tpu")
     assert not mixtral._use_ragged(ep4, 80, False, "tpu")
     assert mixtral.expert_form(CFG, 528) == "all_experts"    # 16 / 4 experts
+    # a verify launch's rows on one chip: the held experts touched, alone
+    assert mixtral.expert_form(ep4, 80, backend="tpu") == "grouped"
+    assert mixtral.expert_form(ep4, 528, backend="tpu") == "sorted"
+    assert mixtral.expert_form(ep4, 80) == "all_experts"
 
 
 # -- through the cache -------------------------------------------------------

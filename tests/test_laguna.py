@@ -369,18 +369,25 @@ def test_route_scores_normalises_and_scales(score, norm, scale):
     # 256 top-8: sorted past the chip's ridge, where a cell read it faster
     ("laguna-xs2:33b", 16, "all_experts"), ("laguna-xs2:33b", 80, "all_experts"),
     ("laguna-xs2:33b", 528, "sorted"),
+    # PR 53: under the chip's ridge the grouped kernel, whatever the shape
+    ("laguna-xs2:33b", 239, "all_experts"), ("laguna-xs2:33b", 240, "sorted"),
+    ("mixtral:8x7b", 80, "all_experts"), ("kimi-linear:48b-ep4", 80, "all_experts"),
 ])
 def test_the_expert_form_is_a_rule_of_the_shape(name, rows, form, monkeypatch):
     cfg = get_config(name)
     assert mixtral._use_ragged(cfg, rows, False, backend="tpu") == (
         form == "sorted")
+    assert mixtral.expert_form(cfg, rows, backend="tpu") == (
+        "grouped" if rows < 240 else form)
+    assert mixtral.expert_form(dataclasses.replace(cfg, use_pallas=False),
+                               rows, backend="tpu") == "all_experts"
     # off the chip the all-experts form, whatever the shape
     assert mixtral.expert_form(cfg, rows) == "all_experts"
     monkeypatch.setenv("GRIDLLM_MOE_RAGGED", "on")
     assert mixtral.expert_form(cfg, rows) == "sorted"
 
 
-def test_both_forms_of_the_expert_layer_agree(params):
+def test_both_forms_of_the_expert_layer_agree(params, interpreted_kernels):
     lp = jax.tree.map(lambda a: a[0], params["glob"])
     x = jnp.asarray(np.random.default_rng(5).standard_normal((40, 64)),
                     jnp.float32)
@@ -388,6 +395,11 @@ def test_both_forms_of_the_expert_layer_agree(params):
     dense = mixtral._moe_mlp_dense(CFG, lp, x, top_w, top_i)
     ragged = mixtral._moe_mlp_ragged(CFG, lp, x, top_w, top_i)
     assert np.abs(np.asarray(dense) - np.asarray(ragged)).max() < 1e-5
+    # and the third (PR 53): the touched experts alone, by the kernel
+    live = jnp.arange(40) < 25
+    grouped = mixtral._moe_mlp_grouped(CFG, lp, x, top_w, top_i, live)
+    assert np.abs(np.asarray(dense) - np.asarray(grouped))[:25].max() < 1e-5
+    assert not np.asarray(grouped)[25:].any()
 
 
 # -- the engine ---------------------------------------------------------------

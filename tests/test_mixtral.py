@@ -149,17 +149,20 @@ def test_engine_generates_with_mixtral():
     assert res.eval_count > 0
 
 
-def test_ragged_dispatch_matches_dense():
+def test_ragged_dispatch_matches_dense(interpreted_kernels):
     """VERDICT #7: the sorted ragged-dispatch MoE form (prefill) must be
     numerically equivalent to the dense all-experts form — exact routing,
-    no capacity drops — across token counts around the dispatch threshold."""
+    no capacity drops — across token counts around the dispatch threshold;
+    and so must the grouped form (PR 53: the kernel, interpreted)."""
     import numpy as np
 
     from gridllm_tpu.models.mixtral import (
         _moe_mlp_dense,
+        _moe_mlp_grouped,
         _moe_mlp_ragged,
         init_params,
     )
+
 
     cfg = get_config("tiny-mixtral")
     params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
@@ -172,6 +175,14 @@ def test_ragged_dispatch_matches_dense():
         np.testing.assert_allclose(
             np.asarray(ragged), np.asarray(dense), rtol=2e-5, atol=2e-5,
         )
+        grouped = _moe_mlp_grouped(cfg, lp, x, *route, None)
+        np.testing.assert_allclose(
+            np.asarray(grouped), np.asarray(dense), rtol=2e-5, atol=2e-5,
+        )
+    # on one chip a verify launch's rows take it, a chunk's do not
+    assert mixtral.expert_form(cfg, 80, backend="tpu") == "grouped"
+    assert mixtral.expert_form(cfg, 528, backend="tpu") == "all_experts"
+    assert mixtral.expert_form(cfg, 80) == "all_experts"
 
 
 def test_ragged_dispatch_through_full_model(monkeypatch):

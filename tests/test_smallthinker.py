@@ -176,10 +176,11 @@ def test_verify_step(params):
     assert CFG.experts_per_token <= int(stats[1]) / CFG.num_layers <= CFG.num_experts
 
 
-@pytest.mark.parametrize("rows", [5, 40])
-def test_the_expert_layers_forms_agree(params, rows):
-    """The all-experts einsum and the sorted ragged dispatch are one
-    function; rows that are not live are counted by neither statistic."""
+@pytest.mark.parametrize("rows", [5, 40, 80])
+def test_the_expert_layers_forms_agree(params, rows, interpreted_kernels):
+    """The all-experts einsum, the sorted ragged dispatch and the grouped
+    kernel (interpreted) are one function; rows that are not live are
+    counted by neither statistic and read by no form's kernel."""
     lp = jax.tree.map(lambda a: a[1], params["layers"])
     x = jax.random.normal(jax.random.PRNGKey(rows), (rows, CFG.hidden_size))
     r = jax.random.normal(jax.random.PRNGKey(rows + 1), (rows, CFG.hidden_size))
@@ -187,11 +188,22 @@ def test_the_expert_layers_forms_agree(params, rows):
     dense = np.asarray(mixtral._moe_mlp_dense(CFG, lp, x, top_w, top_i))
     ragged = np.asarray(mixtral._moe_mlp_ragged(CFG, lp, x, top_w, top_i))
     np.testing.assert_allclose(ragged, dense, rtol=2e-5, atol=2e-5)
+    grouped = np.asarray(
+        mixtral._moe_mlp_grouped(CFG, lp, x, top_w, top_i, None))
+    np.testing.assert_allclose(grouped, dense, rtol=2e-5, atol=2e-5)
+    # the rule of the shape: under the ridge on one chip, nowhere else
+    assert mixtral.expert_form(CFG, rows, backend="tpu") == "grouped"
+    assert mixtral.expert_form(CFG, rows) == "all_experts"
+    assert mixtral.expert_form(dataclasses.replace(CFG, use_pallas=False),
+                               rows, backend="tpu") == "all_experts"
     # ReGLU, not SwiGLU: the same weights under mixtral's activation differ
     silu = dataclasses.replace(CFG, expert_act="silu")
     other = np.asarray(mixtral._moe_mlp_dense(silu, lp, x, top_w, top_i))
     assert np.abs(other - dense).max() > 1e-3
     live = jnp.arange(rows) % 2 == 0
+    half = np.asarray(mixtral._moe_mlp_grouped(CFG, lp, x, top_w, top_i, live))
+    np.testing.assert_allclose(half[::2], dense[::2], rtol=2e-5, atol=2e-5)
+    assert not half[1::2].any()
     stats = mixtral._route_stats(CFG, top_i, live)
     touched = len(set(np.asarray(top_i)[::2].ravel().tolist()))
     assert stats.tolist() == [(rows + 1) // 2, touched]
