@@ -36,10 +36,14 @@ compute path was `client/src/services/OllamaService.ts` HTTP calls). Design
   spec_accept) keeps the longest accepted prefix + one corrected token
   — 1..K+1 tokens per step, greedy streams byte-identical to spec-off,
   sampled streams exactly rejection-sampled. Candidate KV is written
-  optimistically and rolled back by length (ops/kvcache.py). The spec
-  path fetches every verify step (the next draft depends on this step's
-  tokens), trading the block pipeline for multi-token steps — the win
-  when the model forward dominates step time and the workload repeats.
+  optimistically and rolled back by length (ops/kvcache.py). While
+  drafts are being accepted the spec path fetches every verify step (the
+  next draft depends on this step's tokens), trading the block pipeline
+  for multi-token steps — the win when the model forward dominates step
+  time and the workload repeats. While none is, a launch emits one token
+  a slot and needs nothing from the host, so the runner keeps draftless
+  verify launches (the same program) in flight by the decode pipeline's
+  rules (_step_spec has the rule that decides).
 - Ollama semantics honored at this layer: sampler option surface (via
   ops/sampling), `seed` determinism per request (unseeded requests draw a
   random seed host-side — seed 0 is NOT a fixed default), real timing
@@ -62,6 +66,7 @@ import threading
 import time
 from collections import deque
 from functools import partial
+from statistics import median
 from typing import Any, Callable
 
 import jax
@@ -283,6 +288,15 @@ _SPEC_LOOKUPS = _OBS.counter(
     "proposed; miss: nothing to propose).",
     ("model", "outcome"),
 )
+_SPEC_LAUNCHES = _OBS.counter(
+    "gridllm_spec_launches_total",
+    "Verify launches of the chain drafter's path, by model and mode "
+    "(serial: drafted for, then fetched before anything else was "
+    "dispatched; ahead: dispatched without drafts on the pipelined "
+    "schedule, up to pipeline_depth in flight, each fetched while a later "
+    "one runs).",
+    ("model", "mode"),
+)
 _SPEC_ACCEPTED = _OBS.counter(
     "gridllm_spec_accepted_tokens_total",
     "Draft tokens accepted by speculative verify steps, by model and "
@@ -306,6 +320,14 @@ _SPEC_ACCEPT_RATE = _OBS.histogram(
 # generations) so the hot loop stays a deque append every few dozen steps
 _FLIGHTREC = default_flight_recorder()
 _FLIGHT_SAMPLE = 16
+
+# Verify launches in a row in which no slot's first proposed token was the
+# token the model then emitted, after which the speculative runner stops
+# fetching each launch before it dispatches the next (_step_spec). Long
+# enough that traffic whose drafts are accepted now and then never gets
+# there, short against a run of thousands of launches that accepts none.
+# Observed, not set: no option reaches it.
+_AHEAD_AFTER = 32
 
 # KV pool sizing (EngineConfig.num_pages = None). 1024 pages is every
 # slot of the worker's defaults at full context (8 slots x 128 pages);
@@ -585,7 +607,7 @@ class _Slot:
         "req", "ids", "prompt_len", "generated", "detok", "text", "emitted_len",
         "num_predict", "stop_seqs", "eos_ids", "capacity", "joined_gen",
         "first_row", "cached_tokens", "spec_proposed", "spec_accepted",
-        "export_only", "snapshot",
+        "shadow", "export_only", "snapshot",
         "t_start", "t_prefill_ns", "t_first_decode", "t_last_ingest",
         "t_admit_wall", "pages_held", "device_s", "admit_wait_ns",
     )
@@ -606,6 +628,10 @@ class _Slot:
         self.cached_tokens = 0           # prompt tokens reused from the prefix cache
         self.spec_proposed = 0           # drafts sent to verify steps
         self.spec_accepted = 0           # drafts the model accepted
+        # the drafter's first proposal for this stream's next token where
+        # the runner ran ahead and verified none (-1: none), held against
+        # that token when it is ingested
+        self.shadow = -1
         self.export_only = req.export_only  # disagg prefill: stop at token 1
         # last consistent (generated ids, text) pair, published as the
         # crash-resume watermark (ISSUE 9). Written only by the engine
@@ -727,8 +753,11 @@ class InferenceEngine:
         self._free_slots = list(range(config.max_slots - 1, -1, -1))
         # dispatch pipeline state (runner thread / step()):
         self._gen = 0                     # generation counter of dispatched blocks
-        # (gen, toks, k)
-        self._inflight: deque[tuple[int, Any, int]] = deque()
+        # (gen, what the launch handed back, its fused steps, a verify
+        # launch's per-slot draft counts or None): decode blocks, mixed
+        # launches and verify launches in dispatch order (_fetch_oldest)
+        self._inflight: deque[
+            tuple[int, Any, int, np.ndarray | None]] = deque()
         # recompile tripwire (obs/perf.py): every jitted entry point is
         # wrapped; armed after the first naturally completed request, at
         # which point any new compile signature is a flagged steady-state
@@ -743,6 +772,16 @@ class InferenceEngine:
         self._tree_width = 1
         self.spec_stats = {"steps": 0, "proposed": 0, "accepted": 0,
                            "emitted": 0, "draft_ns": 0}
+        # first proposals that matched since the last verify ingest, and
+        # the verify launches in a row that ended with none (_AHEAD_AFTER)
+        self._spec_hits = 0
+        self._spec_quiet = 0
+        # the runner's last eight of each, in seconds: its wait at a verify
+        # launch's fetch, and an admission's host time (pop to dispatched).
+        # _step_spec compares their medians: a median, because an admission
+        # that compiled a program is no admission's measure
+        self._fetch_waits: deque[float] = deque(maxlen=8)
+        self._admits: deque[float] = deque(maxlen=8)
         # the runner thread's wall time, phase by phase (obs/perf.py);
         # runner_wall_s is the same stretch measured on its own, _run
         # entry to exit, so a test can hold the phases to it
@@ -1531,6 +1570,7 @@ class InferenceEngine:
         with self._alloc_lock, self.dispatch_lock:
             self._slots.clear()
             self._inflight.clear()
+            self._spec_hits = self._spec_quiet = 0
             self._free_slots = list(range(self.config.max_slots - 1, -1, -1))
             self._init_device_state()
             if self._drafter is not None and hasattr(self._drafter, "reset"):
@@ -2024,8 +2064,8 @@ class InferenceEngine:
         # marked only once a request was popped: the phase's count is
         # the number of admissions tried
         self._clock.mark("admit", request=req.id)
-        wait_ns = (time.perf_counter_ns() - req.t_submit_ns
-                   if req.t_submit_ns else 0)
+        t_pop = time.perf_counter_ns()
+        wait_ns = t_pop - req.t_submit_ns if req.t_submit_ns else 0
         ids = self._tokenize(req)
         images = list(req.images or [])
         # decode resume (ISSUE 9): tokens a previous attempt already
@@ -2209,6 +2249,7 @@ class InferenceEngine:
                           request=req.id, slot=slot, promptTokens=len(ids),
                           cachedTokens=cached)
         self._update_kv_gauges()
+        self._admits.append((time.perf_counter_ns() - t_pop) / 1e9)
         return True
 
     def _plan_state(self, slot: int, ids: list[int],
@@ -2464,10 +2505,11 @@ class InferenceEngine:
         elif op == "verify_tree":
             # the record carries the tree topology, so the follower
             # rebuilds the exact program regardless of its own env
+            valid = np.asarray(rec["valid"], bool)
             self._dispatch_verify_tree(
-                np.asarray(rec["drafts"], np.int32),
-                np.asarray(rec["valid"], bool),
+                np.asarray(rec["drafts"], np.int32), valid,
                 np.asarray(rec["parents"], np.int32),
+                np.zeros(len(valid), np.int32),  # nothing is ingested here
             )
             self._inflight.clear()  # replay never fetches
         elif op == "deact":
@@ -2485,6 +2527,11 @@ class InferenceEngine:
 
     def _ingest(self, slot: int, st: _Slot, tok: int) -> None:
         """Record one sampled token; emit text; finish the slot if done."""
+        if st.shadow >= 0:
+            # the runner ran ahead and verified no draft: would the
+            # drafter's first proposal for this token have been accepted?
+            self._spec_hits += tok == st.shadow
+            st.shadow = -1
         if st.export_only:
             # disaggregated prefill (ISSUE 7): the first host-visible token
             # proves the whole prompt's KV is written — finish NOW with
@@ -2628,7 +2675,7 @@ class InferenceEngine:
                 self.params, self.cache, self.tokens, self.active,
                 self.counts, self.window, self.wlen, self.sampling, k=k,
             )
-            self._inflight.append((self._gen, out, k))
+            self._inflight.append((self._gen, out, k, None))
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({"op": "block", "k": k})
 
@@ -2662,31 +2709,43 @@ class InferenceEngine:
             np.int32(start), np.int32(length), np.int32(slot), row,
             np.bool_(is_final), embeds=embeds, state_io=state_io,
         )
-        self._inflight.append((self._gen, (out, None), 1))
+        self._inflight.append((self._gen, (out, None), 1, None))
 
-    def _fetch_oldest(self) -> None:
-        """Fetch + ingest the oldest in-flight decode/mixed block — the
-        ONE copy of the block fetch protocol: step()'s sync path,
-        _pump_once's pipelined pop, and the admission-block drains all go
-        through here. Observes per-fused-step duration (fetch+ingest
-        wall over the block's step count)."""
-        gen, out, blk = self._inflight.popleft()
+    def _fetch_oldest(self) -> int:
+        """Fetch + ingest the oldest in-flight block, a decode / mixed
+        block or a verify launch each by its own rule — the ONE copy of
+        the block fetch protocol: step()'s sync path, _pump_once's
+        pipelined pop, the speculative step's own launch and the
+        admission-block drains all go through here, so blocks are
+        ingested in dispatch order whatever their kinds. Observes
+        per-fused-step duration (fetch+ingest wall over the block's step
+        count). Returns the block's generation."""
+        gen, out, blk, dlen = self._inflight.popleft()
         t0 = time.perf_counter()
         self._clock.mark("fetch")
         # the ONE declared block-fetch sync point (host-sync-discipline)
-        # (tokens, a routed family's decode-block statistics or None)
-        raw, stats = jax.device_get(out)  # sync-ok
-        self._mark_ingest()
-        if stats is not None:
-            self._count_step_stats(stats)
-        self._ingest_block(gen, raw)
+        if dlen is None:
+            # (tokens, a routed family's decode-block statistics or None)
+            raw, stats = jax.device_get(out)  # sync-ok
+            self._mark_ingest()
+            if stats is not None:
+                self._count_step_stats(stats)
+            self._ingest_block(gen, raw)
+        else:
+            block, n_emit = out
+            raw = np.asarray(jax.device_get(block))  # sync-ok
+            n_np = np.asarray(jax.device_get(n_emit))  # sync-ok
+            self._fetch_waits.append(self._mark_ingest())
+            self._count_step_stats(n_np[self.config.max_slots:])
+            self._ingest_spec(gen, raw, n_np, dlen)
         _STEP_DURATION.observe(
             (time.perf_counter() - t0) / max(blk, 1), model=self.cfg.name)
+        return gen
 
     def _dispatch_verify(self, drafts: np.ndarray, dlen: np.ndarray) -> None:
         """Dispatch one speculative verify block: [S, K] host drafts (+
         per-slot valid count) against the device's committed last tokens.
-        No host sync — the fetch happens in _step_spec."""
+        No host sync — the fetch is _fetch_oldest's."""
         with self.dispatch_lock:
             _BATCH_OCCUPANCY.observe(len(self._slots), model=self.cfg.name)
             self._gen += 1
@@ -2703,17 +2762,18 @@ class InferenceEngine:
                 drafts, dlen,
                 k1=int(drafts.shape[1]) + 1,  # from the record: follower
             )                                 # replay may differ from env K
-            self._inflight.append((self._gen, (block, n_emit), 1))
+            self._inflight.append((self._gen, (block, n_emit), 1, dlen))
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({"op": "verify", "drafts": drafts.tolist(),
                                 "dlen": dlen.tolist()})
 
     def _dispatch_verify_tree(self, drafts: np.ndarray, valid: np.ndarray,
-                              parents: np.ndarray) -> None:
+                              parents: np.ndarray, dlen: np.ndarray) -> None:
         """Dispatch one TREE verify block (ISSUE 18): [S, N-1] drafted
         node tokens + [S, N] per-slot node validity against the static
-        topology `parents`. No host sync — the fetch happens in
-        _step_spec_tree. The plan record carries the topology, so a
+        topology `parents`; `dlen` is the chain depth proposed a slot, for
+        the ingest's accounting alone. No host sync — the fetch is
+        _fetch_oldest's. The plan record carries the topology, so a
         multi-host follower replays the identical program without any
         env agreement (mirrors the chain path's k-from-record rule)."""
         with self.dispatch_lock:
@@ -2733,7 +2793,7 @@ class InferenceEngine:
                 self.counts, self.window, self.wlen, self.sampling,
                 drafts, valid,
             )
-            self._inflight.append((self._gen, (block, n_emit), 1))
+            self._inflight.append((self._gen, (block, n_emit), 1, dlen))
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({
                     "op": "verify_tree", "drafts": drafts.tolist(),
@@ -2744,10 +2804,10 @@ class InferenceEngine:
     def _step_spec_tree(self, k: int) -> None:
         """One draft-model TREE iteration (ISSUE 18): batched device
         drafting over every live slot, one tree-masked verify dispatch,
-        fetch, ragged ingest. Same serial-by-construction shape as
-        _step_spec — the next step's drafts depend on this step's
-        emitted tokens — but the draft pass itself is one device batch
-        instead of per-slot host loops."""
+        fetch, ragged ingest. The shape of _step_spec's series — the
+        next step's drafts depend on this step's emitted tokens — but
+        the draft pass itself is one device batch instead of per-slot
+        host loops, so it never runs ahead."""
         width = self._tree_width
         parents = tree_topology(k, width)
         n = len(parents)
@@ -2794,44 +2854,60 @@ class InferenceEngine:
             # (siblings are a free second chance, not extra proposals)
             dlen[slot] = depth
         self._mark_launch()
-        self._dispatch_verify_tree(drafts, valid, parents)
-        gen, (block, n_emit), _blk = self._inflight.popleft()
-        t0 = time.perf_counter()
-        self._clock.mark("fetch")
-        raw = np.asarray(jax.device_get(block))  # sync-ok (see _step_spec)
-        n_np = np.asarray(jax.device_get(n_emit))  # sync-ok
-        self._mark_ingest()
-        self._count_step_stats(n_np[self.config.max_slots:])
-        self._ingest_spec(gen, raw, n_np, dlen)
-        _STEP_DURATION.observe(time.perf_counter() - t0, model=self.cfg.name)
+        self._dispatch_verify_tree(drafts, valid, parents, dlen)
+        self._fetch_oldest()  # its own launch: _step_spec drained the rest
 
-    def _step_spec(self) -> None:
-        """One speculative iteration: draft per slot from host-visible
-        history, dispatch the verify block, fetch, ingest the ragged
-        accept counts. Serial by construction — the next step's drafts
-        depend on this step's emitted tokens, so there is no block
-        pipeline to hide the fetch behind; speculation pays that back by
-        emitting up to K+1 tokens per fetch."""
+    def _step_spec(self, ahead_ok: bool = False) -> None:
+        """One speculative iteration, on one of two schedules.
+
+        In series: drain what is in flight, draft per slot from
+        host-visible history, dispatch the verify block, fetch it, ingest
+        the ragged accept counts. Nothing hides the fetch (the next drafts
+        depend on this step's tokens); up to K+1 tokens a fetch pay for it.
+
+        Ahead (`ahead_ok`: the runner alone, never step() nor the tree
+        drafter): draftless launches, `pipeline_depth` in flight, the
+        oldest ingested while the device runs the newest, by the decode
+        pipeline's rules (_ingest_spec). Both conditions are observed:
+        (1) no first proposal matched over the last `_AHEAD_AFTER`
+        launches; the drafter is still asked after each ingest, and a
+        first token the next launch then emits (`_Slot.shadow`) brings
+        the series back. (2) A launch in flight delays the next
+        admission's own launch unless the admission's host work outlasts
+        it: the median of the last eight waits at a verify fetch is at
+        most the median of the last eight admissions. In series that wait
+        is the whole launch, ahead the launch less an iteration's host
+        work: a band that wide in which the runner stays as it is, so it
+        does not flap at the boundary. Readings: PERF.md 6, PR 54."""
+        chain = not getattr(self._drafter, "tree", False)
+        if (ahead_ok and chain and self._spec_quiet >= _AHEAD_AFTER
+                and self._admits and median(self._fetch_waits)
+                <= median(self._admits)):
+            depth = max(1, self.config.pipeline_depth)
+            slots = self.config.max_slots
+            while len(self._inflight) < depth:
+                self._launch_verify(np.zeros((slots, self._spec_k), np.int32),
+                                    np.zeros((slots,), np.int32), "ahead")
+            # down to depth - 1: an admission's mixed launches queue here
+            # too, and a new stream's first token is in the last of them
+            while len(self._inflight) >= depth:
+                gen = self._fetch_oldest()
+            for _slot, st, prop in self._draft_live(gen):
+                st.shadow = prop[0] if prop else -1
+            return
         while self._inflight:
-            # drain mixed admission blocks first: their decode tokens must
-            # be host-visible before drafting (and the verify fetch below
-            # assumes the queue head is its own dispatch)
+            # mixed admission blocks (and, at the switch back from running
+            # ahead, verify launches): their tokens must be host-visible
+            # before drafting
             self._fetch_oldest()
-        self._clock.mark("draft")
         k = self._spec_k
-        if getattr(self._drafter, "tree", False):
+        if not chain:
+            self._clock.mark("draft")
             self._step_spec_tree(k)
             return
         drafts = np.zeros((self.config.max_slots, k), np.int32)
         dlen = np.zeros((self.config.max_slots,), np.int32)
-        looked = hits = history = 0
-        for slot, st in list(self._slots.items()):
-            if st.joined_gen > self._gen:
-                continue  # first token still device-side — nothing to extend
-            prop = self._drafter.draft(st.ids, k, slot)
-            looked += 1
-            hits += bool(prop)
-            history += len(st.ids)
+        for slot, st, prop in self._draft_live(self._gen):
             if prop and st.num_predict >= 0:
                 # don't draft past num_predict: the host would discard the
                 # overshoot anyway, and counting it would skew acceptance
@@ -2839,25 +2915,40 @@ class InferenceEngine:
             if prop:
                 dlen[slot] = len(prop)
                 drafts[slot, :len(prop)] = prop
+        self._launch_verify(drafts, dlen, "serial")
+        self._fetch_oldest()  # its own launch: nothing else is in flight
+
+    def _draft_live(self, gen: int) -> list[tuple[int, _Slot, list[int]]]:
+        """Enter ``draft`` and ask the chain drafter about every stream
+        the host holds a token of (one whose first token block `gen` or an
+        earlier one carried): (slot, stream, proposal). One lookup a live
+        slot a launch on either schedule, counted alike."""
+        self._clock.mark("draft")
+        props = []
+        hits = history = 0
+        for slot, st in self._slots.items():
+            if st.joined_gen > gen:
+                continue  # first token still device-side — nothing to extend
+            prop = self._drafter.draft(st.ids, self._spec_k, slot)
+            hits += bool(prop)
+            history += len(st.ids)
+            props.append((slot, st, prop))
         if hits:
             _SPEC_LOOKUPS.inc(hits, model=self.cfg.name, outcome="hit")
-        if looked - hits:
-            _SPEC_LOOKUPS.inc(looked - hits, model=self.cfg.name,
+        if len(props) - hits:
+            _SPEC_LOOKUPS.inc(len(props) - hits, model=self.cfg.name,
                               outcome="miss")
-        self._clock.annotate(slots=looked, hits=hits, history_tokens=history)
+        self._clock.annotate(slots=len(props), hits=hits,
+                             history_tokens=history)
+        return props
+
+    def _launch_verify(self, drafts: np.ndarray, dlen: np.ndarray,
+                       mode: str) -> None:
+        """One verify launch of the chain path: its ``dispatch_verify``
+        mark, its count by schedule, its dispatch."""
         self._mark_launch()
+        _SPEC_LAUNCHES.inc(model=self.cfg.name, mode=mode)
         self._dispatch_verify(drafts, dlen)
-        gen, (block, n_emit), _blk = self._inflight.popleft()
-        t0 = time.perf_counter()
-        self._clock.mark("fetch")
-        # the spec path's declared fetch: serial by construction (drafts
-        # depend on this step's tokens), so the sync is the design
-        raw = np.asarray(jax.device_get(block))  # sync-ok
-        n_np = np.asarray(jax.device_get(n_emit))  # sync-ok
-        self._mark_ingest()
-        self._count_step_stats(n_np[self.config.max_slots:])
-        self._ingest_spec(gen, raw, n_np, dlen)
-        _STEP_DURATION.observe(time.perf_counter() - t0, model=self.cfg.name)
 
     def _ingest_spec(self, gen: int, tok_np: np.ndarray,
                      n_emit: np.ndarray, dlen: np.ndarray) -> None:
@@ -2886,6 +2977,7 @@ class InferenceEngine:
             n = int(n_emit[slot])
             prop = int(dlen[slot])
             acc = max(n - 1, 0)
+            self._spec_hits += n >= 2  # the first proposal was accepted
             st.spec_proposed += prop
             st.spec_accepted += acc
             proposed_t += prop
@@ -2916,6 +3008,9 @@ class InferenceEngine:
         # row-0 tokens are prefill samples riding the block protocol, not
         # verify output — only rows >= 1 count toward tokens-per-step
         stats["emitted"] += emitted_t
+        # the schedule's signal, one reading a verify launch (_step_spec)
+        self._spec_quiet = 0 if self._spec_hits else self._spec_quiet + 1
+        self._spec_hits = 0
 
     def _ingest_block(self, gen: int, tok_np: np.ndarray) -> None:
         """Feed one fetched [k+1, S] token block through per-token
@@ -3043,17 +3138,18 @@ class InferenceEngine:
             MOE_PICKS_TOTAL.inc(int(stats[3]), model=self.cfg.name,
                                 where="absent")
 
-    def _mark_ingest(self) -> None:
+    def _mark_ingest(self) -> float:
         """Leave ``fetch`` for ``ingest``, right after the device_get
-        returned. The fetch's measured time is the runner blocked on the
-        device: usage attribution (ISSUE 16) splits it evenly across the
-        slots that shared the batch (engine thread owns _slots — no lock
-        needed)."""
+        returned. The fetch's measured time, returned, is the runner
+        blocked on the device: usage attribution (ISSUE 16) splits it
+        evenly across the slots that shared the batch (engine thread owns
+        _slots — no lock needed)."""
         waited = self._clock.mark("ingest")
         if self._slots:
             share = waited / len(self._slots)
             for st in self._slots.values():
                 st.device_s += share
+        return waited
 
     # ------------------------------------------------------------- runner
 
@@ -3167,10 +3263,11 @@ class InferenceEngine:
             return
         if self._spec_k:
             # speculative serving: one verify block per iteration, fetched
-            # immediately (the next step's drafts depend on this step's
-            # tokens, so the block pipeline can't apply — acceptance > 1
-            # token/step is what pays the un-hidden fetch back)
-            self._step_spec()
+            # immediately while drafts are being accepted (the next step's
+            # drafts depend on this step's tokens — acceptance > 1
+            # token/step is what pays the un-hidden fetch back), pipelined
+            # like the blocks below while none is
+            self._step_spec(ahead_ok=True)
             return
         k = self.config.decode_block
         while len(self._inflight) < max(1, self.config.pipeline_depth):

@@ -1016,11 +1016,11 @@ class JobScheduler(EventEmitter):
         async with self._dispatch_lock:
             if not self.job_queue:
                 return
-            assigned_ids: set[str] = set()
+            done: set[int] = set()  # id() of the entries this pass settles
             now = time.time()
             for qj in sorted(list(self.job_queue), key=_QueuedJob.sort_key):
                 if qj.request.id in self._cancelled:
-                    assigned_ids.add(qj.request.id)  # drop from queue below
+                    done.add(id(qj))  # drop from queue below
                     await self.bus.hdel(self._qkey(qj.request.id), qj.request.id)
                     self._end_queue_span(qj.request.id, cancelled=True)
                     continue
@@ -1038,7 +1038,7 @@ class JobScheduler(EventEmitter):
                     # past its class deadline while still queued: shed
                     # instead of occupying the queue (ISSUE 9); the
                     # gateway maps the failure to HTTP 504
-                    assigned_ids.add(qj.request.id)
+                    done.add(id(qj))
                     await self.bus.hdel(self._qkey(qj.request.id), qj.request.id)
                     await self._shed_deadline(qj.request)
                     continue
@@ -1065,11 +1065,16 @@ class JobScheduler(EventEmitter):
                                         job_id=qj.request.id, model=qj.request.model)
                     continue
                 if await self._assign_job(qj, worker, disagg=disagg):
-                    assigned_ids.add(qj.request.id)
-            if assigned_ids:
-                # jobs added during assignment awaits stay for the next pass
+                    done.add(id(qj))
+            if done:
+                # jobs added during assignment awaits stay for the next
+                # pass. By entry, not by job id: a job this pass assigned
+                # can be back already (a capacity NACK, an orphan or a
+                # drain requeue handled while a later assignment was
+                # awaited) as a new entry, and dropping that one would
+                # leave the job neither queued nor active
                 self.job_queue = [qj for qj in self.job_queue
-                                  if qj.request.id not in assigned_ids]
+                                  if id(qj) not in done]
 
     def _plan_placement(
         self, request: InferenceRequest

@@ -34,6 +34,63 @@ def ragged_decode(q, k_pool, v_pool, table, prefix, ps, k_cur, v_cur, **kw):
     return out[:, 0]
 
 
+def fetch_waits(eng, seconds: float) -> None:
+    """The runner reads `seconds` as its wait at every fetch: held against
+    an admission's host time it says whether a launch kept in flight would
+    stand in a new request's way (ISSUE 54; a CPU's own timings say
+    either)."""
+    mark = eng._mark_ingest
+
+    def marking():
+        mark()
+        return seconds
+
+    eng._mark_ingest = marking
+
+
+def turns_running_ahead(eng, monkeypatch, turns, window: int = 2):
+    """Serve `turns` ((id, prompt, num_predict), ...) one after another
+    through the runner thread, greedy, with a drafter that proposes nothing
+    the window of quiet launches cut to `window` and the runner's waits at
+    a fetch read as nothing, so the speculative runner keeps draftless
+    verify launches in flight (ISSUE 54). Returns
+    the results in order, and for every verify dispatch the verify
+    launches then still to be fetched."""
+    import threading
+
+    from gridllm_tpu.engine import GenerationRequest
+    from gridllm_tpu.engine import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "_AHEAD_AFTER", window)
+    monkeypatch.setattr(eng._drafter, "draft", lambda ids, k, slot=None: [])
+    fetch_waits(eng, 0.0)   # launches short against an admission
+    behind, dispatch = [], eng._dispatch_verify
+
+    def watching(drafts, dlen):
+        behind.append(sum(e[3] is not None for e in eng._inflight))
+        dispatch(drafts, dlen)
+
+    monkeypatch.setattr(eng, "_dispatch_verify", watching)
+    results, done = [], threading.Event()
+
+    def on_chunk(delta, fin, res):
+        if fin:
+            results.append(res)
+            done.set()
+
+    eng.start()
+    try:
+        for rid, prompt, n in turns:
+            done.clear()
+            eng.submit(GenerationRequest(
+                id=rid, prompt=prompt, on_chunk=on_chunk,
+                options={"temperature": 0.0, "num_predict": n}))
+            assert done.wait(120), rid
+    finally:
+        eng.stop()
+    return results, behind
+
+
 def fast_config() -> SchedulerConfig:
     """Sub-second timers so failure-path tests run quickly."""
     return SchedulerConfig(

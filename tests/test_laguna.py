@@ -690,3 +690,23 @@ def test_both_layer_shapes_compile_for_the_chip(one_chip, heads, pages, window):
         k, v, kn, vn, t, p, a, 128, use_pallas=True)).lower(
             pool, pool, rows, rows, real((s, 64), i32), real((s,), i32),
             real((s,), jnp.bool_)).compile()
+
+
+def test_draftless_launches_back_to_back_leave_the_serial_rings(
+        cold_engine, monkeypatch):
+    """The runner running ahead (nothing proposed; the window cut to two
+    launches): verify launches are dispatched behind verify launches with
+    no fetch between, each committing one row of the window layers' rings
+    past a wrap, and the tokens are the serial steps'. A later asker of the
+    prefix restores the rings' snapshot beside the pages and says what a
+    cold admission says."""
+    from tests.helpers import turns_running_ahead
+
+    doc = (WORDS * 2)[:107]
+    (first, again), behind = turns_running_ahead(
+        _engine(), monkeypatch,
+        [("a", doc + " one two", 40), ("b", doc + " six ten", 24)])
+    assert sum(behind) >= 30          # a verify launch behind a verify launch
+    assert first.cached_tokens == 0 and again.cached_tokens == 96
+    assert first.token_ids == _ask(cold_engine, "c", doc + " one two", 40).token_ids
+    assert again.token_ids == _ask(cold_engine, "d", doc + " six ten", 24).token_ids

@@ -709,3 +709,22 @@ def test_the_delta_rule_kernels_compile_for_the_chip(one_chip):
             real((15, s, dk, h * dv)), real((), jnp.int32), tuple(rows[1:]),
             real((s,), jnp.int32), tuple(rows), real((s,), jnp.bool_)).compile()
     assert step.memory_analysis().alias_size_in_bytes >= 15 * s * dk * h * dv * 4
+
+
+def test_draftless_launches_back_to_back_leave_the_serial_state(
+        cold_engine, monkeypatch):
+    """The runner running ahead (nothing proposed; the window cut to two
+    launches): verify launches are dispatched behind verify launches with
+    no fetch between, each committing one row of the recurrent state, and
+    the tokens are the serial steps'. A later asker of the prefix restores
+    the snapshot beside the pages and says what a cold admission says."""
+    from tests.helpers import turns_running_ahead
+
+    doc = (WORDS * 2)[:107]
+    (first, again), behind = turns_running_ahead(
+        _engine(), monkeypatch,
+        [("a", doc + " one two", 24), ("b", doc + " six ten", 24)])
+    assert sum(behind) >= 20          # a verify launch behind a verify launch
+    assert first.cached_tokens == 0 and again.cached_tokens == 96
+    assert first.token_ids == _ask(cold_engine, "c", doc + " one two", 24).token_ids
+    assert again.token_ids == _ask(cold_engine, "d", doc + " six ten", 24).token_ids

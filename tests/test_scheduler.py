@@ -380,6 +380,49 @@ async def test_nack_does_not_consume_retry_ladder():
     await teardown(bus, registry, scheduler, w)
 
 
+async def test_a_job_nacked_back_while_its_pass_still_assigns_stays_queued():
+    """A dispatch pass assigns A, then awaits B's assignment; A's capacity
+    NACK comes back meanwhile and A is requeued as a new entry. The pass's
+    clean-up drops the entries it assigned, not every entry of their jobs:
+    A is assigned again and both finish (a burst behind a stalled host lost
+    four streams of 230 this way: PERF.md section 6, PR 54)."""
+    bus, registry, scheduler = await make_stack()
+    w = FakeWorker(bus, "w1", ["m1"], max_concurrent=2, nack_times=1)
+    a, b = req(), req()
+    assign, calls = scheduler._assign_job, []
+
+    async def assigning(qj, worker, disagg=None):
+        calls.append(qj)
+        ok = await assign(qj, worker, disagg=disagg)
+        if len(calls) == 2:
+            # the pass is still open: wait here until A is back in the
+            # queue, as a new entry beside the one this pass holds
+            first = calls[0]
+            for _ in range(400):
+                if any(q.request.id == first.request.id and q is not first
+                       for q in scheduler.job_queue):
+                    break
+                await asyncio.sleep(0.005)
+            else:
+                raise AssertionError("the NACKed job was not requeued")
+        return ok
+
+    scheduler._assign_job = assigning
+    waiters = [asyncio.ensure_future(scheduler.submit_and_wait(r, timeout_ms=5000))
+               for r in (a, b)]
+    while len(scheduler.job_queue) < 2:     # both held: no worker yet
+        await asyncio.sleep(0.005)
+    await w.start()                         # one pass finds both
+    results = await asyncio.gather(*waiters)
+    assert [r.success for r in results] == [True, True]
+    assert [q.request.id for q in calls] == [
+        calls[0].request.id, calls[1].request.id, calls[0].request.id]
+    assert calls[2] is not calls[0]
+    assert sorted(w.processed) == sorted([a.id, b.id])
+    assert scheduler.total_failed == 0
+    await teardown(bus, registry, scheduler, w)
+
+
 async def test_layout_tiebreak_discriminates():
     """VERDICT #8: the shard-layout tiebreak must distinguish workers.
     (a) context fit: a request with num_ctx beyond one worker's layout

@@ -4,11 +4,14 @@ rollback-to-length units (page-boundary crossing + ref-counted cached
 pages), n-gram drafter units, and zero steady-state recompiles with
 speculation armed (reusing the PR-4 tripwire harness)."""
 
+import threading
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from gridllm_tpu.engine import EngineConfig, GenerationRequest, InferenceEngine
+from gridllm_tpu.engine import engine as engine_mod
 from gridllm_tpu.obs.perf import recompile_totals
 from gridllm_tpu.ops.kvcache import (
     PagedKVCache,
@@ -19,6 +22,7 @@ from gridllm_tpu.ops.kvcache import (
     write_multi_all,
 )
 from gridllm_tpu.ops.spec import NgramDrafter, make_drafter
+from tests.helpers import fetch_waits
 
 TINY = dict(
     model="tiny-llama",
@@ -501,3 +505,473 @@ def test_spec_env_defaults(monkeypatch):
     assert InferenceEngine(EngineConfig(**TINY))._spec_k == 2
     assert InferenceEngine(
         EngineConfig(**TINY, spec_decode=False))._spec_k == 0
+
+
+# ---------------------------------------------------------------------------
+# the runner's two schedules (ISSUE 54): in series while first proposals are
+# accepted, ahead (draftless launches in flight) while none is
+# ---------------------------------------------------------------------------
+
+ROOMY = dict(TINY, num_pages=128, max_pages_per_slot=24)
+GREEDY = {"temperature": 0.0}
+MODEL = "tiny-llama"
+
+
+class _Never:
+    """A chain drafter that proposes nothing."""
+
+    kind = "ngram"
+
+    def __init__(self):
+        self.calls = 0
+
+    def draft(self, ids, k, slot=None):
+        self.calls += 1
+        return []
+
+    def reset_slot(self, slot):
+        pass
+
+    def reset(self):
+        pass
+
+
+class _Knows(_Never):
+    """Proposes a known stream's continuation once `after` tokens of it are
+    in the history, nothing before."""
+
+    def __init__(self, stream, after):
+        super().__init__()
+        self.stream, self.after = list(stream), after
+        self.ahead_at_first = None
+
+    def draft(self, ids, k, slot=None):
+        self.calls += 1
+        n = len(ids)
+        if n < self.after or list(ids) != self.stream[:n]:
+            return []
+        if self.ahead_at_first is None:
+            self.ahead_at_first = _launches("ahead")
+        return self.stream[n:n + k]
+
+
+def _launches(mode):
+    return engine_mod._SPEC_LAUNCHES.value(model=MODEL, mode=mode)
+
+
+def _lookups():
+    return sum(engine_mod._SPEC_LOOKUPS.value(model=MODEL, outcome=o)
+               for o in ("hit", "miss"))
+
+
+def _spec_engine(drafter=None, **kw):
+    eng = InferenceEngine(EngineConfig(**{**ROOMY, **kw}, spec_decode=True,
+                                       spec_k=4))
+    eng._drafter = drafter or _Never()
+    fetch_waits(eng, 0.0)
+    return eng
+
+
+def _watch_dispatch(eng):
+    """The blocks in flight at each verify dispatch."""
+    seen, dispatch = [], eng._dispatch_verify
+
+    def watching(drafts, dlen):
+        seen.append((len(eng._inflight), int(dlen.sum())))
+        dispatch(drafts, dlen)
+
+    eng._dispatch_verify = watching
+    return seen
+
+
+def _serve(eng, reqs, timeout=120.0):
+    """Through the runner thread: {id: (result, [(delta, done), ...])}."""
+    got, frames, done = {}, {r.id: [] for r in reqs}, threading.Event()
+
+    def on_chunk(rid, user):
+        def cb(delta, fin, res):
+            frames[rid].append((delta, fin))
+            if user:
+                user(delta, fin, res)
+            if fin:
+                got[rid] = res
+                if len(got) == len(reqs):
+                    done.set()
+        return cb
+
+    eng.start()
+    try:
+        for r in reqs:
+            r.on_chunk = on_chunk(r.id, r.on_chunk)
+            eng.submit(r)
+        assert done.wait(timeout), sorted(got)
+    finally:
+        eng.stop()
+    return {rid: (got[rid], frames[rid]) for rid in got}
+
+
+def _req(rid, prompt, n, **opts):
+    return GenerationRequest(id=rid, prompt=prompt,
+                             options={**GREEDY, "num_predict": n, **opts})
+
+
+def _warm(eng):
+    """Past the window: a stream of more launches than _AHEAD_AFTER with
+    nothing proposed leaves the runner running ahead."""
+    _serve(eng, [_req("warm", "warm up", engine_mod._AHEAD_AFTER + 8)])
+    assert eng._spec_quiet >= engine_mod._AHEAD_AFTER
+
+
+THREE = (("x0", "hello world hello", 60), ("x1", "ab ab ab ab ab", 47),
+         ("x2", "xyzzy", 52))
+
+
+def test_nothing_proposed_runs_ahead_and_says_what_the_series_says(
+        monkeypatch):
+    """Greedy streams under the default repeat penalty, three at once and
+    ending at different launches: byte for byte the same in series (the
+    window never closing), running ahead, and with speculation off; and
+    running ahead really dispatched launches behind launches."""
+    def run(eng):
+        out = _serve(eng, [_req(*r) for r in THREE])
+        return {rid: (res.token_ids, res.text, res.done_reason)
+                for rid, (res, _f) in out.items()}
+
+    ahead = _spec_engine()
+    seen, a0 = _watch_dispatch(ahead), _launches("ahead")
+    said = run(ahead)
+    assert _launches("ahead") > a0
+    assert max(n for n, _d in seen) >= 1 and not any(d for _n, d in seen)
+    assert said == run(InferenceEngine(EngineConfig(**ROOMY,
+                                                    spec_decode=False)))
+    monkeypatch.setattr(engine_mod, "_AHEAD_AFTER", 10 ** 9)
+    series = _spec_engine()
+    seen, a0 = _watch_dispatch(series), _launches("ahead")
+    assert said == run(series)
+    assert _launches("ahead") == a0 and not any(n for n, _d in seen)
+    assert {rid: len(ids) for rid, (ids, _t, _r) in said.items()} == {
+        rid: n for rid, _prompt, n in THREE}
+
+
+def test_a_right_proposal_brings_the_series_back():
+    """The drafter is silent for 45 tokens (the runner goes ahead), then
+    proposes what the model will say: the first such proposal is held
+    against the next launch's token, the series is back within the
+    launches already in flight, and drafts are accepted again."""
+    prompt = "hello world hello"
+    ref = _spec_engine().generate(_req("ref", prompt, 70))
+    n_prompt = ref.prompt_eval_count
+    knows = _Knows(ref.context, n_prompt + 45)
+    eng = _spec_engine(knows)
+    seen, a0, s0 = _watch_dispatch(eng), _launches("ahead"), _launches("serial")
+    (res, _frames), = _serve(eng, [_req("k", prompt, 70)]).values()
+    assert res.token_ids == ref.token_ids and res.text == ref.text
+    assert knows.ahead_at_first is not None and knows.ahead_at_first > a0
+    assert _launches("ahead") - knows.ahead_at_first <= eng.config.pipeline_depth
+    assert res.spec_accepted > 0 and any(d for _n, d in seen)
+    # accepted drafts: fewer launches than tokens
+    assert (_launches("ahead") - a0) + (_launches("serial") - s0) < 70
+    assert eng._spec_quiet < engine_mod._AHEAD_AFTER
+
+
+@pytest.fixture(scope="module")
+def one_slot_ahead():
+    """One slot, already past the window: the next request takes over the
+    slot the last one left, behind whatever that one left in flight."""
+    eng = _spec_engine(max_slots=1)
+    _warm(eng)
+    return eng
+
+
+@pytest.mark.parametrize("end", ["eos", "num_predict", "stop", "cancel"])
+def test_a_stream_that_ends_under_a_launch_in_flight(one_slot_ahead, spec_off,
+                                                     end, monkeypatch):
+    """Each way a stream ends, landing while a launch that still counts
+    its slot is in flight: nothing past the end is delivered, the pages
+    come back, and the request that takes the slot over says what it says
+    alone (it ingests nothing of the old launch)."""
+    eng = one_slot_ahead
+    assert eng._spec_quiet >= engine_mod._AHEAD_AFTER
+    full = spec_off.generate(_req("f", REP_PROMPT, 24))
+    after = spec_off.generate(_req("g", "xyzzy", 12))
+    opts, cut = {}, 6
+    if end == "eos":
+        eos = full.token_ids[cut]
+        assert eos not in full.token_ids[:cut]
+        monkeypatch.setattr(eng.tokenizer, "eos_ids", frozenset({eos}))
+    elif end == "num_predict":
+        opts = {"num_predict": cut}
+    elif end == "stop":
+        # a stop first found some tokens in, whole characters only
+        stop = next(full.text[i:i + 2] for i in range(4, len(full.text) - 2)
+                    if "\ufffd" not in full.text[i:i + 2]
+                    and full.text.find(full.text[i:i + 2]) == i)
+        opts = {"stop": [stop]}
+        stopped = spec_off.generate(_req("h", REP_PROMPT, 24, **opts))
+        assert stopped.done_reason == "stop" and stopped.token_ids
+    a = _req("a", REP_PROMPT, 24, **opts)
+    if end == "cancel":
+        seen_a = []
+
+        def cancel_later(delta, fin, res):
+            seen_a.append(delta)
+            if len(seen_a) == cut:
+                eng.cancel("a")
+        a.on_chunk = cancel_later
+    in_flight, finish = [], eng._finish
+
+    def finishing(slot, st, reason, error=""):
+        in_flight.append((st.req.id, len(eng._inflight)))
+        finish(slot, st, reason, error)
+
+    monkeypatch.setattr(eng, "_finish", finishing)
+    out = _serve(eng, [a, _req("b", "xyzzy", 12)])
+    (res_a, frames_a), (res_b, _fb) = out["a"], out["b"]
+    assert dict(in_flight)["a"] >= 1
+    assert res_a.done_reason == {"eos": "stop", "num_predict": "length",
+                                 "stop": "stop", "cancel": "cancel"}[end]
+    # one final frame, the last; the frames are the text and no more
+    assert [fin for _d, fin in frames_a] == [False] * (len(frames_a) - 1) + [True]
+    assert "".join(d for d, _fin in frames_a) == res_a.text
+    n = len(res_a.token_ids)
+    assert res_a.token_ids == full.token_ids[:n]
+    if end in ("eos", "num_predict"):
+        assert n == cut
+    elif end == "stop":
+        assert (res_a.text, res_a.token_ids) == (stopped.text,
+                                                 stopped.token_ids)
+        assert stop not in res_a.text
+    else:
+        assert cut <= n < 24 and full.text.startswith(res_a.text)
+    if end == "eos":
+        n_b = next((i for i, t in enumerate(after.token_ids) if t == eos), 12)
+        assert res_b.token_ids == after.token_ids[:n_b]
+    else:
+        assert res_b.token_ids == after.token_ids and res_b.text == after.text
+    assert not eng._slots
+    assert eng.alloc.free_pages + eng.alloc.cached_pages == eng.config.num_pages
+
+
+CHURN = ("hello world hello", "ab ab ab ab ab", "xyzzy", "the quick brown fox",
+         "a", "one two three four five six seven")
+
+
+@pytest.mark.parametrize("slots", [3, 8])
+def test_churn_every_stream_ends_once_and_every_page_comes_back(
+        slots, spec_off, monkeypatch):
+    """Some hundreds of short requests, staggered, through the runner
+    running ahead on a few slots: streams end under launches in flight
+    while others run on, and requests take slots over behind a stale verify
+    launch and their own mixed launch. Every request gets one final frame,
+    its last; it says what it says alone; no slot and no page is left
+    held."""
+    import random
+    import time
+
+    monkeypatch.setattr(engine_mod, "_AHEAD_AFTER", 4)
+    eng = _spec_engine(max_slots=slots, num_pages=256)
+    alone = {p: spec_off.generate(_req("ref", p, 14)).token_ids
+             for p in CHURN}
+    rng = random.Random(slots)
+    asked = [(f"r{i}", rng.choice(CHURN), rng.randint(1, 14))
+             for i in range(300)]
+    ends_under, joins_behind = [], []
+    finish, mixed = eng._finish, eng._dispatch_mixed_chunk
+
+    def finishing(slot, st, reason, error=""):
+        ends_under.append(len(eng._inflight) and len(eng._slots) > 1)
+        finish(slot, st, reason, error)
+
+    def mixing(*a, **kw):
+        joins_behind.append(any(e[3] is not None for e in eng._inflight))
+        mixed(*a, **kw)
+
+    monkeypatch.setattr(eng, "_finish", finishing)
+    monkeypatch.setattr(eng, "_dispatch_mixed_chunk", mixing)
+    frames, lock = {rid: [] for rid, _p, _n in asked}, threading.Lock()
+
+    def on_chunk(rid):
+        def cb(delta, fin, res):
+            with lock:
+                frames[rid].append((fin, res))
+        return cb
+
+    a0 = _launches("ahead")
+    eng.start()
+    try:
+        for rid, prompt, n in asked:
+            req = _req(rid, prompt, n)
+            req.on_chunk = on_chunk(rid)
+            eng.submit(req)
+            time.sleep(rng.choice((0, 0, 0.001, 0.004, 0.01, 0.03)))
+        deadline = time.monotonic() + 120
+        while time.monotonic() < deadline and not all(
+                f and f[-1][0] for f in frames.values()):
+            time.sleep(0.02)
+        time.sleep(0.1)     # a second final frame would come now
+    finally:
+        eng.stop()
+    for rid, prompt, n in asked:
+        fins = [fin for fin, _res in frames[rid]]
+        assert fins == [False] * (len(fins) - 1) + [True], (rid, fins)
+        res = frames[rid][-1][1]
+        assert res.done_reason == "length", (rid, res.done_reason, res.error)
+        assert res.token_ids == alone[prompt][:n], rid
+    assert sum(ends_under) >= 50 and sum(joins_behind) >= 10
+    assert _launches("ahead") - a0 >= 20
+    assert not eng._slots and not eng._pending
+    assert sorted(eng._free_slots) == list(range(slots))
+    assert eng.alloc.free_pages + eng.alloc.cached_pages == eng.config.num_pages
+
+
+def test_a_request_admitted_behind_a_launch_in_flight(spec_off):
+    """Its mixed launch queues behind the verify launch on the device, its
+    first token is read from that launch's own block, and blocks of both
+    kinds are ingested in the order they were dispatched."""
+    eng = _spec_engine()
+    _warm(eng)
+    order, behind, first_from = [], [], []
+    ingest_block, ingest_spec = eng._ingest_block, eng._ingest_spec
+    mixed = eng._dispatch_mixed_chunk
+
+    def firsts(kind, gen):
+        for st in eng._slots.values():
+            if st.req.id == "late" and st.joined_gen <= gen and not st.generated:
+                first_from.append(kind)
+
+    def block(gen, tok):
+        order.append(gen)
+        firsts("mixed", gen)
+        ingest_block(gen, tok)
+
+    def spec(gen, tok, n_emit, dlen):
+        order.append(gen)
+        firsts("verify", gen)
+        ingest_spec(gen, tok, n_emit, dlen)
+
+    def mixing(*a, **kw):
+        behind.append([e[3] is not None for e in eng._inflight])
+        mixed(*a, **kw)
+
+    eng._ingest_block, eng._ingest_spec = block, spec
+    eng._dispatch_mixed_chunk = mixing
+    submitted = []
+
+    def then_submit(delta, fin, res):
+        submitted.append(delta)
+        if len(submitted) == 10:
+            eng.submit(late)
+
+    box, done = {}, threading.Event()
+    late = _req("late", "xyzzy", 12)
+    late.on_chunk = lambda d, fin, res: fin and (box.update(late=res), done.set())
+    early = _req("early", REP_PROMPT, 40)
+    early.on_chunk = then_submit
+    out = _serve(eng, [early])
+    if not done.is_set():       # the runner stopped on `early`'s last frame
+        eng.start()
+        try:
+            assert done.wait(60)
+        finally:
+            eng.stop()
+    assert behind[-1] and behind[-1][0], behind   # a verify launch ahead of it
+    assert first_from == ["mixed"]
+    assert order == sorted(order) and len(set(order)) == len(order)
+    assert box["late"].token_ids == spec_off.generate(
+        _req("l", "xyzzy", 12)).token_ids
+    assert out["early"][0].token_ids == spec_off.generate(
+        _req("e", REP_PROMPT, 40)).token_ids
+
+
+def test_launches_and_lookups_are_counted_on_both_schedules():
+    """gridllm_spec_launches_total's two modes add up to the launches the
+    phase clock marked, and the drafter is asked (and counted) on both."""
+    from gridllm_tpu.obs.perf import PHASE_SECONDS
+
+    eng = _spec_engine()
+    launch, at_first_ahead = eng._launch_verify, []
+
+    def launching(drafts, dlen, mode):
+        if mode == "ahead" and not at_first_ahead:
+            at_first_ahead.append(_lookups())
+        launch(drafts, dlen, mode)
+
+    eng._launch_verify = launching
+    marks = PHASE_SECONDS.count(model=MODEL, phase="dispatch_verify")
+    s0, a0, l0 = _launches("serial"), _launches("ahead"), _lookups()
+    _serve(eng, [_req("c0", "hello world", 50), _req("c1", "xyzzy", 44)])
+    serial, ahead = _launches("serial") - s0, _launches("ahead") - a0
+    assert serial >= engine_mod._AHEAD_AFTER and ahead > 0
+    assert serial + ahead == PHASE_SECONDS.count(
+        model=MODEL, phase="dispatch_verify") - marks
+    assert l0 < at_first_ahead[0] < _lookups()
+    assert eng._drafter.calls == _lookups() - l0
+
+
+def test_launches_longer_than_an_admission_stay_in_series():
+    """Nothing is proposed and the window is long past, but the runner
+    waits longer at a launch's fetch than an admission takes it: a launch
+    kept in flight would hold up the next request's own, so none is."""
+    eng = _spec_engine()
+    fetch_waits(eng, 10.0)
+    seen, a0 = _watch_dispatch(eng), _launches("ahead")
+    (res, _frames), = _serve(eng, [_req("slow", "hello world", 60)]).values()
+    assert eng._spec_quiet >= engine_mod._AHEAD_AFTER
+    assert 0 < max(eng._admits) < min(eng._fetch_waits)
+    assert _launches("ahead") == a0 and not any(n for n, _d in seen)
+    assert res.token_ids == _spec_engine().generate(
+        _req("ref", "hello world", 60)).token_ids
+
+
+def test_launch_time_walked_across_admission_time_enters_stays_and_leaves(
+        monkeypatch):
+    """The second condition's band. A launch of `launch` seconds is read
+    whole at a fetch in series and less an iteration's host work (4 ms
+    here) ahead, against admissions of 10 ms: the runner enters at launch
+    <= 10, stays ahead up to 14, leaves above, and once in series stays
+    there down to 10. Twelve launches a step of the walk; the median of
+    eight has turned by the sixth."""
+    from collections import deque
+
+    walk = ((0.020, "serial"), (0.008, "ahead"), (0.012, "ahead"),
+            (0.016, "serial"), (0.012, "serial"), (0.008, "ahead"))
+    monkeypatch.setattr(engine_mod, "_AHEAD_AFTER", 2)
+    eng = _spec_engine()
+    seen, launch, mark = [], eng._launch_verify, eng._mark_ingest
+
+    def step():
+        return min(len(seen) // 12, len(walk) - 1)
+
+    def launching(drafts, dlen, mode):
+        eng._admits = deque([0.010] * 8, maxlen=8)
+        seen.append((step(), mode))
+        launch(drafts, dlen, mode)
+
+    def marking():
+        mark()
+        # called with the fetched launch popped: another still in flight
+        # is the runner ahead of the device
+        return walk[step()][0] - (0.004 if eng._inflight else 0.0)
+
+    eng._launch_verify, eng._mark_ingest = launching, marking
+    n = 12 * len(walk) + 4
+    (res, _frames), = _serve(eng, [_req("w", "hello world", n)]).values()
+    for i, (_launch_s, mode) in enumerate(walk):
+        late = [m for at, m in seen[12 * i + 7:12 * i + 12] if at == i]
+        assert late and set(late) == {mode}, (i, seen[12 * i:12 * i + 12])
+    assert res.token_ids == _spec_engine().generate(
+        _req("ref", "hello world", n)).token_ids
+
+
+def test_step_never_leaves_a_launch_in_flight():
+    """The synchronous driver stays serial however quiet the drafter."""
+    eng = _spec_engine()
+    a0, done = _launches("ahead"), []
+    eng.submit(GenerationRequest(
+        id="s", prompt="hello", options={**GREEDY, "num_predict": 50},
+        on_chunk=lambda d, fin, res: fin and done.append(res)))
+    while eng.step():
+        assert not eng._inflight
+    assert len(done) == 1 and done[0].eval_count == 50
+    assert eng._spec_quiet >= engine_mod._AHEAD_AFTER
+    assert _launches("ahead") == a0
