@@ -9,6 +9,13 @@ architecture (routed experts, latent attention) names a file of its own,
 ``total_params``, ``weight_bytes``, ``step_weight_bytes``,
 ``kv_bytes_per_token``, ``flash_prefill_flops`` and ``chip_share``, each
 taking the configuration. ``of(spec)`` is the one lookup the readers use.
+A family whose launch does not read every position of a context in every
+layer (a window, a ring, a selection) also defines ``kv_launch_bytes(spec,
+per_launch)``: the cache bytes ONE verify / decode launch reads, counted
+from the program's own counters (``per_launch(name)`` is a counter's change
+a launch of the capture, None where it did not move), never from a
+constant; ``phases.kv_bytes_per_launch`` then charges that and not
+context x ``kv_bytes_per_token``.
 The first five count the whole model; ``chip_share`` says by how much
 each is divided to give what ONE chip holds under the configuration's
 ``mesh``, because a trace's times are one chip's."""
@@ -24,6 +31,10 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4, "int8": 1}
 MESH_AXES = ("pp", "dp", "ep", "tp", "sp")      # the program's vocabulary
+# the program's count of what a verify / decode launch reads of its contexts
+# where layers have a window: Σ over live slots of the mean over layers of
+# min(context, that layer's window); the context counter where none has
+WINDOW_TOKENS = "gridllm_engine_verify_window_tokens_total"
 
 
 def of(spec: dict):

@@ -135,15 +135,10 @@ def kv_bytes_per_token(spec: dict, kv_dtype_bytes: int = 2,
                        context: int | None = None) -> float:
     """Keys and values of one position over the layers that keep it. With
     no `context`: the global layers alone (8,192 B at two of five layers),
-    which is what a position costs in pages and what a launch reads of it
-    whatever the context's length; a window layer keeps and reads a window
-    of rows a slot, not the context. The readers that cannot be told of the
-    window (``step.verify_mem_mfu_pct``,
-    ``kernel.ragged_decode_roofline_pct``) take this and so leave out the
-    window layers' reads (3 x 512 rows x 4,096 B = 6.3 MB a live slot, a
-    fifth of a launch's KV bytes at a context of 2,700): they read low
-    here, never high. With a `context` length: the global layers' rows,
-    and of a window layer's min(context, window) rows a position's
+    which is what a position costs in pages; a window layer keeps and
+    reads a window of rows a slot, not the context (what a launch READS is
+    ``kv_launch_bytes``). With a `context` length: the global layers'
+    rows, and of a window layer's min(context, window) rows a position's
     share."""
     win = sum(kinds(spec))
     glob = spec["num_hidden_layers"] - win
@@ -151,6 +146,17 @@ def kv_bytes_per_token(spec: dict, kv_dtype_bytes: int = 2,
         return glob * row_bytes(spec, kv_dtype_bytes)
     seen = min(context, spec["sliding_window"]) / max(context, 1)
     return (glob + win * seen) * row_bytes(spec, kv_dtype_bytes)
+
+
+def kv_launch_bytes(spec: dict, per_launch) -> float | None:
+    """Cache bytes one verify / decode launch READS: a global layer the
+    context from its pages, a window layer min(context, 512) rows from its
+    ring (3 x 512 x 4,096 B = 6.3 MB a live slot past the window). The
+    program counts that (``costs.WINDOW_TOKENS``: Σ over live slots of the
+    mean over the layers held of min(context, window)), so the bytes are
+    one layer's row times the layers times it."""
+    tokens = per_launch(costs.WINDOW_TOKENS)
+    return None if tokens is None else table_bytes_per_token(spec) * tokens
 
 
 def table_bytes_per_token(spec: dict, kv_dtype_bytes: int = 2) -> int:

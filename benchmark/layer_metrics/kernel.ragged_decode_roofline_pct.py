@@ -1,8 +1,9 @@
 """The ragged attention kernel's share of its memory roofline in its
 decode form, in the traced window, the first chip's time against the
 first chip's share: the KV bytes one launch must read on one chip
-(``phases.kv_bytes_per_launch``: mean live context over every layer and
-that chip's KV heads) over the chip's memory bandwidth, over the device
+(``phases.kv_bytes_per_launch``: the live contexts over every layer and
+that chip's KV heads, or what the configuration's costs file says a launch
+reads of them where a layer has a window) over the chip's memory bandwidth, over the device
 time of the ``ragged_attention`` operations (``readers.RAGGED_OPS``) inside
 the verify / decode programs, a launch. Bound named: memory (each key is read once for
 1-5 query rows)."""

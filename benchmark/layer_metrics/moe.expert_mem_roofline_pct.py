@@ -18,7 +18,7 @@ import readers
 
 NAME, UNIT, LAYER, MOVES = ("moe.expert_mem_roofline_pct", "%",
                             "routed experts", "itl_p95_ms")
-CELLS = ["smallthinker21b.chat"]
+CELLS = ["smallthinker21b.chat", "smallthinker21b.long_doc"]
 
 
 def compute(run):

@@ -10,7 +10,7 @@ import phases
 
 NAME, UNIT, LAYER, MOVES = ("moe.experts_touched_pct", "%", "routed experts",
                             "itl_p95_ms")
-CELLS = ["smallthinker21b.chat"]
+CELLS = ["smallthinker21b.chat", "smallthinker21b.long_doc"]
 
 
 def compute(run):

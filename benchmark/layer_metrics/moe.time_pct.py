@@ -5,7 +5,7 @@ import moe
 import readers
 
 NAME, UNIT, LAYER, MOVES = "moe.time_pct", "%", "routed experts", "itl_p95_ms"
-CELLS = ["smallthinker21b.chat"]
+CELLS = ["smallthinker21b.chat", "smallthinker21b.long_doc"]
 
 
 def compute(run):

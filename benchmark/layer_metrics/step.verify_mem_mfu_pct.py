@@ -4,7 +4,8 @@ step's peak, the first chip's time against the first chip's share: the
 bytes one launch has to read on one chip (``step_weight_bytes`` of the
 configuration's costs over ``chip_share``'s weights + the mean live KV
 bytes a launch, ``phases.kv_bytes_per_launch``: the engine's context-token
-counter over the capture, not a sampled gauge) over the chip's memory
+counter over the capture, with each layer's window applied where the costs
+file has ``kv_launch_bytes``; not a sampled gauge) over the chip's memory
 bandwidth, over the program's mean device time a launch. A routed family
 is charged for the experts its live rows TOUCHED, not for every expert
 held (``phases.touched_per_launch`` handed to a costs file whose

@@ -12,6 +12,8 @@ added it) gives {} and every reader built on this returns None.
 
 from __future__ import annotations
 
+import functools
+
 import costs
 import readers
 import stack
@@ -82,14 +84,24 @@ def touched_per_launch(run: dict) -> float | None:
 
 def kv_bytes_per_launch(run: dict) -> float | None:
     """Mean KV bytes one verify / decode launch has to read ON ONE CHIP,
-    over the capture: the context-token counter's change a launch
-    (``capture_per_launch``) times the bytes of one position over every
-    layer, over the chips its KV heads are split across."""
-    tokens, share = capture_per_launch(run, CTX_TOKENS), chip_share(run)
-    if tokens is None or not share:
+    over the capture. A costs file that defines ``kv_launch_bytes(spec,
+    per_launch)`` says what a launch READS of the cache, from the program's
+    own counters (``per_launch(name)`` is ``capture_per_launch(run, name)``):
+    a window, a ring or a selection leaves part of a context unread. Without
+    it: the context-token counter's change a launch times the bytes of one
+    position over every layer. Either way over the chips the KV heads are
+    split across."""
+    share, count = chip_share(run), costs.of(run["config"])
+    if not share:
         return None
-    per_token = costs.of(run["config"]).kv_bytes_per_token(run["config"])
-    return tokens * (per_token / share["kv"])
+    if hasattr(count, "kv_launch_bytes"):
+        whole = count.kv_launch_bytes(
+            run["config"], functools.partial(capture_per_launch, run))
+        return None if whole is None else whole / share["kv"]
+    tokens = capture_per_launch(run, CTX_TOKENS)
+    if tokens is None:
+        return None
+    return tokens * (count.kv_bytes_per_token(run["config"]) / share["kv"])
 
 
 def verify_launches(run: dict) -> tuple[float, int]:

@@ -132,20 +132,29 @@ def warmup_requests(schedule: list, slots: int, chunk: int,
             for phase in (first + cold, second)]
 
 
-def reference_sample(requests: list) -> set[int]:
-    """The requests whose served tokens the reference checks: the
-    shortest prompt and, where prompts share a document, a later question
-    of the same document (one cold admission, one from the prefix cache);
-    else a prompt near the median length. None longer than
-    REFERENCE_MAX_PROMPT."""
-    short = sorted((r for r in requests if len(r.prompt) <= REFERENCE_MAX_PROMPT),
-                   key=lambda r: (len(r.prompt), r.index))
-    if not short:
+def reference_sample(requests: list, rule: dict | None = None) -> set[int]:
+    """The requests whose served tokens the reference checks, by the
+    cell's own `rule` (``workloads/<cell>.json`` ``reference``; absent:
+    ``max_prompt`` REFERENCE_MAX_PROMPT, the shortest). The shortest
+    prompt, or with ``"prefer": "longest"`` the longest (where a window, a
+    ring or a selection does the most), none over ``max_prompt``; and,
+    where prompts share a document, a later question of the same document
+    (one cold admission, one from the prefix cache); else a prompt near the
+    median length. No prompt fits: no record, and `correct` is false."""
+    rule = rule or {}
+    limit = rule.get("max_prompt", REFERENCE_MAX_PROMPT)
+    prefer = rule.get("prefer", "shortest")
+    if prefer not in ("shortest", "longest"):
+        raise ValueError(f"reference.prefer {prefer!r}: shortest or longest")
+    sign = -1 if prefer == "longest" else 1
+    fits = sorted((r for r in requests if len(r.prompt) <= limit),
+                  key=lambda r: (sign * len(r.prompt), r.index))
+    if not fits:
         return set()
-    a = short[0]
-    mates = [r for r in short if r.shared_bytes and r.group == a.group
+    a = fits[0]
+    mates = [r for r in fits if r.shared_bytes and r.group == a.group
              and r.stream == a.stream and r.index != a.index]
-    b = mates[0] if mates else short[len(short) // 2]
+    b = mates[0] if mates else fits[len(fits) // 2]
     return {a.index, b.index}
 
 
@@ -195,9 +204,16 @@ class Run:
         self.mix = self.rehearse_mix() if self.rehearse else self.cell.mix
 
     def rehearse_mix(self) -> dict:
-        """The cell's mix with every length divided by 24: tiny-mistral
-        holds 256 positions."""
+        """The cell's mix with every length divided by 24, or by as much
+        more as brings its longest prompt under 180: the tiny presets
+        hold 256 positions."""
         mix = json.loads(json.dumps(self.cell.mix))
+        def top(d: dict | None) -> int:
+            return (d or {}).get("max", (d or {}).get("value", 0))
+
+        longest = max(top(s.get("shared_tokens")) + top(s.get("own_tokens"))
+                      for s in mix["streams"])
+        by = max(24, -(-longest // 180))
         for s in mix["streams"]:
             for key in ("shared_tokens", "own_tokens", "output_tokens"):
                 d = s.get(key)
@@ -205,7 +221,7 @@ class Run:
                     continue
                 for k in ("value", "median", "min", "max"):
                     if k in d:
-                        d[k] = max(4, d[k] // 24)
+                        d[k] = max(4, d[k] // by)
         return mix
 
     # -- start-up ------------------------------------------------------
@@ -426,7 +442,8 @@ def main() -> int:
         if args.sweep:
             sweep(run, [float(r) for r in args.sweep.split(",")], seconds)
             return 0
-        keep_set = reference_sample(requests)
+        keep_set = reference_sample(requests,
+                                    run.cell.params.get("reference"))
         w = window(run, requests, seconds, bool(args.trace),
                    lambda r: r.index in keep_set)
         setup_s = w["t0"] - T_START

@@ -84,6 +84,17 @@ def kv_bytes_per_token(spec: dict, kv_dtype_bytes: int = 2) -> int:
             * spec["head_dim"] * kv_dtype_bytes)
 
 
+def kv_launch_bytes(spec: dict, per_launch) -> float | None:
+    """Cache bytes one verify / decode launch READS: a window layer reads
+    min(context, 4,096) rows of a slot, a global layer the context. The
+    program counts exactly that (``costs.WINDOW_TOKENS``: Σ over live slots
+    of the mean over layers of min(context, window)), so the bytes are one
+    position's over every layer times it. Under the window the counter is
+    the context counter and this is context x ``kv_bytes_per_token``."""
+    tokens = per_launch(costs.WINDOW_TOKENS)
+    return None if tokens is None else kv_bytes_per_token(spec) * tokens
+
+
 def flash_prefill_flops(spec: dict, t: int) -> float:
     """One causal flash-attention call over a bucket of t positions, one
     layer: QK^T and PV are 2*t*t*D each per query head, half of the

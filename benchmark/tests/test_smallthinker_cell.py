@@ -17,6 +17,7 @@ import run as harness
 from conftest import BENCH, ROOT
 
 CELL = "smallthinker21b.chat"
+CELLS = [CELL, "smallthinker21b.long_doc"]     # the second since PR 55
 READERS = ("moe.time_pct", "moe.expert_mem_roofline_pct",
            "moe.experts_touched_pct")
 
@@ -36,8 +37,8 @@ def test_the_cell_and_its_files_are_found_by_name():
     for name in READERS:
         mod = cell.reader(name)
         assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES, mod.CELLS) == (
-            name, "%", "routed experts", "itl_p95_ms", [CELL])
-        assert entries[name]["workloads"] == [CELL]
+            name, "%", "routed experts", "itl_p95_ms", CELLS)
+        assert entries[name]["workloads"] == CELLS
     spec = cell.config
     cfg = launch_worker.model_config(spec, cell.config_name, False)
     assert (cfg.family, cfg.num_layers, cfg.num_experts, cfg.experts_per_token,

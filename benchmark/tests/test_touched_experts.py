@@ -14,6 +14,7 @@ import run as harness
 BANDWIDTH = 819e9        # TPU v5e, benchmark/peaks.json
 LAUNCHES = 40            # verify launches in the synthetic capture
 CTX_TOKENS = 30_000      # live context tokens a launch
+WINDOW_TOKENS = 21_000   # the same with each layer's window applied
 VERIFY = "jit_verify_block_fn"
 # a grouped kernel as the trace would print it: named after its wrapper,
 # scalar operands first, no weight shape within the 240 characters kept
@@ -41,14 +42,29 @@ WHOLE_STEP_BYTES = {
 }
 
 
-def metrics(launches: float, touched: float | None, ctx_tokens: float) -> str:
+def metrics(launches: float, touched: float | None, ctx_tokens: float,
+            window_tokens: float) -> str:
     lines = [
         f'gridllm_engine_phase_seconds_sum{{model="m",phase="dispatch_verify"}} 1.0',
         f'gridllm_engine_phase_seconds_count{{model="m",phase="dispatch_verify"}} {launches}',
-        f'gridllm_engine_verify_ctx_tokens_total{{model="m"}} {ctx_tokens}']
+        f'gridllm_engine_verify_ctx_tokens_total{{model="m"}} {ctx_tokens}',
+        f'gridllm_engine_verify_window_tokens_total{{model="m"}} {window_tokens}']
     if touched is not None:
         lines.append(f'gridllm_moe_experts_touched_total{{model="m"}} {touched}')
     return "\n".join(lines) + "\n"
+
+
+def kv_bytes(cell: str) -> float:
+    """What a launch of the synthetic capture reads of the cache, by hand:
+    the two families with a window by the window counter over every layer
+    (PR 55: ``kv_launch_bytes``), the others the context over the layers
+    that keep pages."""
+    spec = harness.Cell(cell).config
+    if cell == "smallthinker21b.chat":
+        return WINDOW_TOKENS * 12 * 2 * 4 * 128 * 2      # 24,576 B a position
+    if cell == "laguna-xs2.agent_turns":
+        return WINDOW_TOKENS * 5 * 2 * 8 * 128 * 2       # 20,480 B a position
+    return CTX_TOKENS * costs.of(spec).kv_bytes_per_token(spec)
 
 
 def counts(cell: str):
@@ -66,16 +82,16 @@ def a_launch(cell: str, touched: float | None, expert_seconds: float,
     of the bandwidth."""
     spec, count, one = counts(cell)
     rest = (count.step_weight_bytes(spec) - count.held_experts(spec) * one
-            + CTX_TOKENS * count.kv_bytes_per_token(spec))
+            + kv_bytes(cell))
     step_seconds = expert_seconds + rest / (0.8 * BANDWIDTH)
     return {
         "config": spec,
         "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
         "trace_counters": (
-            metrics(10, None if touched is None else 1000, 1e6),
+            metrics(10, None if touched is None else 1000, 1e6, 8e5),
             metrics(10 + LAUNCHES,
                     None if touched is None else 1000 + touched * LAUNCHES,
-                    1e6 + CTX_TOKENS * LAUNCHES)),
+                    1e6 + CTX_TOKENS * LAUNCHES, 8e5 + WINDOW_TOKENS * LAUNCHES)),
         "trace": {
             "devices": {"/device:TPU:0": {"busy_s": 2 * step_seconds * LAUNCHES}},
             "programs": {VERIFY: {"seconds": step_seconds * LAUNCHES,
@@ -123,7 +139,8 @@ def test_a_launch_that_reads_the_touched_experts_alone_reads_100(cell, share):
     assert phases.touched_per_launch(run) == pytest.approx(touched)
     assert read(cell, FAMILIES[cell][0], run) == pytest.approx(100.0)
     step = read(cell, "step.verify_mem_mfu_pct", run)
-    kv = CTX_TOKENS * count.kv_bytes_per_token(spec)
+    kv = kv_bytes(cell)
+    assert phases.kv_bytes_per_launch(run) == pytest.approx(kv, rel=1e-12)
     secs = run["trace"]["programs"][VERIFY]["seconds"] / LAUNCHES
     assert step == pytest.approx(
         100.0 * (count.step_weight_bytes(spec, touched) + kv) / BANDWIDTH / secs)
