@@ -20,7 +20,6 @@ PERF.md (PR 36) and models/deepseek.py's docstring.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import statistics
 import tempfile
 import time
@@ -65,11 +64,12 @@ def main() -> None:
           f"chunk {args.chunk}", flush=True)
     ps, maxp = args.page, 8192 // args.page
     n_pages = 640 if not interpret else 64
-    one = jax.tree.map(
-        lambda a: a[0],
-        deepseek.init_params(
-            dataclasses.replace(cfg, num_layers=2, vocab_size=256),
-            jax.random.PRNGKey(0))["layers"])
+    # the one leaf both forms read: the latent's up-projection (any latent
+    # family's shapes, whatever else its layers hold)
+    r, hv = cfg.kv_lora_rank, cfg.num_heads * (
+        cfg.qk_nope_head_dim + cfg.v_head_dim)
+    one = {"w_kvb": (jax.random.normal(jax.random.PRNGKey(0), (r, hv))
+                     * r ** -0.5).astype(jnp.bfloat16)}
     cache = PagedKVCache.create(1, n_pages, ps, 1, width, args.slots, maxp,
                                 latent=True)
     cache = PagedKVCache(

@@ -103,12 +103,12 @@ def main() -> None:
                 * shape[-2] ** -0.5).astype(jnp.bfloat16)
 
     lp = {"router": (jax.random.normal(
-              keys[0], (e, cfg.num_experts), jnp.float32) * 0.02
+              keys[0], (e, cfg.router_width), jnp.float32) * 0.02
                      ).astype(jnp.bfloat16),      # init_params' scale
           "we_gate": w(keys[1], x, e, f), "we_up": w(keys[2], x, e, f),
           "we_down": w(keys[3], x, f, e)}
     if cfg.router_bias:
-        lp["router_bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
+        lp["router_bias"] = jnp.zeros((cfg.router_width,), jnp.float32)
     expert_bytes = 3 * e * f * 2
     forms = {
         "dense": lambda lp, h, tw, ti: mixtral._moe_mlp_dense(

@@ -412,6 +412,10 @@ def _model_module(cfg: ModelConfig):
         from gridllm_tpu.models import kimi_linear
 
         return kimi_linear
+    if cfg.family == "longcat_flash":
+        from gridllm_tpu.models import longcat_flash
+
+        return longcat_flash
     if cfg.family == "bert_embed":
         from gridllm_tpu.models import bert_embed
 
@@ -686,6 +690,11 @@ class InferenceEngine:
             from gridllm_tpu.models.configs import config_from_hf_dir
 
             self.cfg = config_from_hf_dir(config.model, config.checkpoint_path)
+        if self.cfg.vocab_held:
+            # a chip's slice of the vocabulary is a smaller vocabulary:
+            # tokenizer, logits and sampling are over the held rows
+            self.cfg = dataclasses.replace(
+                self.cfg, vocab_size=self.cfg.vocab_held)
         self.mod = _model_module(self.cfg)
         # each layer's sliding window (none: it reads the whole context),
         # and how many layers have one
@@ -3128,7 +3137,8 @@ class InferenceEngine:
         """A launch's [live rows routed, experts touched] (summed over
         layers), from the launch's own fetch; empty for a dense family.
         A share of the experts (`cfg.experts_held`) adds the live rows'
-        picks [on held experts, on absent ones]."""
+        picks [on held experts, on absent ones], zero-compute experts
+        (`cfg.zero_experts`) [on those]."""
         if len(stats) >= 2:
             MOE_EXPERT_ROWS_TOTAL.inc(int(stats[0]), model=self.cfg.name)
             MOE_EXPERTS_TOUCHED_TOTAL.inc(int(stats[1]), model=self.cfg.name)
@@ -3137,6 +3147,9 @@ class InferenceEngine:
                                 where="held")
             MOE_PICKS_TOTAL.inc(int(stats[3]), model=self.cfg.name,
                                 where="absent")
+        if len(stats) >= 5:
+            MOE_PICKS_TOTAL.inc(int(stats[4]), model=self.cfg.name,
+                                where="zero")
 
     def _mark_ingest(self) -> float:
         """Leave ``fetch`` for ``ingest``, right after the device_get

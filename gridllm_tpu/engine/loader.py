@@ -86,9 +86,9 @@ def load_checkpoint(
     from gridllm_tpu.models import hf_layout
     from gridllm_tpu.ops.quant import NO_QUANT_SUBTREES, quantize_np_leaf
 
-    if cfg.family == "kimi_linear":
+    if cfg.family in ("kimi_linear", "longcat_flash"):
         raise NotImplementedError(
-            f"{cfg.name}: kimi_linear checkpoints are not read (its "
+            f"{cfg.name}: {cfg.family} checkpoints are not read (its "
             "equations are written from the published keys and the report, "
             "the tensor names of no modeling file are here): it is served "
             "on seeded weights")

@@ -47,7 +47,8 @@ class VisionConfig:
 class ModelConfig:
     name: str
     # llama | qwen2 | qwen3 | gemma2 | mixtral | smallthinker |
-    # deepseek_v2 | olmo_hybrid | laguna | kimi_linear | llava | bert_embed
+    # deepseek_v2 | olmo_hybrid | laguna | kimi_linear | longcat_flash |
+    # llava | bert_embed
     # (engine._model_module picks the module)
     family: str = "llama"
     vocab_size: int = 128_256
@@ -90,6 +91,17 @@ class ModelConfig:
     # of the expert-parallel group, whose exchange is not run)
     experts_held: int | None = None
     experts_first: int | None = None
+    # the rows of the vocabulary this chip holds (None = all of
+    # vocab_size): a slice of the embedding and the head, one chip of the
+    # group that shares the vocabulary (longcat_flash). A sliced vocabulary
+    # is a smaller vocabulary: the engine serves `vocab_rows` ids, the
+    # logits and the sampling are over them
+    vocab_held: int | None = None
+    # zero-compute experts (longcat_flash): the router is num_experts +
+    # zero_experts wide, and a pick at or past num_experts adds its weight
+    # times the token itself (identity) with no product: it is neither
+    # held nor absent, and every chip computes it for its own tokens
+    zero_experts: int = 0
     # latent attention (MLA, deepseek_v2): the cache row of a token in a
     # layer is ONE latent of kv_lora_rank and one RoPE key of
     # qk_rope_head_dim, shared by every head (`cache_heads`, `cache_dim`);
@@ -99,6 +111,16 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # longcat_flash's latent attention: a low-rank query (q = W_qb
+    # RMSNorm(W_qa h); 0 = one full product), the query times
+    # sqrt(hidden / q_lora_rank) and the normed latent times sqrt(hidden /
+    # kv_lora_rank) (`mla_scales`), and attn_sublayers attention
+    # sublayers a block, each with pages of its own: a block owns that
+    # many layers of the pool (`cache_layers`)
+    q_lora_rank: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    attn_sublayers: int = 1
     # smallthinker: the router reads the PRE-attention normed state, not
     # the post-attention one the experts compute on
     router_pre_attn: bool = False
@@ -175,6 +197,11 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: experts [{self.experts_first}, +"
                 f"{self.experts_held}) are not among {self.num_experts}")
+        if self.vocab_held is not None and not (
+                0 < self.vocab_held <= self.vocab_size):
+            raise ValueError(
+                f"{self.name}: {self.vocab_held} rows are not a slice of a "
+                f"vocabulary of {self.vocab_size}")
 
     @property
     def head_dim_(self) -> int:
@@ -252,8 +279,29 @@ class ModelConfig:
 
     @property
     def cache_layers(self) -> int:
-        """Layers that own pages: the page pool's leading axis."""
-        return self.num_layers - self.linear_layers - self.ring_layers
+        """Layers that own pages: the page pool's leading axis. A block of
+        several attention sublayers (`attn_sublayers`) owns one each."""
+        return (self.num_layers - self.linear_layers
+                - self.ring_layers) * self.attn_sublayers
+
+    @property
+    def vocab_rows(self) -> int:
+        """Rows of the embedding and the head held here."""
+        return self.vocab_held or self.vocab_size
+
+    @property
+    def router_width(self) -> int:
+        """The router's outputs: the routed experts and, behind them, the
+        zero-compute ones."""
+        return self.num_experts + self.zero_experts
+
+    @property
+    def mla_scales(self) -> tuple[float, float]:
+        """(what the low-rank query is multiplied by, what the normed
+        latent is): sqrt(hidden / rank) where the config says so, else 1."""
+        e = self.hidden_size
+        return ((e / self.q_lora_rank) ** 0.5 if self.mla_scale_q_lora else 1.0,
+                (e / self.kv_lora_rank) ** 0.5 if self.mla_scale_kv_lora else 1.0)
 
     def ring_pages(self, launch_rows: int, page_size: int) -> int:
         """Pages of a slot's ring in each window layer: the window, the
@@ -296,6 +344,12 @@ class ModelConfig:
         return self.experts_first or 0, self.experts_held
 
     @property
+    def routes_elsewhere(self) -> bool:
+        """Whether a router pick may land on no expert held here: a share
+        (`experts_held`) or zero-compute experts behind the routed ones."""
+        return self.experts_held is not None or bool(self.zero_experts)
+
+    @property
     def conv_channels(self) -> int:
         """Channels the linear layers' convolution runs over: q, k, v."""
         return self.linear_num_heads * (
@@ -323,7 +377,7 @@ class ModelConfig:
             attention_bias=False,
         )
         if self.family in ("smallthinker", "deepseek_v2", "olmo_hybrid",
-                           "laguna", "kimi_linear"):
+                           "laguna", "kimi_linear", "longcat_flash"):
             raise NotImplementedError(
                 f"{self.family} has no transformers twin here: its "
                 "reference is under benchmark/reference/")
@@ -642,6 +696,32 @@ _KIMI_LINEAR = register(ModelConfig(
 register(dataclasses.replace(
     _KIMI_LINEAR, name="kimi-linear:48b-ep4", experts_held=64,
     experts_first=0))
+# LongCat-Flash (meituan-longcat, config.json of -Chat and -Omni alike;
+# the language model of -Omni): 28 blocks of TWO latent-attention
+# sublayers (64 heads of 128 + 64, a latent of 512, a low-rank query of
+# 1,536, both scaled by sqrt(hidden / rank)), two dense SwiGLUs of 12,288
+# and one expert layer that leaves after the first attention and rejoins
+# at the block's end (the shortcut): 512 experts of 2,048 and 256
+# zero-compute (identity) ones behind one softmax router of 768, top-12
+# chosen with a selection bias, not renormalised, x 6; no shared expert
+_LONGCAT_FLASH = register(ModelConfig(
+    name="longcat-flash:560b", family="longcat_flash", vocab_size=131_072,
+    hidden_size=6144, intermediate_size=12_288, num_layers=28, num_heads=64,
+    num_kv_heads=64, head_dim=192, rope_theta=10_000_000.0, rms_eps=1e-5,
+    max_seq_len=131_072, num_experts=512, experts_per_token=12,
+    moe_intermediate_size=2048, norm_topk_prob=False,
+    routed_scaling_factor=6.0, router_bias=True, zero_experts=256,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, q_lora_rank=1536, mla_scale_q_lora=True,
+    mla_scale_kv_lora=True, attn_sublayers=2,
+))
+# one chip of the 32 that share each block (expert-parallel over 32): the
+# router's 768 outputs and top-12 as published, experts 0-15 held here,
+# and of the embedding and the head an eighth (rows 0-16,383: the
+# vocabulary split eight ways)
+register(dataclasses.replace(
+    _LONGCAT_FLASH, name="longcat-flash:560b-ep32", experts_held=16,
+    experts_first=0, vocab_held=16_384))
 register(ModelConfig(
     name="all-minilm", family="bert_embed", vocab_size=30_522,
     hidden_size=384, intermediate_size=1536, num_layers=6, num_heads=12,
@@ -742,6 +822,23 @@ register(ModelConfig(
     linear_conv_kernel=4, linear_channel_decay=True,
     experts_held=4, experts_first=4,
 ))
+# longcat-flash's shape in small: two blocks (four pool layers), a
+# low-rank query, both latent scales, 16 experts and 8 zero-compute ones
+# top-4 with a selection bias (a token in three picks a zero-compute
+# expert by chance alone; eight leave no test to chance); this chip holds
+# experts 4-7 (the second of four shares: neither the first nor all)
+register(ModelConfig(
+    name="tiny-longcat-flash", family="longcat_flash", vocab_size=256,
+    hidden_size=64, intermediate_size=128, num_layers=2, num_heads=4,
+    num_kv_heads=4, head_dim=32, rope_theta=10_000.0, rms_eps=1e-5,
+    max_seq_len=256, num_experts=16, experts_per_token=4,
+    moe_intermediate_size=32, norm_topk_prob=False,
+    routed_scaling_factor=6.0, router_bias=True, zero_experts=8,
+    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16,
+    v_head_dim=16, q_lora_rank=24, mla_scale_q_lora=True,
+    mla_scale_kv_lora=True, attn_sublayers=2,
+    experts_held=4, experts_first=4,
+))
 register(ModelConfig(
     name="tiny-qwen2", family="qwen2", vocab_size=256, hidden_size=64,
     intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -818,6 +915,7 @@ _HF_FAMILY = {
     "olmo_hybrid": "olmo_hybrid",
     "laguna": "laguna",
     "kimi_linear": "kimi_linear",
+    "longcat_flash": "longcat_flash",
     "bert": "bert_embed",
 }
 
@@ -1102,6 +1200,70 @@ def _kimi_linear_from_hf(name: str, hf: dict, path: str) -> ModelConfig:
     return cfg
 
 
+def _longcat_flash_from_hf(name: str, hf: dict, path: str) -> ModelConfig:
+    """LongCat-Flash's published keys (`num_layers`, `ffn_hidden_size`,
+    `expert_ffn_hidden_size`, `moe_topk`, `zero_expert_num`, ...): a block
+    is two latent-attention sublayers, two dense SwiGLUs and one expert
+    layer on a shortcut. `n_routed_experts` is what THIS chip holds of each
+    block's routed experts: where the file also gives `router_experts`
+    (the published count) and they differ, the block is a share from
+    `experts_first`; the router is `router_experts + zero_expert_num`
+    wide either way. `vocab_held`, where the file gives it, is the rows
+    of the vocabulary this chip holds (`ModelConfig.vocab_held`: a slice
+    of the embedding and the head, over which the traffic, the logits and
+    the sampling run). Refused, not run wrong: a full-rank query, another
+    attention than MLA, a scaled rotary embedding, zero-compute experts of
+    another kind than identity, a bias on the projections."""
+    unserved = {
+        "q_lora_rank": not hf.get("q_lora_rank"),
+        "attention_method": hf.get("attention_method", "MLA") != "MLA",
+        "rope_scaling": hf.get("rope_scaling") is not None,
+        "zero_expert_type": bool(hf.get("zero_expert_num")) and hf.get(
+            "zero_expert_type", "identity") != "identity",
+        "attention_bias": bool(hf.get("attention_bias")),
+        "hidden_act": hf.get("hidden_act", "silu") != "silu",
+    }
+    if any(unserved.values()):
+        raise ValueError(
+            f"{path}: longcat_flash with "
+            f"{[k for k, v in unserved.items() if v]} as published is not "
+            "served (a low-rank query, MLA, an unscaled rotary embedding, "
+            "identity zero-compute experts, no bias, silu)")
+    routed = hf.get("router_experts", hf["n_routed_experts"])
+    share = hf["n_routed_experts"] != routed
+    return ModelConfig(
+        name=name, family="longcat_flash",
+        vocab_size=hf["vocab_size"], vocab_held=hf.get("vocab_held"),
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["ffn_hidden_size"],
+        num_layers=hf["num_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_attention_heads"],
+        head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+        rope_theta=float(hf.get("rope_theta", 10_000.0)),
+        rms_eps=hf.get("rms_norm_eps", 1e-5),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_seq_len=hf.get("max_position_embeddings", 131_072),
+        num_experts=routed,
+        experts_per_token=hf["moe_topk"],
+        moe_intermediate_size=hf["expert_ffn_hidden_size"],
+        norm_topk_prob=False,
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        router_bias=True,
+        zero_experts=hf.get("zero_expert_num") or 0,
+        kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        q_lora_rank=hf["q_lora_rank"],
+        mla_scale_q_lora=bool(hf.get("mla_scale_q_lora")),
+        mla_scale_kv_lora=bool(hf.get("mla_scale_kv_lora")),
+        attn_sublayers=2,
+        experts_held=hf["n_routed_experts"] if share else None,
+        experts_first=(hf.get("experts_first") or 0) if share else None,
+    )
+
+
 def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
     mt = hf.get("model_type", "llama")
     if mt == "llava":
@@ -1186,6 +1348,8 @@ def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
         return _laguna_from_hf(name, hf, path)
     if family == "kimi_linear":
         return _kimi_linear_from_hf(name, hf, path)
+    if family == "longcat_flash":
+        return _longcat_flash_from_hf(name, hf, path)
     scaling = None
     rs = hf.get("rope_scaling") or None
     if rs and rs.get("rope_type", rs.get("type")) == "llama3":

@@ -105,13 +105,30 @@ def _project(cfg: ModelConfig, lp: Params, h: jnp.ndarray, pos: jnp.ndarray,
     """Normed state h [B, T, E] at positions pos [B, T] → q_nope
     [B, T, H, dn], q_pe [B, T, H, dr] (rotated), row [B, T, R + dr]: the
     cache row, the normed latent and its rotated key. `inv_freq` None: no
-    positional encoding (kimi_linear's latent layers), nothing rotates."""
+    positional encoding (kimi_linear's latent layers), nothing rotates.
+    `cfg.q_lora_rank`: the query through a low-rank pair and its norm;
+    `cfg.mla_scales`: the query and the normed latent each times a
+    constant (longcat_flash; 1 and 1 elsewhere)."""
     p = llama._precision(h)
     b, t, _ = h.shape
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    q = jnp.dot(h, lp["wq"], precision=p).reshape(b, t, cfg.num_heads, dn + dr)
+    s_q, s_kv = cfg.mla_scales
+    if cfg.q_lora_rank:
+        # longcat_flash: q = W_qb RMSNorm(W_qa h), times sqrt(E / rank)
+        with jax.named_scope("mla_qlora"):
+            cq = rms_norm(jnp.dot(h, lp["w_qa"], precision=p), lp["q_norm"],
+                          cfg.rms_eps)
+            q = jnp.dot(cq, lp["w_qb"], precision=p,
+                        preferred_element_type=jnp.float32)
+            q = (q * s_q).astype(h.dtype)
+    else:
+        q = jnp.dot(h, lp["wq"], precision=p)
+    q = q.reshape(b, t, cfg.num_heads, dn + dr)
     kva = jnp.dot(h, lp["w_kva"], precision=p)
-    c = rms_norm(kva[..., :cfg.kv_lora_rank], lp["kv_norm"], cfg.rms_eps)
+    kv_norm = lp["kv_norm"]
+    if s_kv != 1.0:     # the scale inside the norm's float32 product
+        kv_norm = kv_norm.astype(jnp.float32) * s_kv
+    c = rms_norm(kva[..., :cfg.kv_lora_rank], kv_norm, cfg.rms_eps)
     if inv_freq is None:
         return q[..., :dn], q[..., dn:], jnp.concatenate(
             [c, kva[..., cfg.kv_lora_rank:]], axis=-1)
@@ -218,8 +235,12 @@ def _stack(params: Params, cfg: ModelConfig, x, pos, attend: Attend,
 
 
 def hidden_states(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-                  seq_lens: jnp.ndarray | None = None, mesh=None) -> jnp.ndarray:
-    """Final-norm hidden states [B, T, E], cache-free: the expanded form."""
+                  seq_lens: jnp.ndarray | None = None, mesh=None,
+                  stack=None) -> jnp.ndarray:
+    """Final-norm hidden states [B, T, E], cache-free: the expanded form.
+    `stack` (here and in every phase below): another family's stack runner
+    with `_stack`'s contract (models/longcat_flash.py), rows [pool layers,
+    B, T, R + dr]."""
     b, t = tokens.shape
     x = params["embed"][tokens]
     pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
@@ -230,15 +251,15 @@ def hidden_states(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
     def attend(lp, li, q_nope, q_pe, row):
         return _expanded(cfg, lp, q_nope, q_pe, pos, row, pos, valid)
 
-    x, _, _ = _stack(params, cfg, x, pos, attend, mesh, valid)
+    x, _, _ = (stack or _stack)(params, cfg, x, pos, attend, mesh, valid)
     return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
-            mesh=None) -> jnp.ndarray:
+            mesh=None, stack=None) -> jnp.ndarray:
     """Cache-free full forward: tokens [B, T] → logits [B, T, V] (fp32)."""
-    return llama._unembed(cfg, params, hidden_states(params, cfg, tokens,
-                                                     mesh=mesh))
+    return llama._unembed(cfg, params, hidden_states(
+        params, cfg, tokens, mesh=mesh, stack=stack))
 
 
 def _with_k(cache: PagedKVCache, k_pool, **kw) -> PagedKVCache:
@@ -293,11 +314,11 @@ def _group_attend(cfg: ModelConfig, cache: PagedKVCache, base, tree_pos=None,
 
 
 def decode_step(params: Params, cfg: ModelConfig, tokens, cache, active,
-                mesh=None, with_stats: bool = False):
+                mesh=None, with_stats: bool = False, stack=None):
     """One decode step for ALL slots (llama.decode_step's contract)."""
     x = params["embed"][tokens][:, None]              # [S, 1, E]
     positions = cache.lengths
-    x, rows, stats = _stack(
+    x, rows, stats = (stack or _stack)(
         params, cfg, x, positions[:, None],
         _group_attend(cfg, cache, positions), mesh, active[:, None])
     x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_eps)
@@ -314,7 +335,7 @@ def decode_step(params: Params, cfg: ModelConfig, tokens, cache, active,
 
 def verify_step(params: Params, cfg: ModelConfig, tokens, cache, active,
                 mesh=None, tree_pos=None, tree_mask=None,
-                with_stats: bool = False):
+                with_stats: bool = False, stack=None):
     """One speculative-verify forward for ALL slots (llama.verify_step's
     contract: candidates written optimistically, lengths unchanged)."""
     s, t = tokens.shape
@@ -323,7 +344,7 @@ def verify_step(params: Params, cfg: ModelConfig, tokens, cache, active,
     rel = (jnp.asarray(tree_pos, jnp.int32) if tree_pos is not None
            else jnp.arange(t, dtype=jnp.int32))
     live = jnp.broadcast_to(active[:, None], tokens.shape)
-    x, rows, stats = _stack(
+    x, rows, stats = (stack or _stack)(
         params, cfg, x, base[:, None] + rel[None],
         _group_attend(cfg, cache, base, tree_pos, tree_mask), mesh, live)
     logits = llama._unembed(
@@ -340,7 +361,7 @@ def verify_step(params: Params, cfg: ModelConfig, tokens, cache, active,
 
 def mixed_step(params: Params, cfg: ModelConfig, chunk_tokens, chunk_start,
                chunk_len, slot, table_row, tokens, cache, active, mesh=None,
-               embeds=None):
+               embeds=None, stack=None):
     """One fused chunked-prefill + decode step (llama.mixed_step's
     contract): rows [0, C) the admitting slot's chunk, rows [C, C + S) one
     decode token a slot, one ragged launch a layer."""
@@ -353,7 +374,7 @@ def mixed_step(params: Params, cfg: ModelConfig, chunk_tokens, chunk_start,
     pos = jnp.concatenate(
         [chunk_start + jnp.arange(c, dtype=jnp.int32), positions])[None]
     live = jnp.concatenate([jnp.arange(c) < chunk_len, active])[None]
-    x, rows, _ = _stack(
+    x, rows, _ = (stack or _stack)(
         params, cfg, x, pos,
         _chunk_attend(cfg, cache, table_row, chunk_start, total, c,
                       group=(cache.page_table, positions)), mesh, live)

@@ -118,7 +118,18 @@ form's one-hot and the grouped form's gates over the held, the sorted
 form's groups over the held, `_sorted_share`, which is an `ep` shard's
 body too), and a pick of an expert that lives elsewhere adds nothing: the exchange that would bring
 its part is not run, and nothing stands in for it. The rule of the shape
-reads the held experts over the picks expected here, which is X / k again.
+reads the held experts over the picks expected here, which is X / k again,
+and the held experts over the picks a token makes anywhere
+(`_SORTED_MIN_HELD_A_PICK`): the sorted form sorts every pick of a row.
+
+ZERO-COMPUTE experts (`cfg.zero_experts`, longcat_flash, PR 57): the
+router is `cfg.router_width` = num_experts + zero_experts wide, and a pick
+at or past num_experts is an identity expert: it adds its weight times the
+token itself, with no product and no weight read (`_zero_mlp`, added once
+whatever form the routed ones take, as a shared expert is: every chip of
+an expert-parallel group computes it alike for its own tokens). Such a
+pick is neither held nor absent: `_held` gives it no gate, `_touched`
+counts no expert for it, `_route_stats` counts it apart.
 
 Routing numerics follow HF `MixtralSparseMoeBlock`: softmax over ALL
 expert logits in fp32 → top-k → renormalize the selected weights (the
@@ -154,6 +165,15 @@ _RAGGED_MIN_TOKENS = 16
 # form bound by its arithmetic: 197 TFLOP/s over 819 GB/s)
 _SORTED_MIN_WASTE = 16
 _SORTED_MIN_ROWS = 240
+# and, of a share, this many held experts a pick of a token: the sorted
+# form sorts, gathers and scatters EVERY pick of a row, held here or not
+# (k rows a token), and inside a layer scan its custom calls are handed a
+# copy of the held experts' slabs, where the all-experts form multiplies
+# the held experts in place. Read in the cells: sorted at 8 held a pick
+# (kimi-linear:48b-ep4, 64 held, top-8; PR 51), all-experts at 1.3
+# (longcat-flash:560b-ep32, 16 held, top-12: a mixed launch's four expert
+# layers 21 ms sorted, 12.3 of them the slabs' copies, against 13.5; PR 57)
+_SORTED_MIN_HELD_A_PICK = 4
 
 
 def _route(cfg: ModelConfig, lp: Params, r: jnp.ndarray):
@@ -195,8 +215,8 @@ def _act(cfg: ModelConfig):
 
 def _held(cfg: ModelConfig, top_i: jnp.ndarray):
     """Router picks as this chip's share sees them: (the pick's index
-    among the held experts, `held` itself for an absent one; whether it
-    is held)."""
+    among the held experts, `held` itself for an absent one or a
+    zero-compute one; whether it is held)."""
     first, held = cfg.held_experts
     local = top_i - first
     here = (local >= 0) & (local < held)
@@ -210,11 +230,11 @@ def _touched(cfg: ModelConfig, top_i: jnp.ndarray, live) -> jnp.ndarray:
     flat = top_i.reshape(-1, cfg.experts_per_token)
     if live is None:
         live = jnp.ones(flat.shape[:1], bool)
-    if cfg.experts_held is None:
+    if not cfg.routes_elsewhere:
         return jnp.zeros((cfg.num_experts,), jnp.int32).at[flat].max(
             jnp.broadcast_to(live.reshape(-1, 1).astype(jnp.int32), flat.shape))
     on = jnp.broadcast_to(live.reshape(-1, 1).astype(jnp.int32), flat.shape)
-    return jnp.zeros((cfg.experts_held,), jnp.int32).at[
+    return jnp.zeros((cfg.held_experts[1],), jnp.int32).at[
         _held(cfg, flat)[0]].max(on, mode="drop")
 
 
@@ -222,17 +242,22 @@ def _route_stats(cfg: ModelConfig, top_i: jnp.ndarray, live) -> jnp.ndarray:
     """[live token rows routed, experts with at least one live row] of one
     layer, int32[2]: what the engine's gridllm_moe_* counters sum. A share
     (`cfg.experts_held`) counts the HELD experts touched and adds the live
-    rows' picks [on held experts, on absent ones]: int32[4]."""
+    rows' picks [on held experts, on absent ones]: int32[4]; a family with
+    zero-compute experts adds [on those]: int32[5]."""
     flat = top_i.reshape(-1, cfg.experts_per_token)
     if live is None:
         live = jnp.ones(flat.shape[:1], bool)
     hit = _touched(cfg, flat, live)
-    if cfg.experts_held is None:
+    if not cfg.routes_elsewhere:
         return jnp.stack([live.sum().astype(jnp.int32), hit.sum()])
     on = jnp.broadcast_to(live.reshape(-1, 1).astype(jnp.int32), flat.shape)
     picks = (on * _held(cfg, flat)[1]).sum()
+    if not cfg.zero_experts:
+        return jnp.stack([live.sum().astype(jnp.int32), hit.sum(), picks,
+                          on.sum() - picks])
+    zero = (on * (flat >= cfg.num_experts)).sum()
     return jnp.stack([live.sum().astype(jnp.int32), hit.sum(), picks,
-                      on.sum() - picks])
+                      on.sum() - picks - zero, zero])
 
 
 def _moe_mlp_dense(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
@@ -246,10 +271,10 @@ def _moe_mlp_dense(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     share (`cfg.experts_held`) the expert leaves hold the held experts
     only and a pick of an absent one has no gate: it adds nothing."""
     p = llama._precision(x)
-    if cfg.experts_held is None:
+    if not cfg.routes_elsewhere:
         one_hot = jax.nn.one_hot(top_i, cfg.num_experts, dtype=jnp.float32)
-    else:       # a share: an absent pick's row of the one-hot is zeros
-        one_hot = jax.nn.one_hot(_held(cfg, top_i)[0], cfg.experts_held,
+    else:       # an absent or zero-compute pick's row of the one-hot is zeros
+        one_hot = jax.nn.one_hot(_held(cfg, top_i)[0], cfg.held_experts[1],
                                  dtype=jnp.float32)
     gates = jnp.einsum("...k,...kx->...x", top_w, one_hot).astype(x.dtype)
 
@@ -272,8 +297,8 @@ def _moe_mlp_grouped(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     if live is not None:
         w = jnp.where(live.reshape(-1, 1), w, 0.0)
     idx = top_i.reshape(-1, k)
-    if cfg.experts_held is not None:    # an absent pick: a row of zeros
-        idx = _held(cfg, idx)[0]
+    if cfg.routes_elsewhere:
+        idx = _held(cfg, idx)[0]        # absent, zero-compute: a row of zeros
     gates = jnp.where(idx[..., None] == jnp.arange(held), w[..., None],
                       0.0).sum(axis=1)
     # the whole stacked leaves and this layer's index where a layer scan
@@ -303,7 +328,7 @@ def _moe_mlp_ragged(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     e = x.shape[-1]
     xf = x.reshape(-1, e)                       # [T, E]
     t = xf.shape[0]
-    if cfg.experts_held is not None:
+    if cfg.routes_elsewhere:
         out = _sorted_share(
             _act(cfg), xf, top_w.reshape(t, k), top_i.reshape(t, k),
             cfg.held_experts[0], lp["we_gate"], lp["we_up"], lp["we_down"])
@@ -416,6 +441,14 @@ def _shared_mlp(lp: Params, x: jnp.ndarray) -> jnp.ndarray:
         return jnp.dot(jax.nn.silu(g) * u, lp["ws_down"], precision=p)
 
 
+def _zero_mlp(cfg: ModelConfig, x: jnp.ndarray, top_w, top_i) -> jnp.ndarray:
+    """The zero-compute experts' part: each row times the sum of its
+    weights on picks at or past `cfg.num_experts` (identity experts)."""
+    with jax.named_scope("moe_zero"):
+        w = jnp.where(top_i >= cfg.num_experts, top_w, 0.0).sum(axis=-1)
+        return (x.astype(jnp.float32) * w[..., None]).astype(x.dtype)
+
+
 def _use_ragged(cfg: ModelConfig, n_tokens: int, meshed: bool,
                 backend: str | None = None) -> bool:
     """Whether a call of `n_tokens` rows takes the sorted dispatch:
@@ -434,8 +467,10 @@ def _use_ragged(cfg: ModelConfig, n_tokens: int, meshed: bool,
     # makes among them: of a share, the held experts over the picks
     # expected here (k held / X), which is X / k again
     held = cfg.held_experts[1]
-    picks = cfg.experts_per_token * held / cfg.num_experts
-    return held >= _SORTED_MIN_WASTE * picks and n_tokens >= _SORTED_MIN_ROWS
+    picks = cfg.experts_per_token * held / cfg.router_width
+    return (held >= _SORTED_MIN_WASTE * picks
+            and held >= _SORTED_MIN_HELD_A_PICK * cfg.experts_per_token
+            and n_tokens >= _SORTED_MIN_ROWS)
 
 
 def expert_form(cfg: ModelConfig, n_tokens: int, mesh=None,
@@ -501,12 +536,16 @@ def _routed_mlp(cfg: ModelConfig, mesh, live, lp: Params, x: jnp.ndarray,
     n_tokens = math.prod(x.shape[:-1])
     form = expert_form(cfg, n_tokens, mesh)
     if form == "grouped":
-        return _moe_mlp_grouped(cfg, lp, x, top_w, top_i, live), stats
-    if form == "all_experts":
-        return _moe_mlp_dense(cfg, lp, x, top_w, top_i), stats
-    if mesh is not None:
-        return _moe_mlp_ragged_ep(cfg, lp, x, top_w, top_i, mesh), stats
-    return _moe_mlp_ragged(cfg, lp, x, top_w, top_i), stats
+        y = _moe_mlp_grouped(cfg, lp, x, top_w, top_i, live)
+    elif form == "all_experts":
+        y = _moe_mlp_dense(cfg, lp, x, top_w, top_i)
+    elif mesh is not None:
+        y = _moe_mlp_ragged_ep(cfg, lp, x, top_w, top_i, mesh)
+    else:
+        y = _moe_mlp_ragged(cfg, lp, x, top_w, top_i)
+    if cfg.zero_experts:
+        y = y + _zero_mlp(cfg, x, top_w, top_i)
+    return y, stats
 
 
 @partial(jax.jit, static_argnames=("shape", "scale", "dtype"))

@@ -185,9 +185,11 @@ MOE_PICKS_TOTAL = _OBS.counter(
     "gridllm_moe_picks_total",
     "A share of the experts (ModelConfig.experts_held): router picks of "
     "live rows in the verify / decode block launches, summed over layers, "
-    "by where the picked expert lives: held (computed here) or absent (on "
-    "another chip of the expert-parallel group: it adds nothing here). "
-    "Nothing for a family that holds every expert.",
+    "by where the picked expert lives: held (computed here), absent (on "
+    "another chip of the expert-parallel group: it adds nothing here) or "
+    "zero (a zero-compute expert, ModelConfig.zero_experts: the token "
+    "itself times the weight, no product). Nothing for a family that "
+    "holds every expert and has no zero-compute ones.",
     ("model", "where"),
 )
 

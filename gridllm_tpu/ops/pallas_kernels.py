@@ -699,6 +699,14 @@ def _ragged_attn_kernel(
         group_tile()
 
 
+# query rows of one chunk tile of `ragged_attention` (tokens x the query
+# heads that share a cache head): 32 heads x 128 tokens is the largest
+# tile a cell has run (kimi-linear's latent layers); twice that (64 heads,
+# longcat-flash) does not compile for a v5e (149 MB of VMEM, 112 of them
+# register spills: tests/test_longcat_flash.py)
+_RAGGED_TILE_ROWS = 4096
+
+
 def _ragged_vmem_limit(ps: int, kvh: int, g: int, d: int, bq: int, c: int,
                        td: int, itemsize: int, pool_itemsize: int) -> int:
     """Scoped-VMEM limit for one ragged_attention launch, from its
@@ -805,7 +813,10 @@ def ragged_attention(
     c = bq = bk = 0
     if has_chunk:
         c = q_chunk.shape[1]
-        bq = min(128, c)
+        # a chunk tile is bq tokens x g query heads a cache head: at most
+        # _RAGGED_TILE_ROWS query rows (64 heads on one latent row: 64
+        # tokens a tile; at 8,192 rows Mosaic's spills pass the VMEM)
+        bq = min(128, c, max(8, _RAGGED_TILE_ROWS // g))
         bk = min(128, c)
         assert c % bq == 0 and c % bk == 0, (c, bq, bk)
         nct = c // bq

@@ -43,6 +43,7 @@ FAMILIES = {
                     "restore_snapshot", "SAVES"},
     "tiny-kimi-linear": {"validate_mesh", "STEP_STATS", "new_state",
                          "commit_verify", "SAVES"},
+    "tiny-longcat-flash": {"validate_mesh", "STEP_STATS"},
 }
 
 
@@ -65,11 +66,11 @@ def admits(mod, cfg, **axes) -> bool:
 
 def test_the_lists_are_the_engines_lookups():
     """A hook added to engine.py is added above, with the families that
-    define it; and the presets reach nine different modules."""
+    define it; and the presets reach ten different modules."""
     assert names_the_engine_reads() == (
         EVERY_FAMILY | DECODER | SP_OR_PP | HOOKS)
     assert len({engine_mod._model_module(get_config(p))
-                for p in FAMILIES}) == len(FAMILIES) == 9
+                for p in FAMILIES}) == len(FAMILIES) == 10
 
 
 @pytest.mark.parametrize("preset", sorted(FAMILIES))
