@@ -1,11 +1,13 @@
 """Time the expert layer's forms on the chip at one routed model's shapes:
-the all-experts einsum, the sorted ``ragged_dot`` dispatch and the grouped
-kernel (``grouped_experts``: the touched experts alone), at the row counts
-the step programs have (a verify launch's slots x (K+1) rows, a chunk's
-rows).
+the all-experts einsum, the sorted ``ragged_dot`` dispatch, the grouped
+kernel (``grouped_experts``: the touched experts alone, every row against
+each) and its sorted regime (``grouped_sorted``: each expert against the
+rows that picked it), at the row counts the step programs have (a verify
+launch's slots x (K+1) rows, a chunk's rows).
 
     python deploy/tpu_moe_forms.py [--model smallthinker:21b] [--rows 80,1040]
                                    [--touch 0.1,0.33,1] [--forms ...] [--ops]
+                                   [--tile 512] [--tile-rows 64]
 
 One layer's weights, random; each form jitted alone and timed over
 ``--reps`` calls after one warm-up (host clock around
@@ -18,13 +20,15 @@ experts' bytes and the bytes/s that makes of the device time.
 router's weights stay, its picks are drawn among the first experts: a
 verify launch's candidate rows route alike, PERF.md PR 33); without it
 the rows route as the random router says. ``--tile`` forces the grouped
-kernel's F-tile. ``--ops`` also prints a form's largest device operations
-by name, which is how a reader's pattern for the grouped products is
-found. What it read
-on the v5e is in models/mixtral.py's docstring and PERF.md (PR 33; PR 36
-for ``--model deepseek-v2-lite:16b``: 64 experts of 2048 x 1408, whose
-router is not renormalised; the shared experts are outside both forms
-and are not timed here).
+kernel's F-tile, ``--tile-rows`` the sorted regime's row tile (by default
+the shape's: ``ops/experts.py`` ``sorted_tile_rows``). ``--ops`` also
+prints a form's largest device operations by name, which is how a reader's
+pattern for the grouped products is found. What it read on the v5e is in
+models/mixtral.py's docstring and PERF.md (PR 33; PR 36 for ``--model
+deepseek-v2-lite:16b``: 64 experts of 2048 x 1408, whose router is not
+renormalised; the shared experts are outside every form and are not timed
+here; PR 58 for ``--rows 528,1040 --forms dense,ragged,grouped_sorted`` at
+six models, the readings ``expert_form``'s rule past the ridge rests on).
 """
 
 from __future__ import annotations
@@ -80,8 +84,10 @@ def main() -> None:
     ap.add_argument("--rows", default="80,1040")
     ap.add_argument("--touch", default="",
                     help="shares of the experts the picks fall among")
-    ap.add_argument("--forms", default="dense,ragged,grouped")
+    ap.add_argument("--forms", default="dense,ragged,grouped,grouped_sorted")
     ap.add_argument("--tile", type=int, default=0)
+    ap.add_argument("--tile-rows", type=int, default=0,
+                    help="the sorted regime's row tile (default: by shape)")
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--ops", action="store_true")
     args = ap.parse_args()
@@ -96,6 +102,8 @@ def main() -> None:
 
         pallas_kernels.grouped_experts = functools.partial(
             pallas_kernels.grouped_experts, tile_f=args.tile)
+        pallas_kernels.grouped_experts_sorted = functools.partial(
+            pallas_kernels.grouped_experts_sorted, tile_f=args.tile)
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
 
     def w(k, *shape):
@@ -117,6 +125,8 @@ def main() -> None:
             cfg, lp, h, tw, ti),
         "grouped": lambda lp, h, tw, ti: mixtral._moe_mlp_grouped(
             cfg, lp, h, tw, ti, None),
+        "grouped_sorted": lambda lp, h, tw, ti: mixtral._moe_mlp_grouped_sorted(
+            cfg, lp, h, tw, ti, None, args.tile_rows or None),
     }
     shares = [float(p) for p in args.touch.split(",") if p] or [None]
 
@@ -162,12 +172,13 @@ def main() -> None:
                     for op, op_ms, n in ops[:8]:
                         print(f"    {op_ms / 3:.3f} ms x{n // 3}  {op}",
                               flush=True)
-            if "dense" in outs and "grouped" in outs:
-                d, g = (outs[k].astype(jnp.float32)
-                        for k in ("dense", "grouped"))
-                print(f"    grouped against dense: max |diff| "
-                      f"{float(jnp.abs(d - g).max()):.4f} of max "
-                      f"{float(jnp.abs(d).max()):.3f}", flush=True)
+            for name in ("grouped", "grouped_sorted"):
+                if "dense" in outs and name in outs:
+                    d, g = (outs[k].astype(jnp.float32)
+                            for k in ("dense", name))
+                    print(f"    {name} against dense: max |diff| "
+                          f"{float(jnp.abs(d - g).max()):.4f} of max "
+                          f"{float(jnp.abs(d).max()):.3f}", flush=True)
 
 
 if __name__ == "__main__":

@@ -352,11 +352,14 @@ def _device_memory_stats(device) -> dict[str, int]:
 # PRs 33 and 39). What differs by family is the widths of that launch.
 #
 # A routed family's one chunk width (where EngineConfig.prefill_chunk is
-# wider). Its launch reads every expert it holds whatever rows it carries,
-# so a chunk that takes about a verify launch's period (35 ms at 512 rows
-# beside 34 on the v5e; PERF.md, PR 33) costs the streams least. One
-# width: a narrower last chunk would put a step in the time to the first
-# token at the prompt length it starts from
+# wider). Its launch read every expert it holds whatever rows it carried,
+# so a chunk that took about a verify launch's period (35 ms at 512 rows
+# beside 34 on the v5e; PERF.md, PR 33) cost the streams least. That
+# reading is STALE: since PRs 53 and 58 both launches read the experts
+# their rows pick (a 528-row launch 18-24 ms beside a verify launch of
+# 7-8: PERF.md, PR 58), and the width has not been read again (ROADMAP
+# S4 b). One width: a narrower last chunk would put a step in the time
+# to the first token at the prompt length it starts from
 ROUTED_CHUNK = 512
 # A dense family's width for the FIRST chunk of an uncached prompt where
 # it holds the whole prompt (else the full chunk): what the smallest

@@ -16,7 +16,7 @@ once whatever form the routed ones take) and the HF tensor names. The
 reference has no MoE (or any model) code — SURVEY.md §2.5 marks expert
 parallelism "No … north star names Mixtral 8×7B EP as a target config".
 
-Three forms of the expert layer, one function (tests hold them equal):
+Four forms of the expert layer, one function (tests hold them equal):
 
 - the all-experts einsum (`_moe_mlp_dense`): every expert computes every
   token, non-selected (token, expert) pairs zero-weighted. No dynamic
@@ -33,18 +33,34 @@ Three forms of the expert layer, one function (tests hold them equal):
   stacked leaves as they are stored, all rows multiply each (a row that
   did not pick the expert has a gate of zero: rows are neither sorted nor
   gathered), float32 sums over F and over experts. The touched set is
-  `_touched`, which is also what `_route_stats` counts.
+  `_touched`, which is also what `_route_stats` counts;
+- that kernel's sorted regime (`_moe_mlp_grouped_sorted`, PR 58): the
+  picks laid out by expert in XLA (ops/experts.py `sorted_layout`: a
+  running count a group, no sort; every group starts at a multiple of the
+  row tile, so a tile belongs to one expert), the rows gathered, one
+  Pallas product over the row tiles in use (ops/pallas_kernels.py
+  `grouped_experts_sorted`, the custom call named `grouped_experts` like
+  the other: a tile's expert from scalar prefetch indexes the stacked
+  leaves, so an expert's slabs arrive once and meet only the rows that
+  PICKED it; float32 sums over F), then a row's picks gathered back,
+  weighted in float32, summed in float32 and cast once. A pick of an
+  absent or zero-compute expert, and every pick of a row that is not
+  live, is in no group.
 
 Which runs on one chip (`expert_form`) is a rule of the SHAPE: the rows
-of the call, and experts over experts a token (what the all-experts form
-multiplies beyond what is used). Under the chip's ridge (197 TFLOP/s over
-819 GB/s = 240 rows: a verify launch's 80, a decode launch's 16) the
-products are bound by the bytes they read, all rows against one expert is
-less arithmetic than its slabs take to arrive, and the grouped form reads
-the fewest bytes: it runs there. From 240 rows (a mixed launch's 528) the
-arithmetic binds and `_use_ragged` picks between the other two. What one
+of the call. Under the chip's ridge (197 TFLOP/s over 819 GB/s = 240
+rows: a verify launch's 80, a decode launch's 16) the products are bound
+by the bytes they read, all rows against one expert is less arithmetic
+than its slabs take to arrive, and the grouped form reads the fewest
+bytes: it runs there. From 240 rows (a mixed launch's 528) that
+arithmetic binds (at 528 rows it is what the all-experts form does), and
+the sorted regime runs: the arithmetic the picks need, against slabs read
+once. The row tile follows the rows a group is expected to have (rows x
+top-k over the router's width, to the next power of two between 16 and
+256: `sorted_tile_rows`); a tile under 128 rows costs the MXU what 128
+do, so tiles much smaller than a group only multiply the passes. What one
 TPU v5e chip read (deploy/tpu_moe_forms.py, jax 0.9.0), one layer's
-expert products:
+expert products, first the two forms XLA builds:
 
 | experts (E x F), top-k, X/k | rows | all-experts | sorted | source |
 | --- | --- | --- | --- | --- |
@@ -91,19 +107,54 @@ arithmetic is then half of what the slabs' arrival hides. F-tiles of 128 to 1,02
 slabs (within 1 %; 256 columns 3 % slower at 4096 x 14336), so a step
 takes an expert whole where its three slabs fit the kernel's VMEM twice.
 
-Between the other two, up to X/k of 10.7 the all-experts form is the
-faster at every row count: at the MXU's peak in a chunk (785 GFLOP in
-4.07 ms) where XLA's `ragged-dot` runs at a tenth of it, so the sorted
-form, with X/k times less arithmetic, only wins where X/k is well past
-ten. At 32 it does where the all-experts form is bound by arithmetic
-(rows past the chip's ridge: a mixed launch's 528). Hence
-`_SORTED_MIN_WASTE` and `_SORTED_MIN_ROWS`; the three shapes accepted
-first keep the all-experts form from 240 rows up. A mixed launch's 528
-rows still multiply every expert (64/6 or 32 times the needed
-arithmetic): a grouped product for that regime sorts rows and is another
-kernel (ROADMAP S9, what remains).
+Past the ridge, the sorted regime beside the two forms XLA builds, the
+rows routed as a random router says (every expert touched), device time
+of the whole form (PR 58; the row tile in brackets; "floor" is the held
+experts' bytes at the chip's 819 GB/s):
+
+| experts (E x F), top-k | rows | all-experts | sorted (`ragged-dot`) | sorted regime (tile) | floor |
+| --- | --- | --- | --- | --- | --- |
+| 64 (2560 x 768), 6 | 528 | 2.104 ms | 3.950 | 1.181 (64) | 0.92 |
+| | 1040 | 4.068 | 6.935 | 1.436 (128) | |
+| 64 (2048 x 1408), 6 | 528 | 3.098 | 8.499 | 1.621 (64) | 1.35 |
+| | 1040 | 6.071 | 14.032 | 1.789 (128) | |
+| 256 (2048 x 512), 8 | 528 | 4.500 | 3.249 | 2.353 (32) | 1.97 |
+| | 1040 | 8.789 | 4.118 | 2.698 (64) | |
+| 64 held of 256 (2304 x 1024), 8 | 528 | 2.537 | 2.654 | 1.366 (32) | 1.11 |
+| | 1040 | 5.022 | 3.549 | 1.599 (64) | |
+| 16 held of 512 + 256 zero (6144 x 2048), 12, F-tiles of 512 | 528 | 3.370 | 3.595 | 2.240 (16) | 1.47 |
+| | 1040 | 6.611 | 6.222 | 3.118 (32) | |
+| 8 (4096 x 14336), 2, F-tiles of 512 | 528 | 7.940 | 20.941 | 4.016 (256) | 3.44 |
+| | 1040 | 15.366 | 38.175 | 6.481 (256) | |
+
+With every expert touched the kernel alone runs within a tenth of the
+floor (1.02 of SmallThinker's 1.18 ms, 2.13 of Laguna's 2.35); the rest
+is XLA's: the rows' gather into the padded layout (0.11 ms at 7,168 rows
+x 2560: its static bound, not the rows in use, is what is written), the
+picks' way back and the layout's small operations. Other row tiles read
+slower at both shapes tried (SmallThinker 32 / 64 / 128: 1.42 / 1.29 /
+1.39 ms; Laguna 16 / 32 / 64: 2.54 / 2.41 / 2.58, before the layout's
+running count became a product). Where the rows' picks fall among a
+third or a tenth of the experts (`--touch`), as a document's rows do in
+the cells, the regime reads only those: SmallThinker 0.68 and 0.46 ms
+against the all-experts form's 2.10 whatever is touched, Laguna 1.07 and
+0.61 against `ragged-dot`'s 1.54 and 0.97. The shares that hold a part
+of the experts pay the layout's static bound for picks they do not hold
+(LongCat: 6,336 picks laid out for 132 held), and at 1,040 rows
+Mixtral's groups of 260 rows take two tiles of 256, so its F-tiles
+arrive twice: both still the fastest form read.
+
+Up to X/k of 10.7 the all-experts form is the faster of XLA's two at
+every row count: at the MXU's peak in a chunk (785 GFLOP in 4.07 ms)
+where XLA's `ragged-dot` runs at a tenth of it; at 32 the sorted dispatch
+won past the ridge. PR 45 to PR 57 chose between them by
+`_SORTED_MIN_WASTE` and `_SORTED_MIN_HELD_A_PICK`; since PR 58 neither
+is anybody's choice on one chip with kernels allowed (the sorted regime
+read faster than both at all six shapes, Mixtral's 8 x top-2 included:
+the all-experts form wastes only 4x there, but its F-tiles arrive once
+for 132 rows a group in tiles of 256), and the constants are gone.
 `GRIDLLM_MOE_RAGGED=on` / `off` still forces a form (`off`: the
-all-experts form everywhere, the grouped form's A/B switch). Under a mesh
+all-experts form everywhere, the A/B switch of both grouped regimes). Under a mesh
 the inherited rule stands (the `ep` dispatch from `_RAGGED_MIN_TOKENS`
 rows up), not measured: its claims, "top_k-proportional FLOPs per shard"
 and that an all-to-all token exchange would buy nothing over replicated
@@ -117,10 +168,11 @@ the held experts only, every form computes those (`_held`: the all-experts
 form's one-hot and the grouped form's gates over the held, the sorted
 form's groups over the held, `_sorted_share`, which is an `ep` shard's
 body too), and a pick of an expert that lives elsewhere adds nothing: the exchange that would bring
-its part is not run, and nothing stands in for it. The rule of the shape
-reads the held experts over the picks expected here, which is X / k again,
-and the held experts over the picks a token makes anywhere
-(`_SORTED_MIN_HELD_A_PICK`): the sorted form sorts every pick of a row.
+its part is not run, and nothing stands in for it. The sorted regime's
+row tile reads the picks expected HERE (rows x top-k over the router's
+whole width); its layout is bounded by every pick of a row being held
+here (T x k rows and a tile's padding a held expert), whatever share of
+them is.
 
 ZERO-COMPUTE experts (`cfg.zero_experts`, longcat_flash, PR 57): the
 router is `cfg.router_width` = num_experts + zero_experts wide, and a pick
@@ -148,7 +200,11 @@ import jax.numpy as jnp
 
 from gridllm_tpu.models import llama
 from gridllm_tpu.models.configs import ModelConfig
-from gridllm_tpu.ops.experts import grouped_experts
+from gridllm_tpu.ops.experts import (
+    grouped_experts,
+    sorted_experts,
+    sorted_tile_rows,
+)
 from gridllm_tpu.ops.kvcache import PagedKVCache
 from gridllm_tpu.utils.config import env_str
 
@@ -159,21 +215,11 @@ Params = dict[str, Any]
 # (`_use_ragged`), starts at this many rows a call: under it the
 # all-experts form is one small einsum
 _RAGGED_MIN_TOKENS = 16
-# one chip, by shape (the module docstring's table): the sorted dispatch
-# needs experts over experts a token of at least this (read: slower at
-# 10.7, faster at 32), and rows past the chip's ridge (the all-experts
-# form bound by its arithmetic: 197 TFLOP/s over 819 GB/s)
-_SORTED_MIN_WASTE = 16
+# one chip: the rows from which the grouped kernel takes its sorted regime,
+# the chip's ridge (197 TFLOP/s over 819 GB/s). Under it all rows against a
+# touched expert is less arithmetic than its slabs take to arrive; past it
+# that arithmetic binds and each expert meets its own rows only
 _SORTED_MIN_ROWS = 240
-# and, of a share, this many held experts a pick of a token: the sorted
-# form sorts, gathers and scatters EVERY pick of a row, held here or not
-# (k rows a token), and inside a layer scan its custom calls are handed a
-# copy of the held experts' slabs, where the all-experts form multiplies
-# the held experts in place. Read in the cells: sorted at 8 held a pick
-# (kimi-linear:48b-ep4, 64 held, top-8; PR 51), all-experts at 1.3
-# (longcat-flash:560b-ep32, 16 held, top-12: a mixed launch's four expert
-# layers 21 ms sorted, 12.3 of them the slabs' copies, against 13.5; PR 57)
-_SORTED_MIN_HELD_A_PICK = 4
 
 
 def _route(cfg: ModelConfig, lp: Params, r: jnp.ndarray):
@@ -285,6 +331,16 @@ def _moe_mlp_dense(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
         return jnp.einsum("...xf,xfe->...e", y, lp["we_down"], precision=p)
 
 
+def _expert_leaves(lp: Params):
+    """(the tree the grouped kernels read the experts from, the layer's
+    index in it): the whole stacked leaves and this layer's index where a
+    layer scan hands them over (`lp["layer_stack"]`), because a custom call
+    given the scan's per-layer slice would be given a copy of it; else the
+    layer's own leaves and None."""
+    layers, li = lp.get("layer_stack", (None, None))
+    return (lp if layers is None else layers), li
+
+
 def _moe_mlp_grouped(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
                      top_w, top_i, live) -> jnp.ndarray:
     """Grouped form: the all-experts form's mathematics over the experts
@@ -301,15 +357,38 @@ def _moe_mlp_grouped(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
         idx = _held(cfg, idx)[0]        # absent, zero-compute: a row of zeros
     gates = jnp.where(idx[..., None] == jnp.arange(held), w[..., None],
                       0.0).sum(axis=1)
-    # the whole stacked leaves and this layer's index where a layer scan
-    # hands them over (`lp["layer_stack"]`): a custom call given the scan's
-    # per-layer slice would be given a copy of it
-    layers, li = lp.get("layer_stack", (None, None))
-    we = lp if layers is None else layers
+    we, li = _expert_leaves(lp)
     with jax.named_scope("moe_experts"):
         out = grouped_experts(
             x.reshape(-1, x.shape[-1]), gates, _touched(cfg, top_i, live),
             we["we_gate"], we["we_up"], we["we_down"], li,
+            act=cfg.expert_act, use_pallas=cfg.use_pallas)
+    return out.reshape(x.shape)
+
+
+def _moe_mlp_grouped_sorted(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
+                            top_w, top_i, live,
+                            tile_rows: int | None = None) -> jnp.ndarray:
+    """Grouped form, sorted regime (rows past the chip's ridge): each held
+    expert's slabs read once and multiplied by the rows that PICKED it and
+    no others (ops/experts.py `sorted_experts`: the picks laid out by
+    expert in XLA, one Pallas product over row tiles, the picks' outputs
+    weighted and summed a row in float32). A pick of an absent or
+    zero-compute expert, and every pick of a row that is not live, is in
+    no group: it multiplies nothing and adds nothing. The row tile follows
+    the rows a group is expected to have (`sorted_tile_rows`)."""
+    k, held = cfg.experts_per_token, cfg.held_experts[1]
+    idx = top_i.reshape(-1, k)
+    if cfg.routes_elsewhere:
+        idx = _held(cfg, idx)[0]
+    if live is not None:
+        idx = jnp.where(live.reshape(-1, 1), idx, held)
+    we, li = _expert_leaves(lp)
+    tm = tile_rows or sorted_tile_rows(idx.shape[0] * k / cfg.router_width)
+    with jax.named_scope("moe_experts"):
+        out = sorted_experts(
+            x.reshape(-1, x.shape[-1]), top_w.reshape(-1, k), idx,
+            we["we_gate"], we["we_up"], we["we_down"], li, tm=tm,
             act=cfg.expert_act, use_pallas=cfg.use_pallas)
     return out.reshape(x.shape)
 
@@ -322,7 +401,8 @@ def _moe_mlp_ragged(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     (no capacity factor, no token dropping), static shapes throughout
     (argsort/bincount are fixed-size; raggedness lives in group_sizes
     values, not array shapes). On one v5e chip the faster form only for
-    many small experts (module docstring; `_use_ragged`)."""
+    no shape read (module docstring): the grouped kernel's sorted regime
+    does the same with an expert's slabs read once."""
     k, X = cfg.experts_per_token, cfg.num_experts
     lead = x.shape[:-1]
     e = x.shape[-1]
@@ -449,47 +529,41 @@ def _zero_mlp(cfg: ModelConfig, x: jnp.ndarray, top_w, top_i) -> jnp.ndarray:
         return (x.astype(jnp.float32) * w[..., None]).astype(x.dtype)
 
 
-def _use_ragged(cfg: ModelConfig, n_tokens: int, meshed: bool,
+def _use_ragged(n_tokens: int, meshed: bool,
                 backend: str | None = None) -> bool:
-    """Whether a call of `n_tokens` rows takes the sorted dispatch:
-    GRIDLLM_MOE_RAGGED on / off says so; `auto` takes it on a TPU only
-    (the CPU's ragged_dot is a serial loop over the groups): under a mesh
-    the inherited rule (not measured), on one chip the rule of the shape
-    the chip's readings give (`_SORTED_MIN_WASTE`, `_SORTED_MIN_ROWS`)."""
+    """Whether a call of `n_tokens` rows takes the sorted `ragged_dot`
+    dispatch: GRIDLLM_MOE_RAGGED on / off says so; `auto` takes it under a
+    mesh on a TPU only (the inherited rule, not measured; the CPU's
+    ragged_dot is a serial loop over the groups). One chip never does by
+    itself: at every shape read the grouped kernel's sorted regime is the
+    faster (the module docstring's table)."""
     raw = env_str("GRIDLLM_MOE_RAGGED").lower()
     if raw != "auto":
         return raw in ("1", "on", "true") and n_tokens >= _RAGGED_MIN_TOKENS
-    if (backend or jax.default_backend()) != "tpu":
-        return False
-    if meshed:
-        return n_tokens >= _RAGGED_MIN_TOKENS
-    # experts the all-experts form multiplies over the picks a token
-    # makes among them: of a share, the held experts over the picks
-    # expected here (k held / X), which is X / k again
-    held = cfg.held_experts[1]
-    picks = cfg.experts_per_token * held / cfg.router_width
-    return (held >= _SORTED_MIN_WASTE * picks
-            and held >= _SORTED_MIN_HELD_A_PICK * cfg.experts_per_token
-            and n_tokens >= _SORTED_MIN_ROWS)
+    return (meshed and (backend or jax.default_backend()) == "tpu"
+            and n_tokens >= _RAGGED_MIN_TOKENS)
 
 
 def expert_form(cfg: ModelConfig, n_tokens: int, mesh=None,
                 backend: str | None = None) -> str:
     """The form `_routed_mlp` gives a call of `n_tokens` rows: "grouped",
-    "sorted" or "all_experts" (the dispatch spans' meta and
-    gridllm_moe_form_rows). Grouped on one TPU chip with kernels allowed
-    at rows under the chip's ridge, where the products are bound by the
-    bytes they read, unless GRIDLLM_MOE_RAGGED forces a form."""
+    "grouped_sorted", "sorted" or "all_experts" (the dispatch spans' meta
+    and gridllm_moe_form_rows). On one TPU chip with kernels allowed the
+    grouped kernel: at rows under the chip's ridge, where the products are
+    bound by the bytes they read, every row against each touched expert;
+    from there its sorted regime, each expert against the rows that picked
+    it; unless GRIDLLM_MOE_RAGGED forces a form."""
     backend = backend or jax.default_backend()
-    ragged = _use_ragged(cfg, n_tokens, mesh is not None, backend)
     if mesh is not None:
         ok = (cfg.num_experts % mesh.shape.get("ep", 1) == 0
               and cfg.expert_width % mesh.shape.get("tp", 1) == 0)
     else:
         ok = cfg.use_pallas is not False
-        if (ok and backend == "tpu" and n_tokens < _SORTED_MIN_ROWS
+        if (ok and backend == "tpu"
                 and env_str("GRIDLLM_MOE_RAGGED").lower() == "auto"):
-            return "grouped"
+            return ("grouped" if n_tokens < _SORTED_MIN_ROWS
+                    else "grouped_sorted")
+    ragged = _use_ragged(n_tokens, mesh is not None, backend)
     return "sorted" if ragged and ok else "all_experts"
 
 
@@ -517,10 +591,9 @@ def _moe_mlp(
     - meshed otherwise (decode-sized batches, indivisible X/F) → dense
       all-experts einsum (EP-shardable via GSPMD, no dynamic shapes);
     - single device → by the shape (`expert_form`): on a TPU the grouped
-      kernel under the chip's ridge of 240 rows; from there the
-      all-experts form but for many small experts (X >= 16 top_k), which
-      the chip read the sorted dispatch faster at; unless
-      GRIDLLM_MOE_RAGGED says.
+      kernel, under the chip's ridge of 240 rows all rows against each
+      touched expert, from there its sorted regime; elsewhere the
+      all-experts form; unless GRIDLLM_MOE_RAGGED says.
     """
     y, stats = _routed_mlp(cfg, mesh, live, lp, x, r)
     if cfg.num_shared_experts:
@@ -537,6 +610,8 @@ def _routed_mlp(cfg: ModelConfig, mesh, live, lp: Params, x: jnp.ndarray,
     form = expert_form(cfg, n_tokens, mesh)
     if form == "grouped":
         y = _moe_mlp_grouped(cfg, lp, x, top_w, top_i, live)
+    elif form == "grouped_sorted":
+        y = _moe_mlp_grouped_sorted(cfg, lp, x, top_w, top_i, live)
     elif form == "all_experts":
         y = _moe_mlp_dense(cfg, lp, x, top_w, top_i)
     elif mesh is not None:

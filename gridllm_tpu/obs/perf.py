@@ -166,8 +166,10 @@ MOE_FORM_ROWS_TOTAL = _OBS.counter(
     "Routed families: token rows a launch put through each expert layer "
     "(its padded rows: every slot's rows of a verify or decode launch, a "
     "mixed launch's chunk width and slots), by the form the expert layer "
-    "took there (models/mixtral.py expert_form: all_experts, sorted, or "
-    "grouped, the touched experts alone by one kernel under 240 rows) and "
+    "took there (models/mixtral.py expert_form: all_experts, sorted, "
+    "grouped, the touched experts alone by one kernel under 240 rows, or "
+    "grouped_sorted, that kernel's regime from 240 rows: each expert "
+    "against the rows that picked it) and "
     "the launch's kind (verify, decode, chunk).",
     ("model", "form", "launch"),
 )

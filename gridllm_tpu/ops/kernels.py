@@ -124,6 +124,19 @@ KERNELS: tuple[KernelSpec, ...] = (
                     "down slabs by double-buffered DMA from the stacked "
                     "leaves, all rows against each, float32 sums",
     ),
+    KernelSpec(
+        name="grouped_experts_sorted",
+        reference="experts:sorted_experts_ref",
+        dispatch="grouped_experts_sorted",
+        rtol=3e-2, atol=3e-2,
+        test="tests/test_grouped_experts.py::"
+             "test_the_sorted_regime_matches_its_reference_and_the_all_experts_form",
+        description="the same products at rows past the chip's ridge: the "
+                    "rows sorted by expert into whole row tiles, a tile's "
+                    "expert from scalar prefetch, each expert's slabs "
+                    "fetched once against its own rows; the custom call "
+                    "is named grouped_experts",
+    ),
 )
 
 # Dispatch labels with NO kernel of their own: dispatchers whose kernel

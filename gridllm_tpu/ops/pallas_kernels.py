@@ -1550,3 +1550,114 @@ def grouped_experts(x, gates, touched, wg, wu, wd, layer=None, *, act: str,
     )(jnp.asarray(layer, jnp.int32).reshape(1), touched.astype(jnp.int32),
       x, gates.astype(jnp.float32), wg, wu, wd)
     return out[:t]
+
+
+# -- the sorted regime: rows past the chip's ridge (a mixed launch's 528) ------
+#
+# There the arithmetic of all rows against every touched expert binds, not the
+# bytes, so the rows are SORTED by expert first (in XLA: ops/experts.py
+# `sorted_layout`) and each expert's slabs meet only the row tiles of its own
+# group. A grid over row tiles; a tile's expert comes from scalar prefetch
+# and indexes the weights' blocks, so consecutive tiles of one expert fetch
+# its slabs once and an expert with no group is never named. The grid's
+# extent is the tiles that hold a group (a traced value: the layout's static
+# bound would cost a step a tile it never fills). The pipeline fetches the
+# next tile's blocks while this one multiplies: with a tile a group the
+# slabs' arrival is continuous.
+
+
+def _sorted_experts_kernel(layer_ref, expert_ref, x_ref, wg_ref, wu_ref,
+                           wd_ref, o_ref, *acc, nf: int, act: str, precision):
+    x = x_ref[...]
+    g = jnp.dot(x, wg_ref[...], precision=precision,
+                preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu_ref[...], precision=precision,
+                preferred_element_type=jnp.float32)
+    g = g * jax.nn.sigmoid(g) if act == "silu" else jnp.maximum(g, 0.0)
+    d = jnp.dot((g * u).astype(wd_ref.dtype), wd_ref[...],
+                precision=precision, preferred_element_type=jnp.float32)
+    if nf == 1:
+        o_ref[...] = d.astype(o_ref.dtype)
+        return
+    acc_ref, = acc
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = d
+
+    @pl.when(j > 0)
+    def _():
+        acc_ref[...] += d
+
+    @pl.when(j == nf - 1)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "act", "interpret",
+                                             "tile_f"))
+def grouped_experts_sorted(xs, tile_expert, used, wg, wu, wd, layer=None, *,
+                           tm: int, act: str, interpret: bool = False,
+                           tile_f: int | None = None):
+    """act(xs wg[j]) * (xs wu[j]) wd[j] a row tile of `tm` rows, j the
+    tile's expert: xs [P, E] the rows GATHERED in the order of their
+    experts, each group laid out from a multiple of `tm` (P a multiple of
+    `tm`; rows between a group's end and the next tile are padding, whose
+    products nobody reads); tile_expert [P / tm] int32 the expert of each
+    tile, `used` int32 the tiles that belong to a group: the grid's
+    extent, so the tiles from there on (P is a static bound) cost no step
+    and are not written; wg, wu, wd and `layer` as `grouped_experts` takes
+    them. Returns [P, E] in xs's dtype, unweighted: float32 sums over F
+    (F-tiles accumulate in VMEM), one cast. An expert's slabs arrive once
+    where its group is one tile or F is whole; with F-tiles a second row
+    tile of the group reads them again. The custom call carries
+    `grouped_experts`' name: it is the same product to a reader of the
+    trace (benchmark/readers.py GROUPED_OPS)."""
+    if wg.ndim == 3:
+        wg, wu, wd = wg[None], wu[None], wd[None]
+    if layer is None:
+        layer = jnp.int32(0)
+    p, e = xs.shape
+    f = wg.shape[3]
+    tf = tile_f or _expert_tile(e, f, wg.dtype.itemsize)
+    assert f % tf == 0 and (tf == f or tf % 128 == 0), (f, tf)
+    assert p % tm == 0 and tm % 8 == 0, (p, tm)
+    nf = f // tf
+    kernel = functools.partial(
+        _sorted_experts_kernel, nf=nf, act=act,
+        precision=(jax.lax.Precision.HIGHEST if xs.dtype == jnp.float32
+                   else None))
+
+    def rows(i, j, layer, expert):
+        return i, 0
+
+    def up(i, j, layer, expert):
+        return layer[0], expert[i], 0, j
+
+    def down(i, j, layer, expert):
+        return layer[0], expert[i], j, 0
+
+    slabs = 2 * 3 * e * tf * wg.dtype.itemsize
+    work = 4 * tm * (3 * e + 4 * tf) + 4 * tm * e * xs.dtype.itemsize
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(jnp.asarray(used, jnp.int32).reshape(()), nf),
+            in_specs=[pl.BlockSpec((tm, e), rows),
+                      pl.BlockSpec((None, None, e, tf), up),
+                      pl.BlockSpec((None, None, e, tf), up),
+                      pl.BlockSpec((None, None, tf, e), down)],
+            out_specs=pl.BlockSpec((tm, e), rows),
+            scratch_shapes=([pltpu.VMEM((tm, e), jnp.float32)]
+                            if nf > 1 else [])),
+        out_shape=jax.ShapeDtypeStruct((p, e), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(min(max(slabs + 2 * work + (4 << 20),
+                                         16 << 20), 100 << 20))),
+        interpret=interpret,
+        name="grouped_experts",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tile_expert.astype(jnp.int32),
+      xs, wg, wu, wd)

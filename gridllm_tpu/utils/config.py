@@ -229,9 +229,9 @@ register_env("GRIDLLM_PALLAS", "auto",
              "0 (force off), interpret (CPU interpreter mode).")
 register_env("GRIDLLM_MOE_RAGGED", "auto",
              "MoE grouped-matmul via ragged_dot: auto (a TPU under a mesh; "
-             "one chip by the rule of the shape, models/mixtral.py "
-             "expert_form), 1 (force on), 0 (the all-experts form "
-             "everywhere).")
+             "one chip takes the grouped kernel instead, by the rows of "
+             "the call, models/mixtral.py expert_form), 1 (force on), 0 "
+             "(the all-experts form everywhere).")
 
 # tiered KV cache (ISSUE 11): host-RAM spill + int8 KV pages
 register_env("GRIDLLM_KV_HOST_BYTES", "0",

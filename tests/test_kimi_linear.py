@@ -389,20 +389,19 @@ def test_holding_every_expert_is_the_layer_of_before(preset,
         got = form(all_held, lp, x, top_w, top_i)
         assert float(jnp.abs(got - want).max()) < 1e-5
     for rows in (16, 80, 528):
-        assert mixtral._use_ragged(cfg, rows, False, "tpu") == (
-            mixtral._use_ragged(all_held, rows, False, "tpu"))
         assert mixtral.expert_form(cfg, rows, backend="tpu") == (
             mixtral.expert_form(all_held, rows, backend="tpu"))
 
 
 def test_the_rule_of_the_shape_reads_the_share():
     ep4 = get_config("kimi-linear:48b-ep4")
-    assert mixtral._use_ragged(ep4, 528, False, "tpu")
-    assert not mixtral._use_ragged(ep4, 80, False, "tpu")
+    assert not mixtral._use_ragged(528, False, "tpu")
+    assert not mixtral._use_ragged(80, False, "tpu")
     assert mixtral.expert_form(CFG, 528) == "all_experts"    # 16 / 4 experts
-    # a verify launch's rows on one chip: the held experts touched, alone
+    # a verify launch's rows on one chip: the held experts touched, alone;
+    # a mixed launch's: each held expert against the rows that picked it
     assert mixtral.expert_form(ep4, 80, backend="tpu") == "grouped"
-    assert mixtral.expert_form(ep4, 528, backend="tpu") == "sorted"
+    assert mixtral.expert_form(ep4, 528, backend="tpu") == "grouped_sorted"
     assert mixtral.expert_form(ep4, 80) == "all_experts"
 
 

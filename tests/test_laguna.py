@@ -362,29 +362,29 @@ def test_route_scores_normalises_and_scales(score, norm, scale):
 
 
 @pytest.mark.parametrize("name,rows,form", [
-    # the accepted shapes keep the all-experts form at every row count
-    ("smallthinker:21b", 80, "all_experts"), ("smallthinker:21b", 1040, "all_experts"),
-    ("deepseek-v2-lite:16b", 80, "all_experts"), ("deepseek-v2-lite:16b", 528, "all_experts"),
-    ("mixtral:8x7b", 1040, "all_experts"),
-    # 256 top-8: sorted past the chip's ridge, where a cell read it faster
-    ("laguna-xs2:33b", 16, "all_experts"), ("laguna-xs2:33b", 80, "all_experts"),
-    ("laguna-xs2:33b", 528, "sorted"),
-    # PR 53: under the chip's ridge the grouped kernel, whatever the shape
-    ("laguna-xs2:33b", 239, "all_experts"), ("laguna-xs2:33b", 240, "sorted"),
-    ("mixtral:8x7b", 80, "all_experts"), ("kimi-linear:48b-ep4", 80, "all_experts"),
+    # one chip with kernels: the grouped kernel at every shape, its sorted
+    # regime from the chip's ridge of 240 rows (PR 58: read faster than
+    # either other form at every accepted shape)
+    ("smallthinker:21b", 80, "grouped"), ("smallthinker:21b", 1040, "grouped_sorted"),
+    ("deepseek-v2-lite:16b", 80, "grouped"), ("deepseek-v2-lite:16b", 528, "grouped_sorted"),
+    ("mixtral:8x7b", 1040, "grouped_sorted"),
+    ("laguna-xs2:33b", 16, "grouped"), ("laguna-xs2:33b", 80, "grouped"),
+    ("laguna-xs2:33b", 528, "grouped_sorted"),
+    ("laguna-xs2:33b", 239, "grouped"), ("laguna-xs2:33b", 240, "grouped_sorted"),
+    ("mixtral:8x7b", 80, "grouped"), ("kimi-linear:48b-ep4", 80, "grouped"),
 ])
 def test_the_expert_form_is_a_rule_of_the_shape(name, rows, form, monkeypatch):
     cfg = get_config(name)
-    assert mixtral._use_ragged(cfg, rows, False, backend="tpu") == (
-        form == "sorted")
-    assert mixtral.expert_form(cfg, rows, backend="tpu") == (
-        "grouped" if rows < 240 else form)
+    # XLA's ragged_dot dispatch is nobody's choice on one chip by itself
+    assert not mixtral._use_ragged(rows, False, backend="tpu")
+    assert mixtral.expert_form(cfg, rows, backend="tpu") == form
     assert mixtral.expert_form(dataclasses.replace(cfg, use_pallas=False),
                                rows, backend="tpu") == "all_experts"
     # off the chip the all-experts form, whatever the shape
     assert mixtral.expert_form(cfg, rows) == "all_experts"
     monkeypatch.setenv("GRIDLLM_MOE_RAGGED", "on")
     assert mixtral.expert_form(cfg, rows) == "sorted"
+    assert mixtral.expert_form(cfg, rows, backend="tpu") == "sorted"
 
 
 def test_both_forms_of_the_expert_layer_agree(params, interpreted_kernels):

@@ -285,18 +285,19 @@ def test_four_shares_and_the_zero_part_add_up_to_the_whole_layer(
 
 def test_the_rule_of_the_shape_reads_the_share_and_what_a_pick_costs():
     ep32 = get_config("longcat-flash:560b-ep32")
-    # 16 held over 12 x 16 / 768 = 0.25 picks a row expected here: waste
-    # enough for the sorted form, but it sorts every pick of a row (12 a
-    # token against 16 held experts): the all-experts form past the ridge
-    assert not mixtral._use_ragged(ep32, 528, False, "tpu")
+    # 16 held over 12 x 16 / 768 = 0.25 picks a row expected here: XLA's
+    # sorted dispatch was the slower (it sorts every pick of a row, 12 a
+    # token against 16 held experts); the grouped kernel's sorted regime
+    # lays the held picks out alone, in tiles of 16 rows (8 a group)
+    assert not mixtral._use_ragged(528, False, "tpu")
     assert mixtral.expert_form(ep32, 80, backend="tpu") == "grouped"
-    assert mixtral.expert_form(ep32, 528, backend="tpu") == "all_experts"
+    assert mixtral.expert_form(ep32, 528, backend="tpu") == "grouped_sorted"
     assert mixtral.expert_form(ep32, 80) == "all_experts"
-    # every choice an accepted cell's family makes stands as it was
+    # every accepted cell's family makes the same choice past the ridge
     assert [mixtral.expert_form(get_config(name), 528, backend="tpu")
             for name in ("kimi-linear:48b-ep4", "laguna-xs2:33b",
                          "smallthinker:21b", "deepseek-v2-lite:16b")] == [
-        "sorted", "sorted", "all_experts", "all_experts"]
+        "grouped_sorted"] * 4
 
 
 # -- through the cache -------------------------------------------------------

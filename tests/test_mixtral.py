@@ -179,10 +179,45 @@ def test_ragged_dispatch_matches_dense(interpreted_kernels):
         np.testing.assert_allclose(
             np.asarray(grouped), np.asarray(dense), rtol=2e-5, atol=2e-5,
         )
-    # on one chip a verify launch's rows take it, a chunk's do not
+    # on one chip a verify launch's rows take it, a chunk's its sorted regime
     assert mixtral.expert_form(cfg, 80, backend="tpu") == "grouped"
-    assert mixtral.expert_form(cfg, 528, backend="tpu") == "all_experts"
+    assert mixtral.expert_form(cfg, 528, backend="tpu") == "grouped_sorted"
     assert mixtral.expert_form(cfg, 80) == "all_experts"
+
+
+@pytest.mark.parametrize("lead,dtype", [
+    ((1, 33), jnp.float32), ((4, 5), jnp.float32), ((40,), jnp.float32),
+    ((1, 33), jnp.bfloat16)])
+def test_the_sorted_regime_through_moe_mlp_matches_dense(
+        lead, dtype, monkeypatch, interpreted_kernels):
+    """PR 58: `_moe_mlp` whole, in the layouts the step programs give it
+    (a chunk's [1, T, E], a verify launch's [S, T, E], flat rows), with
+    `expert_form` picking the grouped kernel's sorted regime: the
+    all-experts form's output on the live rows, zeros on the others, the
+    same statistics; in float32 to float32's bound (the weighting and the
+    sums over a row's picks are float32)."""
+    from functools import partial
+
+    cfg = get_config("tiny-mixtral")
+    params = mixtral.init_params(cfg, jax.random.PRNGKey(3), dtype=dtype)
+    lp = jax.tree.map(lambda a: a[0], params["layers"])
+    x = jax.random.normal(jax.random.PRNGKey(7), (*lead, cfg.hidden_size)
+                          ).astype(dtype)
+    live = (jnp.arange(x.size // cfg.hidden_size) % 4 != 2).reshape(lead)
+    dense, want_stats = mixtral._moe_mlp(cfg, None, live, lp, x)
+    monkeypatch.setattr(mixtral, "_SORTED_MIN_ROWS", 0)
+    monkeypatch.setattr(mixtral, "expert_form",
+                        partial(mixtral.expert_form, backend="tpu"))
+    assert mixtral.expert_form(cfg, live.size) == "grouped_sorted"
+    got, stats = mixtral._moe_mlp(cfg, None, live, lp, x)
+    assert got.shape == x.shape and got.dtype == x.dtype
+    assert np.array_equal(np.asarray(stats), np.asarray(want_stats))
+    tol = 2e-5 if dtype == jnp.float32 else 6e-2
+    on = np.asarray(live)
+    np.testing.assert_allclose(np.asarray(got, np.float32)[on],
+                               np.asarray(dense, np.float32)[on],
+                               rtol=tol, atol=tol)
+    assert not np.asarray(got, np.float32)[~on].any()
 
 
 def test_ragged_dispatch_through_full_model(monkeypatch):
