@@ -704,13 +704,7 @@ class InferenceEngine:
         self._layer_windows = np.asarray(
             [w or np.inf for w in self.cfg.layer_windows])
         self._windowed = int(np.isfinite(self._layer_windows).sum())
-        # span meta of every launch, from the family's cache kinds: what a
-        # slot holds of its past and the form each kind is read in (latent
-        # rows: absorbed in every region; a state: the delta rule)
         kinds = self.cfg.cache_kinds
-        self._attn_meta = {
-            "cache_row": "+".join(kinds),
-            "attn_form": "+".join(_ATTN_FORMS[k] for k in kinds)}
         # a second kind of cache beside the pages: a recurrent state a slot
         self._hybrid = "state" in kinds
         # or a ring of the window layers' last rows a slot; either way the
@@ -724,7 +718,7 @@ class InferenceEngine:
         )
         self.mesh = build_mesh(config.mesh) if config.mesh else None
         # the mesh as GRIDLLM_MESH_SHAPE writes it ("tp:4"; "" unmeshed):
-        # for log records and the dispatch spans' meta
+        # for log records and batch_state's shape
         self.mesh_axes = "" if self.mesh is None else ",".join(
             f"{a}:{n}" for a, n in self.mesh.shape.items() if n > 1)
         # family-specific mesh constraints fail HERE (engine startup), not
@@ -2075,7 +2069,7 @@ class InferenceEngine:
             req = self._pending.popleft()
         # marked only once a request was popped: the phase's count is
         # the number of admissions tried
-        self._clock.mark("admit", request=req.id)
+        self._clock.mark("admit", stage="tokenize", request=req.id)
         t_pop = time.perf_counter_ns()
         wait_ns = t_pop - req.t_submit_ns if req.t_submit_ns else 0
         ids = self._tokenize(req)
@@ -2127,6 +2121,7 @@ class InferenceEngine:
                     self._fail(req, "context window too small for image "
                                     "inputs", retryable=False)
                     return True
+        self._clock.stage(None)
         num_predict = int(opts.get("num_predict", -1))
         want = (
             # resumed tokens are already in `ids`; capacity reserves only
@@ -2144,6 +2139,7 @@ class InferenceEngine:
         # then allocate the remainder. Images are excluded — token ids
         # alone don't address spliced pixel embeddings — and sp meshes
         # have no chunked path to resume from (cap forced to 0 there).
+        self._clock.stage("match")
         with self._alloc_lock:
             cached = 0
             if self._prefix_cache_cap != 0 and not images:
@@ -2158,6 +2154,7 @@ class InferenceEngine:
                 return False
             state_plan = (self._plan_state(slot, ids, cached)
                           if self._second else None)
+        self._clock.stage(None)
         self._free_slots.pop()
 
         stop = opts.get("stop") or []
@@ -2206,7 +2203,8 @@ class InferenceEngine:
         # ordinary admissions, where prompt_len == len(ids) >= cached)
         st.cached_tokens = min(cached, st.prompt_len)
         row_list = self.alloc.table_row(slot)
-        st.pages_held = len(row_list)
+        # the pages the slot holds, not the table row's padded width
+        st.pages_held = self.alloc.pages_owned(slot)
         t0 = time.perf_counter_ns()
         # the span that caused the program launch; what follows the
         # dispatch in this function (counters, gauges) stays in this phase
@@ -2215,7 +2213,6 @@ class InferenceEngine:
             "replayed_tokens": state_plan["replayed"]}
         self._clock.mark("dispatch_prefill", request=req.id,
                          prompt_tokens=len(ids), cached_tokens=cached,
-                         mesh=self.mesh_axes, **self._attn_meta,
                          **state_meta)
         with self.dispatch_lock:
             # emit AFTER the dispatch succeeds: a record for a program the
@@ -2226,6 +2223,7 @@ class InferenceEngine:
             # group down — there is no cheap reconciliation for that.)
             self._dispatch_prefill(slot, ids, row_list, upd, images=images,
                                    cached=cached, state_plan=state_plan)
+            self._clock.stage("book")
             if self.plan_sink is not None:
                 # SNAPSHOT the ids: the list is also _Slot.ids, which
                 # _ingest APPENDS generated tokens to — a by-reference
@@ -2400,6 +2398,11 @@ class InferenceEngine:
         # jitted call's argument path transfers it, where an eager jnp
         # scalar or array constructor would be a program of its own
         slot_ = np.int32(slot)
+        restore = bool(state_plan) and state_plan["restore"] >= 0
+        # the stage's jitted calls: the sampler row, the restore, and a
+        # window seed for every chunk of the cached prefix
+        self._clock.stage("seed", launches=1 + restore
+                          + -(-cached // self._chunk_len))
         self.sampling = self._sampler_row_fn(
             self.sampling, slot_, *SamplingParams.pack_row(upd))
         img_flat = self._image_embeds(images) if images else None
@@ -2408,7 +2411,7 @@ class InferenceEngine:
         # no host-side clear here (it would be a dead full-row rewrite)
         row = _host_i32(row_list, len(row_list))
         saves = state_plan["saves"] if state_plan else []
-        if state_plan and state_plan["restore"] >= 0:
+        if restore:
             self.cache = self._state_restore_fn(
                 self.cache, slot_, np.int32(state_plan["restore"]),
                 np.int32(cached))
@@ -2437,6 +2440,9 @@ class InferenceEngine:
                 # program would compile inside a user's request
                 width = (self._chunk_width(len(ids) - s0, s0)
                          if img_flat is None else c)
+                # one stretch a launch: its host arrays, its arguments
+                # placed, the jitted call returning
+                self._clock.stage("chunk", width=width, start=s0)
                 padded = _host_i32(part, width)
                 embeds = None
                 if img_flat is not None:
@@ -2470,8 +2476,10 @@ class InferenceEngine:
                         slot_, row, np.bool_(final), embeds=embeds,
                     )
                 )
+                self._clock.fed()
         else:
             width = self._bucket_for(len(ids))
+            self._clock.stage("chunk", width=width, start=0)
             padded = _host_i32(ids, width)
             embeds = None
             if img_flat is not None:
@@ -2485,6 +2493,7 @@ class InferenceEngine:
                 self.sampling, np.int32(len(ids)), slot_, row,
                 embeds=embeds,
             )
+            self._clock.fed()
         # the last launch's width and the tokens that went through the
         # model, on the gridllm.dispatch_prefill span (free off a capture)
         self._clock.annotate(width=width, tokens=len(ids) - cached)
@@ -2598,7 +2607,9 @@ class InferenceEngine:
         if safe > st.emitted_len and st.req.on_chunk:
             delta = st.text[st.emitted_len : safe]
             st.emitted_len = safe
+            self._clock.stage("emit")
             st.req.on_chunk(delta, False, None)
+            self._clock.stage(None)
 
     def _finish(self, slot: int, st: _Slot, reason: str, error: str = "") -> None:
         now = time.perf_counter_ns()
@@ -2608,7 +2619,8 @@ class InferenceEngine:
         # attribution; the admission-time count is the floor
         with self._alloc_lock:
             try:
-                st.pages_held = max(st.pages_held, len(self.alloc.table_row(slot)))
+                st.pages_held = max(st.pages_held,
+                                    self.alloc.pages_owned(slot))
             except Exception:
                 pass
         res = GenerationResult(
@@ -2670,7 +2682,10 @@ class InferenceEngine:
             self._perf_armed = True
             self.perf.arm()
         if st.req.on_chunk:
+            # (a cancel finishes a stream from ctl: not a stage of that)
+            self._clock.stage("emit", of="ingest")
             st.req.on_chunk(last_delta, True, res)
+            self._clock.stage(None)
 
     def _dispatch_block(self, k: int) -> None:
         """Dispatch one fused k-step decode block (no host sync)."""
@@ -2688,6 +2703,7 @@ class InferenceEngine:
                 self.counts, self.window, self.wlen, self.sampling, k=k,
             )
             self._inflight.append((self._gen, out, k, None))
+            self._clock.fed()
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({"op": "block", "k": k})
 
@@ -2722,6 +2738,7 @@ class InferenceEngine:
             np.bool_(is_final), embeds=embeds, state_io=state_io,
         )
         self._inflight.append((self._gen, (out, None), 1, None))
+        self._clock.fed()
 
     def _fetch_oldest(self) -> int:
         """Fetch + ingest the oldest in-flight block, a decode / mixed
@@ -2734,8 +2751,18 @@ class InferenceEngine:
         count). Returns the block's generation."""
         gen, out, blk, dlen = self._inflight.popleft()
         t0 = time.perf_counter()
-        self._clock.mark("fetch")
-        # the ONE declared block-fetch sync point (host-sync-discipline)
+        self._clock.mark("fetch", stage="wait")
+        # the ONE declared block-fetch sync point (host-sync-discipline),
+        # a stage each: until the launch has finished, then its tokens' way
+        # to the host. The copy device_get begins with is queued ahead of
+        # the wait, as it was before the two were split: it starts when
+        # the launch ends, with no wake-up of this thread in between
+        for leaf in jax.tree.leaves(out if dlen is None else out[0]):
+            leaf.copy_to_host_async()
+        jax.block_until_ready(out)  # sync-ok
+        ready = self._clock.stage("copy")
+        if not self._inflight:
+            self._clock.starve(ready)
         if dlen is None:
             # (tokens, a routed family's decode-block statistics or None)
             raw, stats = jax.device_get(out)  # sync-ok
@@ -2775,6 +2802,7 @@ class InferenceEngine:
                 k1=int(drafts.shape[1]) + 1,  # from the record: follower
             )                                 # replay may differ from env K
             self._inflight.append((self._gen, (block, n_emit), 1, dlen))
+            self._clock.fed()
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({"op": "verify", "drafts": drafts.tolist(),
                                 "dlen": dlen.tolist()})
@@ -2806,6 +2834,7 @@ class InferenceEngine:
                 drafts, valid,
             )
             self._inflight.append((self._gen, (block, n_emit), 1, dlen))
+            self._clock.fed()
             if self.plan_sink is not None:  # after-success; see _try_admit
                 self.plan_sink({
                     "op": "verify_tree", "drafts": drafts.tolist(),
@@ -3110,9 +3139,7 @@ class InferenceEngine:
         kind = "verify" if self._spec_k else "decode"
         self._clock.mark("dispatch_verify", gen=self._gen + 1,
                          slots=len(self._slots), ctx_tokens=ctx,
-                         mesh=self.mesh_axes, experts=self.cfg.num_experts,
-                         window_layers=self._windowed,
-                         **self._attn_meta, **self._expert_meta(
+                         **self._expert_meta(
                              kind, self.config.max_slots * (self._spec_k + 1)))
 
     def _expert_meta(self, launch: str, rows: int) -> dict[str, str]:
@@ -3129,12 +3156,7 @@ class InferenceEngine:
                 self.cfg, rows, self.mesh)
         MOE_FORM_ROWS_TOTAL.inc(rows, model=self.cfg.name, form=form,
                                 launch=launch)
-        if self.cfg.experts_held is None:
-            return {"expert_form": form}
-        # a share: the experts held here of the router's width
-        return {"expert_form": form,
-                "experts_held": str(self.cfg.experts_held),
-                "experts_of": str(self.cfg.num_experts)}
+        return {"expert_form": form}
 
     def _count_step_stats(self, stats: np.ndarray) -> None:
         """A launch's [live rows routed, experts touched] (summed over
@@ -3920,6 +3942,21 @@ class InferenceEngine:
                 **self.spec_stats,
             } if self._spec_k else None,
             "jit": self.perf.state(),
+            # what the engine is from start to stop (the launch spans
+            # carried these until PR 59): its mesh ("" unmeshed), the
+            # router's width and the experts held here (None: all of
+            # them), the layers with a sliding window, what a slot holds
+            # of its past and the form each kind is read in (latent rows:
+            # absorbed in every region; a state: the delta rule)
+            "shape": {
+                "mesh": self.mesh_axes,
+                "experts": self.cfg.num_experts,
+                "expertsHeld": self.cfg.experts_held,
+                "windowLayers": self._windowed,
+                "cacheRow": "+".join(self.cfg.cache_kinds),
+                "attnForm": "+".join(_ATTN_FORMS[k]
+                                     for k in self.cfg.cache_kinds),
+            },
             # the runner's wall time so far, phase by phase (a wedged
             # runner shows in the dump as one phase that stopped growing)
             "runnerPhaseSeconds": {p: round(v, 3) for p, v

@@ -37,8 +37,17 @@ this module instruments the three dominant TPU-side reasons they don't:
    (``gridllm_engine_phase_cpu_seconds_total``: wall less CPU is what a
    phase spent blocked), and the same boundaries as
    ``jax.profiler.TraceAnnotation`` spans while a capture runs, so a
-   trace shows what the host did in every device gap.
+   trace shows what the host did in every device gap. A phase divides
+   into **stages** (``PhaseClock.stage``:
+   ``gridllm_engine_stage_seconds``, flat ``gridllm.<phase>.<stage>``
+   spans), and the clock keeps the time the runner was busy with nothing
+   in flight (``gridllm_engine_unfed_seconds_total``).
    Driven by the engine's runner loop — see engine/engine.py.
+5. **Stall witnesses** — the cyclic collector's pauses
+   (:func:`install_gc_witness`: ``gridllm_process_gc_pause_seconds``, a
+   ``gridllm.gc`` span while a capture runs) and an event loop's lag
+   (:class:`LoopLagTimer`: ``gridllm_worker_loop_lag_seconds``): what a
+   long gap under a busy phase may turn out to be.
 
 jax is imported lazily (function-level): importing this module — and
 therefore ``gridllm_tpu.obs`` — must stay cheap for control-plane-only
@@ -47,8 +56,10 @@ processes. Pure stdlib otherwise.
 
 from __future__ import annotations
 
+import asyncio
 import contextlib
 import functools
+import gc
 import math
 import os
 import shutil
@@ -127,6 +138,55 @@ PHASE_CPU_SECONDS_TOTAL = _OBS.counter(
     "nobody is doing for it. Not served where the host's kernel keeps a "
     "thread's CPU time in ticks of a millisecond or more (gVisor: 10 ms).",
     ("model", "phase"),
+)
+STAGE_SECONDS = _OBS.histogram(
+    "gridllm_engine_stage_seconds",
+    "Stages inside a phase of gridllm_engine_phase_seconds, on the same "
+    "clock and by the same flush rule (one observation a stretch, several "
+    "stretches of one stage inside one runner iteration at their mean). "
+    "admit: tokenize (the prompt's ids, image expansion, truncation), match "
+    "(prefix lookup, page allocation, the state plan, under the "
+    "allocator's lock); dispatch_prefill: seed (the sampler row, a state "
+    "restore and the window-seed launches), chunk (one stretch for each "
+    "prefill / chunk / mixed-chunk jitted call returning), book (plan "
+    "record, counters and gauges after the last call); fetch: wait (until "
+    "the oldest launch's outputs are ready), copy (device finished to "
+    "tokens on the host); ingest: emit (inside a stream's on_chunk). The "
+    "sum over a phase's stages is at most the phase; the difference is the "
+    "phase's unstaged time.",
+    ("model", "phase", "stage"), buckets=STEP_PHASE_BUCKETS,
+)
+UNFED_SECONDS_TOTAL = _OBS.counter(
+    "gridllm_engine_unfed_seconds_total",
+    "Runner-thread wall time outside idle_wait during which no launch was "
+    "in flight: from the moment the oldest launch's outputs were ready "
+    "with nothing queued behind it to the return of the next jitted launch "
+    "call. In series that is copy + ingest + admit + draft + ctl + the "
+    "launch call; where the runner keeps a launch in flight, near zero. "
+    "Over the sum of gridllm_engine_phase_seconds it is the share of the "
+    "runner's wall in which the host starved the chip; idle_wait over the "
+    "same sum is the share in which nobody asked.",
+    ("model",),
+)
+GC_PAUSE_SECONDS = _OBS.histogram(
+    "gridllm_process_gc_pause_seconds",
+    "Pauses of the cyclic garbage collector in this process, one "
+    "observation a collection, by generation (gc.callbacks' start / stop "
+    "pair, on whichever thread collected). A full collection walks every "
+    "container alive in jax's caches: a long gap under a busy phase of the "
+    "runner is one of these or it is not.",
+    ("generation",), buckets=STEP_PHASE_BUCKETS,
+)
+# Observed from inside a collection, which can begin on a thread that
+# holds this very lock (a scrape's render allocates under it): reentrant,
+# or that thread waits for itself.
+GC_PAUSE_SECONDS._lock = threading.RLock()
+LOOP_LAG_SECONDS = _OBS.histogram(
+    "gridllm_worker_loop_lag_seconds",
+    "How late a timer re-armed every 50 ms fired in the worker's event "
+    "loop: the time a callback due now waits behind whatever the loop is "
+    "running (or behind the interpreter lock). One observation a firing.",
+    buckets=STEP_PHASE_BUCKETS,
 )
 ADMIT_WAIT_SECONDS = _OBS.histogram(
     "gridllm_engine_admit_wait_seconds",
@@ -919,11 +979,32 @@ class PhaseClock:
     stays empty and the CPU series is not served: no reading, not a
     reading of zero.
 
+    ``stage(name)`` divides the open phase one level down: it closes the
+    stretch of the phase that was running (a stage, or the phase's own
+    unstaged time) and opens ``<phase>.<name>``; ``stage(None)`` goes back
+    to the phase's own time, and the next ``mark`` closes the open stage
+    with its phase. One ``perf_counter`` and two float adds, no CPU clock.
+    The phase's own accounting does not see stages: ``mark`` still returns
+    the whole closed phase's seconds, and
+    ``gridllm_engine_stage_seconds{model,phase,stage}`` sums to at most the
+    phase. With no phase open (a multi-host follower replaying a dispatch
+    off the runner) ``stage`` does nothing.
+
+    The clock also keeps the **unfed** time: the engine says ``starve(t)``
+    when the oldest launch's outputs were ready at `t` with nothing queued
+    behind it and ``fed()`` when the next launch call has returned; what
+    lies between, ``idle_wait`` and paused time left out, goes to
+    ``gridllm_engine_unfed_seconds_total{model}``. A clock starts starved:
+    nothing is in flight before the first launch.
+
     While the profiler captures, each phase is also entered as a
     ``TraceAnnotation("gridllm.<phase>", **meta)``, so the ``.xplane.pb``
     holds the runner's phases on the same clock as the device's ``XLA
-    Ops`` line; with no capture no annotation is constructed. Owned by one
-    thread: no lock."""
+    Ops`` line, and a stage as ``gridllm.<phase>.<stage>`` IN PLACE of its
+    phase's span (closed, not nested under: the benchmark's reduction
+    names an idle gap by the shortest span that covers it, and a parent
+    left open would own every gap that straddles two stages); with no
+    capture no annotation is constructed. Owned by one thread: no lock."""
 
     def __init__(self, model: str, profiler: ProfilerCapture | None = None):
         self.model = model
@@ -932,21 +1013,56 @@ class PhaseClock:
         self._phase: str | None = None
         self._t = self._cpu = 0.0
         self._span: Any = None
+        self._meta: dict[str, Any] = {}     # the open phase's span meta
+        # the open stage (None: the phase's own time) and when it opened
+        self._stage: str | None = None
+        self._ts = 0.0
         # closed and not yet flushed: phase -> [seconds, stretches, cpu s]
         self._acc: dict[str, list] = {p: [0.0, 0, 0.0] for p in PHASES}
+        # the same of stages: (phase, stage) -> [seconds, stretches]
+        self._stage_acc: dict[tuple[str, str], list] = {}
         # cumulative, flushed: what tests and batch_state read
         self.seconds: dict[str, float] = dict.fromkeys(PHASES, 0.0)
         self.cpu_seconds: dict[str, float] = (
             dict.fromkeys(PHASES, 0.0) if self._cpu_clock else {})
         self.counts: dict[str, int] = dict.fromkeys(PHASES, 0)
+        self.stage_seconds: dict[tuple[str, str], float] = {}
+        self.stage_counts: dict[tuple[str, str], int] = {}
+        # nothing in flight, as the engine last said, and since when that
+        # has been costing the runner's time (None: idle, paused or fed)
+        self._starved = True
+        self._unfed_t: float | None = None
+        self._unfed_acc = 0.0
+        self.unfed_seconds = 0.0
+        UNFED_SECONDS_TOTAL.inc(0.0, model=model)   # served from the start
 
-    def _close(self, now: float, cpu: float) -> float:
+    def _span_open(self, name: str, meta: dict[str, Any]) -> None:
+        import jax
+
+        self._span = jax.profiler.TraceAnnotation(name, **meta)
+        self._span.__enter__()
+
+    def _span_close(self) -> None:
         span = self._span
         if span is not None:
             self._span = None
             span.__exit__(None, None, None)
+
+    def _close_stage(self, now: float) -> None:
+        key = (self._phase, self._stage)
+        cell = self._stage_acc.get(key)
+        if cell is None:
+            cell = self._stage_acc[key] = [0.0, 0]
+        cell[0] += now - self._ts
+        cell[1] += 1
+        self._stage = None
+
+    def _close(self, now: float, cpu: float) -> float:
+        self._span_close()
         if self._phase is None:
             return 0.0
+        if self._stage is not None:
+            self._close_stage(now)
         dt = now - self._t
         cell = self._acc[self._phase]
         cell[0] += dt
@@ -954,30 +1070,78 @@ class PhaseClock:
         cell[2] += cpu - self._cpu
         return dt
 
-    def mark(self, phase: str, **meta: Any) -> float:
-        """Enter `phase`; returns the seconds the closed phase lasted."""
+    def mark(self, phase: str, stage: str | None = None,
+             **meta: Any) -> float:
+        """Enter `phase` (in its stage `stage`, if given: one clock read
+        for both); returns the seconds the closed phase lasted."""
         now, clock = time.perf_counter(), self._cpu_clock
         cpu = clock() if clock else 0.0
         dt = self._close(now, cpu)
         self._phase, self._t, self._cpu = phase, now, cpu
+        self._stage, self._ts = stage, now
+        if self._starved:
+            if phase == "idle_wait":
+                self._unfed_stop(now)
+            elif self._unfed_t is None:     # back from idle or a pause
+                self._unfed_t = now
+        self._meta = meta
         if self._profiler.tracing:
-            import jax
-
-            self._span = jax.profiler.TraceAnnotation(
-                "gridllm." + phase, **meta)
-            self._span.__enter__()
+            self._span_open(
+                f"gridllm.{phase}.{stage}" if stage else "gridllm." + phase,
+                meta)
         return dt
 
+    def stage(self, name: str | None, of: str | None = None,
+              **meta: Any) -> float:
+        """Enter stage `name` of the open phase (None: the phase's own
+        time again), if that phase is `of` where `of` is given; returns
+        the clock's reading, 0.0 where it did nothing (no phase open)."""
+        phase = self._phase
+        if phase is None or (of is not None and phase != of):
+            return 0.0
+        now = time.perf_counter()
+        if self._stage is not None:
+            self._close_stage(now)
+        self._stage, self._ts = name, now
+        if self._span is not None or self._profiler.tracing:
+            self._span_close()
+            if self._profiler.tracing:
+                if name is None:
+                    self._span_open("gridllm." + phase, self._meta)
+                else:
+                    self._span_open(f"gridllm.{phase}.{name}", meta)
+        return now
+
     def annotate(self, **meta: Any) -> None:
-        """Metadata known only once the phase is under way (the tokens an
-        ingest emitted). Free when nothing is being captured."""
+        """Metadata known only once the phase (or its open stage) is under
+        way (the tokens an ingest emitted). Free when nothing is being
+        captured."""
         if self._span is not None:
             self._span.set_metadata(**meta)
 
+    def starve(self, now: float) -> None:
+        """Nothing is in flight as of `now` (a reading of this clock: the
+        end of ``fetch.wait``)."""
+        self._starved, self._unfed_t = True, now
+
+    def fed(self) -> None:
+        """A launch call returned: something is in flight again. A clock
+        read only where the runner was starved."""
+        if self._starved:
+            self._starved = False
+            self._unfed_stop(time.perf_counter())
+
+    def _unfed_stop(self, now: float) -> None:
+        if self._unfed_t is not None:
+            self._unfed_acc += now - self._unfed_t
+            self._unfed_t = None
+
     def pause(self) -> None:
         clock = self._cpu_clock
-        self._close(time.perf_counter(), clock() if clock else 0.0)
+        now = time.perf_counter()
+        self._close(now, clock() if clock else 0.0)
         self._phase = None
+        self._unfed_stop(now)
         self.flush()
 
     def flush(self) -> None:
@@ -988,11 +1152,108 @@ class PhaseClock:
             cell[0], cell[1], cell[2] = 0.0, 0, 0.0
             self.seconds[phase] += secs
             self.counts[phase] += n
-            for _ in range(n):
-                PHASE_SECONDS.observe(secs / n, model=self.model, phase=phase)
+            PHASE_SECONDS.observe_many(secs / n, n, model=self.model,
+                                       phase=phase)
             if self._cpu_clock:
                 self.cpu_seconds[phase] += cpu
                 PHASE_CPU_SECONDS_TOTAL.inc(cpu, model=self.model, phase=phase)
+        for key, cell in self._stage_acc.items():
+            secs, n = cell
+            if not n:
+                continue
+            cell[0], cell[1] = 0.0, 0
+            self.stage_seconds[key] = self.stage_seconds.get(key, 0.0) + secs
+            self.stage_counts[key] = self.stage_counts.get(key, 0) + n
+            STAGE_SECONDS.observe_many(secs / n, n, model=self.model,
+                                       phase=key[0], stage=key[1])
+        if self._unfed_acc:
+            self.unfed_seconds += self._unfed_acc
+            UNFED_SECONDS_TOTAL.inc(self._unfed_acc, model=self.model)
+            self._unfed_acc = 0.0
+
+
+# ---------------------------------------------------------------------------
+# stall witnesses: the collector's pauses, an event loop's lag
+# ---------------------------------------------------------------------------
+
+_gc_witness_lock = threading.Lock()
+_gc_witness_on = False
+# one collection runs at a time in a process (the collector does not
+# nest), so its start time and span are plain globals
+_gc_t0: float | None = None
+_gc_span: Any = None
+
+
+def _on_gc(phase: str, info: dict[str, int]) -> None:
+    """``gc.callbacks`` entry: runs on the thread that collects, before
+    and after every collection of every generation. A clock read each and
+    one observe; while a capture runs, a ``gridllm.gc`` span over the
+    collection, inside whatever span the thread had open (so it is the
+    shortest cover of a gap it spans)."""
+    global _gc_t0, _gc_span
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        if _PROFILER.tracing:
+            import jax
+
+            _gc_span = jax.profiler.TraceAnnotation(
+                "gridllm.gc", generation=info["generation"])
+            _gc_span.__enter__()
+        return
+    t0, _gc_t0 = _gc_t0, None
+    if t0 is None:      # registered between a collection's two callbacks
+        return
+    GC_PAUSE_SECONDS.observe(time.perf_counter() - t0,
+                             generation=str(info["generation"]))
+    span, _gc_span = _gc_span, None
+    if span is not None:
+        span.set_metadata(collected=info["collected"])
+        span.__exit__(None, None, None)
+
+
+def install_gc_witness() -> None:
+    """Time every collection of this process from here on
+    (``gridllm_process_gc_pause_seconds{generation}``). Idempotent: a
+    worker process calls it once at start, and no thread is added."""
+    global _gc_witness_on
+    with _gc_witness_lock:
+        if not _gc_witness_on:
+            gc.callbacks.append(_on_gc)
+            _gc_witness_on = True
+
+
+class LoopLagTimer:
+    """A timer re-armed every ``INTERVAL`` seconds in the running event
+    loop, observing how late each firing came
+    (``gridllm_worker_loop_lag_seconds``): no thread, one ``call_at``
+    and one observe a firing. ``start()`` inside the loop, ``stop()``
+    cancels the pending firing."""
+
+    INTERVAL = 0.05
+
+    def __init__(self) -> None:
+        self._loop: asyncio.AbstractEventLoop | None = None
+        self._handle: asyncio.TimerHandle | None = None
+        self._due = 0.0
+
+    def start(self) -> "LoopLagTimer":
+        if self._loop is None:
+            self._loop = asyncio.get_running_loop()
+            self._arm()
+        return self
+
+    def _arm(self) -> None:
+        self._due = self._loop.time() + self.INTERVAL
+        self._handle = self._loop.call_at(self._due, self._fire)
+
+    def _fire(self) -> None:
+        LOOP_LAG_SECONDS.observe(max(self._loop.time() - self._due, 0.0))
+        self._arm()
+
+    def stop(self) -> None:
+        if self._handle is not None:
+            self._handle.cancel()
+        self._loop = self._handle = None
 
 
 def handle_profile_request(seconds_raw: str | None,

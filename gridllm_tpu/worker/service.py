@@ -58,6 +58,7 @@ from gridllm_tpu.obs import (
     unregister_engine_probe,
     unregister_memory_probe,
 )
+from gridllm_tpu.obs.perf import LoopLagTimer, install_gc_witness
 from gridllm_tpu.utils.config import WorkerConfig, env_int_lenient
 from gridllm_tpu.utils.events import EventEmitter
 from gridllm_tpu.utils.logging import bind_request_id, get_logger
@@ -242,6 +243,7 @@ class WorkerService(EventEmitter):
         self._running = False
         self._subs: list[Subscription] = []
         self._tasks: list[asyncio.Task] = []
+        self._loop_lag = LoopLagTimer()
         self._cancelled: set[str] = set()
         self._executing: set[str] = set()
         self._last_status: str | None = None
@@ -374,6 +376,10 @@ class WorkerService(EventEmitter):
             if not eng.embedding_only:
                 eng.start()
         self._tasks.append(asyncio.create_task(self._engine_watchdog()))
+        # the two stall witnesses (obs/perf.py): the collector's pauses,
+        # timed from here on in this process, and this loop's lag
+        install_gc_witness()
+        self._loop_lag.start()
         # one probe per service, resolving the CURRENT engine set at dump
         # time — admin load/unload and watchdog drops need no re-wiring
         register_engine_probe(self.worker_id, self._engine_states)
@@ -416,6 +422,7 @@ class WorkerService(EventEmitter):
         for t in self._tasks:
             t.cancel()
         self._tasks.clear()
+        self._loop_lag.stop()
         for eng in self.engines.values():
             if not eng.embedding_only:
                 await asyncio.to_thread(eng.stop)

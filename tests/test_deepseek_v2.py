@@ -338,7 +338,8 @@ def test_the_engine_serves_it_on_one_latent_row_a_token():
     mem = eng.memory_arrays()["alloc"]
     assert mem["cacheRow"] == "latent" and mem["rowBytes"] == CFG.cache_dim * itemsize
     assert not eng.kv_transfer_supported()
-    assert eng._attn_meta == {"cache_row": "latent", "attn_form": "absorbed"}
+    shape = eng.batch_state()["shape"]
+    assert (shape["cacheRow"], shape["attnForm"]) == ("latent", "absorbed")
 
 
 @pytest.mark.parametrize("refused", [{"kv_int8": True}, {"kv_host_bytes": 1 << 20}])

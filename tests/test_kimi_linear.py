@@ -547,10 +547,11 @@ def test_a_reasked_prefix_is_admitted_from_latent_pages_and_a_snapshot():
 def test_the_engine_accounts_for_both_caches():
     eng = _engine()
     eng.prewarm()
-    assert eng._attn_meta == {"cache_row": "latent+state",
-                              "attn_form": "absorbed+delta"}
-    assert eng._expert_meta("verify", 10) == {
-        "expert_form": "all_experts", "experts_held": "4", "experts_of": "16"}
+    shape = eng.batch_state()["shape"]
+    assert (shape["cacheRow"], shape["attnForm"]) == ("latent+state",
+                                                      "absorbed+delta")
+    assert (shape["expertsHeld"], shape["experts"]) == (4, 16)
+    assert eng._expert_meta("verify", 10) == {"expert_form": "all_experts"}
     assert eng.cache.v is None and eng.cache.k.shape[0] == CFG.cache_layers == 2
     rec = eng.cache.rec
     assert rec.state.shape == (5, 2, 16, 128) and rec.step_rows == 5

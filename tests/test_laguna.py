@@ -502,8 +502,9 @@ def test_the_engine_accounts_for_the_rings():
 
     eng = _engine()
     eng.prewarm()
-    assert eng._attn_meta == {"cache_row": "kv+window",
-                              "attn_form": "per_head+ring"}
+    shape = eng.batch_state()["shape"]
+    assert (shape["cacheRow"], shape["attnForm"]) == ("kv+window",
+                                                      "per_head+ring")
     assert eng.cache.k.shape[0] == CFG.cache_layers == 2
     win = eng.cache.win
     # a window of 8, a launch of 32, a page of 16: four pages a slot

@@ -439,9 +439,10 @@ def test_a_reasked_prefix_is_admitted_from_latent_pages():
 def test_the_engine_accounts_for_two_pool_layers_a_block():
     eng = _engine()
     eng.prewarm()
-    assert eng._attn_meta == {"cache_row": "latent", "attn_form": "absorbed"}
-    assert eng._expert_meta("verify", 10) == {
-        "expert_form": "all_experts", "experts_held": "4", "experts_of": "16"}
+    shape = eng.batch_state()["shape"]
+    assert (shape["cacheRow"], shape["attnForm"]) == ("latent", "absorbed")
+    assert (shape["expertsHeld"], shape["experts"]) == (4, 16)
+    assert eng._expert_meta("verify", 10) == {"expert_form": "all_experts"}
     assert eng.cache.v is None and eng.cache.k.shape[0] == CFG.cache_layers == 4
     mem = eng.memory_arrays()
     assert mem["alloc"]["cacheRow"] == "latent"

@@ -557,8 +557,9 @@ def test_the_engine_accounts_for_the_state():
 
     eng = _engine()
     eng.prewarm()
-    assert eng._attn_meta == {"cache_row": "kv+state",
-                              "attn_form": "per_head+delta"}
+    shape = eng.batch_state()["shape"]
+    assert (shape["cacheRow"], shape["attnForm"]) == ("kv+state",
+                                                      "per_head+delta")
     assert eng.cache.k.shape[0] == CFG.cache_layers == 2
     rec = eng.cache.rec
     assert rec.state.shape == (6, 2, 16, 128) and rec.step_rows == 5

@@ -9,24 +9,40 @@ drafter's lookups are counted by outcome and ride on the draft span
 wall seconds, so that a wait (a sleep, the interpreter lock held by
 another thread) reads as blocked time and computing does not; and on a
 host whose kernel counts a thread's CPU in ticks the second clock is not
-read at all and its series not served."""
+read at all and its series not served. Stages, the unfed clock and the two
+stall witnesses (ISSUE 59): a phase divides into stages that sum to at most
+the phase and leave the phase's own series as it was, a stage's span
+replaces its phase's, the runner's busy time with nothing in flight is
+counted and idle time is not, a collection is timed by generation, and an
+event loop's lag is read by a timer that stops with its worker."""
+
+import asyncio
+import gc
 
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from gridllm_tpu.engine import EngineConfig, GenerationRequest, InferenceEngine
-from gridllm_tpu.engine.engine import _SPEC_LOOKUPS
+from gridllm_tpu.engine.engine import _CHUNK_LAUNCHES, _SPEC_LOOKUPS
+from gridllm_tpu.obs import perf
 from gridllm_tpu.obs.perf import (
     ADMIT_WAIT_SECONDS,
+    GC_PAUSE_SECONDS,
+    LOOP_LAG_SECONDS,
     PHASE_CPU_SECONDS_TOTAL,
     PHASE_SECONDS,
     PHASES,
+    STAGE_SECONDS,
+    UNFED_SECONDS_TOTAL,
     VERIFY_CTX_TOKENS_TOTAL,
+    LoopLagTimer,
     PhaseClock,
     ProfilerCapture,
     handle_profile_request,
+    install_gc_witness,
     thread_cpu_clock,
 )
 
@@ -42,6 +58,23 @@ def _phase_counts() -> dict[str, int]:
 
 def _phase_sums() -> dict[str, float]:
     return {p: PHASE_SECONDS.sum(model=MODEL, phase=p) for p in PHASES}
+
+
+# every stage the runner enters (ISSUE 59's table), by phase
+STAGES = {"admit": ("tokenize", "match"),
+          "dispatch_prefill": ("seed", "chunk", "book"),
+          "fetch": ("wait", "copy"), "ingest": ("emit",)}
+STAGE_KEYS = [(p, s) for p, ss in STAGES.items() for s in ss]
+
+
+def _stage_counts() -> dict[tuple[str, str], int]:
+    return {k: STAGE_SECONDS.count(model=MODEL, phase=k[0], stage=k[1])
+            for k in STAGE_KEYS}
+
+
+def _stage_sums() -> dict[tuple[str, str], float]:
+    return {k: STAGE_SECONDS.sum(model=MODEL, phase=k[0], stage=k[1])
+            for k in STAGE_KEYS}
 
 
 def _phase_cpu() -> dict[str, float]:
@@ -241,7 +274,10 @@ def made(monkeypatch):
             made.append(self)
 
         def __enter__(self):
+            # the spans open on this thread when this one opens
+            self.under = [s.name for s in made if s.open]
             self.open = True
+            self.thread = threading.get_ident()
 
         def __exit__(self, *exc):
             self.open = False
@@ -265,20 +301,36 @@ def test_no_trace_annotation_is_constructed_with_no_capture_active(made):
     _serve(eng, n=2, idle_s=0.0)
     flag.tracing = False
     names = {s.name for s in made}
-    assert names == {"gridllm." + p for p in PHASES}
+    # a fetch is entered in its first stage: all of it is wait or copy
+    assert names == ({"gridllm." + p for p in PHASES if p != "fetch"}
+                     | {f"gridllm.{p}.{s}" for p, s in STAGE_KEYS})
     assert all(s.open is False for s in made)       # each closed by the next mark
+    # flat: a stage's span REPLACES its phase's (closed first, not nested
+    # under), so no span ever opens under another
+    assert all(s.under == [] for s in made)
     launch = next(s for s in made if s.name == "gridllm.dispatch_verify")
-    assert set(launch.meta) == {"gen", "slots", "ctx_tokens", "mesh", "experts",
-                                "window_layers", "cache_row", "attn_form"}
-    assert launch.meta["mesh"] == ""                # unmeshed; "tp:4" under one
-    # K and V per head; "latent" / "absorbed" for a latent-attention family
-    assert (launch.meta["cache_row"], launch.meta["attn_form"]) == ("kv", "per_head")
+    # what varies by launch; the engine's constants are in batch_state()
+    assert set(launch.meta) == {"gen", "slots", "ctx_tokens"}
     assert launch.meta["slots"] >= 1 and launch.meta["ctx_tokens"] > 0
-    admit = next(s for s in made if s.name == "gridllm.admit")
+    shape = eng.batch_state()["shape"]
+    assert shape["mesh"] == ""                      # unmeshed; "tp:4" under one
+    # K and V per head; "latent" / "absorbed" for a latent-attention family
+    assert (shape["cacheRow"], shape["attnForm"]) == ("kv", "per_head")
+    assert (shape["experts"], shape["expertsHeld"], shape["windowLayers"]) == (
+        0, None, 0)
+    admit = next(s for s in made if s.name == "gridllm.admit.tokenize")
     assert admit.meta["request"] in ("r0", "r1")
+    # back from a stage the phase's span opens again, with the phase's meta
+    assert all(s.meta["request"] in ("r0", "r1")
+               for s in made if s.name == "gridllm.admit")
     prefill = next(s for s in made if s.name == "gridllm.dispatch_prefill")
     assert prefill.meta["prompt_tokens"] > 0 and "cached_tokens" in prefill.meta
-    assert prefill.meta["mesh"] == ""
+    assert set(prefill.meta) == {"request", "prompt_tokens", "cached_tokens"}
+    seed = next(s for s in made if s.name == "gridllm.dispatch_prefill.seed")
+    # the sampler row, and a window-seed launch where r0's pages were found
+    assert set(seed.meta) == {"launches"} and seed.meta["launches"] in (1, 2)
+    chunk = next(s for s in made if s.name == "gridllm.dispatch_prefill.chunk")
+    assert {"width", "start"} <= set(chunk.meta)
     assert any("tokens" in s.meta for s in made if s.name == "gridllm.ingest")
     draft = next(s for s in made if s.name == "gridllm.draft")
     assert set(draft.meta) == {"slots", "hits", "history_tokens"}
@@ -455,3 +507,248 @@ def test_without_a_fine_thread_clock_nothing_is_read_and_nothing_served(
                for p in PHASES)
     assert not [labels for labels, _ in PHASE_CPU_SECONDS_TOTAL.items()
                 if labels["model"] == "no-thread-clock"]
+
+
+# ---------------------------------------------------------------------------
+# stages, the unfed clock and the stall witnesses (ISSUE 59)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("spec", [True, False], ids=["speculative", "block"])
+def test_stages_sum_to_at_most_their_phase_and_leave_the_phase_series_alone(spec):
+    """Σ stages of a phase <= the phase (the rest is its unstaged time), a
+    fetch is all wait and copy, and the phases still partition the
+    runner's wall time: a stage never touches its phase's accounting."""
+    eng = InferenceEngine(EngineConfig(**TINY, spec_decode=spec,
+                                       decode_block=2, pipeline_depth=2))
+    before, sums0 = _phase_sums(), _stage_sums()
+    _serve(eng)
+    clock = eng._clock
+    after, sums1 = _phase_sums(), _stage_sums()
+    assert sum(clock.seconds.values()) == pytest.approx(eng.runner_wall_s,
+                                                        rel=0.01)
+    for phase, stages in STAGES.items():
+        staged = sum(clock.stage_seconds[phase, s] for s in stages)
+        assert 0.0 < staged <= clock.seconds[phase] + 1e-9, phase
+        # the registry holds what the clock holds
+        assert sum(sums1[phase, s] - sums0[phase, s] for s in stages) == (
+            pytest.approx(staged, rel=1e-6))
+        assert after[phase] - before[phase] == pytest.approx(
+            clock.seconds[phase], rel=1e-6)
+    assert (clock.stage_seconds["fetch", "wait"]
+            + clock.stage_seconds["fetch", "copy"]) == pytest.approx(
+                clock.seconds["fetch"], rel=1e-6)
+    assert set(clock.stage_seconds) == set(STAGE_KEYS)     # and nothing else
+
+
+def test_every_stage_is_observed_with_the_count_its_table_says():
+    """admit's stages and dispatch_prefill's seed and book once an
+    admission, a chunk stretch a chunk launch, a wait and a copy a fetch
+    (verify launches and the mixed launches that admitted), an emit a
+    callback."""
+    eng = InferenceEngine(EngineConfig(**TINY, spec_decode=True,
+                                       prefill_chunk=16))
+    calls = []
+    before, gen0 = _stage_counts(), eng._gen
+    chunks0 = sum(v for _, v in _CHUNK_LAUNCHES.items())
+    phase0 = _phase_counts()
+    eng.start()
+    try:
+        done = []
+        for i, prompt in enumerate(["hello there", "hello there " * 4]):
+            eng.submit(GenerationRequest(
+                id=f"s{i}", prompt=prompt, options=OPTS,
+                on_chunk=lambda d, fin, res: (calls.append(d),
+                                              done.append(res) if fin else None)))
+        deadline = time.time() + 120
+        while len(done) < 2 and time.time() < deadline:
+            time.sleep(0.01)
+        assert len(done) == 2
+    finally:
+        eng.stop()
+    got = {k: v - before[k] for k, v in _stage_counts().items()}
+    chunks = sum(v for _, v in _CHUNK_LAUNCHES.items()) - chunks0
+    assert chunks >= 4                # the longer prompt took three or more
+    fetches = _phase_counts()["fetch"] - phase0["fetch"]
+    assert fetches == eng._gen - gen0
+    assert got == {("admit", "tokenize"): 2, ("admit", "match"): 2,
+                   ("dispatch_prefill", "seed"): 2,
+                   ("dispatch_prefill", "chunk"): chunks,
+                   ("dispatch_prefill", "book"): 2,
+                   ("fetch", "wait"): fetches, ("fetch", "copy"): fetches,
+                   ("ingest", "emit"): len(calls)}
+
+
+def test_a_stage_with_no_phase_open_does_nothing():
+    """A multi-host follower replays _dispatch_prefill off the runner:
+    no phase is open, so nothing is timed and nothing annotated."""
+    flag = _Tracing()
+    flag.tracing = True
+    clock = PhaseClock("follower", profiler=flag)
+    assert clock.stage("seed") == 0.0 and clock.stage(None) == 0.0
+    clock.fed()
+    clock.flush()
+    assert clock.stage_seconds == {} and clock._span is None
+    assert clock.unfed_seconds == 0.0
+    # and a stage of another phase than the one named is not entered
+    clock.mark("ctl")
+    assert clock.stage("emit", of="ingest") == 0.0
+    clock.pause()
+    assert clock.stage_seconds == {}
+
+
+def test_mark_returns_the_whole_phase_whatever_stages_divided_it():
+    """_mark_ingest's return feeds usage attribution and the run-ahead
+    rule: it reads the closed phase whole, wait and copy together."""
+    clock = PhaseClock("whole-phase")
+    clock.mark("fetch", stage="wait")
+    time.sleep(0.02)
+    clock.stage("copy")
+    time.sleep(0.02)
+    waited = clock.mark("ingest")
+    clock.pause()
+    assert waited >= 0.04
+    assert clock.seconds["fetch"] == pytest.approx(waited)
+    assert clock.stage_counts == {("fetch", "wait"): 1, ("fetch", "copy"): 1}
+    assert min(clock.stage_seconds.values()) >= 0.02
+    assert sum(clock.stage_seconds.values()) == pytest.approx(waited)
+
+
+def _unfed() -> float:
+    return UNFED_SECONDS_TOTAL.value(model=MODEL)
+
+
+def test_the_unfed_clock_counts_a_series_and_never_idle_wait():
+    """In series (the synchronous driver: every launch is fetched before
+    the next is dispatched) the chip has nothing in flight from a fetch to
+    the next launch: counted. An idle runner is not: nobody asked."""
+    eng = InferenceEngine(EngineConfig(**TINY, spec_decode=False))
+    u0 = _unfed()
+    eng.submit(GenerationRequest(id="u0", prompt="hello there", options=OPTS))
+    while eng.step():
+        pass
+    series = _unfed() - u0
+    assert series > 0.0
+    clock = eng._clock
+    busy = sum(v for p, v in clock.seconds.items() if p != "fetch")
+    # the host's phases and the copy, never the fetch's wait
+    assert series <= busy + clock.stage_seconds["fetch", "copy"] + 1e-6
+    assert series == pytest.approx(eng._clock.unfed_seconds)
+    # an idle runner: half a second of idle_wait, not a microsecond unfed
+    u1, idle0 = _unfed(), eng._clock.seconds["idle_wait"]
+    eng.start()
+    time.sleep(0.5)
+    eng.stop()
+    assert eng._clock.seconds["idle_wait"] - idle0 >= 0.4
+    assert _unfed() - u1 < 0.01
+
+
+def test_the_unfed_clock_stands_still_while_a_launch_is_in_flight():
+    """Run-ahead: the block pipeline keeps `pipeline_depth` launches in
+    flight, so from its first fetch to its last the clock is never started
+    and the counter does not move, whatever the host does in between."""
+    eng = InferenceEngine(EngineConfig(**TINY, spec_decode=False,
+                                       decode_block=1, pipeline_depth=2))
+    clock = eng._clock
+    seen = []
+    fetch = eng._fetch_oldest
+
+    def watched():
+        # behind this fetch another launch is in flight: the pump tops the
+        # pipeline up before it fetches
+        behind = len(eng._inflight) - 1
+        gen = fetch()
+        seen.append((behind, clock._unfed_t,
+                     clock.unfed_seconds + clock._unfed_acc))
+        return gen
+
+    eng._fetch_oldest = watched
+    done = []
+    eng.submit(GenerationRequest(
+        id="a0", prompt="hello there", options={**OPTS, "num_predict": 24},
+        on_chunk=lambda d, fin, res: done.append(res) if fin else None))
+    eng.start()
+    try:
+        deadline = time.time() + 120
+        while not done and time.time() < deadline:
+            time.sleep(0.01)
+    finally:
+        eng.stop()
+    assert len(seen) >= 20 and all(behind == 1 for behind, _, _ in seen)
+    assert all(t is None for _, t, _ in seen)
+    assert seen[0][2] == seen[-1][2] > 0.0      # the admission's, before it
+
+
+def test_a_collection_is_timed_by_generation_and_spanned_under_a_capture(
+        made, monkeypatch):
+    install_gc_witness()
+    install_gc_witness()                                # once a process
+    assert gc.callbacks.count(perf._on_gc) == 1
+
+    def counts():
+        return {g: GC_PAUSE_SECONDS.count(generation=str(g)) for g in range(3)}
+
+    was = gc.isenabled()
+    gc.disable()                # no collection but the ones forced here
+    try:
+        before = counts()
+        gc.collect(1)
+        assert {g: n - before[g] for g, n in counts().items()} == {
+            0: 0, 1: 1, 2: 0}
+        assert made == []                               # off a capture: no span
+        monkeypatch.setattr(perf._PROFILER, "tracing", True)
+        s0 = GC_PAUSE_SECONDS.sum(generation="2")
+        gc.collect()
+        monkeypatch.setattr(perf._PROFILER, "tracing", False)
+        assert counts()[2] == before[2] + 1
+        assert GC_PAUSE_SECONDS.sum(generation="2") > s0
+    finally:
+        if was:
+            gc.enable()
+    span, = (s for s in made if s.name == "gridllm.gc")
+    assert span.meta["generation"] == 2 and span.meta["collected"] >= 0
+    assert span.open is False
+
+
+def test_the_loop_lag_timer_reads_a_blocked_loop_and_stops_with_its_worker():
+    async def run():
+        timer = LoopLagTimer().start()
+        await asyncio.sleep(0.12)                       # two firings on time
+        n0, s0 = LOOP_LAG_SECONDS.count(), LOOP_LAG_SECONDS.sum()
+        assert n0 >= 1
+        await asyncio.sleep(0.001)
+        time.sleep(0.1)         # async-ok: the blocked loop is the test
+        await asyncio.sleep(0.02)
+        late = LOOP_LAG_SECONDS.sum() - s0
+        assert LOOP_LAG_SECONDS.count() > n0 and late >= 0.04
+        timer.stop()
+        n1 = LOOP_LAG_SECONDS.count()
+        await asyncio.sleep(0.12)
+        assert LOOP_LAG_SECONDS.count() == n1           # nothing re-armed
+        timer.stop()                                    # idempotent
+
+    asyncio.run(run())
+
+
+def test_the_worker_arms_both_witnesses_and_stops_its_timer():
+    """WorkerService.start() installs the collector's witness and the
+    loop-lag timer; stop() cancels the timer."""
+    from gridllm_tpu.bus import create_bus
+    from gridllm_tpu.utils.config import WorkerConfig
+    from gridllm_tpu.worker.service import WorkerService
+
+    async def run():
+        bus = create_bus("")
+        worker = WorkerService(bus, {}, WorkerConfig(worker_id="lag-w"))
+        await worker.start()
+        try:
+            assert perf._on_gc in gc.callbacks
+            assert worker._loop_lag._handle is not None
+            n0 = LOOP_LAG_SECONDS.count()
+            await asyncio.sleep(0.12)
+            assert LOOP_LAG_SECONDS.count() > n0
+        finally:
+            await worker.stop()
+        assert worker._loop_lag._handle is None
+
+    asyncio.run(run())

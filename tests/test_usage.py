@@ -124,6 +124,24 @@ def test_build_usage_and_engine_ledger_roundtrip():
     assert after["spec_wasted"] - before.get("spec_wasted", 0.0) == 2
 
 
+def test_kv_page_seconds_count_the_pages_a_slot_holds_not_its_table_row():
+    """A request's kvPageSeconds is the pages its slot held times the time
+    it held them. The table row is padded to max_pages_per_slot (16 here,
+    128 in a deployment): counting its width billed every request the
+    whole row (PR 45 (d2), repaired in PR 59)."""
+    from gridllm_tpu.engine import GenerationRequest
+
+    eng = make_engine()
+    res = eng.generate(GenerationRequest(
+        id="pages", prompt="hello there",
+        options={"temperature": 0.0, "num_predict": 4}))
+    held = -(-(res.prompt_eval_count + 4) // 8)     # pages of 8 tokens
+    assert held == 2 < 16
+    # the slot's seconds are not on the result: bound them by the request's
+    seconds = res.total_duration_ns / 1e9
+    assert 0.0 < res.kv_page_s <= held * (seconds + 0.05)
+
+
 def test_usage_accountant_folds_exactly_once_and_snapshots():
     acc = UsageAccountant(MetricsRegistry(), lru_cap=2)
     u = build_usage(tenant="acme", model="m1", prompt_tokens=10,
