@@ -101,6 +101,7 @@ from gridllm_tpu.ops.kvcache import (
 )
 from gridllm_tpu.ops.kvtier import set_tier_gauges
 from gridllm_tpu.ops.sampling import (
+    ROW_LEN,
     SamplingParams,
     sample_tokens,
     spec_accept,
@@ -182,6 +183,15 @@ _CHUNK_TOKENS = _OBS.counter(
     "Tokens through chunked-prefill launches, by model and kind (real = "
     "prompt tokens, padded = the launches' widths, padding included).",
     ("model", "kind"),
+)
+# the jitted calls of dispatch_prefill's `seed` stage (beside its seconds
+# in gridllm_engine_stage_seconds): 1-2 an admission whatever is cached
+_SEED_LAUNCHES = _OBS.counter(
+    "gridllm_engine_seed_launches_total",
+    "Jitted calls of an admission's seed stage (the sampler row and the "
+    "repeat-penalty window's tail in one program, and a state restore "
+    "where the family has a second-kind cache), by model.",
+    ("model",),
 )
 _KV_ROW_BYTES = _OBS.gauge(
     "gridllm_kv_row_bytes",
@@ -390,6 +400,30 @@ def _host_i32(values: list[int], size: int) -> np.ndarray:
     buf = np.zeros((size,), np.int32)
     buf[:len(values)] = values
     return buf
+
+
+# Admission's host record (admit_seed_fn's one host argument): the slot,
+# the tail's length, the sampler row (SamplingParams.pack_row), the tail.
+# _seed_record writes it and _seed_fields reads it, in the program
+_SEED_TAIL = 2 + ROW_LEN
+
+
+def _seed_record(slot: int, upd: dict[str, Any], tail: list[int],
+                 width: int) -> np.ndarray:
+    """ONE host int32 array of the slot, the sampler values `upd` and
+    `tail`, the last tokens of the prefix-cached span, zero-padded to
+    `width` (repeat_window). One array, not five: each host argument of a
+    jitted call is a transfer of its own."""
+    rec = np.zeros((_SEED_TAIL + width,), np.int32)
+    rec[0], rec[1] = slot, len(tail)
+    rec[2:_SEED_TAIL] = SamplingParams.pack_row(upd)
+    rec[_SEED_TAIL:_SEED_TAIL + len(tail)] = tail
+    return rec
+
+
+def _seed_fields(rec: jnp.ndarray):
+    """(slot, tail length, sampler row, padded tail) of a _seed_record."""
+    return rec[0], rec[1], rec[2:_SEED_TAIL], rec[_SEED_TAIL:]
 
 
 def _model_module(cfg: ModelConfig):
@@ -783,10 +817,17 @@ class InferenceEngine:
         self._spec_hits = 0
         self._spec_quiet = 0
         # the runner's last eight of each, in seconds: its wait at a verify
-        # launch's fetch, and an admission's host time (pop to dispatched).
-        # _step_spec compares their medians: a median, because an admission
-        # that compiled a program is no admission's measure
-        self._fetch_waits: deque[float] = deque(maxlen=8)
+        # launch's fetch (and whether another launch was in flight behind
+        # it: the runner ahead of the device), an iteration's host work
+        # (what the phase clock closed of ingest, draft and the launch call
+        # since the iteration before: _step_spec reads it, and an
+        # admission, a fetch or an idle wait is another phase's), and an
+        # admission's host time (pop to dispatched). _step_spec compares
+        # their medians: a median, because an admission that compiled a
+        # program is no admission's measure
+        self._fetch_waits: deque[tuple[float, bool]] = deque(maxlen=8)
+        self._host_works: deque[float] = deque(maxlen=8)
+        self._host_spent = 0.0      # the clock's reading an iteration ago
         self._admits: deque[float] = deque(maxlen=8)
         # the runner thread's wall time, phase by phase (obs/perf.py);
         # runner_wall_s is the same stretch measured on its own, _run
@@ -1008,8 +1049,8 @@ class InferenceEngine:
             # a full chunk and a one-token last chunk behind it: the full
             # and the narrow width of the chunk program (_chunk_width);
             # sent twice when the prefix cache is on, so the second
-            # admission is a hit (window_seed, then the narrow width
-            # behind a cached prefix)
+            # admission is a hit (a second-kind cache's state_restore,
+            # then the narrow width behind a cached prefix)
             lengths += [self._chunk_len + 1] * (
                 2 if self._prefix_cache_cap != 0 else 1)
         # one fill token per length: prompts that shared a first page
@@ -1017,7 +1058,7 @@ class InferenceEngine:
         prompts = [(n, 1 + lengths.index(n)) for n in lengths]
         if self.mesh is not None:
             # the first request again, as a new prompt: under a mesh its
-            # programs (the sampler row, the first width's chunk) were
+            # programs (admit_seed, the first width's chunk) were
             # compiled for the state as created, on one device, and every
             # step leaves the state laid out over the mesh as XLA chose, so
             # their second call compiles again with no new Python signature
@@ -1800,29 +1841,32 @@ class InferenceEngine:
             stats = stats[0].sum(axis=0) if stats else None
             return (out, stats), tokens, cache, counts, window, wlen, sp
 
-        # Prefix-cache warm admission (ISSUE 3): the cached region's tokens
-        # skip the model forward but must still flow through the
-        # repeat-penalty window/counts bookkeeping, or a warm request's
-        # sampler state (and therefore its tokens) would diverge from the
-        # cold path's. Same chunk shape as prefill_chunk_fn → one compiled
-        # program; integer-only state, so warm == cold bit for bit.
-        @partial(jax.jit, donate_argnums=(1, 2, 3))
-        def window_seed_fn(sp, window, wlen, counts, chunk, start, length,
-                           slot):
-            rl = sp.repeat_last_n[slot]
-            return window_set_slot(
-                window, wlen, counts, slot, chunk, start, length, rl,
-                mc.vocab_size,
-            )
-
-        # Admission's sampler row and a finished slot's active flag: ONE
-        # donated program each, fed a small host record. An eager
+        # Admission's sampler state (ISSUE 25, ISSUE 60): ONE donated
+        # program of integer and scalar state an admission, fed ONE host
+        # record (_seed_record). It writes the slot's sampler row (an eager
         # `.at[slot].set(v)` per field is several one-element programs,
-        # each a Python + PJRT dispatch with the chip idle (PERF.md, PR 25)
-        @partial(jax.jit, donate_argnums=(0,))
-        def sampler_row_fn(sp, slot, f32, i32):
-            return sp.set_row(slot, f32, i32)
+        # each a Python + PJRT dispatch with the chip idle: PERF.md, PR 25)
+        # and rebuilds the slot's repeat-penalty window, wlen and counts row
+        # from the record's tail, the last min(cached, repeat_window) tokens
+        # of the prefix-cached span: those tokens skip the model forward but
+        # must still be in the window, or a warm request's sampler state
+        # (and so its tokens) would part from the cold path's. A window
+        # keeps the last min(total, repeat_last_n <= repeat_window) tokens,
+        # so the tail alone gives what appending the span chunk by chunk
+        # gave, bit for bit, whatever `cached` is; the first real chunk
+        # behind it appends (start = cached != 0). With nothing cached the
+        # tail is empty and the reset is the one the first chunk does anyway.
+        @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+        def admit_seed_fn(sp, window, wlen, counts, rec):
+            slot, tail_len, row, tail = _seed_fields(rec)
+            sp = sp.set_row(slot, row)
+            window, wlen, counts = window_set_slot(
+                window, wlen, counts, slot, tail, jnp.int32(0), tail_len,
+                sp.repeat_last_n[slot], mc.vocab_size,
+            )
+            return sp, window, wlen, counts
 
+        # a finished slot's active flag: one donated program too
         @partial(jax.jit, donate_argnums=(0,))
         def deactivate_fn(active, slot):
             return active.at[slot].set(False)
@@ -1840,9 +1884,8 @@ class InferenceEngine:
 
             self._state_restore_fn = self.perf.wrap(
                 "state_restore", state_restore_fn)
-        self._sampler_row_fn = self.perf.wrap("sampler_row", sampler_row_fn)
+        self._admit_seed_fn = self.perf.wrap("admit_seed", admit_seed_fn)
         self._deactivate_fn = self.perf.wrap("deactivate", deactivate_fn)
-        self._window_seed_fn = self.perf.wrap("window_seed", window_seed_fn)
         # vision models legitimately double the prefill signature space
         # post-warmup: an image request adds the embeds leaf to the same
         # bucket a text request compiled without it, so armed prefill
@@ -2399,12 +2442,16 @@ class InferenceEngine:
         # scalar or array constructor would be a program of its own
         slot_ = np.int32(slot)
         restore = bool(state_plan) and state_plan["restore"] >= 0
-        # the stage's jitted calls: the sampler row, the restore, and a
-        # window seed for every chunk of the cached prefix
-        self._clock.stage("seed", launches=1 + restore
-                          + -(-cached // self._chunk_len))
-        self.sampling = self._sampler_row_fn(
-            self.sampling, slot_, *SamplingParams.pack_row(upd))
+        # the stage's jitted calls, a fixed number whatever `cached` is:
+        # the sampler row and the window's tail in one program, and the
+        # restore where the family has a second-kind cache
+        self._clock.stage("seed", launches=1 + restore)
+        w = self.config.repeat_window
+        (self.sampling, self.window, self.wlen, self.counts) = (
+            self._admit_seed_fn(
+                self.sampling, self.window, self.wlen, self.counts,
+                _seed_record(slot, upd, ids[max(0, cached - w):cached], w)))
+        _SEED_LAUNCHES.inc(model=self.cfg.name)
         img_flat = self._image_embeds(images) if images else None
         img_tok = self.cfg.vision_cfg.image_token if images else -1
         # counts[slot] is cleared INSIDE prefill_fn / prefill_chunk_fn —
@@ -2415,6 +2462,7 @@ class InferenceEngine:
             self.cache = self._state_restore_fn(
                 self.cache, slot_, np.int32(state_plan["restore"]),
                 np.int32(cached))
+            _SEED_LAUNCHES.inc(model=self.cfg.name)
         if (self._use_mixed or cached
                 or (self._use_chunked and len(ids) > self._chunk_len)):
             # chunked prefill: repeated invocations of ONE fixed-shape
@@ -2422,17 +2470,6 @@ class InferenceEngine:
             # traces, no padding to a distant bucket (VERDICT.md #4). An
             # engine with a mixed step admits every prompt this way
             c = self._chunk_len
-            for s0 in range(0, cached, c):
-                # cached region: repeat-penalty window/counts bookkeeping
-                # only (no model forward, no page writes) so the sampler
-                # state a warm request decodes with is bit-identical to
-                # the cold path's
-                part = ids[s0 : min(s0 + c, cached)]
-                (self.window, self.wlen, self.counts) = self._window_seed_fn(
-                    self.sampling, self.window, self.wlen, self.counts,
-                    _host_i32(part, c), np.int32(s0), np.int32(len(part)),
-                    slot_,
-                )
             for s0 in range(cached, len(ids), c):
                 part = ids[s0 : s0 + c]
                 final = s0 + c >= len(ids)
@@ -2774,7 +2811,8 @@ class InferenceEngine:
             block, n_emit = out
             raw = np.asarray(jax.device_get(block))  # sync-ok
             n_np = np.asarray(jax.device_get(n_emit))  # sync-ok
-            self._fetch_waits.append(self._mark_ingest())
+            self._fetch_waits.append(
+                (self._mark_ingest(), bool(self._inflight)))
             self._count_step_stats(n_np[self.config.max_slots:])
             self._ingest_spec(gen, raw, n_np, dlen)
         _STEP_DURATION.observe(
@@ -2914,16 +2952,34 @@ class InferenceEngine:
         launches; the drafter is still asked after each ingest, and a
         first token the next launch then emits (`_Slot.shadow`) brings
         the series back. (2) A launch in flight delays the next
-        admission's own launch unless the admission's host work outlasts
-        it: the median of the last eight waits at a verify fetch is at
-        most the median of the last eight admissions. In series that wait
-        is the whole launch, ahead the launch less an iteration's host
-        work: a band that wide in which the runner stays as it is, so it
-        does not flap at the boundary. Readings: PERF.md 6, PR 54."""
+        admission's own launch by what the admission's host work does
+        not outlast of it, and the series costs EVERY launch an
+        iteration's host work: ahead while the first is no more than the
+        second, by the medians of the last eight of each (the wait at a
+        verify fetch; an admission; what the phase clock closed of
+        ingest, draft and the launch call from one iteration to the next,
+        read here alone: an admission, a fetch and an idle wait are other
+        phases). Fetched in series, the wait is the whole launch; with
+        another launch in flight, the launch less an iteration's host
+        work: that work is taken off a wait in series, so both schedules
+        judge the wait the launch leaves AHEAD and a switch does not
+        change what is compared. What band is left is the series' wake-up
+        after a launch (1-2 ms), in which the runner stays as it is.
+        Readings: PERF.md 6, PR 54 and PR 60."""
         chain = not getattr(self._drafter, "tree", False)
+        # the iteration behind this one, by the runner's clock: its ingest,
+        # its draft and its launch call, whatever else it did
+        spent = self._clock.spent("ingest", "draft", "dispatch_verify")
+        self._host_works.append(spent - self._host_spent)
+        self._host_spent = spent
+        ahead = False
         if (ahead_ok and chain and self._spec_quiet >= _AHEAD_AFTER
-                and self._admits and median(self._fetch_waits)
-                <= median(self._admits)):
+                and self._admits):
+            host = median(self._host_works)
+            wait = median(w if behind else w - host
+                          for w, behind in self._fetch_waits)
+            ahead = wait - median(self._admits) <= host
+        if ahead:
             depth = max(1, self.config.pipeline_depth)
             slots = self.config.max_slots
             while len(self._inflight) < depth:

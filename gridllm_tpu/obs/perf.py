@@ -455,7 +455,7 @@ class JitProbe:
         A probe's very FIRST signature is always ``warmup`` even when
         armed: a program must compile once to exist, and some entry
         points legitimately run for the first time only after the engine
-        warms (window_seed needs a prefix-cache hit, which requires a
+        warms (state_restore needs a prefix-cache hit, which requires a
         COMPLETED request — the very event that arms the tripwire;
         chunked prefill needs the first long prompt). Only a SECOND
         signature on an armed probe is evidence of shape leakage."""
@@ -1135,6 +1135,11 @@ class PhaseClock:
         if self._unfed_t is not None:
             self._unfed_acc += now - self._unfed_t
             self._unfed_t = None
+
+    def spent(self, *phases: str) -> float:
+        """Seconds closed in `phases` so far, flushed or not; the open
+        phase's running stretch is not in it."""
+        return sum(self.seconds[p] + self._acc[p][0] for p in phases)
 
     def pause(self) -> None:
         clock = self._cpu_clock

@@ -82,6 +82,7 @@ def _topk_candidates(logits: jnp.ndarray, k: int) -> tuple[jnp.ndarray,
 # SamplingParams' fields by dtype: the order of a pack_row record
 _F32_FIELDS = ("temperature", "top_p", "min_p", "repeat_penalty")
 _I32_FIELDS = ("top_k", "repeat_last_n", "seed", "step")
+ROW_LEN = len(_F32_FIELDS) + len(_I32_FIELDS)   # a pack_row record's length
 
 
 @partial(
@@ -121,20 +122,29 @@ class SamplingParams:
         )
 
     @staticmethod
-    def pack_row(values: dict) -> tuple[np.ndarray, np.ndarray]:
+    def pack_row(values: dict) -> np.ndarray:
         """One slot's values (keyed by field name, plain host numbers) as
-        the host record `set_row` takes: the f32 fields and the i32 fields,
-        each in `_F32_FIELDS` / `_I32_FIELDS` order at its final dtype —
-        two small transfers instead of eight scalars."""
-        return (np.array([values[f] for f in _F32_FIELDS], np.float32),
-                np.array([values[f] for f in _I32_FIELDS], np.int32))
+        the host record `set_row` takes: ONE int32 array, the i32 fields
+        in `_I32_FIELDS` order and then the f32 fields' bits in
+        `_F32_FIELDS` order. One array because every host argument of a
+        jitted call is a transfer of its own (0.16 ms each on the
+        benchmark's hosts: PERF.md, PR 60), where eight scalars were
+        eight."""
+        row = np.empty((ROW_LEN,), np.int32)
+        row[:len(_I32_FIELDS)] = [values[f] for f in _I32_FIELDS]
+        row[len(_I32_FIELDS):] = np.array(
+            [values[f] for f in _F32_FIELDS], np.float32).view(np.int32)
+        return row
 
-    def set_row(self, slot, f32, i32) -> "SamplingParams":
+    def set_row(self, slot, row) -> "SamplingParams":
         """Write one slot's row of every field from a `pack_row` record
-        (traceable: the engine runs it as one donated program per
-        admission). Other rows are untouched."""
-        upd = {f: f32[i] for i, f in enumerate(_F32_FIELDS)}
-        upd.update({f: i32[i] for i, f in enumerate(_I32_FIELDS)})
+        (traceable: the engine runs it inside admission's one donated
+        program). Each field lands at its own dtype, the floats bit for
+        bit; other rows are untouched."""
+        n = len(_I32_FIELDS)
+        f32 = jax.lax.bitcast_convert_type(row[n:], jnp.float32)
+        upd = {f: row[i] for i, f in enumerate(_I32_FIELDS)}
+        upd.update({f: f32[i] for i, f in enumerate(_F32_FIELDS)})
         return SamplingParams(**{
             f: getattr(self, f).at[slot].set(v) for f, v in upd.items()})
 

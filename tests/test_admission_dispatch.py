@@ -1,10 +1,12 @@
-"""Admission's device half (ISSUE 25): one admission launches the wrapped
-entry points and the one sampler-row program, and nothing else — no eager
-one-element program per scalar or per sampler field; the row program writes
-exactly the admitted slot's row at each field's dtype; a finished slot's
-`active` flag goes down in one launch; the row program has one signature;
-and a follower that replays the liaison's plan records ends with the same
-sampler rows and active flags."""
+"""Admission's device half (ISSUE 25, ISSUE 60): one admission launches the
+wrapped entry points and the one `admit_seed` program (the sampler row and
+the cached span's tail into the repeat-penalty window: one launch however
+many chunks are cached), and nothing else — no eager one-element program per
+scalar or per sampler field; the program writes exactly the admitted slot's
+row at each field's dtype; a finished slot's `active` flag goes down in one
+launch; the program has one signature; a warm re-ask under a repeat penalty
+streams the cold ask's tokens; and a follower that replays the liaison's
+plan records ends with the same sampler rows and active flags."""
 
 import dataclasses
 
@@ -13,6 +15,7 @@ import pytest
 from jax._src import dispatch as jax_dispatch
 
 from gridllm_tpu.engine import EngineConfig, GenerationRequest, InferenceEngine
+from gridllm_tpu.engine.engine import _SEED_LAUNCHES
 from gridllm_tpu.ops.sampling import SamplingParams
 
 # chunk length 16 (two pages): a 40-token prompt is three chunks, and a
@@ -67,42 +70,50 @@ def test_the_hook_counts_eager_programs():
     assert _eager_dispatches() - n0 >= 1
 
 
-@pytest.mark.parametrize("route", ["bucketed", "chunked_cold", "cache_warm"])
+@pytest.mark.parametrize("route", ["bucketed", "chunked_cold", "cache_warm",
+                                   "cache_warm_long"])
 def test_admission_launches_only_named_programs(route):
-    eng = InferenceEngine(EngineConfig(**TINY, prefix_cache=True))
+    eng = InferenceEngine(EngineConfig(**{
+        **TINY, "max_pages_per_slot": 16}, prefix_cache=True))
     long_ids = [3 + (i % 50) for i in range(40)]
+    chunk = "mixed_chunk" if eng._use_mixed else "prefill_chunk"
     if route == "bucketed":
         # a prompt one bucket would hold: one launch, of the mixed step
         # where the engine has one
         ids, expect = [5] * 10, [
-            "sampler_row", "mixed_chunk" if eng._use_mixed else "prefill"]
+            "admit_seed", "mixed_chunk" if eng._use_mixed else "prefill"]
     elif route == "chunked_cold":
         ids = long_ids
-        chunk = "mixed_chunk" if eng._use_mixed else "prefill_chunk"
-        expect = ["sampler_row"] + [chunk] * 3
+        expect = ["admit_seed"] + [chunk] * 3
     else:
+        if route == "cache_warm_long":
+            # six chunks and a half: the re-ask finds five or more cached
+            long_ids = [3 + (i * 7 % 190) for i in range(100)]
         # the first ask leaves its full pages in the prefix cache
         eng.generate(GenerationRequest(id="cold", raw=True,
                                        prompt_ids=long_ids, options=GREEDY))
         ids = long_ids
-        chunk = "mixed_chunk" if eng._use_mixed else "prefill_chunk"
-        expect = None  # window_seed × cached chunks, then the tail's chunks
+        expect = None   # ONE admit_seed, then the uncached tail's chunks
     calls = _count_launches(eng)
     eng.submit(GenerationRequest(id="probe", raw=True, prompt_ids=ids,
                                  options=GREEDY))
     n0 = _eager_dispatches()
+    seeds0 = _SEED_LAUNCHES.value(model=eng.cfg.name)
     assert eng._try_admit()
     eager = _eager_dispatches() - n0
     st = next(iter(eng._slots.values()))
-    if route == "cache_warm":
+    if expect is None:
         cached = st.cached_tokens
-        assert cached >= 16, "the re-ask must hit the prefix cache"
         c = eng._chunk_len
-        expect = (["sampler_row"] + ["window_seed"] * (-(-cached // c))
-                  + [chunk] * (-(-(len(ids) - cached) // c)))
+        floor = 5 * c if route == "cache_warm_long" else c
+        assert cached >= floor, "the re-ask must hit the prefix cache"
+        expect = ["admit_seed"] + [chunk] * (-(-(len(ids) - cached) // c))
     assert calls == expect
+    # the stage's counter says the same: one jitted call, whatever is cached
+    assert _SEED_LAUNCHES.value(model=eng.cfg.name) - seeds0 == 1
     assert eager == 0, f"{eager} eager one-off programs in one admission"
     _drain(eng)
+    assert eng.perf.state()["admit_seed"]["signatures"] == 1
 
 
 def test_sampler_row_written_at_dtype_and_other_rows_untouched():
@@ -178,9 +189,38 @@ def test_row_program_has_one_signature():
             options={**o, "num_predict": 3}))
         assert res.done_reason == "length"
     state = eng.perf.state()
-    assert state["sampler_row"]["compiles"] == 1
+    assert state["admit_seed"]["compiles"] == 1
     assert state["deactivate"]["compiles"] == 1
     assert all(p["steadyRecompiles"] == 0 for p in state.values()), state
+
+
+@pytest.mark.parametrize("last_n", [8, 64, -1])
+def test_warm_reask_under_a_repeat_penalty_streams_the_cold_tokens(last_n):
+    """The window a cached admission seeds from the span's tail is the one
+    the cold ask built through the model, so a penalised stream cannot tell
+    them apart: the warm re-ask's tokens are the cold ask's, and an engine
+    with no prefix cache gives both."""
+    kw = {**TINY, "max_pages_per_slot": 16}
+    ids = [3 + (i * 7 % 23) for i in range(100)]    # tokens repeat: it bites
+    opts = {"temperature": 0.0, "repeat_penalty": 1.3,
+            "repeat_last_n": last_n, "num_predict": 12}
+
+    def ask(eng, rid):
+        return eng.generate(GenerationRequest(
+            id=rid, raw=True, prompt_ids=ids, options=opts))
+
+    eng = InferenceEngine(EngineConfig(**kw, prefix_cache=True))
+    cold, warm = ask(eng, "cold"), ask(eng, "warm")
+    assert cold.cached_tokens == 0
+    assert warm.cached_tokens >= 5 * eng._chunk_len
+    assert warm.token_ids == cold.token_ids
+    plain = ask(InferenceEngine(EngineConfig(**kw, prefix_cache=False)), "p")
+    assert plain.cached_tokens == 0 and plain.token_ids == cold.token_ids
+    # the penalty is live: without it the same prompt streams other tokens
+    free = eng.generate(GenerationRequest(
+        id="free", raw=True, prompt_ids=ids,
+        options={**opts, "repeat_penalty": 1.0}))
+    assert free.token_ids != cold.token_ids
 
 
 def test_follower_replay_matches_sampler_rows_and_active_flags():

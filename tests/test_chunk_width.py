@@ -17,7 +17,8 @@ import pytest
 
 from gridllm_tpu.engine import EngineConfig, GenerationRequest, InferenceEngine
 from gridllm_tpu.engine import engine as engine_module
-from gridllm_tpu.engine.engine import _CHUNK_LAUNCHES, _CHUNK_TOKENS
+from gridllm_tpu.engine.engine import (_CHUNK_LAUNCHES, _CHUNK_TOKENS,
+                                       _SEED_TAIL)
 from gridllm_tpu.obs.perf import XLA_COMPILE_SECONDS
 
 # pages of 8, chunks of 32, the narrow width 16: a 70-token prompt is two
@@ -30,6 +31,8 @@ GREEDY = {"temperature": 0.0, "num_predict": 6}
 # the shipped first-chunk width is no narrower than a tiny chunk: a test
 # that wants it between the narrow width and the chunk builds with this one
 FIRST = 24
+# admission's one host record: slot, tail length, the sampler row, the tail
+SEED_RECORD = _SEED_TAIL + EngineConfig(**TINY).repeat_window
 
 
 def build(kw: dict, first: int | None = None, **more) -> InferenceEngine:
@@ -56,18 +59,22 @@ def ids(n: int, salt: int = 0) -> list[int]:
     return [3 + (salt + 7 * i) % 200 for i in range(n)]
 
 
-def chunk_widths(eng: InferenceEngine,
-                 names=("mixed_chunk", "prefill_chunk", "window_seed"),
-                 ) -> list[tuple[str, int]]:
+CHUNKS = ("mixed_chunk", "prefill_chunk")
+# with the admission's one seed launch, recorded at its host record's width
+WITH_SEED = CHUNKS + ("admit_seed",)
+
+
+def chunk_widths(eng: InferenceEngine, names=CHUNKS) -> list[tuple[str, int]]:
     """Record (program, chunk width) of every chunk-shaped launch: the
-    chunk programs and ``window_seed``; or of ``names`` (``prefill``: the
-    bucket)."""
+    chunk programs; or of ``names`` (``prefill``: the bucket;
+    ``admit_seed``: its one host record, the slot's row and the cached
+    span's tail at ``repeat_window``)."""
     seen: list[tuple[str, int]] = []
     for name in names:
         probe = eng.perf._probes.get(name)
         if probe is None:
             continue
-        at = 4 if name == "window_seed" else 1
+        at = 4 if name == "admit_seed" else 1
 
         def counted(*a, _fn=probe._fn, _name=name, _at=at, **kw):
             seen.append((_name, int(a[_at].shape[0])))
@@ -77,7 +84,7 @@ def chunk_widths(eng: InferenceEngine,
 
 
 def model_launches(seen) -> list[int]:
-    return [w for name, w in seen if name != "window_seed"]
+    return [w for name, w in seen if name != "admit_seed"]
 
 
 def drain(eng: InferenceEngine) -> None:
@@ -165,7 +172,7 @@ def test_tokens_and_window_match_a_one_width_engine(case):
         if case == "cached_reask":
             eng.generate(GenerationRequest(
                 id="first", raw=True, prompt_ids=prompt, options=GREEDY))
-        seen = chunk_widths(eng)
+        seen = chunk_widths(eng, WITH_SEED)
         eng.submit(GenerationRequest(id=case, raw=True, prompt_ids=prompt,
                                      options={**GREEDY, "repeat_last_n": 48}))
         assert eng._try_admit()
@@ -174,6 +181,9 @@ def test_tokens_and_window_match_a_one_width_engine(case):
                  for x in (eng.window, eng.wlen, eng.counts)]
         drain(eng)
         assert st.cached_tokens == (64 if case == "cached_reask" else 0)
+        # one seed launch, two cached chunks or none
+        assert seen[0] == ("admit_seed", SEED_RECORD)
+        assert len(seen) == 1 + len(model_launches(seen))
         out[kind] = (list(st.generated), state, model_launches(seen))
     (tok_l, state_l, w_l), (tok_o, state_o, w_o) = out["ladder"], out["one"]
     assert tok_l == tok_o and len(tok_l) == 6
@@ -288,7 +298,7 @@ def test_an_empty_raw_prompt_is_its_bos():
 
 def test_prewarm_compiles_every_width_with_three_requests():
     eng = build(LADDER, first=FIRST, prefix_cache=True)
-    seen = chunk_widths(eng)
+    seen = chunk_widths(eng, WITH_SEED)
     sent: list[int] = []
     generate = eng.generate
 
@@ -302,15 +312,18 @@ def test_prewarm_compiles_every_width_with_three_requests():
     eng.generate = generate
     # a first chunk's width, then chunk + 1 twice: no bucket
     assert sent == [24, 33, 33]
-    # the first width; a full chunk and a one-token last chunk, cold; then
-    # the cached prefix through window_seed and the same one token behind it
-    assert seen == [("mixed_chunk", 24), ("mixed_chunk", 32), ("mixed_chunk", 16),
-                    ("window_seed", 32), ("mixed_chunk", 16)]
+    # each admission's one seed launch; the first width; a full chunk and a
+    # one-token last chunk, cold; then the cached prefix's tail in the seed
+    # and the same one token behind it
+    seed = ("admit_seed", SEED_RECORD)
+    assert seen == [seed, ("mixed_chunk", 24),
+                    seed, ("mixed_chunk", 32), ("mixed_chunk", 16),
+                    seed, ("mixed_chunk", 16)]
     assert _CHUNK_LAUNCHES.value(model=name, width="32") - before["32"] == 1
     assert _CHUNK_LAUNCHES.value(model=name, width="16") - before["16"] == 2
     state = eng.perf.state()
     assert state["mixed_chunk"]["signatures"] == 3
-    assert state["window_seed"]["signatures"] == 1
+    assert state["admit_seed"]["signatures"] == 1
     assert state["prefill"]["signatures"] == 0
 
 
@@ -423,7 +436,8 @@ def test_follower_replays_the_liaisons_widths():
     follower = build(LADDER, first=FIRST, prefix_cache=True)
     records: list[dict] = []
     liaison.plan_sink = records.append
-    led, followed = chunk_widths(liaison), chunk_widths(follower)
+    led, followed = (chunk_widths(liaison, WITH_SEED),
+                     chunk_widths(follower, WITH_SEED))
     # cold with a short tail, its re-ask, a tail too long for the narrow
     # width, a prompt that ends on a chunk boundary, one the first width
     # holds and one it does not
@@ -437,6 +451,7 @@ def test_follower_replays_the_liaisons_widths():
         follower.apply_plan_op(rec)
     assert followed == led
     assert model_launches(led) == [32, 32, 16, 16, 32, 32, 32, 32, 32, 24, 32]
+    assert len(led) - len(model_launches(led)) == len(admits)   # a seed each
     np.testing.assert_array_equal(np.asarray(follower.tokens),
                                   np.asarray(liaison.tokens))
     np.testing.assert_array_equal(np.asarray(follower.window),

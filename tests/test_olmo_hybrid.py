@@ -486,13 +486,18 @@ def test_a_reasked_prefix_is_admitted_from_pages_and_a_snapshot(
     both sides of 8 with a 8-byte question, so the first asker's own last
     page boundary is the match's (96) or the one after (112). Either way
     the re-ask restores at 96 and says what a cold admission says."""
+    from gridllm_tpu.engine.engine import _SEED_LAUNCHES
+
     eng = _engine()
     doc = (WORDS * 2)[:doc_len]
     hits = _state_counts(outcome="hit")
+    seeds = _SEED_LAUNCHES.value(model="tiny-olmo-hybrid")
     first = _ask(eng, "a", doc + " one two")
     again = _ask(eng, "b", doc + " six ten")
     assert first.cached_tokens == 0 and again.cached_tokens == 96
     assert _state_counts(outcome="hit") == hits + 1
+    # the seed stage's jitted calls: admit_seed twice, the one restore
+    assert _SEED_LAUNCHES.value(model="tiny-olmo-hybrid") - seeds == 3
     assert again.token_ids == _ask(cold_engine, "c", doc + " six ten").token_ids
     assert first.token_ids == _ask(cold_engine, "d", doc + " one two").token_ids
 

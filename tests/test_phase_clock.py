@@ -327,8 +327,8 @@ def test_no_trace_annotation_is_constructed_with_no_capture_active(made):
     assert prefill.meta["prompt_tokens"] > 0 and "cached_tokens" in prefill.meta
     assert set(prefill.meta) == {"request", "prompt_tokens", "cached_tokens"}
     seed = next(s for s in made if s.name == "gridllm.dispatch_prefill.seed")
-    # the sampler row, and a window-seed launch where r0's pages were found
-    assert set(seed.meta) == {"launches"} and seed.meta["launches"] in (1, 2)
+    # admit_seed alone, r0's pages found or not: this family restores nothing
+    assert seed.meta == {"launches": 1}
     chunk = next(s for s in made if s.name == "gridllm.dispatch_prefill.chunk")
     assert {"width", "start"} <= set(chunk.meta)
     assert any("tokens" in s.meta for s in made if s.name == "gridllm.ingest")

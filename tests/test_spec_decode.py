@@ -5,7 +5,9 @@ pages), n-gram drafter units, and zero steady-state recompiles with
 speculation armed (reusing the PR-4 tripwire harness)."""
 
 import threading
+import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -917,7 +919,8 @@ def test_launches_longer_than_an_admission_stay_in_series():
     seen, a0 = _watch_dispatch(eng), _launches("ahead")
     (res, _frames), = _serve(eng, [_req("slow", "hello world", 60)]).values()
     assert eng._spec_quiet >= engine_mod._AHEAD_AFTER
-    assert 0 < max(eng._admits) < min(eng._fetch_waits)
+    assert 0 < max(eng._admits) < min(w for w, _behind in eng._fetch_waits)
+    assert not any(behind for _w, behind in eng._fetch_waits)
     assert _launches("ahead") == a0 and not any(n for n, _d in seen)
     assert res.token_ids == _spec_engine().generate(
         _req("ref", "hello world", 60)).token_ids
@@ -925,16 +928,23 @@ def test_launches_longer_than_an_admission_stay_in_series():
 
 def test_launch_time_walked_across_admission_time_enters_stays_and_leaves(
         monkeypatch):
-    """The second condition's band. A launch of `launch` seconds is read
-    whole at a fetch in series and less an iteration's host work (4 ms
-    here) ahead, against admissions of 10 ms: the runner enters at launch
-    <= 10, stays ahead up to 14, leaves above, and once in series stays
-    there down to 10. Twelve launches a step of the walk; the median of
-    eight has turned by the sixth."""
+    """The second condition's band. A launch of `launch` seconds is read at
+    a fetch in series whole and with the runner's wake-up behind it (2 ms
+    here), and less an iteration's host work (4 ms) with another launch in
+    flight, against admissions of 10 ms. Either way the runner judges the
+    wait the launch leaves ahead, and runs ahead while that is past an
+    admission by no more than the host's 4 ms: it enters at launch <= 16
+    (the series' reading carries the wake-up), stays ahead up to 18, leaves
+    above, and once in series stays there down to 16. At 17, inside the
+    band, it stays as it is from either side and never alternates. (Until
+    ISSUE 60 the series' whole wait was held against the admission alone:
+    the band was 10 to 14.) Twelve launches a step of the walk; the median
+    of eight has turned by the sixth."""
     from collections import deque
 
-    walk = ((0.020, "serial"), (0.008, "ahead"), (0.012, "ahead"),
-            (0.016, "serial"), (0.012, "serial"), (0.008, "ahead"))
+    walk = ((0.024, "serial"), (0.008, "ahead"), (0.017, "ahead"),
+            (0.020, "serial"), (0.017, "serial"), (0.012, "ahead"),
+            (0.030, "serial"))
     monkeypatch.setattr(engine_mod, "_AHEAD_AFTER", 2)
     eng = _spec_engine()
     seen, launch, mark = [], eng._launch_verify, eng._mark_ingest
@@ -944,23 +954,77 @@ def test_launch_time_walked_across_admission_time_enters_stays_and_leaves(
 
     def launching(drafts, dlen, mode):
         eng._admits = deque([0.010] * 8, maxlen=8)
+        # seven of eight: _step_spec adds this CPU's own reading to them
+        eng._host_works = deque([0.004] * 8, maxlen=8)
         seen.append((step(), mode))
         launch(drafts, dlen, mode)
 
     def marking():
         mark()
         # called with the fetched launch popped: another still in flight
-        # is the runner ahead of the device
-        return walk[step()][0] - (0.004 if eng._inflight else 0.0)
+        # is the runner ahead of the device; with none, it was woken
+        return walk[step()][0] + (-0.004 if eng._inflight else 0.002)
 
     eng._launch_verify, eng._mark_ingest = launching, marking
     n = 12 * len(walk) + 4
     (res, _frames), = _serve(eng, [_req("w", "hello world", n)]).values()
-    for i, (_launch_s, mode) in enumerate(walk):
+    for i, (launch_s, mode) in enumerate(walk):
         late = [m for at, m in seen[12 * i + 7:12 * i + 12] if at == i]
         assert late and set(late) == {mode}, (i, seen[12 * i:12 * i + 12])
+        if launch_s == 0.017:       # in the band: as it was, every launch
+            assert {m for _at, m in seen[12 * i:12 * i + 12]} == {mode}, i
     assert res.token_ids == _spec_engine().generate(
         _req("ref", "hello world", n)).token_ids
+
+
+def test_an_iterations_host_work_is_its_ingest_draft_and_launch_alone(
+        monkeypatch):
+    """What `_step_spec` holds a wait against: `_host_works` gets one
+    reading an iteration, what the phase clock closed of ingest, draft and
+    the launch call since the iteration before. An admission's host work
+    (0.5 s each here), the wait at a fetch (0.1 s a launch) and the
+    runner's idle wait between two requests (0.5 s) are other phases, and
+    no reading holds any of them."""
+    from collections import deque
+
+    eng = _spec_engine()
+    _warm(eng)                  # every program compiled
+    works = eng._host_works = deque()       # every reading, not the last 8
+    admit, spec, calls = eng._dispatch_prefill, eng._step_spec, []
+
+    def admitting(*a, **kw):
+        time.sleep(0.5)
+        return admit(*a, **kw)
+
+    def stepping(ahead_ok=False):
+        calls.append(len(works))
+        spec(ahead_ok)
+
+    def waiting(out, wait=jax.block_until_ready):
+        time.sleep(0.1)
+        return wait(out)
+
+    eng._dispatch_prefill, eng._step_spec = admitting, stepping
+    monkeypatch.setattr(engine_mod.jax, "block_until_ready", waiting)
+    spent0 = eng._clock.spent("ingest", "draft", "dispatch_verify")
+    a0 = len(eng._admits)
+    _serve(eng, [_req("h0", "hello world", 12)])
+    time.sleep(0.5)             # the runner stopped: nobody's time
+    eng.start()                 # and idles until a request comes
+    try:
+        time.sleep(0.5)
+    finally:
+        eng.stop()
+    _serve(eng, [_req("h1", "xyzzy", 12), _req("h2", "ab ab ab", 9)])
+    assert calls == list(range(len(calls))) and len(calls) >= 20
+    assert len(works) == len(calls)
+    assert max(works) < 0.1, sorted(works)[-3:]
+    spent = eng._clock.spent("ingest", "draft", "dispatch_verify")
+    # the readings are the clock's: all of it up to the last iteration
+    assert sum(works) == pytest.approx(spent - spent0, abs=0.05)
+    assert eng._clock.seconds["dispatch_prefill"] >= 0.5 * 3
+    assert eng._clock.seconds["fetch"] >= 0.1 * len(calls)
+    assert len(eng._admits) - a0 == 3
 
 
 def test_step_never_leaves_a_launch_in_flight():
