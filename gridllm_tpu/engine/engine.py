@@ -453,6 +453,10 @@ def _model_module(cfg: ModelConfig):
         from gridllm_tpu.models import longcat_flash
 
         return longcat_flash
+    if cfg.family == "granite_hybrid":
+        from gridllm_tpu.models import granite_hybrid
+
+        return granite_hybrid
     if cfg.family == "bert_embed":
         from gridllm_tpu.models import bert_embed
 
@@ -1333,30 +1337,18 @@ class InferenceEngine:
         keeps the model's dim (tests stay fast) unless GRIDLLM_POOL_PAD=1
         forces the padded layout for coverage. The ops dispatchers
         pad/slice at the boundary."""
-        from gridllm_tpu.ops.kvcache import (
-            _pallas_mode,
-            flat_lanes_ok,
-            lane_pad_dim,
-            local_kv_heads,
-        )
+        from gridllm_tpu.ops.kvcache import _pallas_mode, lane_pad_dim
 
-        # a latent family's row (cfg.cache_dim: 576 at DeepSeek-V2-Lite)
-        # has one cache head and no flat-lane view: Mosaic tiles the HBM
-        # page [ps, 576] at 640 lanes and refuses to slice 576 of them, so
-        # the row is stored lane-padded (PERF.md, PR 36)
+        # no layout keeps a narrower head unpadded where kernels compile:
+        # Mosaic tiles a [.., KVH, 64] page at 128 lanes whatever its
+        # shape says and refuses every kernel's slice of 64 of them
+        # (tests/test_granite_hybrid.py compiles the ragged kernel and
+        # the three writes for a described v5e both ways, PR 61); a
+        # latent family's row (cfg.cache_dim: 576 at DeepSeek-V2-Lite) is
+        # tiled at 640 lanes likewise (PERF.md, PR 36)
         d = self.cfg.cache_dim
         use, interpret = _pallas_mode(self.cfg.use_pallas)
-        if not use:
-            return d
-        if interpret and not env_bool("GRIDLLM_POOL_PAD"):
-            return d
-        kvh = local_kv_heads(self.cfg.cache_heads, self.mesh)
-        if flat_lanes_ok(kvh, d):
-            # flat-lane layout: page rows are lane-aligned viewed
-            # flat ([ps, KVH*D] — PER tp SHARD, where kv heads split), so
-            # the ragged kernel and the DMA write kernels run on the
-            # UNPADDED pool — the lane-pad KV-byte overhead /admin/memory
-            # itemized drops to zero
+        if not use or (interpret and not env_bool("GRIDLLM_POOL_PAD")):
             return d
         return lane_pad_dim(d)
 
@@ -4010,8 +4002,10 @@ class InferenceEngine:
                 "expertsHeld": self.cfg.experts_held,
                 "windowLayers": self._windowed,
                 "cacheRow": "+".join(self.cfg.cache_kinds),
-                "attnForm": "+".join(_ATTN_FORMS[k]
-                                     for k in self.cfg.cache_kinds),
+                # a state without the delta (keys a group) is a scan
+                "attnForm": "+".join(
+                    "scan" if k == "state" and self.cfg.linear_groups
+                    else _ATTN_FORMS[k] for k in self.cfg.cache_kinds),
             },
             # the runner's wall time so far, phase by phase (a wedged
             # runner shows in the dump as one phase that stopped growing)
@@ -4063,14 +4057,13 @@ class InferenceEngine:
             "cachedBytes": int(self.alloc.cached_pages * bpp),
             "freeBytes": int(self.alloc.free_pages * bpp),
             # lane padding multiplies KV bytes for d<128 models under the
-            # kernel path (_pool_head_dim) — this is that overhead's share.
-            # Under the ragged flat-lane layout (kvLayout "ragged") the
-            # pool stays UNPADDED, so this reads 0
+            # kernel path (_pool_head_dim) — this is that overhead's share
+            # (0 for an unpadded pool, kvLayout "ragged")
             "lanePadOverheadBytes": int(
                 kv_bytes * (1 - mc.cache_dim / dpool)) if dpool else 0,
-            # "ragged" = an unpadded pool (the zero-overhead case the
-            # README documents); "ragged-padded" = the shape can't go
-            # flat-lane (e.g. KVH=1, d=64), so the pool still pays the pad
+            # "ragged" = an unpadded pool (a head of whole lane tiles, or
+            # no compiled kernels); "ragged-padded" = a narrower head
+            # stored at 128 lanes for the kernels
             "kvLayout": (
                 "ragged" if dpool == mc.cache_dim else "ragged-padded"),
             # what a token's row of one layer is: K and V per KV head, or

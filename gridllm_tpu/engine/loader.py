@@ -86,7 +86,7 @@ def load_checkpoint(
     from gridllm_tpu.models import hf_layout
     from gridllm_tpu.ops.quant import NO_QUANT_SUBTREES, quantize_np_leaf
 
-    if cfg.family in ("kimi_linear", "longcat_flash"):
+    if cfg.family in ("kimi_linear", "longcat_flash", "granite_hybrid"):
         raise NotImplementedError(
             f"{cfg.name}: {cfg.family} checkpoints are not read (its "
             "equations are written from the published keys and the report, "

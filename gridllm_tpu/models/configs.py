@@ -48,7 +48,7 @@ class ModelConfig:
     name: str
     # llama | qwen2 | qwen3 | gemma2 | mixtral | smallthinker |
     # deepseek_v2 | olmo_hybrid | laguna | kimi_linear | longcat_flash |
-    # llava | bert_embed
+    # granite_hybrid | llava | bert_embed
     # (engine._model_module picks the module)
     family: str = "llama"
     vocab_size: int = 128_256
@@ -143,6 +143,19 @@ class ModelConfig:
     linear_conv_kernel: int = 0
     linear_allow_neg_eigval: bool = False
     linear_channel_decay: bool = False
+    # a state-space layer (granite_hybrid's Mamba-2) is a linear layer
+    # WITHOUT the delta: its keys and queries (B and C) are one a GROUP of
+    # heads, not one a head (linear_groups of them, 0 = a head's own)
+    linear_groups: int = 0
+    # the granite family's four multipliers: the embedding times
+    # embedding_multiplier, each block's two residual branches times
+    # residual_multiplier, attention scores times attention_multiplier
+    # (that family's reader requires it; no other family reads it) and the
+    # logits DIVIDED by logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     # attention variants
     attn_logit_softcap: float = 0.0
     sliding_window: int = 0          # 0 → full attention
@@ -351,9 +364,11 @@ class ModelConfig:
 
     @property
     def conv_channels(self) -> int:
-        """Channels the linear layers' convolution runs over: q, k, v."""
-        return self.linear_num_heads * (
-            2 * self.linear_key_head_dim + self.linear_value_head_dim)
+        """Channels the linear layers' convolution runs over: q, k, v (a
+        state-space layer's x, B, C: keys and queries a group)."""
+        kq = self.linear_groups or self.linear_num_heads
+        return (2 * kq * self.linear_key_head_dim
+                + self.linear_num_heads * self.linear_value_head_dim)
 
     @property
     def layer_windows(self) -> tuple[int, ...]:
@@ -644,6 +659,26 @@ register(ModelConfig(
     linear_num_heads=30, linear_key_head_dim=96, linear_value_head_dim=192,
     linear_conv_kernel=4, linear_allow_neg_eigval=True,
 ))
+# granite-4.0-h-micro (ibm-granite, config.json): Mamba-2 layers 9:1 with
+# attention without positions (32 heads of 64 over 8 KV heads, scores
+# times 1/64), the attention layer at place 5 of each ten; 64 state-space
+# heads of 64 with a state of 128, B and C one group, a convolution of 4
+# with a bias; a shared SwiGLU of 8,192, no routed expert; the family's
+# four multipliers; the head tied to the embedding. rope_theta is the
+# published key's value and rotates nothing (position_embedding_type nope)
+_GRANITE_H_PERIOD = (("linear_attention",) * 5 + ("full_attention",)
+                     + ("linear_attention",) * 4)
+register(ModelConfig(
+    name="granite4:h-micro", family="granite_hybrid", vocab_size=100_352,
+    hidden_size=2048, intermediate_size=8192, num_layers=40, num_heads=32,
+    num_kv_heads=8, head_dim=64, rope_theta=10_000.0, rms_eps=1e-5,
+    tie_embeddings=True, max_seq_len=131_072, experts_per_token=0,
+    layer_types=_GRANITE_H_PERIOD * 4,
+    linear_num_heads=64, linear_key_head_dim=128, linear_value_head_dim=64,
+    linear_conv_kernel=4, linear_groups=1,
+    embedding_multiplier=12.0, residual_multiplier=0.22,
+    attention_multiplier=0.015625, logits_scaling=8.0,
+))
 # Laguna-XS.2 (poolside, config.json): layer 0 global with a dense SwiGLU
 # of 8,192, then window layers (512 keys, 64 heads, RoPE 10,000 on the
 # whole head) 3:1 with global ones (48 heads, YaRN x64 on half a head),
@@ -839,6 +874,23 @@ register(ModelConfig(
     mla_scale_kv_lora=True, attn_sublayers=2,
     experts_held=4, experts_first=4,
 ))
+# granite-hybrid's shape in small: two periods of `m m A m` (the attention
+# layer inside the period, not at its end), every multiplier different
+# from 1 and the attention scale not head_dim^-0.5, one B/C group, four
+# state-space heads of 32 (4 x 32 = one lane tile) over a state of 16,
+# 4 query heads over 2 KV heads, the head tied
+register(ModelConfig(
+    name="tiny-granite-hybrid", family="granite_hybrid", vocab_size=256,
+    hidden_size=64, intermediate_size=128, num_layers=8, num_heads=4,
+    num_kv_heads=2, head_dim=16, rope_theta=10_000.0, rms_eps=1e-5,
+    tie_embeddings=True, max_seq_len=256, experts_per_token=0,
+    layer_types=(("linear_attention",) * 2 + ("full_attention",)
+                 + ("linear_attention",)) * 2,
+    linear_num_heads=4, linear_key_head_dim=16, linear_value_head_dim=32,
+    linear_conv_kernel=4, linear_groups=1,
+    embedding_multiplier=3.0, residual_multiplier=0.4,
+    attention_multiplier=0.125, logits_scaling=2.0,
+))
 register(ModelConfig(
     name="tiny-qwen2", family="qwen2", vocab_size=256, hidden_size=64,
     intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -916,6 +968,7 @@ _HF_FAMILY = {
     "laguna": "laguna",
     "kimi_linear": "kimi_linear",
     "longcat_flash": "longcat_flash",
+    "granitemoehybrid": "granite_hybrid",
     "bert": "bert_embed",
 }
 
@@ -1264,6 +1317,70 @@ def _longcat_flash_from_hf(name: str, hf: dict, path: str) -> ModelConfig:
     )
 
 
+def _granite_hybrid_from_hf(name: str, hf: dict, path: str) -> ModelConfig:
+    """GraniteMoeHybrid's published keys: Mamba-2 layers (`mamba_*`) among
+    attention layers (`layer_types`: "mamba" / "attention"), the family's
+    four multipliers, a shared SwiGLU of `shared_intermediate_size`.
+    Refused by name, not run wrong: routed experts beside the shared MLP
+    (`num_local_experts` > 0: no configuration proves that part), more
+    B / C groups than one, a positional embedding, a bias on a projection,
+    another activation or norm, an inner width that is not heads x head, no
+    attention multiplier (the family has no head^-0.5 to fall back to)."""
+    heads, dh = hf["mamba_n_heads"], hf["mamba_d_head"]
+    kinds = {"mamba": "linear_attention", "attention": "full_attention"}
+    unserved = {
+        "num_local_experts": bool(hf.get("num_local_experts")),
+        "mamba_n_groups": hf.get("mamba_n_groups", 1) != 1,
+        "position_embedding_type":
+            hf.get("position_embedding_type", "nope") != "nope",
+        "attention_bias": bool(hf.get("attention_bias")),
+        "mamba_proj_bias": bool(hf.get("mamba_proj_bias")),
+        "mamba_conv_bias": not hf.get("mamba_conv_bias", True),
+        "hidden_act": hf.get("hidden_act", "silu") != "silu",
+        "normalization_function":
+            hf.get("normalization_function", "rmsnorm") != "rmsnorm",
+        "mamba_expand": hf["mamba_expand"] * hf["hidden_size"] != heads * dh,
+        "layer_types": not set(hf["layer_types"]) <= set(kinds),
+        "attention_multiplier": not hf.get("attention_multiplier"),
+    }
+    if any(unserved.values()):
+        raise ValueError(
+            f"{path}: granitemoehybrid with "
+            f"{[k for k, v in unserved.items() if v]} as published is not "
+            "served (no routed experts, one B/C group, no positional "
+            "embedding, no projection bias, a convolution bias, silu, "
+            "rmsnorm, expand x hidden = heads x head, an attention "
+            "multiplier)")
+    return ModelConfig(
+        name=name, family="granite_hybrid",
+        vocab_size=hf["vocab_size"],
+        hidden_size=hf["hidden_size"],
+        intermediate_size=hf["shared_intermediate_size"],
+        num_layers=hf["num_hidden_layers"],
+        num_heads=hf["num_attention_heads"],
+        num_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf.get("head_dim")
+        or hf["hidden_size"] // hf["num_attention_heads"],
+        # read and not used: position_embedding_type is nope, and no
+        # other is served (the published file carries 10000 all the same)
+        rope_theta=hf.get("rope_theta", 10_000.0),
+        rms_eps=hf.get("rms_norm_eps", 1e-5),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        max_seq_len=hf.get("max_position_embeddings", 131_072),
+        experts_per_token=0,
+        layer_types=tuple(kinds[t] for t in hf["layer_types"]),
+        linear_num_heads=heads,
+        linear_key_head_dim=hf["mamba_d_state"],
+        linear_value_head_dim=dh,
+        linear_conv_kernel=hf["mamba_d_conv"],
+        linear_groups=hf.get("mamba_n_groups", 1),
+        embedding_multiplier=float(hf.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(hf.get("residual_multiplier", 1.0)),
+        attention_multiplier=float(hf["attention_multiplier"]),
+        logits_scaling=float(hf.get("logits_scaling", 1.0)),
+    )
+
+
 def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
     mt = hf.get("model_type", "llama")
     if mt == "llava":
@@ -1350,6 +1467,8 @@ def _config_from_hf_dict(name: str, hf: dict, path: str) -> ModelConfig:
         return _kimi_linear_from_hf(name, hf, path)
     if family == "longcat_flash":
         return _longcat_flash_from_hf(name, hf, path)
+    if family == "granite_hybrid":
+        return _granite_hybrid_from_hf(name, hf, path)
     scaling = None
     rs = hf.get("rope_scaling") or None
     if rs and rs.get("rope_type", rs.get("type")) == "llama3":

@@ -501,14 +501,12 @@ def ragged_paged_attention(
     use, interpret = _pallas_mode(use_pallas)
     mode, ax = kernel_mesh_axis(mesh, kvh, h)
     # per-SHARD head count: under tp the kernel runs inside a shard_map
-    # with kv heads split, so both the lane and VMEM gates must look at
-    # what one shard actually sees
+    # with kv heads split, so the VMEM gate must look at what one shard
+    # actually sees
     kvh_local = kvh // mesh.shape["tp"] if ax == "tp" else kvh
-    # Mosaic lane alignment: either classic 128-lane head dim, or the
-    # ragged flat-lane layout — page rows viewed as [ps, KVH*D], aligned
-    # whenever the SHARD's KVH*D divides the lane tile (d=64 models with
-    # enough kv heads per shard)
-    lanes_ok = interpret or d % 128 == 0 or (kvh_local * d) % 128 == 0
+    # Mosaic lane alignment: a head dim of whole 128-lane tiles (the
+    # engine stores a narrower head lane-padded where kernels compile)
+    lanes_ok = interpret or d % 128 == 0
     chunk_ok = True
     if q_chunk is not None:
         c = q_chunk.shape[1]

@@ -113,6 +113,29 @@ KERNELS: tuple[KernelSpec, ...] = (
                     "into the state in place, the new rows run on top",
     ),
     KernelSpec(
+        name="ssd_chunk",
+        reference="linear_attn:ssd_recurrent",
+        dispatch="ssd_chunk",
+        rtol=2e-3, atol=2e-3,
+        test="tests/test_granite_hybrid.py::test_ssd_chunk_matches_recurrent",
+        description="the state-space scan (the rule without the delta, B "
+                    "and C shared by every head) over one slot's prompt "
+                    "rows in blocks of 64 from a carried state: one "
+                    "product at the packed width a lane tile of 1,024, "
+                    "the state at chosen blocks' ends handed back",
+    ),
+    KernelSpec(
+        name="ssd_step",
+        reference="linear_attn:ssd_recurrent",
+        dispatch="ssd_step",
+        rtol=2e-3, atol=2e-3,
+        test="tests/test_granite_hybrid.py::test_ssd_step_matches_recurrent",
+        description="the state-space scan over a launch's 1 to K+1 rows of "
+                    "every live slot: the last launch's accepted rows "
+                    "committed into the state in place, the new rows read "
+                    "on top; one read and one write of a live slot's state",
+    ),
+    KernelSpec(
         name="grouped_experts",
         reference="experts:grouped_experts_ref",
         dispatch="grouped_experts",

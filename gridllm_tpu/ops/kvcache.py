@@ -191,41 +191,11 @@ def _shard_map_kernel(mesh, body, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
-def flat_lanes_ok(kvh: int, d: int) -> bool:
-    """True when a page's rows are lane-aligned VIEWED FLAT ([ps, KVH*D])
-    even though d alone is not — the ragged layout's trick (ISSUE 6):
-    pages are contiguous, so the page DMA moves tile-aligned flat rows
-    without padding D itself (the ragged kernel lane-pads the LOADED
-    values in-register before its dots). d=64 models with KVH >= 2 per
-    shard keep the kernel write/attention paths on an UNPADDED pool
-    (half the KV bytes of the lane-padded layout).
-
-    `kvh` must be the PER-SHARD head count: under tp the kernels run
-    inside a full-manual shard_map with kv heads split over "tp"
-    (kernel_mesh_axis), so each shard's page rows are (kvh/tp)*D lanes —
-    callers divide before asking (see local_kv_heads)."""
-    return (kvh * d) % 128 == 0
-
-
-def local_kv_heads(kvh: int, mesh) -> int:
-    """KV heads per kernel shard: kvh/tp when the tp axis will split the
-    head dim (the same divisibility rule kernel_mesh_axis applies),
-    otherwise the full count (no mesh, or indivisible heads replicate)."""
-    if mesh is None:
-        return kvh
-    tp = mesh.shape.get("tp", 1)
-    return kvh // tp if tp > 1 and kvh % tp == 0 else kvh
-
-
-def _write_lane_gate(k_pages, ax, mesh, interpret: bool) -> bool:
-    """Mosaic lane-alignment gate for the pool-write kernels: classic
-    128-lane head dim, or the ragged flat-lane row view — checked at the
-    PER-SHARD head count when `ax` says tp will split heads."""
-    d = k_pages.shape[-1]
-    kvh = k_pages.shape[-2]
-    if ax == "tp":
-        kvh //= mesh.shape["tp"]
-    return interpret or d % 128 == 0 or flat_lanes_ok(kvh, d)
+def _write_lane_gate(k_pages, interpret: bool) -> bool:
+    """Mosaic lane-alignment gate for the pool-write kernels: a head dim
+    of whole 128-lane tiles (a narrower head is stored lane-padded where
+    kernels compile: `lane_pad_dim`), or interpreted."""
+    return interpret or k_pages.shape[-1] % 128 == 0
 
 
 def lane_pad_dim(d: int) -> int:
@@ -319,9 +289,18 @@ class RecurrentState:
     # array is stored at 16 rows, five times the bytes)
     conv: jnp.ndarray        # [Ll, S, (K-1)*C]: rows before the next one
     pend_x: jnp.ndarray      # [Ll, S, T*C] the rows before the convolution
+    # keys a head, or a GROUP of heads where a family says so (`groups`:
+    # a state-space layer's B, [.., T, 1, dk]; C*, the convolution's
+    # channels, is then 2 G dk + H dv)
     pend_k: jnp.ndarray      # [Ll, S, T, H, dk] float32, normalised
-    pend_v: jnp.ndarray      # [Ll, S, T, H, dv] float32
-    pend_b: jnp.ndarray      # [Ll, S, T, H] float32 beta
+    pend_v: jnp.ndarray      # [Ll, S, T, H, dv] float32 ([Ll, S, T*H*dv],
+    #                          rows and heads side by side on the lanes as
+    #                          pend_x lies, with `groups`: a [.., 5, 64, 64]
+    #                          tail is tiled at 8 rows and 128 lanes, three
+    #                          times the bytes, and XLA relays it to put
+    #                          the slots under the rows, twice a launch)
+    pend_b: jnp.ndarray      # [Ll, S, T, H] float32 beta ([.., 0] where
+    #                          the rule has no delta)
     pend_g: jnp.ndarray      # [Ll, S, T, H] float32 log decay ([.., H, dk]
     #                          where it is a value a key channel)
     pend_n: jnp.ndarray      # [S] int32 pending rows that count
@@ -332,17 +311,25 @@ class RecurrentState:
     def create(layers: int, slots: int, heads: int, dk: int, dv: int,
                conv_kernel: int, step_rows: int, snapshots: int,
                dtype=jnp.bfloat16,
-               channel_decay: bool = False) -> "RecurrentState":
-        c = heads * (2 * dk + dv)
+               channel_decay: bool = False,
+               groups: int | None = None) -> "RecurrentState":
+        """`groups`: keys and queries one a group of heads and no delta
+        (a state-space scan); None: one a head, with a beta."""
+        kq = heads if groups is None else groups
+        c = 2 * kq * dk + heads * dv
         f32 = jnp.float32
         decay = (dk,) if channel_decay else ()
         return RecurrentState(
             state=jnp.zeros((layers, slots, dk, heads * dv), f32),
             conv=jnp.zeros((layers, slots, (conv_kernel - 1) * c), dtype),
             pend_x=jnp.zeros((layers, slots, step_rows * c), dtype),
-            pend_k=jnp.zeros((layers, slots, step_rows, heads, dk), f32),
-            pend_v=jnp.zeros((layers, slots, step_rows, heads, dv), f32),
-            pend_b=jnp.zeros((layers, slots, step_rows, heads), f32),
+            pend_k=jnp.zeros((layers, slots, step_rows, kq, dk), f32),
+            pend_v=jnp.zeros(
+                (layers, slots, *((step_rows, heads, dv) if groups is None
+                                  else (step_rows * heads * dv,))), f32),
+            pend_b=jnp.zeros(
+                (layers, slots, step_rows, heads if groups is None else 0),
+                f32),
             pend_g=jnp.zeros((layers, slots, step_rows, heads, *decay), f32),
             pend_n=jnp.zeros((slots,), jnp.int32),
             snap_state=jnp.zeros((layers, snapshots, dk, heads * dv), f32),
@@ -751,11 +738,9 @@ def write_decode_all(
         return _write_latent_rows(k_pages, k_new, page_idx, offset), None
     use, interpret = _pallas_mode(use_pallas)
     # same Mosaic constraint as the attention kernels: page slices need a
-    # 128-lane-aligned minor dim on real TPU — met either by a (padded)
-    # d % 128 pool or by the ragged layout's flat [ps, KVH*D] row view
+    # 128-lane-aligned minor dim on real TPU — a (padded) d % 128 pool
     mode, ax = kernel_mesh_axis(mesh, k_new.shape[2])
-    if use and mode != "ref" and _write_lane_gate(k_pages, ax, mesh,
-                                                  interpret):
+    if use and mode != "ref" and _write_lane_gate(k_pages, interpret):
         from gridllm_tpu.ops.pallas_kernels import paged_write_decode
 
         record_kernel_path("write_decode", True)
@@ -851,8 +836,7 @@ def write_multi_all(
     v_flat = v_new.reshape(n_layers, s * t, *v_new.shape[3:])
     use, interpret = _pallas_mode(use_pallas)
     mode, ax = kernel_mesh_axis(mesh, k_new.shape[3])
-    if use and mode != "ref" and _write_lane_gate(k_pages, ax, mesh,
-                                                  interpret):
+    if use and mode != "ref" and _write_lane_gate(k_pages, interpret):
         from gridllm_tpu.ops.pallas_kernels import paged_write_decode
 
         record_kernel_path("write_multi", True)
@@ -1019,7 +1003,7 @@ def write_prefill_all(
     use, interpret = _pallas_mode(use_pallas)
     mode, ax = kernel_mesh_axis(mesh, k_new.shape[2])
     if use and mode != "ref" and k_new.shape[1] % page_size == 0 and (
-        _write_lane_gate(k_pages, ax, mesh, interpret)
+        _write_lane_gate(k_pages, interpret)
     ):
         from gridllm_tpu.ops.pallas_kernels import paged_write_chunk
 

@@ -34,6 +34,19 @@ a third more bytes) and a kernel block of `head_pack` heads is lane-dense.
 
 `gdn_recurrent` is the plain token-by-token form: the oracle of both.
 
+THE STATE-SPACE SCAN (`ssd_recurrent`, `ssd_chunk`, `ssd_step`: Mamba-2's
+SSD, at the end of this module) is the rule WITHOUT the delta, and with
+keys and queries shared by all heads (one group: B and C): S <- alpha S +
+k v^T, o = S^T q. No correction means no triangular system: a block's own
+part is a masked product, and because k and q are every head's, the
+state's products are ONE matrix product at the packed width (q [C, dk]
+times the packed state [dk, H*dv]) with the heads' decays applied on the
+lanes. Kernels of their own (`ssd_chunk`, `ssd_step` in pallas_kernels.py),
+not `gdn_*`'s block update with beta switched off: that update takes k, q
+and the rows' corrections a HEAD ([H, C, dk]), so it would read B and C
+copied `heads` times (64 at the one family served) and spend two products
+a head on a correction that is zero.
+
 KIMI DELTA ATTENTION (`kda_recurrent`, `kda_chunk`, `kda_step`) is the same
 rule with a log decay a head A KEY CHANNEL, g [.., H, dk]: S <- Diag(alpha)
 S in the first line above. The two forms and the two kernels are the same
@@ -80,14 +93,18 @@ def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def causal_conv(x_full: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
-    """Depthwise causal convolution then SiLU. x_full [..., K-1+T, C]: the
-    K-1 rows before the first, then the T rows; w [K, C], tap K-1 on the
-    row itself. Returns [..., T, C] float32."""
+def causal_conv(x_full: jnp.ndarray, w: jnp.ndarray,
+                bias: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Depthwise causal convolution (plus `bias` [C] where the layer has
+    one) then SiLU. x_full [..., K-1+T, C]: the K-1 rows before the first,
+    then the T rows; w [K, C], tap K-1 on the row itself. Returns
+    [..., T, C] float32."""
     k = w.shape[0]
     t = x_full.shape[-2] - (k - 1)
     xf, wf = x_full.astype(jnp.float32), w.astype(jnp.float32)
     y = sum(xf[..., i:i + t, :] * wf[i] for i in range(k))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y)
 
 
@@ -413,4 +430,149 @@ def _step(op: str, use: bool, interpret: bool, states, layer, pend, n, q, k,
     order = jnp.argsort(~live, stable=True)
     states, o = kernel(states, layer, order, live.sum(), _lanes(wy, dv),
                        heads=h, interpret=interpret, name=op)
+    return states, jnp.where(alive, o[:, :t].reshape(s, t, h, dv), 0.0)
+
+
+# ---------------------------------------------------------------------------
+# the state-space scan (Mamba-2's SSD): no delta, keys and queries a group
+# ---------------------------------------------------------------------------
+
+
+def ssd_recurrent(state, q, k, v, g):
+    """Token by token: S <- exp(g) S + k v^T, o = S^T q. state [H, dk, dv];
+    q, k [T, dk] (one group: every head's C and B); v [T, H, dv] (dt x);
+    g [T, H] the log decay. Returns (o [T, H, dv], state after the T rows).
+    The oracle of `ssd_chunk` and `ssd_step`."""
+    def one(s, row):
+        qt, kt, vt, gt = row
+        s = s * jnp.exp(gt)[:, None, None] + kt[None, :, None] * vt[:, None, :]
+        return s, jnp.einsum("hkd,k->hd", s, qt, precision=HI)
+
+    state, o = jax.lax.scan(
+        one, state.astype(jnp.float32),
+        tuple(x.astype(jnp.float32) for x in (q, k, v, g)))
+    return o, state
+
+
+def ssd_lane_tile(lanes: int) -> int:
+    """Lanes of the packed state one kernel step holds: the widest of
+    1024 .. 128 that divides H*dv, all of them where none does."""
+    return next((t for t in (1024, 512, 256, 128) if lanes % t == 0), lanes)
+
+
+def _ssd_blocks(q, k, v, g, block: int):
+    """What a block's rows give without the state. q, k [T, dk]; v [T, H,
+    dv]; g [T, H], T a multiple of `block`. Returns, each with leading
+    [nb]: q, k [C, dk]; own [C, H*dv] (the rows' own part of the output);
+    eg [C, H*dv] (O = (q S) * eg + own); vd [C, H*dv] and gc [1, H*dv]
+    (the state after: S' = gc * S + k^T vd), the heads' factors repeated
+    over their value lanes as the packed state lies. Every exponent formed
+    is <= 0 (g <= 0, and only later-minus-earlier running sums)."""
+    t, h, dv = v.shape
+    nb = t // block
+    q, k = (x.astype(jnp.float32).reshape(nb, block, -1) for x in (q, k))
+    v = v.astype(jnp.float32).reshape(nb, block, h, dv)
+    cum = jnp.cumsum(g.astype(jnp.float32).reshape(nb, block, h), axis=1)
+    cum = jnp.moveaxis(cum, -1, 1)                          # [nb, H, C]
+    low = jnp.tril(jnp.ones((block, block), bool))
+    gam = jnp.where(low, jnp.exp(jnp.where(
+        low, cum[..., :, None] - cum[..., None, :], 0.0)), 0.0)
+    qk = jnp.einsum("nid,njd->nij", q, k, precision=HI)
+    own = jnp.einsum("nhij,njhd->nihd", gam * qk[:, None], v, precision=HI)
+    last = cum[..., -1:]                                    # [nb, H, 1]
+
+    def lanes(x):                        # [nb, H, R] -> [nb, R, H*dv]
+        return jnp.repeat(jnp.moveaxis(x, 1, 2), dv, axis=-1)
+
+    return {
+        "q": q, "k": k, "own": own.reshape(nb, block, h * dv),
+        "eg": lanes(jnp.exp(cum)),
+        "vd": v.reshape(nb, block, h * dv) * lanes(jnp.exp(last - cum)),
+        "gc": lanes(jnp.exp(last)),
+    }
+
+
+def _ssd_chain(state, blocks, keep):
+    """The blocks one after another from `state` [dk, H*dv] packed (jnp
+    form). `keep` as in `_chain`. Returns (o [nb, C, H*dv], state, kept
+    [n, dk, H*dv])."""
+    nb = blocks["q"].shape[0]
+    kept0 = jnp.zeros((keep.shape[0], *state.shape), jnp.float32)
+
+    def one(carry, xs):
+        s, kept = carry
+        blk, i = xs
+        o = jnp.dot(blk["q"], s, precision=HI) * blk["eg"] + blk["own"]
+        s = s * blk["gc"] + jnp.einsum("ck,cl->kl", blk["k"], blk["vd"],
+                                       precision=HI)
+        kept = jnp.where((keep == i)[:, None, None], s[None], kept)
+        return (s, kept), o
+
+    (state, kept), o = jax.lax.scan(
+        one, (state.astype(jnp.float32), kept0),
+        (blocks, jnp.arange(nb, dtype=jnp.int32)))
+    return o, state, kept
+
+
+def ssd_chunk(state, q, k, v, g, keep, block: int,
+              use_pallas: bool | None = None):
+    """ONE slot's rows from its carried state. state [dk, H*dv] (packed);
+    q, k [T, dk]; v [T, H, dv]; g [T, H]; rows that hold no token ZERO in
+    all four; keep [n] block indices whose end state is handed back (-1:
+    zeros). Returns (o [T, H, dv], state after, kept [n, dk, H*dv])."""
+    use, interpret = _pallas_mode(use_pallas)
+    record_kernel_path("ssd_chunk", use)
+    t, h, dv = v.shape
+    blocks = _ssd_blocks(q, k, v, g, block)
+    if use:
+        from gridllm_tpu.ops.pallas_kernels import ssd_chunk as kernel
+
+        o, state, kept = kernel(state, blocks, keep, interpret=interpret)
+    else:
+        o, state, kept = _ssd_chain(state, blocks, keep)
+    return o.reshape(t, h, dv), state, kept
+
+
+def ssd_step(states, layer, pend, n, q, k, v, g, live,
+             use_pallas: bool | None = None):
+    """A launch's rows of every slot over a lagging state (`_step`'s
+    contract without the delta). states [Ll, S, dk, H*dv] (every linear
+    layer, packed), `layer` the one stepped; pend = (k [S, Tp, dk], v [S,
+    Tp, H, dv], g [S, Tp, H]) of the last launch's rows of which the first
+    n [S] were kept; q, k [S, T, dk], v [S, T, H, dv], g [S, T, H] the new
+    rows; live [S] bool. Returns (states with `layer`'s live slots
+    committed through the pending rows, o [S, T, H, dv]; zeros for a slot
+    that is not live)."""
+    use, interpret = _pallas_mode(use_pallas)
+    record_kernel_path("ssd_step", use)
+    s, t, h, dv = v.shape
+    pk, pv, pg = pend
+    # what does not count is ZEROED before any product (see `_step`)
+    took = (jnp.arange(pk.shape[1])[None] < n[:, None]) & live[:, None]
+    pk, pv, pg = (
+        jnp.where(took.reshape(took.shape + (1,) * (z.ndim - 2)), z, 0.0)
+        for z in (pk, pv, pg))
+    q, k, v, g = (
+        jnp.where(live.reshape((s,) + (1,) * (z.ndim - 1)), z, 0.0)
+        for z in (q, k, v, g))
+    alive = live[:, None, None, None]
+    if not use:
+        st = unpack(jax.lax.dynamic_index_in_dim(states, layer, keepdims=False),
+                    h)
+        _, st = jax.vmap(ssd_recurrent)(st, pk, pk, pv, pg)
+        o, _ = jax.vmap(ssd_recurrent)(st, q, k, v, g)
+        return jax.lax.dynamic_update_index_in_dim(
+            states, pack(st), layer, 0), jnp.where(alive, o, 0.0)
+    from gridllm_tpu.ops.pallas_kernels import ssd_step as kernel
+
+    rows = STEP_ROWS * -(-max(t, pk.shape[1]) // STEP_ROWS)
+    old, new = (
+        jax.tree.map(lambda a: a[:, 0], jax.vmap(
+            lambda *a: _ssd_blocks(*a, rows))(
+                *(_pad_rows(x.astype(jnp.float32), rows) for x in part)))
+        for part in ((pk, pk, pv, pg), (q, k, v, g)))
+    # live slots first: the kernel's grid walks them and moves no other's
+    order = jnp.argsort(~live, stable=True)
+    states, o = kernel(states, layer, order, live.sum(), old, new,
+                       interpret=interpret)
     return states, jnp.where(alive, o[:, :t].reshape(s, t, h, dv), 0.0)

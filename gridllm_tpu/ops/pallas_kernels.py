@@ -389,11 +389,11 @@ def _ragged_attn_kernel(
     layer = scal_ref[0]
     window = scal_ref[1]
     scale = jax.lax.rsqrt(jnp.float32(d))
-    # flat-lane pools (d % 128 != 0, ISSUE 6): pages are STORED unpadded
-    # (the KV-bytes win) and lane-padded here, in-register after the
-    # load, so every dot still runs on 128-lane minors — numerically
-    # exact (zero lanes meet zero q lanes), same compute as over a
-    # lane-padded pool, half the HBM bytes/bandwidth (`_lp`)
+    # an interpreted pool keeps the model's head dim (d % 128 != 0: tests
+    # stay small) and is lane-padded here, in-register after the load, so
+    # every dot runs on 128-lane minors as over the lane-padded pool a
+    # compiled kernel is given — numerically exact (zero lanes meet zero
+    # q lanes) (`_lp`)
     do = dv if latent else d     # output (value) width
     dop = -(-do // 128) * 128    # ... and the accumulator's, lane-padded
 
@@ -768,12 +768,11 @@ def ragged_attention(
     """Kernel form of ops.attention.ragged_paged_attention: ONE launch,
     static grid (C/BQ chunk tiles + S group tiles) serving chunked
     prefill, decode (Td=1), and spec-verify (Td=K+1) at once. See the
-    dispatcher's docstring for the region contracts. Accepts d < 128
-    pools when the PER-SHARD KVH*D is lane-aligned: pages are STORED
-    unpadded (contiguous [ps, KVH*D]-byte rows, so the page DMA stays
-    tile-aligned) and the loaded values are zero-padded to 128 lanes
-    in-register before every dot — same compute as over a lane-padded
-    pool, half the HBM bytes/bandwidth.
+    dispatcher's docstring for the region contracts. Interpreted, it
+    accepts a pool at a head dim under 128 (the loaded values are
+    zero-padded to 128 lanes in-register before every dot); compiled,
+    Mosaic refuses the slice of such a page, so the engine hands it a
+    lane-padded pool (`engine._pool_head_dim`).
 
     `latent_dv` > 0: a latent pool (`v_pages`, `v_chunk`, `v_group` all
     None): the one cache head's row is the key and its first `latent_dv`
@@ -1010,6 +1009,10 @@ def _write_decode_all_kernel(
             ).wait()
 
 
+# rows one call of paged_write_decode writes (two DMA semaphores each)
+_WRITE_ROWS = 128
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_write_decode(
     k_pages: jnp.ndarray,
@@ -1035,6 +1038,17 @@ def paged_write_decode(
     """
     L, _, _, kvh, d = k_pages.shape
     s = k_new.shape[1]
+    if s > _WRITE_ROWS:
+        # a DMA semaphore a row and pool: 48 slots x 5 verify rows ask
+        # for 480, past the chip's semaphore memory (compiled for a v5e:
+        # "ran out of memory in memory space sflag", PR 61). The rows in
+        # calls of at most _WRITE_ROWS; each updates the pools in place
+        for a in range(0, s, _WRITE_ROWS):
+            b = min(a + _WRITE_ROWS, s)
+            k_pages, v_pages = paged_write_decode(
+                k_pages, v_pages, k_new[:, a:b], v_new[:, a:b],
+                page_idx[a:b], offset[a:b], interpret=interpret)
+        return k_pages, v_pages
     num_pages = k_pages.shape[1]
     kernel = functools.partial(
         _write_decode_all_kernel, num_pages=num_pages, s=s
@@ -1393,6 +1407,176 @@ def gdn_step(states, layer, order, n_live, wy, *, heads: int,
     )(jnp.asarray(layer, jnp.int32).reshape(1), order.astype(jnp.int32),
       jnp.asarray(n_live, jnp.int32).reshape(1), states, wy["wv"], wy["wk"],
       wy["aqk"], wy["qg"], wy["kd"], wy["gc"])
+
+
+# ---------------------------------------------------------------------------
+# the state-space scan (ops/linear_attn.py: `ssd_*`): the rule without the
+# delta, keys and queries shared by every head
+# ---------------------------------------------------------------------------
+
+
+def _ssd_read(s, q, eg, own):
+    """A block's output on the state before it: (q S) * eg + own. s [dk,
+    L] float32 (a lane tile of the packed state); q [C, dk]; eg, own [C,
+    L]. One product at the packed width: q is every head's."""
+    return jnp.dot(q, s, preferred_element_type=jnp.float32,
+                   precision=_HI) * eg + own
+
+
+def _ssd_write(s, k, vd, gc):
+    """The state after a block: gc * S + k^T vd. k [C, dk]; vd [C, L];
+    gc [1, L]."""
+    return s * gc + jax.lax.dot_general(
+        k, vd, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=_HI)
+
+
+def _ssd_chunk_kernel(keep_ref, s0_ref, q_ref, k_ref, eg_ref, own_ref,
+                      vd_ref, gc_ref, o_ref, s_ref, kept_ref, acc, *,
+                      n_keep: int):
+    i = pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _():
+        acc[...] = s0_ref[...]
+        kept_ref[...] = jnp.zeros_like(kept_ref)
+
+    s = acc[...]
+    o_ref[...] = _ssd_read(s, q_ref[...], eg_ref[...], own_ref[...])
+    s = _ssd_write(s, k_ref[...], vd_ref[...], gc_ref[...])
+    acc[...] = s
+    for j in range(n_keep):
+        @pl.when(keep_ref[j] == i)
+        def _(j=j):
+            kept_ref[j] = s
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _():
+        s_ref[...] = s
+
+
+def ssd_chunk(state, blocks, keep, *, interpret: bool = False):
+    """The blocks of ONE slot's rows chained through its state. state
+    [dk, H*dv] float32 (packed); blocks: ops.linear_attn._ssd_blocks(..)
+    with leading [nb]; keep [n] int32 block indices whose end state is
+    handed back (-1: zeros). Returns (o [nb, C, H*dv], the state after,
+    kept [n, dk, H*dv]). Grid (lane tiles, blocks): a tile of the state
+    stays in VMEM across its blocks; q and k are read once a tile."""
+    from gridllm_tpu.ops.linear_attn import ssd_lane_tile
+
+    dk, hd = state.shape
+    lanes = ssd_lane_tile(hd)
+    nb, c = blocks["q"].shape[:2]
+    n_keep = keep.shape[0]
+    shared = pl.BlockSpec((None, c, dk), lambda h, i, *_: (i, 0, 0))
+    packed = pl.BlockSpec((None, c, lanes), lambda h, i, *_: (i, 0, h))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(hd // lanes, nb),
+        in_specs=[
+            pl.BlockSpec((dk, lanes), lambda h, i, *_: (0, h)),
+            shared, shared, packed, packed, packed,
+            pl.BlockSpec((None, 1, lanes), lambda h, i, *_: (i, 0, h)),
+        ],
+        out_specs=[
+            packed,
+            pl.BlockSpec((dk, lanes), lambda h, i, *_: (0, h)),
+            pl.BlockSpec((n_keep, dk, lanes), lambda h, i, *_: (0, 0, h)),
+        ],
+        scratch_shapes=[pltpu.VMEM((dk, lanes), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_ssd_chunk_kernel, n_keep=n_keep),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((nb, c, hd), jnp.float32),
+            jax.ShapeDtypeStruct((dk, hd), jnp.float32),
+            jax.ShapeDtypeStruct((n_keep, dk, hd), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="ssd_chunk",
+    )(keep.astype(jnp.int32), state, blocks["q"], blocks["k"], blocks["eg"],
+      blocks["own"], blocks["vd"], blocks["gc"])
+
+
+def _ssd_step_kernel(layer_ref, order_ref, live_ref, s_in, pk_ref, pvd_ref,
+                     pgc_ref, q_ref, eg_ref, own_ref, s_out, o_ref):
+    del layer_ref, order_ref         # read by the index maps
+    n_live = live_ref[0]
+
+    @pl.when(pl.program_id(1) < n_live)
+    def _():
+        # the last launch's rows that were kept, committed and written
+        # back; this launch's rows read on top, not written
+        s = _ssd_write(s_in[...], pk_ref[...], pvd_ref[...], pgc_ref[...])
+        s_out[...] = s
+        o_ref[...] = _ssd_read(s, q_ref[...], eg_ref[...], own_ref[...])
+
+    @pl.when(n_live == 0)
+    def _():
+        # nothing is live: every step visits the first slot's block, which
+        # goes back as it came
+        s_out[...] = s_in[...]
+
+
+def ssd_step(states, layer, order, n_live, old, new, *,
+             interpret: bool = False):
+    """Every LIVE slot's pending rows committed and its new rows read.
+    states [Ll, S, dk, H*dv] float32 (every linear layer; `layer` picks,
+    updated IN PLACE: input_output_aliases); order [S] int32 the slots,
+    live ones first, n_live of them; old, new: _ssd_blocks(..) of the
+    pending and the new rows with leading [S], rows padded to whole
+    sublane tiles. Returns (states, o [S, C, H*dv] of the new rows; junk
+    for a slot that is not live). Grid (lane tiles, slots in `order`): one
+    read and one write of each live slot's state; a step past the live
+    ones names the last live slot's blocks again, which moves nothing."""
+    from gridllm_tpu.ops.linear_attn import ssd_lane_tile
+
+    _, slots, dk, hd = states.shape
+    lanes = ssd_lane_tile(hd)
+    c = new["q"].shape[1]
+
+    def slot(g, order, live):
+        return order[jnp.minimum(g, jnp.maximum(live[0] - 1, 0))]
+
+    def shared():
+        return pl.BlockSpec(
+            (None, c, dk),
+            lambda h, g, li, order, live: (slot(g, order, live), 0, 0))
+
+    def packed(rows):
+        return pl.BlockSpec(
+            (None, rows, lanes),
+            lambda h, g, li, order, live: (slot(g, order, live), 0, h))
+
+    state_spec = pl.BlockSpec(
+        (None, None, dk, lanes),
+        lambda h, g, li, order, live: (li[0], slot(g, order, live), 0, h))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(hd // lanes, slots),
+        in_specs=[state_spec, shared(), packed(c), packed(1),
+                  shared(), packed(c), packed(c)],
+        out_specs=[state_spec, packed(c)],
+    )
+    return pl.pallas_call(
+        _ssd_step_kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(states.shape, jnp.float32),
+            jax.ShapeDtypeStruct((slots, c, hd), jnp.float32),
+        ],
+        # 0: layer, 1: order, 2: n_live, 3: states, 4..: the blocks' arrays
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="ssd_step",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), order.astype(jnp.int32),
+      jnp.asarray(n_live, jnp.int32).reshape(1), states, old["k"], old["vd"],
+      old["gc"], new["q"], new["eg"], new["own"])
 
 
 # ---------------------------------------------------------------------------

@@ -1176,7 +1176,8 @@ def test_live_tree_has_seven_kernels_and_no_attention_switch():
     """The KERNELS registry names exactly the public functions of
     ops/pallas_kernels.py that launch a pl.pallas_call — seven since
     PR 42 (the delta rule's two), eight since PR 53 (the routed experts'
-    grouped product), nine since PR 58 (its sorted regime), with one
+    grouped product), nine since PR 58 (its sorted regime), eleven since
+    PR 61 (the state-space scan's two), with one
     paged-attention kernel among them — and no
     knob selects a second paged-attention path."""
     import ast
@@ -1195,7 +1196,7 @@ def test_live_tree_has_seven_kernels_and_no_attention_switch():
     assert launchers == set(kernel_names()) == {
         "flash_prefill", "flash_prefill_streamed", "ragged_attention",
         "paged_write_decode", "paged_write_chunk", "gdn_chunk", "gdn_step",
-        "grouped_experts", "grouped_experts_sorted",
+        "grouped_experts", "grouped_experts_sorted", "ssd_chunk", "ssd_step",
     }
     assert {lb for lb in dispatch_labels() if lb.startswith("attention_")} \
         == {"attention_prefill", "attention_ragged"}

@@ -44,6 +44,8 @@ FAMILIES = {
     "tiny-kimi-linear": {"validate_mesh", "STEP_STATS", "new_state",
                          "commit_verify", "SAVES"},
     "tiny-longcat-flash": {"validate_mesh", "STEP_STATS"},
+    "tiny-granite-hybrid": {"validate_mesh", "new_state", "commit_verify",
+                            "SAVES"},
 }
 
 
@@ -66,11 +68,11 @@ def admits(mod, cfg, **axes) -> bool:
 
 def test_the_lists_are_the_engines_lookups():
     """A hook added to engine.py is added above, with the families that
-    define it; and the presets reach ten different modules."""
+    define it; and the presets reach eleven different modules."""
     assert names_the_engine_reads() == (
         EVERY_FAMILY | DECODER | SP_OR_PP | HOOKS)
     assert len({engine_mod._model_module(get_config(p))
-                for p in FAMILIES}) == len(FAMILIES) == 10
+                for p in FAMILIES}) == len(FAMILIES) == 11
 
 
 @pytest.mark.parametrize("preset", sorted(FAMILIES))

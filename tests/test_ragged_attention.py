@@ -361,11 +361,12 @@ def test_zero_steady_recompiles_over_varying_mixes():
 
 
 def test_ragged_pool_unpadded_and_memory_fields():
-    """_pool_head_dim: the pool stays at the model's head
-    dim when KVH*D is flat-lane aligned (no 2x lane-pad bytes), and
-    /admin/memory's allocator math reports zero lane-pad overhead with
-    kvLayout "ragged". (Interpret/CPU engines keep the unpadded pool
-    either way; the layout assertion is on the accounting fields.)"""
+    """_pool_head_dim: an interpreted engine's pool stays at the model's
+    head dim, and /admin/memory's allocator math then reports zero
+    lane-pad overhead with kvLayout "ragged". (Where kernels compile, a
+    head under 128 is stored at 128 lanes, "ragged-padded":
+    tests/test_ops.py forces that layout, tests/test_granite_hybrid.py
+    holds why there is no other.)"""
     eng = _engine(spec_decode=False)
     alloc = eng.memory_arrays()["alloc"]
     assert alloc["kvLayout"] == "ragged"
